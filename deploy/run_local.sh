@@ -6,7 +6,6 @@
 set -euo pipefail
 
 MODE="${RUN_MODE:-EVA}"
-PLATFORM=""
 
 probe_tpu() {
     # TPU VM device nodes: /dev/accel* (v4+/v5) or vfio-bound PCI.
@@ -21,11 +20,14 @@ probe_tpu() {
     return 1
 }
 
-if ! probe_tpu; then
-    echo "no TPU devices found — running on the CPU fake backend" >&2
-    PLATFORM="cpu"
-    export EVAM_PLATFORM=cpu
+# No silent CPU fallback: without TPU device nodes the run fails unless
+# the caller asked for the CPU dry run with JAX_PLATFORMS=cpu (serve
+# makes the same check on the backend JAX actually brings up).
+if [ "${JAX_PLATFORMS:-}" = "cpu" ]; then
     export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
+elif ! probe_tpu; then
+    echo "no TPU devices found; set JAX_PLATFORMS=cpu for the CPU dry run" >&2
+    exit 1
 fi
 
 # Build native kernels if the toolchain is present.
@@ -33,5 +35,5 @@ if command -v g++ > /dev/null; then
     make -C "$(dirname "$0")/../native" >/dev/null 2>&1 || true
 fi
 
-echo "starting evam-tpu (mode=$MODE platform=${PLATFORM:-tpu})"
+echo "starting evam-tpu (mode=$MODE platform=${JAX_PLATFORMS:-tpu})"
 exec python -m evam_tpu.cli.main serve --mode "$MODE" "$@"

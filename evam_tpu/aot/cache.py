@@ -15,7 +15,7 @@ Contract:
   (:func:`cache_key`) hashes the hub's program fingerprint (engine
   key + wire/synth/ragged/sched config), the bucket rung, every step
   input's shape+dtype, the params aval signature, the device set the
-  executable is bound to, the donation tuple and the backend. The
+  executable is bound to and the backend. The
   jax / jaxlib / PJRT platform versions deliberately live in the
   entry HEADER, not the key — a version upgrade then reads as a
   distinguishable ``version`` miss instead of a silent absent one.
@@ -60,17 +60,10 @@ MISS_REASONS = ("absent", "version", "crc", "deserialize", "execute")
 
 _EXT = ".aotx"
 
-try:  # gated: never a hard dependency — absent support disables the layer
-    from jax.experimental.serialize_executable import (
-        deserialize_and_load,
-        serialize,
-    )
-
-    _HAVE_SERIALIZE = True
-except Exception:  # noqa: BLE001 — old jaxlib / stripped install
-    deserialize_and_load = None
-    serialize = None
-    _HAVE_SERIALIZE = False
+from jax.experimental.serialize_executable import (
+    deserialize_and_load,
+    serialize,
+)
 
 
 class _EntryError(ValueError):
@@ -82,7 +75,7 @@ class _EntryError(ValueError):
 
 
 def cache_key(program: str, bucket: int, inputs, params_sig,
-              devices, donate, backend: str) -> str:
+              devices, backend: str) -> str:
     """Content address for one (program, rung, placement) executable.
 
     Everything that changes the compiled artifact is in here;
@@ -97,7 +90,6 @@ def cache_key(program: str, bucket: int, inputs, params_sig,
         "params": [[[int(d) for d in shape], str(dt)]
                    for shape, dt in params_sig],
         "devices": [str(d) for d in devices],
-        "donate": [int(i) for i in donate],
         "backend": str(backend),
     }
     blob = json.dumps(doc, sort_keys=True, separators=(",", ":"))
@@ -196,8 +188,10 @@ class AotCache:
     def _path(self, key: str) -> Path:
         return self.root / f"{key}{_EXT}"
 
-    def load(self, key: str, engine: str = ""):
-        """The loaded executable for ``key``, or None after counting
+    def load(self, key: str, devices, engine: str = ""):
+        """The executable for ``key`` loaded onto ``devices`` (the
+        engine's own device list, in mesh order — without it jax binds
+        the executable to every local device), or None after counting
         the miss reason (``absent``/``version``/``crc``/
         ``deserialize``). The caller validates with one execute and
         then confirms via :meth:`hit` (or :meth:`execute_miss`) — a
@@ -227,7 +221,9 @@ class AotCache:
             return None
         try:
             unloaded, in_tree, out_tree = pickle.loads(payload)
-            loaded = deserialize_and_load(unloaded, in_tree, out_tree)
+            loaded = deserialize_and_load(
+                unloaded, in_tree, out_tree,
+                execution_devices=list(devices))
         except Exception as exc:  # noqa: BLE001 — any pjrt/pickle rot
             log.warning("aot entry %s failed to deserialize (%s) — "
                         "falling back to jit", path.name, exc)
@@ -364,9 +360,8 @@ _resolved: tuple[AotCache | None] | None = None
 
 
 def active() -> AotCache | None:
-    """The process AotCache, or None with EVAM_AOT=off (default) or a
-    jax that can't serialize executables. Memoized: the off path costs
-    one global load per consult."""
+    """The process AotCache, or None with EVAM_AOT=off (default).
+    Memoized: the off path costs one global load per consult."""
     if _resolved is not None:
         return _resolved[0]
     return _resolve()
@@ -379,18 +374,12 @@ def _resolve() -> AotCache | None:
     cfg = get_settings().aot
     cache: AotCache | None = None
     if cfg.enabled:
-        if not _HAVE_SERIALIZE:
-            log.warning(
-                "EVAM_AOT=on but this jax has no serialize_executable "
-                "support — AOT cache disabled, serving plain jit")
-        else:
-            root = cfg.dir or os.path.join(
-                tempfile.gettempdir(), "evam_aot")
-            try:
-                cache = AotCache(root, cfg.max_bytes)
-            except OSError as exc:
-                log.warning("EVAM_AOT dir %s unusable (%s) — AOT "
-                            "cache disabled", root, exc)
+        root = cfg.dir or os.path.join(tempfile.gettempdir(), "evam_aot")
+        try:
+            cache = AotCache(root, cfg.max_bytes)
+        except OSError as exc:
+            log.warning("EVAM_AOT dir %s unusable (%s) — AOT "
+                        "cache disabled", root, exc)
     _resolved = (cache,)
     return cache
 

@@ -35,16 +35,13 @@ class TPUSettings(BaseModel):
 
     mesh_shape: list[int] = Field(default_factory=lambda: [-1])
     mesh_axes: list[str] = Field(default_factory=lambda: ["data"])
-    #: top batch bucket. 128 is the measured p99<100 ms operating
-    #: point on the v5e (PROFILE.md); throughput-bound deployments set
-    #: EVAM_MAX_BATCH=256-512 (127-142 streams/chip measured, higher
-    #: p99) — dispatch overhead amortizes with batch, so undersizing
-    #: this is the first thing to check when a chip underdelivers.
+    #: top batch bucket: the latency-leaning default; throughput-bound
+    #: deployments raise EVAM_MAX_BATCH (higher p99) — dispatch
+    #: overhead amortizes with batch, so undersizing this is the first
+    #: thing to check when a chip underdelivers.
     max_batch: int = 128
     batch_deadline_ms: float = 8.0
     precision: str = "bfloat16"
-    donate_buffers: bool = True
-    compile_cache_dir: str = ""
     #: precompile every batch bucket in the background when an engine
     #: is created (kills mid-traffic compile spikes; off in tests)
     warmup: bool = True
@@ -75,7 +72,7 @@ class TPUSettings(BaseModel):
     transfer: Literal["pipelined", "inline"] = "pipelined"
     #: pipelined-transfer upload-queue depth: how many staged batches
     #: may sit between the dispatcher's h2d_issue and the launcher.
-    #: 2 is the measured sweet spot at boot; the control plane
+    #: 2 = one batch uploading while one launches; the control plane
     #: (EVAM_TUNE=on) retunes it live from the h2d_wait/launch ratio.
     #: Setting it explicitly pins it against the controller.
     transfer_depth: int = 2
@@ -347,7 +344,6 @@ class Settings(BaseModel):
             "EVAM_MAX_BATCH": ("max_batch", int),
             "EVAM_BATCH_DEADLINE_MS": ("batch_deadline_ms", float),
             "EVAM_PRECISION": ("precision", str),
-            "EVAM_COMPILE_CACHE_DIR": ("compile_cache_dir", str),
             "EVAM_WARMUP": ("warmup", _parse_bool),
             "EVAM_STALL_TIMEOUT_S": ("stall_timeout_s", float),
             "EVAM_ENGINE_SUPERVISE": ("supervise", _parse_bool),

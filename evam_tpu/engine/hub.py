@@ -277,7 +277,7 @@ class EngineHub:
 
         # AOT cache program fingerprint (evam_tpu/aot/): everything at
         # the hub level that changes what the step COMPUTES. Shapes,
-        # devices, donation and params avals are appended per bucket
+        # devices and params avals are appended per bucket
         # by the engine (BatchEngine._aot_bucket_key) — so supervisor
         # rebuilds and fleet shard spin-ups of the same program land
         # on the same entries, while a wire-format or ragged-mode flip
@@ -393,8 +393,11 @@ class EngineHub:
             "aot": {"hits": e.stats.aot_hits,
                     "load_s": round(e.stats.aot_load_seconds, 3)},
             "oversize_splits": e.stats.oversize_splits,
-            # per-batch host clock means (ringbuf.STAGES order)
+            # per-batch host clock means (ringbuf.STAGES order) and
+            # how many batches they cover (EngineStats.stage_seconds
+            # says which ones the clock leaves out)
             "stage_ms": e.stats.stage_ms_per_batch(),
+            "stage_batches": e.stats.clocked,
             # supervision lifecycle (engine/supervisor.py);
             # unsupervised raw engines report a static running
             "state": getattr(e, "state", "running"),
@@ -416,21 +419,27 @@ class EngineHub:
             "group": group,
         }
 
-    def stats(self) -> dict[str, dict]:
+    def _rows(self):
+        """(row key, engine, shard label, device, group) per /engines
+        row: one per engine, one per shard of a FleetEngine."""
         with self._lock:
             engines = dict(self._engines)
-        default_dev = (str(self.plan.mesh.devices.flat[0])
+        # a mesh engine runs on EVERY device of its plan — name them
+        # all, so a multi-chip placement is checkable from /engines
+        default_dev = (self.plan.device_names()
                        if self.plan is not None else None)
-        out: dict[str, dict] = {}
         for k, e in engines.items():
             if hasattr(e, "shard_rows"):  # FleetEngine (duck-typed: no cycle)
                 for label, dev, sub in e.shard_rows():
-                    out[f"{k}@{label}"] = self._stat_row(
-                        sub, shard=label, device=dev, group=k)
+                    yield f"{k}@{label}", sub, label, dev, k
             else:
-                out[k] = self._stat_row(
-                    e, shard=None, device=default_dev, group=k)
-        return out
+                yield k, e, None, default_dev, k
+
+    def stats(self) -> dict[str, dict]:
+        return {
+            key: self._stat_row(e, shard=shard, device=dev, group=group)
+            for key, e, shard, dev, group in self._rows()
+        }
 
     def stage_summary(self) -> dict[str, float]:
         """Batch-weighted mean per-batch host-stage cost across ALL
@@ -444,12 +453,12 @@ class EngineHub:
 
         with self._lock:
             engines = list(self._engines.values())
-        batches = sum(e.stats.batches for e in engines)
+        batches = sum(e.stats.clocked for e in engines)
         return {
             s: (round(
                 1e3 * sum(e.stats.stage_seconds.get(s, 0.0)
                           for e in engines) / batches, 3)
-                if batches else 0.0)
+                if batches > 0 else 0.0)
             for s in STAGES
         }
 
@@ -551,6 +560,13 @@ class EngineHub:
             "degraded": sum(1 for s in states if s == "degraded"),
             "restarts": sum(getattr(e, "restarts", 0) for e in engines),
         }
+
+    def warm_errors(self) -> dict[str, str]:
+        """Engine key → why its background warmup failed (empty when
+        every warmup so far succeeded)."""
+        errors = {key: getattr(e, "warm_error", None)
+                  for key, e, *_ in self._rows()}
+        return {key: err for key, err in errors.items() if err}
 
     def fleet_summary(self) -> dict:
         """The /scheduler fleet operating point (fixed keys — route

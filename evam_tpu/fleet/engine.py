@@ -587,8 +587,9 @@ class FleetEngine:
         with self._lock:
             rows = [(label, self._devices[label], eng)
                     for label, eng in self.shards.items()]
-        if self._mesh_eng is not None:
-            rows.append(("mesh", "mesh", self._mesh_eng))
+        mesh = self._mesh_eng
+        if mesh is not None:
+            rows.append(("mesh", mesh.plan.device_names(), mesh))
         return rows
 
     def placement_counts(self) -> dict[str, int]:

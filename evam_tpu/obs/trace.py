@@ -30,7 +30,7 @@ PROFILING_MODE env (eii/docker-compose.yml:43,59). Here, three layers:
    ``degraded`` transition. Pending (in-flight) batch records hold a
    reference to the SAME clock dict the dispatch path fills in
    stage-by-stage, so a wedged batch's record shows its last completed
-   stage — the post-mortem the tunnel-wedge question needs.
+   stage — the post-mortem a hung device call needs.
 
 ``EVAM_TRACE=off`` disables layer 2/3 entirely: ``active()`` memoizes
 to None, FrameContext.trace stays None, and every hook is a cheap
@@ -51,6 +51,7 @@ import threading
 import time
 import uuid
 from collections import deque
+from pathlib import Path
 
 from evam_tpu.obs import get_logger
 from evam_tpu.obs.metrics import metrics
@@ -589,17 +590,29 @@ def profiler_running() -> bool:
 def init_observability(settings) -> None:
     """One-call runtime bootstrap for both serve entrypoints:
     compilation cache + optional profiler server."""
-    configure_compilation_cache(settings.tpu.compile_cache_dir)
+    configure_compilation_cache()
     maybe_start_profiler(settings.profiling_mode)
 
 
-def configure_compilation_cache(cache_dir: str) -> None:
+#: the in-checkout default. The directory is part of every cache
+#: entry's key, so it is one fixed path — never derived from a
+#: tempdir, uid, pid or the clock, or a restart would never hit.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def configure_compilation_cache() -> str:
     """Persist XLA executables across restarts (SURVEY.md §5.4 — the
-    reference's analogue is the OpenCL cl_cache, Dockerfile:77-78)."""
-    if not cache_dir:
-        return
+    reference's analogue is the OpenCL cl_cache, Dockerfile:77-78).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    this sets no directory; otherwise the cache lives at
+    ``COMPILE_CACHE_DIR``. Returns the directory in use."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     log.info("XLA compilation cache at %s", cache_dir)
+    return cache_dir

@@ -35,8 +35,8 @@ def resize_matrix(in_size: int, out_size: int) -> np.ndarray:
     centers, kernel widened by 1/scale when downscaling, rows
     normalized) — tests/test_ops.py pins equality against
     jax.image.resize itself. Computed host-side so tracing the resize
-    path never needs a CPU jax backend (callers may restrict
-    jax_platforms to tpu only).
+    path never needs a CPU jax backend (JAX_PLATFORMS may name the
+    tpu only).
     """
     scale = out_size / in_size
     kernel_scale = min(scale, 1.0)  # antialias when downscaling

@@ -46,6 +46,11 @@ class MeshPlan:
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
 
+    def device_names(self) -> str:
+        """Every device of the mesh, space-joined in mesh order — the
+        /engines ``device`` column (a TPU's own name holds commas)."""
+        return " ".join(str(d) for d in self.mesh.devices.flat)
+
     def pad_batch(self, n: int) -> int:
         """Round n up to a multiple of the data-axis size."""
         d = self.data_size
@@ -62,6 +67,21 @@ class MeshPlan:
                      data_axis=self.data_axis)
             for dev in self.mesh.devices.flat
         ]
+
+
+def require_requested_backend() -> None:
+    """Refuse a CPU backend nobody asked for (serve and bench entry).
+
+    JAX falls back to the CPU when libtpu finds no chip; a server that
+    carried on would answer requests at CPU speed under a TPU name.
+    ``JAX_PLATFORMS=cpu`` is the explicit opt-in for the hardware-free
+    dry run (tests, rehearsals)."""
+    asked = os.environ.get("JAX_PLATFORMS", "")
+    if jax.default_backend() == "cpu" and asked.strip().lower() != "cpu":
+        raise SystemExit(
+            "evam-tpu: JAX came up on the CPU backend but JAX_PLATFORMS="
+            f"{asked!r} did not ask for it — no accelerator was found. "
+            "Set JAX_PLATFORMS=cpu to run the CPU dry run on purpose.")
 
 
 def build_mesh(
@@ -90,8 +110,8 @@ def build_mesh(
         raise ValueError(f"mesh shape {shape} != device count {n}")
     mesh = Mesh(np.asarray(devices).reshape(shape), axes)
     model_axis = "model" if "model" in axes else None
-    log.info("mesh: %s over %d devices (%s)", dict(zip(axes, shape)), n,
-             devices[0].platform)
+    log.info("mesh: %s over %d devices (%s, %s)", dict(zip(axes, shape)), n,
+             devices[0].platform, devices[0].device_kind)
     return MeshPlan(mesh=mesh, model_axis=model_axis)
 
 

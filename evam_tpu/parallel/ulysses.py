@@ -38,10 +38,7 @@ from typing import Callable
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from evam_tpu.parallel.ring import plain_attention
 

@@ -24,8 +24,9 @@ DECLARED fps (request ``fps`` field, default
 ``EVAM_SCHED_DEFAULT_FPS``). A start is rejected when projected
 utilization exceeds the class ceiling — ``EVAM_SCHED_ADMIT_UTIL``
 scaled by CLASS_HEADROOM, so ``batch`` is turned away first and
-``realtime`` last. A cold hub (no measured batches, no declared
-capacity) admits everything: you cannot model what you have not run.
+``realtime`` last. A cold hub (no declared capacity, and fewer than
+``MIN_CLOCKED_BATCHES`` steady-state batches on the stage clock)
+admits everything: you cannot model what you have not run.
 """
 
 from __future__ import annotations
@@ -44,6 +45,12 @@ log = get_logger("sched.admission")
 #: ceiling is hit by batch first, then standard, then realtime — the
 #: admission-side expression of the class ladder.
 CLASS_HEADROOM = {"realtime": 1.0, "standard": 0.85, "batch": 0.6}
+
+#: fewest steady-state batches a stats row must have clocked before
+#: its stage means count as a capacity measurement — the mean of a
+#: handful of batches at the tail of a cold start is noise, and a
+#: capacity read off it refuses streams the chip would carry
+MIN_CLOCKED_BATCHES = 32
 
 #: device-path stages of the per-batch clock (engine/ringbuf.STAGES)
 #: that bound the serial service time of one batch. With the
@@ -212,6 +219,8 @@ class AdmissionController:
         for key, stats in self.hub.stats().items():
             batches = stats.get("batches")
             if not batches:
+                continue
+            if stats.get("stage_batches", batches) < MIN_CLOCKED_BATCHES:
                 continue
             stage_ms = stats.get("stage_ms") or {}
             service_ms = sum(stage_ms.get(s, 0.0) for s in _SERVICE_STAGES)

@@ -13,6 +13,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 from pathlib import Path
 from typing import Any
 
@@ -174,38 +175,47 @@ class PipelineRegistry:
 
     def preload(self, names: str) -> int:
         """Serve-time engine preload (round-1 VERDICT item 7): build
-        the engines (and fire their background bucket warmup, when
-        ``tpu.warmup``) for the named pipelines BEFORE the REST port
-        opens, so the first POST never pays model build + XLA compile
-        in the hot path. ``names``: comma list of ``name/version`` (or
-        bare ``name`` = all versions), or ``all``.
+        and warm the engines for the named pipelines BEFORE the REST
+        port opens, so the first POST never pays model build + XLA
+        compile in the hot path. ``names``: comma list of
+        ``name/version`` (or bare ``name`` = all versions), or ``all``.
+
+        Raises when a name matches no pipeline, a pipeline fails to
+        build, or (with ``tpu.warmup``) a bucket fails to compile — a
+        deployment that asked for a pipeline to be ready must not open
+        the port without it.
 
         Engines are cached in the hub by (kind, model-instance) —
         building a throwaway stage chain per pipeline is exactly the
         instance start path minus the stream, so later instances get
         cache hits."""
-        from evam_tpu.graph.params import resolve_parameters
-        from evam_tpu.stages.build import build_stages
-
         wanted = [n.strip() for n in names.split(",") if n.strip()]
+        known = self.loader.names()
+
+        def names_it(w: str, name: str, version: str) -> bool:
+            return w in ("all", name, f"{name}/{version}")
+
+        for w in wanted:
+            if not any(names_it(w, n, v) for n, v in known):
+                raise KeyError(f"preload: pipeline {w!r} not found")
         count = 0
-        for name, version in self.loader.names():
+        for name, version in known:
             label = f"{name}/{version}"
-            if "all" not in wanted and not any(
-                w in (name, label) for w in wanted
-            ):
+            if not any(names_it(w, name, version) for w in wanted):
                 continue
             spec = self.loader.get(name, version)
-            try:
-                stage_specs, _ = resolve_parameters(spec, {})
-                build_stages(
-                    stage_specs, self.hub,
-                    publish_fn=lambda ctx: None, sink_fn=lambda ctx: None,
-                )
-                count += 1
-                log.info("preloaded %s", label)
-            except Exception as exc:  # noqa: BLE001 — preload is best-effort
-                log.warning("preload %s failed: %s", label, exc)
+            stage_specs, _ = resolve_parameters(spec, {})
+            build_stages(
+                stage_specs, self.hub,
+                publish_fn=lambda ctx: None, sink_fn=lambda ctx: None,
+            )
+            count += 1
+            log.info("preloaded %s", label)
+        while self.hub.readiness()["warming"]:
+            time.sleep(0.2)
+        failed = self.hub.warm_errors()
+        if failed:
+            raise RuntimeError(f"preload: engine warmup failed: {failed}")
         return count
 
     # ----------------------------------------------------- definitions

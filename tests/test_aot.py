@@ -42,7 +42,6 @@ _KEY_ARGS = dict(
     inputs=[("frames", (8, 64, 64, 3), "uint8")],
     params_sig=[((4, 4), "float32")],
     devices=["TFRT_CPU_0"],
-    donate=(),
     backend="cpu",
 )
 
@@ -123,7 +122,6 @@ class TestCacheKey:
         ("inputs", [("frames", (8, 64, 64, 3), "float32")]),
         ("params_sig", [((8, 4), "float32")]),
         ("devices", ["TFRT_CPU_1"]),
-        ("donate", (1,)),
         ("backend", "tpu"),
     ])
     def test_every_field_addresses_a_different_entry(self, field, value):
@@ -249,7 +247,7 @@ class TestFallbackLadder:
         self._populate(monkeypatch, tmp_path)
         aot.reset_cache()
 
-        def bad_load(self, key, engine=""):
+        def bad_load(self, key, devices, engine=""):
             def boom(*args, **kwargs):
                 raise RuntimeError("bound to a device that is gone")
             return boom

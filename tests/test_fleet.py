@@ -416,7 +416,7 @@ class TestFleetAdmission:
 
     def test_rows_without_group_fall_back_to_key(self):
         rows = {
-            "a": {"batches": 10, "items": 100,
+            "a": {"batches": 100, "items": 1000,
                   "stage_ms": {"launch": 10.0}},
         }
         assert self._ctrl(rows).capacity_fps() == pytest.approx(1000.0)

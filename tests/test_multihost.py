@@ -24,8 +24,8 @@ REPO = Path(__file__).resolve().parent.parent
 
 _WORKER = r"""
 import os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from evam_tpu.parallel.mesh import initialize_distributed
 

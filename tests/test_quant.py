@@ -4,6 +4,8 @@ the full fused step running quantized."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,6 +137,14 @@ class TestPallasQGemm:
             dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
         xla_q = quant_conv(x, w, b)
         monkeypatch.setattr(qlinear, "QGEMM_BACKEND", "pallas")
+        # Mosaic does not target the CPU: ask for the interpreter
+        # explicitly (no code path picks it from the backend)
+        from evam_tpu.ops import pallas_qgemm
+
+        monkeypatch.setattr(
+            pallas_qgemm, "pallas_quant_dense",
+            functools.partial(pallas_qgemm.pallas_quant_dense,
+                              interpret=True))
         pallas_q = qlinear.quant_conv(x, w, b)
         assert pallas_q.shape == fp.shape
 

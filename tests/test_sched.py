@@ -270,12 +270,12 @@ class TestAdmission:
         clock: h2d issue/wait + launch + readback residual)."""
         stats = {
             "detect:m": {  # 10ms/batch, occ 0.5 -> 100*0.5*16 = 800
-                "batches": 10, "mean_occupancy": 0.5,
+                "batches": 100, "mean_occupancy": 0.5,
                 "stage_ms": {"h2d_issue": 1.0, "h2d_wait": 1.0,
                              "launch": 6.0, "readback": 2.0},
             },
             "classify:m": {  # 40ms/batch, occ 1.0 -> 25*1.0*16 = 400
-                "batches": 5, "mean_occupancy": 1.0,
+                "batches": 50, "mean_occupancy": 1.0,
                 "stage_ms": {"h2d_issue": 8.0, "h2d_wait": 2.0,
                              "launch": 20.0, "readback": 10.0},
             },
@@ -284,6 +284,26 @@ class TestAdmission:
         }
         ctrl = AdmissionController(_FakeHub(stats), SchedConfig())
         assert ctrl.capacity_fps() == pytest.approx(400.0, rel=0.01)
+
+    def test_a_handful_of_clocked_batches_is_not_a_capacity(self):
+        """The stage clock covers steady-state batches only
+        (``stage_batches``); until a row has clocked enough of them
+        its means are noise — found on the v5e, where the tail of a
+        cold start read as 99-321 fps of capacity and refused streams
+        the chip carries. Such a row says nothing: with no other row
+        the hub is cold and admits."""
+        from evam_tpu.sched.admission import MIN_CLOCKED_BATCHES
+
+        row = {"batches": 500, "items": 4000,
+               "stage_batches": MIN_CLOCKED_BATCHES - 1,
+               "stage_ms": {"h2d_issue": 1.0, "launch": 3.0,
+                            "readback": 1.0}}
+        ctrl = AdmissionController(_FakeHub({"detect:m": row}),
+                                   SchedConfig())
+        assert ctrl.capacity_fps() == 0.0
+        ctrl.admit("standard", 30.0)  # unknown capacity admits
+        row["stage_batches"] = MIN_CLOCKED_BATCHES
+        assert ctrl.capacity_fps() == pytest.approx(1600.0)
 
     def test_snapshot_shape(self):
         ctrl = AdmissionController(_FakeHub(), SchedConfig())

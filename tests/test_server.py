@@ -109,8 +109,11 @@ class TestRoutes:
         so an operator can see WHERE a batch's time goes."""
         from evam_tpu.engine.ringbuf import STAGES
 
+        # enough frames that the bucket serves a second, warm batch:
+        # a cold bucket's launch is its compile and stays out of the
+        # service-time clock (BatchEngine._record_batch)
         body = {
-            "source": {"uri": "synthetic://96x96@30?count=3",
+            "source": {"uri": "synthetic://96x96@30?count=12",
                        "type": "uri"},
             "destination": {"metadata": {"type": "null"}},
         }
@@ -147,6 +150,43 @@ class TestRoutes:
         assert status == 200
         _wait_state(registry, iid)
         assert set(registry.hub.stats()) == before | created
+
+    def test_preload_failure_stops_startup(self, registry, monkeypatch):
+        """A pipeline named in EVAM_PRELOAD that is unknown, fails to
+        build, or fails to compile must RAISE (run_server then never
+        opens the port) — not log a warning and serve without it."""
+        with pytest.raises(KeyError, match="no_such_pipeline"):
+            registry.preload("no_such_pipeline")
+
+        from evam_tpu.server import registry as registry_mod
+
+        def broken_build(*args, **kwargs):
+            raise RuntimeError("model build exploded")
+
+        monkeypatch.setattr(registry_mod, "build_stages", broken_build)
+        with pytest.raises(RuntimeError, match="model build exploded"):
+            registry.preload("object_detection/person")
+        monkeypatch.undo()
+
+        monkeypatch.setattr(
+            registry.hub, "warm_errors",
+            lambda: {"detect:x": "XlaRuntimeError: compile failed"})
+        with pytest.raises(RuntimeError, match="warmup failed"):
+            registry.preload("object_detection/person")
+
+    def test_engines_device_column_names_every_mesh_device(
+            self, registry, eight_devices):
+        """A mesh engine runs on every device of its plan; /engines
+        must say so (one name was `flat[0]` only) — a multi-chip
+        placement is then checkable from the REST surface."""
+        registry.preload("object_detection/person")
+        status, data = _request(registry, "GET", "/engines")
+        assert status == 200
+        rows = [r for k, r in data.items() if k.startswith("detect:")]
+        assert rows
+        for row in rows:
+            assert row["device"].split() == [
+                str(d) for d in eight_devices]
 
 
 class TestInstanceLifecycle:

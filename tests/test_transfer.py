@@ -138,10 +138,9 @@ class TestTransferModes:
 
 class TestSerializeCompileForcesInline:
     def test_devlock_degrades_pipelined_to_inline(self, monkeypatch):
-        """EVAM_SERIALIZE_COMPILE=1 is the wedge-proof mode: device
-        RPCs must never overlap, so a pipelined request degrades to
-        the inline serial path at construction and the devlock gauge
-        pins overlap_max at 1 (the tools/wedge_repro.py /
+        """EVAM_SERIALIZE_COMPILE=1: device RPCs must never overlap,
+        so a pipelined request degrades to the inline serial path at
+        construction and the devlock gauge pins overlap_max at 1 (the
         TestSerializeCompile harness contract)."""
         monkeypatch.setenv("EVAM_SERIALIZE_COMPILE", "1")
         devlock.reset_stats()

@@ -62,10 +62,7 @@ def main() -> int:
     import os
 
     os.environ.setdefault("EVAM_ALLOW_RANDOM_WEIGHTS", "1")
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     import numpy as np
 
