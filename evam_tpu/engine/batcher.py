@@ -93,6 +93,14 @@ from evam_tpu.sched.shedder import Shedder
 
 log = get_logger("engine.batcher")
 
+#: background bucket warmups running in this process, over ALL
+#: engines. A batch dispatched while any of them compiles shares the
+#: host — and, for engines of one chip, the device — with the
+#: compiler, so the steady-state stage clock leaves it out
+#: (EngineStats.stage_seconds).
+_warmups_running = 0
+_warmups_lock = threading.Lock()
+
 
 @dataclasses.dataclass
 class _WorkItem:
@@ -179,12 +187,14 @@ class EngineStats:
     #: readback/resolve from the completion thread. Single writer per
     #: key, so plain dict updates are safe. The clock is the
     #: STEADY-STATE service signal (admission derives capacity from
-    #: it), so it leaves out what only a cold start pays: a cold
-    #: bucket's launch (trace + XLA compile, banked as
-    #: compile_seconds) and every batch dispatched while the
-    #: background warmup still shares the host and the chip with it.
+    #: it), so one rule leaves out what only a start-up pays: a batch
+    #: is ``unclocked`` when its bucket is cold (its launch is trace +
+    #: XLA compile, banked as compile_seconds) or when any engine's
+    #: background warmup is running in this process. The
+    #: evam_engine_stage_seconds histogram and the trace clock keep
+    #: recording EVERY batch — a wedged cold batch must stay visible.
     stage_seconds: dict[str, float] = dataclasses.field(default_factory=dict)
-    #: batches the clock left out (dispatched mid-warmup)
+    #: batches the clock left out
     unclocked: int = 0
 
     @property
@@ -377,13 +387,12 @@ class BatchEngine:
         #: wedged call later completes (slow compile, transient hang).
         self.stalled = threading.Event()
         #: every dispatched-but-not-completed batch: id → (t_dispatch,
-        #: items, bucket, stall_deadline, dispatched mid-warmup).
-        #: Covers the device launch, the _done queue wait, AND the
-        #: readback — a wedge anywhere
-        #: strands nothing. The deadline is FIXED at dispatch time
-        #: (_track_dispatch): a concurrent warmup finishing mid-flight
-        #: must not retroactively shrink an in-flight cold batch's
-        #: compile allowance.
+        #: items, bucket, stall_deadline, unclocked). Covers the
+        #: device launch, the _done queue wait, AND the readback — a
+        #: wedge anywhere strands nothing. The deadline is FIXED at
+        #: dispatch time (_track_dispatch): a concurrent warmup
+        #: finishing mid-flight must not retroactively shrink an
+        #: in-flight cold batch's compile allowance.
         self._outstanding: dict[
             int, tuple[float, list[_WorkItem], int, float, bool]] = {}
         self._next_batch_id = 0
@@ -851,10 +860,13 @@ class BatchEngine:
         """Fire-and-forget bucket precompilation (serving path: kills
         the mid-traffic compile spike when a batch first crosses a
         bucket boundary). Idempotent."""
+        global _warmups_running
         with self._warm_lock:
             if self._warming:
                 return
             self._warming = True
+        with _warmups_lock:
+            _warmups_running += 1
         self.set_example(**example)
         threading.Thread(
             target=self._warm_guarded,
@@ -863,12 +875,15 @@ class BatchEngine:
         ).start()
 
     def _warm_guarded(self) -> None:
+        global _warmups_running
         try:
             self.warmup()
         except Exception as exc:  # noqa: BLE001 — warmup must never kill serving
             self.warm_error = f"{type(exc).__name__}: {exc}"
             log.warning("engine %s warmup failed: %s", self.name, exc)
         finally:
+            with _warmups_lock:
+                _warmups_running -= 1
             self.warmed.set()
 
     def stop(self) -> None:
@@ -904,12 +919,11 @@ class BatchEngine:
                 _safe_set_exception(item.future, exc)
 
     def _track_dispatch(self, t0: float, items: list[_WorkItem],
-                        bucket: int) -> tuple[int, bool, bool]:
+                        bucket: int) -> tuple[int, bool]:
         """Register a dispatched batch with the watchdog; returns its
-        id, whether its bucket is cold (this launch will trace +
-        compile) and whether the background warmup is still running
-        (``EngineStats.stage_seconds`` says what those two exclude).
-        The stall deadline is locked in here. A bucket that has never
+        id and whether the stage clock leaves it out (the one rule on
+        ``EngineStats.stage_seconds``). The stall deadline is locked
+        in here. A bucket that has never
         completed a batch gets stall_timeout_s × first_batch_grace (its
         round-trip legitimately contains trace + compile). Device
         execution is ordered, so a batch enqueued behind others can't
@@ -932,10 +946,10 @@ class BatchEngine:
                                queue_ahead + self.stall_timeout_s)
             bid = self._next_batch_id
             self._next_batch_id += 1
-            warming = self._warming and not self.warmed.is_set()
+            unclocked = cold or _warmups_running > 0
             self._outstanding[bid] = (t0, items, bucket, deadline,
-                                      warming)
-        return bid, cold, warming
+                                      unclocked)
+        return bid, unclocked
 
     def abandon(self) -> None:
         """Quarantine teardown (EngineSupervisor): release every
@@ -1105,7 +1119,7 @@ class BatchEngine:
     def _record_batch(self, n: int, b: int, clock: dict[str, float],
                       items: list[_WorkItem] | None = None,
                       sealed: SealedBatch | None = None,
-                      cold: bool = False, warming: bool = False) -> None:
+                      unclocked: bool = False) -> None:
         spec = self.ragged_spec
         with self._exec_lock:
             self.stats.batches += 1
@@ -1131,12 +1145,10 @@ class BatchEngine:
                 self.stats.units += n
             self.stats.bucket_batches[b] = (
                 self.stats.bucket_batches.get(b, 0) + 1)
-            if warming:
+            if unclocked:
                 self.stats.unclocked += 1
             else:
                 for stage, dt in clock.items():
-                    if cold and stage == "launch":
-                        dt = 0.0
                     self.stats.add_stage(stage, dt)
             mean_occ = self.stats.mean_occupancy
             unit_occ = self.stats.unit_occupancy
@@ -1170,7 +1182,7 @@ class BatchEngine:
         if not self._pipelined:
             self._in_flight.acquire()
             t0 = time.perf_counter()
-            bid, cold, warming = self._track_dispatch(t0, items, b)
+            bid, unclocked = self._track_dispatch(t0, items, b)
             # the pending trace record holds the SAME clock dict _run
             # fills in — a flight dump of a wedged batch reads the
             # stages completed so far (obs/trace.py)
@@ -1192,7 +1204,7 @@ class BatchEngine:
                 return
             self._done.put((out, items, t0, bid, sealed))
             self._record_batch(n, b, clock, items=items, sealed=sealed,
-                               cold=cold, warming=warming)
+                               unclocked=unclocked)
             return
         try:
             with devlock.device_call(f"{self.name}:h2d"):
@@ -1289,7 +1301,7 @@ class BatchEngine:
                 continue
             self._in_flight.acquire()
             t0 = time.perf_counter()
-            bid, cold, warming = self._track_dispatch(t0, items, b)
+            bid, unclocked = self._track_dispatch(t0, items, b)
             # clock by reference — same wedge-visibility contract as
             # the inline path (obs/trace.py)
             trace.batch_begin(self.name, bid, items, b, n, clock,
@@ -1310,7 +1322,7 @@ class BatchEngine:
                 continue
             self._done.put((out, items, t0, bid, sealed))
             self._record_batch(n, b, clock, items=items, sealed=sealed,
-                               cold=cold, warming=warming)
+                               unclocked=unclocked)
 
     def _drain_upload_q(self, exc: Exception) -> None:
         """Fail every uploaded-but-unlaunched batch (stop/abandon/
@@ -1620,7 +1632,7 @@ class BatchEngine:
             with self._exec_lock:
                 slots = list(self._outstanding.values())
             stuck: list[_WorkItem] = []
-            for _t0, items, _b, deadline, _warming in slots:
+            for _t0, items, _b, deadline, _unclocked in slots:
                 if now > deadline:
                     stuck.extend(items)
             if not stuck:

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 import numpy as np
 
@@ -367,6 +368,9 @@ class EngineHub:
             "items": e.stats.items,
             "mean_occupancy": e.stats.mean_occupancy,
             "warmed": e.warmed.is_set(),
+            # why the background warmup failed (traffic then compiles
+            # on demand), else None
+            "warm_error": getattr(e, "warm_error", None),
             "assembly": e.assembly,
             # effective device-transfer mode (EVAM_TRANSFER;
             # devlock may have forced a pipelined request to
@@ -381,6 +385,9 @@ class EngineHub:
             # to shrink
             "ragged": getattr(e, "ragged", "off"),
             "unit_occupancy": round(e.stats.unit_occupancy, 4),
+            # the bucket ladder: a finished warmup has compiled (or
+            # AOT-loaded) one program per rung
+            "buckets": list(e.buckets),
             "bucket_batches": {
                 str(b): c for b, c in sorted(
                     e.stats.bucket_batches.items())},
@@ -561,12 +568,26 @@ class EngineHub:
             "restarts": sum(getattr(e, "restarts", 0) for e in engines),
         }
 
-    def warm_errors(self) -> dict[str, str]:
-        """Engine key → why its background warmup failed (empty when
-        every warmup so far succeeded)."""
-        errors = {key: getattr(e, "warm_error", None)
-                  for key, e, *_ in self._rows()}
-        return {key: err for key, err in errors.items() if err}
+    def wait_warm(self, timeout_s: float) -> None:
+        """Block until every engine's background warmup has ended;
+        raise if one failed, or if ``timeout_s`` (> 0) runs out — a
+        compile that hangs is not under the stall watchdog, and a
+        caller that waits for warm must not wait forever."""
+        t_end = time.monotonic() + timeout_s
+        while True:
+            rows = self.stats()
+            if not self.warmup or all(r["warmed"] for r in rows.values()):
+                break
+            if timeout_s > 0 and time.monotonic() > t_end:
+                cold = sorted(k for k, r in rows.items() if not r["warmed"])
+                raise TimeoutError(
+                    f"engine warmup not finished after {timeout_s:.0f} s: "
+                    f"{cold}")
+            time.sleep(0.2)
+        failed = {k: r["warm_error"] for k, r in rows.items()
+                  if r["warm_error"]}
+        if failed:
+            raise RuntimeError(f"engine warmup failed: {failed}")
 
     def fleet_summary(self) -> dict:
         """The /scheduler fleet operating point (fixed keys — route

@@ -24,9 +24,9 @@ DECLARED fps (request ``fps`` field, default
 ``EVAM_SCHED_DEFAULT_FPS``). A start is rejected when projected
 utilization exceeds the class ceiling — ``EVAM_SCHED_ADMIT_UTIL``
 scaled by CLASS_HEADROOM, so ``batch`` is turned away first and
-``realtime`` last. A cold hub (no declared capacity, and fewer than
-``MIN_CLOCKED_BATCHES`` steady-state batches on the stage clock)
-admits everything: you cannot model what you have not run.
+``realtime`` last. A cold hub (no declared capacity, no steady-state
+batch on the stage clock yet) admits everything: you cannot model
+what you have not run.
 """
 
 from __future__ import annotations
@@ -45,12 +45,6 @@ log = get_logger("sched.admission")
 #: ceiling is hit by batch first, then standard, then realtime — the
 #: admission-side expression of the class ladder.
 CLASS_HEADROOM = {"realtime": 1.0, "standard": 0.85, "batch": 0.6}
-
-#: fewest steady-state batches a stats row must have clocked before
-#: its stage means count as a capacity measurement — the mean of a
-#: handful of batches at the tail of a cold start is noise, and a
-#: capacity read off it refuses streams the chip would carry
-MIN_CLOCKED_BATCHES = 32
 
 #: device-path stages of the per-batch clock (engine/ringbuf.STAGES)
 #: that bound the serial service time of one batch. With the
@@ -206,43 +200,62 @@ class AdmissionController:
         their ``group`` (the shards of one engine key are parallel
         capacity, Σ shards — not independent bottlenecks), and the
         fleet capacity is the min ACROSS groups (a pipeline is still
-        bounded by its slowest engine kind). Single-chip rows are
-        their own group, so EVAM_FLEET=off reproduces the old
-        bottleneck-engine number exactly."""
+        bounded by its slowest engine kind). A live shard without a
+        sample counts as the mean of its group's measured shards (on
+        the four-chip v5e one sampled shard stood for its whole
+        group). Single-chip rows are their own group, so
+        EVAM_FLEET=off reproduces the old bottleneck-engine number
+        exactly."""
         if self.cfg.capacity_fps > 0:
             return self.cfg.capacity_fps
         if not live:
             op = current_op()
             if op is not None and op.capacity_fps > 0:
                 return op.capacity_fps
-        group_caps: dict[str, float] = {}
+        measured: dict[str, list[float]] = {}
+        unmeasured: dict[str, int] = {}
         for key, stats in self.hub.stats().items():
-            batches = stats.get("batches")
-            if not batches:
-                continue
-            if stats.get("stage_batches", batches) < MIN_CLOCKED_BATCHES:
-                continue
-            stage_ms = stats.get("stage_ms") or {}
-            service_ms = sum(stage_ms.get(s, 0.0) for s in _SERVICE_STAGES)
-            if service_ms <= 0:
-                continue
-            # honest occupancy (the ragged-batching satellite): real
-            # items per dispatched batch, straight from the engine
-            # counters. The old mean_occupancy × top-bucket projection
-            # overstated capacity whenever traffic landed in small
-            # buckets (a FULL bucket-4 batch read as occupancy 1.0 of
-            # the 128-slot shape). Stats rows without an item count
-            # (declared/faked hubs) keep the legacy projection.
-            items = stats.get("items")
-            if items:
-                per_batch = items / batches
-            else:
-                occ = max(float(stats.get("mean_occupancy", 0.0)), 1e-3)
-                per_batch = occ * self.hub.max_batch
             group = stats.get("group") or key
-            group_caps[group] = (group_caps.get(group, 0.0)
-                                 + (1e3 / service_ms) * per_batch)
-        return min(group_caps.values()) if group_caps else 0.0
+            cap = self._row_capacity_fps(stats)
+            if cap > 0:
+                measured.setdefault(group, []).append(cap)
+            elif (stats.get("shard") not in (None, "mesh")
+                  and stats.get("state", "running") == "running"):
+                # a live fleet shard the clock has not sampled yet (no
+                # stream hashed to it since warmup): the same program
+                # on the same kind of chip as its measured siblings,
+                # not zero capacity
+                unmeasured[group] = unmeasured.get(group, 0) + 1
+        group_caps = [
+            sum(caps) + unmeasured.get(group, 0) * sum(caps) / len(caps)
+            for group, caps in measured.items()
+        ]
+        return min(group_caps) if group_caps else 0.0
+
+    def _row_capacity_fps(self, stats: dict) -> float:
+        """One stats row's items per device-path second; 0 = the
+        steady-state stage clock has no sample of it."""
+        batches = stats.get("batches")
+        if not batches:
+            return 0.0
+        stage_ms = stats.get("stage_ms") or {}
+        service_ms = sum(stage_ms.get(s, 0.0) for s in _SERVICE_STAGES)
+        if service_ms <= 0:
+            return 0.0
+        # honest occupancy (the ragged-batching satellite): real
+        # items per dispatched batch, straight from the engine
+        # counters. The old mean_occupancy × top-bucket projection
+        # overstated capacity whenever traffic landed in small
+        # buckets (a FULL bucket-4 batch read as occupancy 1.0 of
+        # the 128-slot shape). Stats rows without an item count
+        # (declared/faked hubs) keep the legacy projection.
+        items = stats.get("items")
+        if items:
+            per_batch = items / batches
+        else:
+            occ = max(float(stats.get("mean_occupancy", 0.0)), 1e-3)
+            per_batch = occ * self.hub.max_batch
+        return (1e3 / service_ms) * per_batch
 
     def utilization(self) -> float:
         cap = self.capacity_fps()

@@ -13,7 +13,6 @@ from __future__ import annotations
 import json
 import os
 import threading
-import time
 from pathlib import Path
 from typing import Any
 
@@ -174,14 +173,15 @@ class PipelineRegistry:
     # ------------------------------------------------------- preload
 
     def preload(self, names: str) -> int:
-        """Serve-time engine preload (round-1 VERDICT item 7): build
+        """Serve-time engine preload: build
         and warm the engines for the named pipelines BEFORE the REST
         port opens, so the first POST never pays model build + XLA
         compile in the hot path. ``names``: comma list of
         ``name/version`` (or bare ``name`` = all versions), or ``all``.
 
         Raises when a name matches no pipeline, a pipeline fails to
-        build, or (with ``tpu.warmup``) a bucket fails to compile — a
+        build, or (with ``tpu.warmup``) a bucket fails to compile or
+        the warmup outlasts ``hub.wait_warm``'s deadline — a
         deployment that asked for a pipeline to be ready must not open
         the port without it.
 
@@ -211,11 +211,11 @@ class PipelineRegistry:
             )
             count += 1
             log.info("preloaded %s", label)
-        while self.hub.readiness()["warming"]:
-            time.sleep(0.2)
-        failed = self.hub.warm_errors()
-        if failed:
-            raise RuntimeError(f"preload: engine warmup failed: {failed}")
+        # the whole ladder gets what the watchdog grants ONE cold
+        # bucket; a compile that hangs must fail start-up, not hold
+        # the port shut forever
+        self.hub.wait_warm(
+            self.hub.stall_timeout_s * self.hub.first_batch_grace)
         return count
 
     # ----------------------------------------------------- definitions

@@ -96,7 +96,8 @@ def test_bench_serialize_compile_serve_emits_contract_line():
     # EVAM_FLEET is off (this run: mode=off, zero shards) or sharded
     # (evam_tpu/fleet/, hub.fleet_summary())
     assert {"mode", "shards", "degraded_shards", "rebalances",
-            "streams"} <= set(data["fleet"])
+            "streams", "max_shards", "scale_ups",
+            "scale_downs"} == set(data["fleet"])
     assert data["fleet"]["mode"] == "off"
     assert data["fleet"]["shards"] == 0
 

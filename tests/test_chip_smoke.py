@@ -31,6 +31,7 @@ _spec.loader.exec_module(chip_smoke)
 def _row(**over) -> dict:
     row = {
         "batches": 10, "stage_batches": 10, "compiled_programs": 8,
+        "buckets": [1, 2, 4, 8, 16, 32, 64, 128], "warm_error": None,
         "compile_s": 60.0, "restarts": 0, "state": "running",
         "bucket_batches": {"1": 2, "8": 8}, "shard": None,
         "device": "TPU_0(process=0,(0,0,0,0))", "group": "detect:m",
@@ -89,6 +90,12 @@ def test_clean_warm_wave_passes(warm_wave):
         bucket_batches={"1": 12, "8": 8}), "bucket larger than 1"),
     (lambda r, b, a: a["engines"]["detect:m"]["stage_ms"].update(
         readback=1.0), "['readback']"),
+    (lambda r, b, a: a["engines"]["detect:m"].update(
+        warm_error="XlaRuntimeError: RESOURCE_EXHAUSTED"),
+     "warmup failed: XlaRuntimeError"),
+    (lambda r, b, a: [e["detect:m"].update(compiled_programs=7)
+                      for e in (b["engines"], a["engines"])],
+     "holds 7 programs for the 8-bucket ladder"),
 ])
 def test_each_broken_contract_fails_the_warm_wave(warm_wave, mutate,
                                                   needle):
@@ -103,16 +110,22 @@ def test_first_wave_tolerates_a_shed_frame_but_not_a_lost_stream(
         warm_wave):
     """A process's first traffic may shed a stale frame (compile
     stalls, first-use costs); it may not lose a stream or restart an
-    engine — and a restarted server's first wave may not compile."""
+    engine — and a restarted server's first wave may not compile, nor
+    shed more than one stall's worth of frames in flight."""
     records, before, after = copy.deepcopy(warm_wave)
     after.update(shed=3, frame_errors=3)
     records[0]["lines"] = 1
-    assert chip_smoke.check_first_wave(records, after, "w") == []
+    assert chip_smoke.check_first_wave(records, before, after, "w") == []
+    assert chip_smoke.check_first_wave(
+        records, before, after, "w", shed_cap=4) == []
+    bad = chip_smoke.check_first_wave(
+        records, before, after, "w", shed_cap=2)
+    assert any("shed 3 frames, more than the 2" in b for b in bad), bad
     assert chip_smoke.check_no_compile(before, after, "w") == []
     records[0]["state"] = "ABORTED"
     after["engines"]["detect:m"]["restarts"] = 2
     after["engines"]["detect:m"]["compiled_programs"] = 9
-    bad = chip_smoke.check_first_wave(records, after, "w")
+    bad = chip_smoke.check_first_wave(records, before, after, "w")
     assert any("ended ABORTED" in b for b in bad), bad
     assert any("restarts=2" in b for b in bad), bad
     assert chip_smoke.check_no_compile(before, after, "w")
@@ -236,7 +249,8 @@ def test_cpu_rehearsal_runs_every_phase(tmp_path):
                     "device": {"platform": "cpu", "kind": "cpu",
                                "count": 1}}
     assert '"ok"' not in r.stdout  # never the chip result line
-    for phase in ("wave1", "wave2", "wave3", "cold server: SIGTERM",
+    for phase in ("wave1", "wave2", "wave3 (warm, admitted by the live",
+                  "wave4 (warm restart)", "cold server: SIGTERM",
                   "warm server: SIGTERM", "wire-encode: native",
                   "compile cache:"):
         assert any(phase in l for l in lines), phase

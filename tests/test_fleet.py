@@ -407,6 +407,29 @@ class TestFleetAdmission:
         # detect group: Σ shards = 2000 fps; classify: 1500 → min
         assert ctrl.capacity_fps() == pytest.approx(1500.0)
 
+    def test_unsampled_live_shard_counts_as_its_siblings_mean(self):
+        """A live shard no stream has hashed to since warmup has no
+        clock sample; it runs the same program on the same kind of
+        chip as its siblings, so it is their mean — not zero. On the
+        four-chip v5e one sampled shard stood for its whole group
+        (467 fps read for four chips)."""
+        idle = dict(self._row("detect:m", 0), batches=0, stage_ms={},
+                    shard="s2", state="running")
+        rows = {
+            "detect:m@s0": dict(self._row("detect:m", 1000), shard="s0"),
+            "detect:m@s1": dict(self._row("detect:m", 600), shard="s1"),
+            "detect:m@s2": idle,
+        }
+        assert self._ctrl(rows).capacity_fps() == pytest.approx(2400.0)
+        # a shard that is not serving, the mesh twin and a plain
+        # engine with no sample add nothing
+        idle["state"] = "restarting"
+        assert self._ctrl(rows).capacity_fps() == pytest.approx(1600.0)
+        idle.update(state="running", shard="mesh")
+        assert self._ctrl(rows).capacity_fps() == pytest.approx(1600.0)
+        idle["shard"] = None
+        assert self._ctrl(rows).capacity_fps() == pytest.approx(1600.0)
+
     def test_single_chip_rows_unchanged(self):
         rows = {
             "detect:m": self._row("detect:m", 1000),

@@ -285,24 +285,22 @@ class TestAdmission:
         ctrl = AdmissionController(_FakeHub(stats), SchedConfig())
         assert ctrl.capacity_fps() == pytest.approx(400.0, rel=0.01)
 
-    def test_a_handful_of_clocked_batches_is_not_a_capacity(self):
-        """The stage clock covers steady-state batches only
-        (``stage_batches``); until a row has clocked enough of them
-        its means are noise — found on the v5e, where the tail of a
-        cold start read as 99-321 fps of capacity and refused streams
-        the chip carries. Such a row says nothing: with no other row
-        the hub is cold and admits."""
-        from evam_tpu.sched.admission import MIN_CLOCKED_BATCHES
-
-        row = {"batches": 500, "items": 4000,
-               "stage_batches": MIN_CLOCKED_BATCHES - 1,
-               "stage_ms": {"h2d_issue": 1.0, "launch": 3.0,
-                            "readback": 1.0}}
+    def test_a_row_the_clock_has_not_sampled_says_nothing(self):
+        """The stage clock covers steady-state batches only (the
+        engine leaves out cold buckets and everything dispatched
+        while a warmup compiles), so a row can have served batches
+        and still carry no ``stage_ms``. Such a row is no
+        measurement: with no other row the hub is cold and admits —
+        on the v5e a cold start read as 99-321 fps and refused
+        streams the chip carries."""
+        row = {"batches": 500, "items": 4000, "stage_batches": 0,
+               "stage_ms": {}}
         ctrl = AdmissionController(_FakeHub({"detect:m": row}),
                                    SchedConfig())
         assert ctrl.capacity_fps() == 0.0
         ctrl.admit("standard", 30.0)  # unknown capacity admits
-        row["stage_batches"] = MIN_CLOCKED_BATCHES
+        row.update(stage_batches=1, stage_ms={
+            "h2d_issue": 1.0, "launch": 3.0, "readback": 1.0})
         assert ctrl.capacity_fps() == pytest.approx(1600.0)
 
     def test_snapshot_shape(self):

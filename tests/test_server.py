@@ -4,6 +4,7 @@ sources and small models on the CPU mesh."""
 
 import asyncio
 import json
+import threading
 import time
 from pathlib import Path
 
@@ -23,17 +24,28 @@ SMALL["audio_detection/environment"] = (1, 1600)
 NARROW = {k: 8 for k in ZOO_SPECS}
 
 
-@pytest.fixture(scope="module")
-def registry(eight_devices, tmp_path_factory):
+def _registry(state_dir, **hub_kw):
     settings = Settings(
-        pipelines_dir=str(REPO / "pipelines"),
-        state_dir=str(tmp_path_factory.mktemp("state")),
-    )
+        pipelines_dir=str(REPO / "pipelines"), state_dir=str(state_dir))
     model_registry = ModelRegistry(dtype="float32", input_overrides=SMALL,
                                    width_overrides=NARROW)
     hub = EngineHub(model_registry, plan=build_mesh(), max_batch=16,
-                    deadline_ms=4.0)
-    reg = PipelineRegistry(settings, hub=hub)
+                    deadline_ms=4.0, **hub_kw)
+    return PipelineRegistry(settings, hub=hub)
+
+
+@pytest.fixture(scope="module")
+def registry(eight_devices, tmp_path_factory):
+    reg = _registry(tmp_path_factory.mktemp("state"))
+    yield reg
+    reg.stop_all()
+
+
+@pytest.fixture
+def warming_registry(eight_devices, tmp_path):
+    """A fresh hub that warms its engines in the background, as
+    ``serve`` does (the shared one above compiles on first batch)."""
+    reg = _registry(tmp_path, warmup=True)
     yield reg
     reg.stop_all()
 
@@ -166,13 +178,40 @@ class TestRoutes:
         monkeypatch.setattr(registry_mod, "build_stages", broken_build)
         with pytest.raises(RuntimeError, match="model build exploded"):
             registry.preload("object_detection/person")
-        monkeypatch.undo()
 
+    def test_preload_of_a_failing_warmup_stops_startup(
+            self, warming_registry, monkeypatch):
+        from evam_tpu.engine.batcher import BatchEngine
+
+        def broken_warmup(self):
+            raise RuntimeError("bucket compile exploded")
+
+        monkeypatch.setattr(BatchEngine, "warmup", broken_warmup)
+        with pytest.raises(RuntimeError, match="bucket compile exploded"):
+            warming_registry.preload("object_detection/person")
+        # the reason is on the REST surface too, not only in the log
+        status, rows = _request(warming_registry, "GET", "/engines")
+        assert status == 200
+        assert any("bucket compile exploded" in (r["warm_error"] or "")
+                   for r in rows.values())
+
+    def test_preload_of_a_hanging_warmup_times_out(
+            self, warming_registry, monkeypatch):
+        """Warmup compiles are not under the stall watchdog; a compile
+        that hangs must fail start-up at a deadline instead of holding
+        the port shut forever (and `bench.py --config serve` with
+        it)."""
+        from evam_tpu.engine.batcher import BatchEngine
+
+        release = threading.Event()
+        monkeypatch.setattr(warming_registry.hub, "stall_timeout_s", 0.05)
         monkeypatch.setattr(
-            registry.hub, "warm_errors",
-            lambda: {"detect:x": "XlaRuntimeError: compile failed"})
-        with pytest.raises(RuntimeError, match="warmup failed"):
-            registry.preload("object_detection/person")
+            BatchEngine, "warmup", lambda self: release.wait(60))
+        try:
+            with pytest.raises(TimeoutError, match="not finished"):
+                warming_registry.preload("object_detection/person")
+        finally:
+            release.set()
 
     def test_engines_device_column_names_every_mesh_device(
             self, registry, eight_devices):

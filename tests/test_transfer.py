@@ -96,6 +96,9 @@ class TestTransferModes:
         for mode in ("pipelined", "inline"):
             eng = _engine(f"xfer-clock-{mode}", transfer=mode)
             try:
+                # the clock leaves cold buckets out: compile them first
+                eng.set_example(x=_rows(1)[0])
+                eng.warmup()
                 futs = [eng.submit(x=r) for r in _rows(20, seed=4)]
                 for f in futs:
                     f.result(timeout=30)

@@ -90,6 +90,7 @@ def _drive(eng, rows: list[np.ndarray], items: int,
     rather than fixed time — keeps the two modes' windows exactly
     comparable on a noisy shared-vCPU host."""
     base_batches = eng.stats.batches
+    base_clocked = eng.stats.clocked
     base_stages = dict(eng.stats.stage_seconds)
     quota = [items // feeders + (1 if k < items % feeders else 0)
              for k in range(feeders)]
@@ -113,9 +114,10 @@ def _drive(eng, rows: list[np.ndarray], items: int,
     elapsed = time.perf_counter() - t0
 
     batches = eng.stats.batches - base_batches
+    clocked = eng.stats.clocked - base_clocked
     stage_ms = {
         s: round(1e3 * (eng.stats.stage_seconds.get(s, 0.0)
-                        - base_stages.get(s, 0.0)) / max(batches, 1), 3)
+                        - base_stages.get(s, 0.0)) / max(clocked, 1), 3)
         for s in ("h2d_issue", "h2d_wait", "launch", "readback")
     }
     return {
