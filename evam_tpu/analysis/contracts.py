@@ -41,7 +41,7 @@ CHECKPOINT = "evam_tpu/state/checkpoint.py"
 
 #: metrics.<method> → positional index of the labels argument
 _METRIC_METHODS = {
-    "inc": 2, "set": 2, "observe": 2, "time": 1,
+    "inc": 2, "set": 2, "observe": 2, "time": 1, "declare": 1,
     "get_counter": 1, "get_gauge": 1, "quantile": 2, "counter_total": None,
     "quantiles_by_label": None, "quantiles_grouped": None,
 }

@@ -309,7 +309,7 @@ def run_eii_service(settings: Settings) -> int:
     """Blocking entrypoint for ``evam-tpu serve --mode EII``."""
     import signal
 
-    from evam_tpu.obs.trace import init_observability
+    from evam_tpu.obs.trace import init_observability, stop_freeze_recorder
 
     init_observability(settings)
     manager = EiiManager(settings)
@@ -324,5 +324,8 @@ def run_eii_service(settings: Settings) -> int:
 
     signal.signal(signal.SIGTERM, _on_term)
     log.info("EII service running")
-    manager.run_forever()
+    try:
+        manager.run_forever()
+    finally:
+        stop_freeze_recorder()
     return 0

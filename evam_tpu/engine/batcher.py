@@ -51,6 +51,16 @@ histogram, so the serve bench and /healthz can attribute host
 overhead instead of hiding it inside a throughput number (VERDICT r5
 weak #5) — and, post-transfer-pipeline, attribute transfer cost vs
 the dispatch floor honestly (h2d_wait and readback are residuals).
+The clock (obs/trace.py ``StageClock``) also keeps each stage's start
+and the batch's waits BETWEEN the stages, named by what the batch
+waits for (``wait_launcher``: uploaded, until the launcher takes it;
+``wait_slot``: an in-flight slot; ``wait_completer``: launched, until
+the completer takes it): a batch record on ``/traces`` is a timeline.
+Each of the three threads marks its own stretches on a
+``ThreadSpans`` (``evam.dispatch.*``, ``evam.launch.*``,
+``evam.complete.*`` in a profiler capture; a wait is named by what
+the THREAD waits for) and their seconds land in
+``evam_engine_thread_seconds``.
 
 ``EVAM_BATCH_ASSEMBLY=legacy`` keeps the old allocate-stack-pad
 dispatch path for A/B (tools/bench_hostpath.py measures the delta).
@@ -525,6 +535,12 @@ class BatchEngine:
         self.warm_error: str | None = None
         self._in_flight = threading.Semaphore(max_in_flight)
         self._stop = threading.Event()
+        #: each worker thread's own stretches (obs/trace.py); inert
+        #: with EVAM_TRACE=off
+        self._sp_dispatch = trace.thread_spans(name, "dispatch", cpu=True)
+        self._sp_launch = trace.thread_spans(name, "launch")
+        self._sp_complete = trace.thread_spans(name, "complete", cpu=True)
+        trace.watch_engine(self)
         if self._classq is not None:
             dispatch_loop = self._dispatch_loop_sched
         elif self._ring is not None:
@@ -669,6 +685,13 @@ class BatchEngine:
         if isinstance(head, _WorkItem):
             return max(0.0, now - head.t_submit)
         return 0.0
+
+    def thread_states(self) -> dict[str, tuple]:
+        """Each worker thread's current stretch and its age in seconds
+        (the freeze recorder's dumps, obs/trace.py)."""
+        return {"dispatch": self._sp_dispatch.where(),
+                "launch": self._sp_launch.where(),
+                "complete": self._sp_complete.where()}
 
     def class_depths(self) -> dict[str, int]:
         """Per-class queued depth ({} when scheduling is off)."""
@@ -1072,7 +1095,7 @@ class BatchEngine:
         return (exe if exe is not None else jit_fn), prm, sharding
 
     def _run(self, batch: dict[str, np.ndarray],
-             clock: dict[str, float] | None = None):
+             clock: trace.StageClock | None = None):
         """Inline transfer path (EVAM_TRANSFER=inline, warmup, and the
         devlock-forced mode): H2D + launch back-to-back on the calling
         thread — the pre-pipeline behavior, byte-identical. h2d_wait
@@ -1090,6 +1113,8 @@ class BatchEngine:
         # device RPC — the wedge-proof measurement mode
         with devlock.device_call(f"{self.name}:launch"):
             t0 = time.perf_counter()
+            if clock is not None:
+                self._sp_dispatch.to("h2d_issue", t0)
             jit_fn, prm, sharding = self._exec_for(
                 batch[self.input_names[0]].shape[0])
             arrays = []
@@ -1099,11 +1124,13 @@ class BatchEngine:
                     a = jax.device_put(a, sharding)
                 arrays.append(a)
             t1 = time.perf_counter()
+            if clock is not None:
+                self._sp_dispatch.to("launch", t1)
             out = jit_fn(prm, *arrays)
             if clock is not None:
-                clock["h2d_issue"] = t1 - t0
+                clock.mark("h2d_issue", t0, t1 - t0)
                 clock["h2d_wait"] = 0.0
-                clock["launch"] = time.perf_counter() - t1
+                clock.mark("launch", t1, time.perf_counter() - t1)
             return out
 
     def refresh_queue_gauges(self) -> None:
@@ -1116,7 +1143,7 @@ class BatchEngine:
         metrics.set("evam_engine_queue_age_s", self.queue_age_s(),
                     {"engine": self.name})
 
-    def _record_batch(self, n: int, b: int, clock: dict[str, float],
+    def _record_batch(self, n: int, b: int, clock: trace.StageClock,
                       items: list[_WorkItem] | None = None,
                       sealed: SealedBatch | None = None,
                       unclocked: bool = False) -> None:
@@ -1169,7 +1196,7 @@ class BatchEngine:
 
     def _dispatch_batch(self, batch: dict[str, np.ndarray],
                         items: list[_WorkItem], n: int, b: int,
-                        clock: dict[str, float],
+                        clock: trace.StageClock,
                         sealed: SealedBatch | None) -> None:
         """Common tail of all three dispatch loops: hand one assembled
         batch to the device path.
@@ -1179,9 +1206,14 @@ class BatchEngine:
         returns once the transfer is in flight) and queue the batch
         for the launcher thread, so the dispatcher is sealing and
         uploading batch N+1 while batch N's launch is being issued."""
+        sp = self._sp_dispatch
         if not self._pipelined:
-            self._in_flight.acquire()
+            if not self._in_flight.acquire(blocking=False):
+                sp.to("wait_slot")
+                self._in_flight.acquire()
             t0 = time.perf_counter()
+            sp.to("bookkeep", t0)
+            clock.wait("wait_slot", t0)
             bid, unclocked = self._track_dispatch(t0, items, b)
             # the pending trace record holds the SAME clock dict _run
             # fills in — a flight dump of a wedged batch reads the
@@ -1202,13 +1234,15 @@ class BatchEngine:
                     self._ring.release(sealed)
                 log.exception("engine %s step failed", self.name)
                 return
-            self._done.put((out, items, t0, bid, sealed))
+            sp.to("bookkeep")
+            self._done.put((out, items, t0, bid, sealed, clock))
             self._record_batch(n, b, clock, items=items, sealed=sealed,
                                unclocked=unclocked)
             return
         try:
             with devlock.device_call(f"{self.name}:h2d"):
                 t0 = time.perf_counter()
+                sp.to("h2d_issue", t0)
                 _, _, sharding = self._exec_for(b)
                 if sharding is not None:
                     # sharded placement is semantics, not an
@@ -1224,7 +1258,8 @@ class BatchEngine:
                     # an explicit device_put here would be a second
                     # copy with no DMA to overlap
                     dev = [batch[name] for name in self._step_inputs]
-                clock["h2d_issue"] = time.perf_counter() - t0
+                t_up = time.perf_counter()
+                clock.mark("h2d_issue", t0, t_up - t0)
         except Exception as exc:  # noqa: BLE001 — surface to every caller
             for it in items:
                 _safe_set_exception(it.future, exc)
@@ -1233,6 +1268,8 @@ class BatchEngine:
             log.exception("engine %s H2D upload failed", self.name)
             return
         entry = (dev, items, n, b, clock, sealed)
+        if self._upload_q.full():
+            sp.to("wait_launcher", t_up)
         while True:
             try:
                 self._upload_q.put(entry, timeout=0.1)
@@ -1247,7 +1284,7 @@ class BatchEngine:
                         self._ring.release(sealed)
                     return
 
-    def _launch(self, dev: list, clock: dict[str, float], b: int = 0):
+    def _launch(self, dev: list, clock: trace.StageClock, b: int = 0):
         """Launcher half of the pipelined transfer: wait out the head
         batch's H2D residual where that is measurable without
         re-serializing (``_h2d_sync`` — h2d_wait is ≈0 when the upload
@@ -1260,16 +1297,20 @@ class BatchEngine:
         inj = active_faults()
         if inj is not None:
             inj.maybe_wedge(self.name)
+        sp = self._sp_launch
         with devlock.device_call(f"{self.name}:launch"):
             t0 = time.perf_counter()
+            sp.to("h2d_wait", t0)
             if self._device_streams:
                 jax.block_until_ready(dev)
             t1 = time.perf_counter()
+            sp.to("launch", t1)
             jit_fn, prm, _ = self._exec_for(b)
             out = jit_fn(prm, *dev)
             t2 = time.perf_counter()
-            clock["h2d_wait"] = t1 - t0
-            clock["launch"] = t2 - t1
+            sp.to("bookkeep", t2)
+            clock.mark("h2d_wait", t0, t1 - t0)
+            clock.mark("launch", t1, t2 - t1)
             if self._device_streams:
                 # async D2H: the device→host copy rides along while
                 # later batches launch; np.asarray in the completer
@@ -1282,7 +1323,12 @@ class BatchEngine:
         """Pipelined transfer: pop uploaded batches and launch them —
         while this thread is inside a launch (or blocked on a wedged
         backend RPC), the dispatcher keeps sealing and uploading."""
+        sp = self._sp_launch
         while True:
+            # seconds kept, no annotation: while the launcher idles for
+            # want of an upload, what gates the device is whatever the
+            # dispatcher is doing or waiting for, and that is annotated
+            sp.to("wait_upload", annotate=False)
             try:
                 entry = self._upload_q.get(timeout=0.1)
             except queue.Empty:
@@ -1299,8 +1345,19 @@ class BatchEngine:
                 if sealed is not None:
                     self._ring.release(sealed)
                 continue
-            self._in_flight.acquire()
-            t0 = time.perf_counter()
+            if self._in_flight.acquire(blocking=False):
+                t0 = time.perf_counter()
+                clock.wait("wait_launcher", t0)
+            else:
+                # every in-flight slot is taken: from here the batch
+                # waits for a readback, not for this thread
+                t_w = sp.to("wait_slot")
+                if t_w is not None:
+                    clock.wait("wait_launcher", t_w)
+                self._in_flight.acquire()
+                t0 = time.perf_counter()
+                clock.wait("wait_slot", t0)
+            sp.to("bookkeep", t0)
             bid, unclocked = self._track_dispatch(t0, items, b)
             # clock by reference — same wedge-visibility contract as
             # the inline path (obs/trace.py)
@@ -1320,9 +1377,10 @@ class BatchEngine:
                     self._ring.release(sealed)
                 log.exception("engine %s step failed", self.name)
                 continue
-            self._done.put((out, items, t0, bid, sealed))
+            self._done.put((out, items, t0, bid, sealed, clock))
             self._record_batch(n, b, clock, items=items, sealed=sealed,
                                unclocked=unclocked)
+        sp.to(None)
 
     def _drain_upload_q(self, exc: Exception) -> None:
         """Fail every uploaded-but-unlaunched batch (stop/abandon/
@@ -1354,7 +1412,11 @@ class BatchEngine:
         they waste a device slot."""
         cq = self._classq
         shedder = self._shedder
+        sp = self._sp_dispatch
         while True:
+            # from here until a batch is formed the dispatcher waits
+            # for items (the pick, then the class deadline's fill)
+            sp.to("wait_items")
             if self._stop.is_set():
                 exc = RuntimeError("engine stopped")
                 for it in cq.drain():
@@ -1385,6 +1447,7 @@ class BatchEngine:
             if not items:
                 continue
             self._launch_sched(items)
+        sp.to(None)
 
     def _launch_sched(self, items: list[_WorkItem]) -> None:
         """Assemble + launch one class-ordered batch: through the
@@ -1400,13 +1463,12 @@ class BatchEngine:
             staged = [(it.inputs, it) for it in items]
             dispatched = 0
             while staged:
-                clock: dict[str, float] = {
-                    "submit_wait":
-                        time.perf_counter() - staged[0][1].t_submit,
-                }
+                clock = trace.StageClock()
+                clock["submit_wait"] = (
+                    time.perf_counter() - staged[0][1].t_submit)
                 try:
                     sealed, staged = self._ring.stage_direct(
-                        staged, bucket_fn, clock)
+                        staged, bucket_fn, clock, self._sp_dispatch)
                 except RuntimeError:
                     exc = RuntimeError(f"engine {self.name} is stopped")
                     for _, it in staged:
@@ -1422,12 +1484,12 @@ class BatchEngine:
                 self._count_oversize_split(dispatched - 1)
             return
         for chunk in self._split_oversize(items):
-            clock = {
-                "submit_wait": time.perf_counter() - chunk[0].t_submit,
-            }
+            clock = trace.StageClock()
+            clock["submit_wait"] = time.perf_counter() - chunk[0].t_submit
             n = len(chunk)
             b = self._bucket(n)
             t_asm = time.perf_counter()
+            self._sp_dispatch.to("slot_write", t_asm)
             batch = {}
             for name in self.input_names:
                 rows = [it.inputs[name] for it in chunk]
@@ -1437,7 +1499,7 @@ class BatchEngine:
                                    stacked.dtype)
                     stacked = np.concatenate([stacked, pad])
                 batch[name] = stacked
-            clock["slot_write"] = time.perf_counter() - t_asm
+            clock.mark("slot_write", t_asm, time.perf_counter() - t_asm)
             self._dispatch_batch(batch, chunk, n, b, clock, None)
 
     # ------------------------------------------------- slot dispatch
@@ -1446,11 +1508,13 @@ class BatchEngine:
         """Seal staged slots at the batch deadline and launch them —
         no stack, no pad concat, no per-batch allocation."""
         bucket_fn = self._bucket_ragged if self._packed else self._bucket
+        sp = self._sp_dispatch
         while True:
+            sp.to("wait_items")
             op = current_op()
             deadline = (self.deadline_s * op.deadline_scale
                         if op is not None else self.deadline_s)
-            sealed = self._ring.next_batch(deadline, bucket_fn)
+            sealed = self._ring.next_batch(deadline, bucket_fn, sp)
             if sealed is None:
                 if self._stop.is_set():
                     break
@@ -1464,6 +1528,7 @@ class BatchEngine:
 
             self._dispatch_batch(sealed.arrays, sealed.items, sealed.n,
                                  sealed.bucket, sealed.clock, sealed)
+        sp.to(None)
 
     # ----------------------------------------------- legacy dispatch
 
@@ -1471,7 +1536,9 @@ class BatchEngine:
         """Pre-ring path (EVAM_BATCH_ASSEMBLY=legacy): per-batch
         stack + zero-pad concat on the dispatcher thread. Kept for
         A/B measurement — tools/bench_hostpath.py."""
+        sp = self._sp_dispatch
         while not self._stop.is_set():
+            sp.to("wait_items")
             try:
                 first = self._queue.get(timeout=0.1)
             except queue.Empty:
@@ -1503,11 +1570,11 @@ class BatchEngine:
             for chunk in self._split_oversize(items):
                 n = len(chunk)
                 b = self._bucket(n)
-                clock: dict[str, float] = {
-                    "submit_wait":
-                        time.perf_counter() - chunk[0].t_submit,
-                }
+                clock = trace.StageClock()
+                clock["submit_wait"] = (
+                    time.perf_counter() - chunk[0].t_submit)
                 t_asm = time.perf_counter()
+                sp.to("slot_write", t_asm)
                 batch: dict[str, np.ndarray] = {}
                 for name in self.input_names:
                     rows = [it.inputs[name] for it in chunk]
@@ -1517,19 +1584,28 @@ class BatchEngine:
                                        stacked.dtype)
                         stacked = np.concatenate([stacked, pad])
                     batch[name] = stacked
-                clock["slot_write"] = time.perf_counter() - t_asm
+                clock.mark("slot_write", t_asm,
+                           time.perf_counter() - t_asm)
 
                 self._dispatch_batch(batch, chunk, n, b, clock, None)
+        sp.to(None)
 
     # ------------------------------------------------------ completion
 
     def _completion_loop(self) -> None:
+        sp = self._sp_complete
         while True:
+            sp.to("wait_launch", annotate=False)  # as the launcher's
             entry = self._done.get()
             if entry is None:
+                sp.to(None)
                 break
-            out, items, t0, bid, sealed = entry
+            out, items, t0, bid, sealed, clock = entry
             t_rb = time.perf_counter()
+            # np.asarray returns when the device has finished the step
+            # and the copy to the host has landed
+            sp.to("wait_device", t_rb)
+            clock.wait("wait_completer", t_rb)
             try:
                 with devlock.device_call(f"{self.name}:readback"):
                     # single readback per batch; with the pipelined
@@ -1547,6 +1623,7 @@ class BatchEngine:
                     self._ring.release(sealed)
                 continue
             finally:
+                sp.to("resolve")
                 with self._exec_lock:
                     done = self._outstanding.pop(bid, None)
             self._in_flight.release()
@@ -1579,6 +1656,7 @@ class BatchEngine:
             metrics.observe("evam_step_seconds", now - t0, {"engine": self.name})
             readback_s = now - t_rb
             t_res = time.perf_counter()
+            clock.span("readback", t_rb, t_res - t_rb)
             # ragged scatter-back: a packed batch's output rows are
             # unit rows — item i owns host[offset[i] : offset[i] +
             # row_len[i]] (exactly its real region rows, zero-region
@@ -1589,6 +1667,10 @@ class BatchEngine:
                 metrics.observe(
                     "evam_item_latency_seconds", now - it.t_submit, {"engine": self.name}
                 )
+                if it.trace is not None:
+                    # the runner's collect wait starts here
+                    # (stages/runner.py pump)
+                    it.future.t_resolved = t_res
                 if ragged:
                     off = int(sealed.row_offset[i])
                     _safe_set_result(
@@ -1597,12 +1679,10 @@ class BatchEngine:
                 else:
                     _safe_set_result(it.future, host[i])
             resolve_s = time.perf_counter() - t_res
-            # retire the batch trace record (appends queue-wait +
-            # dispatch spans to every member frame's tree and banks
-            # the completion-side stages the clock never sees)
-            trace.batch_complete(self.name, bid, items,
-                                 readback_s=readback_s,
-                                 resolve_s=resolve_s)
+            clock.span("resolve", t_res, resolve_s)
+            # retire the batch trace record: every member frame's
+            # tree gets its queue wait and the batch's timeline
+            trace.batch_complete(self.name, bid, items)
             if done is not None and not done[4]:
                 with self._exec_lock:
                     self.stats.add_stage("readback", readback_s)

@@ -62,6 +62,7 @@ from typing import Any
 import numpy as np
 
 from evam_tpu.engine.ragged import RaggedSpec
+from evam_tpu.obs.trace import StageClock
 
 #: stage names of the per-batch host clock, in pipeline order.
 #: submit_wait covers slot backpressure AND the deadline-batching
@@ -74,6 +75,9 @@ from evam_tpu.engine.ragged import RaggedSpec
 #: by definition on the inline path, where the launch itself absorbs
 #: it), and readback the device→host residual the completer still
 #: has to block on after the async D2H copy was put in flight.
+#: Each batch's ``StageClock`` (obs/trace.py) keeps these durations
+#: and, beside them, every stage's start: its ``spans`` are the
+#: batch's timeline.
 STAGES = (
     "submit_wait", "slot_write", "seal",
     "h2d_issue", "h2d_wait", "launch", "readback", "resolve",
@@ -133,7 +137,7 @@ class SealedBatch:
 
     def __init__(self, slot: _Slot, arrays: dict[str, np.ndarray],
                  items: list, n: int, bucket: int,
-                 clock: dict[str, float],
+                 clock: StageClock,
                  row_len: np.ndarray | None = None,
                  row_offset: np.ndarray | None = None,
                  units: int = 0, unit_rows: int = 0):
@@ -260,14 +264,18 @@ class SlotRing:
 
     # --------------------------------------------------- dispatcher side
 
-    def next_batch(self, deadline_s: float, bucket_fn) -> SealedBatch | None:
+    def next_batch(self, deadline_s: float, bucket_fn,
+                   spans=None) -> SealedBatch | None:
         """Wait for rows, honor the batch-fill deadline (measured from
         the open slot's FIRST write), then seal: close the slot, wait
         out in-flight row writers, zero the dirty pad tail, and return
         contiguous ``[:bucket]`` views. On a ragged ring ``bucket_fn``
         is called with ``(n, units)`` and the packed block/seg tail is
         masked too. Returns None once the ring is closed and
-        drained."""
+        drained. ``spans`` is the dispatcher's ``ThreadSpans``: the
+        seal is marked on it. ``slot_write`` stays a duration here: the
+        rows were copied by the submitting threads while the batch
+        formed, before its timeline starts."""
         with self._cv:
             while True:
                 if self._full:
@@ -317,12 +325,14 @@ class SlotRing:
                 write_sum = slot.write_sum
                 break
         t0 = time.perf_counter()
+        if spans is not None:
+            spans.to("seal", t0)
         sealed = self._seal(slot, items, n, bucket_fn)
         sealed.clock.update({
             "submit_wait": submit_wait,
             "slot_write": write_sum,
         })
-        sealed.clock["seal"] = time.perf_counter() - t0
+        sealed.clock.mark("seal", t0, time.perf_counter() - t0)
         return sealed
 
     def _seal(self, slot: _Slot, items: list, n: int,
@@ -355,7 +365,7 @@ class SlotRing:
             row_len = slot.row_len[:n].copy()
             row_offset = np.zeros(n, np.int32)
             np.cumsum(row_len[:-1], out=row_offset[1:])
-            return SealedBatch(slot, views, items, n, bucket, {},
+            return SealedBatch(slot, views, items, n, bucket, StageClock(),
                                row_len=row_len, row_offset=row_offset,
                                units=units, unit_rows=u)
         bucket = bucket_fn(n)
@@ -364,7 +374,7 @@ class SlotRing:
             if dirty > n:
                 arr[n:dirty] = 0
         views = {k: a[:bucket] for k, a in slot.arrays.items()}
-        return SealedBatch(slot, views, items, n, bucket, {})
+        return SealedBatch(slot, views, items, n, bucket, StageClock())
 
     # ------------------------------------------------------- completion
 
@@ -453,7 +463,7 @@ class SlotRing:
     # ------------------------------------------- dispatcher-side staging
 
     def stage_direct(self, staged: list[tuple[dict, Any]], bucket_fn,
-                     clock: dict[str, float],
+                     clock: StageClock, spans=None,
                      ) -> tuple[SealedBatch | None, list]:
         """Stage a dispatcher-assembled batch into a free slot (the
         sched path: items arrive from per-class queues, so the row
@@ -470,18 +480,24 @@ class SlotRing:
         (the oversize-split contract). Blocks while every slot is in
         flight (the same host-side backpressure as the submit path);
         raises RuntimeError once the ring is closed; the sealed batch
-        is None when no row survived."""
+        is None when no row survived. ``spans`` is the dispatcher's
+        ``ThreadSpans``: the wait for a free slot, the row copies and
+        the seal are marked on it."""
         first = {k: np.asarray(v) for k, v in staged[0][0].items()}
         spec = self.ragged
         with self._cv:
             if self._shapes is None:
                 self._allocate(first)
+            if spans is not None and not self._free and not self._closed:
+                spans.to("wait_staging")
             while not self._free and not self._closed:
                 self._cv.wait(0.1)
             if self._closed:
                 raise RuntimeError("staging ring is closed")
             slot = self._free.popleft()
         t0 = time.perf_counter()
+        if spans is not None:
+            spans.to("slot_write", t0)
         ok_items: list = []
         remaining: list = []
         row = 0
@@ -518,7 +534,8 @@ class SlotRing:
                     slot.arrays[name][row] = a
             ok_items.append(item)
             row += 1
-        clock["slot_write"] = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        clock.mark("slot_write", t0, t1 - t0)
         if not ok_items:
             with self._cv:
                 slot.count = 0
@@ -529,12 +546,13 @@ class SlotRing:
                 self._free.append(slot)
                 self._cv.notify_all()
             return None, remaining
-        t1 = time.perf_counter()
+        if spans is not None:
+            spans.to("seal", t1)
         slot.count = row
         slot.unit_count = off
         sealed = self._seal(slot, ok_items, row, bucket_fn)
-        sealed.clock.update(clock)
-        sealed.clock["seal"] = time.perf_counter() - t1
+        clock.mark("seal", t1, time.perf_counter() - t1)
+        sealed.clock = clock
         return sealed, remaining
 
     # -------------------------------------------------------- internals

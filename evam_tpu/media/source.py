@@ -36,6 +36,10 @@ class FrameEvent:
     #: host decode cost in seconds (set by DecodeWorker) — becomes the
     #: frame trace's "decode" span (obs/trace.py)
     decode_s: float | None = None
+    #: when a paced (``realtime``) source's own clock says the frame is
+    #: due (``perf_counter``); the runner's feed minus this is
+    #: evam_source_lag_seconds
+    due_t: float | None = None
 
 
 class VideoSource(Protocol):
@@ -85,7 +89,8 @@ class FileSource:
                     self._cap = self._open()
                     continue
                 break
-            yield FrameEvent(frame=frame, pts_ns=seq * frame_ns, seq=seq)
+            yield FrameEvent(frame=frame, pts_ns=seq * frame_ns, seq=seq,
+                             due_t=t_wall if self.realtime else None)
             seq += 1
             if self.realtime:
                 t_wall += 1.0 / fps
@@ -153,7 +158,8 @@ class SyntheticSource:
             x = (self.seed * 37 + seq * 7) % max(1, self.width - sq)
             y = (self.seed * 53 + seq * 5) % max(1, self.height - sq)
             frame[y : y + sq, x : x + sq] = (64, 160, 240)
-            yield FrameEvent(frame=frame, pts_ns=seq * frame_ns, seq=seq)
+            yield FrameEvent(frame=frame, pts_ns=seq * frame_ns, seq=seq,
+                             due_t=t_wall if self.realtime else None)
             seq += 1
             if self.realtime:
                 t_wall += 1.0 / self.fps

@@ -84,6 +84,21 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_trace_retained": ("counter", ("reason",)),
     "evam_trace_dropped": ("counter", ()),
     "evam_flight_dumps": ("counter", ("engine",)),
+    # the freeze recorder (obs/trace.py): how late the 250 ms heartbeat
+    # woke, when that was >= 100 ms, and every garbage collection's
+    # pause by generation
+    "evam_freeze_seconds": ("histogram", ()),
+    "evam_gc_pause_seconds": ("histogram", ("gen",)),
+    # waits between the layers: a result resolved by the engine until
+    # the stream's runner takes it, and a paced source's frame from its
+    # due time until it is fed
+    "evam_collect_wait_seconds": ("histogram", ()),
+    "evam_source_lag_seconds": ("histogram", ()),
+    # wall seconds of the dispatch/launch/complete threads by state
+    # ("work", or the wait named by what it waits for) and CPU seconds
+    # of the dispatcher's and completer's work stretches
+    "evam_engine_thread_seconds": ("counter", ("engine", "thread", "state")),
+    "evam_engine_thread_cpu_seconds": ("counter", ("engine", "thread")),
     # self-tuning control plane (evam_tpu/control/): controller ticks,
     # applied retune actions per knob, and the current operating-point
     # setpoint per knob (the same values /scheduler reports)
@@ -96,6 +111,9 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
 def _label_str(labels: dict[str, str] | None) -> str:
     if not labels:
         return ""
+    if len(labels) == 1:  # most call sites: skip the sort and the join
+        for k, v in labels.items():
+            return f'{{{k}="{v}"}}'
     inner = ",".join(f'{k}="{v}"' for k, v in sorted(labels.items()))
     return "{" + inner + "}"
 
@@ -166,6 +184,13 @@ class MetricsRegistry:
                 self._hists[key] = _Histogram()
             self._hists[key].observe(value, exemplar)
 
+    def declare(self, name: str, labels: dict[str, str] | None = None) -> None:
+        """Make a histogram's series exist (count 0) before its first
+        observation, so a reader of deltas can tell "nothing happened"
+        from "this build has no such series"."""
+        with self._lock:
+            self._hists.setdefault((name, _label_str(labels)), _Histogram())
+
     def time(self, name: str, labels: dict[str, str] | None = None):
         """Context manager observing elapsed seconds into a histogram."""
         registry = self
@@ -201,16 +226,6 @@ class MetricsRegistry:
         with self._lock:
             hist = self._hists.get((name, _label_str(labels)))
             return hist.quantile(q) if hist else 0.0
-
-    def exemplar(self, name: str, labels: dict[str, str] | None = None
-                 ) -> tuple[float, str] | None:
-        """Slowest recorded (value, exemplar) pair of one histogram —
-        the trace id render() attaches to its p99 line."""
-        with self._lock:
-            hist = self._hists.get((name, _label_str(labels)))
-            if hist is None or not hist.exemplars:
-                return None
-            return max(hist.exemplars)
 
     def quantiles_by_label(self, name: str, q: float) -> dict[str, float]:
         """All labeled series of one histogram → {label_str: quantile}

@@ -1,47 +1,58 @@
-"""Per-frame distributed tracing, stage timers + profiler hooks.
+"""What the serving path records about itself, and who reads it.
 
-The reference exposes only GST_DEBUG levels and a pass-through
-PROFILING_MODE env (eii/docker-compose.yml:43,59). Here, three layers:
+Everything here is on when ``EVAM_TRACE`` is on (the default) and a
+no-op when it is ``off``: ``active()`` memoizes to None, FrameContext
+.trace stays None, no thread is started and no hook takes a stamp.
 
-1. **Stage histograms** (PR 1): every stage execution lands in a
-   labeled latency histogram (visible at /metrics as p50/p90/p99), and
-   PROFILING_MODE=true starts the jax.profiler server so
-   `tensorboard --logdir` / `jax.profiler.trace` can capture device
-   timelines from a running service.
+* **Stage histograms** (always on): ``stage_timer`` lands a stage
+  execution in ``evam_stage_seconds{stage}``; ``observe_frame_latency``
+  is feed to chain complete, with the slowest frame's trace id as an
+  OpenMetrics exemplar on the p99 line.
+* **Frame span trees**: ``start_frame`` mints a ``FrameTrace`` at feed
+  (stages/runner.py) that rides FrameContext into every engine submit.
+  Spans are ``(name, t0, dur_s, attrs)`` with ``t0`` a ``perf_counter``
+  stamp: decode, ``stage.<name>[.submit|.complete]``, ``wire``,
+  ``sched.queue_wait``, the member batch's ``engine.<stage>`` spans at
+  their real starts, ``engine.dispatch`` and ``runner.collect_wait``.
+  ``TraceRing`` keeps error, shed and slow frames always and healthy
+  ones 1 in N.
+* **Batch timelines**: the engine's per-batch ``StageClock`` holds the
+  stage durations (what ``EngineStats`` and the capacity model sum) and,
+  as ``spans``, every stage and every named wait between two stages
+  with its start, in the order they happened. A pending batch's record
+  holds the SAME clock object the dispatch path fills in, so a flight
+  dump of a wedged batch shows the last stage it completed.
+* **Thread stretches**: ``ThreadSpans`` is one engine thread's state
+  machine (dispatch, launch, complete). Each stretch is a
+  ``jax.profiler.TraceAnnotation`` named ``evam.<thread>.<what>`` (a
+  wait is named by what it waits for), so a profiler capture shows the
+  host on the device's clock, and its seconds land in
+  ``evam_engine_thread_seconds{engine,thread,state}``.
+* **The freeze recorder**: ``FreezeRecorder`` is one daemon heartbeat.
+  Every 250 ms it measures how late it woke; ``gc.callbacks`` stamp
+  every collection. A wake >= 100 ms late lands in
+  ``evam_freeze_seconds``; >= 1 s writes a ``freeze`` flight dump with
+  a verdict (process stopped, GIL held by the collector, GIL held in a
+  call), the collections, the engines' queue ages and every thread's
+  stack. A beat that woke on time while an engine's oldest item is a
+  second old writes a ``stall`` dump instead: the interpreter runs, an
+  engine thread sits in a call.
+* **Readout**: ``GET /traces`` (``traces_payload``: ring counters, one
+  ``clock`` pair mapping ``perf_counter`` onto the wall clock, Chrome
+  trace events; tools/trace_dump.py renders a capture) and
+  ``flight_dump`` (JSONL of the last N records plus the caller's state;
+  the engine supervisor writes one per quarantine, the freeze recorder
+  one per freeze), rotated oldest-first.
 
-2. **Per-frame span trees** (this PR): a trace id is minted at ingest
-   (``start_frame``, stages/runner.py) and threaded through
-   FrameContext into every engine submit, so one frame's causal path —
-   decode → gate decide → sched queue wait → engine dispatch
-   (slot_write/seal/h2d_issue/h2d_wait/launch/readback/resolve) →
-   publish — is reconstructable. Batch spans are *linked* to their N
-   member frame spans via batch id, with the owning engine/device
-   recorded (fleet shards name their chip). Spans land in a bounded
-   in-process ``TraceRing`` with **tail-based sampling**: error / shed
-   frames and the slowest tail are always retained, everything else
-   1-in-N. ``GET /traces`` serves the ring as Chrome trace-event JSON
-   (tools/trace_dump.py renders/validates a capture), and
-   ``observe_frame_latency`` attaches OpenMetrics exemplars linking
-   the p99 latency quantile to a concrete trace id.
-
-3. **Flight recorder**: ``flight_dump`` writes the last-N retained
-   spans plus live engine/queue state to a JSONL artifact; the engine
-   supervisor calls it on every quarantine and on the terminal
-   ``degraded`` transition. Pending (in-flight) batch records hold a
-   reference to the SAME clock dict the dispatch path fills in
-   stage-by-stage, so a wedged batch's record shows its last completed
-   stage — the post-mortem a hung device call needs.
-
-``EVAM_TRACE=off`` disables layer 2/3 entirely: ``active()`` memoizes
-to None, FrameContext.trace stays None, and every hook is a cheap
-no-op — byte-identical A/B, same discipline as EVAM_TRANSFER /
-EVAM_GATE (tools/bench_trace.py gates overhead + off-identity in CI).
-Sampling config is memoized through config/settings.py — no env reads
-on any hot path (the evamlint knobs pass enforces this).
+Sampling and flight settings are memoized through config/settings.py:
+no environment read on any hot path.
 """
 
 from __future__ import annotations
 
+import contextlib
+import faulthandler
+import gc
 import itertools
 import json
 import os
@@ -50,6 +61,7 @@ import tempfile
 import threading
 import time
 import uuid
+import weakref
 from collections import deque
 from pathlib import Path
 
@@ -101,6 +113,159 @@ def observe_frame_latency(stream_id: str, seconds: float,
                         {"class": priority}, exemplar=trace_id)
 
 
+class StageClock(dict):
+    """One batch's host clock: ``stage -> seconds`` (the dict itself:
+    what ``EngineStats`` sums and ``evam_engine_stage_seconds``
+    observes) plus ``spans``, the batch's timeline: every stage and
+    every named wait between two stages as ``(name, t0, dur_s)`` with
+    ``t0`` a ``perf_counter`` stamp, appended in the order they
+    happened. ``submit_wait`` is a duration only: it lies before the
+    batch, and each member frame's ``sched.queue_wait`` span shows it.
+    The completer appends to ``spans`` alone, never to the dict, which
+    the launcher may be iterating."""
+
+    __slots__ = ("spans",)
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.spans: list[tuple[str, float, float]] = []
+
+    def mark(self, stage: str, t0: float, dur: float) -> None:
+        self[stage] = dur
+        self.spans.append((stage, t0, dur))
+
+    def span(self, name: str, t0: float, dur: float) -> None:
+        self.spans.append((name, t0, dur))
+
+    def wait(self, name: str, until: float) -> None:
+        """A named wait from the end of the last span to ``until``."""
+        if self.spans:
+            _, t0, dur = self.spans[-1]
+            self.spans.append((name, t0 + dur, until - t0 - dur))
+
+
+_annotation = None
+_profiling = None
+
+
+def _profiler_hooks():
+    """``jax.profiler.TraceAnnotation`` and its ``is_enabled``, imported
+    on first use (obs never imports jax at module load)."""
+    global _annotation, _profiling
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation, _profiling = TraceAnnotation, TraceAnnotation.is_enabled
+    return _annotation, _profiling
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+class ThreadSpans:
+    """One engine thread's stretches. ``to(state)`` ends the stretch
+    the thread was in and begins ``state``: while a profiler capture
+    runs, an annotation ``evam.<thread>.<state>`` on the calling thread
+    (no capture, no object); always, the ended stretch's wall seconds
+    under ``work`` or, for a state named ``wait_*``, under that name.
+    With ``cpu`` the thread's CPU seconds (``time.thread_time``) are
+    kept too: a wait burns none, so they are the work stretches', and
+    wall far above CPU inside work is time spent waiting for the GIL.
+    The sums reach the registry about every ``FLUSH_S``, on the owning
+    thread: a stretch costs one stamp and one dict update, no lock."""
+
+    __slots__ = ("_prefix", "_labels", "_ann", "_key", "_state", "_t",
+                 "_acc", "_flushed", "_cpu0")
+
+    FLUSH_S = 0.25
+
+    def __init__(self, engine: str, thread: str, cpu: bool = False) -> None:
+        _profiler_hooks()
+        self._prefix = f"evam.{thread}."
+        self._labels = {"engine": engine, "thread": thread}
+        self._ann = None
+        #: the stretch the thread is in (None: in none) and the key its
+        #: seconds go under
+        self._state: str | None = None
+        self._key: str | None = None
+        self._t = self._flushed = time.perf_counter()
+        self._acc: dict[str, float] = {}
+        #: thread CPU seconds at the last flush (None: not kept); the
+        #: first flush, on the owning thread, only sets it
+        self._cpu0: float | None = -1.0 if cpu else None
+
+    def to(self, state: str | None, now: float | None = None,
+           annotate: bool = True) -> float:
+        """``now`` is the caller's own stamp of this moment where it
+        has one already (the stamp used is returned); ``annotate=False``
+        keeps the seconds and leaves the profiler's timeline without
+        the stretch."""
+        if now is None:
+            now = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        key = self._key
+        if key is not None:
+            self._acc[key] = self._acc.get(key, 0.0) + now - self._t
+        self._key = (state if state is None or state[:5] == "wait_"
+                     else "work")
+        self._state = state
+        self._t = now
+        if annotate and state is not None and _profiling():
+            self._ann = _annotation(self._prefix + state)
+        if now - self._flushed >= self.FLUSH_S or state is None:
+            self._flush(now)
+        return now
+
+    def where(self) -> tuple[str | None, float]:
+        """The stretch the thread is in and for how long (read from
+        another thread: a moment's view, good enough for a dump)."""
+        return self._state, round(time.perf_counter() - self._t, 4)
+
+    def _flush(self, now: float) -> None:
+        self._flushed = now
+        for state, sec in self._acc.items():
+            metrics.inc("evam_engine_thread_seconds", sec,
+                        {**self._labels, "state": state})
+        self._acc.clear()
+        if self._cpu0 is not None:
+            cpu = time.thread_time()
+            if self._cpu0 >= 0.0:
+                metrics.inc("evam_engine_thread_cpu_seconds",
+                            cpu - self._cpu0, self._labels)
+            self._cpu0 = cpu
+
+
+class _NoSpans:
+    """``ThreadSpans`` with tracing off: nothing stamped or kept."""
+
+    __slots__ = ()
+
+    def to(self, state, now=None, annotate=True) -> None:
+        pass
+
+    def where(self) -> tuple[None, float]:
+        return None, 0.0
+
+
+_NO_SPANS = _NoSpans()
+
+
+def thread_spans(engine: str, thread: str,
+                 cpu: bool = False) -> "ThreadSpans | _NoSpans":
+    return (ThreadSpans(engine, thread, cpu) if active() is not None
+            else _NO_SPANS)
+
+
+def annotate(name: str):
+    """A profiler annotation for one stretch on the calling thread
+    (``with annotate("evam.runner.wire"): ...``) while a capture runs,
+    else nothing; callers hold a frame trace, so tracing is on."""
+    annotation, profiling = _profiler_hooks()
+    return annotation(name) if profiling() else _NO_ANNOTATION
+
+
 class FrameTrace:
     """One frame's span tree, mutated lock-free by its owning threads.
 
@@ -110,15 +275,19 @@ class FrameTrace:
     needed on the hot path."""
 
     __slots__ = ("trace_id", "stream_id", "seq", "priority", "t0",
-                 "status", "spans", "bids")
+                 "due_t", "status", "spans", "bids")
 
     def __init__(self, trace_id: str, stream_id: str, seq: int,
-                 priority: str, t0: float) -> None:
+                 priority: str, t0: float,
+                 due_t: float | None = None) -> None:
         self.trace_id = trace_id
         self.stream_id = stream_id
         self.seq = seq
         self.priority = priority
+        #: the feed (ingest) stamp, and the paced source's own due time
+        #: for the frame where it has one; both ``perf_counter``
         self.t0 = t0
+        self.due_t = due_t
         self.status = "open"
         self.spans: list[tuple] = []
         self.bids: list[str] = []
@@ -134,6 +303,7 @@ class FrameTrace:
             "seq": self.seq,
             "class": self.priority,
             "t0": self.t0,
+            "due_t": self.due_t,
             "status": self.status,
             "bids": list(self.bids),
             "spans": [
@@ -188,10 +358,12 @@ class TraceRing:
 
     # -- frame lifecycle ------------------------------------------------
 
-    def mint(self, stream_id: str, seq: int, priority: str) -> FrameTrace:
+    def mint(self, stream_id: str, seq: int, priority: str,
+             t0: float | None = None,
+             due_t: float | None = None) -> FrameTrace:
         trace_id = f"{_TRACE_PREFIX}-{next(_trace_seq)}"
         return FrameTrace(trace_id, stream_id, seq, priority,
-                          time.perf_counter())
+                          time.perf_counter() if t0 is None else t0, due_t)
 
     def finish(self, ft: FrameTrace, status: str) -> None:
         """Tail-based retention decision: error/shed/deadline-miss
@@ -225,21 +397,24 @@ class TraceRing:
     # -- batch lifecycle ------------------------------------------------
 
     def batch_begin(self, engine: str, bid: int, items, bucket: int,
-                    n: int, clock: dict, device: str = "") -> None:
+                    n: int, clock: StageClock, device: str = "") -> None:
         """Register an in-flight batch. ``items`` are duck-typed work
         items carrying an optional ``.trace`` attribute; ``clock`` is
-        stored BY REFERENCE — the dispatch path keeps mutating it
-        stage-by-stage, so a flight dump of a still-pending batch
-        reads the stages completed so far."""
+        stored BY REFERENCE — the dispatch path keeps appending to its
+        ``spans``, so a flight dump of a still-pending batch reads the
+        stages completed so far. The batch begins where its first span
+        does: when the dispatcher took its items."""
         frames = []
         for it in items:
             ft = getattr(it, "trace", None)
             if ft is not None:
                 frames.append(ft.trace_id)
                 ft.bids.append(f"{engine}#{bid}")
+        spans = getattr(clock, "spans", None)
         rec = {
             "engine": engine, "bid": bid, "bucket": bucket, "n": n,
-            "device": device, "t0": time.perf_counter(),
+            "device": device,
+            "t0": spans[0][1] if spans else time.perf_counter(),
             "wall_t": time.time(), "frames": frames, "clock": clock,
             "status": "in_flight", "dur_s": None,
         }
@@ -249,29 +424,24 @@ class TraceRing:
                 self._pending.pop(next(iter(self._pending)))
 
     def batch_complete(self, engine: str, bid: int, items=(),
-                       status: str = "ok",
-                       readback_s: float | None = None,
-                       resolve_s: float | None = None) -> None:
-        """Retire an in-flight batch record and append per-frame
-        queue-wait + dispatch spans to every member trace."""
+                       status: str = "ok") -> None:
+        """Retire an in-flight batch record and give every member
+        frame its queue wait, the batch's spans at their real starts
+        and the dispatch as a whole."""
         now = time.perf_counter()
         with self._lock:
             rec = self._pending.pop((engine, bid), None)
         t0 = None
+        member: list[tuple] = []
         if rec is not None:
             t0 = rec["t0"]
-            # The clock is quiescent once the batch reaches
-            # completion; snapshot it (plus the completion-side
-            # stages, which the engine never writes into the clock).
-            stages = _clock_stages(rec["clock"])
-            if readback_s is not None:
-                stages["readback"] = readback_s
-            if resolve_s is not None:
-                stages["resolve"] = resolve_s
-            rec["stages"] = stages
+            # the clock is quiescent once the batch reaches completion
+            rec["spans"] = _clock_spans(rec["clock"])
             rec["clock"] = None
             rec["status"] = status
             rec["dur_s"] = now - t0
+            member = [(f"engine.{name}", s0, dur, None)
+                      for (name, s0, dur) in rec["spans"]]
             with self._lock:
                 self._batches.append(rec)
         for it in items:
@@ -282,6 +452,7 @@ class TraceRing:
             if t0 is not None and t_sub is not None:
                 ft.add_span("sched.queue_wait", t_sub, t0 - t_sub,
                             {"class": getattr(it, "priority", "")})
+            ft.spans.extend(member)
             start = t0 if t0 is not None else now
             ft.add_span("engine.dispatch", start, now - start,
                         {"engine": engine, "bid": bid, "status": status})
@@ -296,22 +467,21 @@ class TraceRing:
                     [dict(rec) for rec in self._pending.values()])
 
 
-def _clock_stages(clock: dict | None) -> dict:
-    """Stage snapshot of a (possibly still-mutating) clock dict:
-    iterates STAGE_ORDER, never the dict itself, so a concurrent
-    writer can't break the copy."""
-    if not clock:
-        return {}
-    return {s: clock[s] for s in STAGE_ORDER if s in clock}
+def _clock_spans(clock) -> tuple:
+    """A (possibly still growing) clock's timeline as it stands."""
+    return tuple(getattr(clock, "spans", ()))
 
 
-def last_stage(stages: dict | None) -> str | None:
-    """The last completed engine stage of a batch record — a wedged
-    batch's record stops exactly where the device stopped answering."""
+def last_stage(spans) -> str | None:
+    """The last engine stage a batch completed — a wedged batch's
+    record stops exactly where the device stopped answering. ``spans``
+    is a timeline (``[name, t0, dur]`` rows) or any iterable of stage
+    names; named waits between stages do not count."""
     found = None
-    for s in STAGE_ORDER:
-        if stages and s in stages:
-            found = s
+    for sp in spans or ():
+        name = sp if isinstance(sp, str) else sp[0]
+        if name in STAGE_ORDER:
+            found = name
     return found
 
 
@@ -348,12 +518,13 @@ def reset_cache() -> None:
 
 # -- hot-path hooks (all no-ops when tracing is off) --------------------
 
-def start_frame(stream_id: str, seq: int,
-                priority: str = "standard") -> FrameTrace | None:
+def start_frame(stream_id: str, seq: int, priority: str = "standard",
+                t0: float | None = None,
+                due_t: float | None = None) -> FrameTrace | None:
     ring = active()
     if ring is None:
         return None
-    return ring.mint(stream_id, seq, priority)
+    return ring.mint(stream_id, seq, priority, t0, due_t)
 
 
 def finish_frame(ft: FrameTrace | None, status: str = "ok") -> None:
@@ -366,21 +537,19 @@ def finish_frame(ft: FrameTrace | None, status: str = "ok") -> None:
 
 
 def batch_begin(engine: str, bid: int, items, bucket: int, n: int,
-                clock: dict, device: str = "") -> None:
+                clock: StageClock, device: str = "") -> None:
     ring = active()
     if ring is None:
         return
     ring.batch_begin(engine, bid, items, bucket, n, clock, device)
 
 
-def batch_complete(engine: str, bid: int, items=(), status: str = "ok",
-                   readback_s: float | None = None,
-                   resolve_s: float | None = None) -> None:
+def batch_complete(engine: str, bid: int, items=(),
+                   status: str = "ok") -> None:
     ring = active()
     if ring is None:
         return
-    ring.batch_complete(engine, bid, items, status=status,
-                        readback_s=readback_s, resolve_s=resolve_s)
+    ring.batch_complete(engine, bid, items, status=status)
 
 
 # -- Chrome trace-event rendering (GET /traces, tools/trace_dump.py) ----
@@ -388,10 +557,12 @@ def batch_complete(engine: str, bid: int, items=(), status: str = "ok",
 def chrome_trace_events(frames: list | None = None,
                         batches: list | None = None) -> list[dict]:
     """Chrome trace-event ("X" complete events, microsecond ts/dur)
-    view of the ring. Frame spans land one track per stream; each
-    batch emits one span carrying ``args.frames`` — the trace ids of
-    its member frames (the batch↔frame link) — plus per-stage child
-    slices laid out sequentially from dispatch."""
+    view of the ring. Frame spans land one track per stream, each with
+    the frame's ``ingest_t`` and ``due_t`` in its args; each batch
+    emits one span carrying ``args.frames`` — the trace ids of its
+    member frames (the batch↔frame link) — plus one child slice per
+    stage (``batch-stage``) and per named wait (``batch-wait``) at its
+    real start."""
     if frames is None and batches is None:
         ring = active()
         if ring is None:
@@ -402,7 +573,8 @@ def chrome_trace_events(frames: list | None = None,
     for ft in frames or ():
         for (name, t0, dur, attrs) in ft.spans:
             args = {"trace_id": ft.trace_id, "seq": ft.seq,
-                    "class": ft.priority, "status": ft.status}
+                    "class": ft.priority, "status": ft.status,
+                    "ingest_t": ft.t0, "due_t": ft.due_t}
             if attrs:
                 args.update(attrs)
             events.append({
@@ -411,44 +583,51 @@ def chrome_trace_events(frames: list | None = None,
                 "pid": "frames", "tid": ft.stream_id, "args": args,
             })
     for rec in batches or ():
-        stages = rec.get("stages")
-        if stages is None:
-            stages = _clock_stages(rec.get("clock"))
-        total = rec.get("dur_s")
-        if total is None:
-            total = sum(stages.values())
+        events.extend(batch_events(rec))
+    return events
+
+
+def batch_events(rec: dict) -> list[dict]:
+    """One batch record (of the ring or of a flight dump) as its span
+    and its child slices."""
+    spans = rec.get("spans")
+    if spans is None:
+        spans = _clock_spans(rec.get("clock"))
+    total = rec.get("dur_s")
+    if total is None:  # pending: up to the end of its last span
+        total = (spans[-1][1] + spans[-1][2] - rec["t0"]) if spans else 0.0
+    pid = f"engine {rec['engine']}"
+    events = [{
+        "name": f"batch {rec['engine']}#{rec['bid']}", "ph": "X",
+        "cat": "batch", "ts": round(rec["t0"] * 1e6, 1),
+        "dur": round(total * 1e6, 1),
+        "pid": pid, "tid": rec.get("device", ""),
+        "args": {
+            "bid": rec["bid"], "frames": list(rec.get("frames", ())),
+            "bucket": rec.get("bucket"), "n": rec.get("n"),
+            "device": rec.get("device", ""),
+            "status": rec.get("status", ""),
+            "stages": [sp[0] for sp in spans],
+            "last_stage": last_stage(spans),
+        },
+    }]
+    for (name, t0, dur) in spans:
         events.append({
-            "name": f"batch {rec['engine']}#{rec['bid']}", "ph": "X",
-            "cat": "batch", "ts": round(rec["t0"] * 1e6, 1),
-            "dur": round(total * 1e6, 1),
-            "pid": f"engine {rec['engine']}", "tid": rec.get("device", ""),
-            "args": {
-                "bid": rec["bid"], "frames": list(rec.get("frames", ())),
-                "bucket": rec.get("bucket"), "n": rec.get("n"),
-                "device": rec.get("device", ""),
-                "status": rec.get("status", ""),
-                "stages": stages, "last_stage": last_stage(stages),
-            },
+            "name": name, "ph": "X",
+            "cat": "batch-stage" if name in STAGE_ORDER else "batch-wait",
+            "ts": round(t0 * 1e6, 1), "dur": round(dur * 1e6, 1),
+            "pid": pid, "tid": f"{rec.get('device', '')}/stages",
+            "args": {"bid": rec["bid"]},
         })
-        t = rec["t0"]
-        for s in STAGE_ORDER:
-            if s not in stages:
-                continue
-            events.append({
-                "name": s, "ph": "X", "cat": "batch-stage",
-                "ts": round(t * 1e6, 1),
-                "dur": round(stages[s] * 1e6, 1),
-                "pid": f"engine {rec['engine']}",
-                "tid": f"{rec.get('device', '')}/stages",
-                "args": {"bid": rec["bid"]},
-            })
-            t += stages[s]
     return events
 
 
 def traces_payload() -> dict:
-    """The GET /traces response body: ring counters + Chrome trace
-    events (fixed key set so the route goldens stay canonical)."""
+    """The GET /traces response body: ring counters, one ``clock``
+    pair taken together (every ``ts`` is ``perf_counter`` microseconds;
+    the pair maps them onto the wall clock a client or a profiler
+    capture uses) and the Chrome trace events (fixed key set so the
+    route goldens stay canonical)."""
     ring = active()
     if ring is None:
         return {"enabled": False, "retained": 0, "dropped": 0,
@@ -462,26 +641,32 @@ def traces_payload() -> dict:
         "frames": len(frames),
         "batches": len(done),
         "pending": len(pending),
+        "clock": {"perf_counter": time.perf_counter(),
+                  "time_ns": time.time_ns()},
         "traceEvents": chrome_trace_events(frames, done + pending),
     }
 
 
 # -- flight recorder ----------------------------------------------------
 
+def _default_flight_dir() -> str:
+    return os.path.join(tempfile.gettempdir(), "evam_flight")
+
+
 def flight_dump(engine: str, reason: str,
                 state: dict | None = None) -> str | None:
     """Dump the ring's last-N frame/batch records plus caller-supplied
     engine/queue state to a JSONL artifact (the supervisor calls this
-    on quarantine and on the degraded transition). Pending batch
-    records read their live clock dict, so a wedged batch's row
-    carries ``last_stage`` — where the device stopped answering.
+    on quarantine and on the degraded transition, the freeze recorder
+    after a freeze). Pending batch records read their live clock, so a
+    wedged batch's row carries ``last_stage`` — where the device
+    stopped answering.
     Returns the artifact path, or None when tracing is off or the
     write fails (a chaos drill must never take the supervisor down)."""
     ring = active()
     if ring is None:
         return None
-    out_dir = ring.flight_dir or os.path.join(tempfile.gettempdir(),
-                                              "evam_flight")
+    out_dir = ring.flight_dir or _default_flight_dir()
     name = re.sub(r"[^A-Za-z0-9._-]+", "_", engine) or "engine"
     frames, done, pending = ring.snapshot()
     try:
@@ -497,14 +682,12 @@ def flight_dump(engine: str, reason: str,
                 "state": state or {},
             }) + "\n")
             for rec in (done + pending)[-ring.flight_n:]:
-                stages = rec.get("stages")
-                if stages is None:
-                    stages = _clock_stages(rec.get("clock"))
                 row = {k: v for k, v in rec.items() if k != "clock"}
                 row["type"] = "batch"
                 row["pending"] = rec.get("status") == "in_flight"
-                row["stages"] = stages
-                row["last_stage"] = last_stage(stages)
+                if row.get("spans") is None:
+                    row["spans"] = _clock_spans(rec.get("clock"))
+                row["last_stage"] = last_stage(row["spans"])
                 fh.write(json.dumps(row) + "\n")
             for ft in frames[-ring.flight_n:]:
                 row = ft.to_dict()
@@ -565,6 +748,207 @@ def _prune_flight_dir(out_dir: str, keep_path: str,
                  removed, out_dir)
 
 
+# -- the freeze recorder ------------------------------------------------
+
+#: live engines (anything with ``name``, ``queue_age_s()`` and
+#: ``thread_states()``) that the recorder's dumps read
+_watched: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def watch_engine(engine) -> None:
+    if active() is not None:
+        _watched.add(engine)
+
+
+_THREAD_HEAD = re.compile(r"^(?:Current thread|Thread) (0x[0-9a-f]+)")
+
+
+def _top_frames(stacks: str) -> dict[str, str]:
+    """``faulthandler``'s dump -> thread name (or id) -> its innermost
+    frame, ``file:line in function``."""
+    names = {f"0x{t.ident:016x}": t.name for t in threading.enumerate()
+             if t.ident is not None}
+    out: dict[str, str] = {}
+    head = None
+    for line in stacks.splitlines():
+        m = _THREAD_HEAD.match(line)
+        if m:
+            head = names.get(m.group(1), m.group(1))
+        elif head is not None and line.startswith("  File "):
+            out[head] = line.strip()
+            head = None
+    return out
+
+
+class FreezeRecorder:
+    """The heartbeat that names a freeze (module docstring). The thread
+    needs the GIL to wake, which is the measurement. Its stack dumps
+    are taken WITH the GIL, right after the wake: a watchdog that needs
+    none (``faulthandler.dump_traceback_later``) walks the frames of
+    threads that run, and killed the server with SIGSEGV twice on the
+    chip (PERF.md section 6, PR 25)."""
+
+    PERIOD_S = 0.25
+    #: a wake this late is recorded in ``evam_freeze_seconds``
+    LATE_S = 0.1
+    #: the lateness, and the queue age, that writes a dump
+    FREEZE_S = 1.0
+    STACKS_MAX_BYTES = 1 << 20
+
+    def __init__(self, flight_dir: str) -> None:
+        self.path = os.path.join(flight_dir, "freeze-stacks.log")
+        self._fh = None
+        self._stop = threading.Event()
+        self._thread: threading.Thread | None = None
+        self._gc_t0 = 0.0
+        #: (t0, dur_s, generation) of finished collections, appended by
+        #: the gc callback and drained by the heartbeat
+        self._gc_log: deque = deque(maxlen=4096)
+        #: collections of >= 10 ms, for the dumps
+        self._gc_slow: deque = deque(maxlen=32)
+        #: a stall has one dump: set while an engine's queue stays old
+        self._stall_dumped = False
+
+    def start(self) -> None:
+        os.makedirs(os.path.dirname(self.path), exist_ok=True)
+        self._fh = open(self.path, "a", encoding="utf-8")
+        metrics.declare("evam_freeze_seconds")
+        for gen in range(3):
+            metrics.declare("evam_gc_pause_seconds", {"gen": str(gen)})
+        gc.callbacks.append(self._on_gc)
+        self._thread = threading.Thread(
+            target=self._run, name="evam-heartbeat", daemon=True)
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join(timeout=5)
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        if self._fh is not None:
+            self._fh.close()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # Runs inside the collector, on whichever thread triggered it
+        # and possibly inside the registry's lock: two stamps and a
+        # deque append, nothing that could take a lock.
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        else:
+            self._gc_log.append((self._gc_t0,
+                                 time.perf_counter() - self._gc_t0,
+                                 info["generation"]))
+
+    def _run(self) -> None:
+        while True:
+            cpu0 = time.thread_time()
+            t0 = time.perf_counter()
+            stopping = self._stop.wait(self.PERIOD_S)
+            late = time.perf_counter() - t0 - self.PERIOD_S
+            while self._gc_log:
+                rec = self._gc_log.popleft()
+                metrics.observe("evam_gc_pause_seconds", rec[1],
+                                {"gen": str(rec[2])})
+                if rec[1] >= 0.01:
+                    self._gc_slow.append(rec)
+            if stopping:
+                break
+            if late >= self.LATE_S:
+                self._on_late(late, t0, time.thread_time() - cpu0)
+            else:
+                self._check_queues(t0)
+
+    def _gc_since(self, t0: float) -> list[dict]:
+        return [{"gen": gen, "at_s": round(g0 - t0, 4),
+                 "dur_s": round(dur, 4)}
+                for (g0, dur, gen) in self._gc_slow if g0 + dur >= t0]
+
+    def _on_late(self, late: float, t0: float, spin_s: float) -> None:
+        """``spin_s``: this thread's own CPU seconds over the beat. A
+        thread that waits for the GIL wakes every 5 ms to ask for it
+        (4-6 ms of CPU per second, measured); one that had no CPU, or
+        whose process stood still, used none."""
+        metrics.observe("evam_freeze_seconds", late)
+        gc_in = self._gc_since(t0)
+        log.warning("heartbeat woke %.0f ms late (%.1f ms of its own CPU "
+                    "in that beat); collections of >= 10 ms: %s",
+                    late * 1e3, spin_s * 1e3, gc_in or "none")
+        if late < self.FREEZE_S:
+            return
+        # Three cases. ``process_stopped``: this thread did not even
+        # contend for the GIL while it was late, so it was not running:
+        # the kernel, the hypervisor, a stop signal stood the process
+        # still. ``gil_held_by_gc``: the collections listed cover half
+        # the lateness. ``gil_held``: one thread kept the GIL inside a
+        # call; the stacks are of the moment after, so look in them
+        # for the thread whose stack holds a call that can keep it.
+        if spin_s < 0.001 * late:
+            verdict = "process_stopped"
+        elif sum(g["dur_s"] for g in gc_in) >= 0.5 * late:
+            verdict = "gil_held_by_gc"
+        else:
+            verdict = "gil_held"
+        self._dump("freeze", {"late_s": round(late, 4), "verdict": verdict,
+                              "gil_wait_cpu_s": round(spin_s, 5),
+                              "gc": gc_in})
+
+    def _check_queues(self, t0: float) -> None:
+        """The other way a server stands still: this thread woke on
+        time, so Python runs, yet an engine's oldest item has waited a
+        second — an engine thread sits in a call that released the GIL
+        (a transfer, a launch, a readback that the runtime does not
+        return from). One ``stall`` dump per such stretch."""
+        if max((eng.queue_age_s() for eng in list(_watched)),
+               default=0.0) < self.FREEZE_S:
+            self._stall_dumped = False
+        elif not self._stall_dumped:
+            self._stall_dumped = True
+            self._dump("stall", {"verdict": "interpreter_alive",
+                                 "gc": self._gc_since(t0 - self.FREEZE_S)})
+
+    def _dump(self, reason: str, state: dict) -> None:
+        """One flight dump: ``state`` plus every watched engine's queue
+        age and its threads' stretches (which stage, for how long) and
+        every thread's stack, taken here with the GIL held."""
+        fd = self._fh.fileno()
+        size0 = os.fstat(fd).st_size
+        faulthandler.dump_traceback(file=self._fh, all_threads=True)
+        with open(self.path, "rb") as fh:
+            fh.seek(size0)
+            stacks = fh.read(65536).decode("utf-8", "replace")
+        ages, threads = {}, {}
+        for eng in list(_watched):
+            ages[eng.name] = round(eng.queue_age_s(), 4)
+            threads[eng.name] = eng.thread_states()
+        flight_dump("process", reason, state={
+            **state, "queue_age_s": ages, "threads": threads,
+            "top_frames": _top_frames(stacks), "stacks": stacks})
+        if os.fstat(fd).st_size > self.STACKS_MAX_BYTES:
+            os.ftruncate(fd, 0)
+
+
+_recorder: FreezeRecorder | None = None
+
+
+def start_freeze_recorder() -> FreezeRecorder | None:
+    """Start the process's one recorder (None with tracing off)."""
+    global _recorder
+    ring = active()
+    if ring is None or _recorder is not None:
+        return _recorder
+    _recorder = FreezeRecorder(ring.flight_dir or _default_flight_dir())
+    _recorder.start()
+    return _recorder
+
+
+def stop_freeze_recorder() -> None:
+    global _recorder
+    if _recorder is not None:
+        _recorder.stop()
+        _recorder = None
+
+
 # -- profiler glue ------------------------------------------------------
 
 def maybe_start_profiler(enabled: bool, port: int = _PROFILER_PORT) -> bool:
@@ -589,9 +973,11 @@ def profiler_running() -> bool:
 
 def init_observability(settings) -> None:
     """One-call runtime bootstrap for both serve entrypoints:
-    compilation cache + optional profiler server."""
+    compilation cache, optional profiler server, freeze recorder
+    (``stop_freeze_recorder`` when the server stops)."""
     configure_compilation_cache()
     maybe_start_profiler(settings.profiling_mode)
+    start_freeze_recorder()
 
 
 #: the in-checkout default. The directory is part of every cache

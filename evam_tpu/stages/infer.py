@@ -22,7 +22,7 @@ import numpy as np
 
 from evam_tpu.engine.hub import EngineHub
 from evam_tpu.models.zoo.action import CLIP_LEN
-from evam_tpu.obs import get_logger
+from evam_tpu.obs import get_logger, metrics, trace
 from evam_tpu.stages.base import AsyncStage
 from evam_tpu.stages.context import FrameContext, Region, Tensor
 from evam_tpu.stages.gate import maybe_gate
@@ -155,6 +155,24 @@ def _wire_frame(
 
 
 
+def _timed_wire(ctx: FrameContext, size: tuple[int, int],
+                wire_format: str, stage: str) -> np.ndarray:
+    """``_wire_frame`` of the frame, timed where the frame is traced:
+    ``evam_stage_seconds{stage="<stage>.wire"}``, a ``wire`` span and
+    ``evam.runner.wire`` on the stream's thread in a profiler
+    capture."""
+    ft = ctx.trace
+    if ft is None:
+        return _wire_frame(ctx.frame, size, wire_format)
+    t0 = time.perf_counter()
+    with trace.annotate("evam.runner.wire"):
+        out = _wire_frame(ctx.frame, size, wire_format)
+    dt = time.perf_counter() - t0
+    metrics.observe("evam_stage_seconds", dt, {"stage": f"{stage}.wire"})
+    ft.add_span("wire", t0, dt)
+    return out
+
+
 def _warm_engine(hub: EngineHub, engine, ingest_size, wire_format,
                  **extra_example) -> None:
     """Precompile the engine's batch buckets in the background when the
@@ -229,7 +247,7 @@ class DetectStage(AsyncStage):
             priority=ctx.priority,
             stream=ctx.stream_id,
             trace=ctx.trace,
-            frames=_wire_frame(ctx.frame, self.ingest_size, self.wire))
+            frames=_timed_wire(ctx, self.ingest_size, self.wire, "detect"))
 
     def complete(self, ctx: FrameContext, result: np.ndarray | None) -> list[FrameContext]:
         if result is None:
@@ -339,7 +357,7 @@ class ClassifyStage(AsyncStage):
             units=len(regions),
             stream=ctx.stream_id,
             trace=ctx.trace,
-            frames=_wire_frame(ctx.frame, self.ingest_size, self.wire),
+            frames=_timed_wire(ctx, self.ingest_size, self.wire, "classify"),
             boxes=boxes)
 
     def complete(self, ctx: FrameContext, result: np.ndarray | None) -> list[FrameContext]:
@@ -418,7 +436,7 @@ class ActionStage(AsyncStage):
             priority=prio,
             stream=ctx.stream_id,
             trace=tr,
-            frames=_wire_frame(ctx.frame, self.ingest_size, self.wire))
+            frames=_timed_wire(ctx, self.ingest_size, self.wire, "action"))
         outer: Future = Future()
 
         def _on_encoded(f: Future) -> None:
@@ -604,7 +622,7 @@ class FusedDetectClassifyStage(AsyncStage):
             priority=ctx.priority,
             stream=ctx.stream_id,
             trace=ctx.trace,
-            frames=_wire_frame(ctx.frame, self.ingest_size, self.wire))
+            frames=_timed_wire(ctx, self.ingest_size, self.wire, "fused"))
 
     def complete(self, ctx: FrameContext, result: np.ndarray | None) -> list[FrameContext]:
         if result is None:

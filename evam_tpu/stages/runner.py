@@ -85,6 +85,7 @@ class StreamRunner:
 
     def feed(self, ev: FrameEvent) -> None:
         self.frames_in += 1
+        ingest_t = time.perf_counter()
         ctx = FrameContext(
             frame=ev.frame,
             audio=ev.audio,
@@ -92,15 +93,23 @@ class StreamRunner:
             seq=ev.seq,
             stream_id=self.stream_id,
             source_uri=self.source_uri,
-            ingest_t=time.perf_counter(),
+            ingest_t=ingest_t,
             priority=self.priority,
-            trace=trace.start_frame(self.stream_id, ev.seq, self.priority),
+            trace=trace.start_frame(self.stream_id, ev.seq, self.priority,
+                                    ingest_t, ev.due_t),
         )
-        if ctx.trace is not None and ev.decode_s is not None:
-            # decode happened before ingest; backdate the span so the
-            # tree starts where the frame's wall time actually started
-            ctx.trace.add_span("decode", ctx.ingest_t - ev.decode_s,
-                               ev.decode_s)
+        if ctx.trace is not None:
+            if ev.due_t is not None:
+                # how long after the paced source's own due time the
+                # frame is fed: the source's frame build and the
+                # runner's previous frame
+                metrics.observe("evam_source_lag_seconds",
+                                ingest_t - ev.due_t)
+            if ev.decode_s is not None:
+                # decode happened before ingest; backdate the span so
+                # the tree starts where the frame's wall time started
+                ctx.trace.add_span("decode", ingest_t - ev.decode_s,
+                                   ev.decode_s)
         if self._faults is not None:
             try:
                 frame = self._faults.apply(ctx.frame)
@@ -126,16 +135,32 @@ class StreamRunner:
         """Resume parked frames whose results are ready (in order)."""
         while self._parked:
             head = self._parked[0]
-            if head.future is not None and not head.future.done() and not block:
+            fut, ft = head.future, head.ctx.trace
+            waits = fut is not None and not fut.done()
+            if waits and not block:
                 return
             self._parked.popleft()
             try:
-                result = head.future.result() if head.future is not None else None
+                if fut is None:
+                    result = None
+                elif waits and ft is not None:
+                    with trace.annotate("evam.runner.wait_result"):
+                        result = fut.result()
+                else:
+                    result = fut.result()
                 t_c = time.perf_counter()
+                t_r = getattr(fut, "t_resolved", None)
+                if t_r is not None and ft is not None:
+                    # the engine resolved the future at t_r (its
+                    # completion loop stamps it); it lay there until
+                    # this pump, which runs inside the NEXT feed unless
+                    # the window was full
+                    metrics.observe("evam_collect_wait_seconds", t_c - t_r)
+                    ft.add_span("runner.collect_wait", t_r, t_c - t_r)
                 with stage_timer(f"{head.stage.name}.complete"):
                     outs = head.stage.complete(head.ctx, result)
-                if head.ctx.trace is not None:
-                    head.ctx.trace.add_span(
+                if ft is not None:
+                    ft.add_span(
                         f"stage.{head.stage.name}.complete", t_c,
                         time.perf_counter() - t_c)
             except Exception as exc:  # noqa: BLE001 — frame-level fault isolation
@@ -158,7 +183,13 @@ class StreamRunner:
             ctx.stage_index = i
             if stage.is_async:
                 try:
-                    fut = stage.submit(ctx)
+                    if ctx.trace is None:
+                        fut = stage.submit(ctx)
+                    else:
+                        t_s = time.perf_counter()
+                        fut = stage.submit(ctx)
+                        ctx.trace.add_span(f"stage.{stage.name}.submit",
+                                           t_s, time.perf_counter() - t_s)
                 except Exception as exc:  # noqa: BLE001
                     self._handle_error(exc, ctx)
                     return

@@ -4,12 +4,17 @@ ring-buffer clock, span-tree completeness through the real serving
 path, batch↔frame linkage, tail-based retention, the EVAM_TRACE=off
 no-op guarantee, the bounded ring, the quarantine flight recorder's
 JSONL shape, the Chrome trace-event renderer (tools/trace_dump.py),
-and the OpenMetrics exemplar on the latency p99 line."""
+the OpenMetrics exemplar on the latency p99 line, batch records as
+timelines, the waits between the layers and the freeze recorder."""
 
 from __future__ import annotations
 
+import functools
+import gc
 import json
+import signal
 import sys
+import threading
 import time
 from pathlib import Path
 
@@ -39,6 +44,35 @@ def _fresh(monkeypatch, **env: str) -> None:
         monkeypatch.setenv(k, v)
     reset_settings()
     trace.reset_cache()
+
+
+def _time_limit(seconds: float):
+    """A time limit of the test's own (no pytest-timeout here): the
+    alarm raises in the main thread, where pytest runs the test."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*a, **k):
+            def on_alarm(signum, frame):
+                raise TimeoutError(f"{fn.__name__} passed {seconds} s")
+            old = signal.signal(signal.SIGALRM, on_alarm)
+            signal.setitimer(signal.ITIMER_REAL, seconds)
+            try:
+                return fn(*a, **k)
+            finally:
+                signal.setitimer(signal.ITIMER_REAL, 0)
+                signal.signal(signal.SIGALRM, old)
+        return run
+    return wrap
+
+
+def _timeline(stages=trace.STAGE_ORDER[1:], t0=100.0, dur=0.001):
+    """A clock whose stages follow each other from ``t0``."""
+    clock = trace.StageClock()
+    clock["submit_wait"] = dur
+    for s in stages:
+        clock.mark(s, t0, dur)
+        t0 += dur
+    return clock
 
 
 def test_stage_order_pins_engine_clock():
@@ -112,17 +146,22 @@ def test_batch_links_frames_and_chrome_rendering(monkeypatch):
 
     fts = [trace.start_frame("cam0", i, "realtime") for i in range(3)]
     items = [_Item(ft) for ft in fts]
-    clock = {s: 0.001 for s in trace.STAGE_ORDER[:-2]}
+    clock = _timeline(trace.STAGE_ORDER[1:-2])
     trace.batch_begin("det", 7, items, bucket=4, n=3, clock=clock,
                       device="cpu:0")
-    trace.batch_complete("det", 7, items, readback_s=0.002,
-                         resolve_s=0.001)
+    # the completer appends its spans, with a named wait before them
+    clock.wait("wait_completer", 100.0065)
+    clock.span("readback", 100.0065, 0.002)
+    clock.span("resolve", 100.0085, 0.001)
+    trace.batch_complete("det", 7, items)
     for ft in fts:
         trace.finish_frame(ft, "ok")
         assert ft.bids == ["det#7"]
         names = [s[0] for s in ft.spans]
         assert "sched.queue_wait" in names
         assert "engine.dispatch" in names
+        # the batch's spans at their real starts, not laid end to end
+        assert ("engine.readback", 100.0065, 0.002, None) in ft.spans
 
     payload = trace.traces_payload()
     assert payload["enabled"] and payload["frames"] == 3
@@ -133,11 +172,16 @@ def test_batch_links_frames_and_chrome_rendering(monkeypatch):
     # the batch↔frame link: one batch span naming >= 2 member frame
     # trace ids, with the full stage clock attributed
     assert args["frames"] == [ft.trace_id for ft in fts]
-    assert set(args["stages"]) == set(trace.STAGE_ORDER)
+    assert args["stages"] == [*trace.STAGE_ORDER[1:-2], "wait_completer",
+                              "readback", "resolve"]
     assert args["last_stage"] == "resolve"
+    assert batch_ev[0]["ts"] == 100.0 * 1e6  # where its first span starts
     stage_ev = [e for e in payload["traceEvents"]
                 if e["cat"] == "batch-stage"]
-    assert [e["name"] for e in stage_ev] == list(trace.STAGE_ORDER)
+    assert [e["name"] for e in stage_ev] == list(trace.STAGE_ORDER[1:])
+    assert stage_ev[-2]["ts"] == round(100.0065 * 1e6, 1)
+    wait_ev = [e for e in payload["traceEvents"] if e["cat"] == "batch-wait"]
+    assert [e["name"] for e in wait_ev] == ["wait_completer"]
 
     import trace_dump
     doc = trace_dump.convert(payload)
@@ -147,11 +191,12 @@ def test_batch_links_frames_and_chrome_rendering(monkeypatch):
 
 def test_wedged_batch_last_stage(monkeypatch):
     _fresh(monkeypatch)
-    clock = {"submit_wait": 0.001, "slot_write": 0.001, "seal": 0.001}
+    clock = _timeline(("slot_write", "seal"))
     trace.batch_begin("det", 3, (), bucket=8, n=2, clock=clock)
-    clock["h2d_issue"] = 0.004  # live mutation AFTER begin is visible
+    clock.mark("h2d_issue", 100.002, 0.004)  # AFTER begin: still visible
+    clock.wait("wait_launcher", 100.01)      # a wait is not a stage
     _, _, pending = trace.active().snapshot()
-    assert trace.last_stage(trace._clock_stages(pending[0]["clock"])) \
+    assert trace.last_stage(trace._clock_spans(pending[0]["clock"])) \
         == "h2d_issue"
 
 
@@ -160,7 +205,7 @@ def test_flight_dump_shape(monkeypatch, tmp_path):
            EVAM_TRACE_SAMPLE_N="1")
     ft = trace.start_frame("cam0", 0, "standard")
     trace.finish_frame(ft, "error")
-    clock = {"submit_wait": 0.001, "h2d_issue": 0.004}
+    clock = _timeline(("slot_write", "seal", "h2d_issue"))
     trace.batch_begin("det", 11, (), bucket=8, n=2, clock=clock)
     path = trace.flight_dump("det", "stall watchdog",
                              state={"queue_depth": 5})
@@ -174,6 +219,8 @@ def test_flight_dump_shape(monkeypatch, tmp_path):
     batch = [r for r in rows if r["type"] == "batch"]
     assert len(batch) == 1 and batch[0]["pending"] is True
     assert batch[0]["last_stage"] == "h2d_issue"
+    assert [sp[0] for sp in batch[0]["spans"]] == [
+        "slot_write", "seal", "h2d_issue"]
     assert "clock" not in batch[0]
     frame = [r for r in rows if r["type"] == "frame"]
     assert len(frame) == 1 and frame[0]["status"] == "error"
@@ -315,9 +362,367 @@ def test_span_tree_through_serving_path(monkeypatch, eight_devices):
     for bid in ft.bids:
         assert ft.trace_id in by_bid[bid]["frames"]
     served = by_bid[ft.bids[0]]
-    assert served["stages"], served
-    assert trace.last_stage(served["stages"]) == "resolve"
+    assert served["spans"], served
+    assert trace.last_stage(served["spans"]) == "resolve"
     # spans nest inside the frame's lifetime, orderable for rendering
     t_end = time.perf_counter()
     for (_, t0, dur, _) in ft.spans:
         assert ft.t0 - 1.0 <= t0 <= t_end and 0.0 <= dur < 300.0
+
+
+# -- the freeze recorder --------------------------------------------------
+
+class _StubEngine:
+    """What the recorder reads of an engine."""
+    def __init__(self, age: float, name: str = "det") -> None:
+        self.age = age
+        self.name = name
+
+    def queue_age_s(self) -> float:
+        return self.age
+
+    def thread_states(self) -> dict:
+        return {"dispatch": ("h2d_issue", self.age)}
+
+
+def _hold_the_gil_for_a_while(then_wait: threading.Event):
+    """The function a freeze dump has to name: a C call that keeps the
+    GIL (``PyDLL`` does not release it) for 1.5 s. The thread lives on
+    afterwards, as a server's do, so the dump can name it."""
+    import ctypes
+
+    ctypes.PyDLL(None).usleep(1_500_000)
+    then_wait.wait(30)
+
+
+@_time_limit(60)
+def test_freeze_recorder_names_a_held_gil(monkeypatch, tmp_path):
+    _fresh(monkeypatch, EVAM_TRACE_FLIGHT_DIR=str(tmp_path))
+    before = metrics.render()
+    rec = trace.start_freeze_recorder()
+    try:
+        assert rec is not None and trace.start_freeze_recorder() is rec
+        assert any(t.name == "evam-heartbeat" for t in threading.enumerate())
+
+        eng = _StubEngine(0.5)
+        trace.watch_engine(eng)
+        time.sleep(0.6)  # a few quiet beats first
+        done = threading.Event()
+        holder = threading.Thread(target=_hold_the_gil_for_a_while,
+                                  args=(done,), name="gil-holder")
+        holder.start()
+        deadline = time.time() + 10
+        while not list(tmp_path.glob("flight-process-*.jsonl")) \
+                and time.time() < deadline:
+            time.sleep(0.05)
+        done.set()
+        holder.join()
+    finally:
+        trace.stop_freeze_recorder()
+    assert not any(t.name == "evam-heartbeat" for t in threading.enumerate())
+    assert rec._on_gc not in gc.callbacks
+
+    def count(text):
+        return float(next(l for l in text.splitlines() if l.startswith(
+            "evam_freeze_seconds_count")).split()[-1]) \
+            if "evam_freeze_seconds_count" in text else 0.0
+    assert count(metrics.render()) >= count(before) + 1
+
+    dumps = list(tmp_path.glob("flight-process-*.jsonl"))
+    assert len(dumps) == 1, dumps
+    header = json.loads(dumps[0].read_text().splitlines()[0])
+    assert header["reason"] == "freeze" and header["engine"] == "process"
+    state = header["state"]
+    assert 1.0 <= state["late_s"] < 3.0
+    # the heartbeat spun on the GIL while it was late: the interpreter
+    # was held, the process was not stopped; the stacks name the holder
+    assert state["verdict"] == "gil_held", state
+    assert state["gil_wait_cpu_s"] >= 0.001 * state["late_s"]
+    # (taken right after the wake: the holder has moved on inside it)
+    assert "_hold_the_gil_for_a_while" in state["stacks"]
+    assert "gil-holder" in state["top_frames"]
+    assert state["queue_age_s"]["det"] == 0.5
+    assert state["threads"]["det"]["dispatch"] == ["h2d_issue", 0.5]
+    assert isinstance(state["gc"], list)
+    assert "_hold_the_gil_for_a_while" in (
+        tmp_path / "freeze-stacks.log").read_text()
+
+
+@pytest.mark.parametrize("spin_s,gc_s,verdict", [
+    (0.0, 0.0, "process_stopped"),   # late, and never asked for the GIL
+    (0.008, 1.6, "gil_held_by_gc"),  # a collection covers the lateness
+    (0.008, 0.0, "gil_held"),
+])
+def test_freeze_verdicts(monkeypatch, tmp_path, spin_s, gc_s, verdict):
+    _fresh(monkeypatch, EVAM_TRACE_FLIGHT_DIR=str(tmp_path))
+    rec = trace.FreezeRecorder(str(tmp_path))
+    rec._fh = open(rec.path, "a", encoding="utf-8")
+    t0 = time.perf_counter()
+    if gc_s:
+        rec._gc_slow.append((t0 + 0.1, gc_s, 2))
+    rec._on_late(2.0, t0, spin_s)
+    rec._fh.close()
+    (dump,) = tmp_path.glob("flight-process-*.jsonl")
+    state = json.loads(dump.read_text().splitlines()[0])["state"]
+    assert state["verdict"] == verdict
+    assert [g["gen"] for g in state["gc"]] == ([2] if gc_s else [])
+    assert "test_freeze_verdicts" in state["stacks"]
+
+
+def test_no_annotation_object_outside_a_capture(monkeypatch):
+    _fresh(monkeypatch)
+    spans = trace.thread_spans("det", "dispatch", cpu=True)
+    spans.to("slot_write")
+    assert spans._ann is None and spans.where()[0] == "slot_write"
+    assert trace.annotate("evam.runner.wire") is trace._NO_ANNOTATION
+    spans.to(None)
+
+
+def _blocked_in_a_call_that_released_the_gil(until: threading.Event):
+    until.wait(30)
+
+
+@_time_limit(30)
+def test_stall_with_a_live_interpreter_leaves_a_dump(monkeypatch, tmp_path):
+    """An engine's queue ages past a second while the heartbeat wakes
+    on time: no freeze, one ``stall`` dump for the whole stretch, with
+    the engine threads' stretches and every thread's stack."""
+    _fresh(monkeypatch, EVAM_TRACE_FLIGHT_DIR=str(tmp_path))
+    eng = _StubEngine(0.2, "det-stall")  # _watched is the process's
+    done = threading.Event()
+    blocked = threading.Thread(
+        target=_blocked_in_a_call_that_released_the_gil, args=(done,),
+        name="engine-det-dispatch")
+    blocked.start()
+    rec = trace.start_freeze_recorder()
+    try:
+        trace.watch_engine(eng)
+        time.sleep(0.6)
+        assert not list(tmp_path.glob("flight-process-*.jsonl"))
+        eng.age = 1.3
+        time.sleep(1.2)  # several beats inside the one stall
+        eng.age = 0.0
+        time.sleep(0.6)
+    finally:
+        trace.stop_freeze_recorder()
+        done.set()
+        blocked.join()
+    dumps = list(tmp_path.glob("flight-process-*.jsonl"))
+    assert len(dumps) == 1, dumps
+    header = json.loads(dumps[0].read_text().splitlines()[0])
+    assert header["reason"] == "stall"
+    state = header["state"]
+    assert state["verdict"] == "interpreter_alive"
+    assert state["queue_age_s"]["det-stall"] == 1.3
+    assert state["threads"]["det-stall"]["dispatch"] == ["h2d_issue", 1.3]
+    assert "_blocked_in_a_call_that_released_the_gil" in state["stacks"]
+    assert "wait" in state["top_frames"]["engine-det-dispatch"]
+    assert rec._stall_dumped is False  # re-armed once the queue was young
+
+
+@_time_limit(30)
+def test_gc_pauses_are_recorded_by_generation(monkeypatch, tmp_path):
+    _fresh(monkeypatch, EVAM_TRACE_FLIGHT_DIR=str(tmp_path))
+
+    def gen2_count():
+        key = 'evam_gc_pause_seconds_count{gen="2"}'
+        return next((float(l.split()[-1]) for l in
+                     metrics.render().splitlines() if l.startswith(key)),
+                    None)
+    rec = trace.start_freeze_recorder()
+    try:
+        n0 = gen2_count()
+        assert n0 is not None  # declared at start: a reader sees zero
+        gc.collect()
+        deadline = time.time() + 5
+        while gen2_count() < n0 + 1 and time.time() < deadline:
+            time.sleep(0.05)
+        assert gen2_count() >= n0 + 1
+    finally:
+        trace.stop_freeze_recorder()
+
+
+@_time_limit(30)
+def test_trace_off_starts_nothing_and_stamps_nothing(monkeypatch):
+    import numpy as np
+
+    from evam_tpu.stages import infer
+    from evam_tpu.stages.context import FrameContext
+
+    _fresh(monkeypatch, EVAM_TRACE="off")
+    callbacks = list(gc.callbacks)
+    assert trace.start_freeze_recorder() is None
+    assert not any(t.name == "evam-heartbeat" for t in threading.enumerate())
+    assert gc.callbacks == callbacks
+
+    class _NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read with EVAM_TRACE=off")
+
+    monkeypatch.setattr(trace, "time", _NoClock())
+    monkeypatch.setattr(infer, "time", _NoClock())
+    spans = trace.thread_spans("det", "dispatch", cpu=True)
+    assert spans.to("wait_items") is None and spans.to(None) is None
+    trace.watch_engine(object())
+    assert len(trace._watched) == 0
+    assert trace.start_frame("s", 0, "standard", None, 1.0) is None
+    ctx = FrameContext(frame=np.zeros((4, 4, 3), np.uint8), pts_ns=0,
+                      seq=0, stream_id="s")
+    assert ctx.trace is None
+    infer._timed_wire(ctx, (4, 4), "seed", "detect")
+
+
+# -- batch timelines and the waits between the layers ---------------------
+
+@pytest.fixture(scope="module")
+def paced_run(eight_devices):
+    """One paced synthetic camera through the real serving path, with
+    every frame retained; what the cases below read."""
+    import os
+
+    from evam_tpu.engine import EngineHub
+    from evam_tpu.models import ModelRegistry
+    from evam_tpu.parallel import build_mesh
+    from evam_tpu.sched import SchedConfig
+    from evam_tpu.server.registry import PipelineRegistry
+
+    saved = {k: os.environ.pop(k, None) for k in _KNOBS}
+    os.environ["EVAM_TRACE_SAMPLE_N"] = "1"
+    reset_settings()
+    trace.reset_cache()
+    small = {k: (64, 64) for k in ZOO_SPECS}
+    small["audio_detection/environment"] = (1, 1600)
+    settings = Settings(pipelines_dir=str(REPO / "pipelines"))
+    # the scheduler on, as the server runs it: the dispatcher copies
+    # the rows itself, so slot_write is a stretch of the timeline
+    hub = EngineHub(
+        ModelRegistry(dtype="float32", input_overrides=small,
+                      width_overrides={k: 8 for k in ZOO_SPECS}),
+        plan=build_mesh(), max_batch=8, deadline_ms=4.0,
+        sched=SchedConfig.from_settings(settings.sched,
+                                        standard_deadline_ms=4.0))
+    registry = PipelineRegistry(settings, hub=hub)
+    n = 12
+    try:
+        # once unpaced, so the paced camera meets a compiled bucket
+        warm = registry.start_instance(
+            "object_detection", "person_vehicle_bike",
+            {"source": {"uri": "synthetic://96x96@30?count=4&seed=2",
+                        "type": "uri"},
+             "destination": {"metadata": {"type": "null"}}})
+        warm.wait(timeout=180)
+        before = metrics.render()
+        inst = registry.start_instance(
+            "object_detection", "person_vehicle_bike",
+            {"source": {"uri": f"synthetic://96x96@30?count={n}&seed=1",
+                        "type": "uri", "realtime": True},
+             "destination": {"metadata": {"type": "null"}}})
+        inst.wait(timeout=180)
+        assert inst.state.value == "COMPLETED", inst.error
+        after = metrics.render()
+        frames, batches, _ = trace.active().snapshot()
+        payload = trace.traces_payload()
+    finally:
+        registry.stop_all()
+        for k, v in saved.items():
+            if v is not None:
+                os.environ[k] = v
+        os.environ.pop("EVAM_TRACE_SAMPLE_N", None)
+        reset_settings()
+        trace.reset_cache()
+    frames = [f for f in frames if f.stream_id == inst.id]
+    assert len(frames) == n
+    return {"n": n, "before": before, "after": after, "frames": frames,
+            "batches": batches, "payload": payload, "stream": inst.id}
+
+
+def _series(text: str, key: str) -> float:
+    return next((float(l.split()[-1]) for l in text.splitlines()
+                 if l.startswith(key + " ")), 0.0)
+
+
+@_time_limit(240)
+def test_batch_record_is_a_timeline(paced_run):
+    """Stage spans are ordered, start inside the batch, leave no hole
+    over 0.5 ms that is not a named wait, and sum to the dispatch."""
+    served = {b for f in paced_run["frames"] for b in f.bids}
+    recs = [r for r in paced_run["batches"]
+            if f"{r['engine']}#{r['bid']}" in served]
+    assert recs
+    for rec in recs:
+        spans = rec["spans"]
+        names = [sp[0] for sp in spans]
+        stages = [n for n in names if n in trace.STAGE_ORDER]
+        assert stages == [s for s in trace.STAGE_ORDER if s in stages]
+        assert {"slot_write", "seal", "h2d_issue", "h2d_wait", "launch",
+                "readback", "resolve"} <= set(stages), names
+        assert {n for n in names if n not in trace.STAGE_ORDER} <= {
+            "wait_launcher", "wait_slot", "wait_completer"}
+        end = rec["t0"]
+        assert spans[0][1] == rec["t0"]
+        for name, t0, dur in spans:
+            assert dur >= 0.0 and t0 >= rec["t0"], (name, spans)
+            assert -1e-6 <= t0 - end < 0.0005, (name, t0 - end, spans)
+            end = t0 + dur
+        assert end <= rec["t0"] + rec["dur_s"] + 1e-6
+        assert abs(sum(sp[2] for sp in spans) - rec["dur_s"]) < 0.001
+    # a member frame carries the same spans, and its dispatch is the batch
+    ft = paced_run["frames"][-1]
+    rec = next(r for r in recs if f"{r['engine']}#{r['bid']}" == ft.bids[0])
+    mine = {s[0]: s for s in ft.spans}
+    for name, t0, dur in rec["spans"]:
+        assert mine[f"engine.{name}"][1:3] == (t0, dur)
+    assert abs(mine["engine.dispatch"][2] - rec["dur_s"]) < 0.001
+    assert mine["sched.queue_wait"][1] + mine["sched.queue_wait"][2] \
+        == pytest.approx(rec["t0"])
+
+
+@_time_limit(240)
+def test_waits_between_layers_observe_once_per_frame(paced_run):
+    n, before, after = (paced_run[k] for k in ("n", "before", "after"))
+    for key in ("evam_collect_wait_seconds_count",
+                "evam_source_lag_seconds_count",
+                'evam_stage_seconds_count{stage="detect.wire"}'):
+        assert _series(after, key) - _series(before, key) == n, key
+    for ft in paced_run["frames"]:
+        names = [s[0] for s in ft.spans]
+        for want in ("wire", "runner.collect_wait"):
+            assert names.count(want) == 1, (want, names)
+        assert sum(n.startswith("stage.") and n.endswith(".submit")
+                   for n in names) == 1
+        spans = {s[0]: s for s in ft.spans}
+        submit = next(s for s in ft.spans if s[0].endswith(".submit"))
+        # feed -> submit -> wire inside it -> collected after the resolve
+        assert ft.due_t <= ft.t0 <= submit[1] <= spans["wire"][1]
+        assert spans["wire"][1] + spans["wire"][2] <= submit[1] + submit[2]
+        resolve = spans["engine.resolve"]
+        assert spans["runner.collect_wait"][1] == resolve[1]
+        assert spans["runner.collect_wait"][2] >= 0.0
+    # the engine's threads account for their seconds by state
+    for state in ("wait_items", "work"):
+        assert any(l.startswith("evam_engine_thread_seconds_total{")
+                   and 'thread="dispatch"' in l and f'state="{state}"' in l
+                   for l in after.splitlines()), state
+    assert any(l.startswith("evam_engine_thread_cpu_seconds_total{")
+               and 'thread="dispatch"' in l for l in after.splitlines())
+
+
+@_time_limit(240)
+def test_traces_route_carries_clock_due_and_ingest(paced_run):
+    payload = paced_run["payload"]
+    clock = payload["clock"]
+    assert abs(clock["time_ns"] / 1e9 - clock["perf_counter"]
+               - (time.time() - time.perf_counter())) < 0.05
+    mine = [e for e in payload["traceEvents"]
+            if e["cat"] == "frame" and e["tid"] == paced_run["stream"]]
+    assert mine
+    by_seq = {f.seq: f for f in paced_run["frames"]}
+    for ev in mine:
+        ft = by_seq[ev["args"]["seq"]]
+        assert ev["args"]["ingest_t"] == ft.t0
+        assert ev["args"]["due_t"] == ft.due_t
+        assert ev["ts"] >= round(ft.t0 * 1e6, 1) - 0.2
+    # a paced camera's frames are due one period apart, on its own clock
+    dues = [by_seq[k].due_t for k in sorted(by_seq)]
+    gaps = [b - a for a, b in zip(dues, dues[1:])]
+    assert all(abs(g - 1 / 30) < 1e-6 for g in gaps), gaps

@@ -26,7 +26,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from evam_tpu.obs.trace import STAGE_ORDER, last_stage  # noqa: E402
+from evam_tpu.obs.trace import batch_events  # noqa: E402
 
 #: the transfer/compute stages a linked batch span must clock for the
 #: acceptance check (readback rides completion, so it proves the batch
@@ -51,7 +51,8 @@ def convert(payload: dict) -> dict:
 def events_from_flight(rows: list[dict]) -> list[dict]:
     """Flight-recorder JSONL rows -> Chrome trace events (same layout
     as the live route: frame spans per stream track, one batch span
-    per record plus sequential per-stage slices)."""
+    per record plus a slice per stage and per named wait at its real
+    start)."""
     events: list[dict] = []
     for row in rows:
         kind = row.get("type")
@@ -69,40 +70,9 @@ def events_from_flight(rows: list[dict]) -> list[dict]:
                     "args": args,
                 })
         elif kind == "batch":
-            stages = row.get("stages") or {}
-            total = row.get("dur_s")
-            if total is None:
-                total = sum(stages.values())
-            events.append({
-                "name": f"batch {row['engine']}#{row['bid']}", "ph": "X",
-                "cat": "batch", "ts": round(row["t0"] * 1e6, 1),
-                "dur": round(total * 1e6, 1),
-                "pid": f"engine {row['engine']}",
-                "tid": row.get("device", ""),
-                "args": {
-                    "bid": row["bid"],
-                    "frames": list(row.get("frames", ())),
-                    "bucket": row.get("bucket"), "n": row.get("n"),
-                    "device": row.get("device", ""),
-                    "status": row.get("status", ""),
-                    "pending": row.get("pending", False),
-                    "stages": stages,
-                    "last_stage": row.get("last_stage") or last_stage(stages),
-                },
-            })
-            t = row["t0"]
-            for s in STAGE_ORDER:
-                if s not in stages:
-                    continue
-                events.append({
-                    "name": s, "ph": "X", "cat": "batch-stage",
-                    "ts": round(t * 1e6, 1),
-                    "dur": round(stages[s] * 1e6, 1),
-                    "pid": f"engine {row['engine']}",
-                    "tid": f"{row.get('device', '')}/stages",
-                    "args": {"bid": row["bid"]},
-                })
-                t += stages[s]
+            batch = batch_events(row)
+            batch[0]["args"]["pending"] = row.get("pending", False)
+            events.extend(batch)
     return events
 
 
