@@ -38,7 +38,8 @@ def cmd_list(args) -> int:
 def cmd_fetch_models(args) -> int:
     modes = [m for m, on in [("--download", args.download),
                              ("--from-ir", bool(args.from_ir)),
-                             ("--synthesize-omz", bool(args.synthesize_omz))]
+                             ("--synthesize-omz", bool(args.synthesize_omz)),
+                             ("--synthesize-lm", bool(args.synthesize_lm))]
              if on]
     if len(modes) > 1:
         print(f"fetch-models: {' and '.join(modes)} are mutually "
@@ -60,6 +61,11 @@ def cmd_fetch_models(args) -> int:
         print(f"installed={report.installed} skipped={report.skipped} "
               f"failed={report.failed}")
         return 0 if report.ok else 1
+    if args.synthesize_lm:
+        from evam_tpu.models.fetch import synthesize_lm
+
+        return synthesize_lm(args.output, alias=args.synthesize_lm,
+                             version=args.version, preset=args.lm_preset)
     if args.synthesize_omz:
         from evam_tpu.models.fetch import synthesize_omz
 
@@ -130,6 +136,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="materialize an OMZ-topology-shaped MobileNet-SSD "
                         "IR under ALIAS (offline stand-in for the OMZ "
                         "download; see models/ir_build.py)")
+    f.add_argument("--synthesize-lm", default=None, metavar="ALIAS",
+                   help="install a language model for the describe stage "
+                        "under ALIAS/--version as its config file; the "
+                        "weights are made on the device from its seed")
+    f.add_argument("--lm-preset", default="deepseek_v2_ep8",
+                   help="--synthesize-lm: which config of "
+                        "models/lm/presets.py")
     f.add_argument("--size", type=int, default=None,
                    help="input resolution for --synthesize-omz "
                         "(default: 512 for ssd, 72 for attributes)")

@@ -261,6 +261,28 @@ class AotSettings(BaseModel):
     max_bytes: int = 1073741824
 
 
+class LMSettings(BaseModel):
+    """Fixed shapes of the generate engine (engine/generate.py), which
+    serves the ``describe`` stage's language model. Nothing reads them
+    in a server without such a stage. The defaults are the deployment's;
+    EVAM_LM_SHAPES exists for rehearsals and tests at a tiny size, and no
+    deployment file sets it."""
+
+    #: sequence slots: generations in flight on the device
+    slots: int = 128
+    #: tokens per page of the latent cache
+    page_tokens: int = 128
+    #: prompt tokens one prefill step packs, of at most max_segments
+    #: sequences
+    chunk_tokens: int = 512
+    max_segments: int = 8
+    #: tokens a sequence may add to the shared prefix (prompt +
+    #: generated)
+    private_tokens: int = 384
+    #: tokens of the operator instruction every sequence shares
+    prefix_tokens: int = 2048
+
+
 class Settings(BaseModel):
     """Flat service settings resolved from env + optional config file."""
 
@@ -306,6 +328,7 @@ class Settings(BaseModel):
     tune: TuneSettings = Field(default_factory=TuneSettings)
     ckpt: CkptSettings = Field(default_factory=CkptSettings)
     aot: AotSettings = Field(default_factory=AotSettings)
+    lm: LMSettings = Field(default_factory=LMSettings)
 
     @classmethod
     def from_env(cls, config_file: str | os.PathLike | None = None) -> "Settings":
@@ -439,6 +462,20 @@ class Settings(BaseModel):
             for var, (key, conv) in aot_mapping.items():
                 if var in env:
                     aot[key] = conv(env[var])
+
+        # EVAM_LM_SHAPES="slots=8,page_tokens=8,...": the generate
+        # engine's shapes move together (a rehearsal sets them all), so
+        # they share one variable
+        lm = data.setdefault("lm", {})
+        if isinstance(lm, dict) and env.get("EVAM_LM_SHAPES"):
+            for item in env["EVAM_LM_SHAPES"].split(","):
+                key, _, value = item.partition("=")
+                key = key.strip()
+                if key not in LMSettings.model_fields:
+                    raise ValueError(
+                        f"EVAM_LM_SHAPES: unknown shape {key!r} "
+                        f"({', '.join(LMSettings.model_fields)})")
+                lm[key] = int(value)
         return cls.model_validate(data)
 
 

@@ -1,0 +1,811 @@
+"""The generate engine: many device steps per request, state on the device
+between them.
+
+``BatchEngine`` (engine/batcher.py) serves one item by one program and
+one future. A generation is a request that spans a prefill and tens of
+decode steps, joins and leaves a running batch, and resolves ONE future
+at its end; this engine serves those. The hub creates and supervises it
+like any other (engine/hub.py ``generate_engine``), and it is listed on
+``/engines``.
+
+* ``slots`` sequence slots. A submitted request waits for a slot (the
+  class's staleness budget applies to THIS wait and to nothing after
+  it), then holds it until its last token.
+* One thread runs device steps of FIXED shapes from a small set of
+  programs, all compiled by ``warm_async``: ``decode`` over the running
+  sequences, padded to a slot bucket, and ``prefill`` of one packed
+  chunk of at most ``chunk_tokens`` prompt tokens of at most
+  ``max_segments`` sequences (segment ids keep them apart; a prompt may
+  continue in the next chunk, as its first segment). While sequences
+  decode, a prefill step runs when the waiting prompts fill a whole
+  chunk, or when they have already waited one decode step, and at most
+  ``MAX_PREFILL_RUN`` chunks run between two decode steps: chunks run
+  full, decoding is held up for two chunks at a time, never more, and
+  a lone prompt waits one decode step at most.
+* The latent cache is one device array of pages (engine/pages.py);
+  every sequence's page table begins with the pages of the shared
+  instruction prefix, prefilled once in ``warm_async`` and never written
+  again, and ends with its own.
+* Sampling is greedy and there is no stop token: a request runs exactly
+  ``max_new_tokens``, so the thread knows every step's make-up without
+  reading a result. Each step's sampled ids stay on the device
+  (``last_ids``, one per slot) for the next step, and its outputs are
+  fetched one step LATE: the device always has the next step queued.
+* ``cancel_stream`` (a stream's DELETE) and ``stop`` resolve the
+  stream's futures with ``None`` and free its slots and pages at the
+  next step.
+
+A future resolves with ``{"ids", "top_ids", "top_logits",
+"prefix_tokens"}``: the generated ids and, per generated token, the 8
+largest logits with their ids.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import threading
+import time
+from collections import deque
+from concurrent.futures import Future
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from evam_tpu.engine.batcher import EngineStats
+from evam_tpu.engine.pages import PagePool
+from evam_tpu.models.lm import deepseek_v2 as lm
+from evam_tpu.obs import get_logger, metrics
+from evam_tpu.obs import trace
+from evam_tpu.sched.classes import DEFAULT_PRIORITY, PRIORITIES, SchedConfig
+from evam_tpu.sched.shedder import Shedder
+
+log = get_logger("engine.generate")
+
+#: prefill chunks that may run between two decode steps. With one (strict
+#: alternation) a chunk ran as soon as one prompt waited, part-empty at the
+#: price of a full one; with two a chunk can wait until it is full (505 of
+#: 512 tokens a chunk, +1.2 frames/s). It does NOT fill the slots: the
+#: runner decides how many generations reach the engine (PERF.md section
+#: 6, PR 28).
+MAX_PREFILL_RUN = 2
+
+#: decode programs: one every ``slots / DECODE_LADDER`` rows, up to the
+#: slots. A decode step costs about 7 ms + 0.2 ms a row of its BUCKET on
+#: a v5e, and the rows in service swing with the streams' phases: with
+#: 32/64/128 a step from 64 to 128 rows made the rate follow that swing
+#: (PERF.md section 6, PR 28).
+DECODE_LADDER = 8
+
+
+def next_step_kind(waiting: int, decoding: bool, prefill_run: int,
+                   passed_over: bool, chunk_tokens: int) -> str | None:
+    """The engine thread's next step. ``waiting``: prompt tokens not yet
+    prefilled; ``prefill_run``: prefill steps since the last decode step;
+    ``passed_over``: that decode step ran while prompts waited. Decode,
+    unless prompts wait and nothing decodes, or they fill a chunk or
+    were passed over and fewer than ``MAX_PREFILL_RUN`` chunks ran since
+    the last decode step."""
+    if not waiting:
+        return "decode" if decoding else None
+    if not decoding:
+        return "prefill"
+    if prefill_run < MAX_PREFILL_RUN and (passed_over
+                                          or waiting >= chunk_tokens):
+        return "prefill"
+    return "decode"
+
+
+@dataclasses.dataclass(frozen=True)
+class GenerateSizes:
+    """The engine's fixed shapes (config/settings.py ``LMSettings``:
+    the defaults are the deployment's, a rehearsal sets tiny ones). The
+    decode ladder follows from the slots."""
+
+    slots: int = 128
+    page_tokens: int = 128
+    chunk_tokens: int = 512
+    max_segments: int = 8
+    #: tokens a sequence may add to the prefix: prompt + generated
+    private_tokens: int = 384
+
+    @property
+    def slot_buckets(self) -> tuple[int, ...]:
+        step = max(1, self.slots // DECODE_LADDER)
+        return tuple(range(step, self.slots, step)) + (self.slots,)
+
+    @classmethod
+    def from_settings(cls, lm) -> "GenerateSizes":
+        return cls(slots=lm.slots, page_tokens=lm.page_tokens,
+                   chunk_tokens=lm.chunk_tokens,
+                   max_segments=lm.max_segments,
+                   private_tokens=lm.private_tokens)
+
+
+class _Seq:
+    """One request from submit to its future's result."""
+
+    __slots__ = ("prompt", "max_new", "stream", "priority", "trace",
+                 "future", "t_submit", "slot", "pages", "n_prefilled",
+                 "n_gen", "ids", "top_ids", "top_logits", "t_first",
+                 "t_prefilled", "cancelled")
+
+    def __init__(self, prompt, max_new, stream, priority, ftrace):
+        self.prompt = prompt
+        self.max_new = max_new
+        self.stream = stream
+        self.priority = priority
+        self.trace = ftrace
+        self.future: Future = Future()
+        self.t_submit = time.perf_counter()
+        self.slot = -1
+        self.pages: list[int] = []
+        #: prompt tokens / generated tokens whose step has been dispatched
+        self.n_prefilled = 0
+        self.n_gen = 0
+        self.ids: list[int] = []
+        self.top_ids: list[list[int]] = []
+        self.top_logits: list[list[float]] = []
+        self.t_first: float | None = None
+        self.t_prefilled: float | None = None
+        self.cancelled = False
+
+
+@dataclasses.dataclass
+class _Step:
+    """A dispatched step whose outputs are still on the device."""
+
+    kind: str
+    key: str
+    t_dispatch: float
+    tokens: int
+    rows_read: int
+    #: (row of the outputs, sequence) for every token this step sampled
+    takers: list
+    top: jax.Array
+    ids: jax.Array
+    held: jax.Array
+
+
+class GenerateEngine:
+    #: what the supervisor and the hub's rows read of any engine
+    assembly = "generate"
+    ragged = "off"
+
+    def __init__(self, name: str, model_cfg: dict, prefix_ids,
+                 sizes: GenerateSizes | None = None, plan=None,
+                 sched: SchedConfig | None = None,
+                 stall_timeout_s: float = 120.0,
+                 first_batch_grace: float = 10.0):
+        self.name = name
+        self.cfg = lm.Config.from_dict(model_cfg)
+        self.sizes = sz = sizes or GenerateSizes()
+        self.stall_timeout_s = stall_timeout_s
+        self.first_batch_grace = first_batch_grace
+        prefix = np.asarray(prefix_ids, np.int32)
+        if len(prefix) % sz.page_tokens:
+            raise ValueError(
+                f"the shared prefix ({len(prefix)} tokens) must fill whole "
+                f"pages of {sz.page_tokens}")
+        if len(prefix) and (prefix.min() < 0 or prefix.max() >= self.cfg.vocab):
+            raise ValueError("prefix ids outside the held vocabulary")
+        self.prefix = prefix
+        self.buckets = list(sz.slot_buckets)
+        self._device = (plan.mesh.devices.flat[0] if plan is not None
+                        else jax.devices()[0])
+        self._prefix_pages = len(prefix) // sz.page_tokens
+        self._private_pages = -(-sz.private_tokens // sz.page_tokens)
+        self._pool = PagePool(
+            1 + self._prefix_pages + sz.slots * self._private_pages,
+            sz.page_tokens)
+        self._shared = self._pool.pin(self._prefix_pages)
+        self.stats = EngineStats()
+        self.warmed = threading.Event()
+        self.warm_error: str | None = None
+        self.stalled = threading.Event()
+        self._stop = threading.Event()
+        self._shedder = (Shedder(name, sched.staleness_s())
+                         if sched is not None else None)
+        self._lock = threading.Lock()
+        self._wake = threading.Condition(self._lock)
+        #: under ``_lock``: submitted, not yet admitted to a slot
+        self._pending: dict[str, deque[_Seq]] = {
+            c: deque() for c in PRIORITIES}
+        self._cancel_streams: set[str] = set()
+        #: the engine thread's own
+        self._free_slots = list(range(sz.slots - 1, -1, -1))
+        self._prefilling: deque[_Seq] = deque()
+        self._decoding: list[_Seq] = []
+        self._inflight: deque[_Step] = deque()
+        #: prefill steps since the last decode step, and whether that
+        #: decode step passed waiting prompts over
+        self._prefill_run = 0
+        self._passed_over = False
+        self._t_free = time.perf_counter()
+        self._step_started: float | None = None
+        self._seen: set[str] = set()
+        #: per program: seconds of its last warm run (the capacity model)
+        self._program_s: dict[str, float] = {}
+        self._mean_prompt = 0.0
+        self._mean_new = 0.0
+        self._done = 0
+        self._outstanding: dict[int, _Seq] = {}
+        self._spans = trace.thread_spans(name, "generate")
+        self._params = None
+        self._cache = None
+        self._last_ids = None
+        self._build_programs()
+        self._thread = threading.Thread(
+            target=self._loop, name=f"engine-{name}-generate", daemon=True)
+        #: the supervisor's liveness checks name these three
+        self._dispatcher = self._completer = self._thread
+        self._launcher = None
+        self._thread.start()
+        self._warm_thread: threading.Thread | None = None
+        self._set_gauges()
+
+    # ------------------------------------------------------------ programs
+
+    def _build_programs(self) -> None:
+        cfg, sz = self.cfg, self.sizes
+        shared = (np.asarray(self._shared, np.int32)
+                  if self._prefix_pages else None)
+        n_cont = self._private_pages
+        n_seg = sz.max_segments
+
+        def prefill(params, cache, last_ids, mat, aux):
+            tokens, seg, pos, dest_page, dest_off = mat
+            cont = aux[:n_cont]
+            n_prefix, n_cont_rows = aux[n_cont], aux[n_cont + 1]
+            last_idx = aux[n_cont + 2:n_cont + 2 + n_seg]
+            last_slot = aux[n_cont + 2 + n_seg:]
+            cache, top, ids, held = lm.prefill_chunk(
+                cfg, params, cache, tokens, seg, pos, dest_page, dest_off,
+                shared, n_prefix, cont, n_cont_rows, last_idx)
+            return (cache, last_ids.at[last_slot].set(ids[:, 0]), top, ids,
+                    held)
+
+        def decode(params, cache, last_ids, mat, page_table):
+            slot, pos, ctx_len, dest_page, dest_off, live = mat
+            cache, top, ids, held = lm.decode_tokens(
+                cfg, params, cache, last_ids[slot], pos, page_table, ctx_len,
+                dest_page, dest_off, live > 0)
+            return cache, last_ids.at[slot].set(ids[:, 0]), top, ids, held
+
+        self._prefill = jax.jit(prefill, donate_argnums=(1, 2))
+        self._decode = jax.jit(decode, donate_argnums=(1, 2))
+
+    def _allocate(self) -> None:
+        """Weights, cache and the per-slot last ids, on the device."""
+        cfg, sz = self.cfg, self.sizes
+        with jax.default_device(self._device):
+            t0 = time.perf_counter()
+            self._params = lm.make_params(cfg)
+            self._cache = jnp.zeros(
+                (cfg.layers, self._pool.n_pages, sz.page_tokens, cfg.latent),
+                lm.BF16)
+            #: one more than the slots: rows that carry no sequence. A
+            #: slot's id is set by its sequence's prefill; until then
+            #: the ids differ, for the warm-up's loaded steps
+            self._last_ids = jnp.arange(
+                sz.slots + 1, dtype=jnp.int32) % cfg.vocab
+            jax.block_until_ready(self._params)
+        log.info(
+            "engine %s: %.2f G parameters and a cache of %d pages x %d "
+            "tokens x %d layers on %s in %.1f s", self.name,
+            lm.param_count(cfg) / 1e9, self._pool.n_pages, sz.page_tokens,
+            cfg.layers, self._device, time.perf_counter() - t0)
+
+    # ---------------------------------------------------------------- API
+
+    def submit(self, priority: str = DEFAULT_PRIORITY,
+               units: int | None = None, stream: str | None = None,
+               trace: "object | None" = None, *, prompt_ids,
+               max_new_tokens: int) -> Future:
+        """Queue one generation; the future resolves with the result
+        dict, with ``None`` if the stream was cancelled, or raises
+        ``ShedError`` if the wait for a slot outlasted the class's
+        staleness budget."""
+        if self._stop.is_set():
+            raise RuntimeError(f"engine {self.name} is stopped")
+        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
+        n, sz = len(prompt), self.sizes
+        if not 1 <= n or max_new_tokens < 1:
+            raise ValueError("a generation needs a prompt and a length")
+        if n + max_new_tokens - 1 > sz.private_tokens:
+            raise ValueError(
+                f"{n} prompt + {max_new_tokens} new tokens do not fit the "
+                f"{sz.private_tokens} a sequence may add to the prefix")
+        if prompt.min() < 0 or prompt.max() >= self.cfg.vocab:
+            raise ValueError("prompt ids outside the held vocabulary")
+        seq = _Seq(prompt, int(max_new_tokens), stream,
+                   priority if priority in PRIORITIES else DEFAULT_PRIORITY,
+                   trace)
+        with self._wake:
+            self._pending[seq.priority].append(seq)
+            self._outstanding[id(seq)] = seq
+            self._wake.notify()
+        return seq.future
+
+    def cancel_stream(self, stream: str) -> None:
+        """Drop every sequence of ``stream`` at the next step: futures
+        resolve with None, slots and pages come back."""
+        with self._wake:
+            self._cancel_streams.add(stream)
+            self._wake.notify()
+
+    def warm_async(self, **_example) -> None:
+        """Allocate, compile every program, prefill the shared prefix,
+        then serve. ``warmed`` is set when all of it is done."""
+        if self._warm_thread is not None:
+            return
+        self._warm_thread = threading.Thread(
+            target=self._warm_guarded, name=f"engine-{self.name}-warm",
+            daemon=True)
+        self._warm_thread.start()
+
+    def set_example(self, **_example) -> None:
+        """No background warm-up was asked for: the first submit pays
+        it (tests)."""
+        self.warm_async()
+
+    def _warm_guarded(self) -> None:
+        try:
+            self._warm()
+        except Exception as exc:  # noqa: BLE001 - reported on /engines
+            log.exception("engine %s warm-up failed", self.name)
+            self.warm_error = f"{type(exc).__name__}: {exc}"
+        finally:
+            self.warmed.set()
+
+    def _warm(self) -> None:
+        sz = self.sizes
+        self._allocate()
+        # the prefix, through the prefill program itself: chunk i attends
+        # to the prefix rows the chunks before it wrote
+        t0 = time.perf_counter()
+        flat = [p * sz.page_tokens + o for p in self._shared
+                for o in range(sz.page_tokens)]
+        for lo in range(0, len(self.prefix), sz.chunk_tokens):
+            part = self.prefix[lo:lo + sz.chunk_tokens]
+            dest = flat[lo:lo + len(part)]
+            self._harvest(self._dispatch_prefill_raw(
+                part, np.zeros(len(part), np.int32),
+                np.arange(lo, lo + len(part)), dest, n_prefix=lo,
+                cont=None, n_cont=0, last=[], takers=[]), count=False)
+        log.info("engine %s: shared prefix of %d tokens prefilled in %.1f s",
+                 self.name, len(self.prefix), time.perf_counter() - t0)
+        # every program LOADED as in service: every row a token of its
+        # own (``_allocate``'s last ids: the held experts are reached),
+        # written to the null page. Once to compile, once more for the
+        # capacity model's step times.
+        n = sz.chunk_tokens
+        per = -(-n // sz.max_segments)
+        chunk = (np.arange(n) % self.cfg.vocab, np.arange(n) // per,
+                 len(self.prefix) + np.arange(n) % per,
+                 np.arange(n) % sz.page_tokens)
+        for timed in (False, True):
+            for b in self.buckets:
+                t0 = time.perf_counter()
+                self._harvest(self._dispatch_decode_raw(
+                    [(slot, 0, [0]) for slot in range(b)], b, []),
+                    count=False)
+                self._program_s[f"decode:{b}"] = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            self._harvest(self._dispatch_prefill_raw(
+                *chunk, len(self.prefix), None, 0, [], []), count=False)
+            self._program_s["prefill"] = time.perf_counter() - t0
+
+    def stop(self) -> None:
+        self._stop.set()
+        with self._wake:
+            self._wake.notify()
+        if self._thread.is_alive():
+            self._thread.join(timeout=30)
+        self._fail_all(None)
+
+    def abandon(self) -> None:
+        """The supervisor's quarantine: resolve what can be resolved,
+        join nothing."""
+        self._stop.set()
+        with self._wake:
+            self._wake.notify()
+        self._fail_all(TimeoutError(
+            f"engine {self.name} was abandoned after a stall"))
+
+    def _fail_all(self, exc: Exception | None) -> None:
+        with self._lock:
+            seqs = list(self._outstanding.values())
+            self._outstanding.clear()
+        for seq in seqs:
+            if seq.future.done():
+                continue
+            try:
+                if exc is None:
+                    seq.future.set_result(None)
+                else:
+                    seq.future.set_exception(exc)
+            except Exception:  # noqa: BLE001 - resolved meanwhile
+                pass
+
+    # ------------------------------------------------- the hub's row reads
+
+    def queue_depth(self) -> int:
+        with self._lock:
+            return sum(len(q) for q in self._pending.values())
+
+    def queue_age_s(self) -> float:
+        with self._lock:
+            heads = [q[0].t_submit for q in self._pending.values() if q]
+        return time.perf_counter() - min(heads) if heads else 0.0
+
+    def class_depths(self) -> dict[str, int]:
+        with self._lock:
+            return {c: len(q) for c, q in self._pending.items()}
+
+    def shed_counts(self) -> dict[str, int]:
+        if self._shedder is None:
+            return {c: 0 for c in PRIORITIES}
+        return dict(self._shedder.counts)
+
+    def thread_states(self) -> dict[str, tuple]:
+        return {"generate": self._spans.where()}
+
+    def retune(self, op) -> None:
+        """No structural knob of the control plane applies here."""
+
+    def refresh_queue_gauges(self) -> None:
+        """The supervisor's 0.1 s poll: the backlog gauges, and the
+        stall check (a step that has not come back in
+        ``stall_timeout_s``, times ``first_batch_grace`` while its
+        program may still be compiling)."""
+        metrics.set("evam_engine_queue_depth", self.queue_depth(),
+                    {"engine": self.name})
+        metrics.set("evam_engine_queue_age_s", self.queue_age_s(),
+                    {"engine": self.name})
+        started = self._step_started
+        if started is None or self.stalled.is_set():
+            return
+        limit = self.stall_timeout_s * (
+            1.0 if self.warmed.is_set() else self.first_batch_grace)
+        if time.perf_counter() - started > limit:
+            log.error("engine %s: a step has not returned in %.0f s",
+                      self.name, limit)
+            self.stalled.set()
+
+    def capacity_fps(self) -> float:
+        """Generations a second with every slot busy, from the requests
+        seen and two step times: a generation costs its prompt's share
+        of a full prefill chunk and, per new token, one row of the
+        decode step over all slots. The times are those of loaded steps
+        (``_warm``), moved on by every step served since. 0 before the
+        first request ends (cold: admission admits)."""
+        t_decode = self._program_s.get(f"decode:{self.sizes.slots}")
+        if not self._done or t_decode is None:
+            return 0.0
+        sz = self.sizes
+        per = (self._mean_prompt / sz.chunk_tokens * self._program_s["prefill"]
+               + (self._mean_new - 1) * t_decode / sz.slots)
+        return 1.0 / per if per > 0 else 0.0
+
+    def pages_in_use(self) -> tuple[int, int]:
+        return self._pool.in_use, self._pool.capacity
+
+    # --------------------------------------------------------- the thread
+
+    def _loop(self) -> None:
+        spans = self._spans
+        while not self.warmed.wait(0.05):
+            if self._stop.is_set():
+                return
+        if self.warm_error is not None:
+            return  # nothing to serve with: the supervisor sees it
+        try:
+            while not self._stop.is_set():
+                self._take_cancels()
+                self._admit()
+                kind = self._next_kind()
+                if kind is None:
+                    if self._inflight:
+                        self._harvest(self._inflight.popleft())
+                        continue
+                    spans.to("wait_requests")
+                    with self._wake:
+                        if not self._has_pending() and not self._stop.is_set():
+                            self._wake.wait(0.05)
+                    continue
+                spans.to("step")
+                step = (self._dispatch_prefill() if kind == "prefill"
+                        else self._dispatch_decode())
+                if kind == "prefill":
+                    self._prefill_run += 1
+                    self._passed_over = False
+                else:
+                    self._prefill_run = 0
+                    self._passed_over = bool(self._prefilling)
+                self._inflight.append(step)
+                while len(self._inflight) > 1:
+                    self._harvest(self._inflight.popleft())
+        except Exception:  # noqa: BLE001 - the supervisor sees a dead thread
+            log.exception("engine %s: the generate thread died", self.name)
+            self._fail_all(RuntimeError(
+                f"engine {self.name}: the generate thread died"))
+        finally:
+            spans.to(None)
+
+    def _has_pending(self) -> bool:
+        return any(self._pending.values()) or bool(self._cancel_streams)
+
+    def _next_kind(self) -> str | None:
+        return next_step_kind(
+            sum(len(s.prompt) - s.n_prefilled for s in self._prefilling),
+            bool(self._decoding), self._prefill_run, self._passed_over,
+            self.sizes.chunk_tokens)
+
+    def _take_cancels(self) -> None:
+        with self._lock:
+            if not self._cancel_streams:
+                return
+            streams, self._cancel_streams = self._cancel_streams, set()
+            dropped = []
+            for q in self._pending.values():
+                keep = [s for s in q if s.stream not in streams]
+                dropped += [s for s in q if s.stream in streams]
+                q.clear()
+                q.extend(keep)
+        for seq in (*self._prefilling, *self._decoding):
+            if seq.stream in streams:
+                dropped.append(seq)
+                self._release(seq)
+        self._prefilling = deque(
+            s for s in self._prefilling if s.stream not in streams)
+        self._decoding = [s for s in self._decoding
+                          if s.stream not in streams]
+        for seq in dropped:
+            seq.cancelled = True
+            self._resolve(seq, None)
+        self._set_gauges()
+
+    def _admit(self) -> None:
+        """Waiting requests into free slots, the better class first;
+        those that waited past their class's budget are shed."""
+        with self._lock:
+            for cls in PRIORITIES:
+                q = self._pending[cls]
+                if q and self._shedder is not None:
+                    fresh = self._shedder.shed(cls, list(q))
+                    if len(fresh) != len(q):
+                        for seq in set(q) - set(fresh):
+                            self._outstanding.pop(id(seq), None)
+                        q.clear()
+                        q.extend(fresh)
+                while q and self._free_slots:
+                    seq = q[0]
+                    pages = self._pool.alloc(self._pool.pages_for(
+                        len(seq.prompt) + seq.max_new - 1))
+                    if pages is None:
+                        return
+                    q.popleft()
+                    seq.slot = self._free_slots.pop()
+                    seq.pages = pages
+                    self._prefilling.append(seq)
+        self._set_gauges()
+
+    def _release(self, seq: _Seq) -> None:
+        """The sequence's slot and pages, back. Steps already queued on
+        the device run before whatever uses them next."""
+        if seq.slot >= 0:
+            self._free_slots.append(seq.slot)
+            self._pool.free(seq.pages)
+            seq.slot, seq.pages = -1, []
+
+    def _set_gauges(self) -> None:
+        metrics.set("evam_generate_slots_active",
+                    self.sizes.slots - len(self._free_slots))
+        metrics.set("evam_generate_pages_in_use", self._pool.in_use)
+
+    # ------------------------------------------------------------ dispatch
+
+    def _where(self, pages: list[int], k: int) -> int:
+        """Flat cache row (page * page_tokens + offset) of the ``k``-th
+        own token of the sequence that holds ``pages``."""
+        pt = self.sizes.page_tokens
+        return pages[k // pt] * pt + k % pt
+
+    def _dispatch_prefill(self) -> _Step:
+        """Pack prompt tokens of the waiting sequences, in order, into
+        one chunk. Only the chunk's first segment may continue a prompt
+        begun in an earlier chunk."""
+        sz = self.sizes
+        tokens, seg, pos, dest, last, takers = [], [], [], [], [], []
+        cont, n_cont = None, 0
+        now = time.perf_counter()
+        while (self._prefilling and len(tokens) < sz.chunk_tokens
+               and len(last) < sz.max_segments):
+            seq = self._prefilling[0]
+            s = len(last)
+            if seq.n_prefilled and s:
+                break  # a continued prompt opens its own chunk
+            if seq.t_first is None:
+                seq.t_first = now
+                metrics.observe("evam_generate_queue_wait_seconds",
+                                now - seq.t_submit)
+            if seq.n_prefilled:
+                cont, n_cont = seq.pages, seq.n_prefilled
+            take = min(len(seq.prompt) - seq.n_prefilled,
+                       sz.chunk_tokens - len(tokens))
+            for k in range(seq.n_prefilled, seq.n_prefilled + take):
+                tokens.append(seq.prompt[k])
+                seg.append(s)
+                pos.append(len(self.prefix) + k)
+                dest.append(self._where(seq.pages, k))
+            seq.n_prefilled += take
+            if seq.n_prefilled == len(seq.prompt):
+                self._prefilling.popleft()
+                seq.n_gen = 1
+                last.append((len(tokens) - 1, seq.slot))
+                takers.append((s, seq))
+                if seq.max_new > 1:
+                    self._decoding.append(seq)
+                else:
+                    self._release(seq)
+            else:
+                last.append((0, sz.slots))
+        return self._dispatch_prefill_raw(
+            tokens, seg, pos, dest, len(self.prefix), cont, n_cont, last,
+            takers)
+
+    def _dispatch_prefill_raw(self, tokens, seg, pos, dest, n_prefix, cont,
+                              n_cont, last, takers) -> _Step:
+        sz = self.sizes
+        n = len(tokens)
+        mat = np.zeros((5, sz.chunk_tokens), np.int32)
+        mat[1] = -1
+        mat[0, :n], mat[1, :n], mat[2, :n] = tokens, seg, pos
+        mat[3, :n] = np.asarray(dest, np.int64) // sz.page_tokens
+        mat[4, :n] = np.asarray(dest, np.int64) % sz.page_tokens
+        aux = np.zeros(self._private_pages + 2 + 2 * sz.max_segments,
+                       np.int32)
+        if cont is not None:
+            aux[:len(cont)] = cont
+        at = self._private_pages
+        aux[at], aux[at + 1] = n_prefix, n_cont
+        aux[at + 2 + sz.max_segments:] = sz.slots
+        for i, (idx, slot) in enumerate(last):
+            aux[at + 2 + i] = idx
+            aux[at + 2 + sz.max_segments + i] = slot
+        # rows of the cache the chunk reads, per layer: the prefix once
+        # (all its tokens share it) and one sequence's earlier rows
+        return self._run("prefill", "prefill", self._prefill, (mat, aux),
+                         tokens=n, rows_read=(n_prefix + n_cont) if n else 0,
+                         takers=takers)
+
+    def _dispatch_decode(self) -> _Step:
+        seqs = self._decoding
+        bucket = next(b for b in self.buckets if b >= len(seqs))
+        # the token fed back is the sequence's newest
+        step = self._dispatch_decode_raw(
+            [(s.slot, len(s.prompt) + s.n_gen - 1, s.pages) for s in seqs],
+            bucket, list(enumerate(seqs)))
+        still = []
+        for seq in seqs:
+            seq.n_gen += 1
+            if seq.n_gen < seq.max_new:
+                still.append(seq)
+            else:
+                self._release(seq)
+        self._decoding = still
+        self._set_gauges()
+        return step
+
+    def _dispatch_decode_raw(self, rows, bucket: int, takers) -> _Step:
+        """``rows``: (slot, index of the own token fed back, own pages)
+        of each row that carries a sequence."""
+        sz = self.sizes
+        n_pages = self._prefix_pages + self._private_pages
+        mat = np.zeros((6, bucket), np.int32)
+        mat[0] = sz.slots
+        mat[2] = 1
+        table = np.zeros((bucket, n_pages), np.int32)
+        rows_read = 0
+        for b, (slot, k, pages) in enumerate(rows):
+            row = self._where(pages, k)
+            mat[:, b] = (slot, len(self.prefix) + k,
+                         len(self.prefix) + k + 1, row // sz.page_tokens,
+                         row % sz.page_tokens, 1)
+            table[b, :self._prefix_pages] = self._shared
+            table[b, self._prefix_pages:self._prefix_pages
+                  + len(pages)] = pages
+            rows_read += len(self.prefix) + k + 1
+        return self._run("decode", f"decode:{bucket}", self._decode,
+                         (mat, table), tokens=len(rows), rows_read=rows_read,
+                         takers=takers)
+
+    def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
+             takers) -> _Step:
+        t0 = time.perf_counter()
+        self._step_started = t0
+        cold = key not in self._seen
+        self._cache, self._last_ids, top, ids, held = fn(
+            self._params, self._cache, self._last_ids, *inputs)
+        for out in (top, ids, held):
+            out.copy_to_host_async()
+        if cold:
+            # the call returns when the program is compiled
+            self._seen.add(key)
+            self.stats.compiled_programs += 1
+            self.stats.compile_seconds += time.perf_counter() - t0
+        return _Step(kind, key, t0, tokens, rows_read, takers, top, ids, held)
+
+    # ------------------------------------------------------------- harvest
+
+    def _harvest(self, step: _Step, count: bool = True) -> None:
+        """Fetch a step's outputs (waits for the step), hand its sampled
+        tokens to their sequences, resolve those that are complete."""
+        top = np.asarray(step.top)
+        ids = np.asarray(step.ids)
+        held = int(step.held)
+        now = time.perf_counter()
+        self._step_started = (self._inflight[0].t_dispatch
+                              if self._inflight else None)
+        # the device ran this step from when it was free (the step before
+        # came back) or from this step's dispatch, whichever was later
+        dt = now - max(self._t_free, step.t_dispatch)
+        self._t_free = now
+        if not count:
+            return
+        labels = {"kind": step.kind}
+        metrics.observe("evam_generate_step_seconds", dt, labels)
+        metrics.inc("evam_generate_steps", 1.0, labels)
+        metrics.inc("evam_generate_tokens", float(step.tokens), labels)
+        metrics.inc("evam_generate_latent_rows_read",
+                    float(step.rows_read), labels)
+        metrics.inc("evam_moe_held_assignments", float(held))
+        st = self.stats
+        st.batches += 1
+        st.add_stage("launch", dt)
+        if step.kind == "decode":
+            bucket = int(step.key.split(":")[1])
+            st.bucket_batches[bucket] = st.bucket_batches.get(bucket, 0) + 1
+            st.occupancy_sum += step.tokens / bucket
+        else:
+            st.occupancy_sum += step.tokens / self.sizes.chunk_tokens
+        self._program_s[step.key] += 0.1 * (dt - self._program_s[step.key])
+        for row, seq in step.takers:
+            if seq.cancelled:
+                continue
+            seq.ids.append(int(ids[row, 0]))
+            seq.top_ids.append(ids[row].tolist())
+            seq.top_logits.append(top[row].tolist())
+            if step.kind == "prefill":
+                seq.t_prefilled = now
+            if len(seq.ids) == seq.max_new:
+                self._finish(seq, now)
+
+    def _finish(self, seq: _Seq, now: float) -> None:
+        self.stats.items += 1
+        self._done += 1
+        a = 1.0 / min(self._done, 64)
+        self._mean_prompt += a * (len(seq.prompt) - self._mean_prompt)
+        self._mean_new += a * (seq.max_new - self._mean_new)
+        ft = seq.trace
+        if ft is not None:
+            ft.add_span("generate.queue_wait", seq.t_submit,
+                        seq.t_first - seq.t_submit)
+            ft.add_span("generate.prefill", seq.t_first,
+                        seq.t_prefilled - seq.t_first)
+            ft.add_span("generate.decode", seq.t_prefilled,
+                        now - seq.t_prefilled)
+        self._resolve(seq, {
+            "ids": seq.ids, "top_ids": seq.top_ids,
+            "top_logits": seq.top_logits,
+            "prefix_tokens": int(len(self.prefix))})
+
+    def _resolve(self, seq: _Seq, result) -> None:
+        with self._lock:
+            self._outstanding.pop(id(seq), None)
+        try:
+            seq.future.t_resolved = time.perf_counter()
+            seq.future.set_result(result)
+        except Exception:  # noqa: BLE001 - failed by abandon meanwhile
+            pass

@@ -63,6 +63,7 @@ class EngineHub:
         fleet_shard_max_batch: int = 0,
         fleet_max_shards: int = 0,
         fleet_initial_shards: int = 0,
+        lm=None,
     ):
         #: serving sets True: stages precompile every batch bucket in
         #: the background right after engine creation
@@ -158,6 +159,13 @@ class EngineHub:
         #: between 1 and the ceiling. 0 = all plan devices (the
         #: pre-autoscaling behavior).
         self.fleet_initial_shards = fleet_initial_shards
+        #: the generate engine's shapes (config/settings.py LMSettings;
+        #: None = its defaults). Part of the rebuild recipe.
+        if lm is None:
+            from evam_tpu.config.settings import LMSettings
+
+            lm = LMSettings()
+        self.lm = lm
         self._engines: dict[str, BatchEngine | SupervisedEngine] = {}
         #: device_synth only: engine key → the (H, W) its on-chip
         #: generator was compiled for (cache-hit mismatch guard)
@@ -243,6 +251,41 @@ class EngineHub:
                 log.info("created fused engine %s", key)
             elif self.device_synth and synth_hw is not None:
                 self._check_synth_hw(key, synth_hw)
+            return self._engines[key]
+
+    def generate_engine(self, model_key: str,
+                        instance_id: str | None = None, *, prefix_ids):
+        """Get or create the generate engine (engine/generate.py) of a
+        language model: many device steps per request, a page cache on
+        the device, ``prefix_ids`` prefilled once and shared by every
+        sequence. Supervised and listed like any engine. It lives on the
+        plan's first device: one chip's share of the model is one
+        chip's."""
+        key = f"generate:{instance_id or model_key}"
+        with self._lock:
+            if key not in self._engines:
+                from evam_tpu.engine.generate import (
+                    GenerateEngine,
+                    GenerateSizes,
+                )
+
+                cfg = self.registry.lm_config(model_key)
+                sizes = GenerateSizes.from_settings(self.lm)
+
+                def factory():
+                    return GenerateEngine(
+                        key, cfg, prefix_ids, sizes=sizes, plan=self.plan,
+                        sched=self.sched,
+                        stall_timeout_s=self.stall_timeout_s,
+                        first_batch_grace=self.first_batch_grace)
+
+                self._engines[key] = (
+                    SupervisedEngine(
+                        key, factory, max_restarts=self.max_restarts,
+                        restart_window_s=self.restart_window_s,
+                        backoff_s=self.restart_backoff_s)
+                    if self.supervise else factory())
+                log.info("created engine %s (model %s)", key, model_key)
             return self._engines[key]
 
     def _ragged_spec(self, kind: str, builder_kwargs: dict
@@ -363,7 +406,8 @@ class EngineHub:
     @staticmethod
     def _stat_row(e, shard: str | None, device: str | None,
                   group: str) -> dict:
-        return {
+        own_capacity = getattr(e, "capacity_fps", None)
+        row = {
             "batches": e.stats.batches,
             "items": e.stats.items,
             "mean_occupancy": e.stats.mean_occupancy,
@@ -425,6 +469,12 @@ class EngineHub:
             "device": device,
             "group": group,
         }
+        if own_capacity is not None:
+            # a generate engine models its own capacity
+            # (sched/admission.py reads it), and holds pages
+            row["capacity_fps"] = round(own_capacity(), 2)
+            row["pages_in_use"], row["pages"] = e.pages_in_use()
+        return row
 
     def _rows(self):
         """(row key, engine, shard label, device, group) per /engines

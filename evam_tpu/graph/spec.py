@@ -32,6 +32,7 @@ class StageKind(str, enum.Enum):
     AUDIO_DETECT = "audio_detect"  # gvaaudiodetect
     AUDIO_MIX = "audio_mix"  # audiomixer (windowing)
     LEVEL = "level"          # level (RMS messages)
+    DESCRIBE = "describe"    # language model over the frame's detections
     UDF = "udf"              # gvapython user extension
     METACONVERT = "metaconvert"  # gvametaconvert → JSON meta
     PUBLISH = "publish"      # gvametapublish → destination
@@ -104,4 +105,7 @@ class PipelineSpec:
                     problems.append(f"action stage '{s.name}' missing enc/dec model")
             elif not s.model:
                 problems.append(f"inference stage '{s.name}' has no model reference")
+        for s in self.stages:
+            if s.kind == StageKind.DESCRIBE and not s.model:
+                problems.append(f"describe stage '{s.name}' has no model reference")
         return problems

@@ -276,6 +276,29 @@ def synthesize_omz(
     return 0
 
 
+def synthesize_lm(output: str | Path, alias: str, version: str = "1",
+                  preset: str = "deepseek_v2_ep8") -> int:
+    """``fetch-models --synthesize-lm``: install a language model as its
+    config file (models/lm/presets.py). The weights are made on the
+    device from the seed in that file when an engine is built, so the
+    file is all there is to install."""
+    import json
+
+    from evam_tpu.models.lm.presets import PRESETS
+
+    if preset not in PRESETS:
+        raise ValueError(
+            f"unknown language-model preset {preset!r} "
+            f"({'|'.join(sorted(PRESETS))})")
+    target = Path(output) / alias / version
+    target.mkdir(parents=True, exist_ok=True)
+    (target / "lm_config.json").write_text(
+        json.dumps(PRESETS[preset], indent=1))
+    log.info("installed language model %s/%s (%s) -> %s", alias, version,
+             preset, target)
+    return 0
+
+
 def _synthesize_manifest(output: str | Path, precision: str = "FP32") -> int:
     """``--synthesize-omz --topology manifest``: materialize IR-backed
     stand-ins for EVERY model in the reference manifest

@@ -1,0 +1,68 @@
+"""Model config files that ``fetch-models --synthesize-lm`` installs.
+
+``deepseek_v2_ep8`` is DeepSeek-V2's published config
+(https://huggingface.co/deepseek-ai/DeepSeek-V2/blob/main/config.json)
+with every width as published, cut to what ONE chip of an 8-way
+expert-parallel deployment holds of six layers: the router keeps its 160
+outputs, 8 groups, top-3 groups and top-6 experts; the chip holds routing
+group ``held_group`` (20 experts), an eighth of the vocabulary, the
+leading dense layer and five expert layers. ``deepseek_v2_tiny`` has the
+same structure at a size a CPU test runs.
+"""
+
+from __future__ import annotations
+
+DEEPSEEK_V2_PUBLISHED = {
+    "attention_bias": False, "first_k_dense_replace": 1,
+    "hidden_act": "silu", "hidden_size": 5120, "intermediate_size": 12288,
+    "kv_lora_rank": 512, "max_position_embeddings": 163840,
+    "model_type": "deepseek_v2", "moe_intermediate_size": 1536,
+    "moe_layer_freq": 1, "n_group": 8, "n_routed_experts": 160,
+    "n_shared_experts": 2, "norm_topk_prob": False,
+    "num_attention_heads": 128, "num_experts_per_tok": 6,
+    "num_hidden_layers": 60, "num_key_value_heads": 128,
+    "q_lora_rank": 1536, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "rms_norm_eps": 1e-06,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 0.707, "mscale_all_dim": 0.707,
+                     "original_max_position_embeddings": 4096,
+                     "type": "yarn"},
+    "rope_theta": 10000, "routed_scaling_factor": 16,
+    "scoring_func": "softmax", "seq_aux": True,
+    "tie_word_embeddings": False, "topk_group": 3,
+    "topk_method": "group_limited_greedy", "v_head_dim": 128,
+    "vocab_size": 102400,
+}
+
+PRESETS = {
+    "deepseek_v2_ep8": {
+        **DEEPSEEK_V2_PUBLISHED,
+        # the cut: depth, the chip's share of the experts and vocabulary
+        "num_hidden_layers": 6,
+        "held_group": 0,
+        "vocab_held": 12800,
+        "weights_seed": 20240507,
+        "initializer_range": 0.02,
+    },
+    "deepseek_v2_tiny": {
+        **DEEPSEEK_V2_PUBLISHED,
+        "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "kv_lora_rank": 32,
+        "q_lora_rank": 48, "num_attention_heads": 4,
+        "num_key_value_heads": 4, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "n_group": 4, "topk_group": 2, "n_routed_experts": 16,
+        "num_experts_per_tok": 3, "num_hidden_layers": 3,
+        "vocab_size": 512,
+        "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                         "mscale": 0.707, "mscale_all_dim": 0.707,
+                         "original_max_position_embeddings": 16,
+                         "type": "yarn"},
+        "held_group": 0,
+        "vocab_held": 128,
+        "weights_seed": 7,
+        # 0.02 at width 64 leaves every score flat; the tests need terms
+        # that matter
+        "initializer_range": 0.15,
+    },
+}

@@ -350,7 +350,30 @@ class ModelRegistry:
         if self.models_dir and self.models_dir.exists():
             for xml in self.models_dir.glob("*/*/*/*.xml"):
                 keys.add(f"{xml.parts[-4]}/{xml.parts[-3]}")
+            for cfg in self.models_dir.glob("*/*/lm_config.json"):
+                keys.add(f"{cfg.parts[-3]}/{cfg.parts[-2]}")
         return sorted(keys)
+
+    def _lm_config_path(self, key: str) -> Path | None:
+        if not self.models_dir:
+            return None
+        path = self.models_dir / key / "lm_config.json"
+        return path if path.is_file() else None
+
+    def lm_config(self, key: str) -> dict:
+        """The config file of a language model (models/lm/), as
+        ``fetch-models --synthesize-lm`` installed it under
+        ``{alias}/{version}/lm_config.json``. Its weights are made on
+        the device from the seed it names."""
+        path = self._lm_config_path(key)
+        if path is None:
+            raise KeyError(
+                f"unknown language model '{key}': no lm_config.json under "
+                f"{self.models_dir} (fetch-models --synthesize-lm installs "
+                "one)")
+        import json
+
+        return json.loads(path.read_text())
 
     def _load(self, key: str) -> LoadedModel:
         ir_xml = self._ir_xml_path(key)
@@ -569,6 +592,8 @@ class ModelRegistry:
                     if (xml.parent / "weights.msgpack").exists()
                     else "ir-bin"
                 )
+            elif self._lm_config_path(key) is not None:
+                weights = "seeded"  # made on the device from the config's seed
             elif (spec := ZOO_SPECS.get(key)) is not None \
                     and self._weights_path(spec) is not None:
                 weights = "msgpack"

@@ -51,6 +51,17 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_engine_state": ("gauge", ("engine",)),
     "evam_engine_restarts": ("counter", ("engine",)),
     "evam_engine_oversize_splits": ("counter", ("engine",)),
+    # the generate engine (engine/generate.py): device steps by kind
+    # (prefill | decode), what they carried and read, the wait for the
+    # first prefill chunk, and what the engine holds
+    "evam_generate_step_seconds": ("histogram", ("kind",)),
+    "evam_generate_steps": ("counter", ("kind",)),
+    "evam_generate_tokens": ("counter", ("kind",)),
+    "evam_generate_latent_rows_read": ("counter", ("kind",)),
+    "evam_generate_queue_wait_seconds": ("histogram", ()),
+    "evam_generate_slots_active": ("gauge", ()),
+    "evam_generate_pages_in_use": ("gauge", ()),
+    "evam_moe_held_assignments": ("counter", ()),
     # QoS scheduling
     "evam_sched_admitted": ("counter", ("class",)),
     "evam_sched_rejected": ("counter", ("class",)),

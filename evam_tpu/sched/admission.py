@@ -235,6 +235,11 @@ class AdmissionController:
     def _row_capacity_fps(self, stats: dict) -> float:
         """One stats row's items per device-path second; 0 = the
         steady-state stage clock has no sample of it."""
+        own = stats.get("capacity_fps")
+        if own is not None:
+            # an engine whose requests take many steps (engine/generate.py)
+            # models its own: items per second with every slot busy
+            return float(own)
         batches = stats.get("batches")
         if not batches:
             return 0.0

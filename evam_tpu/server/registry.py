@@ -97,6 +97,7 @@ class PipelineRegistry:
                 fleet_initial_shards=(
                     settings.tpu.fleet_shards
                     if settings.tpu.fleet_max_shards > 0 else 0),
+                lm=settings.lm,
             )
         self.hub = hub
         #: QoS layer (evam_tpu/sched/): the hub's sched config is the

@@ -33,6 +33,11 @@ class Stage:
     def close(self) -> None:
         pass
 
+    def cancel(self) -> None:
+        """The stream is stopping: give up work still in flight for it
+        (``StreamRunner.stop``). Its parked futures then resolve at
+        once."""
+
     # ---- stream-state checkpointing (SURVEY §5.4 + §7 "tracking
     # statefulness"): stages with cross-frame state can round-trip a
     # JSON-serializable snapshot through the stream registry's

@@ -97,6 +97,13 @@ def build_stages(
             stages.append(
                 AudioDetectStage(spec.name, spec.model, spec.properties, hub)
             )
+        elif kind == StageKind.DESCRIBE:
+            # imported here: a server without such a stage loads nothing
+            # of the language-model path
+            from evam_tpu.stages.describe import DescribeStage
+
+            stages.append(
+                DescribeStage(spec.name, spec.model, spec.properties, hub))
         elif kind == StageKind.UDF:
             stages.append(UdfStage(spec.name, spec.properties))
         elif kind == StageKind.METACONVERT:

@@ -82,6 +82,8 @@ class StreamRunner:
 
     def stop(self) -> None:
         self._stopped = True
+        for stage in self.stages:
+            stage.cancel()
 
     def feed(self, ev: FrameEvent) -> None:
         self.frames_in += 1

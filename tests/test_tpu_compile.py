@@ -1,0 +1,40 @@
+"""Compiles for a DESCRIBED v5e (no chip attached): what Mosaic refuses
+at the published widths, it refuses here, at no chip time. Keep every
+such test in THIS file: one worker loads the TPU's library, inside the
+fixture, never while a module is imported."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows,keys", [
+    (512 * 128, 2048 + 384 + 512),   # a prefill chunk, every head
+    (64 * 128, 512 + 64),            # a short chunk, ragged key count
+])
+def test_latent_attention_kernel_compiles_at_published_widths(
+        one_chip, rows, keys):
+    from evam_tpu.ops.pallas_mla import latent_attention
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: latent_attention(*a, scale=0.1147, b0=2048)).lower(
+        s((rows, 512), jnp.bfloat16), s((rows, 64), jnp.bfloat16),
+        s((keys, 512), jnp.bfloat16), s((keys, 64), jnp.bfloat16),
+        s((rows, 4), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
