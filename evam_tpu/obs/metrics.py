@@ -105,6 +105,12 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # due time until it is fed
     "evam_collect_wait_seconds": ("histogram", ()),
     "evam_source_lag_seconds": ("histogram", ()),
+    # parked frames a stream's chain thread resumed, by what the one
+    # in-order window would have done with the result at that moment:
+    # "resolve" (left it lying: only the future's signal brought the
+    # chain there), "feed" (found it: a frame was being fed, or a feed
+    # stood blocked on a full window), "drain"
+    "evam_runner_resumes": ("counter", ("by",)),
     # wall seconds of the dispatch/launch/complete threads by state
     # ("work", or the wait named by what it waits for) and CPU seconds
     # of the dispatcher's and completer's work stretches

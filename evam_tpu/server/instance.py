@@ -4,8 +4,8 @@ TPU restatement of the reference's per-instance lifecycle
 (`pipeline.start(source, destination, parameters)` → instance with
 status/stop — evas/manager.py:134-146 and the REST contract
 charts/templates/NOTES.txt:7-21). The instance owns only light host
-work: a decode thread walking the stage chain via StreamRunner; all
-inference rides the shared EngineHub batch queues. A dying stream
+work: a decode thread feeding a StreamRunner, whose chain thread walks
+the stage chain; all inference rides the shared EngineHub batch queues. A dying stream
 never takes the engine down (per-stream supervision, SURVEY.md §5.3).
 """
 

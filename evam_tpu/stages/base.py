@@ -1,11 +1,16 @@
 """Stage interfaces.
 
 Sync stages transform a FrameContext inline; async stages submit work
-to a shared BatchEngine and are resumed by the StreamRunner when the
-batch containing their item completes. The async split is what lets
-one stream keep multiple frames in flight (overlapping decode,
-batching and TPU steps — the role GStreamer queues play between
-elements in the reference, SURVEY.md §2d-5).
+to a shared engine and park the frame in their own in-order queue. The
+StreamRunner resumes a queue's head when its future resolves, so each
+stage sees its stream's frames in seq order, one call at a time, always
+on the stream's chain thread and never on the thread that resolved the
+future. The async split is what lets one stream keep multiple frames
+in flight (overlapping decode, batching and TPU steps — the role
+GStreamer queues play between elements in the reference, SURVEY.md
+§2d-5), a later frame at an earlier stage while an earlier one is
+still parked further down the chain; of a chain's several async
+stages each holds at most its even share of the stream's window.
 """
 
 from __future__ import annotations
