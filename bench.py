@@ -439,12 +439,6 @@ def main() -> int:
         "--stall-timeout", type=float, default=600.0,
         help="[serve] engine stall watchdog (s): how long a hung "
              "device call may burn the window before the entry fails")
-    p.add_argument(
-        "--serialize-compile", action="store_true",
-        help="[serve] set EVAM_SERIALIZE_COMPILE=1 so every engine "
-             "device call (launch/compile/readback) runs under one "
-             "process-wide lock — no compile can race a dispatch RPC. "
-             "Costs double-buffering")
     p.add_argument("--deadline-ms", type=float, default=8.0,
                    help="[serve] engine batch-fill deadline")
     p.add_argument(
@@ -473,9 +467,6 @@ def main() -> int:
     p.add_argument("--p99-target-ms", type=float, default=100.0,
                    help="latency bound the sweep optimizes under")
     args = p.parse_args()
-
-    if args.serialize_compile:
-        os.environ["EVAM_SERIALIZE_COMPILE"] = "1"
 
     metric_name = _metric_for(args.config)
 

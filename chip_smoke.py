@@ -547,7 +547,6 @@ def main() -> int:
 
     env = dict(os.environ)
     env.pop("EVAM_PRELOAD", None)
-    env.pop("EVAM_SERIALIZE_COMPILE", None)
     env.pop("EVAM_NO_NATIVE", None)
     env.update({
         # the registry refuses missing weights unless told otherwise;

@@ -49,7 +49,7 @@ _METRIC_METHODS = {
 #: files whose string constants form the producer-key universe for the
 #: bench contract pins (see module docstring, item 3)
 _PRODUCER_FILES = (
-    "bench.py", "tools/bench_fleet.py", "tools/bench_hostpath.py",
+    "bench.py", "tools/bench_fleet.py",
     "evam_tpu/stages/gate.py", "evam_tpu/fleet/engine.py",
     "evam_tpu/engine/hub.py", "evam_tpu/engine/ringbuf.py",
     "evam_tpu/sched/classes.py", "evam_tpu/sched/admission.py",

@@ -5,8 +5,8 @@ the serving rate; anything slow or syscall-shaped inside their loop
 bodies is paid thousands of times per second.  History: PR 4 found
 ``os.environ`` reads per batch in the fault injector.
 
-Starting from the configured entry methods (``BatchEngine._run*`` and
-its loop threads, ``FleetEngine``, ``SupervisedEngine._monitor*``),
+Starting from the configured entry methods (``BatchEngine``'s loop
+threads, ``FleetEngine``, ``SupervisedEngine._monitor*``),
 this pass walks a lexical intra-package call graph (``self.method`` →
 same class, bare name → same module, ``mod.fn`` / from-imports across
 modules) and flags, for code that executes inside a ``while``/``for``
@@ -33,7 +33,7 @@ from .core import Finding, SourceFile
 # (file regex, class name, method regex) — the thread entry points.
 ENTRY_POINTS = (
     (r"evam_tpu/engine/batcher\.py", "BatchEngine",
-     r"^(_run|_dispatch_loop|_launch|_completion_loop|_watchdog_loop)"),
+     r"^(_dispatch_loop|_launch|_completion_loop|_watchdog_loop)"),
     (r"evam_tpu/engine/supervisor\.py", "SupervisedEngine", r"^_monitor"),
     (r"evam_tpu/fleet/engine\.py", "FleetEngine", r".*"),
 )

@@ -63,14 +63,7 @@ class TPUSettings(BaseModel):
     #: cold start — including a supervisor rebuild's fresh jit —
     #: reads as a wedge
     first_batch_grace: float = 10.0
-    #: device-transfer pipeline (engine/batcher.py): "pipelined"
-    #: (default) overlaps the H2D upload of batch N+1 with batch N's
-    #: launch on a dedicated launcher thread and issues D2H copies
-    #: asynchronously at launch; "inline" is the serial pre-pipeline
-    #: path, kept byte-identical for A/B (tools/bench_transfer.py).
-    #: EVAM_SERIALIZE_COMPILE=1 forces inline regardless.
-    transfer: Literal["pipelined", "inline"] = "pipelined"
-    #: pipelined-transfer upload-queue depth: how many staged batches
+    #: upload-queue depth (engine/batcher.py): how many staged batches
     #: may sit between the dispatcher's h2d_issue and the launcher.
     #: 2 = one batch uploading while one launches; the control plane
     #: (EVAM_TUNE=on) retunes it live from the h2d_wait/launch ratio.
@@ -94,7 +87,7 @@ class TPUSettings(BaseModel):
     #: fleet-wide Σ-shard admission capacity and drain-and-rebalance
     #: on shard degradation; "off" (default) keeps the single-chip
     #: path byte-identical for A/B (tools/bench_fleet.py), the same
-    #: discipline as EVAM_TRANSFER / EVAM_GATE / EVAM_RAGGED.
+    #: discipline as EVAM_GATE / EVAM_RAGGED.
     fleet: Literal["sharded", "off"] = "off"
     #: fleet only: restrict sharding to the first N mesh devices
     #: (0 = all) — the bench/canary knob for scaling curves
@@ -115,8 +108,8 @@ class TPUSettings(BaseModel):
 class SchedSettings(BaseModel):
     """QoS scheduling knobs (evam_tpu/sched/): admission control,
     priority classes, load shedding. ``EVAM_SCHED=off`` disables the
-    whole layer — engines keep the legacy single-FIFO dispatch,
-    byte-identical (A/B, like EVAM_BATCH_ASSEMBLY=legacy)."""
+    whole layer — admission admits everything and every engine's
+    class queues run as one FIFO that sheds nothing."""
 
     enabled: bool = True
     #: projected-utilization ceiling for admission control; a start
@@ -151,7 +144,7 @@ class TraceSettings(BaseModel):
     ring with tail-based sampling, and the quarantine flight
     recorder. ``EVAM_TRACE=off`` disables the whole layer —
     byte-identical A/B (tools/bench_trace.py), same discipline as
-    EVAM_TRANSFER / EVAM_GATE."""
+    EVAM_GATE."""
 
     enabled: bool = True
     #: healthy-frame retention: keep 1-in-N (error/shed/deadline-miss
@@ -184,8 +177,8 @@ class CkptSettings(BaseModel):
     post-resolve and pre-rebalance barriers and restored before the
     first frame after a migration, rebuild, or restart.
     ``EVAM_CKPT=off`` (default until proven) disables the whole layer
-    — byte-identical A/B, same discipline as EVAM_TRANSFER /
-    EVAM_GATE / EVAM_TRACE."""
+    — byte-identical A/B, same discipline as EVAM_GATE /
+    EVAM_TRACE."""
 
     enabled: bool = False
     #: post-resolve capture cadence: refresh a stream's checkpoint
@@ -206,8 +199,8 @@ class TuneSettings(BaseModel):
     capacity, staleness budgets — from the live stage clock and queue
     gauges. ``EVAM_TUNE=off`` (default until a TPU window proves it)
     disables the whole layer — byte-identical A/B
-    (tools/bench_tune.py), same discipline as EVAM_TRANSFER /
-    EVAM_GATE / EVAM_TRACE. Every knob the controller manages stays
+    (tools/bench_tune.py), same discipline as EVAM_GATE /
+    EVAM_TRACE. Every knob the controller manages stays
     pinnable via its existing env var: an explicitly-set key is
     clamped out of the control loop."""
 
@@ -248,8 +241,8 @@ class AotSettings(BaseModel):
     size-capped on-disk store shared by supervisor rebuilds, fleet
     shard spin-up and every warmup path. ``EVAM_AOT=off`` (default
     until proven) disables the whole layer — byte-identical A/B
-    (tools/bench_aot.py), same discipline as EVAM_TRANSFER /
-    EVAM_GATE / EVAM_TRACE / EVAM_CKPT."""
+    (tools/bench_aot.py), same discipline as EVAM_GATE /
+    EVAM_TRACE / EVAM_CKPT."""
 
     enabled: bool = False
     #: cache directory; empty = <tmpdir>/evam_aot. Share it across
@@ -374,7 +367,6 @@ class Settings(BaseModel):
             "EVAM_ENGINE_RESTART_WINDOW_S": ("restart_window_s", float),
             "EVAM_ENGINE_RESTART_BACKOFF_S": ("restart_backoff_s", float),
             "EVAM_FIRST_BATCH_GRACE": ("first_batch_grace", float),
-            "EVAM_TRANSFER": ("transfer", str),
             "EVAM_TRANSFER_DEPTH": ("transfer_depth", int),
             "EVAM_RAGGED": ("ragged", str),
             "EVAM_RAGGED_UNIT_BUDGET": ("ragged_unit_budget", int),

@@ -5,52 +5,53 @@ Where the reference runs one inference engine per GStreamer pipeline
 reference pipelines/object_detection/person_vehicle_bike/
 pipeline.json:26-32), evam_tpu runs ONE BatchEngine per model
 instance and multiplexes every active stream into it (BASELINE.json
-north_star). Three cooperating threads per engine (four with the
-pipelined transfer, the default):
+north_star). One path, four cooperating threads per engine:
 
-  submit() ──slot──► dispatcher ──upload──► launcher ──► completion
+  submit() ──class queue──► dispatcher ──upload──► launcher ──► completer
 
-* **submit()** (stream threads) writes each item's arrays straight
-  into its reserved row of a pre-allocated staging slot
-  (engine/ringbuf.py) — the one host copy, parallelized across
-  submitters instead of serialized on the dispatcher;
-* the **dispatcher** seals a slot at the batch deadline
-  (latency/occupancy tension, SURVEY.md §7 "hard parts"): picks the
-  bucket (bounded compile count), zeroes only the dirty pad tail
-  (no stack, no concat, no allocation), places the block view on the
-  mesh (data-axis sharded) and launches the jitted step — WITHOUT
-  waiting for the result;
-* the **launcher** thread (``EVAM_TRANSFER=pipelined``, the default)
-  waits out the residual of the head batch's H2D copy, issues the
-  jitted step, and puts the device→host copy in flight immediately
-  (``copy_to_host_async``) — so the dispatcher is already sealing and
-  ``device_put``-ing batch N+1's slot while batch N's launch is being
-  issued, and up to ``depth`` D2H copies ride the device at once.
-  ``EVAM_TRANSFER=inline`` reproduces the pre-pipeline serial path
-  (H2D + launch back-to-back on the dispatcher) byte-identically for
-  A/B (tools/bench_transfer.py); ``EVAM_SERIALIZE_COMPILE=1`` forces
-  inline — overlapped device RPCs are exactly what the wedge-proof
-  mode exists to forbid;
-* the **completion** thread blocks on the single per-batch readback
-  residual, resolves per-item futures, and returns the slot to the
-  ring. Keeping dispatch and readback on separate threads
+* **submit()** (stream threads) appends the item to its scheduling
+  class's FIFO (sched/classes.py ``ClassQueues``) and returns a
+  future — O(1), no copy: class-ordered dispatch needs the item
+  mobile until it is picked;
+* the **dispatcher** sheds what outlived its class's staleness
+  budget, picks a class (realtime first, starvation-proof), forms a
+  batch under that class's deadline (latency/occupancy tension,
+  SURVEY.md §7 "hard parts"), copies its rows into a free
+  pre-allocated staging block (engine/ringbuf.py — the one host copy;
+  no stack, no concat, no allocation), picks the bucket (bounded
+  compile count), zeroes only the dirty pad tail, and ``device_put``s
+  the block view on the mesh (data-axis sharded) — WITHOUT waiting
+  for the copy;
+* the **launcher** waits out the residual of the head batch's H2D
+  copy, issues the jitted step, and puts the device→host copy in
+  flight immediately (``copy_to_host_async``) — so the dispatcher is
+  already staging and uploading batch N+1 while batch N's launch is
+  being issued, and up to ``max_in_flight`` D2H copies ride the
+  device at once;
+* the **completer** blocks on the single per-batch readback
+  residual, resolves per-item futures, and returns the block to the
+  ring. Keeping launch and readback on separate threads
   double-buffers the device: batch N+1 is enqueued while batch N
   computes (the decode-ahead/infer overlap the reference gets from
   GStreamer element threads, SURVEY.md §2d-5);
 * an in-flight semaphore bounds device queueing (backpressure, the
   analogue of the reference msgbus ``zmq_recv_hwm``,
   eii/config.json:37); the staging ring adds a second, host-side
-  bound — a slot is reusable only after its batch's readback (its
-  block may back an in-flight H2D transfer until the step consumes
-  the device buffer).
+  bound — a block is reusable only after its batch's readback (it
+  may back an in-flight H2D transfer until the step consumes the
+  device buffer).
+
+Without a scheduler config (``sched=None``, ``EVAM_SCHED=off``) the
+same loop runs as one FIFO: every submit joins the ``standard`` class,
+batches form under the engine's ``deadline_ms`` and nothing is shed.
 
 Every batch carries a **stage clock** (ringbuf.STAGES: submit_wait →
 slot_write → seal → h2d_issue → h2d_wait → launch → readback →
 resolve) into ``EngineStats`` and the ``evam_engine_stage_seconds``
 histogram, so the serve bench and /healthz can attribute host
 overhead instead of hiding it inside a throughput number (VERDICT r5
-weak #5) — and, post-transfer-pipeline, attribute transfer cost vs
-the dispatch floor honestly (h2d_wait and readback are residuals).
+weak #5) — and attribute transfer cost vs the dispatch floor honestly
+(h2d_wait and readback are residuals).
 The clock (obs/trace.py ``StageClock``) also keeps each stage's start
 and the batch's waits BETWEEN the stages, named by what the batch
 waits for (``wait_launcher``: uploaded, until the launcher takes it;
@@ -61,9 +62,6 @@ Each of the three threads marks its own stretches on a
 ``evam.complete.*`` in a profiler capture; a wait is named by what
 the THREAD waits for) and their seconds land in
 ``evam_engine_thread_seconds``.
-
-``EVAM_BATCH_ASSEMBLY=legacy`` keeps the old allocate-stack-pad
-dispatch path for A/B (tools/bench_hostpath.py measures the delta).
 """
 
 from __future__ import annotations
@@ -82,7 +80,6 @@ import numpy as np
 from evam_tpu.aot import active as aot_active
 from evam_tpu.aot import cache_key as aot_cache_key
 from evam_tpu.control.state import current_op
-from evam_tpu.engine import devlock
 from evam_tpu.engine.ragged import (
     RaggedSpec,
     consolidate_buckets,
@@ -191,9 +188,8 @@ class EngineStats:
     #: instead of silently clamped (oversize-split contract)
     oversize_splits: int = 0
     #: cumulative per-stage host clock (seconds), keyed by
-    #: ringbuf.STAGES — submit_wait/slot_write/seal come from the
-    #: dispatcher, h2d_issue from the upload span, h2d_wait/launch
-    #: from the launch span (launcher thread when pipelined),
+    #: ringbuf.STAGES — submit_wait/slot_write/seal/h2d_issue come
+    #: from the dispatcher, h2d_wait/launch from the launcher,
     #: readback/resolve from the completion thread. Single writer per
     #: key, so plain dict updates are safe. The clock is the
     #: STEADY-STATE service signal (admission derives capacity from
@@ -286,11 +282,9 @@ class BatchEngine:
         max_in_flight: int = 3,
         input_names: tuple[str, ...] = ("frames",),
         stall_timeout_s: float = 120.0,
-        assembly: str | None = None,
         staging_depth: int | None = None,
         first_batch_grace: float = 10.0,
         sched: SchedConfig | None = None,
-        transfer: str | None = None,
         ragged: str | None = None,
         ragged_spec: RaggedSpec | None = None,
         fleet_local: bool = False,
@@ -303,53 +297,18 @@ class BatchEngine:
         self.deadline_s = deadline_ms / 1000.0
         self.input_names = input_names
         self.stats = EngineStats()
-        #: host batch assembly: "slot" (pre-allocated staging ring,
-        #: default) or "legacy" (per-batch stack+concat) — kept for
-        #: A/B via EVAM_BATCH_ASSEMBLY (tools/bench_hostpath.py)
-        self.assembly = (assembly
-                         or os.environ.get("EVAM_BATCH_ASSEMBLY", "slot"))
-        if self.assembly not in ("slot", "legacy"):
-            raise ValueError(
-                f"EVAM_BATCH_ASSEMBLY must be 'slot' or 'legacy', "
-                f"got {self.assembly!r}")
         #: ragged batching (engine/ragged.py, EVAM_RAGGED): "packed"
         #: packs variable-size items into one fixed device shape with
         #: a row_len/row_offset descriptor + masked compute, and thins
         #: the bucket ladder so adjacent shapes share a program; "off"
-        #: (default) keeps the dense bucketed path byte-identical for
-        #: A/B (tools/bench_ragged.py). Packing needs the staging ring
-        #: — the legacy stack+concat assembly forces it off.
+        #: (default) is the dense bucketed path.
         self.ragged = ragged_mode(ragged)
-        if self.ragged == "packed" and self.assembly == "legacy":
-            log.warning(
-                "engine %s: EVAM_RAGGED=packed requires the slot "
-                "staging ring; EVAM_BATCH_ASSEMBLY=legacy forces it "
-                "off", name)
-            self.ragged = "off"
         #: unit-level shape of the one ragged input (classify-family
         #: engines). Attached even in "off" mode so the occupancy
         #: accounting stays honest about per-item ROI padding; packing
         #: itself is mode-gated.
         self.ragged_spec = ragged_spec
         self._packed = self.ragged == "packed" and ragged_spec is not None
-        #: device-transfer pipeline: "pipelined" (default) issues the
-        #: H2D copy on the dispatcher and launches from a dedicated
-        #: launcher thread — batch N+1's upload overlaps batch N's
-        #: launch, and D2H copies are put in flight at launch time;
-        #: "inline" is the pre-pipeline serial path (H2D + launch
-        #: back-to-back on the dispatcher), kept byte-identical for
-        #: A/B via EVAM_TRANSFER (tools/bench_transfer.py).
-        #: EVAM_SERIALIZE_COMPILE=1 forces inline at construction:
-        #: concurrently-issued transfer RPCs are exactly the overlap
-        #: the wedge-proof devlock mode exists to forbid.
-        self.transfer = transfer or os.environ.get(
-            "EVAM_TRANSFER", "pipelined")
-        if self.transfer not in ("pipelined", "inline"):
-            raise ValueError(
-                f"EVAM_TRANSFER must be 'pipelined' or 'inline', "
-                f"got {self.transfer!r}")
-        self._pipelined = (self.transfer == "pipelined"
-                           and not devlock.enabled())
         #: whether the backend keeps transfer streams separate from
         #: compute (TPU: PJRT tracks per-buffer readiness and DMAs
         #: ride their own stream). Gates the device-specific halves of
@@ -360,27 +319,33 @@ class BatchEngine:
         #: the async D2H issue (an extra host-side copy when the
         #: "device" is host memory). The pipeline STRUCTURE
         #: (dispatcher/launcher split, upload queue, watchdog
-        #: semantics) runs identically on CPU so tests exercise it end
-        #: to end.
+        #: semantics) is the same on CPU, so tests exercise it end to
+        #: end.
         self._device_streams = jax.default_backend() == "tpu"
         #: device identity recorded on batch trace records — a fleet
         #: shard's spans name the chip it serves (obs/trace.py)
         self._trace_device = (str(plan.mesh.devices.flat[0])
                               if plan is not None
                               else jax.default_backend())
-        #: QoS scheduling (evam_tpu/sched/): when set (and enabled),
-        #: submit routes into per-class queues drained realtime-first
-        #: with per-class batch deadlines and staleness shedding.
-        #: None/disabled = the legacy single-FIFO path, byte-identical
-        #: (EVAM_SCHED=off A/B).
+        #: QoS scheduling (evam_tpu/sched/): per-class batch deadlines
+        #: and staleness budgets for the class queues every submit
+        #: joins. None (or disabled, EVAM_SCHED=off) is the same loop
+        #: read as one FIFO: every submit joins ``standard``, every
+        #: class forms under the engine's ``deadline_ms`` and no
+        #: class has a staleness budget (the shedder's "never shed").
         self.sched = sched if (sched is not None and sched.enabled) else None
-        self._classq = ClassQueues() if self.sched is not None else None
-        self._shedder = (Shedder(name, self.sched.staleness_s())
-                         if self.sched is not None else None)
+        self._class_deadline_s = {
+            c: (self.sched.deadline_s(c) if self.sched is not None
+                else self.deadline_s)
+            for c in PRIORITIES}
+        self._classq = ClassQueues()
+        self._shedder = Shedder(
+            name, self.sched.staleness_s() if self.sched is not None else {})
         #: watchdog bound on one batch's device round-trip; a wedged
-        #: backend blocks the dispatcher in C++ forever — the watchdog can't unblock it, but it CAN
-        #: fail the stranded futures and flag the engine so /healthz
-        #: degrades and callers stop queueing into a black hole
+        #: backend blocks the launcher in C++ forever — the watchdog
+        #: can't unblock it, but it CAN fail the stranded futures and
+        #: flag the engine so /healthz degrades and callers stop
+        #: queueing into a black hole
         #: (SURVEY §5.3 failure detection; 0 disables).
         self.stall_timeout_s = stall_timeout_s
         #: a bucket's FIRST batch pays jit trace + XLA compile inside
@@ -410,8 +375,8 @@ class BatchEngine:
         #: persistent AOT executable cache (evam_tpu/aot/): the hub's
         #: program fingerprint for this engine — part of the cache key
         #: together with shapes and devices. None (the EVAM_AOT
-        #: default, or a caller that never passes it) keeps warmup and
-        #: dispatch byte-identical to the plain jit path.
+        #: default, or a caller that never passes it) leaves warmup and
+        #: dispatch on the plain jit path.
         self._aot_key = aot_key
         #: bucket → validated AOT executable, installed by warmup;
         #: dispatch (``_exec_for``) prefers it over the jitted step —
@@ -439,7 +404,7 @@ class BatchEngine:
         #: lightly-filled bucket on the mesh engine runs on one chip
         #: instead of paying an 8-way collective for 2 real rows. The
         #: existing bucket fn does the selection (_exec_for); off
-        #: (default) leaves ladder and dispatch byte-identical.
+        #: (default) leaves ladder and dispatch as they are.
         self._fleet_local = bool(fleet_local and plan is not None
                                  and plan.data_size > 1)
         if self._fleet_local:
@@ -457,10 +422,9 @@ class BatchEngine:
         #: depth × top-bucket batches. EVAM_STAGING_DEPTH overrides.
         depth = staging_depth or int(
             os.environ.get("EVAM_STAGING_DEPTH", "0")) or (max_in_flight + 1)
-        self._ring = (SlotRing(capacity=self.buckets[-1], depth=depth,
-                               ragged=(ragged_spec if self._packed
-                                       else None))
-                      if self.assembly == "slot" else None)
+        self._ring = SlotRing(capacity=self.buckets[-1], depth=depth,
+                              ragged=(ragged_spec if self._packed
+                                      else None))
         #: jit-call input order: the packed-ragged step takes the
         #: segment-id vector after the submit inputs (the stage never
         #: submits it — the ring seals it per batch)
@@ -510,10 +474,9 @@ class BatchEngine:
         else:
             self._local_plan = None
 
-        self._queue: queue.Queue[_WorkItem | None] = queue.Queue()
         self._done: queue.Queue[tuple | None] = queue.Queue()
-        #: pipelined transfer only: sealed batches whose H2D copy has
-        #: been issued, awaiting launch. Default depth 2 — device-side
+        #: sealed batches whose H2D copy has been issued, awaiting
+        #: launch. Default depth 2 — device-side
         #: double buffering (one batch uploading while one launches);
         #: EVAM_TRANSFER_DEPTH pins it, and the control plane
         #: (EVAM_TUNE=on) retunes it live from the h2d_wait/launch
@@ -541,27 +504,19 @@ class BatchEngine:
         self._sp_launch = trace.thread_spans(name, "launch")
         self._sp_complete = trace.thread_spans(name, "complete", cpu=True)
         trace.watch_engine(self)
-        if self._classq is not None:
-            dispatch_loop = self._dispatch_loop_sched
-        elif self._ring is not None:
-            dispatch_loop = self._dispatch_loop_slot
-        else:
-            dispatch_loop = self._dispatch_loop_legacy
         self._dispatcher = threading.Thread(
-            target=self._thread_guard, args=(dispatch_loop,),
+            target=self._thread_guard, args=(self._dispatch_loop,),
             name=f"engine-{name}-dispatch", daemon=True,
         )
         self._completer = threading.Thread(
             target=self._thread_guard, args=(self._completion_loop,),
             name=f"engine-{name}-complete", daemon=True,
         )
-        self._launcher: threading.Thread | None = None
-        if self._pipelined:
-            self._launcher = threading.Thread(
-                target=self._thread_guard, args=(self._launch_loop,),
-                name=f"engine-{name}-launch", daemon=True,
-            )
-            self._launcher.start()
+        self._launcher = threading.Thread(
+            target=self._thread_guard, args=(self._launch_loop,),
+            name=f"engine-{name}-launch", daemon=True,
+        )
+        self._launcher.start()
         self._dispatcher.start()
         self._completer.start()
         if self.stall_timeout_s > 0:
@@ -572,9 +527,10 @@ class BatchEngine:
 
     def _thread_guard(self, loop_fn: Callable) -> None:
         """Engine worker loops must never escape their thread with a
-        raw traceback: a crashed dispatcher/completer is an ENGINE
-        failure — logged here, detected by the EngineSupervisor via
-        thread liveness, and answered with a quarantine + rebuild."""
+        raw traceback: a crashed dispatcher, launcher or completer is
+        an ENGINE failure — logged here, detected by the
+        EngineSupervisor via thread liveness, and answered with a
+        quarantine + rebuild."""
         try:
             loop_fn()
         except Exception:  # noqa: BLE001 — terminal thread failure
@@ -594,12 +550,12 @@ class BatchEngine:
         """Enqueue one item (no batch dim); resolves to its packed row(s).
 
         ``priority`` selects the scheduling class (realtime|standard|
-        batch) when the engine runs the QoS layer (evam_tpu/sched/);
-        without it the argument is accepted and ignored — the legacy
-        single-FIFO path stays byte-identical.
+        batch) when the engine has a scheduler config
+        (evam_tpu/sched/); without one the argument is accepted and
+        ignored and the item joins ``standard`` — one FIFO.
 
         ``stream`` is the submitting stream's identity. A single-chip
-        engine accepts and ignores it (byte-identical legacy path) —
+        engine accepts and ignores it —
         it exists so the fleet mode (evam_tpu/fleet/) can pin a
         stream's traffic to a per-chip shard; stages pass it
         unconditionally and the engine kind behind the hub decides
@@ -618,16 +574,15 @@ class BatchEngine:
         appends queue-wait + dispatch spans to the frame's tree.
         Accepted and ignored — zero-cost — when tracing is off.
 
-        On the slot path this call COPIES the item's arrays into the
-        staging block on the calling thread (ringbuf.write) — the
-        dispatcher never re-stacks them — and blocks only when every
-        staging slot is in flight (host-side backpressure). On the
-        sched path the copy moves to the dispatcher (class-ordered
-        dispatch needs the item mobile until it is picked)."""
+        The call never blocks and never copies: the item waits in its
+        class queue, and the dispatcher copies its arrays into a
+        staging block once it has picked it (class-ordered dispatch
+        needs the item mobile until then). An array that does not
+        match the ring's shapes fails this item's future, there."""
         if self._stop.is_set():
             raise RuntimeError(f"engine {self.name} is stopped")
         if self.stalled.is_set():
-            # the dispatcher is wedged inside a device call — queueing
+            # the launcher is wedged inside a device call — queueing
             # more work would strand more futures
             raise RuntimeError(
                 f"engine {self.name} is stalled (device call exceeded "
@@ -641,50 +596,28 @@ class BatchEngine:
             units = int(np.asarray(
                 inputs[self.ragged_spec.input]).shape[0])
         fut: Future = Future()
-        if self._classq is not None:
-            if priority not in PRIORITIES:
-                raise ValueError(
-                    f"unknown priority {priority!r}; valid: "
-                    f"{'|'.join(PRIORITIES)}")
-            item = _WorkItem(inputs, fut, time.perf_counter(), priority,
-                             units, trace)
-            try:
-                self._classq.put(priority, item)
-            except RuntimeError:
-                raise RuntimeError(f"engine {self.name} is stopped") from None
-            return fut
-        item = _WorkItem(inputs, fut, time.perf_counter(), units=units,
-                         trace=trace)
-        if self._ring is not None:
-            try:
-                self._ring.write(inputs, item)
-            except RuntimeError:
-                raise RuntimeError(f"engine {self.name} is stopped") from None
-        else:
-            self._queue.put(item)
+        if self.sched is None:
+            priority = DEFAULT_PRIORITY
+        elif priority not in PRIORITIES:
+            raise ValueError(
+                f"unknown priority {priority!r}; valid: "
+                f"{'|'.join(PRIORITIES)}")
+        item = _WorkItem(inputs, fut, time.perf_counter(), priority,
+                         units, trace)
+        try:
+            self._classq.put(priority, item)
+        except RuntimeError:
+            raise RuntimeError(f"engine {self.name} is stopped") from None
         return fut
 
     def queue_depth(self) -> int:
         """Items submitted but not yet dispatched — the previously
         invisible backlog (satellite: queue gauges)."""
-        if self._classq is not None:
-            return self._classq.depth()
-        if self._ring is not None:
-            return self._ring.pending_items()
-        return self._queue.qsize()
+        return self._classq.depth()
 
     def queue_age_s(self) -> float:
         """Age (s) of the oldest undispatched item; 0 when idle."""
-        now = time.perf_counter()
-        if self._classq is not None:
-            return self._classq.oldest_age_s(now)
-        if self._ring is not None:
-            return self._ring.oldest_age_s(now)
-        with self._queue.mutex:
-            head = self._queue.queue[0] if self._queue.queue else None
-        if isinstance(head, _WorkItem):
-            return max(0.0, now - head.t_submit)
-        return 0.0
+        return self._classq.oldest_age_s(time.perf_counter())
 
     def thread_states(self) -> dict[str, tuple]:
         """Each worker thread's current stretch and its age in seconds
@@ -694,15 +627,13 @@ class BatchEngine:
                 "complete": self._sp_complete.where()}
 
     def class_depths(self) -> dict[str, int]:
-        """Per-class queued depth ({} when scheduling is off)."""
-        if self._classq is None:
-            return {}
+        """Per-class queued depth (without a scheduler config every
+        item queues as ``standard``)."""
         return self._classq.depth_by_class()
 
     def shed_counts(self) -> dict[str, int]:
-        """Per-class shed totals ({} when scheduling is off)."""
-        if self._shedder is None:
-            return {}
+        """Per-class shed totals (zeros without a scheduler config:
+        no class has a staleness budget)."""
         return dict(self._shedder.counts)
 
     def retune(self, op) -> None:
@@ -735,10 +666,7 @@ class BatchEngine:
             if cache is not None and self._warm_bucket_aot(
                     cache, b, batch, t0):
                 continue
-            # whole compile+execute+readback under one devlock span:
-            # a warmup must never leave a half-overlapped RPC behind
-            with devlock.device_call(f"{self.name}:warmup"):
-                np.asarray(self._run(batch))
+            np.asarray(self._run(b, batch))
             with self._exec_lock:
                 if b not in self._buckets_done:
                     # compile-cache accounting: a bucket's first run
@@ -784,9 +712,9 @@ class BatchEngine:
         return aot_cache_key(self._aot_key, b, inputs, params_sig,
                              devices, jax.default_backend())
 
-    def _aot_arrays(self, b: int, batch: dict[str, np.ndarray]):
-        """(params, placed input arrays) for bucket ``b`` — the same
-        placement ``_run`` performs, shared by the AOT validate and
+    def _warm_arrays(self, b: int, batch: dict[str, np.ndarray]):
+        """(params, placed input arrays) for bucket ``b``'s warm
+        batch — shared by the plain warm-up and the AOT validate and
         populate paths."""
         _, prm, sharding = self._exec_plain(b)
         arrays = []
@@ -806,57 +734,53 @@ class BatchEngine:
         misses into compile_seconds — /engines attributes cold vs
         warm spin-up from exactly these."""
         key = self._aot_bucket_key(b, batch)
-        compiled = None
-        with devlock.device_call(f"{self.name}:warmup"):
-            prm, arrays = self._aot_arrays(b, batch)
-            loaded = cache.load(key, self._bucket_devices(b),
-                                engine=self.name)
-            if loaded is not None:
-                try:
-                    # the only honest validation of a deserialized,
-                    # device-bound executable is running it — this IS
-                    # the warm run on success
-                    np.asarray(loaded(prm, *arrays))
-                except Exception as exc:  # noqa: BLE001 — device/placement drift
-                    log.warning(
-                        "engine %s: cached AOT executable for bucket "
-                        "%d would not execute (%s) — recompiling",
-                        self.name, b, exc)
-                    cache.execute_miss(key, engine=self.name)
-                    loaded = None
-            if loaded is not None:
-                with self._exec_lock:
-                    if b not in self._buckets_done:
-                        self.stats.compiled_programs += 1
-                        self.stats.aot_hits += 1
-                        self.stats.aot_load_seconds += (
-                            time.perf_counter() - t0)
-                    self._aot_exec[b] = loaded
-                    self._buckets_done.add(b)
-                cache.hit(engine=self.name)
-                return True
+        prm, arrays = self._warm_arrays(b, batch)
+        loaded = cache.load(key, self._bucket_devices(b),
+                            engine=self.name)
+        if loaded is not None:
             try:
-                # miss: compile ahead-of-time ONCE (lower().compile()
-                # and jit don't share a cache — running both would
-                # double the cold-start bill) and use the compiled
-                # executable for the warm run and for dispatch
-                jit_fn, _, _ = self._exec_plain(b)
-                compiled = jit_fn.lower(prm, *arrays).compile()
-                np.asarray(compiled(prm, *arrays))
-            except Exception as exc:  # noqa: BLE001 — AOT unsupported here
+                # the only honest validation of a deserialized,
+                # device-bound executable is running it — this IS
+                # the warm run on success
+                np.asarray(loaded(prm, *arrays))
+            except Exception as exc:  # noqa: BLE001 — device/placement drift
                 log.warning(
-                    "engine %s: AOT compile path failed for bucket %d "
-                    "(%s) — plain jit warmup", self.name, b, exc)
-                return False
+                    "engine %s: cached AOT executable for bucket "
+                    "%d would not execute (%s) — recompiling",
+                    self.name, b, exc)
+                cache.execute_miss(key, engine=self.name)
+                loaded = None
+        if loaded is not None:
             with self._exec_lock:
                 if b not in self._buckets_done:
                     self.stats.compiled_programs += 1
-                    self.stats.compile_seconds += (
+                    self.stats.aot_hits += 1
+                    self.stats.aot_load_seconds += (
                         time.perf_counter() - t0)
-                self._aot_exec[b] = compiled
+                self._aot_exec[b] = loaded
                 self._buckets_done.add(b)
-        # serialize+write outside the devlock span — disk I/O must not
-        # serialize against other engines' device calls
+            cache.hit(engine=self.name)
+            return True
+        try:
+            # miss: compile ahead-of-time ONCE (lower().compile()
+            # and jit don't share a cache — running both would
+            # double the cold-start bill) and use the compiled
+            # executable for the warm run and for dispatch
+            jit_fn, _, _ = self._exec_plain(b)
+            compiled = jit_fn.lower(prm, *arrays).compile()
+            np.asarray(compiled(prm, *arrays))
+        except Exception as exc:  # noqa: BLE001 — AOT unsupported here
+            log.warning(
+                "engine %s: AOT compile path failed for bucket %d "
+                "(%s) — plain jit warmup", self.name, b, exc)
+            return False
+        with self._exec_lock:
+            if b not in self._buckets_done:
+                self.stats.compiled_programs += 1
+                self.stats.compile_seconds += (
+                    time.perf_counter() - t0)
+            self._aot_exec[b] = compiled
+            self._buckets_done.add(b)
         cache.store(key, compiled, engine=self.name)
         return True
 
@@ -911,35 +835,19 @@ class BatchEngine:
 
     def stop(self) -> None:
         self._stop.set()
-        if self._classq is not None:
-            self._classq.close()
-        if self._ring is not None:
-            self._ring.close()
-        self._queue.put(None)
+        self._classq.close()
+        self._ring.close()
         self._dispatcher.join(timeout=10)
-        if self._launcher is not None:
-            try:
-                self._upload_q.put_nowait(None)
-            except queue.Full:
-                pass  # launcher drains the backlog, then exits on _stop
-            self._launcher.join(timeout=10)
+        try:
+            self._upload_q.put_nowait(None)
+        except queue.Full:
+            pass  # launcher drains the backlog, then exits on _stop
+        self._launcher.join(timeout=10)
         self._done.put(None)
         self._completer.join(timeout=10)
         exc = RuntimeError("engine stopped")
         self._drain_upload_q(exc)
-        if self._classq is not None:
-            for item in self._classq.drain():
-                _safe_set_exception(item.future, exc)
-        if self._ring is not None:
-            for item in self._ring.drain_items():
-                _safe_set_exception(item.future, exc)
-        while True:
-            try:
-                item = self._queue.get_nowait()
-            except queue.Empty:
-                break
-            if item is not None:
-                _safe_set_exception(item.future, exc)
+        self._fail_queued(exc)
 
     def _track_dispatch(self, t0: float, items: list[_WorkItem],
                         bucket: int) -> tuple[int, bool]:
@@ -977,7 +885,7 @@ class BatchEngine:
     def abandon(self) -> None:
         """Quarantine teardown (EngineSupervisor): release every
         failable caller WITHOUT joining the worker threads — a wedged
-        engine's dispatcher/completer may be blocked in C++ (or an
+        engine's launcher/completer may be blocked in C++ (or an
         injected wedge's sleep) indefinitely, and the supervisor must
         not inherit that wait. The threads are daemons; they observe
         ``_stop``/the closed ring when (if) they ever wake and exit on
@@ -987,24 +895,11 @@ class BatchEngine:
             f"engine {self.name} quarantined: wedged device call; "
             "the supervisor is rebuilding the engine"
         )
-        if self._classq is not None:
-            self._classq.close()
-            for item in self._classq.drain():
-                _safe_set_exception(item.future, exc)
-        if self._ring is not None:
-            self._ring.close()
-            for item in self._ring.drain_items():
-                _safe_set_exception(item.future, exc)
-        self._queue.put(None)
+        self._classq.close()
+        self._fail_queued(exc)
+        self._ring.close()
         self._done.put(None)
         self._drain_upload_q(exc)
-        while True:
-            try:
-                item = self._queue.get_nowait()
-            except queue.Empty:
-                break
-            if item is not None:
-                _safe_set_exception(item.future, exc)
         with self._exec_lock:
             stranded = [it for entry in self._outstanding.values()
                         for it in entry[1]]
@@ -1030,10 +925,10 @@ class BatchEngine:
         for b in self.buckets:
             if n <= b:
                 return b
-        # n past the top bucket would silently truncate: the dispatch
-        # paths split oversize submits across batches BEFORE bucketing
-        # (_split_oversize / stage_direct leftovers), so landing here
-        # is an accounting bug — be loud, never lossy
+        # n past the top bucket would silently truncate: the ring
+        # hands back what a block cannot hold and the dispatcher
+        # stages it as another batch BEFORE bucketing, so landing
+        # here is an accounting bug — be loud, never lossy
         log.warning(
             "engine %s: %d items exceed top bucket %d (oversize split "
             "missed a path); clamping the SHAPE, items are preserved "
@@ -1057,18 +952,6 @@ class BatchEngine:
         metrics.inc("evam_engine_oversize_splits", float(extra),
                     labels={"engine": self.name})
 
-    def _split_oversize(self, items: list[_WorkItem]) -> list[list[_WorkItem]]:
-        """Chunk a formed batch at the top bucket instead of letting
-        ``_bucket`` silently clamp (and the assembly paths truncate) a
-        packed submit past the largest shape. Each extra chunk counts
-        on ``evam_engine_oversize_splits``."""
-        top = self.buckets[-1]
-        if len(items) <= top:
-            return [items]
-        chunks = [items[i:i + top] for i in range(0, len(items), top)]
-        self._count_oversize_split(len(chunks) - 1)
-        return chunks
-
     def _exec_plain(self, b: int):
         """(jit, params, sharding) for one sealed bucket. With the
         fleet mode's local bypass, sub-data-size buckets select the
@@ -1086,52 +969,22 @@ class BatchEngine:
         """(callable, params, sharding) for one sealed bucket — the
         warmed AOT executable when the cache installed one for this
         rung, the jitted step otherwise. Both share the
-        ``fn(params, *arrays)`` call signature, so every dispatch
-        path is agnostic to which it got. (Lock-free read: dict get
+        ``fn(params, *arrays)`` call signature, so the launcher is
+        agnostic to which it got. (Lock-free read: dict get
         is atomic and a rung's entry, once installed by warmup, is
         never replaced.)"""
         jit_fn, prm, sharding = self._exec_plain(b)
         exe = self._aot_exec.get(b)
         return (exe if exe is not None else jit_fn), prm, sharding
 
-    def _run(self, batch: dict[str, np.ndarray],
-             clock: trace.StageClock | None = None):
-        """Inline transfer path (EVAM_TRANSFER=inline, warmup, and the
-        devlock-forced mode): H2D + launch back-to-back on the calling
-        thread — the pre-pipeline behavior, byte-identical. h2d_wait
-        is 0 by definition here: the launch call itself absorbs any
-        residual transfer wait inside the runtime."""
-        # chaos hook: an injected `wedge` blocks right here — on the
-        # dispatching thread, inside the engine, exactly where a hung
-        # backend RPC would — so the watchdog/supervisor path is
-        # testable without wedging real hardware (obs/faults.py)
-        inj = active_faults()
-        if inj is not None:
-            inj.maybe_wedge(self.name)
-        # devlock: with EVAM_SERIALIZE_COMPILE=1 this launch (and any
-        # compile it triggers) cannot overlap another engine thread's
-        # device RPC — the wedge-proof measurement mode
-        with devlock.device_call(f"{self.name}:launch"):
-            t0 = time.perf_counter()
-            if clock is not None:
-                self._sp_dispatch.to("h2d_issue", t0)
-            jit_fn, prm, sharding = self._exec_for(
-                batch[self.input_names[0]].shape[0])
-            arrays = []
-            for name in self._step_inputs:
-                a = batch[name]
-                if sharding is not None:
-                    a = jax.device_put(a, sharding)
-                arrays.append(a)
-            t1 = time.perf_counter()
-            if clock is not None:
-                self._sp_dispatch.to("launch", t1)
-            out = jit_fn(prm, *arrays)
-            if clock is not None:
-                clock.mark("h2d_issue", t0, t1 - t0)
-                clock["h2d_wait"] = 0.0
-                clock.mark("launch", t1, time.perf_counter() - t1)
-            return out
+    def _run(self, b: int, batch: dict[str, np.ndarray]):
+        """Warm-up's device calls, back to back on the calling thread:
+        place bucket ``b``'s warm batch and run the jitted step on it
+        (the first run of a shape is its trace + XLA compile).
+        Nothing that serves comes through here."""
+        jit_fn, _, _ = self._exec_for(b)
+        prm, arrays = self._warm_arrays(b, batch)
+        return jit_fn(prm, *arrays)
 
     def refresh_queue_gauges(self) -> None:
         """Push the submit-backlog gauges. Called on every dispatch
@@ -1143,10 +996,9 @@ class BatchEngine:
         metrics.set("evam_engine_queue_age_s", self.queue_age_s(),
                     {"engine": self.name})
 
-    def _record_batch(self, n: int, b: int, clock: trace.StageClock,
-                      items: list[_WorkItem] | None = None,
-                      sealed: SealedBatch | None = None,
-                      unclocked: bool = False) -> None:
+    def _record_batch(self, sealed: SealedBatch,
+                      unclocked: bool) -> None:
+        n, b, clock = sealed.n, sealed.bucket, sealed.clock
         spec = self.ragged_spec
         with self._exec_lock:
             self.stats.batches += 1
@@ -1159,14 +1011,14 @@ class BatchEngine:
             # max_units unit rows and fall back to the pessimistic
             # budget for items that didn't declare their real count.
             # Frame-per-row engines: 1 unit per item.
-            if sealed is not None and sealed.row_len is not None:
+            if sealed.row_len is not None:
                 self.stats.units += sealed.units
                 self.stats.unit_slots += sealed.unit_rows
             elif spec is not None:
                 self.stats.unit_slots += b * spec.max_units
                 self.stats.units += sum(
                     (it.units if it.units is not None else spec.max_units)
-                    for it in (items or []))
+                    for it in sealed.items)
             else:
                 self.stats.unit_slots += b
                 self.stats.units += n
@@ -1194,80 +1046,52 @@ class BatchEngine:
 
     # --------------------------------------------- transfer pipeline
 
-    def _dispatch_batch(self, batch: dict[str, np.ndarray],
-                        items: list[_WorkItem], n: int, b: int,
-                        clock: trace.StageClock,
-                        sealed: SealedBatch | None) -> None:
-        """Common tail of all three dispatch loops: hand one assembled
-        batch to the device path.
+    def _fail_queued(self, exc: Exception) -> None:
+        """Fail every item still waiting in the class queues."""
+        for it in self._classq.drain():
+            _safe_set_exception(it.future, exc)
 
-        Inline: H2D + launch back-to-back on this thread (``_run``).
-        Pipelined: enqueue the H2D copy here (h2d_issue — device_put
-        returns once the transfer is in flight) and queue the batch
-        for the launcher thread, so the dispatcher is sealing and
-        uploading batch N+1 while batch N's launch is being issued."""
+    def _fail_batch(self, sealed: SealedBatch, exc: Exception) -> None:
+        """Fail every future of a batch that will not reach the device
+        and give its block back. The block releases without waiting
+        on a possibly in-flight H2D copy: the futures are failed, so
+        nothing ever observes those rows again."""
+        for it in sealed.items:
+            _safe_set_exception(it.future, exc)
+        self._ring.release(sealed)
+
+    def _dispatch_batch(self, sealed: SealedBatch) -> None:
+        """Hand one sealed batch to the device: enqueue the H2D copy
+        here (h2d_issue — device_put returns once the transfer is in
+        flight) and queue the batch for the launcher thread, so the
+        dispatcher is staging and uploading batch N+1 while batch N's
+        launch is being issued."""
         sp = self._sp_dispatch
-        if not self._pipelined:
-            if not self._in_flight.acquire(blocking=False):
-                sp.to("wait_slot")
-                self._in_flight.acquire()
-            t0 = time.perf_counter()
-            sp.to("bookkeep", t0)
-            clock.wait("wait_slot", t0)
-            bid, unclocked = self._track_dispatch(t0, items, b)
-            # the pending trace record holds the SAME clock dict _run
-            # fills in — a flight dump of a wedged batch reads the
-            # stages completed so far (obs/trace.py)
-            trace.batch_begin(self.name, bid, items, b, n, clock,
-                              self._trace_device)
-            try:
-                out = self._run(batch, clock=clock)
-            except Exception as exc:  # noqa: BLE001 — surface to every caller
-                self._in_flight.release()
-                with self._exec_lock:
-                    self._outstanding.pop(bid, None)
-                for it in items:
-                    _safe_set_exception(it.future, exc)
-                trace.batch_complete(self.name, bid, items,
-                                     status="error")
-                if sealed is not None:
-                    self._ring.release(sealed)
-                log.exception("engine %s step failed", self.name)
-                return
-            sp.to("bookkeep")
-            self._done.put((out, items, t0, bid, sealed, clock))
-            self._record_batch(n, b, clock, items=items, sealed=sealed,
-                               unclocked=unclocked)
-            return
+        batch = sealed.arrays
         try:
-            with devlock.device_call(f"{self.name}:h2d"):
-                t0 = time.perf_counter()
-                sp.to("h2d_issue", t0)
-                _, _, sharding = self._exec_for(b)
-                if sharding is not None:
-                    # sharded placement is semantics, not an
-                    # optimization — always explicit
-                    dev = [jax.device_put(batch[name], sharding)
-                           for name in self._step_inputs]
-                elif self._device_streams:
-                    dev = [jax.device_put(batch[name])
-                           for name in self._step_inputs]
-                else:
-                    # CPU: let the launcher's jit call do the one
-                    # host-side conversion exactly like inline does —
-                    # an explicit device_put here would be a second
-                    # copy with no DMA to overlap
-                    dev = [batch[name] for name in self._step_inputs]
-                t_up = time.perf_counter()
-                clock.mark("h2d_issue", t0, t_up - t0)
+            t0 = time.perf_counter()
+            sp.to("h2d_issue", t0)
+            _, _, sharding = self._exec_for(sealed.bucket)
+            if sharding is not None:
+                # sharded placement is semantics, not an
+                # optimization — always explicit
+                dev = [jax.device_put(batch[name], sharding)
+                       for name in self._step_inputs]
+            elif self._device_streams:
+                dev = [jax.device_put(batch[name])
+                       for name in self._step_inputs]
+            else:
+                # CPU: let the launcher's jit call do the one
+                # host-side conversion — an explicit device_put here
+                # would be a second copy with no DMA to overlap
+                dev = [batch[name] for name in self._step_inputs]
+            t_up = time.perf_counter()
+            sealed.clock.mark("h2d_issue", t0, t_up - t0)
         except Exception as exc:  # noqa: BLE001 — surface to every caller
-            for it in items:
-                _safe_set_exception(it.future, exc)
-            if sealed is not None:
-                self._ring.release(sealed)
+            self._fail_batch(sealed, exc)
             log.exception("engine %s H2D upload failed", self.name)
             return
-        entry = (dev, items, n, b, clock, sealed)
+        entry = (dev, sealed)
         if self._upload_q.full():
             sp.to("wait_launcher", t_up)
         while True:
@@ -1277,52 +1101,48 @@ class BatchEngine:
             except queue.Full:
                 if self._stop.is_set():
                     # launcher is exiting — don't strand the batch
-                    exc = RuntimeError(f"engine {self.name} is stopped")
-                    for it in items:
-                        _safe_set_exception(it.future, exc)
-                    if sealed is not None:
-                        self._ring.release(sealed)
+                    self._fail_batch(sealed, RuntimeError(
+                        f"engine {self.name} is stopped"))
                     return
 
-    def _launch(self, dev: list, clock: trace.StageClock, b: int = 0):
-        """Launcher half of the pipelined transfer: wait out the head
-        batch's H2D residual where that is measurable without
-        re-serializing (``_h2d_sync`` — h2d_wait is ≈0 when the upload
-        overlapped the previous launch, the full copy time when it did
-        not), issue the jitted step, and put the D2H copy in flight
-        immediately so the completer blocks only on the readback
-        residual."""
-        # chaos hook: same consult as _run — the wedge must block the
-        # thread that issues the device RPC
+    def _launch(self, dev: list, clock: trace.StageClock, b: int):
+        """Wait out the head batch's H2D residual where that is
+        measurable without re-serializing (h2d_wait is ≈0 when the
+        upload overlapped the previous launch, the full copy time
+        when it did not), issue the jitted step, and put the D2H copy
+        in flight immediately so the completer blocks only on the
+        readback residual."""
+        # chaos hook: an injected `wedge` blocks right here — on the
+        # thread that issues the device RPC, exactly where a hung
+        # backend would — so the watchdog/supervisor path is testable
+        # without wedging real hardware (obs/faults.py)
         inj = active_faults()
         if inj is not None:
             inj.maybe_wedge(self.name)
         sp = self._sp_launch
-        with devlock.device_call(f"{self.name}:launch"):
-            t0 = time.perf_counter()
-            sp.to("h2d_wait", t0)
-            if self._device_streams:
-                jax.block_until_ready(dev)
-            t1 = time.perf_counter()
-            sp.to("launch", t1)
-            jit_fn, prm, _ = self._exec_for(b)
-            out = jit_fn(prm, *dev)
-            t2 = time.perf_counter()
-            sp.to("bookkeep", t2)
-            clock.mark("h2d_wait", t0, t1 - t0)
-            clock.mark("launch", t1, t2 - t1)
-            if self._device_streams:
-                # async D2H: the device→host copy rides along while
-                # later batches launch; np.asarray in the completer
-                # then pays only the residual (the `readback` stage,
-                # now honest)
-                out.copy_to_host_async()
+        t0 = time.perf_counter()
+        sp.to("h2d_wait", t0)
+        if self._device_streams:
+            jax.block_until_ready(dev)
+        t1 = time.perf_counter()
+        sp.to("launch", t1)
+        jit_fn, prm, _ = self._exec_for(b)
+        out = jit_fn(prm, *dev)
+        t2 = time.perf_counter()
+        sp.to("bookkeep", t2)
+        clock.mark("h2d_wait", t0, t1 - t0)
+        clock.mark("launch", t1, t2 - t1)
+        if self._device_streams:
+            # async D2H: the device→host copy rides along while
+            # later batches launch; np.asarray in the completer
+            # then pays only the residual (the `readback` stage)
+            out.copy_to_host_async()
         return out
 
     def _launch_loop(self) -> None:
-        """Pipelined transfer: pop uploaded batches and launch them —
-        while this thread is inside a launch (or blocked on a wedged
-        backend RPC), the dispatcher keeps sealing and uploading."""
+        """Pop uploaded batches and launch them — while this thread is
+        inside a launch (or blocked on a wedged backend RPC), the
+        dispatcher keeps staging and uploading."""
         sp = self._sp_launch
         while True:
             # seconds kept, no annotation: while the launcher idles for
@@ -1337,14 +1157,12 @@ class BatchEngine:
                 continue
             if entry is None:
                 break
-            dev, items, n, b, clock, sealed = entry
+            dev, sealed = entry
             if self._stop.is_set():
-                exc = RuntimeError(f"engine {self.name} is stopped")
-                for it in items:
-                    _safe_set_exception(it.future, exc)
-                if sealed is not None:
-                    self._ring.release(sealed)
+                self._fail_batch(sealed, RuntimeError(
+                    f"engine {self.name} is stopped"))
                 continue
+            items, clock = sealed.items, sealed.clock
             if self._in_flight.acquire(blocking=False):
                 t0 = time.perf_counter()
                 clock.wait("wait_launcher", t0)
@@ -1358,58 +1176,47 @@ class BatchEngine:
                 t0 = time.perf_counter()
                 clock.wait("wait_slot", t0)
             sp.to("bookkeep", t0)
-            bid, unclocked = self._track_dispatch(t0, items, b)
-            # clock by reference — same wedge-visibility contract as
-            # the inline path (obs/trace.py)
-            trace.batch_begin(self.name, bid, items, b, n, clock,
-                              self._trace_device)
+            bid, unclocked = self._track_dispatch(t0, items, sealed.bucket)
+            # the pending trace record holds the SAME clock dict
+            # _launch fills in — a flight dump of a wedged batch reads
+            # the stages completed so far (obs/trace.py)
+            trace.batch_begin(self.name, bid, items, sealed.bucket,
+                              sealed.n, clock, self._trace_device)
             try:
-                out = self._launch(dev, clock, b)
+                out = self._launch(dev, clock, sealed.bucket)
             except Exception as exc:  # noqa: BLE001 — surface to every caller
                 self._in_flight.release()
                 with self._exec_lock:
                     self._outstanding.pop(bid, None)
-                for it in items:
-                    _safe_set_exception(it.future, exc)
+                self._fail_batch(sealed, exc)
                 trace.batch_complete(self.name, bid, items,
                                      status="error")
-                if sealed is not None:
-                    self._ring.release(sealed)
                 log.exception("engine %s step failed", self.name)
                 continue
-            self._done.put((out, items, t0, bid, sealed, clock))
-            self._record_batch(n, b, clock, items=items, sealed=sealed,
-                               unclocked=unclocked)
+            self._done.put((out, t0, bid, sealed))
+            self._record_batch(sealed, unclocked)
         sp.to(None)
 
     def _drain_upload_q(self, exc: Exception) -> None:
         """Fail every uploaded-but-unlaunched batch (stop/abandon/
-        stall). Slots release without waiting on their possibly
-        in-flight H2D copies — same contract as the launch-failure
-        path: the batch's futures are already failed, so nothing ever
-        observes those rows again."""
+        stall) and return its block."""
         while True:
             try:
                 entry = self._upload_q.get_nowait()
             except queue.Empty:
                 return
-            if entry is None:
-                continue
-            _dev, items, _n, _b, _clock, sealed = entry
-            for it in items:
-                _safe_set_exception(it.future, exc)
-            if sealed is not None:
-                self._ring.release(sealed)
+            if entry is not None:
+                self._fail_batch(entry[1], exc)
 
-    # ------------------------------------------------ sched dispatch
+    # -------------------------------------------------------- dispatch
 
-    def _dispatch_loop_sched(self) -> None:
-        """QoS dispatch (evam_tpu/sched/): drain per-class queues
-        realtime-first (starvation-proof weighted pick), form batches
-        under the CLASS deadline — cameras keep a small latency floor
-        while bulk traffic fills big buckets — and shed frames that
-        outlived their class staleness budget (oldest-first) before
-        they waste a device slot."""
+    def _dispatch_loop(self) -> None:
+        """The one dispatch loop (evam_tpu/sched/): drain the class
+        queues realtime-first (starvation-proof weighted pick), form
+        batches under the CLASS deadline — cameras keep a small
+        latency floor while bulk traffic fills big buckets — and shed
+        frames that outlived their class staleness budget
+        (oldest-first) before they waste a device slot."""
         cq = self._classq
         shedder = self._shedder
         sp = self._sp_dispatch
@@ -1418,9 +1225,7 @@ class BatchEngine:
             # for items (the pick, then the class deadline's fill)
             sp.to("wait_items")
             if self._stop.is_set():
-                exc = RuntimeError("engine stopped")
-                for it in cq.drain():
-                    _safe_set_exception(it.future, exc)
+                self._fail_queued(RuntimeError("engine stopped"))
                 break
             # shed expired waiters across ALL classes first: the
             # backlog a busy realtime lane starves must fail loudly
@@ -1434,7 +1239,7 @@ class BatchEngine:
             # demanded bucket rung
             op = current_op()
             cap = self.max_batch
-            deadline = self.sched.deadline_s(cls)
+            deadline = self._class_deadline_s[cls]
             if op is not None:
                 if op.batch_cap:
                     cap = min(cap, op.batch_cap)
@@ -1446,149 +1251,36 @@ class BatchEngine:
             items = shedder.shed(cls, items)
             if not items:
                 continue
-            self._launch_sched(items)
+            self._dispatch_items(items)
         sp.to(None)
 
-    def _launch_sched(self, items: list[_WorkItem]) -> None:
-        """Assemble + launch one class-ordered batch: through the
-        staging ring (zero per-batch allocation, copies on this
-        thread) or the legacy stack+concat when
-        EVAM_BATCH_ASSEMBLY=legacy. A pick that exceeds the top
-        bucket's rows — or, packed, the unit block — is split across
-        batches in dispatch order instead of silently clamped
-        (oversize-split contract)."""
-        if self._ring is not None:
-            bucket_fn = (self._bucket_ragged if self._packed
-                         else self._bucket)
-            staged = [(it.inputs, it) for it in items]
-            dispatched = 0
-            while staged:
-                clock = trace.StageClock()
-                clock["submit_wait"] = (
-                    time.perf_counter() - staged[0][1].t_submit)
-                try:
-                    sealed, staged = self._ring.stage_direct(
-                        staged, bucket_fn, clock, self._sp_dispatch)
-                except RuntimeError:
-                    exc = RuntimeError(f"engine {self.name} is stopped")
-                    for _, it in staged:
-                        _safe_set_exception(it.future, exc)
-                    return
-                if sealed is None:
-                    continue  # every staged row failed its shape check
-                dispatched += 1
-                self._dispatch_batch(sealed.arrays, sealed.items,
-                                     sealed.n, sealed.bucket,
-                                     sealed.clock, sealed)
-            if dispatched > 1:
-                self._count_oversize_split(dispatched - 1)
-            return
-        for chunk in self._split_oversize(items):
-            clock = trace.StageClock()
-            clock["submit_wait"] = time.perf_counter() - chunk[0].t_submit
-            n = len(chunk)
-            b = self._bucket(n)
-            t_asm = time.perf_counter()
-            self._sp_dispatch.to("slot_write", t_asm)
-            batch = {}
-            for name in self.input_names:
-                rows = [it.inputs[name] for it in chunk]
-                stacked = np.stack(rows)
-                if b > n:
-                    pad = np.zeros((b - n,) + stacked.shape[1:],
-                                   stacked.dtype)
-                    stacked = np.concatenate([stacked, pad])
-                batch[name] = stacked
-            clock.mark("slot_write", t_asm, time.perf_counter() - t_asm)
-            self._dispatch_batch(batch, chunk, n, b, clock, None)
-
-    # ------------------------------------------------- slot dispatch
-
-    def _dispatch_loop_slot(self) -> None:
-        """Seal staged slots at the batch deadline and launch them —
-        no stack, no pad concat, no per-batch allocation."""
+    def _dispatch_items(self, items: list[_WorkItem]) -> None:
+        """Stage + dispatch one class-ordered pick through the staging
+        ring (zero per-batch allocation, copies on this thread). A
+        pick that exceeds the top bucket's rows — or, packed, the
+        unit block — is split across batches in dispatch order
+        instead of silently clamped (oversize-split contract)."""
         bucket_fn = self._bucket_ragged if self._packed else self._bucket
-        sp = self._sp_dispatch
-        while True:
-            sp.to("wait_items")
-            op = current_op()
-            deadline = (self.deadline_s * op.deadline_scale
-                        if op is not None else self.deadline_s)
-            sealed = self._ring.next_batch(deadline, bucket_fn, sp)
-            if sealed is None:
-                if self._stop.is_set():
-                    break
-                continue
-            if self._stop.is_set():
-                exc = RuntimeError("engine stopped")
-                for it in sealed.items:
-                    _safe_set_exception(it.future, exc)
-                self._ring.release(sealed)
-                continue  # drain whatever else is staged, then exit
-
-            self._dispatch_batch(sealed.arrays, sealed.items, sealed.n,
-                                 sealed.bucket, sealed.clock, sealed)
-        sp.to(None)
-
-    # ----------------------------------------------- legacy dispatch
-
-    def _dispatch_loop_legacy(self) -> None:
-        """Pre-ring path (EVAM_BATCH_ASSEMBLY=legacy): per-batch
-        stack + zero-pad concat on the dispatcher thread. Kept for
-        A/B measurement — tools/bench_hostpath.py."""
-        sp = self._sp_dispatch
-        while not self._stop.is_set():
-            sp.to("wait_items")
+        staged = [(it.inputs, it) for it in items]
+        dispatched = 0
+        while staged:
+            clock = trace.StageClock()
+            clock["submit_wait"] = (
+                time.perf_counter() - staged[0][1].t_submit)
             try:
-                first = self._queue.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            if first is None:
-                break
-            op = current_op()
-            cap = self.max_batch
-            deadline_s = self.deadline_s
-            if op is not None:
-                if op.batch_cap:
-                    cap = min(cap, op.batch_cap)
-                deadline_s *= op.deadline_scale
-            items = [first]
-            deadline = time.perf_counter() + deadline_s
-            while len(items) < cap:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    self._stop.set()
-                    break
-                items.append(nxt)
-
-            for chunk in self._split_oversize(items):
-                n = len(chunk)
-                b = self._bucket(n)
-                clock = trace.StageClock()
-                clock["submit_wait"] = (
-                    time.perf_counter() - chunk[0].t_submit)
-                t_asm = time.perf_counter()
-                sp.to("slot_write", t_asm)
-                batch: dict[str, np.ndarray] = {}
-                for name in self.input_names:
-                    rows = [it.inputs[name] for it in chunk]
-                    stacked = np.stack(rows)
-                    if b > n:
-                        pad = np.zeros((b - n,) + stacked.shape[1:],
-                                       stacked.dtype)
-                        stacked = np.concatenate([stacked, pad])
-                    batch[name] = stacked
-                clock.mark("slot_write", t_asm,
-                           time.perf_counter() - t_asm)
-
-                self._dispatch_batch(batch, chunk, n, b, clock, None)
-        sp.to(None)
+                sealed, staged = self._ring.stage(
+                    staged, bucket_fn, clock, self._sp_dispatch)
+            except RuntimeError:
+                exc = RuntimeError(f"engine {self.name} is stopped")
+                for _, it in staged:
+                    _safe_set_exception(it.future, exc)
+                return
+            if sealed is None:
+                continue  # every staged row failed its shape check
+            dispatched += 1
+            self._dispatch_batch(sealed)
+        if dispatched > 1:
+            self._count_oversize_split(dispatched - 1)
 
     # ------------------------------------------------------ completion
 
@@ -1600,27 +1292,23 @@ class BatchEngine:
             if entry is None:
                 sp.to(None)
                 break
-            out, items, t0, bid, sealed, clock = entry
+            out, t0, bid, sealed = entry
+            items, clock = sealed.items, sealed.clock
             t_rb = time.perf_counter()
             # np.asarray returns when the device has finished the step
             # and the copy to the host has landed
             sp.to("wait_device", t_rb)
             clock.wait("wait_completer", t_rb)
             try:
-                with devlock.device_call(f"{self.name}:readback"):
-                    # single readback per batch; with the pipelined
-                    # transfer the D2H copy is already in flight
-                    # (copy_to_host_async at launch), so this blocks
-                    # only on the residual
-                    host = np.asarray(out)
+                # single readback per batch; the D2H copy is already
+                # in flight (copy_to_host_async at launch), so this
+                # blocks only on the residual
+                host = np.asarray(out)
             except Exception as exc:  # noqa: BLE001
-                for it in items:
-                    _safe_set_exception(it.future, exc)
+                self._fail_batch(sealed, exc)
                 trace.batch_complete(self.name, bid, items,
                                      status="error")
                 self._in_flight.release()
-                if sealed is not None:
-                    self._ring.release(sealed)
                 continue
             finally:
                 sp.to("resolve")
@@ -1639,10 +1327,9 @@ class BatchEngine:
                         self.stats.compile_seconds += (
                             time.perf_counter() - done[0])
                     self._buckets_done.add(done[2])
-            if sealed is not None:
-                # the staging block is free the moment the readback
-                # materialized the output on host
-                self._ring.release(sealed)
+            # the staging block is free the moment the readback
+            # materialized the output on host
+            self._ring.release(sealed)
             if self.stalled.is_set():
                 # the "wedged" call was merely slow (e.g. a mid-traffic
                 # multichip compile) and has now completed — recover
@@ -1662,7 +1349,7 @@ class BatchEngine:
             # row_len[i]] (exactly its real region rows, zero-region
             # items resolve to an empty slice). Dense batches keep the
             # one-row-per-item contract.
-            ragged = (sealed is not None and sealed.row_len is not None)
+            ragged = sealed.row_len is not None
             for i, it in enumerate(items):
                 metrics.observe(
                     "evam_item_latency_seconds", now - it.t_submit, {"engine": self.name}
@@ -1694,14 +1381,14 @@ class BatchEngine:
 
     def _watchdog_loop(self) -> None:
         """Fail futures stranded behind a wedged device call and flag
-        the engine (the dispatcher/completer threads stay blocked in
+        the engine (the launcher/completer threads stay blocked in
         C++ — only the service-level contract can be saved). A
         bucket's first batch gets stall_timeout_s × first_batch_grace:
         its round-trip legitimately contains trace + XLA compile, and
         without the grace every cold start — especially a supervisor
         rebuild's fresh jit — reads as a wedge."""
-        # floor 0.2 s (was 1.0): supervised tests run sub-second stall
-        # budgets; production timeouts (120 s) still poll every 30 s
+        # floor 0.2 s: supervised tests run sub-second stall budgets;
+        # production timeouts (120 s) still poll every 30 s
         interval = max(self.stall_timeout_s / 4.0, 0.2)
         while not self._stop.wait(interval):
             # keep the backlog gauges live even when nothing
@@ -1730,19 +1417,7 @@ class BatchEngine:
             )
             for it in stuck:
                 _safe_set_exception(it.future, exc)
-            # strand nothing in the class queues, staging ring,
-            # upload queue or legacy queue either
+            # strand nothing in the upload queue or the class queues
+            # either
             self._drain_upload_q(exc)
-            if self._classq is not None:
-                for it in self._classq.drain():
-                    _safe_set_exception(it.future, exc)
-            if self._ring is not None:
-                for it in self._ring.drain_items():
-                    _safe_set_exception(it.future, exc)
-            while True:
-                try:
-                    queued = self._queue.get_nowait()
-                except queue.Empty:
-                    break
-                if queued is not None:
-                    _safe_set_exception(queued.future, exc)
+            self._fail_queued(exc)

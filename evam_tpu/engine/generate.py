@@ -168,8 +168,7 @@ class _Step:
 
 
 class GenerateEngine:
-    #: what the supervisor and the hub's rows read of any engine
-    assembly = "generate"
+    #: what the hub's rows read of any engine
     ragged = "off"
 
     def __init__(self, name: str, model_cfg: dict, prefix_ids,
@@ -238,8 +237,7 @@ class GenerateEngine:
         self._thread = threading.Thread(
             target=self._loop, name=f"engine-{name}-generate", daemon=True)
         #: the supervisor's liveness checks name these three
-        self._dispatcher = self._completer = self._thread
-        self._launcher = None
+        self._dispatcher = self._launcher = self._completer = self._thread
         self._thread.start()
         self._warm_thread: threading.Thread | None = None
         self._set_gauges()

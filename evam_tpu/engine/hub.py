@@ -55,7 +55,6 @@ class EngineHub:
         restart_backoff_s: float = 0.5,
         first_batch_grace: float = 10.0,
         sched: SchedConfig | None = None,
-        transfer: str | None = None,
         transfer_depth: int = 0,
         ragged: str | None = None,
         ragged_unit_budget: int = 0,
@@ -93,19 +92,12 @@ class EngineHub:
         #: batch — see BatchEngine._track_dispatch
         self.first_batch_grace = first_batch_grace
         #: QoS scheduling config (evam_tpu/sched/): engines get
-        #: per-class queues, deadlines and staleness shedding. Part of
-        #: the rebuild recipe — a supervisor-rebuilt engine inherits
-        #: the class queues because the factory closure carries it.
-        #: None = the legacy single-FIFO engines (EVAM_SCHED=off).
+        #: per-class deadlines and staleness shedding. Part of the
+        #: rebuild recipe — a supervisor-rebuilt engine inherits it
+        #: because the factory closure carries it. None = every
+        #: engine's class queues run as one FIFO (EVAM_SCHED=off).
         self.sched = sched if (sched is not None and sched.enabled) else None
-        #: device-transfer pipeline (EVAM_TRANSFER): "pipelined"
-        #: (default) overlaps H2D upload / launch / async D2H inside
-        #: every engine; "inline" is the serial pre-pipeline path
-        #: (A/B, tools/bench_transfer.py). Part of the rebuild recipe:
-        #: the factory closure carries it, so a supervisor-rebuilt
-        #: engine keeps its transfer mode. None = engine reads the env.
-        self.transfer = transfer
-        #: pipelined upload-queue bound (EVAM_TRANSFER_DEPTH): the
+        #: upload-queue bound (EVAM_TRANSFER_DEPTH): the
         #: static boot value; the control plane (evam_tpu/control/)
         #: retunes the live bound through ``retune``. Part of the
         #: rebuild recipe — but BatchEngine construction consults the
@@ -344,7 +336,6 @@ class EngineHub:
                     stall_timeout_s=self.stall_timeout_s,
                     first_batch_grace=self.first_batch_grace,
                     sched=self.sched,
-                    transfer=self.transfer,
                     transfer_depth=self.transfer_depth or None,
                     ragged=self.ragged,
                     ragged_spec=ragged_spec,
@@ -415,12 +406,6 @@ class EngineHub:
             # why the background warmup failed (traffic then compiles
             # on demand), else None
             "warm_error": getattr(e, "warm_error", None),
-            "assembly": e.assembly,
-            # effective device-transfer mode (EVAM_TRANSFER;
-            # devlock may have forced a pipelined request to
-            # inline — report what actually runs)
-            "transfer": ("pipelined" if getattr(
-                e, "_pipelined", False) else "inline"),
             # ragged batching (engine/ragged.py): effective
             # mode, the honest units/computed-unit-rows
             # occupancy (the pad tax n/bucket hides), where

@@ -34,7 +34,7 @@ vectors and masked compute, instead of one program per
 
 ``EVAM_RAGGED=off`` (the default until a TPU accuracy window) keeps
 today's bucketed dense path byte-identical — the same A/B discipline
-as ``EVAM_TRANSFER`` / ``EVAM_GATE``. Supervisor rebuilds inherit the
+as ``EVAM_GATE``. Supervisor rebuilds inherit the
 mode through the hub's factory closure.
 """
 

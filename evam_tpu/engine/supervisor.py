@@ -251,7 +251,7 @@ class SupervisedEngine:
 
     def __getattr__(self, item):
         # only called for attributes NOT found on the proxy: stats,
-        # warmed, stalled, assembly, buckets, _ring, _bucket, ...
+        # warmed, stalled, buckets, _ring, _bucket, ...
         return getattr(object.__getattribute__(self, "_engine"), item)
 
     # ------------------------------------------------------- internals
@@ -272,7 +272,7 @@ class SupervisedEngine:
             return "dispatcher thread died"
         if not eng._completer.is_alive():
             return "completion thread died"
-        if eng._launcher is not None and not eng._launcher.is_alive():
+        if not eng._launcher.is_alive():
             return "transfer launcher thread died"
         return None
 

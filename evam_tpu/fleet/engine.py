@@ -82,8 +82,8 @@ class FleetEngine:
     ``shard_factory(plan, label)`` builds one shard engine on a
     single-device plan; ``mesh_factory(label)`` (optional) builds the
     data-parallel big-bucket engine on the full mesh. Both are hub
-    closures so shards inherit the hub's supervision, sched, transfer
-    and ragged configuration.
+    closures so shards inherit the hub's supervision, sched,
+    transfer-depth and ragged configuration.
     """
 
     #: Placement/carry state is hit from every submitting stream
@@ -571,7 +571,7 @@ class FleetEngine:
         self.drain_wait()
 
     def __getattr__(self, item):
-        # structural attributes (buckets, assembly, ragged flags, …)
+        # structural attributes (buckets, ragged flags, …)
         # are identical across shards by construction — answer from
         # the first one
         with self._lock:

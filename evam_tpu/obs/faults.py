@@ -164,8 +164,8 @@ class FaultInjector:
         return frame
 
     def maybe_wedge(self, name: str = "") -> None:
-        """Engine-side consult (BatchEngine._run): with probability
-        ``wedge`` block the calling dispatcher thread for ``wedge_s``
+        """Engine-side consult (BatchEngine._launch): with probability
+        ``wedge`` block the calling launcher thread for ``wedge_s``
         seconds — indistinguishable, from the watchdog's and
         supervisor's point of view, from a hung backend RPC."""
         if not self.wedge_p:

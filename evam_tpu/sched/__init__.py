@@ -14,9 +14,9 @@ Three cooperating parts (see each module's docstring):
   dispatch: stale frames shed oldest-first (freshest-frame-wins),
   futures failed loudly as ShedError.
 
-``EVAM_SCHED=off`` disables the whole layer and keeps the legacy
-single-FIFO engine path byte-identical (A/B, like
-``EVAM_BATCH_ASSEMBLY=legacy``).
+``EVAM_SCHED=off`` disables the whole layer: admission admits
+everything, and every engine's class queues run as one FIFO (every
+submit joins ``standard``, nothing is shed).
 """
 
 from evam_tpu.sched.admission import (

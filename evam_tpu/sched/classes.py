@@ -8,7 +8,8 @@ three classes the scheduler speaks —
 
 * ``realtime`` — live cameras; small batch-formation deadline, tight
   staleness budget, drained first;
-* ``standard`` — the default; the pre-sched engine behavior;
+* ``standard`` — the default, and the one class every submit joins
+  with the scheduler off;
 * ``batch``    — bulk/offline re-runs; big batch-formation deadline
   (fill large buckets), generous staleness budget, first to shed.
 
@@ -16,8 +17,8 @@ three classes the scheduler speaks —
 on: ``SchedConfig`` (the resolved knob set, kept OUT of the hot loop
 — tf.data's lesson from PAPERS.md: policy is data, the loop only
 reads it) and ``ClassQueues`` (per-class FIFOs with a
-starvation-proof realtime-first pick, replacing the single unbounded
-``BatchEngine._queue``).
+starvation-proof realtime-first pick — the queues every
+``BatchEngine.submit`` joins).
 """
 
 from __future__ import annotations
@@ -138,7 +139,8 @@ class SchedConfig:
 
 class ClassQueues:
     """Per-class FIFO queues with a starvation-proof realtime-first
-    pick — the sched-mode replacement for ``BatchEngine._queue``.
+    pick — what ``BatchEngine.submit`` puts into and its dispatcher
+    drains.
 
     Items must expose ``t_submit`` (perf_counter at enqueue) and
     ``future`` (failable on drain) — the engine's ``_WorkItem``
@@ -217,9 +219,8 @@ class ClassQueues:
                 deadline_s: float) -> list:
         """Form one batch from ``priority``'s queue: wait until it
         holds ``max_n`` items or until ``deadline_s`` past the HEAD
-        item's submit time (matches the slot ring's first-write
-        deadline semantics — a backlogged queue dispatches a full
-        bucket immediately, a trickle dispatches at the deadline)."""
+        item's submit time — a backlogged queue dispatches a full
+        bucket immediately, a trickle dispatches at the deadline."""
         with self._cv:
             dq = self._q[priority]
             if not dq:

@@ -83,7 +83,6 @@ class PipelineRegistry:
                 restart_backoff_s=settings.tpu.restart_backoff_s,
                 first_batch_grace=settings.tpu.first_batch_grace,
                 sched=sched_cfg if sched_cfg.enabled else None,
-                transfer=settings.tpu.transfer,
                 transfer_depth=settings.tpu.transfer_depth,
                 ragged=settings.tpu.ragged,
                 ragged_unit_budget=settings.tpu.ragged_unit_budget,

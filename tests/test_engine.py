@@ -151,7 +151,6 @@ def test_hub_stats(hub):
     assert det["items"] >= 25
     assert 0 < det["mean_occupancy"] <= 1.0
     # the host stage clock rides every engine's stats
-    assert det["assembly"] == "slot"
     assert {"slot_write", "launch", "readback"} <= set(det["stage_ms"])
     # the /healthz aggregate: fixed keys, real time where work ran
     summary = hub.stage_summary()
@@ -177,58 +176,13 @@ def test_warm_async_precompiles_buckets(hub):
     assert out.shape[-1] == 7
 
 
-class TestSerializeCompile:
-    """EVAM_SERIALIZE_COMPILE=1: warmup compiles must never overlap
-    dispatch RPCs."""
-
-    def test_overlap_exists_then_lock_removes_it(self, monkeypatch):
-        """The serve path's unique condition (a warmup compile racing
-        steady dispatch) is real at the client, and the global lock
-        removes it."""
-        from evam_tpu.engine import devlock
-
-        def run_with(serialize: bool) -> tuple[int, list]:
-            monkeypatch.setenv("EVAM_SERIALIZE_COMPILE",
-                               "1" if serialize else "0")
-            devlock.reset_stats()
-            eng = BatchEngine(
-                "ser", lambda p, x: x.sum(axis=(1, 2, 3)).astype(np.float32),
-                params={}, max_batch=8, deadline_ms=1.0,
-                input_names=("x",),
-            )
-            try:
-                eng.set_example(x=np.ones((2, 2, 3), np.uint8))
-                eng.warm_async(x=np.ones((2, 2, 3), np.uint8))
-                outs = [
-                    eng.submit(x=np.full((2, 2, 3), i, np.uint8))
-                    .result(timeout=60)
-                    for i in range(20)
-                ]
-                assert eng.warmed.wait(timeout=60)
-            finally:
-                eng.stop()
-            return devlock.max_concurrent(), outs
-
-        peak, outs = run_with(serialize=True)
-        # correctness is unaffected by the lock...
-        assert [float(o) for o in outs] == [12.0 * i for i in range(20)]
-        # ...and no two device calls ever overlapped
-        assert peak == 1
-
-        # sanity: the gauge CAN exceed 1 (it is not trivially 1) —
-        # the unlocked engine double-buffers launch vs readback
-        peak_free, outs = run_with(serialize=False)
-        assert [float(o) for o in outs] == [12.0 * i for i in range(20)]
-        assert peak_free >= 1  # >1 when readback overlaps launch (timing)
-
-
 class TestStallWatchdog:
     def test_wedged_step_fails_futures_and_flags_engine(self, monkeypatch):
         """A device call that never returns must not strand callers: the watchdog fails in-flight
         and queued futures with TimeoutError, flags the engine, and
         submit() starts rejecting. The wedge is injected with the
-        `wedge` fault (obs/faults.py) — it blocks the dispatcher
-        inside _run exactly like a hung backend RPC — and hits a WARM
+        `wedge` fault (obs/faults.py) — it blocks the launcher
+        inside _launch exactly like a hung backend RPC — and hits a WARM
         bucket; a cold bucket's first batch gets the compile grace
         (test_first_batch_compile_grace below)."""
         from evam_tpu.engine.batcher import BatchEngine
@@ -258,9 +212,66 @@ class TestStallWatchdog:
         finally:
             monkeypatch.setenv("EVAM_FAULT_INJECT", "")
             faults.reset_cache()
-            # the dispatcher is mid-wedge: abandon (non-blocking)
+            # the launcher is mid-wedge: abandon (non-blocking)
             # instead of stop()'s joins
             eng.abandon()
+
+    def test_wedge_fails_in_flight_uploaded_and_queued(self, monkeypatch):
+        """The wedge holds the LAUNCHER; the dispatcher keeps staging
+        and uploading behind it until the upload queue is full. When
+        the watchdog fires, the stranded work sits in three places and
+        all of it must fail with TimeoutError: the batch in flight
+        (``_outstanding``), the batches uploaded but not launched
+        (the upload queue, and the one the dispatcher holds while it
+        waits to put it), and the items still in the class queues.
+        Their staging blocks come back."""
+        from evam_tpu.engine.batcher import BatchEngine
+        from evam_tpu.obs import faults
+
+        eng = BatchEngine(
+            "wedged-3", lambda p, frames: frames, params=None,
+            max_batch=1, deadline_ms=1.0, stall_timeout_s=1.0,
+            staging_depth=4, transfer_depth=1,
+        )
+        one = np.zeros((2, 2), np.float32)
+        try:
+            eng.submit(frames=one).result(timeout=30)  # warm bucket 1
+            monkeypatch.setenv("EVAM_FAULT_INJECT",
+                               "wedge=1,wedge_n=1,wedge_s=3")
+            faults.reset_cache()
+            futs = [eng.submit(frames=one)]
+            deadline = time.time() + 10
+            while not eng._outstanding and time.time() < deadline:
+                time.sleep(0.01)
+            assert eng._outstanding  # batch 1 is in flight, wedged
+            futs += [eng.submit(frames=one) for _ in range(5)]
+            # batch 2 fills the upload queue, the dispatcher holds
+            # batch 3 waiting to put it, the rest stay queued
+            deadline = time.time() + 10
+            while time.time() < deadline and not (
+                    eng._upload_q.qsize() == 1
+                    and eng.queue_depth() == 3):
+                time.sleep(0.01)
+            assert eng._upload_q.qsize() == 1
+            assert eng.queue_depth() == 3
+            for f in futs:
+                with pytest.raises(TimeoutError):
+                    f.result(timeout=10)
+            assert eng.stalled.is_set()
+            assert eng.queue_depth() == 0
+            # every block but the in-flight batch's is free again
+            # (the wedged launch still holds that one)
+            deadline = time.time() + 5
+            while (len(eng._ring._free) < 3
+                   and time.time() < deadline):
+                time.sleep(0.05)
+            assert len(eng._ring._free) == 3
+        finally:
+            monkeypatch.setenv("EVAM_FAULT_INJECT", "")
+            faults.reset_cache()
+            # joins the launcher once its 3 s wedge has run out: no
+            # thread of this engine outlives the test
+            eng.stop()
 
     def test_first_batch_compile_grace(self, monkeypatch):
         """A cold bucket's first round-trip legitimately contains
@@ -314,10 +325,28 @@ class TestStallWatchdog:
             eng.stop()
 
 
+class _Item:
+    """What the ring needs of a work item: a failable future."""
+
+    def __init__(self):
+        from concurrent.futures import Future
+
+        self.future = Future()
+
+
+def _stage(ring, rows, bucket, name="x"):
+    """Stage ``rows`` as one pick; returns (sealed, leftovers, items)."""
+    from evam_tpu.obs.trace import StageClock
+
+    staged = [({name: r}, _Item()) for r in rows]
+    sealed, rest = ring.stage(staged, lambda n: bucket, StageClock())
+    return sealed, rest, [it for _, it in staged]
+
+
 class TestSlotAssembly:
     """Zero-copy staging path (engine/ringbuf.py): pre-allocated
-    blocks reused across batches, zeroed pad tails, row-exclusive
-    concurrent submits, and the per-batch stage clock."""
+    blocks reused across batches, zeroed pad tails, per-row shape
+    checks, backpressure, and the per-batch stage clock."""
 
     @staticmethod
     def _echo_engine(**kw):
@@ -332,10 +361,12 @@ class TestSlotAssembly:
         from evam_tpu.engine.ringbuf import SlotRing
 
         ring = SlotRing(capacity=8, depth=2)
-        for i in range(6):
-            ring.write({"x": np.full((4,), 1.0, np.float32)}, i)
-        sealed = ring.next_batch(0.001, lambda n: 8)
+        sealed, rest, items = _stage(
+            ring, [np.full((4,), 1.0, np.float32)] * 6, bucket=8)
+        assert rest == []
         assert sealed.n == 6 and sealed.bucket == 8
+        assert sealed.items == items
+        assert set(sealed.clock) == {"slot_write", "seal"}
         arr = sealed.arrays["x"]
         assert arr.shape == (8, 4)
         # the sealed batch is a VIEW of the staging block, not a copy
@@ -347,14 +378,107 @@ class TestSlotAssembly:
         # exhaust every slot several times over: tails stay zero and
         # no block is EVER allocated again (buffer identity)
         for _ in range(6):
-            for i in range(3):
-                ring.write({"x": np.full((4,), 9.0, np.float32)}, i)
-            s = ring.next_batch(0.001, lambda n: 4)
+            s, _, _ = _stage(
+                ring, [np.full((4,), 9.0, np.float32)] * 3, bucket=4)
             assert s.n == 3 and s.arrays["x"].shape == (4, 4)
             np.testing.assert_array_equal(s.arrays["x"][3:], 0.0)
             np.testing.assert_array_equal(s.arrays["x"][:3], 9.0)
             ring.release(s)
         assert ring.blocks_allocated == allocs
+
+    def test_ring_bad_row_fails_only_its_own_future(self):
+        """A row whose shape or dtype mismatches the ring fails ITS
+        item's future; the survivors compact into contiguous rows, in
+        order, and no future of theirs is touched."""
+        from evam_tpu.engine.ringbuf import SlotRing
+
+        ring = SlotRing(capacity=8, depth=2)
+        rows = [np.full((4,), 1.0, np.float32),
+                np.full((5,), 2.0, np.float32),   # wrong shape
+                np.full((4,), 3.0, np.float32),
+                np.full((4,), 4, np.int32),       # wrong dtype
+                np.full((4,), 5.0, np.float32)]
+        sealed, rest, items = _stage(ring, rows, bucket=4)
+        assert rest == []
+        assert sealed.n == 3
+        assert sealed.items == [items[0], items[2], items[4]]
+        np.testing.assert_array_equal(
+            sealed.arrays["x"][:3, 0], [1.0, 3.0, 5.0])
+        np.testing.assert_array_equal(sealed.arrays["x"][3:], 0.0)
+        for i in (1, 3):
+            with pytest.raises(ValueError, match="staging ring"):
+                items[i].future.result(timeout=0)
+        assert not any(items[i].future.done() for i in (0, 2, 4))
+        ring.release(sealed)
+        # no row survives: no batch, and the block goes straight back
+        sealed, rest, items = _stage(
+            ring, [np.zeros((5,), np.float32)] * 2, bucket=4)
+        assert sealed is None and rest == []
+        assert all(it.future.done() for it in items)
+        assert len(ring._free) == 2
+
+    def test_ring_hands_back_what_a_block_cannot_hold(self):
+        """Rows past the block's capacity are not clamped: they come
+        back, in order, for the caller to stage as the next batch."""
+        from evam_tpu.engine.ringbuf import SlotRing
+
+        ring = SlotRing(capacity=4, depth=2)
+        rows = [np.full((2,), float(i), np.float32) for i in range(10)]
+        sealed, rest, items = _stage(ring, rows, bucket=4)
+        assert sealed.n == 4 and sealed.items == items[:4]
+        assert [it for _, it in rest] == items[4:]
+        np.testing.assert_array_equal(
+            sealed.arrays["x"][:, 0], [0.0, 1.0, 2.0, 3.0])
+
+    def test_ring_blocks_while_every_block_is_in_flight(self):
+        """Host-side backpressure: with every block sealed and
+        unreleased, ``stage()`` waits; a ``release()`` lets it
+        through."""
+        from evam_tpu.engine.ringbuf import SlotRing
+
+        ring = SlotRing(capacity=2, depth=2)
+        row = [np.ones((3,), np.float32)]
+        a, _, _ = _stage(ring, row, bucket=1)
+        b, _, _ = _stage(ring, row, bucket=1)
+        got: list = []
+        t = threading.Thread(
+            target=lambda: got.append(_stage(ring, row, bucket=1)[0]))
+        t.start()
+        t.join(timeout=0.4)
+        assert t.is_alive() and not got  # no free block: still waiting
+        ring.release(a)
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert got[0].slot is a.slot  # the released block, reused
+        ring.release(b)
+
+    def test_ring_raises_once_closed(self):
+        """``close()`` wakes a dispatcher waiting for a block and
+        every later ``stage()`` raises; the staged items' futures are
+        the caller's to fail (none is touched here)."""
+        from evam_tpu.engine.ringbuf import SlotRing
+
+        ring = SlotRing(capacity=2, depth=2)
+        row = [np.ones((3,), np.float32)]
+        _stage(ring, row, bucket=1)
+        _stage(ring, row, bucket=1)
+        raised: list = []
+
+        def waiter():
+            try:
+                _stage(ring, row, bucket=1)
+            except RuntimeError as exc:
+                raised.append(exc)
+
+        t = threading.Thread(target=waiter)
+        t.start()
+        t.join(timeout=0.3)
+        assert t.is_alive()
+        ring.close()
+        t.join(timeout=10)
+        assert not t.is_alive() and len(raised) == 1
+        with pytest.raises(RuntimeError, match="closed"):
+            _stage(ring, row, bucket=1)
 
     def test_no_per_batch_allocation_at_steady_state(self):
         eng = self._echo_engine()
@@ -491,28 +615,73 @@ class TestSlotAssembly:
         finally:
             eng.stop()
 
-    def test_legacy_assembly_env_var(self, monkeypatch):
-        monkeypatch.setenv("EVAM_BATCH_ASSEMBLY", "legacy")
-        eng = self._echo_engine()
+    def test_mismatched_shape_fails_its_future_not_its_batch(self):
+        """Through the engine: the ring's shapes are pinned by the
+        first batch; a later submit of another shape fails ITS future
+        (at the dispatcher, not at submit) while the rows that share
+        its batch resolve to their own values."""
+        eng = self._echo_engine(deadline_ms=200.0)
         try:
-            assert eng.assembly == "legacy"
-            assert eng._ring is None
-            outs = [eng.submit(x=np.full((4,), float(i), np.float32))
-                    .result(timeout=30) for i in range(10)]
-            assert [float(o[0]) for o in outs] == [float(i)
-                                                  for i in range(10)]
-            # the legacy path still feeds the stage clock (A/B runs
-            # compare like with like in tools/bench_hostpath.py)
-            assert "slot_write" in eng.stats.stage_seconds
-            assert "launch" in eng.stats.stage_seconds
+            eng.submit(x=np.zeros((4,), np.float32)).result(timeout=30)
+            good = [eng.submit(x=np.full((4,), float(i), np.float32))
+                    for i in range(3)]
+            bad = eng.submit(x=np.zeros((5,), np.float32))
+            good += [eng.submit(x=np.full((4,), float(i), np.float32))
+                     for i in range(3, 7)]  # the 8th fills the batch
+            with pytest.raises(ValueError, match="staging ring"):
+                bad.result(timeout=30)
+            for i, f in enumerate(good):
+                np.testing.assert_array_equal(
+                    f.result(timeout=30), np.full((4,), float(i)))
+            # one pick of 8, one batch of its 7 survivors
+            assert eng.stats.batches == 2 and eng.stats.items == 8
         finally:
             eng.stop()
 
-    def test_mismatched_shape_is_rejected(self):
-        eng = self._echo_engine()
+    def test_dense_pick_past_the_top_bucket_splits_in_order(self):
+        """A pick larger than the top bucket's rows is split across
+        batches in dispatch order and counted, never clamped. (The
+        dense path cannot form one by itself — ``max_batch`` never
+        exceeds the top bucket — so the cap is lifted under a built
+        engine; the packed path reaches the same split through its
+        unit rows, tests/test_ragged.py.)"""
+        from evam_tpu.engine.batcher import BatchEngine
+        from evam_tpu.obs.metrics import metrics
+
+        eng = BatchEngine(
+            "slot-split", lambda p, x: x.astype(np.float32),
+            params=None, max_batch=4, deadline_ms=2.0,
+            input_names=("x",))
+        assert eng.buckets[-1] == 4 and eng._ring.capacity == 4
+        eng.max_batch = 16
+        gate, entered = threading.Event(), threading.Event()
+        batches: list[list] = []
+        orig = eng._dispatch_batch
+
+        def gated(sealed):
+            batches.append([it.future for it in sealed.items])
+            entered.set()
+            gate.wait(timeout=60)
+            return orig(sealed)
+
+        eng._dispatch_batch = gated
+        split0 = metrics.get_counter(
+            "evam_engine_oversize_splits", labels={"engine": "slot-split"})
         try:
-            eng.submit(x=np.zeros((4,), np.float32)).result(timeout=30)
-            with pytest.raises(ValueError, match="staging ring"):
-                eng.submit(x=np.zeros((5,), np.float32))
+            first = eng.submit(x=np.full((3,), -1.0, np.float32))
+            assert entered.wait(timeout=30)  # dispatcher parked
+            futs = [eng.submit(x=np.full((3,), float(i), np.float32))
+                    for i in range(10)]
+            gate.set()
+            for i, f in enumerate(futs):
+                np.testing.assert_array_equal(
+                    f.result(timeout=30), np.full((3,), float(i)))
+            # ONE pick of ten against a four-row block: 4 + 4 + 2
+            assert batches == [[first], futs[:4], futs[4:8], futs[8:]]
+            assert eng.stats.oversize_splits == 2
+            assert metrics.get_counter(
+                "evam_engine_oversize_splits",
+                labels={"engine": "slot-split"}) == split0 + 2
         finally:
+            gate.set()
             eng.stop()

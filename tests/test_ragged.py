@@ -16,7 +16,6 @@ from evam_tpu.engine.ragged import (
     ragged_mode,
 )
 from evam_tpu.engine.ringbuf import SlotRing
-from evam_tpu.obs.metrics import metrics
 from evam_tpu.sched.classes import SchedConfig
 
 #: "equal" for two differently-shaped programs computing the same rows
@@ -114,14 +113,6 @@ class TestRaggedMode:
         try:
             assert eng.ragged == "packed" and eng._packed
             assert eng._ring.ragged is SPEC
-        finally:
-            eng.stop()
-
-    def test_legacy_assembly_forces_off(self):
-        eng = _engine("rag-legacy", ragged="packed", step=_dense_step,
-                      assembly="legacy")
-        try:
-            assert eng.ragged == "off" and not eng._packed
         finally:
             eng.stop()
 
@@ -225,13 +216,12 @@ class TestPackedIdentity:
 
 class TestRaggedSched:
     def test_scatter_back_ordering_under_class_queues(self):
-        """The sched dispatcher stages class-ordered picks through
-        stage_direct: each future must still resolve to ITS OWN boxes'
+        """The dispatcher stages class-ordered picks through the
+        ring: each future must still resolve to ITS OWN boxes'
         rows whatever class interleaving dispatch chose."""
         cfg = SchedConfig(deadline_ms={"realtime": 1.0, "standard": 2.0,
                                        "batch": 4.0})
-        eng = _engine("rag-sched", ragged="packed", sched=cfg,
-                      transfer="inline")
+        eng = _engine("rag-sched", ragged="packed", sched=cfg)
         try:
             rng = np.random.default_rng(2)
             futs, expects = [], []
@@ -256,30 +246,6 @@ class TestRaggedSched:
 
 
 class TestOversizeSplit:
-    def test_legacy_path_splits_past_top_bucket(self):
-        """_bucket() used to silently clamp n past the top bucket; the
-        dispatch paths now split the batch and count it."""
-        metrics.reset()
-        eng = BatchEngine(
-            "rag-oversize", lambda params, x: x * 2 + 1, None,
-            max_batch=16, deadline_ms=50.0, input_names=("x",),
-            stall_timeout_s=0, assembly="legacy")
-        try:
-            # shrink the ladder under the engine: max_batch admits 16
-            # items per formed batch but the top shape only fits 4
-            eng.buckets = [2, 4]
-            futs = [eng.submit(x=np.full((3,), i, np.uint8))
-                    for i in range(10)]
-            outs = [f.result(timeout=30) for f in futs]
-            for i, o in enumerate(outs):
-                np.testing.assert_array_equal(
-                    o, (np.full((3,), i, np.uint8) * 2 + 1))
-            assert eng.stats.oversize_splits >= 1
-            assert metrics.counter_total(
-                "evam_engine_oversize_splits") >= 1
-        finally:
-            eng.stop()
-
     def test_packed_unit_split_counts(self):
         """Sched + packed: a class pick whose units overflow the top
         unit block splits across batches and counts as oversize."""
@@ -287,7 +253,7 @@ class TestOversizeSplit:
                                        "standard": 30.0,
                                        "batch": 4.0})
         eng = _engine("rag-unit-split", ragged="packed", sched=cfg,
-                      transfer="inline", deadline_ms=30.0)
+                      deadline_ms=30.0)
         try:
             items = _items(8, seed=8, counts=[8])
             outs = _submit(eng, items, packed=True)
@@ -357,19 +323,31 @@ class TestSupervisorInheritsRagged:
             eng.stop()
 
 
+class _Item:
+    def __init__(self):
+        from concurrent.futures import Future
+
+        self.future = Future()
+
+
+def _stage(ring, rows, bucket):
+    """Stage ``rows`` ([(frames, boxes), ...]) as one pick; returns
+    (sealed, leftovers, items)."""
+    from evam_tpu.obs.trace import StageClock
+
+    staged = [({"frames": f, "boxes": bx}, _Item()) for f, bx in rows]
+    sealed, rest = ring.stage(staged, lambda n, u: bucket, StageClock())
+    return sealed, rest, [it for _, it in staged]
+
+
 class TestRaggedRing:
     def test_pack_seal_descriptor(self):
         ring = SlotRing(capacity=8, depth=2, ragged=SPEC)
-
-        class Item:
-            pass
-
         counts = [2, 0, 3, 1]
-        for k in counts:
-            ring.write({"frames": np.full((6,), k, np.uint8),
-                        "boxes": np.full((k, 4), float(k),
-                                         np.float32)}, Item())
-        sealed = ring.next_batch(0.01, lambda n, u: 8)
+        sealed, rest, items = _stage(ring, [
+            (np.full((6,), k, np.uint8),
+             np.full((k, 4), float(k), np.float32)) for k in counts], 8)
+        assert rest == [] and sealed.items == items
         assert sealed.n == 4 and sealed.units == 6
         np.testing.assert_array_equal(sealed.row_len, counts)
         np.testing.assert_array_equal(sealed.row_offset, [0, 2, 2, 5])
@@ -382,22 +360,50 @@ class TestRaggedRing:
         # pad tail of the packed block is zeroed
         assert np.all(sealed.arrays["boxes"][6:] == 0)
         ring.release(sealed)
+        # the block's next use: what the first left in the unit block
+        # and the seg vector past the new fill is masked again
+        sealed, _, _ = _stage(ring, [
+            (np.full((6,), 9, np.uint8),
+             np.full((1, 4), 9.0, np.float32))], 8)
+        np.testing.assert_array_equal(sealed.arrays["seg"][:1], [0])
+        assert np.all(sealed.arrays["seg"][1:] == -1)
+        assert np.all(sealed.arrays["boxes"][1:] == 0)
+        assert np.all(sealed.arrays["frames"][1:] == 0)
+        ring.release(sealed)
+        ring.close()
+
+    def test_unit_overflow_comes_back_in_order(self):
+        """A pick whose packed unit rows overflow the block's unit
+        capacity (before its batch rows run out) seals what fits and
+        hands the rest back, in order."""
+        ring = SlotRing(capacity=4, depth=2, ragged=SPEC)
+        assert ring.unit_capacity == SPEC.unit_rows(4) == 16
+        rows = [(np.full((6,), i, np.uint8),
+                 np.full((8, 4), float(i), np.float32))
+                for i in range(3)]
+        sealed, rest, items = _stage(ring, rows, 4)
+        assert sealed.n == 2 and sealed.units == 16
+        assert sealed.items == items[:2]
+        assert [it for _, it in rest] == items[2:]
+        np.testing.assert_array_equal(sealed.row_offset, [0, 8])
         ring.close()
 
     def test_ragged_shape_check(self):
+        """Too many unit rows, or a wrong unit shape, fails that
+        item's future only."""
         ring = SlotRing(capacity=4, depth=2, ragged=SPEC)
-
-        class Item:
-            pass
-
-        ring.write({"frames": np.zeros((6,), np.uint8),
-                    "boxes": np.zeros((2, 4), np.float32)}, Item())
-        with pytest.raises(ValueError):
-            ring.write({"frames": np.zeros((6,), np.uint8),
-                        "boxes": np.zeros((9, 4), np.float32)}, Item())
-        with pytest.raises(ValueError):
-            ring.write({"frames": np.zeros((6,), np.uint8),
-                        "boxes": np.zeros((2, 5), np.float32)}, Item())
+        f = np.zeros((6,), np.uint8)
+        sealed, rest, items = _stage(ring, [
+            (f, np.zeros((2, 4), np.float32)),
+            (f, np.zeros((9, 4), np.float32)),   # > max_units
+            (f, np.zeros((2, 5), np.float32)),   # wrong unit shape
+            (f, np.zeros((1, 4), np.float32))], 4)
+        assert rest == []
+        assert sealed.items == [items[0], items[3]]
+        np.testing.assert_array_equal(sealed.row_len, [2, 1])
+        for i in (1, 2):
+            with pytest.raises(ValueError, match="ragged input"):
+                items[i].future.result(timeout=0)
         ring.close()
 
 

@@ -2,7 +2,7 @@
 control, priority-class scheduling, and load shedding.
 
 Deterministic by construction — the flood tests gate the engine's
-device call on a threading.Event instead of hoping a race lands, so
+dispatcher on a threading.Event instead of hoping a race lands, so
 the overload ladder (admit → queue → shed) is asserted exactly:
 
 * an over-capacity start is rejected (503 path = AdmissionError),
@@ -10,9 +10,9 @@ the overload ladder (admit → queue → shed) is asserted exactly:
 * under a synthetic flood, realtime-class frames are never shed while
   batch-class sheds are nonzero and counted in
   ``evam_sched_shed_total{class}``;
-* with scheduling disabled (EVAM_SCHED=off / sched=None) the legacy
-  single-FIFO engine path is used unchanged (A/B, like
-  EVAM_BATCH_ASSEMBLY=legacy).
+* with scheduling disabled (EVAM_SCHED=off / sched=None) the same
+  dispatch loop runs as one FIFO: one class, the engine's deadline,
+  nothing shed.
 
 Marker-gated (``-m "not sched"`` skips) but NOT slow — this is the
 tier-1 contract suite for the subsystem, like ``chaos``.
@@ -315,6 +315,27 @@ class TestAdmission:
 # ---------------------------------------------------------------- engine
 
 
+def _gate_dispatcher(eng: BatchEngine):
+    """Park the dispatcher at its hand-over to the device path
+    (``_dispatch_batch``: the batch is staged, nothing is uploaded)
+    until ``gate`` is set, so everything submitted meanwhile stays in
+    the class queues. ``batches`` records, in dispatch order, the
+    futures each batch carried."""
+    gate = threading.Event()
+    entered = threading.Event()
+    batches: list[list[Future]] = []
+    orig = eng._dispatch_batch
+
+    def gated(sealed):
+        batches.append([it.future for it in sealed.items])
+        entered.set()
+        gate.wait(timeout=60)
+        return orig(sealed)
+
+    eng._dispatch_batch = gated
+    return gate, entered, batches
+
+
 class TestEngineSched:
     def test_classes_all_resolve(self):
         eng = _toy_engine("sched-ok", sched=SchedConfig())
@@ -336,28 +357,18 @@ class TestEngineSched:
             eng.stop()
 
     def test_flood_sheds_batch_never_realtime(self):
-        """The acceptance gate: gate the device call on an Event so a
+        """The acceptance gate: gate the dispatcher on an Event so a
         backlog builds deterministically; realtime (10s budget) rides
         it out, batch (40ms budget) is shed oldest-first and counted
         in evam_sched_shed_total{class}."""
         cfg = SchedConfig(staleness_ms={
             "realtime": 10_000.0, "standard": 10_000.0, "batch": 40.0})
-        # inline transfer: the gate patches the serial device call, so
-        # the DISPATCHER must be the thread that blocks on it — with
-        # the pipelined transfer the dispatcher would keep draining
-        # the class queues into the upload pipeline and the backlog
-        # this test asserts on would live there instead
-        eng = _toy_engine("sched-flood", sched=cfg, transfer="inline")
-        gate = threading.Event()
-        entered = threading.Event()
-        orig_run = eng._run
-
-        def gated_run(batch, clock=None):
-            entered.set()
-            gate.wait(timeout=60)
-            return orig_run(batch, clock=clock)
-
-        eng._run = gated_run
+        # the DISPATCHER must be the thread that blocks: gated at the
+        # launcher it would keep draining the class queues into the
+        # upload queue and the backlog this test asserts on would
+        # live there instead
+        eng = _toy_engine("sched-flood", sched=cfg)
+        gate, entered, _ = _gate_dispatcher(eng)
         shed0 = {
             c: metrics.get_counter("evam_sched_shed", labels={"class": c})
             for c in ("realtime", "batch")
@@ -401,54 +412,64 @@ class TestEngineSched:
             gate.set()
             eng.stop()
 
-    def test_sched_off_is_legacy_single_fifo(self):
-        """EVAM_SCHED=off A/B: sched=None keeps the pre-sched engine —
-        no class queues, no shedder, priority accepted and ignored,
-        FIFO results identical."""
+    def test_sched_off_is_one_fifo(self):
+        """EVAM_SCHED=off: sched=None is the same loop read as one
+        FIFO — every submit joins ``standard`` whatever priority it
+        names (and none is validated), dispatch order is submit order
+        across the named priorities, and nothing is shed however old
+        (10 s here: past every class's default budget)."""
         eng = _toy_engine("sched-off")
+        gate, entered, batches = _gate_dispatcher(eng)
         try:
-            assert eng._classq is None
-            assert eng._shedder is None
             assert eng.sched is None
-            assert eng.class_depths() == {}
-            assert eng.shed_counts() == {}
-            futs = [eng.submit(priority="batch", x=_x(i)) for i in range(6)]
-            for i, f in enumerate(futs):
+            first = eng.submit(priority="batch", x=_x(0))
+            assert entered.wait(timeout=30)  # dispatcher is now gated
+            names = ["batch", "realtime", "standard", "turbo",
+                     "realtime", "batch", "realtime", "batch"]
+            futs = [eng.submit(priority=p, x=_x(i + 1))
+                    for i, p in enumerate(names)]
+            assert eng.class_depths() == {
+                "realtime": 0, "standard": len(names), "batch": 0}
+            with eng._classq._cv:
+                for it in eng._classq._q["standard"]:
+                    it.t_submit -= 10.0
+            assert eng.queue_age_s() >= 10.0
+            gate.set()
+            for i, f in enumerate([first] + futs):
                 np.testing.assert_allclose(
                     f.result(timeout=60), np.full((2,), 2.0 * i))
+            assert sum(batches, []) == [first] + futs
+            assert eng.shed_counts() == {
+                "realtime": 0, "standard": 0, "batch": 0}
         finally:
+            gate.set()
             eng.stop()
 
-    def test_sched_with_legacy_assembly(self):
-        """QoS scheduling composes with EVAM_BATCH_ASSEMBLY=legacy
-        (stack+concat instead of the staging ring)."""
-        eng = _toy_engine("sched-legacy", sched=SchedConfig(),
-                          assembly="legacy")
+    def test_sched_off_forms_under_engine_deadline(self):
+        """sched=None: whatever priority a submit names, its batch
+        forms under the engine's ``deadline_ms`` (300 ms here), not
+        under that class's scheduler default (realtime: 4 ms) — two
+        submits 50 ms apart ride ONE batch, dispatched no earlier
+        than the deadline past the first."""
+        eng = _toy_engine("sched-off-deadline", deadline_ms=300.0)
         try:
-            assert eng._ring is None and eng._classq is not None
-            futs = [eng.submit(priority=p, x=_x(i)) for i, p in
-                    enumerate(["realtime", "batch", "standard"])]
-            for i, f in enumerate(futs):
-                np.testing.assert_allclose(
-                    f.result(timeout=60), np.full((2,), 2.0 * i))
+            t0 = time.perf_counter()
+            f1 = eng.submit(priority="realtime", x=_x(1.0))
+            time.sleep(0.05)
+            f2 = eng.submit(priority="realtime", x=_x(2.0))
+            f1.result(timeout=60)
+            f2.result(timeout=60)
+            assert time.perf_counter() - t0 >= 0.3
+            assert eng.stats.batches == 1 and eng.stats.items == 2
         finally:
             eng.stop()
 
     def test_stop_fails_queued_items(self):
         cfg = SchedConfig()
-        # inline: the gate must block the dispatcher (see the flood
-        # test) so the stuck submits stay queued until stop()
-        eng = _toy_engine("sched-stop", sched=cfg, transfer="inline")
-        gate = threading.Event()
-        entered = threading.Event()
-        orig_run = eng._run
-
-        def gated_run(batch, clock=None):
-            entered.set()
-            gate.wait(timeout=60)
-            return orig_run(batch, clock=clock)
-
-        eng._run = gated_run
+        # the gate blocks the dispatcher (see the flood test) so the
+        # stuck submits stay queued until stop()
+        eng = _toy_engine("sched-stop", sched=cfg)
+        gate, entered, _ = _gate_dispatcher(eng)
         eng.submit(priority="realtime", x=_x())
         assert entered.wait(timeout=30)
         stuck = [eng.submit(priority="batch", x=_x()) for _ in range(3)]
@@ -523,7 +544,6 @@ class TestSettingsPlumbing:
         from evam_tpu.server.registry import PipelineRegistry
 
         monkeypatch.setenv("EVAM_BATCH_DEADLINE_MS", "11.5")
-        monkeypatch.setenv("EVAM_TRANSFER", "inline")
         monkeypatch.setenv("EVAM_SCHED", "on")
         monkeypatch.setenv("EVAM_SCHED_ADMIT_UTIL", "0.7")
         monkeypatch.setenv("EVAM_SCHED_DEADLINE_MS_BATCH", "40")
@@ -532,13 +552,9 @@ class TestSettingsPlumbing:
         settings = settings.model_copy(
             update={"pipelines_dir": str(REPO / "pipelines")})
         assert settings.tpu.batch_deadline_ms == 11.5
-        assert settings.tpu.transfer == "inline"
         reg = PipelineRegistry(settings)
         try:
             assert reg.hub.deadline_ms == 11.5
-            # EVAM_TRANSFER reaches the hub (and through its factory,
-            # every engine and every supervisor rebuild)
-            assert reg.hub.transfer == "inline"
             assert reg.hub.sched is not None
             assert reg.hub.sched.admit_util == 0.7
             assert reg.hub.sched.deadline_ms["batch"] == 40.0
@@ -575,9 +591,10 @@ class TestSettingsPlumbing:
         finally:
             reg.stop_all()
 
-    def test_supervised_rebuild_inherits_class_queues(self):
+    def test_supervised_rebuild_inherits_sched_config(self):
         """The factory closure carries the sched config, so a
-        supervisor-rebuilt engine keeps its class queues."""
+        supervisor-rebuilt engine keeps its classes (and goes on
+        validating the priority a submit names)."""
         from evam_tpu.engine.hub import EngineHub
 
         hub = EngineHub(registry=None, plan=None, max_batch=4,
@@ -585,14 +602,15 @@ class TestSettingsPlumbing:
                         stall_timeout_s=0)
         eng = hub._build("toy", lambda params, x: x + 1.0, None, ("x",))
         try:
-            assert eng._classq is not None  # delegated to live engine
+            assert eng.sched is hub.sched  # delegated to live engine
             out = eng.submit(priority="realtime", x=_x(1.0)).result(
                 timeout=60)
             np.testing.assert_allclose(out, np.full((2,), 2.0))
             rebuilt = eng._factory()
             try:
-                assert rebuilt._classq is not None
                 assert rebuilt.sched is eng.sched
+                with pytest.raises(ValueError, match="priority"):
+                    rebuilt.submit(priority="turbo", x=_x())
             finally:
                 rebuilt.stop()
         finally:

@@ -103,13 +103,14 @@ class TestSupervisedEngine:
         try:
             sup.submit(x=np.zeros((3,), np.float32)).result(timeout=30)
             _wedge_env(monkeypatch, "wedge=1,wedge_s=2")
+            # one item at a time (see the hub-level twin below)
             deadline = time.time() + 60
             while sup.state != "degraded" and time.time() < deadline:
                 try:
-                    sup.submit(x=np.zeros((3,), np.float32))
+                    sup.submit(
+                        x=np.zeros((3,), np.float32)).result(timeout=15)
                 except (TimeoutError, RuntimeError):
-                    pass
-                time.sleep(0.05)
+                    time.sleep(0.05)
             assert sup.state == "degraded"
             assert sup.restarts == 2
             assert metrics.get_counter(
@@ -149,7 +150,8 @@ class TestSupervisedEngine:
             # fresh engine: local counters are zeroed...
             assert sup._engine.stats.items == 0
             # ...but the handle's view carried everything across
-            assert sup.shed_counts() == {"batch": 5}
+            assert sup.shed_counts() == {
+                "realtime": 0, "standard": 0, "batch": 5}
             assert sup.stats.batches >= pre_batches
             assert sup.stats.items >= pre_items
             assert sup.stats.stage_seconds.get("launch", 0.0) >= pre_launch
@@ -163,27 +165,62 @@ class TestSupervisedEngine:
 
     def test_dispatcher_death_triggers_rebuild(self):
         """The second wedge signal: a dispatcher thread that DIES
-        (not blocks) is detected by liveness, not the stalled flag."""
+        (not blocks) is detected by liveness, not the stalled flag.
+        The death is deterministic wherever the dispatcher is parked:
+        the loop runs the shedder's sweep at the top of every
+        iteration (at the latest 50 ms after the last, the pick's
+        time-out), and the sweep is what raises."""
         sup = SupervisedEngine(
             "sup-dispdeath", _toy_factory("sup-dispdeath"),
             max_restarts=3, restart_window_s=60.0, backoff_s=0.05)
         try:
             eng = sup._engine
+            out = sup.submit(
+                x=np.full((2,), 3.0, np.float32)).result(timeout=30)
+            np.testing.assert_allclose(out, 3.0)
 
             def boom(*a, **k):
                 raise RuntimeError("injected dispatcher death")
 
-            # patch while the dispatcher is parked inside the ORIGINAL
-            # next_batch call: the first submit is served by that call,
-            # and the loop's NEXT iteration hits the patched one
-            eng._ring.next_batch = boom
-            out = sup.submit(
-                x=np.full((2,), 3.0, np.float32)).result(timeout=30)
-            np.testing.assert_allclose(out, 3.0)
+            eng._shedder.sweep = boom
             _wait_for(lambda: not eng._dispatcher.is_alive(),
                       msg="dispatcher death")
+            assert not eng.stalled.is_set()
             _wait_for(lambda: sup.state == "running" and sup.restarts == 1,
                       msg="rebuild after dispatcher death")
+            assert sup._engine is not eng
+            out = sup.submit(
+                x=np.full((2,), 9.0, np.float32)).result(timeout=30)
+            np.testing.assert_allclose(out, 9.0)
+        finally:
+            sup.stop()
+
+    def test_launcher_death_triggers_rebuild(self):
+        """Every engine has a launcher thread, and its death is a
+        wedge signal of its own: the batch it died on never reaches
+        the device, so no stall deadline exists for the watchdog to
+        miss. The supervisor's teardown fails what sat in the upload
+        queue behind it."""
+        sup = SupervisedEngine(
+            "sup-launchdeath", _toy_factory("sup-launchdeath"),
+            max_restarts=3, restart_window_s=60.0, backoff_s=0.05)
+        try:
+            eng = sup._engine
+            sup.submit(
+                x=np.full((2,), 3.0, np.float32)).result(timeout=30)
+
+            def boom(*a, **k):
+                raise RuntimeError("injected launcher death")
+
+            # what the launcher's own except-clause does not guard
+            eng._track_dispatch = boom
+            sup.submit(x=np.full((2,), 4.0, np.float32))
+            _wait_for(lambda: not eng._launcher.is_alive(),
+                      msg="launcher death")
+            assert eng._dispatcher.is_alive()
+            _wait_for(lambda: sup.state == "running" and sup.restarts == 1,
+                      msg="rebuild after launcher death")
+            assert sup._engine is not eng
             out = sup.submit(
                 x=np.full((2,), 9.0, np.float32)).result(timeout=30)
             np.testing.assert_allclose(out, 9.0)
@@ -268,13 +305,17 @@ class TestHubSupervision:
         frame = np.zeros((64, 64, 3), np.uint8)
         eng.submit(frames=frame).result(timeout=60)
         _wedge_env(monkeypatch, "wedge=1,wedge_s=2", seed=1)
+        # one frame at a time, as a stream's window would hold it: a
+        # submit never blocks, so a loop that does not wait for its
+        # results queues batches behind the wedge, and every queued
+        # batch buys the watchdog's detection one more stall budget
+        # (BatchEngine._track_dispatch)
         deadline = time.time() + 40
         while eng.state != "degraded" and time.time() < deadline:
             try:
-                eng.submit(frames=frame)
+                eng.submit(frames=frame).result(timeout=15)
             except (TimeoutError, RuntimeError):
-                pass
-            time.sleep(0.05)
+                time.sleep(0.05)
         assert eng.state == "degraded"
         status, data = _request(sup_registry, "GET", "/healthz")
         assert status == 503

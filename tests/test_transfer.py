@@ -1,21 +1,28 @@
-"""Transfer pipeline A/B (EVAM_TRANSFER, engine/batcher.py): pipelined
-H2D-prefetch / launcher / async-D2H vs the inline serial path —
-bit-identical results, stage-clock attribution (h2d_issue / h2d_wait /
-readback residual), devlock degradation to inline, supervisor rebuilds
-inheriting the mode, and the queue-gauge refresh satellite."""
+"""The device path of the engine (engine/batcher.py): the dispatcher
+issues the upload, the launcher thread launches and puts the readback
+in flight, the completer resolves — stage-clock attribution
+(h2d_issue / h2d_wait / readback residual), what happens to a batch
+whose upload or launch raises, stop/abandon with batches parked
+between the threads, supervisor rebuilds inheriting the upload-queue
+depth, and the queue-gauge refresh satellite."""
 
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
 import pytest
 
-from evam_tpu.engine import devlock
+from evam_tpu.config.settings import reset_settings
+from evam_tpu.control import state as control_state
+from evam_tpu.control.state import ZERO_SIGNALS, OperatingPoint
+from evam_tpu.engine import batcher
 from evam_tpu.engine.batcher import BatchEngine
 from evam_tpu.engine.ringbuf import STAGES
 from evam_tpu.obs import faults
 from evam_tpu.obs.metrics import metrics
+from evam_tpu.sched.classes import SchedConfig
 
 
 def _engine(name: str, **kw) -> BatchEngine:
@@ -38,138 +45,213 @@ def _rows(n: int, seed: int = 0) -> list[np.ndarray]:
     return [rng.integers(0, 255, (6, 4), np.uint8) for _ in range(n)]
 
 
-class TestTransferModes:
-    def test_pipelined_is_default_with_launcher_thread(self):
-        eng = _engine("xfer-default")
+def _x(v: int) -> np.ndarray:
+    return np.full((4,), v, np.uint8)
+
+
+def _gate_launcher(eng: BatchEngine):
+    """Park the launcher inside ``_launch`` (where a hung backend RPC
+    would hold it) until ``gate`` is set; the dispatcher keeps staging
+    and uploading behind it."""
+    gate = threading.Event()
+    entered = threading.Event()
+    orig = eng._launch
+
+    def gated(dev, clock, b):
+        entered.set()
+        gate.wait(timeout=60)
+        return orig(dev, clock, b)
+
+    eng._launch = gated
+    return gate, entered
+
+
+def _free_blocks(eng: BatchEngine) -> int:
+    with eng._ring._cv:
+        return len(eng._ring._free)
+
+
+def _await(cond, timeout: float = 10.0) -> bool:
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if cond():
+            return True
+        time.sleep(0.02)
+    return cond()
+
+
+class TestOnePath:
+    @pytest.mark.parametrize("kw", [
+        {},
+        {"sched": SchedConfig()},
+        {"sched": SchedConfig.disabled()},
+        {"staging_depth": 2, "transfer_depth": 1, "max_in_flight": 1},
+    ], ids=["plain", "sched", "sched-disabled", "shallow"])
+    def test_one_dispatch_loop_whatever_the_arguments(self, kw):
+        """Every engine starts the same three workers on the same
+        three loops — the ones ``serve`` runs — and serves through
+        them."""
+        eng = _engine("xfer-one", **kw)
         try:
-            assert eng.transfer == "pipelined"
-            assert eng._pipelined
-            assert eng._launcher is not None and eng._launcher.is_alive()
-            out = eng.submit(x=np.full((4,), 7, np.uint8)).result(
-                timeout=30)
-            np.testing.assert_array_equal(out, np.full((4,), 22))
+            for thread, loop in ((eng._dispatcher, eng._dispatch_loop),
+                                 (eng._launcher, eng._launch_loop),
+                                 (eng._completer, eng._completion_loop)):
+                assert thread.is_alive()
+                assert thread._args == (loop,)
+            outs = [eng.submit(x=_x(i)).result(timeout=30)
+                    for i in range(5)]
+            for i, out in enumerate(outs):
+                np.testing.assert_array_equal(out, _x(i * 3 + 1))
         finally:
             eng.stop()
-
-    def test_inline_env_var_selects_serial_path(self, monkeypatch):
-        monkeypatch.setenv("EVAM_TRANSFER", "inline")
-        eng = _engine("xfer-inline-env")
-        try:
-            assert eng.transfer == "inline"
-            assert not eng._pipelined and eng._launcher is None
-            out = eng.submit(x=np.full((4,), 1, np.uint8)).result(
-                timeout=30)
-            np.testing.assert_array_equal(out, np.full((4,), 4))
-        finally:
-            eng.stop()
-
-    def test_explicit_arg_beats_env(self, monkeypatch):
-        monkeypatch.setenv("EVAM_TRANSFER", "inline")
-        eng = _engine("xfer-arg", transfer="pipelined")
-        try:
-            assert eng.transfer == "pipelined" and eng._pipelined
-        finally:
-            eng.stop()
-
-    def test_invalid_transfer_rejected(self):
-        with pytest.raises(ValueError, match="EVAM_TRANSFER"):
-            _engine("xfer-bad", transfer="sideways")
-
-    def test_pipelined_and_inline_outputs_bit_identical(self):
-        rows = _rows(40, seed=3)
-        results = {}
-        for mode in ("pipelined", "inline"):
-            eng = _engine(f"xfer-ab-{mode}", transfer=mode)
-            try:
-                futs = [eng.submit(x=r) for r in rows]
-                results[mode] = [f.result(timeout=30) for f in futs]
-            finally:
-                eng.stop()
-        for a, b in zip(results["pipelined"], results["inline"]):
-            assert a.dtype == b.dtype and a.shape == b.shape
-            assert a.tobytes() == b.tobytes()
 
     def test_stage_clock_reports_transfer_split(self):
-        """Both modes must keep the full STAGES clock: h2d_issue and
-        h2d_wait land in stats (inline pins h2d_wait at exactly 0 —
-        the launch call absorbs any wait there by definition)."""
-        for mode in ("pipelined", "inline"):
-            eng = _engine(f"xfer-clock-{mode}", transfer=mode)
-            try:
-                # the clock leaves cold buckets out: compile them first
-                eng.set_example(x=_rows(1)[0])
-                eng.warmup()
-                futs = [eng.submit(x=r) for r in _rows(20, seed=4)]
-                for f in futs:
-                    f.result(timeout=30)
-                st = eng.stats
-                assert set(st.stage_seconds) == set(STAGES), mode
-                assert st.stage_seconds["h2d_issue"] >= 0.0
-                assert st.stage_seconds["h2d_wait"] >= 0.0
-                if mode == "inline":
-                    assert st.stage_seconds["h2d_wait"] == 0.0
-                assert set(st.stage_ms_per_batch()) == set(STAGES)
-            finally:
-                eng.stop()
+        """The full STAGES clock lands in stats: h2d_issue from the
+        dispatcher, h2d_wait and launch from the launcher."""
+        eng = _engine("xfer-clock")
+        try:
+            # the clock leaves cold buckets out: compile them first
+            eng.set_example(x=_rows(1)[0])
+            eng.warmup()
+            futs = [eng.submit(x=r) for r in _rows(20, seed=4)]
+            for f in futs:
+                f.result(timeout=30)
+            st = eng.stats
+            assert set(st.stage_seconds) == set(STAGES)
+            assert st.stage_seconds["h2d_issue"] >= 0.0
+            assert st.stage_seconds["h2d_wait"] >= 0.0
+            assert set(st.stage_ms_per_batch()) == set(STAGES)
+        finally:
+            eng.stop()
 
-    def test_sched_class_queues_compose_with_pipelined(self):
-        from evam_tpu.sched.classes import SchedConfig
-
+    def test_classes_ride_the_launcher(self):
         eng = _engine("xfer-sched", sched=SchedConfig())
         try:
-            assert eng._pipelined and eng._classq is not None
-            futs = [eng.submit(priority=p, x=np.full((4,), i, np.uint8))
+            futs = [eng.submit(priority=p, x=_x(i))
                     for i, p in enumerate(
                         ["realtime", "batch", "standard"])]
             for i, f in enumerate(futs):
                 np.testing.assert_array_equal(
-                    f.result(timeout=30), np.full((4,), i * 3 + 1))
+                    f.result(timeout=30), _x(i * 3 + 1))
         finally:
             eng.stop()
 
-    def test_legacy_assembly_composes_with_pipelined(self):
-        eng = _engine("xfer-legacy", assembly="legacy")
+
+class TestBatchFailures:
+    """A batch that cannot reach the device, or fails on it, fails
+    ITS futures, gives its staging block back, and the engine goes on
+    serving."""
+
+    def test_upload_that_raises_fails_that_batch_only(self, monkeypatch):
+        eng = _engine("xfer-h2d-raise", staging_depth=2)
         try:
-            assert eng._pipelined and eng._ring is None
-            outs = [eng.submit(x=np.full((4,), i, np.uint8))
-                    .result(timeout=30) for i in range(10)]
-            assert [int(o[0]) for o in outs] == [i * 3 + 1
-                                                for i in range(10)]
+            eng.submit(x=_x(1)).result(timeout=30)
+            # take the explicit device_put arm of the dispatcher (a
+            # TPU's; works on the CPU backend too) and make the next
+            # upload raise
+            eng._device_streams = True
+            real = batcher.jax.device_put
+            calls = []
+
+            def flaky(*a, **k):
+                calls.append(1)
+                if len(calls) == 1:
+                    raise RuntimeError("injected upload failure")
+                return real(*a, **k)
+
+            monkeypatch.setattr(batcher.jax, "device_put", flaky)
+            with pytest.raises(RuntimeError, match="injected upload"):
+                eng.submit(x=_x(2)).result(timeout=30)
+            np.testing.assert_array_equal(
+                eng.submit(x=_x(3)).result(timeout=30), _x(10))
+            assert len(calls) >= 2
+            assert eng._dispatcher.is_alive()
+            assert _await(lambda: _free_blocks(eng) == 2)
         finally:
             eng.stop()
 
-
-class TestSerializeCompileForcesInline:
-    def test_devlock_degrades_pipelined_to_inline(self, monkeypatch):
-        """EVAM_SERIALIZE_COMPILE=1: device RPCs must never overlap,
-        so a pipelined request degrades to the inline serial path at
-        construction and the devlock gauge pins overlap_max at 1 (the
-        TestSerializeCompile harness contract)."""
-        monkeypatch.setenv("EVAM_SERIALIZE_COMPILE", "1")
-        devlock.reset_stats()
-        eng = _engine("xfer-devlock", transfer="pipelined")
+    def test_launch_that_raises_fails_that_batch_only(self):
+        eng = _engine("xfer-launch-raise", staging_depth=2)
         try:
-            assert eng.transfer == "pipelined"  # the request...
-            assert not eng._pipelined           # ...forced inline
-            assert eng._launcher is None
-            outs = [eng.submit(x=np.full((4,), i, np.uint8))
-                    .result(timeout=30) for i in range(20)]
-            assert [int(o[0]) for o in outs] == [(i * 3 + 1) % 256
-                                                for i in range(20)]
+            eng.submit(x=_x(1)).result(timeout=30)
+            orig = eng._launch
+            fails = [True]
+
+            def flaky(dev, clock, b):
+                if fails.pop() if fails else False:
+                    raise RuntimeError("injected launch failure")
+                return orig(dev, clock, b)
+
+            eng._launch = flaky
+            with pytest.raises(RuntimeError, match="injected launch"):
+                eng.submit(x=_x(2)).result(timeout=30)
+            np.testing.assert_array_equal(
+                eng.submit(x=_x(3)).result(timeout=30), _x(10))
+            assert eng._launcher.is_alive()
+            # the failed batch holds no in-flight slot, no watchdog
+            # entry and no staging block
+            assert not eng._outstanding
+            assert _await(lambda: _free_blocks(eng) == 2)
         finally:
             eng.stop()
-        assert devlock.max_concurrent() == 1
+
+    @pytest.mark.parametrize("how", ["stop", "abandon"])
+    def test_teardown_fails_uploaded_unlaunched_batches(self, how):
+        """With the launcher parked in a launch, the dispatcher keeps
+        staging and uploading: one batch is in flight, the next sits
+        in the upload queue. ``stop()``/``abandon()`` must fail the
+        queued batch's futures and return its block."""
+        eng = _engine(f"xfer-{how}", max_batch=1, staging_depth=4,
+                      transfer_depth=2)
+        gate, entered = _gate_launcher(eng)
+        try:
+            f1 = eng.submit(x=_x(1))
+            assert entered.wait(timeout=30)  # launcher holds batch 1
+            f2 = eng.submit(x=_x(2))
+            f3 = eng.submit(x=_x(3))
+            assert _await(lambda: eng._upload_q.qsize() == 2)
+            assert _free_blocks(eng) == 1  # 4 blocks, 3 batches staged
+            if how == "abandon":
+                eng.abandon()
+                err = TimeoutError
+            else:
+                threading.Timer(0.3, gate.set).start()
+                eng.stop()
+                err = RuntimeError
+            for f in (f2, f3):
+                with pytest.raises(err):
+                    f.result(timeout=10)
+            if how == "abandon":
+                # in flight behind the wedge: failed with the rest
+                with pytest.raises(TimeoutError):
+                    f1.result(timeout=10)
+                assert _free_blocks(eng) == 3
+            else:
+                # the launch ran to its end once the gate opened
+                np.testing.assert_array_equal(
+                    f1.result(timeout=10), _x(4))
+                assert _free_blocks(eng) == 4
+            assert eng._upload_q.qsize() == 0
+        finally:
+            gate.set()
+            eng.abandon()
 
 
-class TestSupervisorInheritsTransfer:
-    def test_rebuild_keeps_transfer_mode(self, monkeypatch):
-        """The factory closure is the rebuild recipe: a wedge-triggered
-        rebuild must come back with the same transfer mode (and a live
-        launcher thread) — EVAM_TRANSFER survives quarantine."""
+class TestSupervisorInheritsTransferDepth:
+    def test_rebuild_resumes_at_the_live_transfer_depth(
+            self, monkeypatch):
+        """The factory closure carries the boot depth, but a rebuild
+        reads the controller's live operating point first: an engine
+        rebuilt after a wedge comes back at the depth the controller
+        holds now, with a live launcher thread."""
         from evam_tpu.engine.supervisor import SupervisedEngine
 
+        monkeypatch.setenv("EVAM_TUNE", "on")
+        reset_settings()
+        control_state.reset_cache()
+
         def factory() -> BatchEngine:
-            return _engine("xfer-sup", transfer="pipelined",
+            return _engine("xfer-sup", transfer_depth=2,
                            max_batch=4, deadline_ms=1.0,
                            stall_timeout_s=0.5)
 
@@ -178,7 +260,10 @@ class TestSupervisorInheritsTransfer:
             max_restarts=3, restart_window_s=60.0, backoff_s=0.05)
         try:
             first = sup._engine
+            assert first.transfer_depth == 2
             sup.submit(x=np.zeros((4,), np.uint8)).result(timeout=30)
+            control_state.active().install(
+                OperatingPoint(transfer_depth=5), dict(ZERO_SIGNALS))
             monkeypatch.setenv("EVAM_FAULT_INJECT",
                                "wedge=1,wedge_n=1,wedge_s=4")
             faults.reset_cache()
@@ -192,8 +277,8 @@ class TestSupervisorInheritsTransfer:
                 time.sleep(0.05)
             assert sup.state == "running" and sup.restarts == 1
             assert sup._engine is not first
-            assert sup._engine.transfer == "pipelined"
-            assert sup._engine._pipelined
+            assert sup._engine.transfer_depth == 5
+            assert sup._engine._upload_q.maxsize == 5
             assert sup._engine._launcher.is_alive()
             monkeypatch.setenv("EVAM_FAULT_INJECT", "")
             faults.reset_cache()
@@ -202,21 +287,23 @@ class TestSupervisorInheritsTransfer:
             np.testing.assert_array_equal(out, np.full((4,), 16))
         finally:
             sup.stop()
+            monkeypatch.delenv("EVAM_TUNE")
+            reset_settings()
 
-    def test_hub_factory_carries_transfer(self):
+    def test_hub_factory_carries_transfer_depth(self):
         from evam_tpu.engine.hub import EngineHub
 
         hub = EngineHub(registry=None, plan=None, max_batch=4,
                         supervise=True, stall_timeout_s=0,
-                        transfer="inline")
+                        transfer_depth=3)
         eng = hub._build("xfer-hub", lambda params, x: x + 1.0,
                          None, ("x",))
         try:
-            assert eng.transfer == "inline"  # delegated to live engine
+            assert eng.transfer_depth == 3  # delegated to live engine
             rebuilt = eng._factory()
             try:
-                assert rebuilt.transfer == "inline"
-                assert not rebuilt._pipelined
+                assert rebuilt.transfer_depth == 3
+                assert rebuilt._upload_q.maxsize == 3
             finally:
                 rebuilt.stop()
         finally:

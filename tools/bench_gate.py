@@ -13,10 +13,8 @@ Three assertions, all gating (full mode):
 
 * **throughput uplift ≥ --min-uplift** — wall-clock frames/s through
   the stage chain, gated / ungated, as the MEDIAN of per-pair ratios
-  over --windows order-alternated window pairs (same pairing
-  discipline as tools/bench_transfer.py). The gate removes whole
-  engine round-trips, so unlike the transfer pipeline this win IS
-  expected on CPU;
+  over --windows order-alternated window pairs. The gate removes
+  whole engine round-trips, so this win IS expected on CPU;
 * **bounded detection staleness** — the gate never skipped more than
   ``gate-max-skip`` consecutive frames (every object re-validated
   within that bound), and every skipped frame still carried coasted

@@ -19,8 +19,8 @@ Four assertions, all gating (full mode):
   a number);
 * **occupancy-weighted throughput ≥ --min-ratio (1.0)** — real unit
   rows (regions) classified per second, packed / off, as the MEDIAN
-  of per-pair ratios over --windows order-alternated window pairs
-  (the bench_transfer pairing discipline). Pad rows are not useful
+  of per-pair ratios over --windows order-alternated window pairs.
+  Pad rows are not useful
   work, so units/s is the honest rate; the CPU gate is parity-plus —
   the masking overhead (per-unit frame gather + seg mask) must not
   eat the computed-rows saving. The full win is device-bound (fewer

@@ -6,7 +6,7 @@ Two properties hold or the exit code says so:
 1. **Off-identity** — with ``EVAM_TRACE=off`` every hook is a
    memoized-None check and the engine's outputs are BIT-IDENTICAL to
    the traced run (tracing observes, it never perturbs compute). Same
-   discipline as EVAM_TRANSFER / EVAM_GATE A/B.
+   discipline as EVAM_GATE A/B.
 2. **Overhead** — with sampling on (default 1-in-16 retention), the
    sustained submit->result throughput delta stays within
    ``--max-overhead`` (3% by default) of the off path.

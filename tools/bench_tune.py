@@ -9,7 +9,7 @@ Two properties hold or the exit code says so:
    engine's outputs are BIT-IDENTICAL to the tuned run while the
    operating point is neutral (the controller retunes WHEN it acts;
    the consult itself never perturbs compute). Same discipline as
-   EVAM_TRANSFER / EVAM_GATE / EVAM_TRACE A/B.
+   EVAM_GATE / EVAM_TRACE A/B.
 2. **Overhead** — with the controller enabled (neutral op, no
    actions — isolating the pure consult cost on the dispatch path),
    sustained submit->result throughput stays within
