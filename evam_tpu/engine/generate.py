@@ -22,10 +22,14 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   ``MAX_PREFILL_RUN`` chunks run between two decode steps: chunks run
   full, decoding is held up for two chunks at a time, never more, and
   a lone prompt waits one decode step at most.
-* The latent cache is one device array of pages (engine/pages.py);
-  every sequence's page table begins with the pages of the shared
-  instruction prefix, prefilled once in ``warm_async`` and never written
-  again, and ends with its own.
+* The latent cache is one device array of pages (engine/pages.py).
+  The pages of the shared instruction prefix are prefilled once in
+  ``warm_async``, never written again, and a constant of both programs:
+  a prefill chunk and a decode step each read them ONCE for all their
+  rows. A decode row's page table holds the sequence's own pages and no
+  others (``[bucket, private_pages]``), its context length counts own
+  rows, and the step merges the two parts of each row's softmax
+  (models/lm/deepseek_v2.py ``mla_decode``).
 * Sampling is greedy and there is no stop token: a request runs exactly
   ``max_new_tokens``, so the thread knows every step's make-up without
   reading a result. Each step's sampled ids stay on the device
@@ -250,6 +254,8 @@ class GenerateEngine:
                   if self._prefix_pages else None)
         n_cont = self._private_pages
         n_seg = sz.max_segments
+        # decoding starts after warm-up: the whole prefix is there
+        n_prefix_rows = len(self.prefix)
 
         def prefill(params, cache, last_ids, mat, aux):
             tokens, seg, pos, dest_page, dest_off = mat
@@ -267,7 +273,7 @@ class GenerateEngine:
             slot, pos, ctx_len, dest_page, dest_off, live = mat
             cache, top, ids, held = lm.decode_tokens(
                 cfg, params, cache, last_ids[slot], pos, page_table, ctx_len,
-                dest_page, dest_off, live > 0)
+                dest_page, dest_off, live > 0, shared, n_prefix_rows)
             return cache, last_ids.at[slot].set(ids[:, 0]), top, ids, held
 
         self._prefill = jax.jit(prefill, donate_argnums=(1, 2))
@@ -698,22 +704,21 @@ class GenerateEngine:
 
     def _dispatch_decode_raw(self, rows, bucket: int, takers) -> _Step:
         """``rows``: (slot, index of the own token fed back, own pages)
-        of each row that carries a sequence."""
+        of each row that carries a sequence. The table holds own pages
+        only and the context length counts own rows: the prefix is the
+        program's."""
         sz = self.sizes
-        n_pages = self._prefix_pages + self._private_pages
         mat = np.zeros((6, bucket), np.int32)
         mat[0] = sz.slots
         mat[2] = 1
-        table = np.zeros((bucket, n_pages), np.int32)
+        table = np.zeros((bucket, self._private_pages), np.int32)
         rows_read = 0
         for b, (slot, k, pages) in enumerate(rows):
             row = self._where(pages, k)
-            mat[:, b] = (slot, len(self.prefix) + k,
-                         len(self.prefix) + k + 1, row // sz.page_tokens,
-                         row % sz.page_tokens, 1)
-            table[b, :self._prefix_pages] = self._shared
-            table[b, self._prefix_pages:self._prefix_pages
-                  + len(pages)] = pages
+            mat[:, b] = (slot, len(self.prefix) + k, k + 1,
+                         row // sz.page_tokens, row % sz.page_tokens, 1)
+            table[b, :len(pages)] = pages
+            # a row's whole context, the prefix's rows among them
             rows_read += len(self.prefix) + k + 1
         return self._run("decode", f"decode:{bucket}", self._decode,
                          (mat, table), tokens=len(rows), rows_read=rows_read,
@@ -763,6 +768,9 @@ class GenerateEngine:
         st.batches += 1
         st.add_stage("launch", dt)
         if step.kind == "decode":
+            # row-key pairs that the one pass over the prefix served
+            metrics.inc("evam_generate_decode_shared_rows",
+                        float(len(self.prefix) * step.tokens))
             bucket = int(step.key.split(":")[1])
             st.bucket_batches[bucket] = st.bucket_batches.get(bucket, 0) + 1
             st.occupancy_sum += step.tokens / bucket

@@ -8,10 +8,12 @@ Per layer, pre-norm residual, RMSNorm:
   ``score = (q_nope . k_nope + rope(q_rope) . k_r) * s``. The cache holds
   ``[c_kv ; k_r]`` per token and layer. Attention runs in the ABSORBED
   form (``W_kvb``'s key half folded into the query, its value half into
-  the output), so every key is read as a latent row: decode through the
-  page table, a prefill chunk over prefix, continued and own rows in one
-  kernel (ops/pallas_mla.py). Heads are materialised only in the
-  reference.
+  the output), so every key is read as a latent row: a decode step in
+  two parts, all rows' queries against the shared prefix's rows in one
+  product and each row against its own pages through its page table,
+  merged by their softmax sums; a prefill chunk over prefix, continued
+  and own rows in one kernel (ops/pallas_mla.py). Heads are
+  materialised only in the reference.
 * **YaRN** rope (blended inverse frequencies) and its softmax scale.
 * **Layer 0** a dense SwiGLU; **later layers** a router over ALL
   ``n_routed_experts`` (softmax in float32, group-limited top-k), the
@@ -401,16 +403,44 @@ def _absorb_q(cfg, w_uk, q_nope, q_rope):
     return jnp.concatenate([q_lat, q_rope], axis=-1)
 
 
-def mla_decode(cfg: Config, lp: dict, q_nope, q_rope, ctx, ctx_len):
-    """One new token per row against its own cached rows ``ctx``
-    [B, T, latent] (the new token's row among them), absorbed form."""
+def _softmax_sums(cfg: Config, score_expr, value_expr, q, rows, visible):
+    """One part of a softmax that is split over its key rows: per query
+    row the float32 maximum ``m`` of its ``visible`` scores, the sum
+    ``l`` of ``exp(score - m)`` over them and those weights' sum of the
+    rows' values ``acc``, not yet divided by ``l``. A query that sees no
+    row gives ``l`` and ``acc`` 0."""
+    s = _es(score_expr, q, rows) * softmax_scale(cfg)
+    s = jnp.where(visible, s, pallas_mla.NEG)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.where(visible, jnp.exp(s - m), 0.0)
+    acc = _es(value_expr, p.astype(BF16), rows[..., :cfg.kv_rank])
+    return m, p.sum(axis=-1, keepdims=True), acc
+
+
+def mla_decode(cfg: Config, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
+               n_prefix):
+    """One new token per row, absorbed form, its softmax in two parts.
+    OWN: each row against its own cached rows ``ctx`` [B, T, latent]
+    (the new token's row among them), visible below ``ctx_len`` [B].
+    SHARED: the queries of all rows and heads against the prefix rows
+    ``prefix`` [Tp, latent] (visible below ``n_prefix``), which every
+    row shares and which are read once: one dense product. The parts
+    are merged by their softmax sums in float32 (the arithmetic of the
+    one softmax over prefix and own rows) before ``W_uv``. ``prefix``
+    may be None: the own part alone."""
     w_uk, w_uv = _kv_b(cfg, lp)
     q = _absorb_q(cfg, w_uk, q_nope, q_rope)
-    s = _es("bhc,btc->bht", q, ctx) * softmax_scale(cfg)
-    ok = jnp.arange(ctx.shape[1])[None, :] < ctx_len[:, None]
-    p = jax.nn.softmax(jnp.where(ok[:, None, :], s, -jnp.inf), axis=-1)
-    o_lat = _es("bht,btc->bhc", p.astype(BF16),
-                       ctx[..., :cfg.kv_rank]).astype(BF16)
+    own = jnp.arange(ctx.shape[1])[None, None, :] < ctx_len[:, None, None]
+    m, l, acc = _softmax_sums(cfg, "bhc,btc->bht", "bht,btc->bhc", q, ctx,
+                              own)
+    if prefix is not None:
+        seen = jnp.arange(prefix.shape[0]) < n_prefix
+        m_p, l_p, acc_p = _softmax_sums(cfg, "bhc,sc->bhs", "bhs,sc->bhc",
+                                        q, prefix, seen)
+        top = jnp.maximum(m, m_p)
+        w, w_p = jnp.exp(m - top), jnp.exp(m_p - top)
+        l, acc = w * l + w_p * l_p, w * acc + w_p * acc_p
+    o_lat = (acc / jnp.where(l > 0, l, 1.0)).astype(BF16)
     o = _es("bhc,hcv->bhv", o_lat, w_uv).astype(BF16)
     return _mm(o.reshape(o.shape[0], -1), lp["o"])
 
@@ -505,10 +535,12 @@ def prefill_chunk(cfg: Config, params: dict, cache, tokens, seg, pos,
 
 
 def decode_tokens(cfg: Config, params: dict, cache, tokens, pos, page_table,
-                  ctx_len, dest_page, dest_off, live):
+                  ctx_len, dest_page, dest_off, live, prefix_pages, n_prefix):
     """One token per row. Each row's latent is written to its page, then
-    the row attends through its page table to ``ctx_len`` cached rows
-    (its own among them)."""
+    the row attends to the ``n_prefix`` rows of the shared prefix
+    (``prefix_pages``, read once a layer for all rows; may be ``None``)
+    and, through its table of its OWN pages ``page_table`` [B, pages],
+    to its ``ctx_len`` own cached rows (the new one among them)."""
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     b = tokens.shape[0]
@@ -519,7 +551,8 @@ def decode_tokens(cfg: Config, params: dict, cache, tokens, pos, page_table,
             qn, qr, lat = _qkv(cfg, lp, h, pos)
             cache = cache.at[i, dest_page, dest_off].set(lat)
             ctx = cache[i][page_table].reshape(b, -1, cfg.latent)
-            x = x + mla_decode(cfg, lp, qn, qr, ctx, ctx_len)
+            x = x + mla_decode(cfg, lp, qn, qr, ctx, ctx_len,
+                               _rows(cfg, cache[i], prefix_pages), n_prefix)
         h = rms_norm(x, lp["post_norm"], cfg.eps)
         y, n = _mlp(cfg, lp, h, live)
         x = x + y

@@ -58,6 +58,9 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_generate_steps": ("counter", ("kind",)),
     "evam_generate_tokens": ("counter", ("kind",)),
     "evam_generate_latent_rows_read": ("counter", ("kind",)),
+    # of a decode step's rows read, those of the shared prefix: read
+    # once a step for all its live rows (prefix rows x live rows)
+    "evam_generate_decode_shared_rows": ("counter", ()),
     "evam_generate_queue_wait_seconds": ("histogram", ()),
     "evam_generate_slots_active": ("gauge", ()),
     "evam_generate_pages_in_use": ("gauge", ()),

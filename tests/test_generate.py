@@ -286,6 +286,86 @@ def test_next_step_kind(waiting, decoding, run, passed_over, want):
     assert next_step_kind(waiting, decoding, run, passed_over, 32) == want
 
 
+#: (prefix pages or None, n_prefix, own rows of each row of the bucket;
+#: a dead row holds the null page and a context of 1, as the engine
+#: pads). Pages hold 8 rows.
+DECODE_CASES = {
+    "no_prefix": (None, 0, [5, 9, 17]),
+    "prefix_and_an_own_length_of_1": ([1, 2], 16, [1, 1, 1]),
+    "own_rows_crossing_a_page": ([1, 2], 16, [8, 9, 16, 17]),
+    "a_bucket_with_dead_rows": ([1, 2], 16, [11, None, 3, None]),
+    "n_prefix_0_with_prefix_pages_given": ([1, 2], 0, [4, 12]),
+    "a_prefix_begun_only": ([1, 2], 5, [7, 24]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_two_part_decode_attention_is_the_one_softmax(case):
+    """``mla_decode`` (shared prefix rows in one pass for all rows, own
+    rows through the page table, merged by softmax sums) against ONE
+    softmax over prefix and own rows, per row, written out in float32
+    with materialised heads' arithmetic left absorbed."""
+    prefix_pages, n_prefix, own = DECODE_CASES[case]
+    cfg = lm.Config.from_dict(TINY)
+    lp = lm.make_layer(cfg, 1)
+    page, own_pages = 8, 3
+    rng = np.random.default_rng(sorted(DECODE_CASES).index(case))
+    b = len(own)
+
+    def bf16(a):
+        return jnp.asarray(a, lm.F32).astype(lm.BF16)
+
+    def f32(a):
+        return np.asarray(a.astype(lm.F32), np.float64)
+
+    def rows_of(ids):
+        return f32(cache)[ids].reshape(-1, cfg.latent)
+
+    cache = bf16(rng.normal(size=(3 + b * own_pages, page, cfg.latent)))
+    q_nope = bf16(rng.normal(size=(b, cfg.heads, cfg.nope)))
+    q_rope = bf16(rng.normal(size=(b, cfg.heads, cfg.rope)))
+    table = np.zeros((b, own_pages), np.int32)
+    ctx_len = np.ones(b, np.int32)
+    for i, n in enumerate(own):
+        if n is not None:
+            table[i] = 3 + i * own_pages + np.arange(own_pages)
+            ctx_len[i] = n
+    # as decode_tokens hands them over
+    pages = None if prefix_pages is None else np.asarray(prefix_pages)
+    got = lm.mla_decode(
+        cfg, lp, q_nope, q_rope,
+        cache[table].reshape(b, -1, cfg.latent), jnp.asarray(ctx_len),
+        lm._rows(cfg, cache, pages), n_prefix)
+    assert got.shape == (b, cfg.hidden)
+
+    w = f32(lp["kv_b"]).reshape(cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
+    w_uk, w_uv = w[..., :cfg.nope], w[..., cfg.nope:]
+    want, own_only = [], []
+    for i in range(b):
+        q_lat = np.einsum("hd,chd->hc", f32(q_nope)[i], w_uk)
+        q = np.concatenate([q_lat, f32(q_rope)[i]], axis=1)
+
+        def attend(keys):
+            s = q @ keys.T * lm.softmax_scale(cfg)
+            pr = np.exp(s - s.max(axis=1, keepdims=True))
+            pr /= pr.sum(axis=1, keepdims=True)
+            o = np.einsum("hc,chv->hv", pr @ keys[:, :cfg.kv_rank], w_uv)
+            return o.reshape(-1) @ f32(lp["o"])
+
+        mine = rows_of(table[i])[:ctx_len[i]]
+        seen = (rows_of(pages)[:n_prefix] if pages is not None
+                else mine[:0])
+        want.append(attend(np.concatenate([seen, mine])))
+        own_only.append(attend(mine))
+    want, own_only = np.asarray(want), np.asarray(own_only)
+    # bfloat16 roundings of the folded query, the softmax weights, the
+    # latent output and the result: 0.003 to 0.006 seen on values to 2
+    np.testing.assert_allclose(f32(got), want, atol=0.025, rtol=0)
+    if n_prefix:
+        # the prefix part is no rounding: without it 0.75 to 2.8 off
+        assert np.abs(own_only - want).max() > 0.25
+
+
 @pytest.mark.parametrize("length", [3, 20, 40])
 def test_prefill_then_decode_matches_the_reference(engine, length):
     """Through the engine and the page cache: packed prefill (40 tokens
@@ -384,7 +464,48 @@ def test_a_request_that_cannot_fit_is_refused(engine):
 def test_capacity_model_and_counters(engine):
     from evam_tpu.obs import metrics
 
-    _generate(engine, _prompt(5, 9))
+    tables, inner = [], engine._decode
+
+    def spy(params, cache, last_ids, mat, page_table):
+        tables.append((np.array(mat), np.array(page_table)))
+        return inner(params, cache, last_ids, mat, page_table)
+
+    def counted():
+        return {
+            "shared": metrics.get_counter(
+                "evam_generate_decode_shared_rows"),
+            "rows": metrics.get_counter(
+                "evam_generate_latent_rows_read", {"kind": "decode"}),
+            "tokens": metrics.get_counter(
+                "evam_generate_tokens", {"kind": "decode"})}
+
+    before = counted()
+    engine._decode = spy
+    try:
+        _generate(engine, _prompt(5, 9))
+    finally:
+        engine._decode = inner
+    # the last step is harvested one step late: wait for its counts
+    deadline = time.time() + 10
+    while (counted()["tokens"] - before["tokens"] < NEW - 1
+           and time.time() < deadline):
+        time.sleep(0.05)
+    grew = {k: v - before[k] for k, v in counted().items()}
+    # a decode row's table: the sequence's OWN pages and no pinned one;
+    # its context length counts own rows, its position all of them
+    assert len(tables) == NEW - 1
+    for step, (mat, table) in enumerate(tables):
+        assert table.shape == (mat.shape[1], engine._private_pages) == (
+            SIZES.slot_buckets[0], 6)
+        assert not set(table.ravel()) & set(engine._pool.pinned)
+        assert mat[5].sum() == 1 and mat[5, 0] == 1
+        assert mat[2, 0] == 9 + step + 1 and mat[1, 0] == 16 + 9 + step
+        assert (table[0, :2] > 0).all() and (table[1:] == 0).all()
+    # the one pass over the prefix served prefix rows x decode tokens;
+    # the rows read are what they were: every row's whole context
+    assert grew["tokens"] == NEW - 1
+    assert grew["shared"] == 16 * (NEW - 1)
+    assert grew["rows"] == sum(16 + 9 + step + 1 for step in range(NEW - 1))
     # every program has a LOADED step's time from warm-up on
     assert set(engine._program_s) == {"prefill"} | {
         f"decode:{b}" for b in SIZES.slot_buckets}
@@ -393,6 +514,7 @@ def test_capacity_model_and_counters(engine):
     for series in ('evam_generate_steps_total{kind="decode"}',
                    'evam_generate_tokens_total{kind="prefill"}',
                    'evam_generate_latent_rows_read_total{kind="decode"}',
+                   "evam_generate_decode_shared_rows_total",
                    "evam_moe_held_assignments_total",
                    "evam_generate_queue_wait_seconds_count",
                    "evam_generate_slots_active",
