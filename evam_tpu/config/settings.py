@@ -261,9 +261,11 @@ class LMSettings(BaseModel):
     EVAM_LM_SHAPES exists for rehearsals and tests at a tiny size, and no
     deployment file sets it."""
 
-    #: sequence slots: generations in flight on the device
+    #: sequence slots: generations in flight on the device; a family
+    #: with recurrent layers keeps one row of state per slot
     slots: int = 128
-    #: tokens per page of the latent cache
+    #: tokens per page of the family's cache (latent rows, or keys and
+    #: values)
     page_tokens: int = 128
     #: prompt tokens one prefill step packs, of at most max_segments
     #: sequences

@@ -22,14 +22,30 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   ``MAX_PREFILL_RUN`` chunks run between two decode steps: chunks run
   full, decoding is held up for two chunks at a time, never more, and
   a lone prompt waits one decode step at most.
-* The latent cache is one device array of pages (engine/pages.py).
-  The pages of the shared instruction prefix are prefilled once in
-  ``warm_async``, never written again, and a constant of both programs:
-  a prefill chunk and a decode step each read them ONCE for all their
-  rows. A decode row's page table holds the sequence's own pages and no
-  others (``[bucket, private_pages]``), its context length counts own
-  rows, and the step merges the two parts of each row's softmax
-  (models/lm/deepseek_v2.py ``mla_decode``).
+* The model FAMILY is chosen at construction from the installed config's
+  ``model_type`` (models/lm ``family``) and says what device state its
+  sequences have (``state_shapes``): a pytree that both programs donate
+  and update in place. ``pages`` is the cache of the layers that attend
+  (engine/pages.py): latent rows in every layer of DeepSeek-V2, key and
+  value rows in Jamba's two attention layers. The pages of the shared
+  instruction prefix are prefilled once in ``warm_async``, never written
+  again, and a constant of both programs: a prefill chunk and a decode
+  step each read them ONCE for all their rows. A decode row's page table
+  holds the sequence's own pages and no others (``[bucket,
+  private_pages]``), its context length counts own rows, and the step
+  merges the two parts of each row's softmax (models/lm/common.py
+  ``merge_softmax_sums``).
+* A family with recurrent layers (Jamba's Mamba layers) also keeps state
+  per SLOT, never paged: arrays ``[layers, slots + 2, ...]`` that every
+  decode step reads and writes at its rows' slots. Row ``slots`` is the
+  null row (rows of a step that carry no sequence), row ``slots + 1`` the
+  PREFIX SNAPSHOT: the state after the shared prefix's last token, left
+  there by warm-up's prefill of the prefix. A prefill chunk tells the
+  program, per segment, the row its state starts from (the snapshot for
+  a new sequence, the slot's own for a prompt that continues from the
+  chunk before) and the row its end state goes to (the slot). A new
+  sequence never starts from its slot, so a slot taken again carries
+  nothing over.
 * Sampling is greedy and there is no stop token: a request runs exactly
   ``max_new_tokens``, so the thread knows every step's make-up without
   reading a result. Each step's sampled ids stay on the device
@@ -58,7 +74,7 @@ import numpy as np
 
 from evam_tpu.engine.batcher import EngineStats
 from evam_tpu.engine.pages import PagePool
-from evam_tpu.models.lm import deepseek_v2 as lm
+from evam_tpu.models.lm import family
 from evam_tpu.obs import get_logger, metrics
 from evam_tpu.obs import trace
 from evam_tpu.sched.classes import DEFAULT_PRIORITY, PRIORITIES, SchedConfig
@@ -164,6 +180,11 @@ class _Step:
     t_dispatch: float
     tokens: int
     rows_read: int
+    #: slot states the step read and wrote (decode rows; a chunk's
+    #: segments), and of a chunk's segments those begun from the prefix
+    #: snapshot; both 0 for a family that keeps no slot state
+    state_rows: int
+    restores: int
     #: (row of the outputs, sequence) for every token this step sampled
     takers: list
     top: jax.Array
@@ -181,7 +202,8 @@ class GenerateEngine:
                  stall_timeout_s: float = 120.0,
                  first_batch_grace: float = 10.0):
         self.name = name
-        self.cfg = lm.Config.from_dict(model_cfg)
+        self._lm = family(model_cfg["model_type"])
+        self.cfg = self._lm.Config.from_dict(model_cfg)
         self.sizes = sz = sizes or GenerateSizes()
         self.stall_timeout_s = stall_timeout_s
         self.first_batch_grace = first_batch_grace
@@ -202,6 +224,12 @@ class GenerateEngine:
             1 + self._prefix_pages + sz.slots * self._private_pages,
             sz.page_tokens)
         self._shared = self._pool.pin(self._prefix_pages)
+        self._state_shapes = self._lm.state_shapes(
+            self.cfg, self._pool.n_pages, sz.page_tokens, sz.slots)
+        #: bytes of what the family keeps per slot (0: pages alone)
+        self._state_bytes = sum(
+            a.size * a.dtype.itemsize
+            for k, a in self._state_shapes.items() if k != "pages")
         self.stats = EngineStats()
         self.warmed = threading.Event()
         self.warm_error: str | None = None
@@ -235,7 +263,7 @@ class GenerateEngine:
         self._outstanding: dict[int, _Seq] = {}
         self._spans = trace.thread_spans(name, "generate")
         self._params = None
-        self._cache = None
+        self._state = None
         self._last_ids = None
         self._build_programs()
         self._thread = threading.Thread(
@@ -249,7 +277,7 @@ class GenerateEngine:
     # ------------------------------------------------------------ programs
 
     def _build_programs(self) -> None:
-        cfg, sz = self.cfg, self.sizes
+        lm, cfg, sz = self._lm, self.cfg, self.sizes
         shared = (np.asarray(self._shared, np.int32)
                   if self._prefix_pages else None)
         n_cont = self._private_pages
@@ -257,37 +285,39 @@ class GenerateEngine:
         # decoding starts after warm-up: the whole prefix is there
         n_prefix_rows = len(self.prefix)
 
-        def prefill(params, cache, last_ids, mat, aux):
+        def prefill(params, state, last_ids, mat, aux):
             tokens, seg, pos, dest_page, dest_off = mat
             cont = aux[:n_cont]
             n_prefix, n_cont_rows = aux[n_cont], aux[n_cont + 1]
-            last_idx = aux[n_cont + 2:n_cont + 2 + n_seg]
-            last_slot = aux[n_cont + 2 + n_seg:]
-            cache, top, ids, held = lm.prefill_chunk(
-                cfg, params, cache, tokens, seg, pos, dest_page, dest_off,
-                shared, n_prefix, cont, n_cont_rows, last_idx)
-            return (cache, last_ids.at[last_slot].set(ids[:, 0]), top, ids,
+            last_idx, last_slot, seg_from, seg_to = (
+                aux[n_cont + 2:].reshape(4, n_seg))
+            state, top, ids, held = lm.prefill_chunk(
+                cfg, params, state, tokens, seg, pos, dest_page, dest_off,
+                shared, n_prefix, cont, n_cont_rows, last_idx, seg_from,
+                seg_to)
+            return (state, last_ids.at[last_slot].set(ids[:, 0]), top, ids,
                     held)
 
-        def decode(params, cache, last_ids, mat, page_table):
+        def decode(params, state, last_ids, mat, page_table):
             slot, pos, ctx_len, dest_page, dest_off, live = mat
-            cache, top, ids, held = lm.decode_tokens(
-                cfg, params, cache, last_ids[slot], pos, page_table, ctx_len,
-                dest_page, dest_off, live > 0, shared, n_prefix_rows)
-            return cache, last_ids.at[slot].set(ids[:, 0]), top, ids, held
+            state, top, ids, held = lm.decode_tokens(
+                cfg, params, state, last_ids[slot], pos, page_table, ctx_len,
+                dest_page, dest_off, live > 0, shared, n_prefix_rows, slot)
+            return state, last_ids.at[slot].set(ids[:, 0]), top, ids, held
 
         self._prefill = jax.jit(prefill, donate_argnums=(1, 2))
         self._decode = jax.jit(decode, donate_argnums=(1, 2))
 
     def _allocate(self) -> None:
-        """Weights, cache and the per-slot last ids, on the device."""
-        cfg, sz = self.cfg, self.sizes
+        """Weights, the family's state (all zero: the snapshot row is
+        the state before any token until warm-up has run the prefix) and
+        the per-slot last ids, on the device."""
+        lm, cfg, sz = self._lm, self.cfg, self.sizes
         with jax.default_device(self._device):
             t0 = time.perf_counter()
             self._params = lm.make_params(cfg)
-            self._cache = jnp.zeros(
-                (cfg.layers, self._pool.n_pages, sz.page_tokens, cfg.latent),
-                lm.BF16)
+            self._state = {k: jnp.zeros(a.shape, a.dtype)
+                           for k, a in self._state_shapes.items()}
             #: one more than the slots: rows that carry no sequence. A
             #: slot's id is set by its sequence's prefill; until then
             #: the ids differ, for the warm-up's loaded steps
@@ -295,10 +325,11 @@ class GenerateEngine:
                 sz.slots + 1, dtype=jnp.int32) % cfg.vocab
             jax.block_until_ready(self._params)
         log.info(
-            "engine %s: %.2f G parameters and a cache of %d pages x %d "
-            "tokens x %d layers on %s in %.1f s", self.name,
-            lm.param_count(cfg) / 1e9, self._pool.n_pages, sz.page_tokens,
-            cfg.layers, self._device, time.perf_counter() - t0)
+            "engine %s: %.2f G parameters, %s on %s in %.1f s", self.name,
+            lm.param_count(cfg) / 1e9,
+            ", ".join(f"{k} {'x'.join(map(str, a.shape))}"
+                      for k, a in self._state_shapes.items()),
+            self._device, time.perf_counter() - t0)
 
     # ---------------------------------------------------------------- API
 
@@ -366,8 +397,10 @@ class GenerateEngine:
         sz = self.sizes
         self._allocate()
         # the prefix, through the prefill program itself: chunk i attends
-        # to the prefix rows the chunks before it wrote
+        # to the prefix rows the chunks before it wrote, and carries the
+        # slot state on in the snapshot row
         t0 = time.perf_counter()
+        snapshot = [(0, sz.slots, sz.slots + 1, sz.slots + 1)]
         flat = [p * sz.page_tokens + o for p in self._shared
                 for o in range(sz.page_tokens)]
         for lo in range(0, len(self.prefix), sz.chunk_tokens):
@@ -376,13 +409,14 @@ class GenerateEngine:
             self._harvest(self._dispatch_prefill_raw(
                 part, np.zeros(len(part), np.int32),
                 np.arange(lo, lo + len(part)), dest, n_prefix=lo,
-                cont=None, n_cont=0, last=[], takers=[]), count=False)
+                cont=None, n_cont=0, segs=snapshot, takers=[]), count=False)
         log.info("engine %s: shared prefix of %d tokens prefilled in %.1f s",
                  self.name, len(self.prefix), time.perf_counter() - t0)
         # every program LOADED as in service: every row a token of its
         # own (``_allocate``'s last ids: the held experts are reached),
-        # written to the null page. Once to compile, once more for the
-        # capacity model's step times.
+        # written to the null page; a chunk's segments start from the
+        # snapshot and end in the null row. Once to compile, once more
+        # for the capacity model's step times.
         n = sz.chunk_tokens
         per = -(-n // sz.max_segments)
         chunk = (np.arange(n) % self.cfg.vocab, np.arange(n) // per,
@@ -494,6 +528,14 @@ class GenerateEngine:
 
     def pages_in_use(self) -> tuple[int, int]:
         return self._pool.in_use, self._pool.capacity
+
+    def state_slots(self) -> tuple[int, int, int]:
+        """(in use, slots, bytes) of the per-slot state; all 0 for a
+        family that keeps none."""
+        if not self._state_bytes:
+            return 0, 0, 0
+        sz = self.sizes
+        return sz.slots - len(self._free_slots), sz.slots, self._state_bytes
 
     # --------------------------------------------------------- the thread
 
@@ -607,6 +649,7 @@ class GenerateEngine:
         metrics.set("evam_generate_slots_active",
                     self.sizes.slots - len(self._free_slots))
         metrics.set("evam_generate_pages_in_use", self._pool.in_use)
+        metrics.set("evam_generate_state_bytes", self._state_bytes)
 
     # ------------------------------------------------------------ dispatch
 
@@ -621,13 +664,16 @@ class GenerateEngine:
         one chunk. Only the chunk's first segment may continue a prompt
         begun in an earlier chunk."""
         sz = self.sizes
-        tokens, seg, pos, dest, last, takers = [], [], [], [], [], []
+        tokens, seg, pos, dest, segs, takers = [], [], [], [], [], []
         cont, n_cont = None, 0
         now = time.perf_counter()
         while (self._prefilling and len(tokens) < sz.chunk_tokens
-               and len(last) < sz.max_segments):
+               and len(segs) < sz.max_segments):
             seq = self._prefilling[0]
-            s = len(last)
+            s = len(segs)
+            # a new sequence starts from the prefix snapshot, one that
+            # continues from its slot; either leaves its state there
+            rows = (seq.slot if seq.n_prefilled else sz.slots + 1, seq.slot)
             if seq.n_prefilled and s:
                 break  # a continued prompt opens its own chunk
             if seq.t_first is None:
@@ -647,20 +693,25 @@ class GenerateEngine:
             if seq.n_prefilled == len(seq.prompt):
                 self._prefilling.popleft()
                 seq.n_gen = 1
-                last.append((len(tokens) - 1, seq.slot))
+                segs.append((len(tokens) - 1, seq.slot, *rows))
                 takers.append((s, seq))
                 if seq.max_new > 1:
                     self._decoding.append(seq)
                 else:
                     self._release(seq)
             else:
-                last.append((0, sz.slots))
+                segs.append((0, sz.slots, *rows))
         return self._dispatch_prefill_raw(
-            tokens, seg, pos, dest, len(self.prefix), cont, n_cont, last,
+            tokens, seg, pos, dest, len(self.prefix), cont, n_cont, segs,
             takers)
 
     def _dispatch_prefill_raw(self, tokens, seg, pos, dest, n_prefix, cont,
-                              n_cont, last, takers) -> _Step:
+                              n_cont, segs, takers) -> _Step:
+        """``segs``: per segment (index of its last token, the slot that
+        takes the sampled id, the slot-state row it starts from, the row
+        its end state goes to); the null row where a segment samples
+        nothing or is not there. With ``segs`` empty (warm-up's loaded
+        chunk) every segment starts from the snapshot."""
         sz = self.sizes
         n = len(tokens)
         mat = np.zeros((5, sz.chunk_tokens), np.int32)
@@ -668,21 +719,25 @@ class GenerateEngine:
         mat[0, :n], mat[1, :n], mat[2, :n] = tokens, seg, pos
         mat[3, :n] = np.asarray(dest, np.int64) // sz.page_tokens
         mat[4, :n] = np.asarray(dest, np.int64) % sz.page_tokens
-        aux = np.zeros(self._private_pages + 2 + 2 * sz.max_segments,
+        aux = np.zeros(self._private_pages + 2 + 4 * sz.max_segments,
                        np.int32)
         if cont is not None:
             aux[:len(cont)] = cont
         at = self._private_pages
         aux[at], aux[at + 1] = n_prefix, n_cont
-        aux[at + 2 + sz.max_segments:] = sz.slots
-        for i, (idx, slot) in enumerate(last):
-            aux[at + 2 + i] = idx
-            aux[at + 2 + sz.max_segments + i] = slot
+        per_seg = aux[at + 2:].reshape(4, sz.max_segments)
+        per_seg[1:] = sz.slots
+        if not segs:
+            per_seg[2] = sz.slots + 1
+        for i, row in enumerate(segs):
+            per_seg[:, i] = row
         # rows of the cache the chunk reads, per layer: the prefix once
         # (all its tokens share it) and one sequence's earlier rows
-        return self._run("prefill", "prefill", self._prefill, (mat, aux),
-                         tokens=n, rows_read=(n_prefix + n_cont) if n else 0,
-                         takers=takers)
+        return self._run(
+            "prefill", "prefill", self._prefill, (mat, aux), tokens=n,
+            rows_read=(n_prefix + n_cont) if n else 0, takers=takers,
+            state_rows=len(segs),
+            restores=sum(row[2] == sz.slots + 1 for row in segs))
 
     def _dispatch_decode(self) -> _Step:
         seqs = self._decoding
@@ -722,15 +777,15 @@ class GenerateEngine:
             rows_read += len(self.prefix) + k + 1
         return self._run("decode", f"decode:{bucket}", self._decode,
                          (mat, table), tokens=len(rows), rows_read=rows_read,
-                         takers=takers)
+                         takers=takers, state_rows=len(rows))
 
     def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
-             takers) -> _Step:
+             takers, state_rows, restores=0) -> _Step:
         t0 = time.perf_counter()
         self._step_started = t0
         cold = key not in self._seen
-        self._cache, self._last_ids, top, ids, held = fn(
-            self._params, self._cache, self._last_ids, *inputs)
+        self._state, self._last_ids, top, ids, held = fn(
+            self._params, self._state, self._last_ids, *inputs)
         for out in (top, ids, held):
             out.copy_to_host_async()
         if cold:
@@ -738,7 +793,10 @@ class GenerateEngine:
             self._seen.add(key)
             self.stats.compiled_programs += 1
             self.stats.compile_seconds += time.perf_counter() - t0
-        return _Step(kind, key, t0, tokens, rows_read, takers, top, ids, held)
+        if not self._state_bytes:
+            state_rows = restores = 0
+        return _Step(kind, key, t0, tokens, rows_read, state_rows, restores,
+                     takers, top, ids, held)
 
     # ------------------------------------------------------------- harvest
 
@@ -763,6 +821,9 @@ class GenerateEngine:
         metrics.inc("evam_generate_tokens", float(step.tokens), labels)
         metrics.inc("evam_generate_latent_rows_read",
                     float(step.rows_read), labels)
+        metrics.inc("evam_generate_state_rows", float(step.state_rows),
+                    labels)
+        metrics.inc("evam_generate_prefix_restores", float(step.restores))
         metrics.inc("evam_moe_held_assignments", float(held))
         st = self.stats
         st.batches += 1

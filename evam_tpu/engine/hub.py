@@ -248,11 +248,12 @@ class EngineHub:
     def generate_engine(self, model_key: str,
                         instance_id: str | None = None, *, prefix_ids):
         """Get or create the generate engine (engine/generate.py) of a
-        language model: many device steps per request, a page cache on
-        the device, ``prefix_ids`` prefilled once and shared by every
-        sequence. Supervised and listed like any engine. It lives on the
-        plan's first device: one chip's share of the model is one
-        chip's."""
+        language model: many device steps per request, its family's
+        state on the device (cache rows in pages, and per-slot recurrent
+        state where the family has such layers), ``prefix_ids`` prefilled
+        once and shared by every sequence. Supervised and listed like any
+        engine. It lives on the plan's first device: what one chip holds
+        of the model is one chip's."""
         key = f"generate:{instance_id or model_key}"
         with self._lock:
             if key not in self._engines:
@@ -459,6 +460,8 @@ class EngineHub:
             # (sched/admission.py reads it), and holds pages
             row["capacity_fps"] = round(own_capacity(), 2)
             row["pages_in_use"], row["pages"] = e.pages_in_use()
+            (row["state_slots_in_use"], row["state_slots"],
+             row["state_bytes"]) = e.state_slots()
         return row
 
     def _rows(self):

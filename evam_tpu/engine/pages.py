@@ -1,12 +1,16 @@
-"""Page accounting of the generate engine's latent cache.
+"""Page accounting of the generate engine's page cache.
 
-The cache itself is one device array ``[layers, pages, page_tokens,
-latent]`` that the step programs update in place (engine/generate.py).
-This is its host side: which pages are free, which belong to a sequence,
-and which are PINNED: the shared instruction prefix, mapped read-only
-into every sequence's page table and never handed back. Page 0 is the
-null page: rows of a step that carry no sequence read and write there.
-Only the engine's one thread calls in.
+The cache itself is one device array ``[layers that attend, pages,
+page_tokens, row width]`` that the step programs update in place
+(engine/generate.py); its shape is the model family's (``state_shapes``:
+576-value latent rows in every layer of DeepSeek-V2, a key and a value of
+128 in Jamba's two attention layers). This is its host side, the same for
+every family: which pages are free, which belong to a sequence, and which
+are PINNED: the shared instruction prefix, mapped read-only into every
+sequence's page table and never handed back. Page 0 is the null page:
+rows of a step that carry no sequence read and write there. What a
+family keeps per SLOT (recurrent state) is not paged and not counted
+here. Only the engine's one thread calls in.
 """
 
 from __future__ import annotations

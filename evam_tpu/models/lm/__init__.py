@@ -5,4 +5,26 @@ JAX, weights made on the device from a seed, installed by
 ``fetch-models --synthesize-lm`` as a config file and found by
 ``ModelRegistry.lm_config``. Nothing here is imported by a server that
 serves no ``describe`` stage.
+
+A FAMILY is a module with ``Config.from_dict``, ``make_params``,
+``param_count``, ``state_shapes`` (the device state of its sequences for
+``(pages, page_tokens, slots)``: cache rows in pages, and whatever it
+keeps per slot), ``prefill_chunk`` and ``decode_tokens``; the installed
+config's ``model_type`` names it.
 """
+
+from __future__ import annotations
+
+import importlib
+
+FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba"}
+
+
+def family(model_type: str):
+    """The module that serves configs of ``model_type``."""
+    if model_type not in FAMILIES:
+        raise ValueError(
+            f"no language-model family serves model_type {model_type!r} "
+            f"({'|'.join(sorted(FAMILIES))})")
+    return importlib.import_module(
+        f"evam_tpu.models.lm.{FAMILIES[model_type]}")

@@ -1,0 +1,148 @@
+"""What the language-model families share (models/lm/deepseek_v2.py,
+models/lm/jamba.py): seeded tensors, norms and products, the dense SwiGLU,
+the pieces of a softmax that is split over its key rows, a packed chunk's
+visibility bounds, and the head with its top logits.
+
+Weights: ``key = fold_in(fold_in(PRNGKey(seed), layer), crc32(name))``;
+a tensor is ``normal(key) * initializer_range`` in float32, stored
+bfloat16 (gains: 1 + that), made on the device.
+"""
+
+from __future__ import annotations
+
+import zlib
+
+import jax
+import jax.numpy as jnp
+
+from evam_tpu.ops.pallas_mla import NEG
+
+F32 = jnp.float32
+BF16 = jnp.bfloat16
+#: the layer index of tensors that belong to no layer
+GLOBAL_LAYER = 1_000_000
+TOP_LOGITS = 8
+#: None: ask the backend. A compile for a described TPU (no chip
+#: attached, the backend reads "cpu") sets True.
+TARGET_TPU: bool | None = None
+
+
+def on_tpu() -> bool:
+    return (jax.default_backend() == "tpu") if TARGET_TPU is None \
+        else TARGET_TPU
+
+
+# --------------------------------------------------------------- weights
+
+
+def tensor_key(seed: int, layer, name: str):
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), layer)
+    return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+
+
+def make(key, shape, scale, gain: bool):
+    w = jax.random.normal(key, shape, F32) * scale
+    return ((1.0 + w) if gain else w).astype(BF16)
+
+
+make_one = jax.jit(make, static_argnums=(1, 2, 3))
+
+
+# ---------------------------------------------------------------- layers
+
+
+def rms_norm(x, gain, eps):
+    x32 = x.astype(F32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * gain.astype(F32)).astype(x.dtype)
+
+
+def mm(x, w):
+    return jnp.dot(x, w, preferred_element_type=F32).astype(BF16)
+
+
+def es(expr, a, b):
+    """Einsum of bfloat16 operands accumulated in float32. XLA's CPU dot
+    lacks bf16 x bf16 -> f32 for some layouts; float32 operands there
+    give the same sums (every product of two bfloat16 values is exact in
+    float32)."""
+    if not on_tpu():
+        return jnp.einsum(expr, a.astype(F32), b.astype(F32))
+    return jnp.einsum(expr, a, b, preferred_element_type=F32)
+
+
+def swiglu(x, gate, up, down):
+    return mm(jax.nn.silu(mm(x, gate)) * mm(x, up), down)
+
+
+def softmax_sums(scale, score_expr, value_expr, q, keys, values, visible):
+    """One part of a softmax that is split over its key rows: per query
+    row the float32 maximum ``m`` of its ``visible`` scores, the sum
+    ``l`` of ``exp(score - m)`` over them and those weights' sum of the
+    rows' ``values``, ``acc``, not yet divided by ``l``. A query that
+    sees no row gives ``l`` and ``acc`` 0."""
+    s = es(score_expr, q, keys) * scale
+    s = jnp.where(visible, s, NEG)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.where(visible, jnp.exp(s - m), 0.0)
+    acc = es(value_expr, p.astype(BF16), values)
+    return m, p.sum(axis=-1, keepdims=True), acc
+
+
+def merge_softmax_sums(own, shared):
+    """The two parts' sums as the one softmax's over both lists of rows
+    (float32), divided through: the weighted values per query row.
+    ``shared`` may be None: the own part alone."""
+    m, l, acc = own
+    if shared is not None:
+        m_p, l_p, acc_p = shared
+        top = jnp.maximum(m, m_p)
+        w, w_p = jnp.exp(m - top), jnp.exp(m_p - top)
+        l, acc = w * l + w_p * l_p, w * acc + w_p * acc_p
+    return acc / jnp.where(l > 0, l, 1.0)
+
+
+def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int):
+    """Which rows of ``[prefix rows | continued rows | the chunk's own
+    rows]`` each token of a packed chunk may see, as ops/pallas_mla.py's
+    three half-open intervals ``[0, a) | [b0, b1) | [c0, c1)`` per token
+    (``bounds`` [T, 4] = a, b1, c0, c1 and the static ``b0``): the
+    prefix rows below ``n_prefix``; the earlier rows of the sequence
+    that continues in this chunk, below ``n_cont`` and to segment 0
+    only; its own segment's rows up to itself. A padded token (segment
+    -1) sees nothing. ``prefix_rows`` / ``cont_rows``: how many rows the
+    two lists hold (0 where there is none)."""
+    t = seg.shape[0]
+    b0 = prefix_rows
+    c_base = b0 + cont_rows
+    live = seg >= 0
+    idx = jnp.arange(t)
+    start = jnp.argmax(seg[:, None] == seg[None, :], axis=1)
+    bounds = jnp.stack([
+        jnp.where(live, n_prefix if prefix_rows else 0, 0),
+        b0 + jnp.where(live & (seg == 0), n_cont if cont_rows else 0, 0),
+        jnp.where(live, c_base + start, 0),
+        jnp.where(live, c_base + idx + 1, 0)], axis=1).astype(jnp.int32)
+    return bounds, b0
+
+
+def page_rows(layer_cache, pages):
+    """The cache rows of ``pages``, in order: [len(pages) * page, width]
+    (None where there are no pages)."""
+    if pages is None:
+        return None
+    return layer_cache[pages].reshape(-1, layer_cache.shape[-1])
+
+
+def head(x, gain, eps, w, tied: bool = False):
+    """Float32 logits, and per row the ``TOP_LOGITS`` largest with their
+    ids (the first is the greedy sample). ``w`` [hidden, vocab], or with
+    ``tied`` the embedding itself, [vocab, hidden]."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, gain, eps)
+        if tied:
+            logits = es("th,vh->tv", x, w)
+        else:
+            logits = jnp.dot(x, w, preferred_element_type=F32)
+        top, ids = jax.lax.top_k(logits, TOP_LOGITS)
+    return logits, top, ids.astype(jnp.int32)

@@ -33,28 +33,27 @@ expert)``, stored bfloat16 (norm gains: 1 + that). Made on the device.
 from __future__ import annotations
 
 import math
-import zlib
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from evam_tpu.models.lm import common
+from evam_tpu.models.lm.common import (  # noqa: F401 - the family's names
+    BF16,
+    F32,
+    GLOBAL_LAYER,
+    TOP_LOGITS,
+    rms_norm,
+    swiglu,
+    tensor_key,
+)
+from evam_tpu.models.lm.common import es as _es
+from evam_tpu.models.lm.common import make as _make
+from evam_tpu.models.lm.common import make_one as _make_one
+from evam_tpu.models.lm.common import mm as _mm
 from evam_tpu.ops import pallas_mla
-
-F32 = jnp.float32
-BF16 = jnp.bfloat16
-#: the layer index of tensors that belong to no layer
-GLOBAL_LAYER = 1_000_000
-TOP_LOGITS = 8
-#: None: ask the backend. A compile for a described TPU (no chip
-#: attached, the backend reads "cpu") sets True.
-TARGET_TPU: bool | None = None
-
-
-def _on_tpu() -> bool:
-    return (jax.default_backend() == "tpu") if TARGET_TPU is None \
-        else TARGET_TPU
 
 
 @dataclass(frozen=True)
@@ -209,17 +208,6 @@ def tensor_shapes(cfg: Config, layer: int) -> dict[str, tuple]:
     return out
 
 
-def tensor_key(seed: int, layer: int, name: str):
-    key = jax.random.fold_in(jax.random.PRNGKey(seed), layer)
-    return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-
-
-def _make(key, shape, scale, gain: bool):
-    w = jax.random.normal(key, shape, F32) * scale
-    return ((1.0 + w) if gain else w).astype(BF16)
-
-
-_make_one = jax.jit(_make, static_argnums=(1, 2, 3))
 _make_experts = jax.jit(
     lambda key, ids, shape, scale: jax.vmap(
         lambda e: _make(jax.random.fold_in(key, e), shape, scale, False))(ids),
@@ -263,30 +251,6 @@ def param_count(cfg: Config) -> int:
 
 
 # ---------------------------------------------------------------- layers
-
-
-def rms_norm(x, gain, eps):
-    x32 = x.astype(F32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * gain.astype(F32)).astype(x.dtype)
-
-
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=F32).astype(BF16)
-
-
-def _es(expr, a, b):
-    """Einsum of bfloat16 operands accumulated in float32. XLA's CPU dot
-    lacks bf16 x bf16 -> f32 for some layouts; float32 operands there
-    give the same sums (every product of two bfloat16 values is exact in
-    float32)."""
-    if not _on_tpu():
-        return jnp.einsum(expr, a.astype(F32), b.astype(F32))
-    return jnp.einsum(expr, a, b, preferred_element_type=F32)
-
-
-def swiglu(x, gate, up, down):
-    return _mm(jax.nn.silu(_mm(x, gate)) * _mm(x, up), down)
 
 
 def route(cfg: Config, x, router):
@@ -404,17 +368,10 @@ def _absorb_q(cfg, w_uk, q_nope, q_rope):
 
 
 def _softmax_sums(cfg: Config, score_expr, value_expr, q, rows, visible):
-    """One part of a softmax that is split over its key rows: per query
-    row the float32 maximum ``m`` of its ``visible`` scores, the sum
-    ``l`` of ``exp(score - m)`` over them and those weights' sum of the
-    rows' values ``acc``, not yet divided by ``l``. A query that sees no
-    row gives ``l`` and ``acc`` 0."""
-    s = _es(score_expr, q, rows) * softmax_scale(cfg)
-    s = jnp.where(visible, s, pallas_mla.NEG)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.where(visible, jnp.exp(s - m), 0.0)
-    acc = _es(value_expr, p.astype(BF16), rows[..., :cfg.kv_rank])
-    return m, p.sum(axis=-1, keepdims=True), acc
+    """``common.softmax_sums`` over latent rows: a row's key is the whole
+    row, its value the row's ``c_kv``."""
+    return common.softmax_sums(softmax_scale(cfg), score_expr, value_expr, q,
+                               rows, rows[..., :cfg.kv_rank], visible)
 
 
 def mla_decode(cfg: Config, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
@@ -431,16 +388,13 @@ def mla_decode(cfg: Config, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
     w_uk, w_uv = _kv_b(cfg, lp)
     q = _absorb_q(cfg, w_uk, q_nope, q_rope)
     own = jnp.arange(ctx.shape[1])[None, None, :] < ctx_len[:, None, None]
-    m, l, acc = _softmax_sums(cfg, "bhc,btc->bht", "bht,btc->bhc", q, ctx,
-                              own)
+    sums = _softmax_sums(cfg, "bhc,btc->bht", "bht,btc->bhc", q, ctx, own)
+    shared = None
     if prefix is not None:
         seen = jnp.arange(prefix.shape[0]) < n_prefix
-        m_p, l_p, acc_p = _softmax_sums(cfg, "bhc,sc->bhs", "bhs,sc->bhc",
-                                        q, prefix, seen)
-        top = jnp.maximum(m, m_p)
-        w, w_p = jnp.exp(m - top), jnp.exp(m_p - top)
-        l, acc = w * l + w_p * l_p, w * acc + w_p * acc_p
-    o_lat = (acc / jnp.where(l > 0, l, 1.0)).astype(BF16)
+        shared = _softmax_sums(cfg, "bhc,sc->bhs", "bhs,sc->bhc", q, prefix,
+                               seen)
+    o_lat = common.merge_softmax_sums(sums, shared).astype(BF16)
     o = _es("bhc,hcv->bhv", o_lat, w_uv).astype(BF16)
     return _mm(o.reshape(o.shape[0], -1), lp["o"])
 
@@ -460,18 +414,10 @@ def mla_prefill(cfg: Config, lp: dict, q_nope, q_rope, lat, seg, prefix,
     q = _absorb_q(cfg, w_uk, q_nope, q_rope)
     keys = jnp.concatenate(
         [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
-    b0 = 0 if prefix is None else prefix.shape[0]
-    c_base = b0 + (0 if cont is None else cont.shape[0])
-    live = seg >= 0
-    idx = jnp.arange(t)
-    start = jnp.argmax(seg[:, None] == seg[None, :], axis=1)
-    bounds = jnp.stack([
-        jnp.where(live, n_prefix if prefix is not None else 0, 0),
-        b0 + jnp.where(live & (seg == 0),
-                       n_cont if cont is not None else 0, 0),
-        jnp.where(live, c_base + start, 0),
-        jnp.where(live, c_base + idx + 1, 0)], axis=1).astype(jnp.int32)
-    attend = (pallas_mla.latent_attention if _on_tpu()
+    bounds, b0 = common.chunk_bounds(
+        seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
+        0 if cont is None else cont.shape[0])
+    attend = (pallas_mla.latent_attention if common.on_tpu()
               else pallas_mla.latent_attention_xla)
     q = q.reshape(t * cfg.heads, cfg.latent)
     o_lat = attend(
@@ -484,14 +430,9 @@ def mla_prefill(cfg: Config, lp: dict, q_nope, q_rope, lat, seg, prefix,
 
 
 def head(cfg: Config, params: dict, x):
-    """Float32 logits over the held slice, and per row the
-    ``TOP_LOGITS`` largest with their ids (the first is the greedy
-    sample)."""
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.eps)
-        logits = jnp.dot(x, params["head"], preferred_element_type=F32)
-        top, ids = jax.lax.top_k(logits, TOP_LOGITS)
-    return logits, top, ids.astype(jnp.int32)
+    """Float32 logits over the held slice and the top of each row
+    (``common.head``)."""
+    return common.head(x, params["final_norm"], cfg.eps, params["head"])
 
 
 # ----------------------------------------------------------- step bodies
@@ -499,20 +440,29 @@ def head(cfg: Config, params: dict, x):
 
 def _rows(cfg: Config, layer_cache, pages):
     """The latent rows of ``pages``, in order: [len(pages) * page, latent]."""
-    if pages is None:
-        return None
-    return layer_cache[pages].reshape(-1, cfg.latent)
+    return common.page_rows(layer_cache, pages)
 
 
-def prefill_chunk(cfg: Config, params: dict, cache, tokens, seg, pos,
+def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
+                 slots: int) -> dict:
+    """The device state of this family's sequences: latent rows in pages,
+    in every layer, and nothing per slot."""
+    return {"pages": jax.ShapeDtypeStruct(
+        (cfg.layers, n_pages, page_tokens, cfg.latent), BF16)}
+
+
+def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
                   dest_page, dest_off, prefix_pages, n_prefix, cont_pages,
-                  n_cont, last_idx):
+                  n_cont, last_idx, seg_from=None, seg_to=None):
     """A packed chunk of new tokens through every layer. Writes their
-    latent rows to ``cache[layer, dest_page, dest_off]`` and returns the
-    cache, the logits rows ``last_idx`` (each segment's last token) as
-    ``(top, ids)``, and the held assignments summed over the layers.
-    ``prefix_pages``/``cont_pages`` may be ``None`` (no shared prefix; no
-    sequence that continues from an earlier chunk)."""
+    latent rows to ``state["pages"][layer, dest_page, dest_off]`` and
+    returns the state, the logits rows ``last_idx`` (each segment's last
+    token) as ``(top, ids)``, and the held assignments summed over the
+    layers. ``prefix_pages``/``cont_pages`` may be ``None`` (no shared
+    prefix; no sequence that continues from an earlier chunk).
+    ``seg_from``/``seg_to`` name slot state, of which this family has
+    none."""
+    cache = state["pages"]
     live = seg >= 0
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
@@ -531,16 +481,19 @@ def prefill_chunk(cfg: Config, params: dict, cache, tokens, seg, pos,
         x = x + y
         held = held + n
     _, top, ids = head(cfg, params, x[last_idx])
-    return cache, top, ids, held
+    return {"pages": cache}, top, ids, held
 
 
-def decode_tokens(cfg: Config, params: dict, cache, tokens, pos, page_table,
-                  ctx_len, dest_page, dest_off, live, prefix_pages, n_prefix):
+def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
+                  ctx_len, dest_page, dest_off, live, prefix_pages, n_prefix,
+                  slot=None):
     """One token per row. Each row's latent is written to its page, then
     the row attends to the ``n_prefix`` rows of the shared prefix
     (``prefix_pages``, read once a layer for all rows; may be ``None``)
     and, through its table of its OWN pages ``page_table`` [B, pages],
-    to its ``ctx_len`` own cached rows (the new one among them)."""
+    to its ``ctx_len`` own cached rows (the new one among them).
+    ``slot`` names slot state, of which this family has none."""
+    cache = state["pages"]
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     b = tokens.shape[0]
@@ -558,4 +511,4 @@ def decode_tokens(cfg: Config, params: dict, cache, tokens, pos, page_table,
         x = x + y
         held = held + n
     _, top, ids = head(cfg, params, x)
-    return cache, top, ids, held
+    return {"pages": cache}, top, ids, held

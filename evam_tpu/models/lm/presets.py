@@ -8,6 +8,13 @@ outputs, 8 groups, top-3 groups and top-6 experts; the chip holds routing
 group ``held_group`` (20 experts), an eighth of the vocabulary, the
 leading dense layer and five expert layers. ``deepseek_v2_tiny`` has the
 same structure at a size a CPU test runs.
+
+``jamba2_3b`` is AI21-Jamba2-3B's published config
+(https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json),
+whole: all 28 layers (26 Mamba, attention at 7 and 21) and the whole
+vocabulary fit one chip, so nothing is cut but the weights (seeded).
+``jamba_tiny`` has the same structure (Mamba runs before, between and
+after two attention layers) at a size a CPU test runs.
 """
 
 from __future__ import annotations
@@ -34,7 +41,39 @@ DEEPSEEK_V2_PUBLISHED = {
     "vocab_size": 102400,
 }
 
+JAMBA2_3B_PUBLISHED = {
+    "attn_layer_offset": 7, "attn_layer_period": 14,
+    "expert_layer_offset": 1, "expert_layer_period": 2,
+    "hidden_act": "silu", "hidden_size": 2560, "intermediate_size": 8192,
+    "mamba_conv_bias": True, "mamba_d_conv": 4, "mamba_d_state": 16,
+    "mamba_dt_rank": 160, "mamba_expand": 2, "mamba_proj_bias": False,
+    "max_position_embeddings": 262144, "model_type": "jamba",
+    "num_attention_heads": 20, "num_experts": 1, "num_experts_per_tok": 1,
+    "num_hidden_layers": 28, "num_key_value_heads": 1,
+    "num_logits_to_keep": 1, "rms_norm_eps": 1e-06, "sliding_window": None,
+    "tie_word_embeddings": True, "use_mamba_kernels": True,
+    "vocab_size": 65536,
+}
+
 PRESETS = {
+    "jamba2_3b": {
+        **JAMBA2_3B_PUBLISHED,
+        "vocab_held": 65536,
+        "weights_seed": 20251008,
+        "initializer_range": 0.02,
+    },
+    "jamba_tiny": {
+        **JAMBA2_3B_PUBLISHED,
+        # Mamba layers 0 | 2, 3 | 5 around attention layers 1 and 4
+        "hidden_size": 64, "intermediate_size": 96,
+        "num_attention_heads": 4, "num_hidden_layers": 6,
+        "attn_layer_period": 3, "attn_layer_offset": 1,
+        "mamba_dt_rank": 8, "vocab_size": 512,
+        "vocab_held": 128,
+        "weights_seed": 11,
+        # as deepseek_v2_tiny: 0.02 at width 64 leaves every score flat
+        "initializer_range": 0.15,
+    },
     "deepseek_v2_ep8": {
         **DEEPSEEK_V2_PUBLISHED,
         # the cut: depth, the chip's share of the experts and vocabulary

@@ -57,7 +57,15 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_generate_step_seconds": ("histogram", ("kind",)),
     "evam_generate_steps": ("counter", ("kind",)),
     "evam_generate_tokens": ("counter", ("kind",)),
+    # cache rows read, per layer THAT HAS a cache (every layer of
+    # DeepSeek-V2, the attention layers of Jamba)
     "evam_generate_latent_rows_read": ("counter", ("kind",)),
+    # per-slot recurrent state (a family that keeps none counts 0): slot
+    # states a step read and wrote (decode rows; a chunk's segments),
+    # sequences started from the prefix snapshot, and the state's bytes
+    "evam_generate_state_rows": ("counter", ("kind",)),
+    "evam_generate_prefix_restores": ("counter", ()),
+    "evam_generate_state_bytes": ("gauge", ()),
     # of a decode step's rows read, those of the shared prefix: read
     # once a step for all its live rows (prefix rows x live rows)
     "evam_generate_decode_shared_rows": ("counter", ()),

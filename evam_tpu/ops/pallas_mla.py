@@ -129,7 +129,7 @@ def latent_attention(q_lat, q_rope, ckv, kr, bounds, *, scale, b0,
 def latent_attention_xla(q_lat, q_rope, ckv, kr, bounds, *, scale, b0):
     """The same through XLA, scores materialised (float32 softmax)."""
     hi = dict(preferred_element_type=F32)
-    if jax.default_backend() != "tpu":  # see deepseek_v2._es
+    if jax.default_backend() != "tpu":  # see models/lm/common.py es
         q_lat, q_rope, ckv, kr = (x.astype(F32) for x in (
             q_lat, q_rope, ckv, kr))
         hi = {}

@@ -79,7 +79,8 @@ def render_prompt(source_uri: str, timestamp_ns: int, objects: list[tuple],
 
 
 class DescribeStage(AsyncStage):
-    """Properties: ``max-new-tokens`` (48), ``max-objects`` (32),
+    """Properties: ``max-new-tokens`` (48; the pipeline's file may set
+    its own default), ``max-objects`` (32),
     ``prefix-tokens`` (``EVAM_LM_SHAPES``: 2048),
     ``model-instance-id``."""
 

@@ -38,3 +38,25 @@ def test_latent_attention_kernel_compiles_at_published_widths(
         s((keys, 512), jnp.bfloat16), s((keys, 64), jnp.bfloat16),
         s((rows, 4), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens,segments", [
+    (512, 8),    # a prefill chunk of the deployment
+    (64, 4),     # a short chunk
+])
+def test_selective_scan_kernel_compiles_at_published_widths(
+        one_chip, tokens, segments):
+    from evam_tpu.ops.pallas_selective_scan import selective_scan
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ch, n = 5120, 16
+    compiled = jax.jit(selective_scan).lower(
+        s((tokens, ch), jnp.bfloat16), s((tokens, ch), jnp.float32),
+        s((tokens, ch), jnp.bfloat16), s((tokens, n), jnp.bfloat16),
+        s((tokens, n), jnp.bfloat16), s((n, ch), jnp.float32),
+        s((ch,), jnp.bfloat16), s((tokens,), jnp.int32),
+        s((segments, n, ch), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_selective_scan" in text
