@@ -35,7 +35,8 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   private_pages]``), its context length counts own rows, and the step
   merges the two parts of each row's softmax (models/lm/common.py
   ``merge_softmax_sums``).
-* A family with recurrent layers (Jamba's Mamba layers) also keeps state
+* A family with recurrent layers (Jamba's Mamba layers, Kimi-Linear's
+  delta-rule layers) also keeps state
   per SLOT, never paged: arrays ``[layers, slots + 2, ...]`` that every
   decode step reads and writes at its rows' slots. Row ``slots`` is the
   null row (rows of a step that carry no sequence), row ``slots + 1`` the
@@ -45,7 +46,9 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   a new sequence, the slot's own for a prompt that continues from the
   chunk before) and the row its end state goes to (the slot). A new
   sequence never starts from its slot, so a slot taken again carries
-  nothing over.
+  nothing over. A family whose recurrence runs in blocks of tokens says so
+  (``SEGMENT_ALIGN``): the packer starts every segment at a multiple of
+  it, and the rows between are rows of no segment, as the chunk's tail.
 * Sampling is greedy and there is no stop token: a request runs exactly
   ``max_new_tokens``, so the thread knows every step's make-up without
   reading a result. Each step's sampled ids stay on the device
@@ -205,6 +208,13 @@ class GenerateEngine:
         self._lm = family(model_cfg["model_type"])
         self.cfg = self._lm.Config.from_dict(model_cfg)
         self.sizes = sz = sizes or GenerateSizes()
+        #: a chunk's segments start at multiples of this many tokens
+        self._align = self._lm.SEGMENT_ALIGN
+        if sz.chunk_tokens % (self._align * sz.max_segments):
+            raise ValueError(
+                f"a chunk of {sz.chunk_tokens} tokens is not "
+                f"{sz.max_segments} segments of whole blocks of "
+                f"{self._align}")
         self.stall_timeout_s = stall_timeout_s
         self.first_batch_grace = first_batch_grace
         prefix = np.asarray(prefix_ids, np.int32)
@@ -667,8 +677,15 @@ class GenerateEngine:
         tokens, seg, pos, dest, segs, takers = [], [], [], [], [], []
         cont, n_cont = None, 0
         now = time.perf_counter()
-        while (self._prefilling and len(tokens) < sz.chunk_tokens
-               and len(segs) < sz.max_segments):
+        while (self._prefilling and len(segs) < sz.max_segments):
+            # rows of no segment up to the next aligned start
+            pad = -len(tokens) % self._align
+            if len(tokens) + pad >= sz.chunk_tokens:
+                break
+            tokens += [0] * pad
+            seg += [-1] * pad
+            pos += [0] * pad
+            dest += [0] * pad
             seq = self._prefilling[0]
             s = len(segs)
             # a new sequence starts from the prefix snapshot, one that
@@ -714,6 +731,7 @@ class GenerateEngine:
         chunk) every segment starts from the snapshot."""
         sz = self.sizes
         n = len(tokens)
+        live = int((np.asarray(seg) >= 0).sum())
         mat = np.zeros((5, sz.chunk_tokens), np.int32)
         mat[1] = -1
         mat[0, :n], mat[1, :n], mat[2, :n] = tokens, seg, pos
@@ -734,7 +752,7 @@ class GenerateEngine:
         # rows of the cache the chunk reads, per layer: the prefix once
         # (all its tokens share it) and one sequence's earlier rows
         return self._run(
-            "prefill", "prefill", self._prefill, (mat, aux), tokens=n,
+            "prefill", "prefill", self._prefill, (mat, aux), tokens=live,
             rows_read=(n_prefix + n_cont) if n else 0, takers=takers,
             state_rows=len(segs),
             restores=sum(row[2] == sz.slots + 1 for row in segs))
@@ -805,7 +823,7 @@ class GenerateEngine:
         tokens to their sequences, resolve those that are complete."""
         top = np.asarray(step.top)
         ids = np.asarray(step.ids)
-        held = int(step.held)
+        held, hit = (int(v) for v in np.asarray(step.held))
         now = time.perf_counter()
         self._step_started = (self._inflight[0].t_dispatch
                               if self._inflight else None)
@@ -825,6 +843,8 @@ class GenerateEngine:
                     labels)
         metrics.inc("evam_generate_prefix_restores", float(step.restores))
         metrics.inc("evam_moe_held_assignments", float(held))
+        # per expert layer, the held experts with at least one assignment
+        metrics.inc("evam_moe_held_experts_hit", float(hit), labels)
         st = self.stats
         st.batches += 1
         st.add_stage("launch", dt)

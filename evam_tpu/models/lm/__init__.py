@@ -9,15 +9,28 @@ serves no ``describe`` stage.
 A FAMILY is a module with ``Config.from_dict``, ``make_params``,
 ``param_count``, ``state_shapes`` (the device state of its sequences for
 ``(pages, page_tokens, slots)``: cache rows in pages, and whatever it
-keeps per slot), ``prefill_chunk`` and ``decode_tokens``; the installed
+keeps per slot), ``prefill_chunk`` and ``decode_tokens`` (each returns the
+state, the top logits with their ids, and int32 ``[held assignments, held
+experts hit]``, zeros for a family without experts) and ``SEGMENT_ALIGN``
+(the multiple of a chunk's tokens at which the engine's packer starts
+every segment: 1, or the block of a chunkwise kernel); the installed
 config's ``model_type`` names it.
+
+What more than one family has lives beside them and is called by each:
+``common.py`` (tensors, norms, the split softmax, a packed chunk's bounds
+and convolution inputs, the head), ``mla.py`` (the latent attention:
+``deepseek_v2`` with its rotation and query down-projection,
+``kimi_linear`` without either) and ``experts.py`` (the expert layer told
+which experts it holds: ``deepseek_v2`` softmax scores and group-limited
+routing, ``kimi_linear`` sigmoid scores with a selection bias).
 """
 
 from __future__ import annotations
 
 import importlib
 
-FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba"}
+FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba",
+            "kimi_linear": "kimi_linear"}
 
 
 def family(model_type: str):

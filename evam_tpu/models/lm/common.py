@@ -1,7 +1,10 @@
 """What the language-model families share (models/lm/deepseek_v2.py,
-models/lm/jamba.py): seeded tensors, norms and products, the dense SwiGLU,
-the pieces of a softmax that is split over its key rows, a packed chunk's
-visibility bounds, and the head with its top logits.
+models/lm/jamba.py, models/lm/kimi_linear.py): seeded tensors, norms and
+products, the dense SwiGLU, the pieces of a softmax that is split over its
+key rows, a packed chunk's visibility bounds, the inputs of a short causal
+convolution over a packed chunk, and the head with its top logits. The
+latent attention is in models/lm/mla.py and the expert layer in
+models/lm/experts.py, each called by the two families that have it.
 
 Weights: ``key = fold_in(fold_in(PRNGKey(seed), layer), crc32(name))``;
 a tensor is ``normal(key) * initializer_range`` in float32, stored
@@ -10,6 +13,7 @@ bfloat16 (gains: 1 + that), made on the device.
 
 from __future__ import annotations
 
+import math
 import zlib
 
 import jax
@@ -46,6 +50,16 @@ def make(key, shape, scale, gain: bool):
 
 
 make_one = jax.jit(make, static_argnums=(1, 2, 3))
+
+
+def step_bias(key, shape, lo: float, hi: float):
+    """The bias of a recurrence's step size (float32): the inverse
+    softplus of a step drawn log-uniformly from [``lo``, ``hi``], the
+    state-space families' own initialisation (a state that neither dies
+    in ten tokens nor never forgets)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, F32)
+                 * (math.log(hi) - math.log(lo)) + math.log(lo))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 # ---------------------------------------------------------------- layers
@@ -124,6 +138,42 @@ def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int):
         jnp.where(live, c_base + start, 0),
         jnp.where(live, c_base + idx + 1, 0)], axis=1).astype(jnp.int32)
     return bounds, b0
+
+
+def packed_conv_inputs(u_pre, seg, conv0, k1: int):
+    """A causal depthwise convolution of ``k1 + 1`` taps over a packed
+    chunk, each segment continuing from inputs it carried in. ``u_pre``
+    [T, C] the chunk's inputs, ``seg`` [T] (a segment's tokens are
+    contiguous; -1: a dead row), ``conv0`` [S, k1 * C] each segment's last
+    ``k1`` inputs before this chunk, taps side by side. Returns the taps
+    of every token, oldest first (``k1`` arrays [T, C]; the newest tap is
+    ``u_pre`` itself), and ``conv_end`` [S, k1 * C]: each segment's last
+    ``k1`` inputs after its last token here (its own rows, and the
+    carried ones where it has fewer)."""
+    t, c = u_pre.shape
+    n_seg = conv0.shape[0]
+    # tap d back of token t: the chunk's own row t - d where the segment
+    # has one, else the segment's carried input
+    s = jnp.maximum(seg, 0)
+    start = jnp.argmax(seg[:, None] == seg[None, :], axis=1)
+    off = jnp.arange(t) - start
+    carried = conv0.reshape(n_seg * k1, c)
+    taps = []
+    for d in range(k1, 0, -1):
+        own = jnp.pad(u_pre, ((d, 0), (0, 0)))[:t]
+        old = carried[s * k1 + jnp.clip(k1 - d + off, 0, k1 - 1)]
+        taps.append(jnp.where((off >= d)[:, None], own, old))
+    seg_ids = jnp.arange(n_seg)
+    mine = seg[None, :] == seg_ids[:, None]
+    count = mine.sum(axis=1)
+    first = jnp.argmax(mine, axis=1)
+    rows = []
+    for r in range(k1):
+        at = count + r - k1  # index among the segment's own rows
+        own = u_pre[jnp.clip(first + at, 0, t - 1)]
+        old = carried[seg_ids * k1 + jnp.clip(count + r, 0, k1 - 1)]
+        rows.append(jnp.where((at >= 0)[:, None], own, old))
+    return taps, jnp.concatenate(rows, axis=1)
 
 
 def page_rows(layer_cache, pages):

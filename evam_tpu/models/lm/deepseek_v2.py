@@ -13,7 +13,10 @@ Per layer, pre-norm residual, RMSNorm:
   product and each row against its own pages through its page table,
   merged by their softmax sums; a prefill chunk over prefix, continued
   and own rows in one kernel (ops/pallas_mla.py). Heads are
-  materialised only in the reference.
+  materialised only in the reference. The latent attention itself lives
+  in models/lm/mla.py (``qkv``, ``mla_decode``, ``mla_prefill``), which
+  models/lm/kimi_linear.py calls too; this family hands it the rotation
+  and a query down-projection.
 * **YaRN** rope (blended inverse frequencies) and its softmax scale.
 * **Layer 0** a dense SwiGLU; **later layers** a router over ALL
   ``n_routed_experts`` (softmax in float32, group-limited top-k), the
@@ -23,7 +26,10 @@ Per layer, pre-norm residual, RMSNorm:
   whose kept groups exclude ``g``) and the shared experts; that partial
   sum goes on to the next layer. Nothing stands in for absent chips.
   The held experts' work follows the tokens routed to them: assignments
-  are sorted by expert and run through ``jax.lax.ragged_dot``.
+  are sorted by expert and run through ``jax.lax.ragged_dot``. The layer
+  itself lives in models/lm/experts.py (``route``, ``held_experts``,
+  ``moe``), which models/lm/kimi_linear.py calls too; this family's
+  config says softmax scores, ``n_group`` groups and the held group.
 
 Weights: every tensor is ``normal(key) * initializer_range`` in float32,
 ``key = fold_in(fold_in(fold_in(PRNGKey(seed), layer), crc32(name)),
@@ -39,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from evam_tpu.models.lm import common
+from evam_tpu.models.lm import common, experts, mla
 from evam_tpu.models.lm.common import (  # noqa: F401 - the family's names
     BF16,
     F32,
@@ -49,11 +55,20 @@ from evam_tpu.models.lm.common import (  # noqa: F401 - the family's names
     swiglu,
     tensor_key,
 )
-from evam_tpu.models.lm.common import es as _es
 from evam_tpu.models.lm.common import make as _make
 from evam_tpu.models.lm.common import make_one as _make_one
-from evam_tpu.models.lm.common import mm as _mm
-from evam_tpu.ops import pallas_mla
+from evam_tpu.models.lm.experts import (  # noqa: F401 - the family's names
+    held_experts,
+    moe,
+    route,
+)
+from evam_tpu.models.lm.mla import (  # noqa: F401 - the family's names
+    mla_decode,
+    mla_prefill,
+)
+
+#: the packer may start a segment at any token of a chunk
+SEGMENT_ALIGN = 1
 
 
 @dataclass(frozen=True)
@@ -115,6 +130,17 @@ class Config:
             vocab=d["vocab_held"], held_group=d["held_group"],
             seed=d["weights_seed"], init_range=d["initializer_range"])
 
+    #: what models/lm/experts.py reads beside the fields
+    score_func = "softmax"
+
+    @property
+    def scale_routed(self) -> bool:
+        return not (self.norm_topk and self.top_k > 1)
+
+    @property
+    def softmax_scale(self) -> float:
+        return softmax_scale(self)
+
     @property
     def per_group(self) -> int:
         return self.n_experts // self.n_group
@@ -174,37 +200,17 @@ def _cos_sin(cfg: Config, pos):
     return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
-def _rope(x, cos, sin):
-    """Rotate the pairs (2i, 2i+1) of the last axis; ``cos``/``sin``
-    broadcast against ``x[..., ::2]``."""
-    x = x.astype(F32)
-    a, b = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                     axis=-1).reshape(x.shape)
-
-
 # --------------------------------------------------------------- weights
 
 
 def tensor_shapes(cfg: Config, layer: int) -> dict[str, tuple]:
-    h, hd = cfg.hidden, cfg.heads
-    out = {
-        "input_norm": (h,), "post_norm": (h,),
-        "q_a": (h, cfg.q_rank), "q_a_norm": (cfg.q_rank,),
-        "q_b": (cfg.q_rank, hd * (cfg.nope + cfg.rope)),
-        "kv_a": (h, cfg.latent), "kv_a_norm": (cfg.kv_rank,),
-        "kv_b": (cfg.kv_rank, hd * (cfg.nope + cfg.v_dim)),
-        "o": (hd * cfg.v_dim, h),
-    }
+    h = cfg.hidden
+    out = {"input_norm": (h,), "post_norm": (h,), **mla.tensor_shapes(cfg)}
     if layer < cfg.first_dense:
         out.update(mlp_gate=(h, cfg.dense_inter), mlp_up=(h, cfg.dense_inter),
                    mlp_down=(cfg.dense_inter, h))
     else:
-        s = cfg.n_shared * cfg.moe_inter
-        out.update(router=(h, cfg.n_experts),
-                   shared_gate=(h, s), shared_up=(h, s), shared_down=(s, h),
-                   expert_gate=(h, cfg.moe_inter), expert_up=(h, cfg.moe_inter),
-                   expert_down=(cfg.moe_inter, h))
+        out.update(experts.tensor_shapes(cfg, bias=False))
     return out
 
 
@@ -253,180 +259,17 @@ def param_count(cfg: Config) -> int:
 # ---------------------------------------------------------------- layers
 
 
-def route(cfg: Config, x, router):
-    """Group-limited top-k over ALL experts: ``(weights [T,k], ids
-    [T,k])``. Scores are a float32 softmax; a group's score is its best
-    expert's; the best ``topk_group`` groups are kept (ties: the lower
-    index, as ``lax.top_k``), the rest set to 0; then the best ``top_k``
-    of what is left."""
-    logits = jnp.dot(x.astype(F32), router.astype(F32),
-                     precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.softmax(logits, axis=-1)
-    t = scores.shape[0]
-    group = scores.reshape(t, cfg.n_group, cfg.per_group).max(-1)
-    _, keep = jax.lax.top_k(group, cfg.topk_group)
-    kept = jnp.zeros((t, cfg.n_group), bool).at[
-        jnp.arange(t)[:, None], keep].set(True)
-    masked = jnp.where(jnp.repeat(kept, cfg.per_group, axis=1), scores, 0.0)
-    w, ids = jax.lax.top_k(masked, cfg.top_k)
-    if cfg.norm_topk and cfg.top_k > 1:
-        w = w / (w.sum(-1, keepdims=True) + 1e-20)
-    else:
-        w = w * cfg.routed_scale
-    return w, ids
-
-
-def held_experts(cfg: Config, lp: dict, x, w, ids, live):
-    """The held experts' part of the routed sum, with work that follows
-    the assignments routed here: the ``T*k`` assignments are sorted by
-    held expert (those of other chips' experts, and of dead rows, last),
-    and the sorted rows go through grouped products. Every assignment to
-    a held expert is computed, however uneven the routing: the grouped
-    product runs over the first ``T*k/4`` rows where they hold all of
-    them (twice the even share at 8 groups, top 3), else over all.
-    Returns the sum [T, hidden] and the number of held assignments."""
-    t, k = ids.shape
-    n_held = lp["expert_gate"].shape[0]
-    local = ids - cfg.held_lo
-    mine = (local >= 0) & (local < n_held) & live[:, None]
-    sort_key = jnp.where(mine, local, n_held).reshape(-1)
-    order = jnp.argsort(sort_key, stable=True)
-    sizes = jnp.bincount(sort_key, length=n_held + 1)[:n_held].astype(
-        jnp.int32)
-    n_mine = sizes.sum()
-    rows = x[order // k]
-    m = t * k
-    m_small = max(8, m // 4)
-
-    def run(r):
-        g = jax.lax.ragged_dot(r, lp["expert_gate"], sizes,
-                               preferred_element_type=F32)
-        u = jax.lax.ragged_dot(r, lp["expert_up"], sizes,
-                               preferred_element_type=F32)
-        hmid = (jax.nn.silu(g.astype(BF16)) * u.astype(BF16))
-        return jax.lax.ragged_dot(hmid, lp["expert_down"], sizes,
-                                  preferred_element_type=F32).astype(BF16)
-
-    def small():
-        return jnp.zeros((m, x.shape[1]), BF16).at[:m_small].set(
-            run(rows[:m_small]))
-
-    y = jax.lax.cond(n_mine <= m_small, small, lambda: run(rows))
-    # rows past the last group hold whatever the kernel left there
-    y = jnp.where((jnp.arange(m) < n_mine)[:, None], y, 0)
-    back = jnp.argsort(order)
-    y = y[back].reshape(t, k, -1).astype(F32)
-    out = (y * jnp.where(mine, w, 0.0)[..., None]).sum(1)
-    return out.astype(BF16), n_mine
-
-
-def moe(cfg: Config, lp: dict, x, live):
-    with jax.named_scope("router"):
-        w, ids = route(cfg, x, lp["router"])
-    with jax.named_scope("experts"):
-        routed, n_mine = held_experts(cfg, lp, x, w, ids, live)
-    with jax.named_scope("shared"):
-        shared = swiglu(x, lp["shared_gate"], lp["shared_up"],
-                        lp["shared_down"])
-    return routed + shared, n_mine
-
-
 def _mlp(cfg: Config, lp: dict, x, live):
     if "router" in lp:
         return moe(cfg, lp, x, live)
     with jax.named_scope("dense_mlp"):
         return swiglu(x, lp["mlp_gate"], lp["mlp_up"],
-                      lp["mlp_down"]), jnp.int32(0)
+                      lp["mlp_down"]), jnp.zeros((2,), jnp.int32)
 
 
 def _qkv(cfg: Config, lp: dict, x, pos):
-    """Per token: roped query ``(q_nope [T,h,nope], q_rope [T,h,rope])``
-    and the latent row ``[c_kv ; k_r]`` that the cache holds."""
-    t = x.shape[0]
-    cos, sin = _cos_sin(cfg, pos)
-    c_q = rms_norm(_mm(x, lp["q_a"]), lp["q_a_norm"], cfg.eps)
-    q = _mm(c_q, lp["q_b"]).reshape(t, cfg.heads, cfg.nope + cfg.rope)
-    q_nope, q_rope = q[..., :cfg.nope], q[..., cfg.nope:]
-    q_rope = _rope(q_rope, cos[:, None], sin[:, None]).astype(BF16)
-    kv = _mm(x, lp["kv_a"])
-    c_kv = rms_norm(kv[:, :cfg.kv_rank], lp["kv_a_norm"], cfg.eps)
-    k_r = _rope(kv[:, cfg.kv_rank:], cos, sin).astype(BF16)
-    return q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
-
-
-def _kv_b(cfg: Config, lp: dict):
-    """``W_kvb`` as ``(W_uk, W_uv)``, each [heads, kv_rank, 128]."""
-    w = lp["kv_b"].reshape(cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
-    w = w.transpose(1, 0, 2)
-    return w[..., :cfg.nope], w[..., cfg.nope:]
-
-
-def _absorb_q(cfg, w_uk, q_nope, q_rope):
-    """The query in the cache's own space: [T, h, kv_rank + rope]."""
-    q_lat = _es("thd,hcd->thc", q_nope, w_uk).astype(BF16)
-    return jnp.concatenate([q_lat, q_rope], axis=-1)
-
-
-def _softmax_sums(cfg: Config, score_expr, value_expr, q, rows, visible):
-    """``common.softmax_sums`` over latent rows: a row's key is the whole
-    row, its value the row's ``c_kv``."""
-    return common.softmax_sums(softmax_scale(cfg), score_expr, value_expr, q,
-                               rows, rows[..., :cfg.kv_rank], visible)
-
-
-def mla_decode(cfg: Config, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
-               n_prefix):
-    """One new token per row, absorbed form, its softmax in two parts.
-    OWN: each row against its own cached rows ``ctx`` [B, T, latent]
-    (the new token's row among them), visible below ``ctx_len`` [B].
-    SHARED: the queries of all rows and heads against the prefix rows
-    ``prefix`` [Tp, latent] (visible below ``n_prefix``), which every
-    row shares and which are read once: one dense product. The parts
-    are merged by their softmax sums in float32 (the arithmetic of the
-    one softmax over prefix and own rows) before ``W_uv``. ``prefix``
-    may be None: the own part alone."""
-    w_uk, w_uv = _kv_b(cfg, lp)
-    q = _absorb_q(cfg, w_uk, q_nope, q_rope)
-    own = jnp.arange(ctx.shape[1])[None, None, :] < ctx_len[:, None, None]
-    sums = _softmax_sums(cfg, "bhc,btc->bht", "bht,btc->bhc", q, ctx, own)
-    shared = None
-    if prefix is not None:
-        seen = jnp.arange(prefix.shape[0]) < n_prefix
-        shared = _softmax_sums(cfg, "bhc,sc->bhs", "bhs,sc->bhc", q, prefix,
-                               seen)
-    o_lat = common.merge_softmax_sums(sums, shared).astype(BF16)
-    o = _es("bhc,hcv->bhv", o_lat, w_uv).astype(BF16)
-    return _mm(o.reshape(o.shape[0], -1), lp["o"])
-
-
-def mla_prefill(cfg: Config, lp: dict, q_nope, q_rope, lat, seg, prefix,
-                n_prefix, cont, n_cont):
-    """A packed chunk, absorbed form throughout: every (token, head) is
-    one query row over ONE list of latent rows: the shared prefix rows
-    ``prefix`` [Tp, latent] (visible below ``n_prefix``), the earlier
-    rows ``cont`` [Tc, latent] of the sequence that continues in this
-    chunk (below ``n_cont``, to segment 0 only) and the chunk's own rows
-    ``lat`` (a token sees its segment's, up to itself). ``prefix`` and
-    ``cont`` may be None. The scores stay on the chip
-    (ops/pallas_mla.py)."""
-    t = lat.shape[0]
-    w_uk, w_uv = _kv_b(cfg, lp)
-    q = _absorb_q(cfg, w_uk, q_nope, q_rope)
-    keys = jnp.concatenate(
-        [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
-    bounds, b0 = common.chunk_bounds(
-        seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
-        0 if cont is None else cont.shape[0])
-    attend = (pallas_mla.latent_attention if common.on_tpu()
-              else pallas_mla.latent_attention_xla)
-    q = q.reshape(t * cfg.heads, cfg.latent)
-    o_lat = attend(
-        q[:, :cfg.kv_rank], q[:, cfg.kv_rank:],
-        keys[:, :cfg.kv_rank], keys[:, cfg.kv_rank:],
-        jnp.repeat(bounds, cfg.heads, axis=0),
-        scale=softmax_scale(cfg), b0=b0)
-    o = _es("thc,hcv->thv", o_lat.reshape(t, cfg.heads, cfg.kv_rank), w_uv)
-    return _mm(o.astype(BF16).reshape(t, -1), lp["o"])
+    """``mla.qkv`` with this family's rotation at ``pos``."""
+    return mla.qkv(cfg, lp, x, _cos_sin(cfg, pos))
 
 
 def head(cfg: Config, params: dict, x):
@@ -457,8 +300,8 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     """A packed chunk of new tokens through every layer. Writes their
     latent rows to ``state["pages"][layer, dest_page, dest_off]`` and
     returns the state, the logits rows ``last_idx`` (each segment's last
-    token) as ``(top, ids)``, and the held assignments summed over the
-    layers. ``prefix_pages``/``cont_pages`` may be ``None`` (no shared
+    token) as ``(top, ids)``, and ``[held assignments, held experts
+    hit]`` summed over the layers. ``prefix_pages``/``cont_pages`` may be ``None`` (no shared
     prefix; no sequence that continues from an earlier chunk).
     ``seg_from``/``seg_to`` name slot state, of which this family has
     none."""
@@ -466,7 +309,7 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     live = seg >= 0
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-    held = jnp.int32(0)
+    held = jnp.zeros((2,), jnp.int32)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope("mla"):
             h = rms_norm(x, lp["input_norm"], cfg.eps)
@@ -497,7 +340,7 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     b = tokens.shape[0]
-    held = jnp.int32(0)
+    held = jnp.zeros((2,), jnp.int32)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope("mla"):
             h = rms_norm(x, lp["input_norm"], cfg.eps)

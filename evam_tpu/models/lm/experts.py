@@ -1,0 +1,130 @@
+"""The expert layer of one chip of an expert-parallel deployment, for every
+family that has one (models/lm/deepseek_v2.py: softmax scores, group-limited
+top-k, one routing group held; models/lm/kimi_linear.py: sigmoid scores with
+a selection bias, one group, a quarter of the experts held).
+
+A router over ALL ``n_experts`` (scores in float32), the routed experts
+this chip HOLDS (ids ``[held_lo, held_lo + n_held)``, ``n_held`` the
+leading axis of the layer's ``expert_*`` tensors) and the shared experts.
+The chip adds, for each token, only its held experts' terms (none for a
+token none of whose experts is held) and the shared experts; that partial
+sum goes on to the next layer. Nothing stands in for absent chips. The
+held experts' work follows the tokens routed to them: assignments are
+sorted by expert and run through ``jax.lax.ragged_dot``.
+
+What differs between the families is data of the config: ``score_func``
+(``softmax`` | ``sigmoid``), ``n_group`` / ``topk_group`` (1: no group
+limiting), ``top_k``, ``norm_topk`` (the chosen weights divided by their
+sum), ``scale_routed`` (times ``routed_scale``), ``held_lo``; and of the
+layer: ``router_bias`` (added to the scores for the SELECTION only; the
+weights are the scores without it).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from evam_tpu.models.lm.common import BF16, F32, swiglu
+
+
+def tensor_shapes(cfg, bias: bool) -> dict[str, tuple]:
+    """The layer's tensors; each ``expert_*`` is one expert's."""
+    h, s = cfg.hidden, cfg.n_shared * cfg.moe_inter
+    out = {"router": (h, cfg.n_experts)}
+    if bias:
+        out["router_bias"] = (cfg.n_experts,)
+    out.update(shared_gate=(h, s), shared_up=(h, s), shared_down=(s, h),
+               expert_gate=(h, cfg.moe_inter), expert_up=(h, cfg.moe_inter),
+               expert_down=(cfg.moe_inter, h))
+    return out
+
+
+def route(cfg, x, router, bias=None):
+    """Top-k over ALL experts: ``(weights [T,k], ids [T,k])``. Scores are
+    a float32 softmax or sigmoid. With groups, a group's score is its best
+    expert's; the best ``topk_group`` groups are kept (ties: the lower
+    index, as ``lax.top_k``), the rest set to 0; then the best ``top_k``
+    of what is left. With a ``bias`` [experts] the experts are chosen by
+    ``score + bias`` and weighted by the score alone."""
+    logits = jnp.dot(x.astype(F32), router.astype(F32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.score_func == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    t = scores.shape[0]
+    chosen_by = scores if bias is None else scores + bias.astype(F32)
+    if cfg.n_group > 1:
+        per_group = cfg.n_experts // cfg.n_group
+        group = chosen_by.reshape(t, cfg.n_group, per_group).max(-1)
+        _, keep = jax.lax.top_k(group, cfg.topk_group)
+        kept = jnp.zeros((t, cfg.n_group), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        chosen_by = jnp.where(jnp.repeat(kept, per_group, axis=1), chosen_by,
+                              0.0)
+    w, ids = jax.lax.top_k(chosen_by, cfg.top_k)
+    if bias is not None:
+        w = jnp.take_along_axis(scores, ids, axis=1)
+    if cfg.norm_topk and cfg.top_k > 1:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    if cfg.scale_routed:
+        w = w * cfg.routed_scale
+    return w, ids
+
+
+def held_experts(cfg, lp: dict, x, w, ids, live):
+    """The held experts' part of the routed sum, with work that follows
+    the assignments routed here: the ``T*k`` assignments are sorted by
+    held expert (those of other chips' experts, and of dead rows, last),
+    and the sorted rows go through grouped products. Every assignment to
+    a held expert is computed, however uneven the routing: the grouped
+    product runs over the first rows where they hold all of them (twice
+    the even share: ``2 T k`` times the held share of the experts), else
+    over all. Returns the sum [T, hidden], the number of held assignments
+    and the number of held experts that received at least one (the
+    grouped products read only those experts' weights)."""
+    t, k = ids.shape
+    n_held = lp["expert_gate"].shape[0]
+    local = ids - cfg.held_lo
+    mine = (local >= 0) & (local < n_held) & live[:, None]
+    sort_key = jnp.where(mine, local, n_held).reshape(-1)
+    order = jnp.argsort(sort_key, stable=True)
+    sizes = jnp.bincount(sort_key, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    n_mine = sizes.sum()
+    rows = x[order // k]
+    m = t * k
+    m_small = min(m, max(8, 2 * m * n_held // cfg.n_experts))
+
+    def run(r):
+        g = jax.lax.ragged_dot(r, lp["expert_gate"], sizes,
+                               preferred_element_type=F32)
+        u = jax.lax.ragged_dot(r, lp["expert_up"], sizes,
+                               preferred_element_type=F32)
+        hmid = (jax.nn.silu(g.astype(BF16)) * u.astype(BF16))
+        return jax.lax.ragged_dot(hmid, lp["expert_down"], sizes,
+                                  preferred_element_type=F32).astype(BF16)
+
+    def small():
+        return jnp.zeros((m, x.shape[1]), BF16).at[:m_small].set(
+            run(rows[:m_small]))
+
+    y = jax.lax.cond(n_mine <= m_small, small, lambda: run(rows))
+    # rows past the last group hold whatever the kernel left there
+    y = jnp.where((jnp.arange(m) < n_mine)[:, None], y, 0)
+    back = jnp.argsort(order)
+    y = y[back].reshape(t, k, -1).astype(F32)
+    out = (y * jnp.where(mine, w, 0.0)[..., None]).sum(1)
+    return out.astype(BF16), n_mine, (sizes > 0).sum().astype(jnp.int32)
+
+
+def moe(cfg, lp: dict, x, live):
+    """Held routed terms plus the shared experts, and ``[held
+    assignments, held experts hit]`` (int32)."""
+    with jax.named_scope("router"):
+        w, ids = route(cfg, x, lp["router"], lp.get("router_bias"))
+    with jax.named_scope("experts"):
+        routed, n_mine, n_hit = held_experts(cfg, lp, x, w, ids, live)
+    with jax.named_scope("shared"):
+        shared = swiglu(x, lp["shared_gate"], lp["shared_up"],
+                        lp["shared_down"])
+    return routed + shared, jnp.stack([n_mine, n_hit])

@@ -26,6 +26,11 @@ is the embedding (tied).
   decode step in two parts merged by their softmax sums, the shared
   prefix read once for all rows.
 
+What it shares with the other families is in models/lm/common.py (the
+packed convolution's inputs among it); the latent attention
+(models/lm/mla.py) and the expert layer (models/lm/experts.py) are the
+other two families' and are not called here.
+
 bfloat16 weights and activations; the recurrence, ``dt`` and the state in
 float32. ``d_inner`` lies on the lanes of every array: ``conv_w`` [d_conv,
 d_inner] and ``A_log`` [d_state, d_inner] are the published tensors
@@ -57,6 +62,8 @@ from evam_tpu.models.lm.common import mm as _mm
 from evam_tpu.ops import pallas_mla, pallas_selective_scan
 
 DT_MIN, DT_MAX = 0.001, 0.1
+#: the packer may start a segment at any token of a chunk
+SEGMENT_ALIGN = 1
 
 
 @dataclass(frozen=True)
@@ -163,10 +170,7 @@ def _tensor(key, kind: str, shape: tuple, std: float):
         bound = shape[0] ** -0.5
         w = jax.random.uniform(key, shape, F32, -bound, bound)
     elif kind == "dt_bias":
-        dt = jnp.exp(jax.random.uniform(key, shape, F32)
-                     * (math.log(DT_MAX) - math.log(DT_MIN))
-                     + math.log(DT_MIN))
-        w = dt + jnp.log(-jnp.expm1(-dt))
+        w = common.step_bias(key, shape, DT_MIN, DT_MAX)
     else:
         w = jax.random.normal(key, shape, F32) * std
         if kind == "A_log":
@@ -275,40 +279,16 @@ def mamba_prefill(cfg: Config, lp: dict, x, seg, conv0, h0):
     d_inner] and ``h0`` [S, d_state, d_inner]: what each segment starts
     from. Returns the mixer's output [T, hidden] and each segment's
     convolution inputs and state after its last token here."""
-    t, c, k1 = x.shape[0], cfg.d_inner, cfg.d_conv - 1
-    n_seg = h0.shape[0]
+    c, k1 = cfg.d_inner, cfg.d_conv - 1
     uz = _mm(rms_norm(x, lp["in_norm"], cfg.eps), lp["in_proj"])
     u_pre, z = uz[:, :c], uz[:, c:]
-    # tap d back of token t: the chunk's own row t - d where the segment
-    # has one, else the segment's carried input
-    s = jnp.maximum(seg, 0)
-    start = jnp.argmax(seg[:, None] == seg[None, :], axis=1)
-    off = jnp.arange(t) - start
-    carried = conv0.reshape(n_seg * k1, c)
-    taps = []
-    for d in range(k1, 0, -1):
-        own = jnp.pad(u_pre, ((d, 0), (0, 0)))[:t]
-        old = carried[s * k1 + jnp.clip(k1 - d + off, 0, k1 - 1)]
-        taps.append(jnp.where((off >= d)[:, None], own, old))
+    taps, conv_end = common.packed_conv_inputs(u_pre, seg, conv0, k1)
     u = _conv(cfg, lp, taps + [u_pre])
     dt, b, cc = _dt_b_c(cfg, lp, u)
     a = -jnp.exp(lp["A_log"].astype(F32))
     scan = (pallas_selective_scan.selective_scan if common.on_tpu()
             else pallas_selective_scan.selective_scan_xla)
     y, h_end = scan(u, dt, z, b, cc, a, lp["D"], seg, h0)
-    # each segment's last d_conv - 1 inputs: its own rows here, and the
-    # carried ones where it has fewer
-    seg_ids = jnp.arange(n_seg)
-    mine = seg[None, :] == seg_ids[:, None]
-    count = mine.sum(axis=1)
-    first = jnp.argmax(mine, axis=1)
-    rows = []
-    for r in range(k1):
-        at = count + r - k1  # index among the segment's own rows
-        own = u_pre[jnp.clip(first + at, 0, t - 1)]
-        old = carried[seg_ids * k1 + jnp.clip(count + r, 0, k1 - 1)]
-        rows.append(jnp.where((at >= 0)[:, None], own, old))
-    conv_end = jnp.concatenate(rows, axis=1)
     return _mm(y.astype(BF16), lp["out_proj"]), conv_end, h_end
 
 
@@ -423,7 +403,7 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     ``seg_from[s]`` (the snapshot's for a new sequence, the slot's own
     for a prompt that continues) and leave its end state in row
     ``seg_to[s]``. Returns the state, the logits rows ``last_idx`` as
-    ``(top, ids)`` and 0 (no held experts). ``pos`` is not used: no
+    ``(top, ids)`` and ``[0, 0]`` (no held experts). ``pos`` is not used: no
     layer has a positional term."""
 
     def mamba_layer(lp, l, x, ssm, conv):
@@ -448,7 +428,7 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
         x = params["embed"][tokens]
     x, state = _layers(cfg, params, x, state, mamba_layer, attn_layer)
     _, top, ids = head(cfg, params, x[last_idx])
-    return state, top, ids, jnp.int32(0)
+    return state, top, ids, jnp.zeros((2,), jnp.int32)
 
 
 def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
@@ -484,4 +464,4 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
         x = params["embed"][tokens]
     x, state = _layers(cfg, params, x, state, mamba_layer, attn_layer)
     _, top, ids = head(cfg, params, x)
-    return state, top, ids, jnp.int32(0)
+    return state, top, ids, jnp.zeros((2,), jnp.int32)

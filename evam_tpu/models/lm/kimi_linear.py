@@ -1,0 +1,552 @@
+"""Kimi-Linear in plain JAX: delta-rule linear attention (KDA) with a latent
+attention layer (MLA, position-free) after every third, over sparse
+experts; one chip's share of an expert-parallel layer.
+
+Layers are numbered from 1 as ``linear_attn_config`` numbers them:
+``kda_layers`` 1, 2, 3, 5, 6, 7, ... and ``full_attn_layers`` 4, 8, ...;
+the first ``first_k_dense_replace`` layers have a dense SwiGLU behind their
+mixer, every other layer the expert layer. Pre-norm residual, RMSNorm;
+untied head.
+
+* **KDA mixer** (H heads of D = 128 keys and values). ``q, k, v = W_q h,
+  W_k h, W_v h``; each through its OWN causal depthwise convolution of
+  ``d_conv`` taps (no bias) and a silu; ``q``, ``k`` L2-normalised per
+  head, ``q`` times D^-1/2. Log decay per head AND channel ``g = -exp(A_log
+  [head]) * softplus(W_f2 (W_f1 h) + dt_bias)``; write strength per head
+  ``b = sigmoid(W_b h)``. ``S_t = (I - b k k^T) Diag(exp(g)) S_{t-1} + b k
+  v^T``, ``o = S^T q``; out = ``W_o (rms_norm_head(o) * sigmoid(W_g2 (W_g1
+  h)))``. No positional term: the recurrence carries order. Between steps
+  a sequence carries, per KDA layer, the float32 state ``S`` [H, D, D] and
+  the last ``d_conv - 1`` inputs of the three convolutions: per SLOT of
+  the generate engine, not per page (``state_shapes``). A prefill chunk
+  runs the recurrence in a Pallas kernel with the state in VMEM
+  (ops/pallas_kda.py), in blocks of ``SEGMENT_ALIGN`` tokens to which the
+  engine's packer aligns every segment's start; a decode step's one-token
+  update is plain XLA.
+* **MLA mixer**: models/lm/mla.py, as DeepSeek-V2's but with no rotation
+  of ``q_r`` / ``k_r`` (``mla_use_nope``) and no query down-projection
+  (``q_lora_rank`` null); the cache row is the same 576 values.
+* **Expert layer**: models/lm/experts.py, as DeepSeek-V2's but with
+  sigmoid scores, a selection bias (chosen by ``s + b``, weighted by
+  ``s``), one group, renormalised weights times ``routed_scaling_factor``,
+  and a held RANGE of experts ``[held_lo, held_lo + experts_held)``.
+
+bfloat16 weights and activations; the KDA state, the decay and the delta
+update float32. The KDA mixers are STACKED and ONE ``lax.scan`` runs over
+them, so that the kernel has one name in a device trace: a trip is a KDA
+mixer, its feed-forward and, where an MLA layer follows it, that layer with
+ITS feed-forward. The feed-forwards and the MLA layers are NOT stacked:
+each trip picks what follows its mixer by ONE ``lax.switch`` over branches
+that hold their weights as they are (a grouped product cannot read a slice of a stack of
+expert weights in place: the 0.9 GB of a layer's held experts would be
+copied every step).
+
+Weights (``common.tensor_key``): ``normal * initializer_range``, gains ``1
++ that``; the convolutions uniform in +-d_conv^-1/2; ``A_log = log U(1,
+16)`` a head; ``dt_bias`` the inverse softplus of a step drawn
+log-uniformly from [0.001, 0.1] (the family's own initialisation: a state
+that neither dies in ten tokens nor never forgets); ``router_bias`` as any
+tensor (small and not zero, so that selection and weighting differ); the
+MLA layers' ``q`` and ``kv_a`` ``mla_qk_init_scale`` times as wide (at
+``initializer_range`` alone a softmax over 2.4 k rows is flat and the layer
+adds a hundredth of what a KDA mixer adds: no comparison would see which
+rows it weighs).
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from evam_tpu.models.lm import common, experts, mla
+from evam_tpu.models.lm.common import BF16, F32, GLOBAL_LAYER, rms_norm
+from evam_tpu.models.lm.common import mm as _mm
+from evam_tpu.ops import pallas_kda
+
+DT_MIN, DT_MAX = 0.001, 0.1
+A_MAX = 16.0
+L2_EPS = 1e-6
+#: the packer starts every segment at a multiple of the kernel's block
+SEGMENT_ALIGN = pallas_kda.BLOCK
+
+
+@dataclass(frozen=True)
+class Config:
+    hidden: int
+    dense_inter: int
+    moe_inter: int
+    layers: int
+    first_dense: int
+    kda_ids: tuple      # 0-based model layers, in order
+    mla_ids: tuple
+    kda_heads: int
+    kda_dim: int
+    d_conv: int
+    gate_rank: int
+    heads: int          # of the latent attention
+    kv_rank: int
+    nope: int
+    rope: int
+    v_dim: int
+    n_experts: int      # the router's outputs
+    n_held: int
+    held_lo: int
+    n_shared: int
+    top_k: int
+    routed_scale: float
+    norm_topk: bool
+    eps: float
+    vocab: int          # rows of the vocabulary held here
+    seed: int
+    init_range: float
+    qk_init_scale: float    # the MLA layers' q and kv_a: init_range times it
+
+    #: what models/lm/mla.py and models/lm/experts.py read beside the fields
+    q_rank = 0
+    score_func = "sigmoid"
+    scale_routed = True
+    n_group = 1
+    topk_group = 1
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "Config":
+        la = d["linear_attn_config"]
+        n = d["num_hidden_layers"]
+        kda = tuple(i - 1 for i in la["kda_layers"] if i <= n)
+        full = tuple(i - 1 for i in la["full_attn_layers"] if i <= n)
+        dense = d["first_k_dense_replace"]
+        if (sorted(kda + full) != list(range(n)) or d["q_lora_rank"]
+                or not d["mla_use_nope"] or d["num_expert_group"] != 1
+                or d["moe_router_activation_func"] != "sigmoid"
+                or d["tie_word_embeddings"] or dense < 1
+                or any(i < dense or i - 1 not in kda for i in full)):
+            raise ValueError(
+                "the kimi_linear family is written for layers that are each "
+                "KDA or full attention, latent attention without rotation "
+                "or a query down-projection behind a KDA layer and over "
+                "experts, one expert group, sigmoid scores, an untied head")
+        return cls(
+            hidden=d["hidden_size"], dense_inter=d["intermediate_size"],
+            moe_inter=d["moe_intermediate_size"], layers=n, first_dense=dense,
+            kda_ids=kda, mla_ids=full, kda_heads=la["num_heads"],
+            kda_dim=la["head_dim"], d_conv=la["short_conv_kernel_size"],
+            gate_rank=d["kda_gate_rank"], heads=d["num_attention_heads"],
+            kv_rank=d["kv_lora_rank"], nope=d["qk_nope_head_dim"],
+            rope=d["qk_rope_head_dim"], v_dim=d["v_head_dim"],
+            n_experts=d["num_experts"], n_held=d["experts_held"],
+            held_lo=d["held_lo"], n_shared=d["num_shared_experts"],
+            top_k=d["num_experts_per_token"],
+            routed_scale=float(d["routed_scaling_factor"]),
+            norm_topk=bool(d["moe_renormalize"]), eps=d["rms_norm_eps"],
+            vocab=d["vocab_held"], seed=d["weights_seed"],
+            init_range=d["initializer_range"],
+            qk_init_scale=float(d["mla_qk_init_scale"]))
+
+    @property
+    def latent(self) -> int:
+        """Values the cache holds per token and MLA layer."""
+        return self.kv_rank + self.rope
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.nope + self.rope) ** -0.5
+
+    @property
+    def kda_width(self) -> int:
+        return self.kda_heads * self.kda_dim
+
+    @property
+    def moe_ids(self) -> tuple:
+        """The model layers that have the expert layer, in order."""
+        return tuple(range(self.first_dense, self.layers))
+
+    @property
+    def mla_after(self) -> tuple:
+        """Per KDA layer: the MLA layer (its index among them) right
+        behind it, -1 where none is."""
+        return tuple(self.mla_ids.index(i + 1) if i + 1 in self.mla_ids
+                     else -1 for i in self.kda_ids)
+
+
+# --------------------------------------------------------------- weights
+
+
+def kda_shapes(cfg: Config) -> dict[str, tuple]:
+    h, w, r = cfg.hidden, cfg.kda_width, cfg.gate_rank
+    return {"input_norm": (h,), "post_norm": (h,),
+            "q": (h, w), "k": (h, w), "v": (h, w),
+            "q_conv": (cfg.d_conv, w), "k_conv": (cfg.d_conv, w),
+            "v_conv": (cfg.d_conv, w),
+            "f_a": (h, r), "f_b": (r, w), "dt_bias": (w,),
+            "A_log": (cfg.kda_heads,), "b_proj": (h, cfg.kda_heads),
+            "g_a": (h, r), "g_b": (r, w), "o_norm": (cfg.kda_dim,),
+            "o": (w, h)}
+
+
+def mla_shapes(cfg: Config) -> dict[str, tuple]:
+    h = cfg.hidden
+    return {"input_norm": (h,), "post_norm": (h,), **mla.tensor_shapes(cfg)}
+
+
+def dense_shapes(cfg: Config) -> dict[str, tuple]:
+    h, i = cfg.hidden, cfg.dense_inter
+    return {"mlp_gate": (h, i), "mlp_up": (h, i), "mlp_down": (i, h)}
+
+
+def moe_shapes(cfg: Config) -> dict[str, tuple]:
+    return experts.tensor_shapes(cfg, bias=True)
+
+
+def _kind(name: str) -> str:
+    if name.endswith("_conv"):
+        return "conv"
+    if name in ("dt_bias", "A_log"):
+        return name
+    return "gain" if name.endswith("norm") else "normal"
+
+
+def _tensor(key, kind: str, shape: tuple, std: float):
+    """One tensor from its key, by the rule of its ``kind``."""
+    if kind == "conv":
+        bound = shape[0] ** -0.5
+        w = jax.random.uniform(key, shape, F32, -bound, bound)
+    elif kind == "dt_bias":
+        w = common.step_bias(key, shape, DT_MIN, DT_MAX)
+    elif kind == "A_log":
+        w = jnp.log(jax.random.uniform(key, shape, F32, 1.0, A_MAX))
+    else:
+        w = jax.random.normal(key, shape, F32) * std
+        if kind == "gain":
+            w = 1.0 + w
+    return w.astype(BF16)
+
+
+#: compiled once per kind and shape, whatever the name and the layer
+_make_one = jax.jit(_tensor, static_argnums=(1, 2, 3))
+#: one tensor per expert id, ``fold_in(key, expert)``, on a leading axis
+_make_experts = jax.jit(
+    lambda key, ids, kind, shape, std: jax.vmap(lambda e: _tensor(
+        jax.random.fold_in(key, e), kind, shape, std))(ids),
+    static_argnums=(2, 3, 4))
+
+
+def make_layer(cfg: Config, layer: int, shapes: dict, held=None,
+               wider=()) -> dict:
+    """The tensors of model layer ``layer`` (0-based) named in ``shapes``;
+    each ``expert_*`` tensor once per expert of ``held`` (global ids);
+    those named in ``wider`` drawn ``qk_init_scale`` times as wide."""
+    out = {}
+    for name, shape in shapes.items():
+        key = common.tensor_key(cfg.seed, layer, name)
+        if name.startswith("expert_"):
+            out[name] = _make_experts(
+                key, jnp.asarray(list(held), jnp.uint32), _kind(name), shape,
+                cfg.init_range)
+        else:
+            std = cfg.init_range * (cfg.qk_init_scale if name in wider else 1)
+            out[name] = _make_one(key, _kind(name), shape, std)
+    return out
+
+
+def ffn_shapes(cfg: Config, layer: int) -> dict[str, tuple]:
+    return dense_shapes(cfg) if layer < cfg.first_dense else moe_shapes(cfg)
+
+
+def make_params(cfg: Config, held=None) -> dict:
+    """``kda``: every KDA mixer's tensors with its two norms, stacked;
+    ``mla``: a list, one dict an MLA layer; ``ffn``: a list, one dict a
+    model layer (dense or experts). ``held``: the routed experts held
+    (default: the config's range)."""
+    if held is None:
+        held = range(cfg.held_lo, cfg.held_lo + cfg.n_held)
+    glob = {"embed": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,),
+            "head": (cfg.hidden, cfg.vocab)}
+    params = make_layer(cfg, GLOBAL_LAYER, glob)
+    kda = [make_layer(cfg, i, kda_shapes(cfg)) for i in cfg.kda_ids]
+    params["kda"] = {name: jnp.stack([lp[name] for lp in kda])
+                     for name in kda[0]}
+    params["mla"] = [make_layer(cfg, i, mla_shapes(cfg), wider=("q", "kv_a"))
+                     for i in cfg.mla_ids]
+    params["ffn"] = [make_layer(cfg, i, ffn_shapes(cfg, i), held)
+                     for i in range(cfg.layers)]
+    return params
+
+
+def param_count(cfg: Config) -> int:
+    def total(shapes):
+        return sum((cfg.n_held if name.startswith("expert_") else 1)
+                   * math.prod(s) for name, s in shapes.items())
+
+    return (2 * cfg.vocab * cfg.hidden + cfg.hidden
+            + len(cfg.kda_ids) * total(kda_shapes(cfg))
+            + len(cfg.mla_ids) * total(mla_shapes(cfg))
+            + sum(total(ffn_shapes(cfg, i)) for i in range(cfg.layers)))
+
+
+def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
+                 slots: int) -> dict:
+    """The device state of this family's sequences. ``pages``: latent rows
+    of the MLA layers. Per SLOT (and two rows more: row ``slots`` for rows
+    of a step that carry no sequence, row ``slots + 1`` the snapshot after
+    the shared prefix's last token) and KDA layer: ``kda``, the float32
+    matrix state of every head, and ``conv``, the last ``d_conv - 1``
+    inputs of the three convolutions (``q | k | v``), taps side by side on
+    the lanes."""
+    rows = slots + 2
+    n = len(cfg.kda_ids)
+    return {
+        "pages": jax.ShapeDtypeStruct(
+            (len(cfg.mla_ids), n_pages, page_tokens, cfg.latent), BF16),
+        "kda": jax.ShapeDtypeStruct(
+            (n, rows, cfg.kda_heads, cfg.kda_dim, cfg.kda_dim), F32),
+        "conv": jax.ShapeDtypeStruct(
+            (n, rows, (cfg.d_conv - 1) * 3 * cfg.kda_width), BF16),
+    }
+
+
+# ---------------------------------------------------------------- layers
+
+
+def _kda_inputs(cfg: Config, lp: dict, h, taps, live):
+    """From the normed residual rows ``h`` [T, hidden] and the
+    convolutions' taps (oldest first, the newest the rows' own ``q | k |
+    v``): what the recurrence reads, each [T, heads, dim] float32: ``q``,
+    ``k``, ``b k``, ``b v``, the log decay ``g``; rows that are not
+    ``live`` write nothing and decay nothing."""
+    t, hd, d = h.shape[0], cfg.kda_heads, cfg.kda_dim
+    w = jnp.concatenate([lp["q_conv"], lp["k_conv"], lp["v_conv"]],
+                        axis=1).astype(F32)
+    acc = sum(w[i] * tap.astype(F32) for i, tap in enumerate(taps))
+    qkv = jax.nn.silu(acc).reshape(t, 3, hd, d)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
+
+    q, k, v = unit(qkv[:, 0]) * d ** -0.5, unit(qkv[:, 1]), qkv[:, 2]
+    step = jax.nn.softplus(
+        jnp.dot(_mm(h, lp["f_a"]), lp["f_b"], preferred_element_type=F32)
+        + lp["dt_bias"].astype(F32)).reshape(t, hd, d)
+    g = -jnp.exp(lp["A_log"].astype(F32))[None, :, None] * step
+    beta = jax.nn.sigmoid(jnp.dot(h, lp["b_proj"],
+                                  preferred_element_type=F32))
+    beta = jnp.where(live[:, None], beta, 0.0)[:, :, None]
+    return q, k, beta * k, beta * v, jnp.where(live[:, None, None], g, 0.0)
+
+
+def _kda_out(cfg: Config, lp: dict, h, o):
+    """``W_o (rms_norm_head(o) * sigmoid(W_g2 (W_g1 h)))``; ``o`` [T,
+    heads, dim] float32."""
+    t = h.shape[0]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.eps)
+    o = o * lp["o_norm"].astype(F32)
+    gate = jax.nn.sigmoid(jnp.dot(_mm(h, lp["g_a"]), lp["g_b"],
+                                  preferred_element_type=F32))
+    return _mm((o.reshape(t, -1) * gate).astype(BF16), lp["o"])
+
+
+def _qkv_pre(lp: dict, h):
+    return jnp.concatenate([_mm(h, lp["q"]), _mm(h, lp["k"]),
+                            _mm(h, lp["v"])], axis=1)
+
+
+def kda_prefill(cfg: Config, lp: dict, x, seg, conv0, s0):
+    """A packed chunk through one KDA mixer. ``conv0`` [S, (d_conv-1) * 3 *
+    width] and ``s0`` [S, heads, dim, dim]: what each segment starts
+    from. Returns the mixer's output [T, hidden] and each segment's
+    convolution inputs and state after its last token here."""
+    t = x.shape[0]
+    h = rms_norm(x, lp["input_norm"], cfg.eps)
+    pre = _qkv_pre(lp, h)
+    taps, conv_end = common.packed_conv_inputs(pre, seg, conv0,
+                                               cfg.d_conv - 1)
+    flat = [a.reshape(t, -1) for a in _kda_inputs(
+        cfg, lp, h, taps + [pre], seg >= 0)]
+    rule = (pallas_kda.delta_rule if common.on_tpu()
+            else pallas_kda.delta_rule_xla)
+    o, s_end = rule(*flat, seg, s0)
+    o = o.reshape(t, cfg.kda_heads, cfg.kda_dim)
+    return _kda_out(cfg, lp, h, o), conv_end, s_end
+
+
+def kda_decode(cfg: Config, lp: dict, x, slot, live, conv_all, s_all):
+    """One token per row through one KDA mixer, the slot state updated
+    WHOLE: ``slot`` [B] names each ``live`` row's row of ``conv_all`` [R,
+    (d_conv-1) * 3 * width] and ``s_all`` [R, heads, dim, dim] float32
+    (every row of the layer's slot state). Returns the output [B, hidden]
+    and both arrays, the rows named moved on by their token and every
+    other row as it was, bit for bit (no write, no decay). Rows move
+    between the step's B rows and the state's R by one-hot products,
+    which are exact, so nothing is gathered or scattered a row at a time
+    (through XLA those are loops of B trips a layer: 18 of a 64-row
+    step's 29.5 ms); every product with the state is elementwise, in
+    float32, in two passes over it. A row of the step that carries no
+    sequence is placed NOWHERE: it reads zeros, writes nothing and comes
+    out zero. (Placed in the null row, such rows' sums grew step by step
+    until they overflowed, and a one-hot product of 0 with inf is NaN in
+    EVERY row: the first build of this on the chip.)"""
+    c = 3 * cfg.kda_width
+    place = ((slot[None, :] == jnp.arange(s_all.shape[0])[:, None])
+             & live[None, :])  # [R, B]
+    at_rows, at_rows32 = place.astype(BF16), place.astype(F32)
+    h = rms_norm(x, lp["input_norm"], cfg.eps)
+    pre = _qkv_pre(lp, h)
+    conv_old = common.es("rb,rc->bc", at_rows, conv_all).astype(BF16)
+    taps = [conv_old[:, i * c:(i + 1) * c] for i in range(cfg.d_conv - 1)]
+    q, k, kb, vb, g = (
+        jnp.einsum("rb,bhd->rhd", at_rows32, a,
+                   precision=jax.lax.Precision.HIGHEST)
+        for a in _kda_inputs(cfg, lp, h, taps + [pre], live))
+    decayed = s_all * jnp.exp(g)[..., None]
+    u = vb - (kb[..., None] * decayed).sum(axis=2)
+    # S^T q of the state after the write, from the state before it
+    o = ((q[..., None] * decayed).sum(axis=2)
+         + u * (k * q).sum(axis=-1, keepdims=True))
+    s_all = decayed + k[..., None] * u[:, :, None, :]
+    o = jnp.einsum("rb,rhd->bhd", at_rows32, o,
+                   precision=jax.lax.Precision.HIGHEST)
+    conv_new = common.es(
+        "rb,bc->rc", at_rows,
+        jnp.concatenate([conv_old[:, c:], pre], axis=1)).astype(BF16)
+    conv_all = jnp.where(place.any(axis=1)[:, None], conv_new, conv_all)
+    return _kda_out(cfg, lp, h, o), conv_all, s_all
+
+
+def head(cfg: Config, params: dict, x):
+    return common.head(x, params["final_norm"], cfg.eps, params["head"])
+
+
+# ----------------------------------------------------------- step bodies
+
+
+def _layers(cfg: Config, params: dict, x, state, live, kda_layer, mla_layer):
+    """Every layer in its order, as ONE ``lax.scan`` over the stacked KDA
+    mixers: ``kda_layer(lp, l, x, kda, conv)`` with ``l`` the layer's row
+    of the slot state, then that layer's feed-forward, then, where an MLA
+    layer follows, ``mla_layer(lp, j, x, pages)`` (pages at index ``j``)
+    and its feed-forward. Returns ``x``, the state and ``[held
+    assignments, held experts hit]`` summed over the layers."""
+    none = jnp.zeros((2,), jnp.int32)
+
+    def ffn(layer: int, post_norm, x):
+        """The feed-forward of model layer ``layer``, residual added."""
+        w = params["ffn"][layer]
+        h = rms_norm(x, post_norm, cfg.eps)
+        if "router" in w:
+            y, n = experts.moe(cfg, w, h, live)
+        else:
+            with jax.named_scope("dense_mlp"):
+                y, n = common.swiglu(h, w["mlp_gate"], w["mlp_up"],
+                                     w["mlp_down"]), none
+        return x + y, n
+
+    def behind(l: int):
+        """What follows KDA mixer ``l`` before the next one: its
+        feed-forward and, where an MLA layer is next, that layer and ITS
+        feed-forward."""
+        def run(post_norm, x, pages):
+            x, n = ffn(cfg.kda_ids[l], post_norm, x)
+            j = cfg.mla_after[l]
+            if j >= 0:
+                lp = params["mla"][j]
+                x, pages = mla_layer(lp, j, x, pages)
+                x, m = ffn(cfg.mla_ids[j], lp["post_norm"], x)
+                n = n + m
+            return x, pages, n
+        return run
+
+    rest = [behind(l) for l in range(len(cfg.kda_ids))]
+
+    def body(carry, xs):
+        lp, l = xs
+        x, pages, kda, conv, held = carry
+        x, kda, conv = kda_layer(lp, l, x, kda, conv)
+        x, pages, n = jax.lax.switch(l, rest, lp["post_norm"], x, pages)
+        return (x, pages, kda, conv, held + n), None
+
+    n = len(cfg.kda_ids)
+    carry = (x, state["pages"], state["kda"], state["conv"], none)
+    (x, pages, kda, conv, held), _ = jax.lax.scan(
+        body, carry, (params["kda"], jnp.arange(n, dtype=jnp.int32)))
+    return x, {"pages": pages, "kda": kda, "conv": conv}, held
+
+
+def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
+                  dest_page, dest_off, prefix_pages, n_prefix, cont_pages,
+                  n_cont, last_idx, seg_from, seg_to):
+    """A packed chunk of new tokens through every layer. MLA layers write
+    the tokens' latent rows to ``state["pages"][layer, dest_page,
+    dest_off]``; KDA layers start segment ``s`` from slot-state row
+    ``seg_from[s]`` (the snapshot's for a new sequence, the slot's own
+    for a prompt that continues) and leave its end state in row
+    ``seg_to[s]``. Rows of no segment (``seg`` -1: the chunk's tail, and
+    the rows before a segment's aligned start) move no state. Returns the
+    state, the logits rows ``last_idx`` as ``(top, ids)`` and ``[held
+    assignments, held experts hit]``. ``pos`` is not used: no layer has a
+    positional term."""
+
+    def kda_layer(lp, l, x, kda, conv):
+        with jax.named_scope("kda"):
+            y, conv_end, s_end = kda_prefill(
+                cfg, lp, x, seg, conv[l, seg_from], kda[l, seg_from])
+            kda = kda.at[l, seg_to].set(s_end)
+            conv = conv.at[l, seg_to].set(conv_end)
+        return x + y, kda, conv
+
+    def mla_layer(lp, j, x, pages):
+        with jax.named_scope("mla"):
+            h = rms_norm(x, lp["input_norm"], cfg.eps)
+            qn, qr, lat = mla.qkv(cfg, lp, h)
+            x = x + mla.mla_prefill(
+                cfg, lp, qn, qr, lat, seg,
+                common.page_rows(pages[j], prefix_pages), n_prefix,
+                common.page_rows(pages[j], cont_pages), n_cont)
+            pages = pages.at[j, dest_page, dest_off].set(lat)
+        return x, pages
+
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+    x, state, held = _layers(cfg, params, x, state, seg >= 0, kda_layer,
+                             mla_layer)
+    _, top, ids = head(cfg, params, x[last_idx])
+    return state, top, ids, held
+
+
+def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
+                  ctx_len, dest_page, dest_off, live, prefix_pages, n_prefix,
+                  slot):
+    """One token per row. KDA layers move row ``slot[b]`` of the slot
+    state on for every ``live`` row and leave every other row as it was
+    (the null row that the others name among them); MLA
+    layers write the row's latent to its page and attend to the shared
+    prefix (read once for all rows) and, through the table of its OWN
+    pages, to its ``ctx_len`` own cached rows."""
+    b = tokens.shape[0]
+
+    def kda_layer(lp, l, x, kda, conv):
+        with jax.named_scope("kda"):
+            y, conv_l, s_l = kda_decode(cfg, lp, x, slot, live, conv[l],
+                                        kda[l])
+            kda = kda.at[l].set(s_l)
+            conv = conv.at[l].set(conv_l)
+        return x + y, kda, conv
+
+    def mla_layer(lp, j, x, pages):
+        with jax.named_scope("mla"):
+            h = rms_norm(x, lp["input_norm"], cfg.eps)
+            qn, qr, lat = mla.qkv(cfg, lp, h)
+            pages = pages.at[j, dest_page, dest_off].set(lat)
+            ctx = pages[j][page_table].reshape(b, -1, cfg.latent)
+            x = x + mla.mla_decode(
+                cfg, lp, qn, qr, ctx, ctx_len,
+                common.page_rows(pages[j], prefix_pages), n_prefix)
+        return x, pages
+
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+    x, state, held = _layers(cfg, params, x, state, live, kda_layer,
+                             mla_layer)
+    _, top, ids = head(cfg, params, x)
+    return state, top, ids, held

@@ -1,0 +1,146 @@
+"""Latent attention (MLA) in the absorbed form, for every family that has it
+(models/lm/deepseek_v2.py: rotated, with a query down-projection;
+models/lm/kimi_linear.py: position-free, the query projected directly).
+
+``[c_kv ; k_r] = W_kva h``, ``c_kv = norm(c_kv)``; the cache holds ``[c_kv
+; k_r]`` per token and layer (``latent`` values); ``[k_nope ; v] = W_kvb
+c_kv`` per head; ``score = (q_nope . k_nope + q_r . k_r) * scale``.
+``W_kvb``'s key half is folded into the query and its value half into the
+output, so every key is read as a latent row: a decode step in two parts,
+all rows' queries against the shared prefix's rows in one product and each
+row against its own pages, merged by their softmax sums; a prefill chunk
+over prefix, continued and own rows in one kernel (ops/pallas_mla.py).
+Heads are materialised only in the references.
+
+What differs between the families is data of the config: ``q_rank`` (0:
+no query down-projection, the layer holds ``q``; else ``q_a``,
+``q_a_norm``, ``q_b``), ``softmax_scale``, and whether the caller hands
+``cos_sin`` (the rotation of ``q_r`` and ``k_r``; None: none). A config
+gives ``heads``, ``kv_rank``, ``nope``, ``rope``, ``v_dim``, ``latent``,
+``eps``.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+from evam_tpu.models.lm import common
+from evam_tpu.models.lm.common import BF16, F32, es, mm, rms_norm
+from evam_tpu.ops import pallas_mla
+
+
+def tensor_shapes(cfg) -> dict[str, tuple]:
+    """The mixer's tensors (``input_norm`` is the layer's)."""
+    h, hd = cfg.hidden, cfg.heads
+    q_out = hd * (cfg.nope + cfg.rope)
+    q = ({"q_a": (h, cfg.q_rank), "q_a_norm": (cfg.q_rank,),
+          "q_b": (cfg.q_rank, q_out)} if cfg.q_rank else {"q": (h, q_out)})
+    return {**q, "kv_a": (h, cfg.latent), "kv_a_norm": (cfg.kv_rank,),
+            "kv_b": (cfg.kv_rank, hd * (cfg.nope + cfg.v_dim)),
+            "o": (hd * cfg.v_dim, h)}
+
+
+def rope(x, cos, sin):
+    """Rotate the pairs (2i, 2i+1) of the last axis; ``cos``/``sin``
+    broadcast against ``x[..., ::2]``."""
+    x = x.astype(F32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(x.shape)
+
+
+def qkv(cfg, lp: dict, x, cos_sin=None):
+    """Per token: the query ``(q_nope [T,h,nope], q_rope [T,h,rope])`` and
+    the latent row ``[c_kv ; k_r]`` that the cache holds; ``q_rope`` and
+    ``k_r`` rotated by ``cos_sin`` where it is given."""
+    t = x.shape[0]
+    if cfg.q_rank:
+        c_q = rms_norm(mm(x, lp["q_a"]), lp["q_a_norm"], cfg.eps)
+        q = mm(c_q, lp["q_b"])
+    else:
+        q = mm(x, lp["q"])
+    q = q.reshape(t, cfg.heads, cfg.nope + cfg.rope)
+    q_nope, q_rope = q[..., :cfg.nope], q[..., cfg.nope:]
+    kv = mm(x, lp["kv_a"])
+    c_kv = rms_norm(kv[:, :cfg.kv_rank], lp["kv_a_norm"], cfg.eps)
+    k_r = kv[:, cfg.kv_rank:]
+    if cos_sin is not None:
+        cos, sin = cos_sin
+        q_rope = rope(q_rope, cos[:, None], sin[:, None]).astype(BF16)
+        k_r = rope(k_r, cos, sin).astype(BF16)
+    return q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
+
+
+def kv_b(cfg, lp: dict):
+    """``W_kvb`` as ``(W_uk, W_uv)``, each [heads, kv_rank, 128]."""
+    w = lp["kv_b"].reshape(cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
+    w = w.transpose(1, 0, 2)
+    return w[..., :cfg.nope], w[..., cfg.nope:]
+
+
+def absorb_q(cfg, w_uk, q_nope, q_rope):
+    """The query in the cache's own space: [T, h, kv_rank + rope]."""
+    q_lat = es("thd,hcd->thc", q_nope, w_uk).astype(BF16)
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+def _softmax_sums(cfg, score_expr, value_expr, q, rows, visible):
+    """``common.softmax_sums`` over latent rows: a row's key is the whole
+    row, its value the row's ``c_kv``."""
+    return common.softmax_sums(cfg.softmax_scale, score_expr, value_expr, q,
+                               rows, rows[..., :cfg.kv_rank], visible)
+
+
+def mla_decode(cfg, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
+               n_prefix):
+    """One new token per row, absorbed form, its softmax in two parts.
+    OWN: each row against its own cached rows ``ctx`` [B, T, latent]
+    (the new token's row among them), visible below ``ctx_len`` [B].
+    SHARED: the queries of all rows and heads against the prefix rows
+    ``prefix`` [Tp, latent] (visible below ``n_prefix``), which every
+    row shares and which are read once: one dense product. The parts
+    are merged by their softmax sums in float32 (the arithmetic of the
+    one softmax over prefix and own rows) before ``W_uv``. ``prefix``
+    may be None: the own part alone."""
+    w_uk, w_uv = kv_b(cfg, lp)
+    q = absorb_q(cfg, w_uk, q_nope, q_rope)
+    own = jnp.arange(ctx.shape[1])[None, None, :] < ctx_len[:, None, None]
+    sums = _softmax_sums(cfg, "bhc,btc->bht", "bht,btc->bhc", q, ctx, own)
+    shared = None
+    if prefix is not None:
+        seen = jnp.arange(prefix.shape[0]) < n_prefix
+        shared = _softmax_sums(cfg, "bhc,sc->bhs", "bhs,sc->bhc", q, prefix,
+                               seen)
+    o_lat = common.merge_softmax_sums(sums, shared).astype(BF16)
+    o = es("bhc,hcv->bhv", o_lat, w_uv).astype(BF16)
+    return mm(o.reshape(o.shape[0], -1), lp["o"])
+
+
+def mla_prefill(cfg, lp: dict, q_nope, q_rope, lat, seg, prefix,
+                n_prefix, cont, n_cont):
+    """A packed chunk, absorbed form throughout: every (token, head) is
+    one query row over ONE list of latent rows: the shared prefix rows
+    ``prefix`` [Tp, latent] (visible below ``n_prefix``), the earlier
+    rows ``cont`` [Tc, latent] of the sequence that continues in this
+    chunk (below ``n_cont``, to segment 0 only) and the chunk's own rows
+    ``lat`` (a token sees its segment's, up to itself). ``prefix`` and
+    ``cont`` may be None. The scores stay on the chip
+    (ops/pallas_mla.py)."""
+    t = lat.shape[0]
+    w_uk, w_uv = kv_b(cfg, lp)
+    q = absorb_q(cfg, w_uk, q_nope, q_rope)
+    keys = jnp.concatenate(
+        [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
+    bounds, b0 = common.chunk_bounds(
+        seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
+        0 if cont is None else cont.shape[0])
+    attend = (pallas_mla.latent_attention if common.on_tpu()
+              else pallas_mla.latent_attention_xla)
+    q = q.reshape(t * cfg.heads, cfg.latent)
+    o_lat = attend(
+        q[:, :cfg.kv_rank], q[:, cfg.kv_rank:],
+        keys[:, :cfg.kv_rank], keys[:, cfg.kv_rank:],
+        jnp.repeat(bounds, cfg.heads, axis=0),
+        scale=cfg.softmax_scale, b0=b0)
+    o = es("thc,hcv->thv", o_lat.reshape(t, cfg.heads, cfg.kv_rank), w_uv)
+    return mm(o.astype(BF16).reshape(t, -1), lp["o"])

@@ -15,6 +15,23 @@ whole: all 28 layers (26 Mamba, attention at 7 and 21) and the whole
 vocabulary fit one chip, so nothing is cut but the weights (seeded).
 ``jamba_tiny`` has the same structure (Mamba runs before, between and
 after two attention layers) at a size a CPU test runs.
+
+``kimi_linear_ep4`` is Kimi-Linear-48B-A3B-Instruct's published config
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json)
+with every width as published, cut to what ONE chip of four that share
+each layer holds of the first eight layers (two whole periods of three KDA
+layers and one MLA layer, the first with the dense feed-forward): the
+router keeps its 256 outputs and 8 a token; the chip holds experts
+``[held_lo, held_lo + experts_held)`` (64) and a quarter of the
+vocabulary. ``kda_gate_rank`` is the one size the config does not carry
+(the family's head_dim). ``kimi_linear_tiny`` has the same structure (KDA,
+KDA, MLA, KDA, the first dense; 16 experts, 4 held, 4 a token) at a size
+a CPU test runs.
+
+Which module serves a preset is its ``model_type`` (models/lm
+``FAMILIES``); the latent attention (models/lm/mla.py) and the expert
+layer (models/lm/experts.py) are shared by ``deepseek_v2`` and
+``kimi_linear`` and read what differs from these keys.
 """
 
 from __future__ import annotations
@@ -55,7 +72,67 @@ JAMBA2_3B_PUBLISHED = {
     "vocab_size": 65536,
 }
 
+KIMI_LINEAR_PUBLISHED = {
+    "first_k_dense_replace": 1, "head_dim": 72, "hidden_act": "silu",
+    "hidden_size": 2304, "intermediate_size": 9216, "kv_lora_rank": 512,
+    "linear_attn_config": {
+        "full_attn_layers": [4, 8, 12, 16, 20, 24, 27], "head_dim": 128,
+        "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                       21, 22, 23, 25, 26],
+        "num_heads": 32, "short_conv_kernel_size": 4},
+    "mla_use_nope": True, "model_max_length": 1048576,
+    "model_type": "kimi_linear", "moe_intermediate_size": 1024,
+    "moe_layer_freq": 1, "moe_renormalize": True,
+    "moe_router_activation_func": "sigmoid", "num_attention_heads": 32,
+    "num_expert_group": 1, "num_experts": 256, "num_experts_per_token": 8,
+    "num_hidden_layers": 27, "num_key_value_heads": 32,
+    "num_nextn_predict_layers": 0, "num_shared_experts": 1,
+    "q_lora_rank": None, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 10000,
+    "routed_scaling_factor": 2.446, "tie_word_embeddings": False,
+    "topk_group": 1, "use_grouped_topk": True, "v_head_dim": 128,
+    "vocab_size": 163840,
+}
+
 PRESETS = {
+    "kimi_linear_ep4": {
+        **KIMI_LINEAR_PUBLISHED,
+        # the cut: depth, the chip's share of the experts and vocabulary
+        "num_hidden_layers": 8,
+        "experts_held": 64,
+        "held_lo": 0,
+        "vocab_held": 40960,
+        # assumed: the rank of the two gates' low-rank projections
+        "kda_gate_rank": 128,
+        "weights_seed": 20251030,
+        "initializer_range": 0.02,
+        # assumed: the MLA layers' q and kv_a drawn this much wider, for a
+        # softmax as peaked as a trained one (at 0.02 alone it is flat
+        # over 2.4 k rows and nothing downstream sees what it weighs)
+        "mla_qk_init_scale": 2.5,
+    },
+    "kimi_linear_tiny": {
+        **KIMI_LINEAR_PUBLISHED,
+        # KDA, KDA, MLA, KDA; the first with the dense feed-forward
+        "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "kv_lora_rank": 32,
+        "num_attention_heads": 4, "num_key_value_heads": 4,
+        "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "linear_attn_config": {
+            "full_attn_layers": [3], "head_dim": 16,
+            "kda_layers": [1, 2, 4], "num_heads": 2,
+            "short_conv_kernel_size": 4},
+        "num_experts": 16, "num_experts_per_token": 4,
+        "num_hidden_layers": 4, "vocab_size": 512,
+        "experts_held": 4,
+        "held_lo": 0,
+        "vocab_held": 128,
+        "kda_gate_rank": 8,
+        "weights_seed": 13,
+        # as deepseek_v2_tiny: 0.02 at width 64 leaves every score flat
+        "initializer_range": 0.15,
+        "mla_qk_init_scale": 1.0,
+    },
     "jamba2_3b": {
         **JAMBA2_3B_PUBLISHED,
         "vocab_held": 65536,

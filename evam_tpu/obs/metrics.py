@@ -73,6 +73,10 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_generate_slots_active": ("gauge", ()),
     "evam_generate_pages_in_use": ("gauge", ()),
     "evam_moe_held_assignments": ("counter", ()),
+    # per step and expert layer, the held experts that received at least
+    # one assignment (the grouped products read only those experts'
+    # weights): what a decode step's bytes follow
+    "evam_moe_held_experts_hit": ("counter", ("kind",)),
     # QoS scheduling
     "evam_sched_admitted": ("counter", ("class",)),
     "evam_sched_rejected": ("counter", ("class",)),

@@ -162,29 +162,45 @@ def test_group_limited_routing_on_crafted_scores(case):
         rtol=1e-5, atol=1e-6)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """All n_group shares' outputs, the shared experts counted once, are
-    the uncut reference layer (every routed expert + the shared)."""
-    cfg = lm.Config.from_dict(TINY)
+@pytest.mark.parametrize("model", ["deepseek_v2", "kimi_linear"])
+def test_the_shares_add_up_to_the_uncut_layer(model):
+    """All four shares' routed sums, the shared experts counted once, are
+    the uncut reference layer (every routed expert + the shared), for both
+    expert families through the one expert layer (models/lm/experts.py):
+    DeepSeek-V2's four routing groups, Kimi-Linear's four ranges of four."""
+    from benchmark.reference import kimi_linear_plain
+    from evam_tpu.models.lm import kimi_linear
+
     layer = 1
+    if model == "deepseek_v2":
+        tiny, plain = TINY, ref
+        cfg = lm.Config.from_dict(tiny)
+        shares = [(share := lm.Config.from_dict({**tiny, "held_group": g}),
+                   lm.make_layer(share, layer)) for g in range(cfg.n_group)]
+    else:
+        tiny, plain = PRESETS["kimi_linear_tiny"], kimi_linear_plain
+        cfg = kimi_linear.Config.from_dict(tiny)
+        shares = [(share := kimi_linear.Config.from_dict(
+            {**tiny, "held_lo": lo}), kimi_linear.make_layer(
+                share, layer, kimi_linear.moe_shapes(share),
+                range(lo, lo + 4))) for lo in range(0, 16, 4)]
     x = (np.random.default_rng(3).standard_normal((24, cfg.hidden))
          .astype(np.float32))
     xb = jnp.asarray(x, lm.BF16)
-    w = ref.layer_weights(TINY, layer)
+    w = plain.layer_weights(tiny, layer)
     x32 = jnp.asarray(xb, jnp.float32)
-    whole = np.asarray(ref.moe(TINY, layer, w, x32, range(16)))
-    shared = np.asarray(ref.moe(TINY, layer, w, x32, []))
+    whole = np.asarray(plain.moe(tiny, layer, w, x32, range(16)))
+    shared = np.asarray(plain.moe(tiny, layer, w, x32, []))
     total = np.zeros_like(whole)
     held = 0
     live = jnp.ones((24,), bool)
-    for g in range(cfg.n_group):
-        share = lm.Config.from_dict({**TINY, "held_group": g})
-        y, n = lm.moe(share, lm.make_layer(share, layer), xb, live)
+    for share, lp in shares:
+        y, n = lm.moe(share, lp, xb, live)
         total += np.asarray(y, np.float32)
-        held += int(n)
-    total -= (cfg.n_group - 1) * shared
+        held += int(n[0])
+    total -= (len(shares) - 1) * shared
     # every assignment went to exactly one share
-    assert held == 24 * cfg.top_k
+    assert len(shares) == 4 and held == 24 * cfg.top_k
     assert np.abs(total - whole).max() < 0.05 * np.abs(whole).max()
     assert np.median(np.abs(total - whole)) < 0.01 * np.abs(whole).max()
 
@@ -198,8 +214,8 @@ def test_no_assignment_is_dropped_when_all_route_here():
         (16, cfg.hidden)), lm.BF16)
     ids = jnp.tile(jnp.asarray([[0, 1, 2]]), (16, 1))
     w = jnp.ones((16, 3), jnp.float32)
-    y, n = lm.held_experts(cfg, lp, x, w, ids, jnp.ones((16,), bool))
-    assert int(n) == 48
+    y, n, hit = lm.held_experts(cfg, lp, x, w, ids, jnp.ones((16,), bool))
+    assert int(n) == 48 and int(hit) == 3
     want = sum(np.asarray(lm.swiglu(x, lp["expert_gate"][e],
                                     lp["expert_up"][e],
                                     lp["expert_down"][e]), np.float32)

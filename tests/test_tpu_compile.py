@@ -60,3 +60,22 @@ def test_selective_scan_kernel_compiles_at_published_widths(
         s((segments, n, ch), jnp.float32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_selective_scan" in text
+
+
+@pytest.mark.parametrize("tokens,segments", [
+    (512, 8),    # a prefill chunk of the deployment
+    (64, 4),     # a short chunk
+])
+def test_delta_rule_kernel_compiles_at_published_widths(
+        one_chip, tokens, segments):
+    from evam_tpu.ops.pallas_kda import delta_rule
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    heads, d = 32, 128
+    compiled = jax.jit(delta_rule).lower(
+        *[s((tokens, heads * d))] * 5, s((tokens,), jnp.int32),
+        s((segments, heads, d, d))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_delta_rule" in text
