@@ -36,9 +36,10 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   merges the two parts of each row's softmax (models/lm/common.py
   ``merge_softmax_sums``).
 * A family with recurrent layers (Jamba's Mamba layers, Kimi-Linear's
-  delta-rule layers) also keeps state
-  per SLOT, never paged: arrays ``[layers, slots + 2, ...]`` that every
-  decode step reads and writes at its rows' slots. Row ``slots`` is the
+  delta-rule layers) also keeps state per SLOT, never paged: arrays
+  ``[layers, slots + 2, ...]`` that every decode step reads and writes at
+  its rows' slots, in place (ops/slot_rows.py; two live rows of a step
+  never name one slot). Row ``slots`` is the
   null row (rows of a step that carry no sequence), row ``slots + 1`` the
   PREFIX SNAPSHOT: the state after the shared prefix's last token, left
   there by warm-up's prefill of the prefix. A prefill chunk tells the

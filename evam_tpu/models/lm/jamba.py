@@ -17,7 +17,8 @@ is the embedding (tied).
   inputs of the convolution: per SLOT of the generate engine, not per
   page (``state_shapes``). A prefill chunk runs the recurrence in a Pallas
   kernel with the state in VMEM (ops/pallas_selective_scan.py); a decode
-  step's one-token update is plain XLA.
+  step's one-token update is a second kernel over the step's rows that
+  moves each row's state in place (``mamba_decode``).
 * **Attention mixer.** ``num_attention_heads`` query heads over ONE
   key-value head, no bias, no positional term, scale head_dim^-1/2. The
   cache row of a token is ``[k ; v]`` in pages (engine/pages.py), in the
@@ -59,7 +60,7 @@ import jax.numpy as jnp
 from evam_tpu.models.lm import common
 from evam_tpu.models.lm.common import BF16, F32, GLOBAL_LAYER, rms_norm
 from evam_tpu.models.lm.common import mm as _mm
-from evam_tpu.ops import pallas_mla, pallas_selective_scan
+from evam_tpu.ops import pallas_mla, pallas_selective_scan, slot_rows
 
 DT_MIN, DT_MAX = 0.001, 0.1
 #: the packer may start a segment at any token of a chunk
@@ -230,7 +231,10 @@ def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
     ``slots`` for rows of a step that carry no sequence, row ``slots +
     1`` the snapshot after the shared prefix's last token) and Mamba
     layer: ``ssm``, the float32 state, and ``conv``, the convolution's
-    last ``d_conv - 1`` inputs, taps side by side on the lanes."""
+    last ``d_conv - 1`` inputs (taps side by side), each slot's row as
+    whole bfloat16 tiles (``slot_rows.tiled``). A decode step's kernel
+    addresses both by ``[layer, slot]`` and moves the rows it names in
+    place (``mamba_decode``)."""
     rows = slots + 2
     return {
         "pages": jax.ShapeDtypeStruct(
@@ -238,7 +242,8 @@ def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
         "ssm": jax.ShapeDtypeStruct(
             (cfg.mamba_layers, rows, cfg.d_state, cfg.d_inner), F32),
         "conv": jax.ShapeDtypeStruct(
-            (cfg.mamba_layers, rows, (cfg.d_conv - 1) * cfg.d_inner), BF16),
+            (cfg.mamba_layers, rows,
+             *slot_rows.tiled((cfg.d_conv - 1) * cfg.d_inner)), BF16),
     }
 
 
@@ -292,24 +297,36 @@ def mamba_prefill(cfg: Config, lp: dict, x, seg, conv0, h0):
     return _mm(y.astype(BF16), lp["out_proj"]), conv_end, h_end
 
 
-def mamba_decode(cfg: Config, lp: dict, x, conv_old, h):
-    """One token per row through one Mamba mixer: ``conv_old`` [B,
-    (d_conv-1) * d_inner], ``h`` [B, d_state, d_inner] float32 -> the
-    output [B, hidden] and both, moved on by the token."""
+def mamba_decode(cfg: Config, lp: dict, l, x, slot, live, conv_all, ssm):
+    """One token per row through Mamba mixer ``l``, each live row's slot
+    state moved IN PLACE: ``slot`` [B] names row ``b``'s row of
+    ``conv_all`` [layers, R, *tile] (``state_shapes``) and ``ssm`` [layers,
+    R, d_state, d_inner] float32, the WHOLE arrays. Returns the output
+    [B, hidden] and both arrays, the rows that ``live`` rows name moved
+    on by their token, every other row as it was. On the chip the
+    recurrence is the Pallas kernel ``ssm_decode_rows`` over the step's
+    rows, the layer and the slot ids its prefetched scalars and the
+    state aliased in and out (ops/pallas_selective_scan.py
+    ``decode_rows``, ops/slot_rows.py); elsewhere its twin gathers the
+    rows and puts them back. A row that carries no sequence names the
+    null row, writes back what it read and comes out zero. The
+    convolution's rows (30 KB each) are gathered through XLA before the
+    mixer's inputs can be made, and written by the same kernel."""
     c = cfg.d_inner
     uz = _mm(rms_norm(x, lp["in_norm"], cfg.eps), lp["in_proj"])
     u_pre, z = uz[:, :c], uz[:, c:]
+    conv_old = conv_all[l, slot].reshape(x.shape[0], -1)
     taps = [conv_old[:, k * c:(k + 1) * c] for k in range(cfg.d_conv - 1)]
+    conv_new = jnp.concatenate([conv_old[:, c:], u_pre], axis=1)
     u = _conv(cfg, lp, taps + [u_pre])
     dt, b, cc = _dt_b_c(cfg, lp, u)
     a = -jnp.exp(lp["A_log"].astype(F32))
-    u32 = u.astype(F32)
-    h = (jnp.exp(dt[:, None, :] * a[None]) * h
-         + (dt * u32)[:, None, :] * b.astype(F32)[:, :, None])
-    y = (h * cc.astype(F32)[:, :, None]).sum(axis=1) + lp["D"].astype(F32) * u32
-    y = y * jax.nn.silu(z.astype(F32))
-    conv_new = jnp.concatenate([conv_old[:, c:], u_pre], axis=1)
-    return _mm(y.astype(BF16), lp["out_proj"]), conv_new, h
+    rows = (pallas_selective_scan.decode_rows if common.on_tpu()
+            else pallas_selective_scan.decode_rows_xla)
+    y, ssm, conv_all = rows(
+        l, slot, live, dt, u, z, b, cc, a, lp["D"],
+        conv_new.reshape(-1, *conv_all.shape[2:]), ssm, conv_all)
+    return _mm(y.astype(BF16), lp["out_proj"]), conv_all, ssm
 
 
 def _qkv(cfg: Config, lp: dict, x):
@@ -409,9 +426,11 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     def mamba_layer(lp, l, x, ssm, conv):
         with jax.named_scope("mamba"):
             y, conv_end, h_end = mamba_prefill(
-                cfg, lp, x, seg, conv[l, seg_from], ssm[l, seg_from])
+                cfg, lp, x, seg, conv[l, seg_from].reshape(len(seg_from), -1),
+                ssm[l, seg_from])
             ssm = ssm.at[l, seg_to].set(h_end)
-            conv = conv.at[l, seg_to].set(conv_end)
+            conv = conv.at[l, seg_to].set(
+                conv_end.reshape(-1, *conv.shape[2:]))
             x = x + y
         return _feed_forward(cfg, lp, x), ssm, conv
 
@@ -434,8 +453,9 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
 def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
                   ctx_len, dest_page, dest_off, live, prefix_pages, n_prefix,
                   slot):
-    """One token per row. Mamba layers read and write row ``slot[b]`` of
-    the slot state (a row that carries no sequence names the null row);
+    """One token per row. Mamba layers move row ``slot[b]`` of the slot
+    state on IN PLACE for every ``live`` row (``mamba_decode``; a row that
+    carries no sequence names the null row and leaves it as it was);
     attention layers write the row's ``[k ; v]`` to its page and attend
     to the shared prefix (read once for all rows) and, through the
     table of its OWN pages, to its ``ctx_len`` own cached rows."""
@@ -443,10 +463,7 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
 
     def mamba_layer(lp, l, x, ssm, conv):
         with jax.named_scope("mamba"):
-            y, conv_new, h = mamba_decode(cfg, lp, x, conv[l, slot],
-                                          ssm[l, slot])
-            ssm = ssm.at[l, slot].set(h)
-            conv = conv.at[l, slot].set(conv_new)
+            y, conv, ssm = mamba_decode(cfg, lp, l, x, slot, live, conv, ssm)
             x = x + y
         return _feed_forward(cfg, lp, x), ssm, conv
 

@@ -22,7 +22,8 @@ untied head.
   runs the recurrence in a Pallas kernel with the state in VMEM
   (ops/pallas_kda.py), in blocks of ``SEGMENT_ALIGN`` tokens to which the
   engine's packer aligns every segment's start; a decode step's one-token
-  update is plain XLA.
+  update is a second kernel over the step's rows that moves each row's
+  state in place (``kda_decode``).
 * **MLA mixer**: models/lm/mla.py, as DeepSeek-V2's but with no rotation
   of ``q_r`` / ``k_r`` (``mla_use_nope``) and no query down-projection
   (``q_lora_rank`` null); the cache row is the same 576 values.
@@ -64,7 +65,7 @@ import jax.numpy as jnp
 from evam_tpu.models.lm import common, experts, mla
 from evam_tpu.models.lm.common import BF16, F32, GLOBAL_LAYER, rms_norm
 from evam_tpu.models.lm.common import mm as _mm
-from evam_tpu.ops import pallas_kda
+from evam_tpu.ops import pallas_kda, slot_rows
 
 DT_MIN, DT_MAX = 0.001, 0.1
 A_MAX = 16.0
@@ -293,8 +294,10 @@ def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
     of a step that carry no sequence, row ``slots + 1`` the snapshot after
     the shared prefix's last token) and KDA layer: ``kda``, the float32
     matrix state of every head, and ``conv``, the last ``d_conv - 1``
-    inputs of the three convolutions (``q | k | v``), taps side by side on
-    the lanes."""
+    inputs of the three convolutions (``q | k | v``, taps side by side),
+    each slot's row as whole bfloat16 tiles (``slot_rows.tiled``). A
+    decode step's kernel addresses both by ``[layer, slot]`` and moves
+    the rows it names in place (``kda_decode``)."""
     rows = slots + 2
     n = len(cfg.kda_ids)
     return {
@@ -303,7 +306,8 @@ def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
         "kda": jax.ShapeDtypeStruct(
             (n, rows, cfg.kda_heads, cfg.kda_dim, cfg.kda_dim), F32),
         "conv": jax.ShapeDtypeStruct(
-            (n, rows, (cfg.d_conv - 1) * 3 * cfg.kda_width), BF16),
+            (n, rows, *slot_rows.tiled((cfg.d_conv - 1) * 3 * cfg.kda_width)),
+            BF16),
     }
 
 
@@ -371,47 +375,40 @@ def kda_prefill(cfg: Config, lp: dict, x, seg, conv0, s0):
     return _kda_out(cfg, lp, h, o), conv_end, s_end
 
 
-def kda_decode(cfg: Config, lp: dict, x, slot, live, conv_all, s_all):
-    """One token per row through one KDA mixer, the slot state updated
-    WHOLE: ``slot`` [B] names each ``live`` row's row of ``conv_all`` [R,
-    (d_conv-1) * 3 * width] and ``s_all`` [R, heads, dim, dim] float32
-    (every row of the layer's slot state). Returns the output [B, hidden]
-    and both arrays, the rows named moved on by their token and every
-    other row as it was, bit for bit (no write, no decay). Rows move
-    between the step's B rows and the state's R by one-hot products,
-    which are exact, so nothing is gathered or scattered a row at a time
-    (through XLA those are loops of B trips a layer: 18 of a 64-row
-    step's 29.5 ms); every product with the state is elementwise, in
-    float32, in two passes over it. A row of the step that carries no
-    sequence is placed NOWHERE: it reads zeros, writes nothing and comes
-    out zero. (Placed in the null row, such rows' sums grew step by step
-    until they overflowed, and a one-hot product of 0 with inf is NaN in
-    EVERY row: the first build of this on the chip.)"""
+def kda_decode(cfg: Config, lp: dict, x, slot, live, conv_all, s_all, l=None):
+    """One token per row through one KDA mixer, each live row's slot
+    state moved IN PLACE: ``slot`` [B] names row ``b``'s row of the slot
+    state, of which ``conv_all`` [layers, R, *tile] (the taps' (d_conv-1)
+    * 3 * width values, ``state_shapes``) and ``s_all`` [layers, R, heads,
+    dim, dim] float32 are the WHOLE arrays
+    and ``l`` this mixer's layer (``l`` None: one layer's arrays, a
+    leading axis less). Returns the output [B, hidden] and both arrays,
+    the rows that ``live`` rows name moved on by their token and every
+    other row as it was, bit for bit. On the chip the recurrence is the
+    Pallas kernel ``kda_decode_rows`` over the step's rows, the layer and
+    the slot ids its prefetched scalars and the state aliased in and out
+    (ops/pallas_kda.py ``decode_rows``, ops/slot_rows.py): nothing is
+    sliced out a layer, gathered or scattered; elsewhere its twin
+    gathers the rows and puts them back. A row that carries no sequence
+    names the null row, writes back what it read and comes out zero. The
+    convolutions' rows (72 KB each) are gathered through XLA before the
+    mixer's inputs can be made, and written by the same kernel."""
+    one = l is None
+    if one:
+        l, conv_all, s_all = jnp.int32(0), conv_all[None], s_all[None]
     c = 3 * cfg.kda_width
-    place = ((slot[None, :] == jnp.arange(s_all.shape[0])[:, None])
-             & live[None, :])  # [R, B]
-    at_rows, at_rows32 = place.astype(BF16), place.astype(F32)
     h = rms_norm(x, lp["input_norm"], cfg.eps)
     pre = _qkv_pre(lp, h)
-    conv_old = common.es("rb,rc->bc", at_rows, conv_all).astype(BF16)
+    conv_old = conv_all[l, slot].reshape(x.shape[0], -1)
     taps = [conv_old[:, i * c:(i + 1) * c] for i in range(cfg.d_conv - 1)]
-    q, k, kb, vb, g = (
-        jnp.einsum("rb,bhd->rhd", at_rows32, a,
-                   precision=jax.lax.Precision.HIGHEST)
-        for a in _kda_inputs(cfg, lp, h, taps + [pre], live))
-    decayed = s_all * jnp.exp(g)[..., None]
-    u = vb - (kb[..., None] * decayed).sum(axis=2)
-    # S^T q of the state after the write, from the state before it
-    o = ((q[..., None] * decayed).sum(axis=2)
-         + u * (k * q).sum(axis=-1, keepdims=True))
-    s_all = decayed + k[..., None] * u[:, :, None, :]
-    o = jnp.einsum("rb,rhd->bhd", at_rows32, o,
-                   precision=jax.lax.Precision.HIGHEST)
-    conv_new = common.es(
-        "rb,bc->rc", at_rows,
-        jnp.concatenate([conv_old[:, c:], pre], axis=1)).astype(BF16)
-    conv_all = jnp.where(place.any(axis=1)[:, None], conv_new, conv_all)
-    return _kda_out(cfg, lp, h, o), conv_all, s_all
+    conv_new = jnp.concatenate([conv_old[:, c:], pre], axis=1)
+    rows = (pallas_kda.decode_rows if common.on_tpu()
+            else pallas_kda.decode_rows_xla)
+    o, s_all, conv_all = rows(
+        l, slot, live, *_kda_inputs(cfg, lp, h, taps + [pre], live),
+        conv_new.reshape(-1, *conv_all.shape[2:]), s_all, conv_all)
+    y = _kda_out(cfg, lp, h, o)
+    return (y, conv_all[0], s_all[0]) if one else (y, conv_all, s_all)
 
 
 def head(cfg: Config, params: dict, x):
@@ -490,9 +487,11 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     def kda_layer(lp, l, x, kda, conv):
         with jax.named_scope("kda"):
             y, conv_end, s_end = kda_prefill(
-                cfg, lp, x, seg, conv[l, seg_from], kda[l, seg_from])
+                cfg, lp, x, seg, conv[l, seg_from].reshape(len(seg_from), -1),
+                kda[l, seg_from])
             kda = kda.at[l, seg_to].set(s_end)
-            conv = conv.at[l, seg_to].set(conv_end)
+            conv = conv.at[l, seg_to].set(
+                conv_end.reshape(-1, *conv.shape[2:]))
         return x + y, kda, conv
 
     def mla_layer(lp, j, x, pages):
@@ -518,8 +517,9 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
                   ctx_len, dest_page, dest_off, live, prefix_pages, n_prefix,
                   slot):
     """One token per row. KDA layers move row ``slot[b]`` of the slot
-    state on for every ``live`` row and leave every other row as it was
-    (the null row that the others name among them); MLA
+    state on IN PLACE for every ``live`` row (``kda_decode``: the whole
+    arrays go in and come out, the layer an index) and leave every other
+    row as it was (the null row that the others name among them); MLA
     layers write the row's latent to its page and attend to the shared
     prefix (read once for all rows) and, through the table of its OWN
     pages, to its ``ctx_len`` own cached rows."""
@@ -527,10 +527,7 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
 
     def kda_layer(lp, l, x, kda, conv):
         with jax.named_scope("kda"):
-            y, conv_l, s_l = kda_decode(cfg, lp, x, slot, live, conv[l],
-                                        kda[l])
-            kda = kda.at[l].set(s_l)
-            conv = conv.at[l].set(conv_l)
+            y, conv, kda = kda_decode(cfg, lp, x, slot, live, conv, kda, l)
         return x + y, kda, conv
 
     def mla_layer(lp, j, x, pages):

@@ -45,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from evam_tpu.ops import slot_rows
 from evam_tpu.ops.pallas_selective_scan import _flags
 
 F32 = jnp.float32
@@ -178,3 +179,81 @@ def delta_rule_xla(q, k, kb, vb, g, seg, h0):
     (_, h_end), o = jax.lax.scan(
         step, (jnp.zeros_like(h0[0]), h0), (q, k, kb, vb, g, *flags))
     return o.reshape(o.shape[0], heads * d), h_end
+
+
+# ------------------------------------------- a decode step's one token
+
+
+def _rows_kernel(l_ref, slot_ref, live_ref, cols_ref, vb_ref, taps_ref, s_ref,
+                 conv_ref, o_ref, sout_ref, convout_ref):
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    heads = vb_ref.shape[0]
+
+    @pl.when(live_ref[b] > 0)
+    def _():
+        # q | k | kb | g, heads side by side, the key channel down the
+        # sublanes: what multiplies a ROW of the state is a column here
+        t = cols_ref[...].T
+        q_t, k_t, kb_t = (t[:, i * heads:(i + 1) * heads] for i in range(3))
+        e_t = jnp.exp(t[:, 3 * heads:])
+        kq = jnp.sum(k_t * q_t, axis=0, keepdims=True)
+        for h in range(heads):
+            decayed = s_ref[h] * e_t[:, h:h + 1]
+            u = vb_ref[h:h + 1, :] - jnp.sum(kb_t[:, h:h + 1] * decayed,
+                                             axis=0, keepdims=True)
+            # S^T q of the state after the write, from the state before
+            o_ref[h:h + 1, :] = (
+                jnp.sum(q_t[:, h:h + 1] * decayed, axis=0, keepdims=True)
+                + u * kq[:, h:h + 1])
+            sout_ref[h] = decayed + k_t[:, h:h + 1] * u
+        convout_ref[...] = taps_ref[...]
+
+    @pl.when(live_ref[b] == 0)
+    def _():
+        slot_rows.keep((s_ref, sout_ref), (conv_ref, convout_ref))
+        o_ref[...] = jnp.zeros(o_ref.shape, F32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_rows(l, slot, live, q, k, kb, vb, g, taps, state, conv, *,
+                interpret=False):
+    """One token a row, the slot state moved IN PLACE (ops/slot_rows.py):
+    ``q``, ``k``, ``kb``, ``vb``, ``g`` [B, H, D] float32; ``state``
+    [layers, rows, H, D, D] float32 and ``conv`` [layers, rows, *tile]
+    (``slot_rows.tiled``), of which live row ``b`` reads and writes
+    ``state[l, slot[b]]`` and takes ``taps[b]`` [*tile] for ``conv[l,
+    slot[b]]`` -> (``o`` [B, H, D] float32, zero where not live,
+    ``state``, ``conv``). Per row and head, in float32 on the vector unit:
+
+        S' = Diag(exp(g)) S;  u = vb - S'^T kb;  o = S'^T q + u (k . q)
+        S  = S' + k u^T
+    """
+    rows, heads, d = q.shape
+    tile = conv.shape[2:]
+    cols = jnp.concatenate([x.astype(F32) for x in (q, k, kb, g)], axis=1)
+    return slot_rows.call(
+        _rows_kernel, "kda_decode_rows", l, slot, live,
+        [cols, vb.astype(F32), taps.astype(conv.dtype)],
+        [slot_rows.per_row(4 * heads, d), slot_rows.per_row(heads, d),
+         slot_rows.per_row(*tile)],
+        [jax.ShapeDtypeStruct((rows, heads, d), F32)],
+        [slot_rows.per_row(heads, d)],
+        [state, conv],
+        [slot_rows.at_slot(heads, d, d), slot_rows.at_slot(*tile)],
+        interpret=interpret)
+
+
+def decode_rows_xla(l, slot, live, q, k, kb, vb, g, taps, state, conv):
+    """The same through XLA: the named rows gathered, the recurrence as
+    written, the rows put back with those that are not live dropped."""
+    decayed = state[l, slot] * jnp.exp(g)[..., None]
+    u = vb - (kb[..., None] * decayed).sum(axis=2)
+    # S^T q of the state after the write, from the state before it
+    o = ((q[..., None] * decayed).sum(axis=2)
+         + u * (k * q).sum(axis=-1, keepdims=True))
+    s = decayed + k[..., None] * u[:, :, None, :]
+    return (jnp.where(live[:, None, None], o, 0.0),
+            slot_rows.put(state, l, slot, live, s, check=True),
+            slot_rows.put(conv, l, slot, live, taps))

@@ -34,6 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from evam_tpu.ops import slot_rows
+
 F32 = jnp.float32
 LANES = 128
 
@@ -49,6 +51,12 @@ def _flags(seg):
             (live & (nxt != seg)).astype(jnp.int32))
 
 
+def _lanes(x, width: int):
+    """[n, 128] -> [n, width]: a token's ``B`` or ``C`` over a block of
+    channels."""
+    return jnp.concatenate([x] * (width // x.shape[1]), axis=1)
+
+
 def _kernel(seg_ref, start_ref, end_ref, u_ref, dt_ref, z_ref, b_ref, c_ref,
             a_ref, d_ref, h0_ref, y_ref, hend_ref, *, unroll):
     from jax.experimental import pallas as pl
@@ -59,17 +67,14 @@ def _kernel(seg_ref, start_ref, end_ref, u_ref, dt_ref, z_ref, b_ref, c_ref,
     d = d_ref[...]
     hend_ref[...] = h0_ref[...]
 
-    def lanes(x):  # [n, 128] -> [n, bc]
-        return jnp.concatenate([x] * (bc // LANES), axis=1)
-
     def token(t, h):
         s = seg_ref[t]
         h = jnp.where(start_ref[t] > 0, h0_ref[s], h)
         row = pl.ds(t, 1)
         u = u_ref[row, :]
         dt = dt_ref[row, :]
-        h = jnp.exp(dt * a) * h + (dt * u) * lanes(b_ref[t])
-        y = jnp.sum(h * lanes(c_ref[t]), axis=0, keepdims=True) + d * u
+        h = jnp.exp(dt * a) * h + (dt * u) * _lanes(b_ref[t], bc)
+        y = jnp.sum(h * _lanes(c_ref[t], bc), axis=0, keepdims=True) + d * u
         z = z_ref[row, :]
         y_ref[row, :] = y * (z * jax.nn.sigmoid(z))
 
@@ -161,3 +166,79 @@ def selective_scan_xla(u, dt, z, b, c, a, d, seg, h0):
     (_, h_end), y = jax.lax.scan(
         step, (jnp.zeros_like(h0[0]), h0), (u, dt, b, c, seg_i, start, end))
     return y * (z * jax.nn.sigmoid(z)), h_end
+
+
+# ------------------------------------------- a decode step's one token
+
+
+def _rows_kernel(l_ref, slot_ref, live_ref, dt_ref, u_ref, z_ref, b_ref,
+                 c_ref, a_ref, d_ref, taps_ref, h_ref, conv_ref, y_ref,
+                 hout_ref, convout_ref, *, block_c):
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    row = pl.ds(i % dt_ref.shape[0], 1)
+    ch = a_ref.shape[1]
+
+    @pl.when(live_ref[i] > 0)
+    def _():
+        b, c = _lanes(b_ref[...], block_c), _lanes(c_ref[...], block_c)
+        for c0 in range(0, ch, block_c):
+            cols = slice(c0, c0 + block_c)
+            dt, u, z = (r[row, cols] for r in (dt_ref, u_ref, z_ref))
+            h = jnp.exp(dt * a_ref[:, cols]) * h_ref[:, cols] + (dt * u) * b
+            hout_ref[:, cols] = h
+            y = jnp.sum(h * c, axis=0, keepdims=True) + d_ref[:, cols] * u
+            y_ref[row, cols] = y * (z * jax.nn.sigmoid(z))
+        convout_ref[...] = taps_ref[...]
+
+    @pl.when(live_ref[i] == 0)
+    def _():
+        slot_rows.keep((h_ref, hout_ref), (conv_ref, convout_ref))
+        y_ref[row, :] = jnp.zeros((1, ch), F32)
+
+
+@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
+def decode_rows(l, slot, live, dt, u, z, b, c, a, d, taps, state, conv, *,
+                block_c=512, interpret=False):
+    """One token a row, the slot state moved IN PLACE (ops/slot_rows.py):
+    ``dt``, ``u``, ``z`` [B, C]; ``b``, ``c`` [B, N]; ``a`` [N, C]; ``d``
+    [C]; ``state`` [layers, rows, N, C] float32 and ``conv`` [layers,
+    rows, *tile] (``slot_rows.tiled``), of which live row ``i`` reads and
+    writes ``state[l, slot[i]]`` and takes ``taps[i]`` [*tile] for
+    ``conv[l, slot[i]]`` -> (``y`` [B, C] float32, zero where not live,
+    ``state``, ``conv``): the recurrence at the head of this file for one
+    token, in float32."""
+    rows, ch = dt.shape
+    n = a.shape[0]
+    tile = conv.shape[2:]
+    if ch % block_c or block_c % LANES:
+        block_c = ch
+    wide = [jnp.broadcast_to(x.astype(F32)[:, :, None],
+                             (rows, n, min(LANES, block_c))) for x in (b, c)]
+    row = slot_rows.grouped(rows, ch)
+    bc = slot_rows.per_row(*wide[0].shape[1:])
+    return slot_rows.call(
+        functools.partial(_rows_kernel, block_c=block_c), "ssm_decode_rows",
+        l, slot, live,
+        [dt.astype(F32), u.astype(F32), z.astype(F32), *wide, a.astype(F32),
+         d.astype(F32).reshape(1, ch), taps.astype(conv.dtype)],
+        [row, row, row, bc, bc, slot_rows.shared(n, ch),
+         slot_rows.shared(1, ch), slot_rows.per_row(*tile)],
+        [jax.ShapeDtypeStruct((rows, ch), F32)], [row],
+        [state, conv], [slot_rows.at_slot(n, ch), slot_rows.at_slot(*tile)],
+        interpret=interpret)
+
+
+def decode_rows_xla(l, slot, live, dt, u, z, b, c, a, d, taps, state, conv):
+    """The same through XLA: the named rows gathered, the recurrence as
+    written, the rows put back with those that are not live dropped."""
+    dt, u, z, b, c, a, d = (jnp.asarray(x, F32) for x in (dt, u, z, b, c, a,
+                                                           d))
+    h = (jnp.exp(dt[:, None, :] * a[None]) * state[l, slot]
+         + (dt * u)[:, None, :] * b[:, :, None])
+    y = (h * c[:, :, None]).sum(axis=1) + d * u
+    y = y * (z * jax.nn.sigmoid(z))
+    return (jnp.where(live[:, None], y, 0.0),
+            slot_rows.put(state, l, slot, live, h, check=True),
+            slot_rows.put(conv, l, slot, live, taps))

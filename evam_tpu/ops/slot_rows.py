@@ -1,0 +1,143 @@
+"""How a decode step reaches the generate engine's per-slot state: one
+addressing scheme for the Pallas kernels that move a step's rows IN
+PLACE, and the plain row write of their twins.
+
+A family keeps ``state`` [layers, rows, ...] per slot (engine/generate.py
+``state_shapes``). A decode step of B rows names one state row a step row
+by integer id (``slot`` [B]; ``live`` [B] false: the row carries no
+sequence and names the null row, as several may in one step). Through XLA
+a gather or scatter of such rows is a loop of one trip a row, and an
+update of the whole array costs every row whatever the step names. Here
+the layer ``l``, ``slot`` and ``live`` are PREFETCHED SCALARS of a kernel
+whose grid is the step's rows, one grid step a row: the state's block at
+grid step ``b`` is ``state[l, slot[b]]``, the array is aliased in and out,
+and so a row is read from its slot, moved on and written back where it
+was. No other row is touched.
+
+What the kernels rely on, and hold:
+
+* two live rows never name one slot (the engine's invariant): a row's
+  block is prefetched while the row before it computes, so a second write
+  to one slot in a step could be lost. The twins check it (``put``);
+* a row that is not live writes back what it read (``keep``), bit for
+  bit: the null row comes back as it was however many rows name it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+#: a bfloat16 tile's rows: a slot's row of a bfloat16 state [layers, rows,
+#: width] is kept as ``[TILE_ROWS, width / TILE_ROWS]`` (``tiled``), whole
+#: tiles, because a block of a kernel cannot be one row of a tile
+TILE_ROWS = 16
+#: step rows in one block of a [B, width] operand (a float32 tile's
+#: sublanes): grid step ``b`` finds its row at ``b % <the block's rows>``
+GROUP = 8
+
+
+def _zeros(n: int) -> tuple:
+    return (0,) * n
+
+
+def at_slot(*tail: int):
+    """The block ``state[l, slot[b]]`` of a state array [layers, rows,
+    *tail]."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (None, None) + tail,
+        lambda b, l, slot, live: (l[0], slot[b]) + _zeros(len(tail)))
+
+
+def per_row(*tail: int):
+    """Step row ``b``'s block of an operand [B, *tail] (``tail`` two
+    dimensions or more: whole tiles a row)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((None,) + tail,
+                        lambda b, *_: (b,) + _zeros(len(tail)))
+
+
+def grouped(rows: int, width: int):
+    """An operand [B, width] in blocks of ``GROUP`` rows (all of them
+    where B is no multiple of it): a block stays in VMEM while the grid
+    walks its rows."""
+    from jax.experimental import pallas as pl
+
+    g = GROUP if rows % GROUP == 0 else rows
+    return pl.BlockSpec((g, width), lambda b, *_: (b // g, 0))
+
+
+def shared(*shape: int):
+    """An operand every row reads whole (fetched once)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(shape, lambda b, *_: _zeros(len(shape)))
+
+
+def tiled(width: int) -> tuple[int, int]:
+    """The two dimensions a slot's bfloat16 row of ``width`` values is
+    kept in."""
+    if width % TILE_ROWS:
+        raise ValueError(f"a row of {width} values is not {TILE_ROWS} "
+                         "equal parts")
+    return TILE_ROWS, width // TILE_ROWS
+
+
+def keep(*pairs) -> None:
+    """A row that is not live: each state block goes back as it came."""
+    for came, goes in pairs:
+        goes[...] = came[...]
+
+
+def call(kernel, name: str, l, slot, live, operands, specs, outs, out_specs,
+         states, state_specs, *, interpret: bool = False):
+    """``kernel(l_ref, slot_ref, live_ref, *operands, *states, *outs,
+    *states_out)`` over a grid of the step's rows. ``states`` are aliased
+    to the last outputs. Returns ``(*outs, *states)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    first = 3 + len(operands)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slot.shape[0],),
+            in_specs=[*specs, *state_specs],
+            out_specs=[*out_specs, *state_specs]),
+        out_shape=[*outs, *(jax.ShapeDtypeStruct(s.shape, s.dtype)
+                            for s in states)],
+        input_output_aliases={first + i: len(outs) + i
+                              for i in range(len(states))},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name=name,
+        interpret=interpret,
+    )(jnp.reshape(l, (1,)).astype(jnp.int32), slot.astype(jnp.int32),
+      live.astype(jnp.int32), *operands, *states)
+
+
+# ------------------------------------------------- the twins' row write
+
+
+def _distinct(slot, live) -> None:
+    named = np.asarray(slot)[np.asarray(live)]
+    if len(set(named.tolist())) != len(named):
+        raise AssertionError(f"two live rows of a decode step name one "
+                             f"slot: {sorted(named.tolist())}")
+
+
+def put(state, l, slot, live, rows, *, check: bool = False):
+    """``state`` with ``rows`` [B, ...] written to ``state[l, slot]``;
+    rows that are not live are dropped, so the null row and every row no
+    live row names come back bit for bit. ``check`` (the kernels' twins):
+    a debug callback refuses two live rows that name one slot."""
+    if __debug__ and check:
+        jax.debug.callback(_distinct, slot, live)
+    at = jnp.where(live, slot, state.shape[1])
+    return state.at[l, at].set(rows.astype(state.dtype), mode="drop")

@@ -154,10 +154,12 @@ def test_parameter_count_matches_the_benchmarks_arithmetic():
     assert 3.02e9 < lm.param_count(cfg) < 3.04e9
     state = lm.state_shapes(cfg, 401, 128, 128)
     assert state["ssm"].shape == (26, 130, 16, 5120)
-    assert state["conv"].shape == (26, 130, 3 * 5120)
+    # a slot's 3 * 5120 taps as 16 rows (a bfloat16 tile's), so that a
+    # kernel's block can be one slot: 960 lanes, 7.5 tiles
+    assert state["conv"].shape == (26, 130, 16, 3 * 5120 // 16)
     assert state["pages"].shape == (2, 401, 128, 256)
     # the lanes hold d_inner: no minor dimension that is not whole tiles
-    assert all(s.shape[-1] % 128 == 0 for s in state.values())
+    assert all(state[k].shape[-1] % 128 == 0 for k in ("ssm", "pages"))
 
 
 def test_chunk_bounds_are_the_three_intervals():
@@ -536,6 +538,14 @@ def test_trace_op_share_reader():
     got = trace_op_share.read(_trace_ctx(ops), dict(
         params, roofline=True, layers=26))
     assert got == pytest.approx(100.0 * least / 0.4) and got < 100
+    # the decode step's kernel beside them is another name: the same
+    both = ops + [["ssm_decode_rows.9 f32[64,5120]", 0.3]]
+    assert trace_op_share.read(_trace_ctx(both), params) == pytest.approx(20.0)
+    one = [ops[0], ["ssm_selective_scan.15 f32[512,5120]", 0.4], both[-1]]
+    assert trace_op_share.read(_trace_ctx(one), files[0]["params"]) == (
+        pytest.approx(20.0))
+    assert trace_op_share.read(_trace_ctx(one), files[1]["params"]) == (
+        pytest.approx(got))
     # a name that fell off the list, or a program without the kernel
     assert trace_op_share.read(_trace_ctx(ops[:3]), params) is None
     assert trace_op_share.read(_trace_ctx(ops[:1]), params) is None

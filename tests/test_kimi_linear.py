@@ -199,7 +199,8 @@ def test_parameter_count_matches_the_benchmarks_arithmetic():
     assert state["pages"].shape == (2, 401, 128, 576)
     assert state["kda"].shape == (6, 130, 32, 128, 128)
     assert state["kda"].dtype == jnp.float32
-    assert state["conv"].shape == (6, 130, 3 * 12288)
+    # a slot's 3 * 12288 taps as 16 rows: whole bfloat16 tiles a slot
+    assert state["conv"].shape == (6, 130, 16, 3 * 12288 // 16)
     assert state["kda"].shape[-1] % 128 == 0
     assert state["conv"].shape[-1] % 128 == 0
     per_slot = 6 * (4 * 32 * 128 * 128 + 2 * 3 * 12288)
@@ -821,6 +822,10 @@ def test_kda_metrics_read_the_one_kernel_name():
                     4096 * (4096 * 12 + 128) / 819e9) * (10 / 20)
     got = trace_op_share.read(ctx(ops), files[1])
     assert got == pytest.approx(100.0 * least / 0.2) and 0 < got < 100
+    # the decode step's kernel beside it is another name: still ONE
+    both = ops + [["kda_decode_rows.7 f32[64,32,128]", 0.4]]
+    assert trace_op_share.read(ctx(both), files[0]) == pytest.approx(10.0)
+    assert trace_op_share.read(ctx(both), files[1]) == pytest.approx(got)
     # a program without the kernel (the parent's): nothing to read
     assert trace_op_share.read(ctx(ops[:1]), files[0]) is None
 

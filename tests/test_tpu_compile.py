@@ -79,3 +79,43 @@ def test_delta_rule_kernel_compiles_at_published_widths(
         s((segments, heads, d, d))).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "kda_delta_rule" in text
+
+
+@pytest.mark.parametrize("rows", [64, 128])   # the cell's step, the widest
+def test_kda_decode_kernel_compiles_at_published_widths(one_chip, rows):
+    """The whole slot state goes in and comes out in place: the program
+    keeps no second copy of its 1.64 GB."""
+    from evam_tpu.ops.pallas_kda import decode_rows
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    heads, d = 32, 128
+    compiled = jax.jit(decode_rows, donate_argnums=(9, 10)).lower(
+        s((), jnp.int32), s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        *[s((rows, heads, d))] * 5, s((rows, 16, 2304), jnp.bfloat16),
+        s((6, 130, heads, d, d)),
+        s((6, 130, 16, 2304), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_decode_rows" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows", [64, 128])
+def test_ssm_decode_kernel_compiles_at_published_widths(one_chip, rows):
+    from evam_tpu.ops.pallas_selective_scan import decode_rows
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ch, n = 5120, 16
+    compiled = jax.jit(decode_rows, donate_argnums=(11, 12)).lower(
+        s((), jnp.int32), s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        s((rows, ch)), s((rows, ch), jnp.bfloat16),
+        s((rows, ch), jnp.bfloat16), s((rows, n), jnp.bfloat16),
+        s((rows, n), jnp.bfloat16), s((n, ch)), s((ch,), jnp.bfloat16),
+        s((rows, 16, 960), jnp.bfloat16), s((26, 130, n, ch)),
+        s((26, 130, 16, 960), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_decode_rows" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
