@@ -824,7 +824,7 @@ class GenerateEngine:
         tokens to their sequences, resolve those that are complete."""
         top = np.asarray(step.top)
         ids = np.asarray(step.ids)
-        held, hit = (int(v) for v in np.asarray(step.held))
+        held, hit, reads = (int(v) for v in np.asarray(step.held))
         now = time.perf_counter()
         self._step_started = (self._inflight[0].t_dispatch
                               if self._inflight else None)
@@ -846,6 +846,8 @@ class GenerateEngine:
         metrics.inc("evam_moe_held_assignments", float(held))
         # per expert layer, the held experts with at least one assignment
         metrics.inc("evam_moe_held_experts_hit", float(hit), labels)
+        # and the (row tile, expert) pairs one grouped product visited
+        metrics.inc("evam_moe_expert_reads", float(reads), labels)
         st = self.stats
         st.batches += 1
         st.add_stage("launch", dt)

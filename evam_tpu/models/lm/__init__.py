@@ -11,7 +11,8 @@ A FAMILY is a module with ``Config.from_dict``, ``make_params``,
 ``(pages, page_tokens, slots)``: cache rows in pages, and whatever it
 keeps per slot), ``prefill_chunk`` and ``decode_tokens`` (each returns the
 state, the top logits with their ids, and int32 ``[held assignments, held
-experts hit]``, zeros for a family without experts) and ``SEGMENT_ALIGN``
+experts hit, expert matrices read a product]``, zeros for a family
+without experts) and ``SEGMENT_ALIGN``
 (the multiple of a chunk's tokens at which the engine's packer starts
 every segment: 1, or the block of a chunkwise kernel); the installed
 config's ``model_type`` names it.

@@ -264,7 +264,7 @@ def _mlp(cfg: Config, lp: dict, x, live):
         return moe(cfg, lp, x, live)
     with jax.named_scope("dense_mlp"):
         return swiglu(x, lp["mlp_gate"], lp["mlp_up"],
-                      lp["mlp_down"]), jnp.zeros((2,), jnp.int32)
+                      lp["mlp_down"]), jnp.zeros((3,), jnp.int32)
 
 
 def _qkv(cfg: Config, lp: dict, x, pos):
@@ -300,16 +300,17 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     """A packed chunk of new tokens through every layer. Writes their
     latent rows to ``state["pages"][layer, dest_page, dest_off]`` and
     returns the state, the logits rows ``last_idx`` (each segment's last
-    token) as ``(top, ids)``, and ``[held assignments, held experts
-    hit]`` summed over the layers. ``prefix_pages``/``cont_pages`` may be ``None`` (no shared
-    prefix; no sequence that continues from an earlier chunk).
+    token) as ``(top, ids)``, and the expert layers' counts (``experts.moe``)
+    summed over the layers. ``prefix_pages``/``cont_pages`` may be
+    ``None`` (no shared prefix; no sequence that continues from an
+    earlier chunk).
     ``seg_from``/``seg_to`` name slot state, of which this family has
     none."""
     cache = state["pages"]
     live = seg >= 0
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-    held = jnp.zeros((2,), jnp.int32)
+    held = jnp.zeros((3,), jnp.int32)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope("mla"):
             h = rms_norm(x, lp["input_norm"], cfg.eps)
@@ -340,7 +341,7 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     b = tokens.shape[0]
-    held = jnp.zeros((2,), jnp.int32)
+    held = jnp.zeros((3,), jnp.int32)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope("mla"):
             h = rms_norm(x, lp["input_norm"], cfg.eps)

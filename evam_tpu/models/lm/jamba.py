@@ -420,7 +420,7 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     ``seg_from[s]`` (the snapshot's for a new sequence, the slot's own
     for a prompt that continues) and leave its end state in row
     ``seg_to[s]``. Returns the state, the logits rows ``last_idx`` as
-    ``(top, ids)`` and ``[0, 0]`` (no held experts). ``pos`` is not used: no
+    ``(top, ids)`` and ``[0, 0, 0]`` (no held experts). ``pos`` is not used: no
     layer has a positional term."""
 
     def mamba_layer(lp, l, x, ssm, conv):
@@ -447,7 +447,7 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
         x = params["embed"][tokens]
     x, state = _layers(cfg, params, x, state, mamba_layer, attn_layer)
     _, top, ids = head(cfg, params, x[last_idx])
-    return state, top, ids, jnp.zeros((2,), jnp.int32)
+    return state, top, ids, jnp.zeros((3,), jnp.int32)
 
 
 def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
@@ -481,4 +481,4 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
         x = params["embed"][tokens]
     x, state = _layers(cfg, params, x, state, mamba_layer, attn_layer)
     _, top, ids = head(cfg, params, x)
-    return state, top, ids, jnp.zeros((2,), jnp.int32)
+    return state, top, ids, jnp.zeros((3,), jnp.int32)

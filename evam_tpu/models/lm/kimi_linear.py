@@ -423,9 +423,9 @@ def _layers(cfg: Config, params: dict, x, state, live, kda_layer, mla_layer):
     mixers: ``kda_layer(lp, l, x, kda, conv)`` with ``l`` the layer's row
     of the slot state, then that layer's feed-forward, then, where an MLA
     layer follows, ``mla_layer(lp, j, x, pages)`` (pages at index ``j``)
-    and its feed-forward. Returns ``x``, the state and ``[held
-    assignments, held experts hit]`` summed over the layers."""
-    none = jnp.zeros((2,), jnp.int32)
+    and its feed-forward. Returns ``x``, the state and the expert
+    layers' counts (``experts.moe``) summed over the layers."""
+    none = jnp.zeros((3,), jnp.int32)
 
     def ffn(layer: int, post_norm, x):
         """The feed-forward of model layer ``layer``, residual added."""
@@ -480,8 +480,8 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     for a prompt that continues) and leave its end state in row
     ``seg_to[s]``. Rows of no segment (``seg`` -1: the chunk's tail, and
     the rows before a segment's aligned start) move no state. Returns the
-    state, the logits rows ``last_idx`` as ``(top, ids)`` and ``[held
-    assignments, held experts hit]``. ``pos`` is not used: no layer has a
+    state, the logits rows ``last_idx`` as ``(top, ids)`` and the expert
+    layers' counts (``experts.moe``). ``pos`` is not used: no layer has a
     positional term."""
 
     def kda_layer(lp, l, x, kda, conv):

@@ -77,6 +77,10 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # one assignment (the grouped products read only those experts'
     # weights): what a decode step's bytes follow
     "evam_moe_held_experts_hit": ("counter", ("kind",)),
+    # per step and expert layer, the (row tile, expert) pairs ONE of the
+    # three grouped products visits (ops/pallas_grouped.py): over the
+    # series above, the times a hit expert's matrix is read a product
+    "evam_moe_expert_reads": ("counter", ("kind",)),
     # QoS scheduling
     "evam_sched_admitted": ("counter", ("class",)),
     "evam_sched_rejected": ("counter", ("class",)),

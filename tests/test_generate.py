@@ -206,16 +206,18 @@ def test_the_shares_add_up_to_the_uncut_layer(model):
 
 
 def test_no_assignment_is_dropped_when_all_route_here():
-    """Every token to the held group (the overflow branch of the grouped
-    product): none is dropped."""
+    """Every token to the held group (three times the even share): none
+    is dropped."""
     cfg = lm.Config.from_dict(TINY)
     lp = lm.make_layer(cfg, 1)
     x = jnp.asarray(np.random.default_rng(4).standard_normal(
         (16, cfg.hidden)), lm.BF16)
     ids = jnp.tile(jnp.asarray([[0, 1, 2]]), (16, 1))
     w = jnp.ones((16, 3), jnp.float32)
-    y, n, hit = lm.held_experts(cfg, lp, x, w, ids, jnp.ones((16,), bool))
-    assert int(n) == 48 and int(hit) == 3
+    y, n, hit, reads = lm.held_experts(cfg, lp, x, w, ids,
+                                       jnp.ones((16,), bool))
+    # 48 rows are one row tile: each hit expert's matrix is read once
+    assert int(n) == 48 and int(hit) == 3 and int(reads) == 3
     want = sum(np.asarray(lm.swiglu(x, lp["expert_gate"][e],
                                     lp["expert_up"][e],
                                     lp["expert_down"][e]), np.float32)

@@ -248,8 +248,8 @@ def test_held_experts_are_a_range_and_the_hit_count_follows_the_routing():
     ids = jnp.tile(jnp.asarray([[8, 10, 0, 1]]), (16, 1))
     w = jnp.ones((16, 4), jnp.float32)
     live = jnp.arange(16) < 12
-    y, n, hit = experts.held_experts(cfg, lp, x, w, ids, live)
-    assert (int(n), int(hit)) == (24, 2)
+    y, n, hit, reads = experts.held_experts(cfg, lp, x, w, ids, live)
+    assert (int(n), int(hit), int(reads)) == (24, 2, 2)
     want = sum(np.asarray(lm.common.swiglu(
         x, lp["expert_gate"][e], lp["expert_up"][e], lp["expert_down"][e]),
         np.float32) for e in (0, 2))
@@ -562,7 +562,10 @@ def test_every_series_is_live_and_the_engines_row(engine):
             "held": c("evam_moe_held_assignments"),
             "hit_decode": c("evam_moe_held_experts_hit", {"kind": "decode"}),
             "hit_prefill": c("evam_moe_held_experts_hit",
-                             {"kind": "prefill"})}
+                             {"kind": "prefill"}),
+            "reads_decode": c("evam_moe_expert_reads", {"kind": "decode"}),
+            "reads_prefill": c("evam_moe_expert_reads",
+                               {"kind": "prefill"})}
 
     _idle(engine)
     before = counted()
@@ -582,6 +585,10 @@ def test_every_series_is_live_and_the_engines_row(engine):
     assert 0 < grew["held"] <= 3 * 4 * (150 + NEW - 1)
     assert 0 < grew["hit_decode"] <= 3 * 4 * (NEW - 1)
     assert 0 < grew["hit_prefill"] <= 3 * 4 * 2
+    # a decode step's 16 rows are one row tile: a hit expert is read once
+    # a product; a chunk's 512 are four, and a group may span two
+    assert grew["reads_decode"] == grew["hit_decode"]
+    assert grew["hit_prefill"] <= grew["reads_prefill"] <= 3 * (4 + 3) * 2
     cfg = engine.cfg
     per_row = len(cfg.kda_ids) * (
         4 * cfg.kda_heads * cfg.kda_dim ** 2 + 2 * 3 * 3 * cfg.kda_width)
@@ -592,6 +599,7 @@ def test_every_series_is_live_and_the_engines_row(engine):
     assert (row["pages"], row["pages_in_use"]) == (2 + 8 * 20, 2)
     text = metrics.render()
     for series in ('evam_moe_held_experts_hit_total{kind="decode"}',
+                   'evam_moe_expert_reads_total{kind="decode"}',
                    'evam_generate_state_rows_total{kind="decode"}',
                    "evam_generate_prefix_restores_total",
                    "evam_generate_state_bytes",
@@ -750,7 +758,8 @@ def test_benchmark_config_holds_the_published_widths_and_the_preset():
     assert rate["workloads"][-1] == "describe_kimi_replay"
     mine = [m for m in bench["per_layer"]
             if "describe_kimi_replay" in m.get("workloads", [])]
-    assert len(mine) == 21
+    assert len(mine) == 22   # PR 34's 21 and lm_expert_reads_per_hit
+    assert mine[-1]["name"] == "lm_expert_reads_per_hit.kimi_replay"
     for m in mine:
         assert m["workloads"] == ["describe_kimi_replay"]
         assert m["moves"] == "frames_per_s"

@@ -119,3 +119,60 @@ def test_ssm_decode_kernel_compiles_at_published_widths(one_chip, rows):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_decode_rows" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("m,groups,hidden,inter", [
+    (4096, 64, 2304, 1024),    # Kimi: a chunk's 512 tokens x 8
+    (512, 64, 2304, 1024),     # Kimi: a 64-row decode step
+    (3072, 20, 5120, 1536),    # DeepSeek: a chunk's 512 tokens x 6
+    (384, 20, 5120, 1536),     # DeepSeek: a 64-row decode step
+])
+def test_grouped_product_kernel_compiles_at_published_widths(
+        one_chip, m, groups, hidden, inter):
+    """Gate and up [groups, hidden, inter] in one call, down [groups,
+    inter, hidden] in the next, the tensors as they are: no temporary of
+    a tensor's size (302 and 315 MB)."""
+    from evam_tpu.ops import pallas_grouped as pg
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(rows, gate, up, down, sizes):
+        return pg.product(pg.swiglu(rows, gate, up, sizes), down, sizes)
+
+    compiled = jax.jit(layer).lower(
+        s((m, hidden)), s((groups, hidden, inter)),
+        s((groups, hidden, inter)), s((groups, inter, hidden)),
+        s((groups,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "expert_gate_up" in text and "expert_down" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_expert_layer_runs_the_grouped_kernel_at_published_widths(
+        one_chip, monkeypatch):
+    """The program around the kernel, a 64-row decode step of Kimi's
+    expert layer: router, sort, gather, the two calls, un-sort, the
+    shared expert; the held experts' 0.9 GB go in as arguments and are
+    not copied."""
+    from evam_tpu.models.lm import experts, kimi_linear
+    from evam_tpu.models.lm.presets import PRESETS
+
+    cfg = kimi_linear.Config.from_dict(PRESETS["kimi_linear_ep4"])
+    monkeypatch.setattr(experts, "on_tpu", lambda: True)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lp = {name: s(((cfg.n_held,) if name.startswith("expert_") else ())
+                  + shape,
+                  jnp.float32 if name.startswith("router") else jnp.bfloat16)
+          for name, shape in kimi_linear.moe_shapes(cfg).items()}
+    compiled = jax.jit(lambda lp, x, live: experts.moe(cfg, lp, x, live)
+                       ).lower(lp, s((64, cfg.hidden)),
+                               s((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "expert_gate_up" in text and "expert_down" in text
+    assert "ragged" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
