@@ -21,9 +21,13 @@ What more than one family has lives beside them and is called by each:
 ``common.py`` (tensors, norms, the split softmax, a packed chunk's bounds
 and convolution inputs, the head), ``mla.py`` (the latent attention:
 ``deepseek_v2`` with its rotation and query down-projection,
-``kimi_linear`` without either) and ``experts.py`` (the expert layer told
-which experts it holds: ``deepseek_v2`` softmax scores and group-limited
-routing, ``kimi_linear`` sigmoid scores with a selection bias).
+``kimi_linear`` without either), ``attention.py`` (plain keys and values
+under grouped queries: ``jamba`` one key-value head and no positions,
+``lfm2_moe`` eight with head norms and rotary positions) and
+``experts.py`` (the expert layer told which experts it holds:
+``deepseek_v2`` softmax scores and group-limited routing, ``kimi_linear``
+sigmoid scores with a selection bias, ``lfm2_moe`` the same with no shared
+expert and its expert layers' tensors in one stack).
 """
 
 from __future__ import annotations
@@ -31,7 +35,7 @@ from __future__ import annotations
 import importlib
 
 FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba",
-            "kimi_linear": "kimi_linear"}
+            "kimi_linear": "kimi_linear", "lfm2_moe": "lfm2_moe"}
 
 
 def family(model_type: str):

@@ -1,10 +1,11 @@
 """What the language-model families share (models/lm/deepseek_v2.py,
-models/lm/jamba.py, models/lm/kimi_linear.py): seeded tensors, norms and
-products, the dense SwiGLU, the pieces of a softmax that is split over its
-key rows, a packed chunk's visibility bounds, the inputs of a short causal
-convolution over a packed chunk, and the head with its top logits. The
-latent attention is in models/lm/mla.py and the expert layer in
-models/lm/experts.py, each called by the two families that have it.
+models/lm/jamba.py, models/lm/kimi_linear.py, models/lm/lfm2_moe.py):
+seeded tensors, norms and products, the dense SwiGLU, the pieces of a
+softmax that is split over its key rows, a packed chunk's visibility
+bounds, the inputs of a short causal convolution over a packed chunk, and
+the head with its top logits. The latent attention is in models/lm/mla.py,
+the plain attention in models/lm/attention.py and the expert layer in
+models/lm/experts.py, each called by the families that have it.
 
 Weights: ``key = fold_in(fold_in(PRNGKey(seed), layer), crc32(name))``;
 a tensor is ``normal(key) * initializer_range`` in float32, stored

@@ -132,6 +132,7 @@ class Config:
 
     #: what models/lm/experts.py reads beside the fields
     score_func = "softmax"
+    topk_eps = 1e-20
 
     @property
     def scale_routed(self) -> bool:

@@ -1,7 +1,9 @@
 """The expert layer of one chip of an expert-parallel deployment, for every
 family that has one (models/lm/deepseek_v2.py: softmax scores, group-limited
 top-k, one routing group held; models/lm/kimi_linear.py: sigmoid scores with
-a selection bias, one group, a quarter of the experts held).
+a selection bias, one group, a quarter of the experts held;
+models/lm/lfm2_moe.py: as Kimi-Linear's with half the experts held, no
+shared expert, and every expert layer's tensors in ONE stack).
 
 A router over ALL ``n_experts`` (scores in float32), the routed experts
 this chip HOLDS (ids ``[held_lo, held_lo + n_held)``, ``n_held`` the
@@ -16,9 +18,14 @@ ops/pallas_grouped.py (off the chip: ``jax.lax.ragged_dot``).
 What differs between the families is data of the config: ``score_func``
 (``softmax`` | ``sigmoid``), ``n_group`` / ``topk_group`` (1: no group
 limiting), ``top_k``, ``norm_topk`` (the chosen weights divided by their
-sum), ``scale_routed`` (times ``routed_scale``), ``held_lo``; and of the
-layer: ``router_bias`` (added to the scores for the SELECTION only; the
-weights are the scores without it).
+sum plus ``topk_eps``), ``scale_routed`` (times ``routed_scale``),
+``held_lo``, ``n_shared`` (0: the layer has no ``shared_*`` tensors and
+no shared term); and of the layer: ``router_bias`` (added to the scores
+for the SELECTION only; the weights are the scores without it). A family
+whose expert layers run in one loop body hands ``moe`` every layer's
+tensors stacked on a leading axis and the layer as a traced index
+(``layer``): the router's slice is taken, the experts' stack goes to the
+grouped products as it is.
 """
 
 from __future__ import annotations
@@ -36,8 +43,9 @@ def tensor_shapes(cfg, bias: bool) -> dict[str, tuple]:
     out = {"router": (h, cfg.n_experts)}
     if bias:
         out["router_bias"] = (cfg.n_experts,)
-    out.update(shared_gate=(h, s), shared_up=(h, s), shared_down=(s, h),
-               expert_gate=(h, cfg.moe_inter), expert_up=(h, cfg.moe_inter),
+    if s:
+        out.update(shared_gate=(h, s), shared_up=(h, s), shared_down=(s, h))
+    out.update(expert_gate=(h, cfg.moe_inter), expert_up=(h, cfg.moe_inter),
                expert_down=(cfg.moe_inter, h))
     return out
 
@@ -67,13 +75,13 @@ def route(cfg, x, router, bias=None):
     if bias is not None:
         w = jnp.take_along_axis(scores, ids, axis=1)
     if cfg.norm_topk and cfg.top_k > 1:
-        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w / (w.sum(-1, keepdims=True) + cfg.topk_eps)
     if cfg.scale_routed:
         w = w * cfg.routed_scale
     return w, ids
 
 
-def held_experts(cfg, lp: dict, x, w, ids, live):
+def held_experts(cfg, lp: dict, x, w, ids, live, layer=None):
     """The held experts' part of the routed sum, with work that follows
     the assignments routed here: the ``T*k`` assignments are sorted by
     held expert (those of other chips' experts, and of dead rows, last;
@@ -90,9 +98,10 @@ def held_experts(cfg, lp: dict, x, w, ids, live):
     decode steps. Returns the sum [T, hidden], the number of held
     assignments, the number of held experts that received at least one
     and the (row tile, expert) pairs one product visits (the times an
-    expert's matrix is read)."""
+    expert's matrix is read). With ``layer`` the ``expert_*`` tensors
+    are stacks [layers, held, ...] of which that layer's are read."""
     t, k = ids.shape
-    n_held = lp["expert_gate"].shape[0]
+    n_held = lp["expert_gate"].shape[-3]
     local = ids - cfg.held_lo
     mine = (local >= 0) & (local < n_held) & live[:, None]
     m = pallas_grouped.padded(t * k)
@@ -106,8 +115,9 @@ def held_experts(cfg, lp: dict, x, w, ids, live):
     swiglu_rows, product_rows = (
         (pallas_grouped.swiglu, pallas_grouped.product) if on_tpu()
         else (pallas_grouped.swiglu_xla, pallas_grouped.product_xla))
-    hmid = swiglu_rows(rows, lp["expert_gate"], lp["expert_up"], sizes)
-    y = product_rows(hmid, lp["expert_down"], sizes)
+    hmid = swiglu_rows(rows, lp["expert_gate"], lp["expert_up"], sizes,
+                       layer)
+    y = product_rows(hmid, lp["expert_down"], sizes, layer)
     # rows past the last group hold whatever the kernel left there
     y = jnp.where((jnp.arange(m) < n_mine)[:, None], y, 0)
     back = jnp.argsort(order)[:t * k]
@@ -117,15 +127,20 @@ def held_experts(cfg, lp: dict, x, w, ids, live):
             pallas_grouped.n_visits(sizes, m))
 
 
-def moe(cfg, lp: dict, x, live):
-    """Held routed terms plus the shared experts, and ``[held
-    assignments, held experts hit, expert matrices read a product]``
-    (int32)."""
+def moe(cfg, lp: dict, x, live, layer=None):
+    """Held routed terms plus the shared experts (where the config has
+    any), and ``[held assignments, held experts hit, expert matrices read
+    a product]`` (int32). ``layer``: ``lp`` holds every expert layer's
+    tensors stacked, and this is the one to run."""
+    router, bias = lp["router"], lp.get("router_bias")
+    if layer is not None:
+        router, bias = router[layer], None if bias is None else bias[layer]
     with jax.named_scope("router"):
-        w, ids = route(cfg, x, lp["router"], lp.get("router_bias"))
+        w, ids = route(cfg, x, router, bias)
     with jax.named_scope("experts"):
-        routed, *counts = held_experts(cfg, lp, x, w, ids, live)
-    with jax.named_scope("shared"):
-        shared = swiglu(x, lp["shared_gate"], lp["shared_up"],
-                        lp["shared_down"])
-    return routed + shared, jnp.stack(counts)
+        y, *counts = held_experts(cfg, lp, x, w, ids, live, layer)
+    if cfg.n_shared:
+        with jax.named_scope("shared"):
+            y = y + swiglu(x, lp["shared_gate"], lp["shared_up"],
+                           lp["shared_down"])
+    return y, jnp.stack(counts)

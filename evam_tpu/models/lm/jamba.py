@@ -19,18 +19,16 @@ is the embedding (tied).
   kernel with the state in VMEM (ops/pallas_selective_scan.py); a decode
   step's one-token update is a second kernel over the step's rows that
   moves each row's state in place (``mamba_decode``).
-* **Attention mixer.** ``num_attention_heads`` query heads over ONE
-  key-value head, no bias, no positional term, scale head_dim^-1/2. The
-  cache row of a token is ``[k ; v]`` in pages (engine/pages.py), in the
-  attention layers only. A chunk attends over prefix, continued and own
-  rows under the bounds ``common.chunk_bounds`` gives every family; a
-  decode step in two parts merged by their softmax sums, the shared
-  prefix read once for all rows.
+* **Attention mixer**: models/lm/attention.py (the module
+  models/lm/lfm2_moe.py calls too), here with ``num_attention_heads``
+  query heads over ONE key-value head, no positional term and no head
+  norms. The cache row of a token is ``[k ; v]`` in pages
+  (engine/pages.py), in the attention layers only.
 
 What it shares with the other families is in models/lm/common.py (the
 packed convolution's inputs among it); the latent attention
-(models/lm/mla.py) and the expert layer (models/lm/experts.py) are the
-other two families' and are not called here.
+(models/lm/mla.py) and the expert layer (models/lm/experts.py) are other
+families' and are not called here.
 
 bfloat16 weights and activations; the recurrence, ``dt`` and the state in
 float32. ``d_inner`` lies on the lanes of every array: ``conv_w`` [d_conv,
@@ -57,10 +55,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from evam_tpu.models.lm import common
+from evam_tpu.models.lm import attention, common
 from evam_tpu.models.lm.common import BF16, F32, GLOBAL_LAYER, rms_norm
 from evam_tpu.models.lm.common import mm as _mm
-from evam_tpu.ops import pallas_mla, pallas_selective_scan, slot_rows
+from evam_tpu.ops import pallas_selective_scan, slot_rows
 
 DT_MIN, DT_MAX = 0.001, 0.1
 #: the packer may start a segment at any token of a chunk
@@ -83,6 +81,11 @@ class Config:
     vocab: int          # rows of the vocabulary held here
     seed: int
     init_range: float
+
+    #: what models/lm/attention.py reads beside the fields
+    kv_heads = 1
+    rope_theta = None
+    chunk_kernel = False
 
     @classmethod
     def from_dict(cls, d: dict) -> "Config":
@@ -138,7 +141,7 @@ class Config:
     @property
     def kv_width(self) -> int:
         """Values a page row holds: one key and one value."""
-        return 2 * self.head_dim
+        return attention.kv_width(self)
 
 
 # --------------------------------------------------------------- weights
@@ -160,9 +163,8 @@ def mamba_shapes(cfg: Config) -> dict[str, tuple]:
 
 
 def attn_shapes(cfg: Config) -> dict[str, tuple]:
-    h, q = cfg.hidden, cfg.heads * cfg.head_dim
-    return {**mlp_shapes(cfg), "q": (h, q), "k": (h, cfg.head_dim),
-            "v": (h, cfg.head_dim), "o": (q, h)}
+    return {**mlp_shapes(cfg),
+            **attention.tensor_shapes(cfg, head_norms=False)}
 
 
 def _tensor(key, kind: str, shape: tuple, std: float):
@@ -332,48 +334,7 @@ def mamba_decode(cfg: Config, lp: dict, l, x, slot, live, conv_all, ssm):
 def _qkv(cfg: Config, lp: dict, x):
     """Per token: the queries [T, heads, head_dim] and the cache row
     ``[k ; v]``."""
-    h = rms_norm(x, lp["in_norm"], cfg.eps)
-    q = _mm(h, lp["q"]).reshape(x.shape[0], cfg.heads, cfg.head_dim)
-    return q, jnp.concatenate([_mm(h, lp["k"]), _mm(h, lp["v"])], axis=-1)
-
-
-def _sums(cfg: Config, score_expr, value_expr, q, rows, visible):
-    hd = cfg.head_dim
-    return common.softmax_sums(hd ** -0.5, score_expr, value_expr, q,
-                               rows[..., :hd], rows[..., hd:], visible)
-
-
-def attn_prefill(cfg: Config, lp: dict, q, kv, seg, prefix, n_prefix, cont,
-                 n_cont):
-    """A packed chunk: every (token, head) over ONE list of cache rows,
-    the shared prefix's, the continued sequence's and the chunk's own,
-    under ``common.chunk_bounds``. ``prefix`` and ``cont`` may be None."""
-    t = kv.shape[0]
-    rows = jnp.concatenate(
-        [r for r in (prefix, cont, kv) if r is not None], axis=0)
-    bounds, b0 = common.chunk_bounds(
-        seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
-        0 if cont is None else cont.shape[0])
-    seen = pallas_mla._visible(jnp.arange(rows.shape[0])[None, :], bounds, b0)
-    o = common.merge_softmax_sums(
-        _sums(cfg, "thd,sd->ths", "ths,sd->thd", q, rows, seen[:, None, :]),
-        None)
-    return _mm(o.astype(BF16).reshape(t, -1), lp["o"])
-
-
-def attn_decode(cfg: Config, lp: dict, q, ctx, ctx_len, prefix, n_prefix):
-    """One new token per row, its softmax in two parts (as
-    deepseek_v2.mla_decode): each row against its OWN cached rows ``ctx``
-    [B, T, 2 * head_dim], visible below ``ctx_len``; all rows' queries
-    against the shared prefix rows ``prefix`` in one product."""
-    own = jnp.arange(ctx.shape[1])[None, None, :] < ctx_len[:, None, None]
-    sums = _sums(cfg, "bhd,btd->bht", "bht,btd->bhd", q, ctx, own)
-    shared = None
-    if prefix is not None:
-        seen = jnp.arange(prefix.shape[0]) < n_prefix
-        shared = _sums(cfg, "bhd,sd->bhs", "bhs,sd->bhd", q, prefix, seen)
-    o = common.merge_softmax_sums(sums, shared).astype(BF16)
-    return _mm(o.reshape(o.shape[0], -1), lp["o"])
+    return attention.qkv(cfg, lp, rms_norm(x, lp["in_norm"], cfg.eps))
 
 
 def head(cfg: Config, params: dict, x):
@@ -437,7 +398,7 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     def attn_layer(lp, j, x, pages):
         with jax.named_scope("attn"):
             q, kv = _qkv(cfg, lp, x)
-            x = x + attn_prefill(
+            x = x + attention.attn_prefill(
                 cfg, lp, q, kv, seg, common.page_rows(pages[j], prefix_pages),
                 n_prefix, common.page_rows(pages[j], cont_pages), n_cont)
             pages = pages.at[j, dest_page, dest_off].set(kv)
@@ -472,7 +433,7 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
             q, kv = _qkv(cfg, lp, x)
             pages = pages.at[j, dest_page, dest_off].set(kv)
             ctx = pages[j][page_table].reshape(b, -1, cfg.kv_width)
-            x = x + attn_decode(
+            x = x + attention.attn_decode(
                 cfg, lp, q, ctx, ctx_len,
                 common.page_rows(pages[j], prefix_pages), n_prefix)
         return _feed_forward(cfg, lp, x), pages

@@ -109,6 +109,7 @@ class Config:
     q_rank = 0
     score_func = "sigmoid"
     scale_routed = True
+    topk_eps = 1e-20
     n_group = 1
     topk_group = 1
 

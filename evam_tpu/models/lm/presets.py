@@ -28,10 +28,26 @@ vocabulary. ``kda_gate_rank`` is the one size the config does not carry
 KDA, MLA, KDA, the first dense; 16 experts, 4 held, 4 a token) at a size
 a CPU test runs.
 
+``lfm2_moe_ep2`` is LFM2-8B-A1B's published config
+(https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json) with
+every width as published, cut to what ONE chip of two that share each
+layer holds of ALL 24 layers (18 gated short convolutions, grouped-query
+attention at 2, 6, 10, 14, 18 and 21, the first two layers dense): the
+router keeps its 32 outputs and 4 a token; the chip holds experts
+``[held_lo, held_lo + experts_held)`` (16) and half the vocabulary.
+``qk_norm_gain`` is the mean of the query and key head norms' seeded gains
+(at 1 a seeded softmax over 2.4 k rows is flat and nothing sees which rows
+it weighs). ``lfm2_moe_tiny`` has the same structure (both dense layers,
+an expert layer behind a convolution and behind an attention layer,
+attention at 2, 6 and 8: no regular period; 8 experts, 4 held, 2 a token,
+2 key-value heads under 4 query heads) at a size a CPU test runs.
+
 Which module serves a preset is its ``model_type`` (models/lm
-``FAMILIES``); the latent attention (models/lm/mla.py) and the expert
-layer (models/lm/experts.py) are shared by ``deepseek_v2`` and
-``kimi_linear`` and read what differs from these keys.
+``FAMILIES``); the latent attention (models/lm/mla.py: ``deepseek_v2``,
+``kimi_linear``), the plain attention (models/lm/attention.py: ``jamba``,
+``lfm2_moe``) and the expert layer (models/lm/experts.py: ``deepseek_v2``,
+``kimi_linear``, ``lfm2_moe``) are each shared and read what differs from
+these keys.
 """
 
 from __future__ import annotations
@@ -94,7 +110,56 @@ KIMI_LINEAR_PUBLISHED = {
     "vocab_size": 163840,
 }
 
+LFM2_8B_A1B_PUBLISHED = {
+    "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+    "intermediate_size": 7168,
+    "layer_types": [
+        "conv", "conv", "full_attention", "conv", "conv", "conv",
+        "full_attention", "conv", "conv", "conv", "full_attention", "conv",
+        "conv", "conv", "full_attention", "conv", "conv", "conv",
+        "full_attention", "conv", "conv", "full_attention", "conv", "conv"],
+    "max_position_embeddings": 128000, "model_type": "lfm2_moe",
+    "moe_intermediate_size": 1792, "norm_eps": 1e-05,
+    "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_dense_layers": 2, "num_experts": 32, "num_experts_per_tok": 4,
+    "num_hidden_layers": 24, "num_key_value_heads": 8,
+    "rope_theta": 1000000, "routed_scaling_factor": 1,
+    "use_expert_bias": True, "vocab_size": 65536,
+}
+
 PRESETS = {
+    "lfm2_moe_ep2": {
+        **LFM2_8B_A1B_PUBLISHED,
+        # the cut: the chip's share of the experts and of the vocabulary;
+        # every one of the 24 layers is here
+        "experts_held": 16,
+        "held_lo": 0,
+        "vocab_held": 32768,
+        "weights_seed": 20251007,
+        "initializer_range": 0.02,
+        # assumed: the mean of the q_layernorm / k_layernorm gains, for a
+        # softmax as peaked as a trained one (a score's deviation is the
+        # product of the two gains)
+        "qk_norm_gain": 1.75,
+    },
+    "lfm2_moe_tiny": {
+        **LFM2_8B_A1B_PUBLISHED,
+        "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 32, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "num_experts": 8,
+        "num_experts_per_tok": 2, "num_hidden_layers": 10,
+        "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
+                        "conv", "full_attention", "conv", "full_attention",
+                        "conv"],
+        "vocab_size": 512,
+        "experts_held": 4,
+        "held_lo": 0,
+        "vocab_held": 128,
+        "weights_seed": 17,
+        # as deepseek_v2_tiny: 0.02 at width 64 leaves every score flat
+        "initializer_range": 0.15,
+        "qk_norm_gain": 1.75,
+    },
     "kimi_linear_ep4": {
         **KIMI_LINEAR_PUBLISHED,
         # the cut: depth, the chip's share of the experts and vocabulary

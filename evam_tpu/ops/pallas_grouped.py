@@ -5,7 +5,11 @@ group's matrix, with work that follows the groups the rows reach.
 other (the expert layer's assignments sorted by held expert,
 models/lm/experts.py); what lies past ``sizes.sum()`` belongs to no group.
 ``w`` [groups, k, n] is one matrix a group, handed AS IT IS: the kernel
-reads blocks of it where it lies and no slice or copy of it is made.
+reads blocks of it where it lies and no slice or copy of it is made. A
+model whose expert layers sit in ONE loop body hands the layers' tensors
+STACKED, ``w`` [layers, groups, k, n], and the layer as a traced scalar
+(``layer``): one more prefetched scalar, which the matrices' block index
+reads, so the stack is addressed in place too.
 
 The grid walks VISITS: the (row tile, group) pairs in which a tile of
 ``tile`` rows holds rows of the group, in order, ``tile_of`` and
@@ -108,7 +112,7 @@ def n_visits(sizes, m: int):
 def _kernel(tile: int, n_w: int):
     from jax.experimental import pallas as pl
 
-    def kernel(tile_of, group_of, starts, ends, rows_ref, *refs):
+    def kernel(tile_of, group_of, starts, ends, layer, rows_ref, *refs):
         w_refs, out_ref = refs[:n_w], refs[n_w]
         v = pl.program_id(1)
         g = group_of[v]
@@ -130,12 +134,16 @@ def _kernel(tile: int, n_w: int):
     return kernel
 
 
-def _call(name: str, rows, ws, sizes, interpret: bool):
+def _call(name: str, rows, ws, sizes, layer, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     m, k = rows.shape
-    n = ws[0].shape[2]
+    n = ws[0].shape[-1]
+    stacked = ws[0].ndim == 4
+    if stacked != (layer is not None):
+        raise ValueError("a stack of layers' matrices [layers, groups, k, "
+                         "n] is addressed by ``layer``, and nothing else is")
     tile = row_tile(m)
     if m % tile or tile % SUBLANES:
         raise ValueError(f"{m} rows are not whole tiles of {tile} "
@@ -147,12 +155,17 @@ def _call(name: str, rows, ws, sizes, interpret: bool):
     # and a decode step lost what the kernel had gained)
     vmem = 2 * 2 * (len(ws) * k * cols + tile * k + tile * cols) + (8 << 20)
     tile_of, group_of, starts, ends, count = visits(sizes, m)
-    w_spec = pl.BlockSpec((None, k, cols),
-                          lambda j, v, t, g, *_: (g[v], 0, j))
+    if stacked:
+        w_spec = pl.BlockSpec(
+            (None, None, k, cols),
+            lambda j, v, t, g, s, e, l: (l[0], g[v], 0, j))
+    else:
+        w_spec = pl.BlockSpec((None, k, cols),
+                              lambda j, v, t, g, *_: (g[v], 0, j))
     return pl.pallas_call(
         _kernel(tile, len(ws)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(n // cols, count),
             in_specs=[pl.BlockSpec((tile, k),
                                    lambda j, v, t, *_: (t[v], 0)),
@@ -165,33 +178,39 @@ def _call(name: str, rows, ws, sizes, interpret: bool):
             vmem_limit_bytes=vmem),
         name=name,
         interpret=interpret,
-    )(tile_of, group_of, starts, ends, rows.astype(BF16), *ws)
+    )(tile_of, group_of, starts, ends,
+      jnp.reshape(0 if layer is None else layer, (1,)).astype(jnp.int32),
+      rows.astype(BF16), *ws)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def swiglu(rows, gate, up, sizes, *, interpret=False):
+def swiglu(rows, gate, up, sizes, layer=None, *, interpret=False):
     """``silu(rows @ gate[g]) * (rows @ up[g])`` for the rows of each
     group ``g``, both products rounded to bfloat16 before the epilogue:
     ``rows`` [m, k] (``m`` = ``padded(m)``), ``gate``, ``up`` [groups, k,
-    n], ``sizes`` [groups] -> [m, n] bfloat16."""
-    return _call("expert_gate_up", rows, (gate, up), sizes, interpret)
+    n] (or [layers, groups, k, n] with ``layer``), ``sizes`` [groups] ->
+    [m, n] bfloat16."""
+    return _call("expert_gate_up", rows, (gate, up), sizes, layer, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def product(rows, w, sizes, *, interpret=False):
+def product(rows, w, sizes, layer=None, *, interpret=False):
     """``rows @ w[g]`` for the rows of each group ``g``: ``rows`` [m, k],
-    ``w`` [groups, k, n] -> [m, n] bfloat16."""
-    return _call("expert_down", rows, (w,), sizes, interpret)
+    ``w`` [groups, k, n] (or [layers, groups, k, n] with ``layer``) -> [m,
+    n] bfloat16."""
+    return _call("expert_down", rows, (w,), sizes, layer, interpret)
 
 
 # --------------------------------------------- the same through XLA
 
 
-def product_xla(rows, w, sizes):
+def product_xla(rows, w, sizes, layer=None):
+    if layer is not None:
+        w = w[layer]
     return jax.lax.ragged_dot(rows.astype(BF16), w, sizes.astype(jnp.int32),
                               preferred_element_type=F32).astype(BF16)
 
 
-def swiglu_xla(rows, gate, up, sizes):
-    return (jax.nn.silu(product_xla(rows, gate, sizes))
-            * product_xla(rows, up, sizes))
+def swiglu_xla(rows, gate, up, sizes, layer=None):
+    return (jax.nn.silu(product_xla(rows, gate, sizes, layer))
+            * product_xla(rows, up, sizes, layer))

@@ -12,7 +12,12 @@ the layer ``l``, ``slot`` and ``live`` are PREFETCHED SCALARS of a kernel
 whose grid is the step's rows, one grid step a row: the state's block at
 grid step ``b`` is ``state[l, slot[b]]``, the array is aliased in and out,
 and so a row is read from its slot, moved on and written back where it
-was. No other row is touched.
+was. No other row is touched. Three kernel bodies use it, one a kind of
+recurrent layer: ``ssm_decode_rows`` (ops/pallas_selective_scan.py: a
+float32 state and the convolution's taps), ``kda_decode_rows``
+(ops/pallas_kda.py: a matrix state a head and three convolutions' taps)
+and ``conv_decode_rows`` (ops/pallas_short_conv.py: taps alone, 8 KB a
+row, the kernel's cost its grid steps and not its bytes).
 
 What the kernels rely on, and hold:
 

@@ -162,45 +162,56 @@ def test_group_limited_routing_on_crafted_scores(case):
         rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("model", ["deepseek_v2", "kimi_linear"])
+@pytest.mark.parametrize("model", ["deepseek_v2", "kimi_linear", "lfm2_moe"])
 def test_the_shares_add_up_to_the_uncut_layer(model):
-    """All four shares' routed sums, the shared experts counted once, are
-    the uncut reference layer (every routed expert + the shared), for both
-    expert families through the one expert layer (models/lm/experts.py):
-    DeepSeek-V2's four routing groups, Kimi-Linear's four ranges of four."""
-    from benchmark.reference import kimi_linear_plain
-    from evam_tpu.models.lm import kimi_linear
+    """All the shares' routed sums, the shared experts counted once, are
+    the uncut reference layer (every routed expert + the shared), for
+    every expert family through the one expert layer
+    (models/lm/experts.py): DeepSeek-V2's four routing groups,
+    Kimi-Linear's four ranges of four, LFM2-MoE's two halves of eight
+    (NO shared expert to count once; the layer one of a stack)."""
+    from benchmark.reference import kimi_linear_plain, lfm2_moe_plain
+    from evam_tpu.models.lm import kimi_linear, lfm2_moe
 
-    layer = 1
+    layer, stacked = 1, None
     if model == "deepseek_v2":
         tiny, plain = TINY, ref
         cfg = lm.Config.from_dict(tiny)
         shares = [(share := lm.Config.from_dict({**tiny, "held_group": g}),
                    lm.make_layer(share, layer)) for g in range(cfg.n_group)]
-    else:
+    elif model == "kimi_linear":
         tiny, plain = PRESETS["kimi_linear_tiny"], kimi_linear_plain
         cfg = kimi_linear.Config.from_dict(tiny)
         shares = [(share := kimi_linear.Config.from_dict(
             {**tiny, "held_lo": lo}), kimi_linear.make_layer(
                 share, layer, kimi_linear.moe_shapes(share),
                 range(lo, lo + 4))) for lo in range(0, 16, 4)]
+    else:
+        tiny, plain = PRESETS["lfm2_moe_tiny"], lfm2_moe_plain
+        cfg = lfm2_moe.Config.from_dict(tiny)
+        layer, stacked = 3, 1   # model layers 2 and 3 in a stack of two
+        shares = [(share := lfm2_moe.Config.from_dict(
+            {**tiny, "held_lo": lo}), lfm2_moe.make_layers(
+                share, (2, 3), lfm2_moe.moe_shapes(share),
+                range(lo, lo + 4))) for lo in range(0, 8, 4)]
     x = (np.random.default_rng(3).standard_normal((24, cfg.hidden))
          .astype(np.float32))
     xb = jnp.asarray(x, lm.BF16)
     w = plain.layer_weights(tiny, layer)
     x32 = jnp.asarray(xb, jnp.float32)
-    whole = np.asarray(plain.moe(tiny, layer, w, x32, range(16)))
+    whole = np.asarray(plain.moe(tiny, layer, w, x32, range(cfg.n_experts)))
     shared = np.asarray(plain.moe(tiny, layer, w, x32, []))
+    assert bool(shared.any()) == bool(cfg.n_shared)
     total = np.zeros_like(whole)
     held = 0
     live = jnp.ones((24,), bool)
     for share, lp in shares:
-        y, n = lm.moe(share, lp, xb, live)
+        y, n = lm.moe(share, lp, xb, live, stacked)
         total += np.asarray(y, np.float32)
         held += int(n[0])
     total -= (len(shares) - 1) * shared
     # every assignment went to exactly one share
-    assert len(shares) == 4 and held == 24 * cfg.top_k
+    assert len(shares) == cfg.n_experts // 4 and held == 24 * cfg.top_k
     assert np.abs(total - whole).max() < 0.05 * np.abs(whole).max()
     assert np.median(np.abs(total - whole)) < 0.01 * np.abs(whole).max()
 

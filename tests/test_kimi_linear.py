@@ -755,7 +755,7 @@ def test_benchmark_config_holds_the_published_widths_and_the_preset():
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == cfg["reduced"]
     rate = next(m for m in bench["end_to_end"] if m["name"] == "frames_per_s")
-    assert rate["workloads"][-1] == "describe_kimi_replay"
+    assert "describe_kimi_replay" in rate["workloads"]
     mine = [m for m in bench["per_layer"]
             if "describe_kimi_replay" in m.get("workloads", [])]
     assert len(mine) == 22   # PR 34's 21 and lm_expert_reads_per_hit

@@ -176,3 +176,121 @@ def test_expert_layer_runs_the_grouped_kernel_at_published_widths(
     assert "expert_gate_up" in text and "expert_down" in text
     assert "ragged" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows", [64, 128])
+def test_conv_decode_kernel_compiles_at_published_widths(one_chip, rows):
+    """A layer's taps [130, 16, 256] go in and come out in place, a slot's
+    row one whole bfloat16 tile."""
+    from evam_tpu.ops.pallas_short_conv import decode_rows
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(decode_rows, donate_argnums=(6,)).lower(
+        s((), jnp.int32), s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        s((rows, 2048)), s((rows, 2048)), s((3, 2048)),
+        s((1, 130, 16, 256))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "conv_decode_rows" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_lfm2_step_programs_compile_at_published_widths(one_chip,
+                                                        monkeypatch, program):
+    """Both step programs of the LFM2-MoE family at the deployment's
+    sizes: all 24 layers in one loop body, so each kernel is in the
+    program ONCE; the 7.75 GB of stacked expert tensors are read where
+    they lie (temporaries stay small); and a decode step holds no scatter
+    and no whole-array copy of the slot state: the taps move through
+    ``conv_decode_rows``."""
+    import re
+
+    import numpy as np
+
+    from evam_tpu.models.lm import common, lfm2_moe as lm
+    from evam_tpu.models.lm.presets import PRESETS
+
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+    cfg = lm.Config.from_dict(PRESETS["lfm2_moe_ep2"])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def stack(n, shapes, held=()):
+        return {k: s((n, *(held if k.startswith("expert_") else ()), *v))
+                for k, v in shapes.items()}
+
+    params = {
+        "embed": s((cfg.vocab, cfg.hidden)), "final_norm": s((cfg.hidden,)),
+        "norms": stack(cfg.layers, lm.norm_shapes(cfg)),
+        "conv": stack(len(cfg.conv_ids), lm.conv_shapes(cfg)),
+        "attn": stack(len(cfg.attn_ids), lm.attn_shapes(cfg)),
+        "dense": stack(cfg.n_dense, lm.dense_shapes(cfg)),
+        "moe": stack(len(cfg.moe_ids), lm.moe_shapes(cfg), (cfg.n_held,)),
+    }
+    state = {k: s(v.shape, v.dtype)
+             for k, v in lm.state_shapes(cfg, 401, 128, 128).items()}
+    shared = np.arange(1, 17, dtype=np.int32)
+    i32 = jnp.int32
+    if program == "decode":
+        b = 64
+
+        def step(params, state, tokens, pos, table, ctx_len, page, off, live,
+                 slot):
+            return lm.decode_tokens(cfg, params, state, tokens, pos, table,
+                                    ctx_len, page, off, live, shared, 2048,
+                                    slot)
+
+        args = (s((b,), i32), s((b,), i32), s((b, 3), i32), s((b,), i32),
+                s((b,), i32), s((b,), i32), s((b,), jnp.bool_), s((b,), i32))
+    else:
+        t, n_seg = 512, 8
+
+        def step(params, state, tokens, seg, pos, page, off, cont, n_cont,
+                 last_idx, seg_from, seg_to):
+            return lm.prefill_chunk(cfg, params, state, tokens, seg, pos,
+                                    page, off, shared, 2048, cont, n_cont,
+                                    last_idx, seg_from, seg_to)
+
+        args = (*[s((t,), i32)] * 5, s((3,), i32), s((), i32),
+                *[s((n_seg,), i32)] * 3)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, state, *args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    for name, n in (("expert_gate_up", 1), ("expert_down", 1),
+                    ("conv_decode_rows", int(program == "decode")),
+                    ("attn_chunk_attention", int(program == "prefill"))):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
+    assert len(calls) == 3
+    assert "ragged" not in text
+    slot_state = "bf16[18,130,16,256]"
+    moved = [line for line in text.splitlines()
+             if re.search(rf"= {re.escape(slot_state)}\S* (scatter|copy)\(",
+                          line)]
+    if program == "decode":
+        assert not moved, moved[:2]
+        assert not re.search(r"= bf16\[(1,)?130,16,256\]\S* scatter\(", text)
+    # no layer's pages sliced out (105 MB), no scores materialised
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("heads,rows,keys,dim", [
+    (8, 512 * 4, 2048 + 384 + 512, 64),    # LFM2: a chunk, 4 heads a group
+    (8, 64 * 4, 512 + 64, 64),             # a short chunk, ragged key count
+])
+def test_chunk_attention_kernel_compiles_at_published_widths(
+        one_chip, heads, rows, keys, dim):
+    from evam_tpu.ops.pallas_attention import chunk_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: chunk_attention(*a, scale=dim ** -0.5, b0=2048)).lower(
+        s((heads, rows, dim)), s((heads, keys, dim)), s((heads, keys, dim)),
+        s((rows, 4), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "attn_chunk_attention" in text
