@@ -26,8 +26,10 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   ``model_type`` (models/lm ``family``) and says what device state its
   sequences have (``state_shapes``): a pytree that both programs donate
   and update in place. ``pages`` is the cache of the layers that attend
-  (engine/pages.py): latent rows in every layer of DeepSeek-V2, key and
-  value rows in Jamba's two attention layers. The pages of the shared
+  (engine/pages.py): latent rows in every layer of DeepSeek-V2 (576
+  values stored 640 wide: whole lane tiles, so the donated array enters
+  and leaves both programs where it lies), key and value rows in Jamba's
+  two attention layers. The pages of the shared
   instruction prefix are prefilled once in ``warm_async``, never written
   again, and a constant of both programs: a prefill chunk and a decode
   step each read them ONCE for all their rows. A decode row's page table

@@ -3,9 +3,11 @@
 The cache itself is one device array ``[layers that attend, pages,
 page_tokens, row width]`` that the step programs update in place
 (engine/generate.py); its shape is the model family's (``state_shapes``:
-576-value latent rows in every layer of DeepSeek-V2 and in Kimi-Linear's
-two latent-attention layers, a key and a value of 128 in Jamba's two
-attention layers). This is its host side, the same for
+576-value latent rows stored 640 wide in every layer of DeepSeek-V2 and
+in Kimi-Linear's two latent-attention layers, a key and a value of 128 in
+Jamba's two attention layers; a row is whole lane tiles of 128 in every
+family, models/lm/common.py ``row_width``, so that the array lies
+rows-minor on the chip). This is its host side, the same for
 every family: which pages are free, which belong to a sequence, and which
 are PINNED: the shared instruction prefix, mapped read-only into every
 sequence's page table and never handed back. Page 0 is the null page:

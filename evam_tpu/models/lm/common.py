@@ -177,12 +177,31 @@ def packed_conv_inputs(u_pre, seg, conv0, k1: int):
     return taps, jnp.concatenate(rows, axis=1)
 
 
+def row_width(values: int) -> int:
+    """The width a page cache stores a row of ``values`` values in: whole
+    lane tiles of 128, the values first and zeros behind them. Rows of
+    another width the TPU's compiler keeps tokens-minor in HBM, and every
+    step program then copies the whole cache to rows-minor and back."""
+    return -(-values // 128) * 128
+
+
 def page_rows(layer_cache, pages):
     """The cache rows of ``pages``, in order: [len(pages) * page, width]
     (None where there are no pages)."""
     if pages is None:
         return None
     return layer_cache[pages].reshape(-1, layer_cache.shape[-1])
+
+
+def layer_page_rows(cache, layer, ids):
+    """The rows of ``layer``'s pages ``ids`` of the WHOLE cache ``[layers,
+    pages, page_tokens, width]``, in order: [..., len(ids) * page, width]
+    (None where there are no pages). One gather out of the array as it
+    lies: ``cache[layer]`` first would copy that layer out every time, a
+    step's layers together the whole cache."""
+    if ids is None:
+        return None
+    return cache[layer, ids].reshape(*ids.shape[:-1], -1, cache.shape[-1])
 
 
 def top_logits(logits):

@@ -6,7 +6,8 @@ Per layer, pre-norm residual, RMSNorm:
   rope). ``[c_kv ; k_r] = W_kva h``, ``c_kv = norm(c_kv)``, ``k_r =
   rope(k_r)`` (one rope key for all heads). ``[k_nope ; v] = W_kvb c_kv``.
   ``score = (q_nope . k_nope + rope(q_rope) . k_r) * s``. The cache holds
-  ``[c_kv ; k_r]`` per token and layer. Attention runs in the ABSORBED
+  ``[c_kv ; k_r ; zeros]`` per token and layer (576 values stored 640
+  wide: whole lane tiles). Attention runs in the ABSORBED
   form (``W_kvb``'s key half folded into the query, its value half into
   the output), so every key is read as a latent row: a decode step in
   two parts, all rows' queries against the shared prefix's rows in one
@@ -152,7 +153,8 @@ class Config:
 
     @property
     def latent(self) -> int:
-        """Values the cache holds per token and layer."""
+        """The model's values per token and layer (the cache stores them
+        in ``common.row_width`` of them)."""
         return self.kv_rank + self.rope
 
     @property
@@ -282,17 +284,14 @@ def head(cfg: Config, params: dict, x):
 # ----------------------------------------------------------- step bodies
 
 
-def _rows(cfg: Config, layer_cache, pages):
-    """The latent rows of ``pages``, in order: [len(pages) * page, latent]."""
-    return common.page_rows(layer_cache, pages)
-
-
 def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
                  slots: int) -> dict:
     """The device state of this family's sequences: latent rows in pages,
-    in every layer, and nothing per slot."""
+    in every layer, each stored in whole lane tiles (``mla.qkv``), and
+    nothing per slot."""
     return {"pages": jax.ShapeDtypeStruct(
-        (cfg.layers, n_pages, page_tokens, cfg.latent), BF16)}
+        (cfg.layers, n_pages, page_tokens, common.row_width(cfg.latent)),
+        BF16)}
 
 
 def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
@@ -316,9 +315,10 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
         with jax.named_scope("mla"):
             h = rms_norm(x, lp["input_norm"], cfg.eps)
             qn, qr, lat = _qkv(cfg, lp, h, pos)
-            a = mla_prefill(cfg, lp, qn, qr, lat, seg,
-                            _rows(cfg, cache[i], prefix_pages), n_prefix,
-                            _rows(cfg, cache[i], cont_pages), n_cont)
+            a = mla_prefill(
+                cfg, lp, qn, qr, lat, seg,
+                common.layer_page_rows(cache, i, prefix_pages), n_prefix,
+                common.layer_page_rows(cache, i, cont_pages), n_cont)
             cache = cache.at[i, dest_page, dest_off].set(lat)
             x = x + a
         h = rms_norm(x, lp["post_norm"], cfg.eps)
@@ -341,16 +341,16 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
     cache = state["pages"]
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-    b = tokens.shape[0]
     held = jnp.zeros((3,), jnp.int32)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope("mla"):
             h = rms_norm(x, lp["input_norm"], cfg.eps)
             qn, qr, lat = _qkv(cfg, lp, h, pos)
             cache = cache.at[i, dest_page, dest_off].set(lat)
-            ctx = cache[i][page_table].reshape(b, -1, cfg.latent)
-            x = x + mla_decode(cfg, lp, qn, qr, ctx, ctx_len,
-                               _rows(cfg, cache[i], prefix_pages), n_prefix)
+            x = x + mla_decode(
+                cfg, lp, qn, qr,
+                common.layer_page_rows(cache, i, page_table), ctx_len,
+                common.layer_page_rows(cache, i, prefix_pages), n_prefix)
         h = rms_norm(x, lp["post_norm"], cfg.eps)
         y, n = _mlp(cfg, lp, h, live)
         x = x + y

@@ -26,7 +26,8 @@ untied head.
   state in place (``kda_decode``).
 * **MLA mixer**: models/lm/mla.py, as DeepSeek-V2's but with no rotation
   of ``q_r`` / ``k_r`` (``mla_use_nope``) and no query down-projection
-  (``q_lora_rank`` null); the cache row is the same 576 values.
+  (``q_lora_rank`` null); the cache row is the same 576 values, stored
+  640 wide.
 * **Expert layer**: models/lm/experts.py, as DeepSeek-V2's but with
   sigmoid scores, a selection bias (chosen by ``s + b``, weighted by
   ``s``), one group, renormalised weights times ``routed_scaling_factor``,
@@ -149,7 +150,8 @@ class Config:
 
     @property
     def latent(self) -> int:
-        """Values the cache holds per token and MLA layer."""
+        """The model's values per token and MLA layer (the cache stores
+        them in ``common.row_width`` of them)."""
         return self.kv_rank + self.rope
 
     @property
@@ -291,9 +293,10 @@ def param_count(cfg: Config) -> int:
 def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
                  slots: int) -> dict:
     """The device state of this family's sequences. ``pages``: latent rows
-    of the MLA layers. Per SLOT (and two rows more: row ``slots`` for rows
-    of a step that carry no sequence, row ``slots + 1`` the snapshot after
-    the shared prefix's last token) and KDA layer: ``kda``, the float32
+    of the MLA layers, each stored in whole lane tiles (``mla.qkv``). Per
+    SLOT (and two rows more: row ``slots`` for rows of a step that carry
+    no sequence, row ``slots + 1`` the snapshot after the shared
+    prefix's last token) and KDA layer: ``kda``, the float32
     matrix state of every head, and ``conv``, the last ``d_conv - 1``
     inputs of the three convolutions (``q | k | v``, taps side by side),
     each slot's row as whole bfloat16 tiles (``slot_rows.tiled``). A
@@ -303,7 +306,8 @@ def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
     n = len(cfg.kda_ids)
     return {
         "pages": jax.ShapeDtypeStruct(
-            (len(cfg.mla_ids), n_pages, page_tokens, cfg.latent), BF16),
+            (len(cfg.mla_ids), n_pages, page_tokens,
+             common.row_width(cfg.latent)), BF16),
         "kda": jax.ShapeDtypeStruct(
             (n, rows, cfg.kda_heads, cfg.kda_dim, cfg.kda_dim), F32),
         "conv": jax.ShapeDtypeStruct(
@@ -501,8 +505,8 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
             qn, qr, lat = mla.qkv(cfg, lp, h)
             x = x + mla.mla_prefill(
                 cfg, lp, qn, qr, lat, seg,
-                common.page_rows(pages[j], prefix_pages), n_prefix,
-                common.page_rows(pages[j], cont_pages), n_cont)
+                common.layer_page_rows(pages, j, prefix_pages), n_prefix,
+                common.layer_page_rows(pages, j, cont_pages), n_cont)
             pages = pages.at[j, dest_page, dest_off].set(lat)
         return x, pages
 
@@ -524,7 +528,6 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
     layers write the row's latent to its page and attend to the shared
     prefix (read once for all rows) and, through the table of its OWN
     pages, to its ``ctx_len`` own cached rows."""
-    b = tokens.shape[0]
 
     def kda_layer(lp, l, x, kda, conv):
         with jax.named_scope("kda"):
@@ -536,10 +539,10 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
             h = rms_norm(x, lp["input_norm"], cfg.eps)
             qn, qr, lat = mla.qkv(cfg, lp, h)
             pages = pages.at[j, dest_page, dest_off].set(lat)
-            ctx = pages[j][page_table].reshape(b, -1, cfg.latent)
             x = x + mla.mla_decode(
-                cfg, lp, qn, qr, ctx, ctx_len,
-                common.page_rows(pages[j], prefix_pages), n_prefix)
+                cfg, lp, qn, qr,
+                common.layer_page_rows(pages, j, page_table), ctx_len,
+                common.layer_page_rows(pages, j, prefix_pages), n_prefix)
         return x, pages
 
     with jax.named_scope("embed"):

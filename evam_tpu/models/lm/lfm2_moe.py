@@ -368,17 +368,6 @@ def _at(stack: dict, i):
     return jax.tree.map(lambda a: a[i], stack)
 
 
-def _page_rows(pages, j, ids):
-    """The cache rows of attention layer ``j``'s pages ``ids``, in order,
-    gathered out of the whole cache (``pages[j]`` first would copy the
-    layer's 105 MB): [..., len(ids) * page, width] (None where there are
-    no pages)."""
-    if ids is None:
-        return None
-    got = pages[j, ids]
-    return got.reshape(*ids.shape[:-1], -1, pages.shape[-1])
-
-
 def _layers(cfg: Config, params: dict, x, state, live, conv_layer,
             attn_layer):
     """Every layer in its order, as ONE ``lax.scan`` over the model's
@@ -461,8 +450,9 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
         with jax.named_scope("attn"):
             q, kv = attention.qkv(cfg, lp, h, pos)
             y = attention.attn_prefill(
-                cfg, lp, q, kv, seg, _page_rows(pages, j, prefix_pages),
-                n_prefix, _page_rows(pages, j, cont_pages), n_cont)
+                cfg, lp, q, kv, seg,
+                common.layer_page_rows(pages, j, prefix_pages), n_prefix,
+                common.layer_page_rows(pages, j, cont_pages), n_cont)
             pages = pages.at[j, dest_page, dest_off].set(kv)
         return y, pages
 
@@ -494,8 +484,9 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
             q, kv = attention.qkv(cfg, lp, h, pos)
             pages = pages.at[j, dest_page, dest_off].set(kv)
             y = attention.attn_decode(
-                cfg, lp, q, _page_rows(pages, j, page_table), ctx_len,
-                _page_rows(pages, j, prefix_pages), n_prefix)
+                cfg, lp, q,
+                common.layer_page_rows(pages, j, page_table), ctx_len,
+                common.layer_page_rows(pages, j, prefix_pages), n_prefix)
         return y, pages
 
     with jax.named_scope("embed"):

@@ -3,10 +3,16 @@
 models/lm/kimi_linear.py: position-free, the query projected directly).
 
 ``[c_kv ; k_r] = W_kva h``, ``c_kv = norm(c_kv)``; the cache holds ``[c_kv
-; k_r]`` per token and layer (``latent`` values); ``[k_nope ; v] = W_kvb
-c_kv`` per head; ``score = (q_nope . k_nope + q_r . k_r) * scale``.
+; k_r ; zeros]`` per token and layer: the model's ``latent`` values (576
+at the published widths) in a row of whole lane tiles
+(``common.row_width``: 640), so that the cache lies rows-minor on the chip
+and enters and leaves both step programs where it lies; ``[k_nope ; v] =
+W_kvb c_kv`` per head; ``score = (q_nope . k_nope + q_r . k_r) * scale``.
 ``W_kvb``'s key half is folded into the query and its value half into the
-output, so every key is read as a latent row: a decode step in two parts,
+output, so every key is read as a latent row: the absorbed query's rope
+part is followed by as many zeros as the stored row's, zeros against
+zeros, so a product over "the whole row" is the one over ``latent``; a
+decode step in two parts,
 all rows' queries against the shared prefix's rows in one product and each
 row against its own pages, merged by their softmax sums; a prefill chunk
 over prefix, continued and own rows in one kernel (ops/pallas_mla.py).
@@ -49,10 +55,18 @@ def rope(x, cos, sin):
                      axis=-1).reshape(x.shape)
 
 
+def _tail(cfg, rope_part):
+    """A stored row's last lane tile: ``[rope_part | zeros]``, what a row
+    of ``common.row_width(latent)`` values holds behind its ``kv_rank``."""
+    pad = common.row_width(cfg.latent) - cfg.latent
+    return jnp.pad(rope_part, [(0, 0)] * (rope_part.ndim - 1) + [(0, pad)])
+
+
 def qkv(cfg, lp: dict, x, cos_sin=None):
     """Per token: the query ``(q_nope [T,h,nope], q_rope [T,h,rope])`` and
-    the latent row ``[c_kv ; k_r]`` that the cache holds; ``q_rope`` and
-    ``k_r`` rotated by ``cos_sin`` where it is given."""
+    the row ``[c_kv ; k_r ; zeros]`` that the cache holds
+    (``common.row_width(latent)`` wide); ``q_rope`` and ``k_r`` rotated
+    by ``cos_sin`` where it is given."""
     t = x.shape[0]
     if cfg.q_rank:
         c_q = rms_norm(mm(x, lp["q_a"]), lp["q_a_norm"], cfg.eps)
@@ -68,7 +82,7 @@ def qkv(cfg, lp: dict, x, cos_sin=None):
         cos, sin = cos_sin
         q_rope = rope(q_rope, cos[:, None], sin[:, None]).astype(BF16)
         k_r = rope(k_r, cos, sin).astype(BF16)
-    return q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
+    return q_nope, q_rope, jnp.concatenate([c_kv, _tail(cfg, k_r)], axis=-1)
 
 
 def kv_b(cfg, lp: dict):
@@ -79,14 +93,16 @@ def kv_b(cfg, lp: dict):
 
 
 def absorb_q(cfg, w_uk, q_nope, q_rope):
-    """The query in the cache's own space: [T, h, kv_rank + rope]."""
+    """The query in the cache's own space, in a stored row's two parts:
+    against ``c_kv`` [T, h, kv_rank] and against the row's last lane tile
+    [T, h, width - kv_rank], zeros behind the rope part."""
     q_lat = es("thd,hcd->thc", q_nope, w_uk).astype(BF16)
-    return jnp.concatenate([q_lat, q_rope], axis=-1)
+    return q_lat, _tail(cfg, q_rope)
 
 
 def _softmax_sums(cfg, score_expr, value_expr, q, rows, visible):
-    """``common.softmax_sums`` over latent rows: a row's key is the whole
-    row, its value the row's ``c_kv``."""
+    """``common.softmax_sums`` over stored rows: a row's key is the whole
+    row (its zeros meet the query's), its value the row's ``c_kv``."""
     return common.softmax_sums(cfg.softmax_scale, score_expr, value_expr, q,
                                rows, rows[..., :cfg.kv_rank], visible)
 
@@ -94,16 +110,17 @@ def _softmax_sums(cfg, score_expr, value_expr, q, rows, visible):
 def mla_decode(cfg, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
                n_prefix):
     """One new token per row, absorbed form, its softmax in two parts.
-    OWN: each row against its own cached rows ``ctx`` [B, T, latent]
-    (the new token's row among them), visible below ``ctx_len`` [B].
+    OWN: each row against its own cached rows ``ctx`` [B, T, width]
+    (stored rows, ``qkv``; the new token's among them), visible below
+    ``ctx_len`` [B].
     SHARED: the queries of all rows and heads against the prefix rows
-    ``prefix`` [Tp, latent] (visible below ``n_prefix``), which every
+    ``prefix`` [Tp, width] (visible below ``n_prefix``), which every
     row shares and which are read once: one dense product. The parts
     are merged by their softmax sums in float32 (the arithmetic of the
     one softmax over prefix and own rows) before ``W_uv``. ``prefix``
     may be None: the own part alone."""
     w_uk, w_uv = kv_b(cfg, lp)
-    q = absorb_q(cfg, w_uk, q_nope, q_rope)
+    q = jnp.concatenate(absorb_q(cfg, w_uk, q_nope, q_rope), axis=-1)
     own = jnp.arange(ctx.shape[1])[None, None, :] < ctx_len[:, None, None]
     sums = _softmax_sums(cfg, "bhc,btc->bht", "bht,btc->bhc", q, ctx, own)
     shared = None
@@ -119,16 +136,18 @@ def mla_decode(cfg, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
 def mla_prefill(cfg, lp: dict, q_nope, q_rope, lat, seg, prefix,
                 n_prefix, cont, n_cont):
     """A packed chunk, absorbed form throughout: every (token, head) is
-    one query row over ONE list of latent rows: the shared prefix rows
-    ``prefix`` [Tp, latent] (visible below ``n_prefix``), the earlier
-    rows ``cont`` [Tc, latent] of the sequence that continues in this
+    one query row over ONE list of stored rows (``qkv``): the shared prefix
+    rows ``prefix`` [Tp, width] (visible below ``n_prefix``), the earlier
+    rows ``cont`` [Tc, width] of the sequence that continues in this
     chunk (below ``n_cont``, to segment 0 only) and the chunk's own rows
     ``lat`` (a token sees its segment's, up to itself). ``prefix`` and
     ``cont`` may be None. The scores stay on the chip
-    (ops/pallas_mla.py)."""
+    (ops/pallas_mla.py), which takes the rope part as it lies: the row's
+    last lane tile, zeros included."""
     t = lat.shape[0]
     w_uk, w_uv = kv_b(cfg, lp)
-    q = absorb_q(cfg, w_uk, q_nope, q_rope)
+    q_lat, q_tail = (q.reshape(t * cfg.heads, -1)
+                     for q in absorb_q(cfg, w_uk, q_nope, q_rope))
     keys = jnp.concatenate(
         [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
     bounds, b0 = common.chunk_bounds(
@@ -136,10 +155,8 @@ def mla_prefill(cfg, lp: dict, q_nope, q_rope, lat, seg, prefix,
         0 if cont is None else cont.shape[0])
     attend = (pallas_mla.latent_attention if common.on_tpu()
               else pallas_mla.latent_attention_xla)
-    q = q.reshape(t * cfg.heads, cfg.latent)
     o_lat = attend(
-        q[:, :cfg.kv_rank], q[:, cfg.kv_rank:],
-        keys[:, :cfg.kv_rank], keys[:, cfg.kv_rank:],
+        q_lat, q_tail, keys[:, :cfg.kv_rank], keys[:, cfg.kv_rank:],
         jnp.repeat(bounds, cfg.heads, axis=0),
         scale=cfg.softmax_scale, b0=b0)
     o = es("thc,hcv->thv", o_lat.reshape(t, cfg.heads, cfg.kv_rank), w_uv)

@@ -14,7 +14,10 @@ flash attention), so the scores never leave the chip.
 
 Every query row is ``[latent | rope]`` (the query folded through
 ``W_uk``, and its rope part); every key row is a cache row ``[c_kv |
-k_r]``; the value of a key row is its ``c_kv``. All heads share the keys,
+k_r]``; the value of a key row is its ``c_kv``. The rope parts come as
+they lie in a stored row (models/lm/mla.py): the row's last lane tile, the
+rope values and zeros behind them, 128 wide at the published widths, so
+nothing is padded to lanes here. All heads share the keys,
 so the rows of all heads of all tokens are one long list of queries. Which
 keys a query row may see is three half-open intervals of key positions,
 ``[0, a) | [b0, b1) | [c0, c1)``, per row (``bounds`` [rows, 4] = a, b1,
@@ -87,7 +90,8 @@ def latent_attention(q_lat, q_rope, ckv, kr, bounds, *, scale, b0,
                      block_q=1024, block_k=512, interpret=False):
     """``q_lat`` [R, C], ``q_rope`` [R, P], ``ckv`` [S, C], ``kr`` [S, P],
     ``bounds`` [R, 4] int32 -> [R, C] (the attention-weighted ``ckv``).
-    R and S are padded here to whole blocks, P to 128 lanes."""
+    R and S are padded here to whole blocks; P is taken as it comes (a
+    stored row's last lane tile: 128)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -96,10 +100,10 @@ def latent_attention(q_lat, q_rope, ckv, kr, bounds, *, scale, b0,
     block_q = min(block_q, -(-r // 16) * 16)
     block_k = min(block_k, -(-s // 128) * 128)
     rp, sp = -(-r // block_q) * block_q, -(-s // block_k) * block_k
-    p = -(-q_rope.shape[1] // 128) * 128
+    p = q_rope.shape[1]
 
-    def pad(x, rows, cols):
-        return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+    def pad(x, rows):
+        return jnp.pad(x, ((0, rows - x.shape[0]), (0, 0)))
 
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, block_k=block_k, b0=b0),
@@ -121,8 +125,8 @@ def latent_attention(q_lat, q_rope, ckv, kr, bounds, *, scale, b0,
             vmem_limit_bytes=64 * 1024 * 1024),
         name="mla_latent_attention",
         interpret=interpret,
-    )(pad(bounds, rp, 4), pad(q_lat, rp, c), pad(q_rope, rp, p),
-      pad(ckv, sp, c), pad(kr, sp, p))
+    )(pad(bounds, rp), pad(q_lat, rp), pad(q_rope, rp), pad(ckv, sp),
+      pad(kr, sp))
     return out[:r]
 
 

@@ -32,6 +32,7 @@ from evam_tpu.engine.generate import (
     next_step_kind,
 )
 from evam_tpu.engine.pages import PagePool
+from evam_tpu.models.lm import common, family
 from evam_tpu.models.lm import deepseek_v2 as lm
 from evam_tpu.models.lm.presets import DEEPSEEK_V2_PUBLISHED, PRESETS
 
@@ -327,9 +328,13 @@ def test_two_part_decode_attention_is_the_one_softmax(case):
         return np.asarray(a.astype(lm.F32), np.float64)
 
     def rows_of(ids):
-        return f32(cache)[ids].reshape(-1, cfg.latent)
+        return f32(cache)[ids].reshape(-1, width)[:, :cfg.latent]
 
-    cache = bf16(rng.normal(size=(3 + b * own_pages, page, cfg.latent)))
+    # rows as the cache holds them: the latent values, then zeros
+    width = common.row_width(cfg.latent)
+    cache = bf16(np.pad(
+        rng.normal(size=(3 + b * own_pages, page, cfg.latent)),
+        ((0, 0), (0, 0), (0, width - cfg.latent))))
     q_nope = bf16(rng.normal(size=(b, cfg.heads, cfg.nope)))
     q_rope = bf16(rng.normal(size=(b, cfg.heads, cfg.rope)))
     table = np.zeros((b, own_pages), np.int32)
@@ -342,8 +347,9 @@ def test_two_part_decode_attention_is_the_one_softmax(case):
     pages = None if prefix_pages is None else np.asarray(prefix_pages)
     got = lm.mla_decode(
         cfg, lp, q_nope, q_rope,
-        cache[table].reshape(b, -1, cfg.latent), jnp.asarray(ctx_len),
-        lm._rows(cfg, cache, pages), n_prefix)
+        common.layer_page_rows(cache[None], 0, jnp.asarray(table)),
+        jnp.asarray(ctx_len),
+        common.layer_page_rows(cache[None], 0, pages), n_prefix)
     assert got.shape == (b, cfg.hidden)
 
     w = f32(lp["kv_b"]).reshape(cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
@@ -386,6 +392,42 @@ def test_prefill_then_decode_matches_the_reference(engine, length):
     assert not problems, (problems, stats)
     assert stats["flipped"] == 0 and stats["max"] < 0.2
     assert out["prefix_tokens"] == 16
+
+
+@pytest.mark.parametrize("preset,width", [
+    ("deepseek_v2_ep8", 640), ("jamba2_3b", 256), ("kimi_linear_ep4", 640),
+    ("lfm2_moe_ep2", 1024)])
+def test_page_cache_rows_are_whole_lane_tiles(preset, width):
+    """Every family's page cache at its deployment's widths: a row the
+    compiler keeps rows-minor on the chip (``common.row_width``; the two
+    latent families' 576 values are stored 640 wide)."""
+    fam = family(PRESETS[preset]["model_type"])
+    cfg = fam.Config.from_dict(PRESETS[preset])
+    pages = fam.state_shapes(cfg, 401, 128, 128)["pages"]
+    assert pages.shape[1:3] == (401, 128)
+    assert pages.shape[-1] == width == common.row_width(width)
+    if hasattr(cfg, "latent"):
+        assert cfg.latent == 576 and width == common.row_width(cfg.latent)
+
+
+def test_stored_rows_end_in_zeros_and_the_logits_are_the_references(engine):
+    """A prefill chunk and three decode steps through the engine: the
+    cache holds ``[c_kv | k_r | zeros]`` (every written row's columns
+    past the model's ``latent`` values exactly zero), and the wider rows
+    and queries change no logit."""
+    cfg = engine.cfg
+    prompt = _prompt(31, 11)
+    out = _generate(engine, prompt, n=4)
+    problems, stats = lm_compare.compare_logits(
+        out, *_ref_logits(engine.prefix, prompt, out, margins=True))
+    assert not problems, (problems, stats)
+    assert stats["flipped"] == 0 and stats["max"] < 0.2
+    pages = np.asarray(engine._state["pages"].astype(jnp.float32))
+    assert pages.shape[-1] == common.row_width(cfg.latent) > cfg.latent
+    written = np.abs(pages[..., :cfg.latent]).sum(axis=-1) > 0
+    # the prefix's 16 rows and the generation's 11 + 3, in every layer
+    assert (written.sum(axis=(1, 2)) >= 16 + 14).all()
+    assert not pages[..., cfg.latent:].any()
 
 
 def test_compiled_programs_constant_after_warmup(engine):
@@ -923,11 +965,14 @@ def test_the_describe_cells_streams_against_the_modelled_capacity(
 # ------------------------------------------------------ the Pallas kernel
 
 
+@pytest.mark.parametrize("zeros", [0, 64])
 @pytest.mark.parametrize("blocks", [(32, 128), (96, 256)])
-def test_latent_attention_kernel_matches_its_xla_twin(blocks):
+def test_latent_attention_kernel_matches_its_xla_twin(blocks, zeros):
     """ops/pallas_mla.py in the interpreter against the same arithmetic
     through XLA: three visible intervals per row, rows that see nothing,
-    rows and keys that do not fill whole blocks."""
+    rows and keys that do not fill whole blocks. ``zeros``: the kernel is
+    handed the rope parts as they lie in a stored row, that many zero
+    columns behind them (128 wide), the twin the unpadded ones."""
     from evam_tpu.ops.pallas_mla import (
         latent_attention,
         latent_attention_xla,
@@ -946,7 +991,9 @@ def test_latent_attention_kernel_matches_its_xla_twin(blocks):
     b[6] = [0, 100, 150, 150]      # three empty intervals
     b[7] = [100, 100, 199, 200]    # the whole prefix and one own row
     want = latent_attention_xla(*args, jnp.asarray(b), scale=0.1, b0=100)
-    got = latent_attention(*args, jnp.asarray(b), scale=0.1, b0=100,
+    as_stored = [jnp.pad(a, ((0, 0), (0, zeros))) if a.shape[1] == p else a
+                 for a in args]
+    got = latent_attention(*as_stored, jnp.asarray(b), scale=0.1, b0=100,
                            block_q=blocks[0], block_k=blocks[1],
                            interpret=True)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)

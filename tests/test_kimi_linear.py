@@ -25,7 +25,7 @@ from benchmark.reference import kimi_linear_plain as ref
 from benchmark.reference.compare import check_schema
 from evam_tpu.config.settings import LMSettings, Settings
 from evam_tpu.engine.generate import GenerateEngine, GenerateSizes
-from evam_tpu.models.lm import experts, family
+from evam_tpu.models.lm import common, experts, family
 from evam_tpu.models.lm import kimi_linear as lm
 from evam_tpu.models.lm.presets import KIMI_LINEAR_PUBLISHED, PRESETS
 from evam_tpu.ops import pallas_kda as pk
@@ -196,7 +196,8 @@ def test_parameter_count_matches_the_benchmarks_arithmetic():
         shapes["model"])
     assert 3.76e9 < lm.param_count(cfg) < 3.78e9
     state = lm.state_shapes(cfg, 401, 128, 128)
-    assert state["pages"].shape == (2, 401, 128, 576)
+    # the 576 values of a latent row stored in whole lane tiles
+    assert state["pages"].shape == (2, 401, 128, 640)
     assert state["kda"].shape == (6, 130, 32, 128, 128)
     assert state["kda"].dtype == jnp.float32
     # a slot's 3 * 12288 taps as 16 rows: whole bfloat16 tiles a slot
@@ -430,6 +431,24 @@ def test_prefill_then_decode_matches_the_reference(engine, length):
         out, _ref_logits(engine.prefix, prompt, out))
     assert not problems, (problems, stats)
     assert out["prefix_tokens"] == 16
+
+
+def test_stored_rows_end_in_zeros_and_the_logits_are_the_references(engine):
+    """A prefill chunk and three decode steps through the engine: the MLA
+    layer's cache holds ``[c_kv | k_r | zeros]`` (every written row's
+    columns past the model's ``latent`` values exactly zero), and the
+    wider rows and queries change no logit."""
+    cfg = engine.cfg
+    prompt = _prompt(31, 11)
+    out = _generate(engine, prompt, n=4)
+    problems, stats = _compare(out, _ref_logits(engine.prefix, prompt, out))
+    assert not problems, (problems, stats)
+    pages = np.asarray(engine._state["pages"].astype(jnp.float32))
+    assert pages.shape[-1] == common.row_width(cfg.latent) > cfg.latent
+    written = np.abs(pages[..., :cfg.latent]).sum(axis=-1) > 0
+    # the prefix's 16 rows and the generation's 11 + 3, in every MLA layer
+    assert (written.sum(axis=(1, 2)) >= 16 + 14).all()
+    assert not pages[..., cfg.latent:].any()
 
 
 def test_compiled_programs_constant_after_warmup(engine):

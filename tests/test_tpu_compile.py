@@ -34,8 +34,8 @@ def test_latent_attention_kernel_compiles_at_published_widths(
 
     compiled = jax.jit(
         lambda *a: latent_attention(*a, scale=0.1147, b0=2048)).lower(
-        s((rows, 512), jnp.bfloat16), s((rows, 64), jnp.bfloat16),
-        s((keys, 512), jnp.bfloat16), s((keys, 64), jnp.bfloat16),
+        s((rows, 512), jnp.bfloat16), s((rows, 128), jnp.bfloat16),
+        s((keys, 512), jnp.bfloat16), s((keys, 128), jnp.bfloat16),
         s((rows, 4), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -196,40 +196,16 @@ def test_conv_decode_kernel_compiles_at_published_widths(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_lfm2_step_programs_compile_at_published_widths(one_chip,
-                                                        monkeypatch, program):
-    """Both step programs of the LFM2-MoE family at the deployment's
-    sizes: all 24 layers in one loop body, so each kernel is in the
-    program ONCE; the 7.75 GB of stacked expert tensors are read where
-    they lie (temporaries stay small); and a decode step holds no scatter
-    and no whole-array copy of the slot state: the taps move through
-    ``conv_decode_rows``."""
-    import re
-
+def _compile_step(lm, cfg, params, one_chip, program):
+    """A family's ``decode`` step at 64 rows or its ``prefill`` chunk of
+    512 tokens at the deployment's sizes (401 pages of 128, 128 slots, a
+    prefix of 16 pages), the state donated, for the described chip:
+    ``(compiled, the state's shapes)``."""
     import numpy as np
-
-    from evam_tpu.models.lm import common, lfm2_moe as lm
-    from evam_tpu.models.lm.presets import PRESETS
-
-    monkeypatch.setattr(common, "TARGET_TPU", True)
-    cfg = lm.Config.from_dict(PRESETS["lfm2_moe_ep2"])
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def stack(n, shapes, held=()):
-        return {k: s((n, *(held if k.startswith("expert_") else ()), *v))
-                for k, v in shapes.items()}
-
-    params = {
-        "embed": s((cfg.vocab, cfg.hidden)), "final_norm": s((cfg.hidden,)),
-        "norms": stack(cfg.layers, lm.norm_shapes(cfg)),
-        "conv": stack(len(cfg.conv_ids), lm.conv_shapes(cfg)),
-        "attn": stack(len(cfg.attn_ids), lm.attn_shapes(cfg)),
-        "dense": stack(cfg.n_dense, lm.dense_shapes(cfg)),
-        "moe": stack(len(cfg.moe_ids), lm.moe_shapes(cfg), (cfg.n_held,)),
-    }
     state = {k: s(v.shape, v.dtype)
              for k, v in lm.state_shapes(cfg, 401, 128, 128).items()}
     shared = np.arange(1, 17, dtype=np.int32)
@@ -258,6 +234,42 @@ def test_lfm2_step_programs_compile_at_published_widths(one_chip,
                 *[s((n_seg,), i32)] * 3)
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, state, *args).compile()
+    return compiled, state
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_lfm2_step_programs_compile_at_published_widths(one_chip,
+                                                        monkeypatch, program):
+    """Both step programs of the LFM2-MoE family at the deployment's
+    sizes: all 24 layers in one loop body, so each kernel is in the
+    program ONCE; the 7.75 GB of stacked expert tensors are read where
+    they lie (temporaries stay small); and a decode step holds no scatter
+    and no whole-array copy of the slot state: the taps move through
+    ``conv_decode_rows``."""
+    import re
+
+    from evam_tpu.models.lm import common, lfm2_moe as lm
+    from evam_tpu.models.lm.presets import PRESETS
+
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+    cfg = lm.Config.from_dict(PRESETS["lfm2_moe_ep2"])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def stack(n, shapes, held=()):
+        return {k: s((n, *(held if k.startswith("expert_") else ()), *v))
+                for k, v in shapes.items()}
+
+    params = {
+        "embed": s((cfg.vocab, cfg.hidden)), "final_norm": s((cfg.hidden,)),
+        "norms": stack(cfg.layers, lm.norm_shapes(cfg)),
+        "conv": stack(len(cfg.conv_ids), lm.conv_shapes(cfg)),
+        "attn": stack(len(cfg.attn_ids), lm.attn_shapes(cfg)),
+        "dense": stack(cfg.n_dense, lm.dense_shapes(cfg)),
+        "moe": stack(len(cfg.moe_ids), lm.moe_shapes(cfg), (cfg.n_held,)),
+    }
+    compiled, _ = _compile_step(lm, cfg, params, one_chip, program)
     text = compiled.as_text()
     calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
     for name, n in (("expert_gate_up", 1), ("expert_down", 1),
@@ -275,6 +287,60 @@ def test_lfm2_step_programs_compile_at_published_widths(one_chip,
         assert not re.search(r"= bf16\[(1,)?130,16,256\]\S* scatter\(", text)
     # no layer's pages sliced out (105 MB), no scores materialised
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("preset", ["deepseek_v2_ep8", "kimi_linear_ep4"])
+def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
+                                               program):
+    """Both step programs of both latent families at the deployment's
+    sizes, the state donated: the page cache, its rows stored in whole
+    lane tiles (``common.row_width``: 640 for the model's 576), enters
+    and leaves rows-minor and no copy turns it tokens-minor (at 576 the
+    compiler kept it tokens-minor and each program copied all of it to
+    rows-minor and back, every step: 355 MB each way in DeepSeek's), and
+    every layer gathers its pages out of the whole array.
+    DeepSeek's programs (six layers unrolled) hold no copy of the whole
+    cache at all; Kimi's two MLA layers sit in branches of a
+    ``lax.switch`` inside a ``lax.scan``, in whose branches the compiler
+    still copies the cache, same layout in and out (PERF.md section 7)."""
+    import re
+
+    from evam_tpu.models.lm import common, family
+    from evam_tpu.models.lm.presets import PRESETS
+
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+    lm = family(PRESETS[preset]["model_type"])
+    cfg = lm.Config.from_dict(PRESETS[preset])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(lambda: lm.make_params(cfg)))
+    compiled, state = _compile_step(lm, cfg, params, one_chip, program)
+    text = compiled.as_text()
+    pages = state["pages"].shape
+    assert pages[-1] == 640 and cfg.latent == 576
+    cache = re.escape("bf16[%s]" % ",".join(map(str, pages)))
+    entry = re.findall(rf"= {cache}(\S*) parameter\(", text)
+    assert entry and all(e.startswith("{3,2,1,0") for e in entry), entry
+    copies = re.findall(rf"= {cache}(\S*) copy\(", text)
+    assert not [c for c in copies if c.startswith("{2,3,1,0")], copies
+    # nor is a layer taken out of it before its pages are gathered
+    # (``common.layer_page_rows``): six of those are the whole cache too
+    layer = re.escape("bf16[%s]" % ",".join(map(str, pages[1:])))
+    assert not re.search(rf"= {layer}", text)
+    if preset == "deepseek_v2_ep8":
+        assert not copies, copies
+        # 74 and 228 MiB; with the 576-value row's two copies 434 and 626
+        assert compiled.memory_analysis().temp_size_in_bytes < {
+            "decode": 256, "prefill": 400}[program] << 20
+    else:
+        # left, rows-minor in and out: in the switch's branches 3 and 4,
+        # which hand the cache through untouched, and in a chunk's
+        # branch 5 (576 wide: these and the layout's two at the entry)
+        assert len(copies) == {"decode": 2, "prefill": 3}[program], copies
 
 
 @pytest.mark.parametrize("heads,rows,keys,dim", [
