@@ -17,11 +17,18 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   chunk of at most ``chunk_tokens`` prompt tokens of at most
   ``max_segments`` sequences (segment ids keep them apart; a prompt may
   continue in the next chunk, as its first segment). While sequences
-  decode, a prefill step runs when the waiting prompts fill a whole
-  chunk, or when they have already waited one decode step, and at most
-  ``MAX_PREFILL_RUN`` chunks run between two decode steps: chunks run
-  full, decoding is held up for two chunks at a time, never more, and
-  a lone prompt waits one decode step at most.
+  decode, prefill is PACED to the decoding it feeds (``next_step_kind``):
+  every decode step earns the prompt tokens that its sequences, each
+  replaced as it ends, will bring back over their decode steps, a full
+  chunk runs when that credit covers it, and at most ``MAX_PREFILL_RUN``
+  chunks run between two decode steps, which is also the most credit
+  that is kept. So under load chunks run full and evenly spaced,
+  generations that were submitted together do not stay together, and what
+  the engine completes a second is even. A part-full chunk runs for
+  prompts that a
+  decode step passed over when the sequences in the engine would not
+  fill it within ``PART_CHUNK_PATIENCE`` decode steps, or have not: a
+  lone prompt waits one decode step, and no prompt more than that many.
 * The model FAMILY is chosen at construction from the installed config's
   ``model_type`` (models/lm ``family``) and says what device state its
   sequences have (``state_shapes``): a pytree that both programs donate
@@ -88,13 +95,21 @@ from evam_tpu.sched.shedder import Shedder
 
 log = get_logger("engine.generate")
 
-#: prefill chunks that may run between two decode steps. With one (strict
+#: prefill chunks that may run between two decode steps, and the chunks of
+#: prefill credit that decode steps can save up. With one (strict
 #: alternation) a chunk ran as soon as one prompt waited, part-empty at the
-#: price of a full one; with two a chunk can wait until it is full (505 of
-#: 512 tokens a chunk, +1.2 frames/s). It does NOT fill the slots: the
-#: runner decides how many generations reach the engine (PERF.md section
-#: 6, PR 28).
+#: price of a full one; with two a chunk can wait until it is full. It
+#: does NOT fill the slots: the runner decides how many generations reach
+#: the engine (PERF.md section 6, PR 28).
 MAX_PREFILL_RUN = 2
+
+#: decode steps that waiting prompts let pass for their chunk to fill, if
+#: the sequences in the engine can fill it in as many. Until PR 42 it was
+#: one, and a full chunk ran at once: under a closed loop of equal
+#: generations the bursts the streams had started in never dispersed, and
+#: 11-14 % of a chunk ran empty, more or less by their phases (PERF.md
+#: section 6, PR 42). With few sequences it is one still.
+PART_CHUNK_PATIENCE = 12
 
 #: decode programs: one every ``slots / DECODE_LADDER`` rows, up to the
 #: slots. A decode step costs about 7 ms + 0.2 ms a row of its BUCKET on
@@ -105,21 +120,71 @@ DECODE_LADDER = 8
 
 
 def next_step_kind(waiting: int, decoding: bool, prefill_run: int,
-                   passed_over: bool, chunk_tokens: int) -> str | None:
+                   passed_over: int, chunk_tokens: int,
+                   credit: float = float("inf"),
+                   owed: float = 0.0) -> str | None:
     """The engine thread's next step. ``waiting``: prompt tokens not yet
     prefilled; ``prefill_run``: prefill steps since the last decode step;
-    ``passed_over``: that decode step ran while prompts waited. Decode,
-    unless prompts wait and nothing decodes, or they fill a chunk or
-    were passed over and fewer than ``MAX_PREFILL_RUN`` chunks ran since
-    the last decode step."""
+    ``passed_over``: decode steps that ran since while prompts waited;
+    ``owed``: the prompt tokens a decode step earns (over the sequences in
+    the engine, each one's prompt over its decode steps: what they bring
+    back a step when each is replaced as it ends); ``credit``: what the
+    decode steps have earned and no chunk has spent. Decode, unless
+    prompts wait and nothing decodes, or fewer than ``MAX_PREFILL_RUN``
+    chunks ran since the last decode step and either the prompts fill a
+    chunk that the credit covers, or they were passed over and a full
+    chunk is more than ``PART_CHUNK_PATIENCE`` decode steps away or that
+    many have passed."""
     if not waiting:
         return "decode" if decoding else None
     if not decoding:
         return "prefill"
-    if prefill_run < MAX_PREFILL_RUN and (passed_over
-                                          or waiting >= chunk_tokens):
+    if prefill_run >= MAX_PREFILL_RUN:
+        return "decode"
+    if waiting >= chunk_tokens:
+        return "prefill" if credit >= chunk_tokens else "decode"
+    if passed_over and (passed_over >= PART_CHUNK_PATIENCE
+                        or chunk_tokens - waiting
+                        > PART_CHUNK_PATIENCE * owed):
         return "prefill"
     return "decode"
+
+
+@dataclasses.dataclass
+class PrefillPace:
+    """What the engine thread keeps between steps for ``next_step_kind``:
+    prefill steps since the last decode step, the decode steps since that
+    passed waiting prompts over, and the prompt tokens that decode steps
+    have earned and no chunk has spent (at most ``MAX_PREFILL_RUN``
+    chunks' worth, which is where it starts)."""
+
+    chunk_tokens: int
+    prefill_run: int = 0
+    passed_over: int = 0
+    credit: float = dataclasses.field(init=False)
+
+    def __post_init__(self) -> None:
+        self.credit = float(MAX_PREFILL_RUN * self.chunk_tokens)
+
+    def next(self, waiting: int, decoding: bool, owed: float) -> str | None:
+        return next_step_kind(waiting, decoding, self.prefill_run,
+                              self.passed_over, self.chunk_tokens,
+                              self.credit, owed)
+
+    def ran(self, kind: str, tokens: int, waiting: bool,
+            owed: float) -> None:
+        """A step of ``kind`` was dispatched: a chunk of ``tokens`` prompt
+        tokens, or a decode step that left prompts ``waiting`` or none and
+        earned ``owed``."""
+        if kind == "prefill":
+            self.prefill_run += 1
+            self.passed_over = 0
+            self.credit = max(0.0, self.credit - tokens)
+        else:
+            self.prefill_run = 0
+            self.passed_over = self.passed_over + 1 if waiting else 0
+            self.credit = min(float(MAX_PREFILL_RUN * self.chunk_tokens),
+                              self.credit + owed)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,6 +251,9 @@ class _Step:
     t_dispatch: float
     tokens: int
     rows_read: int
+    #: of ``rows_read``, the rows a layer under the family's window read
+    #: (all of them for a family without one)
+    window_read: int
     #: slot states the step read and wrote (decode rows; a chunk's
     #: segments), and of a chunk's segments those begun from the prefix
     #: snapshot; both 0 for a family that keeps no slot state
@@ -213,6 +281,9 @@ class GenerateEngine:
         self.sizes = sz = sizes or GenerateSizes()
         #: a chunk's segments start at multiples of this many tokens
         self._align = self._lm.SEGMENT_ALIGN
+        #: the positions a window layer sees (None: every layer of the
+        #: family sees everything earlier)
+        self._window = getattr(self.cfg, "window", None)
         if sz.chunk_tokens % (self._align * sz.max_segments):
             raise ValueError(
                 f"a chunk of {sz.chunk_tokens} tokens is not "
@@ -261,10 +332,7 @@ class GenerateEngine:
         self._prefilling: deque[_Seq] = deque()
         self._decoding: list[_Seq] = []
         self._inflight: deque[_Step] = deque()
-        #: prefill steps since the last decode step, and whether that
-        #: decode step passed waiting prompts over
-        self._prefill_run = 0
-        self._passed_over = False
+        self._pace = PrefillPace(sz.chunk_tokens)
         self._t_free = time.perf_counter()
         self._step_started: float | None = None
         self._seen: set[str] = set()
@@ -428,24 +496,29 @@ class GenerateEngine:
         # every program LOADED as in service: every row a token of its
         # own (``_allocate``'s last ids: the held experts are reached),
         # written to the null page; a chunk's segments start from the
-        # snapshot and end in the null row. Once to compile, once more
-        # for the capacity model's step times.
+        # snapshot and end in the null row. Once to compile, twice more
+        # for the capacity model's step times, of which the lesser counts:
+        # ``decode:<slots>`` may never run in service, so one run held up
+        # here (another engine warms beside this one) would stand for
+        # good, and admission refuse streams the engine has room for.
         n = sz.chunk_tokens
         per = -(-n // sz.max_segments)
         chunk = (np.arange(n) % self.cfg.vocab, np.arange(n) // per,
                  len(self.prefix) + np.arange(n) % per,
                  np.arange(n) % sz.page_tokens)
-        for timed in (False, True):
-            for b in self.buckets:
+        steps = [(f"decode:{b}", lambda b=b: self._dispatch_decode_raw(
+            [(slot, 0, [0]) for slot in range(b)], b, []))
+            for b in self.buckets]
+        steps.append(("prefill", lambda: self._dispatch_prefill_raw(
+            *chunk, len(self.prefix), None, 0, [], [])))
+        for run in range(3):
+            for key, dispatch in steps:
                 t0 = time.perf_counter()
-                self._harvest(self._dispatch_decode_raw(
-                    [(slot, 0, [0]) for slot in range(b)], b, []),
-                    count=False)
-                self._program_s[f"decode:{b}"] = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            self._harvest(self._dispatch_prefill_raw(
-                *chunk, len(self.prefix), None, 0, [], []), count=False)
-            self._program_s["prefill"] = time.perf_counter() - t0
+                self._harvest(dispatch(), count=False)
+                took = time.perf_counter() - t0
+                if run:
+                    self._program_s[key] = min(
+                        self._program_s.get(key, took), took)
 
     def stop(self) -> None:
         self._stop.set()
@@ -576,12 +649,8 @@ class GenerateEngine:
                 spans.to("step")
                 step = (self._dispatch_prefill() if kind == "prefill"
                         else self._dispatch_decode())
-                if kind == "prefill":
-                    self._prefill_run += 1
-                    self._passed_over = False
-                else:
-                    self._prefill_run = 0
-                    self._passed_over = bool(self._prefilling)
+                self._pace.ran(kind, step.tokens, bool(self._prefilling),
+                               self._owed())
                 self._inflight.append(step)
                 while len(self._inflight) > 1:
                     self._harvest(self._inflight.popleft())
@@ -595,11 +664,16 @@ class GenerateEngine:
     def _has_pending(self) -> bool:
         return any(self._pending.values()) or bool(self._cancel_streams)
 
+    def _owed(self) -> float:
+        """Prompt tokens a decode step earns: each sequence in the engine
+        brings its prompt back once in the decode steps it rides."""
+        return sum(len(s.prompt) / max(1, s.max_new - 1)
+                   for s in (*self._prefilling, *self._decoding))
+
     def _next_kind(self) -> str | None:
-        return next_step_kind(
+        return self._pace.next(
             sum(len(s.prompt) - s.n_prefilled for s in self._prefilling),
-            bool(self._decoding), self._prefill_run, self._passed_over,
-            self.sizes.chunk_tokens)
+            bool(self._decoding), self._owed())
 
     def _take_cancels(self) -> None:
         with self._lock:
@@ -753,10 +827,13 @@ class GenerateEngine:
         for i, row in enumerate(segs):
             per_seg[:, i] = row
         # rows of the cache the chunk reads, per layer: the prefix once
-        # (all its tokens share it) and one sequence's earlier rows
+        # (all its tokens share it) and one sequence's earlier rows; a
+        # window layer the last of them that the chunk's first token sees
+        cached = (n_prefix + n_cont) if n else 0
+        seen = min(cached, self._window - 1) if self._window else cached
         return self._run(
             "prefill", "prefill", self._prefill, (mat, aux), tokens=live,
-            rows_read=(n_prefix + n_cont) if n else 0, takers=takers,
+            rows_read=cached, takers=takers, window_read=seen,
             state_rows=len(segs),
             restores=sum(row[2] == sz.slots + 1 for row in segs))
 
@@ -788,20 +865,24 @@ class GenerateEngine:
         mat[0] = sz.slots
         mat[2] = 1
         table = np.zeros((bucket, self._private_pages), np.int32)
-        rows_read = 0
+        rows_read = window_read = 0
         for b, (slot, k, pages) in enumerate(rows):
             row = self._where(pages, k)
             mat[:, b] = (slot, len(self.prefix) + k, k + 1,
                          row // sz.page_tokens, row % sz.page_tokens, 1)
             table[b, :len(pages)] = pages
             # a row's whole context, the prefix's rows among them
-            rows_read += len(self.prefix) + k + 1
+            ctx = len(self.prefix) + k + 1
+            rows_read += ctx
+            # of which a window layer sees the last ``window``
+            window_read += min(ctx, self._window or ctx)
         return self._run("decode", f"decode:{bucket}", self._decode,
                          (mat, table), tokens=len(rows), rows_read=rows_read,
-                         takers=takers, state_rows=len(rows))
+                         takers=takers, state_rows=len(rows),
+                         window_read=window_read)
 
     def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
-             takers, state_rows, restores=0) -> _Step:
+             takers, state_rows, restores=0, window_read=None) -> _Step:
         t0 = time.perf_counter()
         self._step_started = t0
         cold = key not in self._seen
@@ -816,8 +897,9 @@ class GenerateEngine:
             self.stats.compile_seconds += time.perf_counter() - t0
         if not self._state_bytes:
             state_rows = restores = 0
-        return _Step(kind, key, t0, tokens, rows_read, state_rows, restores,
-                     takers, top, ids, held)
+        return _Step(kind, key, t0, tokens, rows_read,
+                     rows_read if window_read is None else window_read,
+                     state_rows, restores, takers, top, ids, held)
 
     # ------------------------------------------------------------- harvest
 
@@ -842,6 +924,11 @@ class GenerateEngine:
         metrics.inc("evam_generate_tokens", float(step.tokens), labels)
         metrics.inc("evam_generate_latent_rows_read",
                     float(step.rows_read), labels)
+        if self._window:
+            metrics.inc("evam_generate_window_rows_read",
+                        float(step.window_read), labels)
+            metrics.inc("evam_generate_window_rows_skipped",
+                        float(step.rows_read - step.window_read), labels)
         metrics.inc("evam_generate_state_rows", float(step.state_rows),
                     labels)
         metrics.inc("evam_generate_prefix_restores", float(step.restores))

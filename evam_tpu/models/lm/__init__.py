@@ -15,19 +15,25 @@ experts hit, expert matrices read a product]``, zeros for a family
 without experts) and ``SEGMENT_ALIGN``
 (the multiple of a chunk's tokens at which the engine's packer starts
 every segment: 1, or the block of a chunkwise kernel); the installed
-config's ``model_type`` names it.
+config's ``model_type`` names it. A ``Config`` whose layers do not all see
+everything earlier says so with ``window`` (the positions a window layer
+sees: the engine counts the rows such a layer read and skipped by it).
 
 What more than one family has lives beside them and is called by each:
 ``common.py`` (tensors, norms, the split softmax, a packed chunk's bounds
 and convolution inputs, the head), ``mla.py`` (the latent attention:
 ``deepseek_v2`` with its rotation and query down-projection,
 ``kimi_linear`` without either), ``attention.py`` (plain keys and values
-under grouped queries: ``jamba`` one key-value head and no positions,
-``lfm2_moe`` eight with head norms and rotary positions) and
+under grouped queries, what differs as data of the layer KIND: ``jamba``
+one key-value head and no positions, ``lfm2_moe`` eight with head norms
+and rotary positions, ``laguna`` two kinds in one model, full layers of 48
+query heads under a rescaled partial rotation and window layers of 64
+under a plain one, both with a gate on every head's output) and
 ``experts.py`` (the expert layer told which experts it holds:
 ``deepseek_v2`` softmax scores and group-limited routing, ``kimi_linear``
 sigmoid scores with a selection bias, ``lfm2_moe`` the same with no shared
-expert and its expert layers' tensors in one stack).
+expert and its expert layers' tensors in one stack, ``laguna`` as
+Kimi-Linear's in one stack with ALL 256 of a layer's experts held).
 """
 
 from __future__ import annotations
@@ -35,7 +41,8 @@ from __future__ import annotations
 import importlib
 
 FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba",
-            "kimi_linear": "kimi_linear", "lfm2_moe": "lfm2_moe"}
+            "kimi_linear": "kimi_linear", "lfm2_moe": "lfm2_moe",
+            "laguna": "laguna"}
 
 
 def family(model_type: str):

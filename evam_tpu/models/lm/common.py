@@ -1,11 +1,13 @@
 """What the language-model families share (models/lm/deepseek_v2.py,
-models/lm/jamba.py, models/lm/kimi_linear.py, models/lm/lfm2_moe.py):
-seeded tensors, norms and products, the dense SwiGLU, the pieces of a
-softmax that is split over its key rows, a packed chunk's visibility
-bounds, the inputs of a short causal convolution over a packed chunk, and
-the head with its top logits. The latent attention is in models/lm/mla.py,
-the plain attention in models/lm/attention.py and the expert layer in
-models/lm/experts.py, each called by the families that have it.
+models/lm/jamba.py, models/lm/kimi_linear.py, models/lm/lfm2_moe.py,
+models/lm/laguna.py): seeded tensors, norms and products, the dense
+SwiGLU, the pieces of a softmax that is split over its key rows, a packed
+chunk's visibility bounds (with a first visible row under a window: only
+Laguna's window layers hand one), the inputs of a short causal convolution
+over a packed chunk, and the head with its top logits. The latent
+attention is in models/lm/mla.py, the plain attention in
+models/lm/attention.py and the expert layer in models/lm/experts.py, each
+called by the families that have it.
 
 Weights: ``key = fold_in(fold_in(PRNGKey(seed), layer), crc32(name))``;
 a tensor is ``normal(key) * initializer_range`` in float32, stored
@@ -51,6 +53,36 @@ def make(key, shape, scale, gain: bool):
 
 
 make_one = jax.jit(make, static_argnums=(1, 2, 3))
+#: one tensor per expert id, ``fold_in(key, expert)``, on a leading axis
+make_experts = jax.jit(
+    lambda key, ids, shape, scale: jax.vmap(lambda e: make(
+        jax.random.fold_in(key, e), shape, scale, False))(ids),
+    static_argnums=(2, 3))
+#: layer ``l`` of a stack written in place (the stack is donated): one
+#: layer's experts at a time, never a second copy of the whole stack
+put_layer = jax.jit(lambda stack, l, one: stack.at[l].set(one),
+                    donate_argnums=0)
+
+
+def make_layers(make_tensor, seed: int, scale: float, layers, shapes: dict,
+                held=None) -> dict:
+    """The tensors of ``layers`` (model layer indices), each name's
+    stacked on a leading axis, ``make_tensor(layer, name, shape)`` each;
+    each ``expert_*`` tensor once per expert of ``held`` (global ids) on a
+    second axis, ``normal * scale``."""
+    out = {}
+    for name, shape in shapes.items():
+        if not name.startswith("expert_"):
+            out[name] = jnp.stack([make_tensor(i, name, shape)
+                                   for i in layers])
+            continue
+        ids = jnp.asarray(list(held), jnp.uint32)
+        stack = jnp.zeros((len(layers), len(ids), *shape), BF16)
+        for l, i in enumerate(layers):
+            stack = put_layer(stack, l, make_experts(
+                tensor_key(seed, i, name), ids, shape, scale))
+        out[name] = stack
+    return out
 
 
 def step_bias(key, shape, lo: float, hi: float):
@@ -117,7 +149,8 @@ def merge_softmax_sums(own, shared):
     return acc / jnp.where(l > 0, l, 1.0)
 
 
-def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int):
+def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int,
+                 window: int | None = None, prefix_first=0):
     """Which rows of ``[prefix rows | continued rows | the chunk's own
     rows]`` each token of a packed chunk may see, as ops/pallas_mla.py's
     three half-open intervals ``[0, a) | [b0, b1) | [c0, c1)`` per token
@@ -126,19 +159,35 @@ def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int):
     that continues in this chunk, below ``n_cont`` and to segment 0
     only; its own segment's rows up to itself. A padded token (segment
     -1) sees nothing. ``prefix_rows`` / ``cont_rows``: how many rows the
-    two lists hold (0 where there is none)."""
+    two lists hold (0 where there is none).
+
+    Under a ``window`` a token sees the ``window`` positions that end at
+    its own, and each interval gets a FIRST visible row: ``bounds`` [T, 6]
+    = a, b1, c0, c1, a_lo, b_lo for ``[a_lo, a) | [b_lo, b1) | [c0, c1)``.
+    The position of a prefix row is ``prefix_first`` + its index (the list
+    may start behind the prefix's first row: what no token here can see
+    need not be handed); of a continued row ``n_prefix`` + its index; of
+    an own row ``n_prefix`` + its place in its sequence."""
     t = seg.shape[0]
     b0 = prefix_rows
     c_base = b0 + cont_rows
     live = seg >= 0
     idx = jnp.arange(t)
     start = jnp.argmax(seg[:, None] == seg[None, :], axis=1)
-    bounds = jnp.stack([
-        jnp.where(live, n_prefix if prefix_rows else 0, 0),
-        b0 + jnp.where(live & (seg == 0), n_cont if cont_rows else 0, 0),
-        jnp.where(live, c_base + start, 0),
-        jnp.where(live, c_base + idx + 1, 0)], axis=1).astype(jnp.int32)
-    return bounds, b0
+    before = jnp.where(live & (seg == 0), n_cont if cont_rows else 0, 0)
+    cols = [jnp.where(live, (n_prefix - prefix_first) if prefix_rows else 0,
+                      0),
+            b0 + before,
+            jnp.where(live, c_base + start, 0),
+            jnp.where(live, c_base + idx + 1, 0)]
+    if window is not None:
+        # the first position the token sees, counted from the prefix's end
+        lo = before + idx - start - window + 1
+        cols[2] = jnp.where(
+            live, c_base + jnp.maximum(start, idx - window + 1), 0)
+        cols += [jnp.maximum(lo + n_prefix - prefix_first, 0),
+                 b0 + jnp.maximum(lo, 0)]
+    return jnp.stack(cols, axis=1).astype(jnp.int32), b0
 
 
 def packed_conv_inputs(u_pre, seg, conv0, k1: int):

@@ -3,7 +3,12 @@ family that has one (models/lm/deepseek_v2.py: softmax scores, group-limited
 top-k, one routing group held; models/lm/kimi_linear.py: sigmoid scores with
 a selection bias, one group, a quarter of the experts held;
 models/lm/lfm2_moe.py: as Kimi-Linear's with half the experts held, no
-shared expert, and every expert layer's tensors in ONE stack).
+shared expert, and every expert layer's tensors in ONE stack;
+models/lm/laguna.py: as Kimi-Linear's in one stack too, with ALL 256 small
+experts of a layer held, 8 a token: the held range is the whole range, a
+64-row step's 512 assignments a layer reach most of 256 groups with two or
+three rows each, and a 512-token chunk gives every expert some 16 rows, an
+eighth of a row tile).
 
 A router over ALL ``n_experts`` (scores in float32), the routed experts
 this chip HOLDS (ids ``[held_lo, held_lo + n_held)``, ``n_held`` the
@@ -24,8 +29,8 @@ no shared term); and of the layer: ``router_bias`` (added to the scores
 for the SELECTION only; the weights are the scores without it). A family
 whose expert layers run in one loop body hands ``moe`` every layer's
 tensors stacked on a leading axis and the layer as a traced index
-(``layer``): the router's slice is taken, the experts' stack goes to the
-grouped products as it is.
+(``layer``): the router's slice and the shared experts' are taken, the
+experts' stack goes to the grouped products as it is.
 """
 
 from __future__ import annotations
@@ -140,7 +145,9 @@ def moe(cfg, lp: dict, x, live, layer=None):
     with jax.named_scope("experts"):
         y, *counts = held_experts(cfg, lp, x, w, ids, live, layer)
     if cfg.n_shared:
+        shared = (lp[f"shared_{name}"] for name in ("gate", "up", "down"))
+        if layer is not None:
+            shared = (w[layer] for w in shared)
         with jax.named_scope("shared"):
-            y = y + swiglu(x, lp["shared_gate"], lp["shared_up"],
-                           lp["shared_down"])
+            y = y + swiglu(x, *shared)
     return y, jnp.stack(counts)

@@ -84,7 +84,9 @@ class Config:
 
     #: what models/lm/attention.py reads beside the fields
     kv_heads = 1
-    rope_theta = None
+    rope_theta = None   # the published config has no positional term
+    rope = None
+    window = None
     chunk_kernel = False
 
     @classmethod

@@ -92,6 +92,7 @@ class Config:
 
     #: what models/lm/attention.py reads beside the fields
     chunk_kernel = True
+    window = None
     #: what models/lm/experts.py reads beside the fields
     score_func = "sigmoid"
     scale_routed = True
@@ -137,6 +138,11 @@ class Config:
     @property
     def head_dim(self) -> int:
         return self.hidden // self.heads
+
+    @property
+    def rope(self) -> attention.Rope:
+        """The plain rotation over the whole head."""
+        return attention.Rope(self.rope_theta)
 
     @property
     def conv_ids(self) -> tuple:
@@ -221,15 +227,6 @@ def _tensor(key, kind: str, shape: tuple, std: float, mean: float):
 
 #: compiled once per kind and shape, whatever the name and the layer
 _make_one = jax.jit(_tensor, static_argnums=(1, 2, 3, 4))
-#: one tensor per expert id, ``fold_in(key, expert)``, on a leading axis
-_make_experts = jax.jit(
-    lambda key, ids, shape, std: jax.vmap(lambda e: _tensor(
-        jax.random.fold_in(key, e), "normal", shape, std, 0.0))(ids),
-    static_argnums=(2, 3))
-#: layer ``l`` of a stack written in place (the stack is donated): a
-#: layer's 117 MB at a time, never a second copy of the experts' 2.6 GB
-_put_layer = jax.jit(lambda stack, l, one: stack.at[l].set(one),
-                     donate_argnums=0)
 
 
 def make_tensor(cfg: Config, layer: int, name: str, shape: tuple):
@@ -240,21 +237,11 @@ def make_tensor(cfg: Config, layer: int, name: str, shape: tuple):
 def make_layers(cfg: Config, layers, shapes: dict, held=None) -> dict:
     """The tensors of ``layers`` (model layer indices), each name's
     stacked on a leading axis; each ``expert_*`` tensor once per expert
-    of ``held`` (global ids) on a second."""
-    out = {}
-    for name, shape in shapes.items():
-        if not name.startswith("expert_"):
-            out[name] = jnp.stack([make_tensor(cfg, i, name, shape)
-                                   for i in layers])
-            continue
-        ids = jnp.asarray(list(held), jnp.uint32)
-        stack = jnp.zeros((len(layers), len(ids), *shape), BF16)
-        for l, i in enumerate(layers):
-            stack = _put_layer(stack, l, _make_experts(
-                common.tensor_key(cfg.seed, i, name), ids, shape,
-                cfg.init_range))
-        out[name] = stack
-    return out
+    of ``held`` (global ids) on a second (``common.make_layers``: a
+    layer's experts written into the stack in place)."""
+    return common.make_layers(
+        lambda i, name, shape: make_tensor(cfg, i, name, shape), cfg.seed,
+        cfg.init_range, layers, shapes, held)
 
 
 def make_params(cfg: Config, held=None) -> dict:

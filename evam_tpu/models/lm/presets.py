@@ -42,12 +42,28 @@ an expert layer behind a convolution and behind an attention layer,
 attention at 2, 6 and 8: no regular period; 8 experts, 4 held, 2 a token,
 2 key-value heads under 4 query heads) at a size a CPU test runs.
 
+``laguna_xs2_pp8`` is Laguna-XS.2's published config
+(https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json) with
+every width as published, cut to what the FIRST of eight pipeline stages
+holds, every layer of it whole: layers 0-4 (full attention with 48 query
+heads and the dense feed-forward; three window layers of 64 query heads
+over the experts; full attention over the experts), the embedding, and the
+final norm and head so that the stage yields logits. All 256 experts of a
+layer and the whole vocabulary are here (``experts_held`` 256). Both
+rotations are the published tables (``rope_parameters``). ``qk_norm_gain``
+is the mean of the head norms' seeded gains, as LFM2's. ``laguna_tiny`` has
+the same structure (dense + full, three window layers, full + experts, one
+more window layer; 6 and 8 query heads over 2 key-value heads of 16; a
+window of 8; half of a head rotated under a YaRN table whose ramp lies
+inside it on the full layers; 8 experts, all held, 2 a token, one shared)
+at a size a CPU test runs.
+
 Which module serves a preset is its ``model_type`` (models/lm
 ``FAMILIES``); the latent attention (models/lm/mla.py: ``deepseek_v2``,
 ``kimi_linear``), the plain attention (models/lm/attention.py: ``jamba``,
-``lfm2_moe``) and the expert layer (models/lm/experts.py: ``deepseek_v2``,
-``kimi_linear``, ``lfm2_moe``) are each shared and read what differs from
-these keys.
+``lfm2_moe``, ``laguna``) and the expert layer (models/lm/experts.py:
+``deepseek_v2``, ``kimi_linear``, ``lfm2_moe``, ``laguna``) are each shared
+and read what differs from these keys.
 """
 
 from __future__ import annotations
@@ -127,7 +143,81 @@ LFM2_8B_A1B_PUBLISHED = {
     "use_expert_bias": True, "vocab_size": 65536,
 }
 
+_LAGUNA_PERIOD = ["full_attention"] + 3 * ["sliding_attention"]
+
+LAGUNA_XS2_PUBLISHED = {
+    "attention_bias": False, "gating": True, "head_dim": 128,
+    "hidden_size": 2048, "intermediate_size": 8192,
+    "layer_types": 10 * _LAGUNA_PERIOD,
+    "max_position_embeddings": 262144,
+    "mlp_layer_types": ["dense"] + 39 * ["sparse"],
+    "model_type": "laguna", "moe_apply_router_weight_on_input": False,
+    "moe_intermediate_size": 512, "moe_routed_scaling_factor": 2.5,
+    "num_attention_heads": 48,
+    "num_attention_heads_per_layer": 10 * [48, 64, 64, 64],
+    "num_experts": 256, "num_experts_per_tok": 8, "num_hidden_layers": 40,
+    "num_key_value_heads": 8, "partial_rotary_factor": 0.5,
+    "rms_norm_eps": 1e-06,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "original_max_position_embeddings": 4096, "beta_slow": 1,
+            "beta_fast": 64, "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {
+            "rope_type": "default", "rope_theta": 10000,
+            "partial_rotary_factor": 1},
+        "original_max_position_embeddings": 4096},
+    "shared_expert_intermediate_size": 512, "sliding_window": 512,
+    "tie_word_embeddings": False, "vocab_size": 100352,
+}
+
 PRESETS = {
+    "laguna_xs2_pp8": {
+        **LAGUNA_XS2_PUBLISHED,
+        # the cut: the first pipeline stage's five layers, each whole
+        "num_hidden_layers": 5,
+        "experts_held": 256,
+        "held_lo": 0,
+        "vocab_held": 100352,
+        "weights_seed": 20260501,
+        "initializer_range": 0.02,
+        # assumed: the mean of the q_norm / k_norm gains, for a softmax as
+        # peaked as a trained one (a score's deviation is the product of
+        # the two gains): at 1 neither a missing window nor a missing
+        # rotation shows in the logits (PERF.md section 6, PR 42)
+        "qk_norm_gain": 1.75,
+    },
+    "laguna_tiny": {
+        **LAGUNA_XS2_PUBLISHED,
+        # dense + full | window x 3 | full + experts | window
+        "hidden_size": 64, "intermediate_size": 96, "head_dim": 16,
+        "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+        "num_attention_heads": 6, "num_key_value_heads": 2,
+        "num_attention_heads_per_layer": [6, 8, 8, 8, 6, 8],
+        "layer_types": _LAGUNA_PERIOD + _LAGUNA_PERIOD[:2],
+        "mlp_layer_types": ["dense"] + 5 * ["sparse"],
+        "num_hidden_layers": 6, "num_experts": 8, "num_experts_per_tok": 2,
+        "sliding_window": 8, "vocab_size": 512,
+        "rope_parameters": {
+            # of 8 rotated values' 4 frequencies: lo 1, hi 3
+            "full_attention": {
+                "rope_theta": 100, "rope_type": "yarn", "factor": 8,
+                "original_max_position_embeddings": 32, "beta_slow": 0.3,
+                "beta_fast": 0.9, "attention_factor": 1.2,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": 100,
+                "partial_rotary_factor": 1},
+            "original_max_position_embeddings": 32},
+        "experts_held": 8,
+        "held_lo": 0,
+        "vocab_held": 512,
+        "weights_seed": 19,
+        # as deepseek_v2_tiny: 0.02 at width 64 leaves every score flat
+        "initializer_range": 0.15,
+        "qk_norm_gain": 1.75,
+    },
     "lfm2_moe_ep2": {
         **LFM2_8B_A1B_PUBLISHED,
         # the cut: the chip's share of the experts and of the vocabulary;

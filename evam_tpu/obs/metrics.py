@@ -60,6 +60,12 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # cache rows read, per layer THAT HAS a cache (every layer of
     # DeepSeek-V2, the attention layers of Jamba)
     "evam_generate_latent_rows_read": ("counter", ("kind",)),
+    # a family with layers under a window (Laguna) only: of those rows,
+    # per WINDOW layer, the ones inside the window (read) and the ones of
+    # the same sequence behind it (not read); the two add up to the
+    # counter above
+    "evam_generate_window_rows_read": ("counter", ("kind",)),
+    "evam_generate_window_rows_skipped": ("counter", ("kind",)),
     # per-slot recurrent state (a family that keeps none counts 0): slot
     # states a step read and wrote (decode rows; a chunk's segments),
     # sequences started from the prefix snapshot, and the state's bytes

@@ -19,7 +19,9 @@ rows (``q`` [kv_heads, R, d], R = tokens x group); keys and values are
 ``k``, ``v`` [kv_heads, S, d]; the grid's first axis walks the key-value
 heads. Which keys a query row may see is ops/pallas_mla.py's three
 half-open intervals per row (``bounds`` [R, 4] = a, b1, c0, c1; ``b0``
-static), the same for every key-value head. A row whose intervals are
+static; [R, 6] with a first visible row of the two leading intervals, for
+a layer under a window: models/lm/laguna.py), the same for every
+key-value head. A row whose intervals are
 empty (a padded token) comes out 0.
 
 ``chunk_attention_xla`` is the same arithmetic through XLA; the CPU tests
@@ -77,7 +79,7 @@ def _kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     "scale", "b0", "block_q", "block_k", "interpret"))
 def chunk_attention(q, k, v, bounds, *, scale, b0, block_q=1024, block_k=512,
                     interpret=False):
-    """``q`` [G, R, d], ``k``, ``v`` [G, S, d], ``bounds`` [R, 4] int32 ->
+    """``q`` [G, R, d], ``k``, ``v`` [G, S, d], ``bounds`` [R, 4 | 6] int32 ->
     [G, R, d] (the attention-weighted ``v``), ``G`` the key-value heads.
     R and S are padded here to whole blocks."""
     from jax.experimental import pallas as pl
@@ -96,7 +98,7 @@ def chunk_attention(q, k, v, bounds, *, scale, b0, block_q=1024, block_k=512,
         functools.partial(_kernel, scale=scale, block_k=block_k, b0=b0),
         grid=(g, rp // block_q, sp // block_k),
         in_specs=[
-            pl.BlockSpec((block_q, 4), lambda h, i, j: (i, 0)),
+            pl.BlockSpec((block_q, bounds.shape[1]), lambda h, i, j: (i, 0)),
             pl.BlockSpec((None, block_q, d), lambda h, i, j: (h, i, 0)),
             pl.BlockSpec((None, block_k, d), lambda h, i, j: (h, j, 0)),
             pl.BlockSpec((None, block_k, d), lambda h, i, j: (h, j, 0)),

@@ -41,8 +41,16 @@ NEG = -1e30
 
 
 def _visible(col, bounds, b0):
+    """The one visibility rule: ``bounds`` [rows, 4] = a, b1, c0, c1 for
+    ``[0, a) | [b0, b1) | [c0, c1)``, or [rows, 6] with a first visible
+    row of the two leading intervals behind them, a_lo and b_lo (a window:
+    models/lm/common.py ``chunk_bounds``)."""
     a, b1, c0, c1 = (bounds[:, i:i + 1] for i in range(4))
-    return ((col < a) | ((col >= b0) & (col < b1))
+    if bounds.shape[1] == 4:
+        return ((col < a) | ((col >= b0) & (col < b1))
+                | ((col >= c0) & (col < c1)))
+    a_lo, b_lo = bounds[:, 4:5], bounds[:, 5:6]
+    return (((col >= a_lo) & (col < a)) | ((col >= b_lo) & (col < b1))
             | ((col >= c0) & (col < c1)))
 
 
@@ -89,7 +97,7 @@ def _kernel(bounds_ref, q_lat_ref, q_rope_ref, ckv_ref, kr_ref, o_ref,
 def latent_attention(q_lat, q_rope, ckv, kr, bounds, *, scale, b0,
                      block_q=1024, block_k=512, interpret=False):
     """``q_lat`` [R, C], ``q_rope`` [R, P], ``ckv`` [S, C], ``kr`` [S, P],
-    ``bounds`` [R, 4] int32 -> [R, C] (the attention-weighted ``ckv``).
+    ``bounds`` [R, 4 | 6] int32 -> [R, C] (the attention-weighted ``ckv``).
     R and S are padded here to whole blocks; P is taken as it comes (a
     stored row's last lane tile: 128)."""
     from jax.experimental import pallas as pl
@@ -109,7 +117,7 @@ def latent_attention(q_lat, q_rope, ckv, kr, bounds, *, scale, b0,
         functools.partial(_kernel, scale=scale, block_k=block_k, b0=b0),
         grid=(rp // block_q, sp // block_k),
         in_specs=[
-            pl.BlockSpec((block_q, 4), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_q, bounds.shape[1]), lambda i, j: (i, 0)),
             pl.BlockSpec((block_q, c), lambda i, j: (i, 0)),
             pl.BlockSpec((block_q, p), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, c), lambda i, j: (j, 0)),

@@ -27,8 +27,10 @@ from benchmark.reference.compare import check_schema
 from evam_tpu.config.settings import LMSettings, Settings
 from evam_tpu.engine.generate import (
     MAX_PREFILL_RUN,
+    PART_CHUNK_PATIENCE,
     GenerateEngine,
     GenerateSizes,
+    PrefillPace,
     next_step_kind,
 )
 from evam_tpu.engine.pages import PagePool
@@ -163,16 +165,21 @@ def test_group_limited_routing_on_crafted_scores(case):
         rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("model", ["deepseek_v2", "kimi_linear", "lfm2_moe"])
+@pytest.mark.parametrize("model", ["deepseek_v2", "kimi_linear", "lfm2_moe",
+                                   "laguna"])
 def test_the_shares_add_up_to_the_uncut_layer(model):
     """All the shares' routed sums, the shared experts counted once, are
     the uncut reference layer (every routed expert + the shared), for
     every expert family through the one expert layer
     (models/lm/experts.py): DeepSeek-V2's four routing groups,
     Kimi-Linear's four ranges of four, LFM2-MoE's two halves of eight
-    (NO shared expert to count once; the layer one of a stack)."""
-    from benchmark.reference import kimi_linear_plain, lfm2_moe_plain
-    from evam_tpu.models.lm import kimi_linear, lfm2_moe
+    (NO shared expert to count once; the layer one of a stack), Laguna's
+    two halves of eight (its configuration holds ALL of them; the halves
+    show that a held range still means what it says with one shared
+    expert, the factor 2.5 and the layer one of a stack)."""
+    from benchmark.reference import (kimi_linear_plain, laguna_plain,
+                                     lfm2_moe_plain)
+    from evam_tpu.models.lm import kimi_linear, laguna, lfm2_moe
 
     layer, stacked = 1, None
     if model == "deepseek_v2":
@@ -187,6 +194,14 @@ def test_the_shares_add_up_to_the_uncut_layer(model):
             {**tiny, "held_lo": lo}), kimi_linear.make_layer(
                 share, layer, kimi_linear.moe_shapes(share),
                 range(lo, lo + 4))) for lo in range(0, 16, 4)]
+    elif model == "laguna":
+        tiny, plain = PRESETS["laguna_tiny"], laguna_plain
+        cfg = laguna.Config.from_dict(tiny)
+        layer, stacked = 2, 1   # model layers 1 and 2 in a stack of two
+        shares = [(share := laguna.Config.from_dict(
+            {**tiny, "held_lo": lo, "experts_held": 4}), laguna.make_layers(
+                share, (1, 2), laguna.moe_shapes(share),
+                range(lo, lo + 4))) for lo in range(0, 8, 4)]
     else:
         tiny, plain = PRESETS["lfm2_moe_tiny"], lfm2_moe_plain
         cfg = lfm2_moe.Config.from_dict(tiny)
@@ -293,6 +308,84 @@ def test_page_pool_hands_out_frees_and_pins():
 ])                                       # up for MAX_PREFILL_RUN chunks
 def test_next_step_kind(waiting, decoding, run, passed_over, want):
     assert next_step_kind(waiting, decoding, run, passed_over, 32) == want
+
+
+@pytest.mark.parametrize("waiting,passed_over,credit,owed,want", [
+    (640, 0, 31.0, 9.0, "decode"),       # a full chunk waits for its credit
+    (640, 0, 32.0, 9.0, "prefill"),
+    (16, 1, 0.0, 2.0, "decode"),         # a part-full one for its fill,
+    (16, 1, 0.0, 1.0, "prefill"),        # if that is on its way,
+    (16, PART_CHUNK_PATIENCE, 0.0, 2.0, "prefill"),  # and not for ever
+    (16, 0, 64.0, 0.0, "decode"),        # nor before a decode step passed
+])
+def test_next_step_kind_paces_prefill(waiting, passed_over, credit, owed,
+                                      want):
+    assert next_step_kind(waiting, True, 0, passed_over, 32, credit,
+                          owed) == want
+
+
+def _closed_loop(n_seqs, n_steps, prompt=272, new=64, chunk=512,
+                 segments=8):
+    """``n_seqs`` equal generations submitted TOGETHER, each submitted
+    again when it ends, through the engine's own pace and packing rules:
+    per step its kind, a chunk's tokens and the generations a decode step
+    ended."""
+    pace = PrefillPace(chunk)
+    waiting = [0] * n_seqs               # tokens prefilled of each prompt
+    decoding: list[int] = []             # tokens generated of each row
+    steps = []
+    for _ in range(n_steps):
+        owed = (len(waiting) + len(decoding)) * prompt / (new - 1)
+        kind = pace.next(sum(prompt - w for w in waiting), bool(decoding),
+                         owed)
+        tokens = ended = 0
+        if kind == "prefill":
+            for seg in range(segments):
+                if not waiting or tokens == chunk or (waiting[0] and seg):
+                    break                # a continued prompt opens a chunk
+                take = min(prompt - waiting[0], chunk - tokens)
+                tokens += take
+                waiting[0] += take
+                if waiting[0] == prompt:
+                    waiting.pop(0)
+                    decoding.append(1)
+        else:
+            decoding = [g + 1 for g in decoding]
+            ended = sum(g == new for g in decoding)
+            decoding = [g for g in decoding if g < new]
+        pace.ran(kind, tokens, bool(waiting),
+                 (len(waiting) + len(decoding)) * prompt / (new - 1))
+        waiting += [0] * ended           # submitted again at once
+        steps.append((kind, tokens, ended))
+    return steps
+
+
+def test_generations_submitted_together_do_not_stay_together():
+    """The describe cells' closed loop: 64 equal generations that start at
+    one moment. After a few rounds the engine ends about one a decode
+    step, never a burst, every chunk runs full and a chunk follows every
+    other decode step: what it completes a second does not depend on the
+    phases the streams started in (PERF.md section 6, PR 42)."""
+    steps = _closed_loop(64, 3000)
+    late = steps[1500:]
+    chunks = [t for k, t, _ in late if k == "prefill"]
+    ends = [e for k, _, e in late if k == "decode"]
+    assert set(chunks) == {512}
+    assert 0.5 < len(chunks) / len(ends) < 0.56      # 272 / 512 a frame
+    assert max(sum(ends[i:i + 8]) for i in range(len(ends) - 8)) <= 12
+    assert min(sum(ends[i:i + 8]) for i in range(len(ends) - 8)) >= 4
+    runs = "".join(k[0] for k, _, _ in late)
+    assert "pp" not in runs and "ddddd" not in runs
+
+
+@pytest.mark.parametrize("n_seqs", [1, 2, 4])
+def test_a_few_generations_are_not_held_back(n_seqs):
+    """With few sequences in the engine a chunk cannot fill in time, and
+    a prompt waits ONE decode step, as before the pace."""
+    steps = _closed_loop(n_seqs, 600)
+    for i, (k, _, ended) in enumerate(steps[:-3]):
+        if k == "decode" and ended:
+            assert "prefill" in (steps[i + 1][0], steps[i + 2][0])
 
 
 #: (prefix pages or None, n_prefix, own rows of each row of the bucket;

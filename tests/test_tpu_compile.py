@@ -196,11 +196,12 @@ def test_conv_decode_kernel_compiles_at_published_widths(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def _compile_step(lm, cfg, params, one_chip, program):
+def _compile_step(lm, cfg, params, one_chip, program, traced_prefix=False):
     """A family's ``decode`` step at 64 rows or its ``prefill`` chunk of
     512 tokens at the deployment's sizes (401 pages of 128, 128 slots, a
     prefix of 16 pages), the state donated, for the described chip:
-    ``(compiled, the state's shapes)``."""
+    ``(compiled, the state's shapes)``. ``traced_prefix``: the chunk takes
+    the prefix's length as an argument, as the engine's program does."""
     import numpy as np
 
     def s(shape, dtype=jnp.bfloat16):
@@ -225,13 +226,14 @@ def _compile_step(lm, cfg, params, one_chip, program):
         t, n_seg = 512, 8
 
         def step(params, state, tokens, seg, pos, page, off, cont, n_cont,
-                 last_idx, seg_from, seg_to):
+                 last_idx, seg_from, seg_to, n_prefix=2048):
             return lm.prefill_chunk(cfg, params, state, tokens, seg, pos,
-                                    page, off, shared, 2048, cont, n_cont,
+                                    page, off, shared, n_prefix, cont, n_cont,
                                     last_idx, seg_from, seg_to)
 
         args = (*[s((t,), i32)] * 5, s((3,), i32), s((), i32),
-                *[s((n_seg,), i32)] * 3)
+                *[s((n_seg,), i32)] * 3,
+                *([s((), i32)] if traced_prefix else []))
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, state, *args).compile()
     return compiled, state
@@ -290,6 +292,50 @@ def test_lfm2_step_programs_compile_at_published_widths(one_chip,
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_laguna_step_programs_compile_at_published_widths(one_chip,
+                                                          monkeypatch,
+                                                          program):
+    """Both step programs of the Laguna family at the deployment's sizes:
+    the five layers in one loop body whose two mixers are the branches of
+    a ``lax.cond``, so the chunk kernel is in the prefill program TWICE
+    (once a kind of layer, under bounds of 4 and of 6 columns) and each
+    grouped product once; the 6.4 GB of stacked expert tensors are read
+    where they lie and no layer's pages are taken out of the cache (210
+    MB): the temporaries stay small. A window layer gathers 4 (decode) or
+    5 (a chunk, whose prefix length is an argument) of the prefix's 16
+    pages."""
+    import re
+
+    from evam_tpu.models.lm import common, laguna as lm
+    from evam_tpu.models.lm.presets import PRESETS
+
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+    cfg = lm.Config.from_dict(PRESETS["laguna_xs2_pp8"])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(lambda: lm.make_params(cfg)))
+    assert params["moe"]["expert_gate"].shape == (4, 256, 2048, 512)
+    compiled, state = _compile_step(lm, cfg, params, one_chip, program,
+                                    traced_prefix=True)
+    text = compiled.as_text()
+    for name, n in (("expert_gate_up", 1), ("expert_down", 1),
+                    ("attn_chunk_attention", 2 * int(program == "prefill"))):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
+    assert "ragged" not in text
+    # no expert tensor copied, whole or a layer of it
+    for shape in ("4,256,2048,512", "256,2048,512", "4,256,512,2048",
+                  "256,512,2048"):
+        assert not re.search(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    # nor a layer taken out of the cache before its pages are gathered
+    assert state["pages"].shape == (5, 401, 128, 2048)
+    assert not re.search(r"= bf16\[401,128,2048\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("preset", ["deepseek_v2_ep8", "kimi_linear_ep4"])
 def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
                                                program):
@@ -343,12 +389,14 @@ def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
         assert len(copies) == {"decode": 2, "prefill": 3}[program], copies
 
 
-@pytest.mark.parametrize("heads,rows,keys,dim", [
-    (8, 512 * 4, 2048 + 384 + 512, 64),    # LFM2: a chunk, 4 heads a group
-    (8, 64 * 4, 512 + 64, 64),             # a short chunk, ragged key count
+@pytest.mark.parametrize("heads,rows,keys,dim,bounds", [
+    (8, 512 * 4, 2048 + 384 + 512, 64, 4),  # LFM2: a chunk, 4 heads a group
+    (8, 64 * 4, 512 + 64, 64, 4),           # a short chunk, ragged key count
+    (8, 512 * 6, 2048 + 384 + 512, 128, 4),  # Laguna: a full layer's chunk
+    (8, 512 * 8, 640 + 384 + 512, 128, 6),   # a window layer's, lower bounds
 ])
 def test_chunk_attention_kernel_compiles_at_published_widths(
-        one_chip, heads, rows, keys, dim):
+        one_chip, heads, rows, keys, dim, bounds):
     from evam_tpu.ops.pallas_attention import chunk_attention
 
     def s(shape, dtype=jnp.bfloat16):
@@ -357,6 +405,6 @@ def test_chunk_attention_kernel_compiles_at_published_widths(
     compiled = jax.jit(
         lambda *a: chunk_attention(*a, scale=dim ** -0.5, b0=2048)).lower(
         s((heads, rows, dim)), s((heads, keys, dim)), s((heads, keys, dim)),
-        s((rows, 4), jnp.int32)).compile()
+        s((rows, bounds), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "attn_chunk_attention" in text
