@@ -44,6 +44,14 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   private_pages]``), its context length counts own rows, and the step
   merges the two parts of each row's softmax (models/lm/common.py
   ``merge_softmax_sums``).
+* A family with latent attention (DeepSeek-V2, Kimi-Linear) prefills over
+  materialised heads (models/lm/mla.py), and the prefix's never change: the
+  engine holds them (``prefix_heads``: per latent layer ``k_nope`` and
+  ``v`` [heads, prefix rows, 128], 805 MB for DeepSeek-V2's six layers),
+  fills them in warm-up as the prefix's chunks complete, by one small
+  program run only there, and hands them to the prefill program beside the
+  weights: an argument it reads and never writes, donates or copies. The
+  decode program does not take them.
 * A family with recurrent layers (Jamba's Mamba layers, Kimi-Linear's
   delta-rule layers) also keeps state per SLOT, never paged: arrays
   ``[layers, slots + 2, ...]`` that every decode step reads and writes at
@@ -314,6 +322,13 @@ class GenerateEngine:
         self._state_bytes = sum(
             a.size * a.dtype.itemsize
             for k, a in self._state_shapes.items() if k != "pages")
+        #: a latent family's: per latent layer the shapes of the prefix's
+        #: heads, which the prefill program takes beside the weights
+        heads = getattr(self._lm, "prefix_heads_shapes", None)
+        self._heads_shapes = (heads(self.cfg, len(prefix))
+                              if heads is not None and len(prefix) else ())
+        self._heads_bytes = sum(a.size * a.dtype.itemsize
+                                for a in jax.tree.leaves(self._heads_shapes))
         self.stats = EngineStats()
         self.warmed = threading.Event()
         self.warm_error: str | None = None
@@ -346,6 +361,7 @@ class GenerateEngine:
         self._params = None
         self._state = None
         self._last_ids = None
+        self._prefix_heads = ()
         self._build_programs()
         self._thread = threading.Thread(
             target=self._loop, name=f"engine-{name}-generate", daemon=True)
@@ -366,7 +382,9 @@ class GenerateEngine:
         # decoding starts after warm-up: the whole prefix is there
         n_prefix_rows = len(self.prefix)
 
-        def prefill(params, state, last_ids, mat, aux):
+        with_heads = bool(self._heads_shapes)
+
+        def prefill(params, state, last_ids, heads, mat, aux):
             tokens, seg, pos, dest_page, dest_off = mat
             cont = aux[:n_cont]
             n_prefix, n_cont_rows = aux[n_cont], aux[n_cont + 1]
@@ -375,7 +393,7 @@ class GenerateEngine:
             state, top, ids, held = lm.prefill_chunk(
                 cfg, params, state, tokens, seg, pos, dest_page, dest_off,
                 shared, n_prefix, cont, n_cont_rows, last_idx, seg_from,
-                seg_to)
+                seg_to, **({"prefix_heads": heads} if with_heads else {}))
             return (state, last_ids.at[last_slot].set(ids[:, 0]), top, ids,
                     held)
 
@@ -388,6 +406,12 @@ class GenerateEngine:
 
         self._prefill = jax.jit(prefill, donate_argnums=(1, 2))
         self._decode = jax.jit(decode, donate_argnums=(1, 2))
+        #: the prefix's heads from its cached rows as they lie, written
+        #: over the held ones (donated and not read: the one buffer)
+        self._expand = jax.jit(
+            lambda params, state, old: lm.prefix_heads(
+                cfg, params, state, shared),
+            donate_argnums=2, keep_unused=True) if with_heads else None
 
     def _allocate(self) -> None:
         """Weights, the family's state (all zero: the snapshot row is
@@ -404,6 +428,8 @@ class GenerateEngine:
             #: the ids differ, for the warm-up's loaded steps
             self._last_ids = jnp.arange(
                 sz.slots + 1, dtype=jnp.int32) % cfg.vocab
+            self._prefix_heads = jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype), self._heads_shapes)
             jax.block_until_ready(self._params)
         log.info(
             "engine %s: %.2f G parameters, %s on %s in %.1f s", self.name,
@@ -491,6 +517,10 @@ class GenerateEngine:
                 part, np.zeros(len(part), np.int32),
                 np.arange(lo, lo + len(part)), dest, n_prefix=lo,
                 cont=None, n_cont=0, segs=snapshot, takers=[]), count=False)
+            if self._expand is not None:
+                # the next chunk attends to these rows' heads
+                self._prefix_heads = self._expand(
+                    self._params, self._state, self._prefix_heads)
         log.info("engine %s: shared prefix of %d tokens prefilled in %.1f s",
                  self.name, len(self.prefix), time.perf_counter() - t0)
         # every program LOADED as in service: every row a token of its
@@ -623,6 +653,11 @@ class GenerateEngine:
         sz = self.sizes
         return sz.slots - len(self._free_slots), sz.slots, self._state_bytes
 
+    def prefix_heads_bytes(self) -> int:
+        """Bytes of the shared prefix's heads held beside the weights (0
+        for a family without latent attention, or without a prefix)."""
+        return self._heads_bytes
+
     # --------------------------------------------------------- the thread
 
     def _loop(self) -> None:
@@ -737,6 +772,7 @@ class GenerateEngine:
                     self.sizes.slots - len(self._free_slots))
         metrics.set("evam_generate_pages_in_use", self._pool.in_use)
         metrics.set("evam_generate_state_bytes", self._state_bytes)
+        metrics.set("evam_generate_prefix_heads_bytes", self._heads_bytes)
 
     # ------------------------------------------------------------ dispatch
 
@@ -832,9 +868,9 @@ class GenerateEngine:
         cached = (n_prefix + n_cont) if n else 0
         seen = min(cached, self._window - 1) if self._window else cached
         return self._run(
-            "prefill", "prefill", self._prefill, (mat, aux), tokens=live,
-            rows_read=cached, takers=takers, window_read=seen,
-            state_rows=len(segs),
+            "prefill", "prefill", self._prefill,
+            (self._prefix_heads, mat, aux), tokens=live, rows_read=cached,
+            takers=takers, window_read=seen, state_rows=len(segs),
             restores=sum(row[2] == sz.slots + 1 for row in segs))
 
     def _dispatch_decode(self) -> _Step:

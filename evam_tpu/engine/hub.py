@@ -462,6 +462,7 @@ class EngineHub:
             row["pages_in_use"], row["pages"] = e.pages_in_use()
             (row["state_slots_in_use"], row["state_slots"],
              row["state_bytes"]) = e.state_slots()
+            row["prefix_heads_bytes"] = e.prefix_heads_bytes()
         return row
 
     def _rows(self):

@@ -9,8 +9,13 @@ serves no ``describe`` stage.
 A FAMILY is a module with ``Config.from_dict``, ``make_params``,
 ``param_count``, ``state_shapes`` (the device state of its sequences for
 ``(pages, page_tokens, slots)``: cache rows in pages, and whatever it
-keeps per slot), ``prefill_chunk`` and ``decode_tokens`` (each returns the
-state, the top logits with their ids, and int32 ``[held assignments, held
+keeps per slot), optionally ``prefix_heads_shapes`` and ``prefix_heads``
+(read-only data of the prefill program that the engine holds beside the
+weights: a latent family's materialised heads of the shared prefix's rows,
+made from ``params``, the state and the pinned pages once warm-up has
+prefilled them, handed to ``prefill_chunk`` as ``prefix_heads``; a family
+without them has no such name), ``prefill_chunk`` and ``decode_tokens``
+(each returns the state, the top logits with their ids, and int32 ``[held assignments, held
 experts hit, expert matrices read a product]``, zeros for a family
 without experts) and ``SEGMENT_ALIGN``
 (the multiple of a chunk's tokens at which the engine's packer starts

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 
 from evam_tpu.models.lm import common
 from evam_tpu.models.lm.common import BF16, F32, mm, rms_norm
-from evam_tpu.ops import pallas_attention, pallas_mla
+from evam_tpu.ops import pallas_attention
 
 
 @dataclass(frozen=True)
@@ -247,8 +247,8 @@ def attn_prefill(kind, lp: dict, q, kv, seg, prefix, n_prefix, cont, n_cont,
             b0=b0)
         o = o.reshape(g, t, group, hd).transpose(1, 0, 2, 3)
     else:
-        seen = pallas_mla._visible(jnp.arange(rows.shape[0])[None, :],
-                                   bounds, b0)
+        seen = pallas_attention._visible(
+            jnp.arange(rows.shape[0])[None, :], bounds, b0)
         o = common.merge_softmax_sums(
             _sums(kind, "tkgd,skd->tkgs", "tkgs,skd->tkgd", _grouped(kind, q),
                   rows, seen[:, None, None, :]), None)

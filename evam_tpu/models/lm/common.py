@@ -22,7 +22,7 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-from evam_tpu.ops.pallas_mla import NEG
+from evam_tpu.ops.pallas_attention import NEG
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -152,7 +152,7 @@ def merge_softmax_sums(own, shared):
 def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int,
                  window: int | None = None, prefix_first=0):
     """Which rows of ``[prefix rows | continued rows | the chunk's own
-    rows]`` each token of a packed chunk may see, as ops/pallas_mla.py's
+    rows]`` each token of a packed chunk may see, as ops/pallas_attention.py's
     three half-open intervals ``[0, a) | [b0, b1) | [c0, c1)`` per token
     (``bounds`` [T, 4] = a, b1, c0, c1 and the static ``b0``): the
     prefix rows below ``n_prefix``; the earlier rows of the sequence

@@ -7,14 +7,15 @@ Per layer, pre-norm residual, RMSNorm:
   rope(k_r)`` (one rope key for all heads). ``[k_nope ; v] = W_kvb c_kv``.
   ``score = (q_nope . k_nope + rope(q_rope) . k_r) * s``. The cache holds
   ``[c_kv ; k_r ; zeros]`` per token and layer (576 values stored 640
-  wide: whole lane tiles). Attention runs in the ABSORBED
+  wide: whole lane tiles). A decode step runs in the ABSORBED
   form (``W_kvb``'s key half folded into the query, its value half into
-  the output), so every key is read as a latent row: a decode step in
+  the output), so every key is read as a latent row, in
   two parts, all rows' queries against the shared prefix's rows in one
   product and each row against its own pages through its page table,
-  merged by their softmax sums; a prefill chunk over prefix, continued
-  and own rows in one kernel (ops/pallas_mla.py). Heads are
-  materialised only in the reference. The latent attention itself lives
+  merged by their softmax sums; a prefill chunk over MATERIALISED heads,
+  the prefix's expanded once and held by the engine (``prefix_heads``),
+  the continued and own rows' a chunk, in the one chunk kernel
+  (ops/pallas_attention.py). The latent attention itself lives
   in models/lm/mla.py (``qkv``, ``mla_decode``, ``mla_prefill``), which
   models/lm/kimi_linear.py calls too; this family hands it the rotation
   and a query down-projection.
@@ -294,16 +295,30 @@ def state_shapes(cfg: Config, n_pages: int, page_tokens: int,
         BF16)}
 
 
+def prefix_heads_shapes(cfg: Config, rows: int) -> list:
+    """``mla.prefix_heads_shapes``: every layer is a latent one."""
+    return mla.prefix_heads_shapes(cfg, rows, cfg.layers)
+
+
+def prefix_heads(cfg: Config, params: dict, state, prefix_pages) -> list:
+    """``mla.prefix_heads`` of every layer."""
+    return mla.prefix_heads(cfg, params["layers"], state["pages"],
+                            prefix_pages)
+
+
 def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
                   dest_page, dest_off, prefix_pages, n_prefix, cont_pages,
-                  n_cont, last_idx, seg_from=None, seg_to=None):
+                  n_cont, last_idx, seg_from=None, seg_to=None,
+                  prefix_heads=None):
     """A packed chunk of new tokens through every layer. Writes their
     latent rows to ``state["pages"][layer, dest_page, dest_off]`` and
     returns the state, the logits rows ``last_idx`` (each segment's last
     token) as ``(top, ids)``, and the expert layers' counts (``experts.moe``)
     summed over the layers. ``prefix_pages``/``cont_pages`` may be
     ``None`` (no shared prefix; no sequence that continues from an
-    earlier chunk).
+    earlier chunk). ``prefix_heads``: the prefix's held heads
+    (``prefix_heads``), read and never written (None: each layer expands
+    the prefix's rows itself).
     ``seg_from``/``seg_to`` name slot state, of which this family has
     none."""
     cache = state["pages"]
@@ -318,7 +333,8 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
             a = mla_prefill(
                 cfg, lp, qn, qr, lat, seg,
                 common.layer_page_rows(cache, i, prefix_pages), n_prefix,
-                common.layer_page_rows(cache, i, cont_pages), n_cont)
+                common.layer_page_rows(cache, i, cont_pages), n_cont,
+                prefix_heads[i] if prefix_heads else None)
             cache = cache.at[i, dest_page, dest_off].set(lat)
             x = x + a
         h = rms_norm(x, lp["post_norm"], cfg.eps)

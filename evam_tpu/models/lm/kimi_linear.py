@@ -475,9 +475,19 @@ def _layers(cfg: Config, params: dict, x, state, live, kda_layer, mla_layer):
     return x, {"pages": pages, "kda": kda, "conv": conv}, held
 
 
+def prefix_heads_shapes(cfg: Config, rows: int) -> list:
+    """``mla.prefix_heads_shapes`` of the MLA layers."""
+    return mla.prefix_heads_shapes(cfg, rows, len(cfg.mla_ids))
+
+
+def prefix_heads(cfg: Config, params: dict, state, prefix_pages) -> list:
+    """``mla.prefix_heads`` of the MLA layers."""
+    return mla.prefix_heads(cfg, params["mla"], state["pages"], prefix_pages)
+
+
 def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
                   dest_page, dest_off, prefix_pages, n_prefix, cont_pages,
-                  n_cont, last_idx, seg_from, seg_to):
+                  n_cont, last_idx, seg_from, seg_to, prefix_heads=None):
     """A packed chunk of new tokens through every layer. MLA layers write
     the tokens' latent rows to ``state["pages"][layer, dest_page,
     dest_off]``; KDA layers start segment ``s`` from slot-state row
@@ -487,7 +497,9 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
     the rows before a segment's aligned start) move no state. Returns the
     state, the logits rows ``last_idx`` as ``(top, ids)`` and the expert
     layers' counts (``experts.moe``). ``pos`` is not used: no layer has a
-    positional term."""
+    positional term. ``prefix_heads``: the prefix's held heads
+    (``prefix_heads``), read and never written (None: an MLA layer expands
+    the prefix's rows itself)."""
 
     def kda_layer(lp, l, x, kda, conv):
         with jax.named_scope("kda"):
@@ -506,7 +518,8 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
             x = x + mla.mla_prefill(
                 cfg, lp, qn, qr, lat, seg,
                 common.layer_page_rows(pages, j, prefix_pages), n_prefix,
-                common.layer_page_rows(pages, j, cont_pages), n_cont)
+                common.layer_page_rows(pages, j, cont_pages), n_cont,
+                prefix_heads[j] if prefix_heads else None)
             pages = pages.at[j, dest_page, dest_off].set(lat)
         return x, pages
 

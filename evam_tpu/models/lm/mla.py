@@ -1,4 +1,4 @@
-"""Latent attention (MLA) in the absorbed form, for every family that has it
+"""Latent attention (MLA), for every family that has it
 (models/lm/deepseek_v2.py: rotated, with a query down-projection;
 models/lm/kimi_linear.py: position-free, the query projected directly).
 
@@ -8,15 +8,27 @@ at the published widths) in a row of whole lane tiles
 (``common.row_width``: 640), so that the cache lies rows-minor on the chip
 and enters and leaves both step programs where it lies; ``[k_nope ; v] =
 W_kvb c_kv`` per head; ``score = (q_nope . k_nope + q_r . k_r) * scale``.
+
+The two step programs take the two sides of one trade. A DECODE step (one
+query a row, bound by the bytes of the cache) runs in the ABSORBED form:
 ``W_kvb``'s key half is folded into the query and its value half into the
-output, so every key is read as a latent row: the absorbed query's rope
+output, so every key is read as a latent row (the absorbed query's rope
 part is followed by as many zeros as the stored row's, zeros against
-zeros, so a product over "the whole row" is the one over ``latent``; a
-decode step in two parts,
-all rows' queries against the shared prefix's rows in one product and each
-row against its own pages, merged by their softmax sums; a prefill chunk
-over prefix, continued and own rows in one kernel (ops/pallas_mla.py).
-Heads are materialised only in the references.
+zeros, so a product over "the whole row" is the one over ``latent``), in
+two parts, all rows' queries against the shared prefix's rows in one
+product and each row against its own pages, merged by their softmax sums.
+A PREFILL chunk (512 tokens a row of the cache, bound by its products)
+runs over MATERIALISED heads, the form the references compute: a query-key
+pair costs ``nope + rope + v_dim`` multiply-adds (320; 384 with the zeros
+of a row's last lane tile) where the absorbed form spends ``2 kv_rank +
+rope`` (1 088; 1 152 with those zeros). The shared
+prefix's heads are expanded ONCE, when warm-up has prefilled its rows
+(``expand``), and held on the device beside the weights
+(engine/generate.py); the continued and the chunk's own rows are expanded
+a chunk; the rope part stays the ONE list all heads share, as it lies in a
+stored row. The chunk kernel (ops/pallas_attention.py) walks the held
+heads and the new rows' as two lists, so nothing is copied behind the
+prefix's 134 MB a layer.
 
 What differs between the families is data of the config: ``q_rank`` (0:
 no query down-projection, the layer holds ``q``; else ``q_a``,
@@ -28,11 +40,12 @@ gives ``heads``, ``kv_rank``, ``nope``, ``rope``, ``v_dim``, ``latent``,
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from evam_tpu.models.lm import common
 from evam_tpu.models.lm.common import BF16, F32, es, mm, rms_norm
-from evam_tpu.ops import pallas_mla
+from evam_tpu.ops import pallas_attention
 
 
 def tensor_shapes(cfg) -> dict[str, tuple]:
@@ -133,31 +146,61 @@ def mla_decode(cfg, lp: dict, q_nope, q_rope, ctx, ctx_len, prefix,
     return mm(o.reshape(o.shape[0], -1), lp["o"])
 
 
-def mla_prefill(cfg, lp: dict, q_nope, q_rope, lat, seg, prefix,
-                n_prefix, cont, n_cont):
-    """A packed chunk, absorbed form throughout: every (token, head) is
-    one query row over ONE list of stored rows (``qkv``): the shared prefix
-    rows ``prefix`` [Tp, width] (visible below ``n_prefix``), the earlier
-    rows ``cont`` [Tc, width] of the sequence that continues in this
-    chunk (below ``n_cont``, to segment 0 only) and the chunk's own rows
-    ``lat`` (a token sees its segment's, up to itself). ``prefix`` and
-    ``cont`` may be None. The scores stay on the chip
-    (ops/pallas_mla.py), which takes the rope part as it lies: the row's
-    last lane tile, zeros included."""
-    t = lat.shape[0]
+def prefix_heads_shapes(cfg, rows: int, layers: int) -> list:
+    """What the engine holds of a shared prefix of ``rows`` rows beside the
+    weights: per latent layer its heads ``(k_nope, v)`` as ``expand`` gives
+    them."""
+    return [(jax.ShapeDtypeStruct((cfg.heads, rows, cfg.nope), BF16),
+             jax.ShapeDtypeStruct((cfg.heads, rows, cfg.v_dim), BF16))
+            ] * layers
+
+
+def prefix_heads(cfg, layers: list, pages, prefix_pages) -> list:
+    """The heads of the prefix's cached rows as they lie in ``prefix_pages``
+    of ``pages`` [latent layers, pages, page_tokens, width] now, per latent
+    layer (``layers``: their tensors, in the cache's order)."""
+    return [expand(cfg, lp, common.layer_page_rows(pages, i, prefix_pages))
+            for i, lp in enumerate(layers)]
+
+
+def expand(cfg, lp: dict, rows):
+    """The heads of stored rows ``rows`` [S, width] (``qkv``): ``(k_nope
+    [heads, S, nope], v [heads, S, v_dim])``, written heads-major by the
+    products themselves."""
     w_uk, w_uv = kv_b(cfg, lp)
-    q_lat, q_tail = (q.reshape(t * cfg.heads, -1)
-                     for q in absorb_q(cfg, w_uk, q_nope, q_rope))
-    keys = jnp.concatenate(
-        [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
+    c_kv = rows[:, :cfg.kv_rank]
+    return (es("sc,hcd->hsd", c_kv, w_uk).astype(BF16),
+            es("sc,hcv->hsv", c_kv, w_uv).astype(BF16))
+
+
+def mla_prefill(cfg, lp: dict, q_nope, q_rope, lat, seg, prefix,
+                n_prefix, cont, n_cont, prefix_heads=None):
+    """A packed chunk over materialised heads: every head's tokens are its
+    query rows over that head's keys and values of the shared prefix rows
+    ``prefix`` [Tp, width] (visible below ``n_prefix``), the earlier rows
+    ``cont`` [Tc, width] of the sequence that continues in this chunk
+    (below ``n_cont``, to segment 0 only) and the chunk's own rows ``lat``
+    (a token sees its segment's, up to itself). ``prefix`` and ``cont`` may
+    be None. ``prefix_heads``: ``expand`` of ``prefix``, which the engine
+    holds (None: expanded here, a chunk); the continued and own rows' heads
+    are expanded here, and the two lists go to the chunk kernel as they are
+    (ops/pallas_attention.py), with the rope part as the one list all heads
+    share, as it lies: a row's last lane tile, zeros included. The scores
+    stay on the chip."""
+    t = lat.shape[0]
+    new = lat if cont is None else jnp.concatenate([cont, lat], axis=0)
+    k, v = expand(cfg, lp, new)
+    k_r = new[:, cfg.kv_rank:]
+    if prefix is not None:
+        k_p, v_p = (expand(cfg, lp, prefix) if prefix_heads is None
+                    else prefix_heads)
+        k, v, k_r = (k_p, k), (v_p, v), (prefix[:, cfg.kv_rank:], k_r)
     bounds, b0 = common.chunk_bounds(
         seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
         0 if cont is None else cont.shape[0])
-    attend = (pallas_mla.latent_attention if common.on_tpu()
-              else pallas_mla.latent_attention_xla)
-    o_lat = attend(
-        q_lat, q_tail, keys[:, :cfg.kv_rank], keys[:, cfg.kv_rank:],
-        jnp.repeat(bounds, cfg.heads, axis=0),
-        scale=cfg.softmax_scale, b0=b0)
-    o = es("thc,hcv->thv", o_lat.reshape(t, cfg.heads, cfg.kv_rank), w_uv)
-    return mm(o.astype(BF16).reshape(t, -1), lp["o"])
+    attend = (pallas_attention.chunk_attention if common.on_tpu()
+              else pallas_attention.chunk_attention_xla)
+    o = attend(q_nope.transpose(1, 0, 2), k, v, bounds,
+               _tail(cfg, q_rope).transpose(1, 0, 2), k_r,
+               scale=cfg.softmax_scale, b0=b0)
+    return mm(o.transpose(1, 0, 2).reshape(t, -1), lp["o"])

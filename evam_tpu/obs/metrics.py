@@ -72,6 +72,10 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_generate_state_rows": ("counter", ("kind",)),
     "evam_generate_prefix_restores": ("counter", ()),
     "evam_generate_state_bytes": ("gauge", ()),
+    # a family with latent attention (DeepSeek-V2, Kimi-Linear): the bytes
+    # of the shared prefix's materialised heads, held beside the weights
+    # for the prefill program (0 for every other family)
+    "evam_generate_prefix_heads_bytes": ("gauge", ()),
     # of a decode step's rows read, those of the shared prefix: read
     # once a step for all its live rows (prefix rows x live rows)
     "evam_generate_decode_shared_rows": ("counter", ()),

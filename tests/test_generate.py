@@ -34,9 +34,10 @@ from evam_tpu.engine.generate import (
     next_step_kind,
 )
 from evam_tpu.engine.pages import PagePool
-from evam_tpu.models.lm import common, family
+from evam_tpu.models.lm import common, family, mla
 from evam_tpu.models.lm import deepseek_v2 as lm
 from evam_tpu.models.lm.presets import DEEPSEEK_V2_PUBLISHED, PRESETS
+from evam_tpu.ops import pallas_attention
 
 REPO = Path(__file__).resolve().parent.parent
 TINY = PRESETS["deepseek_v2_tiny"]
@@ -77,6 +78,15 @@ def engine():
 def _generate(eng, prompt, n=NEW, stream="s"):
     return eng.submit(stream=stream, prompt_ids=prompt,
                       max_new_tokens=n).result(timeout=300)
+
+
+def _idle(eng, timeout=10):
+    """Wait until every sequence has left the engine."""
+    deadline = time.time() + timeout
+    while ((eng.pages_in_use()[0] != eng._prefix_pages
+            or len(eng._free_slots) != eng.sizes.slots)
+           and time.time() < deadline):
+        time.sleep(0.05)
 
 
 def _ref_logits(prefix, prompt, result, **kw):
@@ -1055,26 +1065,314 @@ def test_the_describe_cells_streams_against_the_modelled_capacity(
     assert stopped == refused_at
 
 
+# ------------------------------------------- prefill over materialised heads
+
+
+def _heads_of_the_cache(eng):
+    """Every latent layer's ``(k_nope, v)`` of the prefix's cached rows as
+    they lie now: through ``mla.expand`` as the engine's program, and in
+    float64 from the rows and ``W_kvb``."""
+    import jax
+
+    cfg, pinned = eng.cfg, np.asarray(eng._shared, np.int32)
+    made = jax.jit(lambda params, state: lm.prefix_heads(
+        cfg, params, state, pinned))(eng._params, eng._state)
+    exact = []
+    for i, lp in enumerate(eng._params["layers"]):
+        rows = np.asarray(common.layer_page_rows(
+            eng._state["pages"], i, pinned).astype(jnp.float32), np.float64)
+        w = np.asarray(lp["kv_b"].astype(jnp.float32), np.float64).reshape(
+            cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
+        both = np.einsum("sc,chd->hsd", rows[:, :cfg.kv_rank], w)
+        exact.append((both[..., :cfg.nope], both[..., cfg.nope:]))
+    return made, exact
+
+
+def _bits(tree):
+    import jax
+
+    return [np.asarray(a) for a in jax.tree.leaves(tree)]
+
+
+def test_the_prefixs_heads_are_made_in_warm_up_and_never_touched(engine):
+    """After warm-up the engine holds, per layer, ``W_kvb`` of the prefix's
+    cached rows (``k_nope`` and ``v`` [heads, 16, 16], heads-major), beside
+    the weights; fifty and more prefill chunks and decode steps later they
+    are the same arrays, bit for bit: the prefill program reads them and
+    neither writes nor donates them, the decode program never sees them."""
+    import jax
+
+    cfg = engine.cfg
+    held = engine._prefix_heads
+    assert len(held) == cfg.layers == 3
+    made, exact = _heads_of_the_cache(engine)
+    for (k, v), (k_made, v_made), (k_64, v_64) in zip(held, made, exact):
+        assert k.shape == (cfg.heads, 16, cfg.nope) and k.dtype == jnp.bfloat16
+        assert v.shape == (cfg.heads, 16, cfg.v_dim)
+        assert (np.asarray(k) == np.asarray(k_made)).all()
+        assert (np.asarray(v) == np.asarray(v_made)).all()
+        for got, want in ((k, k_64), (v, v_64)):
+            got = np.asarray(got.astype(jnp.float32))
+            assert np.abs(want).max() > 0.1
+            np.testing.assert_allclose(got, want, atol=0.01, rtol=0.01)
+    assert engine.prefix_heads_bytes() == sum(a.nbytes for a in _bits(held))
+    leaves, before = jax.tree.leaves(held), _bits(held)
+    _idle(engine)
+    steps = engine.stats.batches
+    futs = [engine.submit(stream=f"h{i}", prompt_ids=_prompt(300 + i, 40),
+                          max_new_tokens=NEW) for i in range(24)]
+    for f in futs:
+        assert len(f.result(timeout=300)["ids"]) == NEW
+    _idle(engine)
+    assert engine.stats.batches - steps >= 50
+    after = jax.tree.leaves(engine._prefix_heads)
+    assert all(a is b and not a.is_deleted() for a, b in zip(after, leaves))
+    assert all((a == b).all() for a, b in zip(_bits(after), before))
+
+
+def test_a_rebuilt_engine_holds_the_same_heads_and_a_long_prefix_fills_them(
+        engine):
+    """An engine built again (the supervisor's rebuild is a new object
+    that warms up anew) over the same prefix holds, bit for bit, the heads
+    the first one holds. And over a prefix of three chunks (80 tokens of
+    32 a chunk: chunk ``lo`` attends to the rows ``[0, lo)`` through the
+    heads the chunks before it left) the held heads are those of the whole
+    prefix's cached rows, and a generation behind it agrees with the
+    reference."""
+    again = _engine(_prefix(), "generate:again",
+                    dataclasses.replace(SIZES, slots=1))
+    try:
+        assert all((a == b).all() for a, b in zip(
+            _bits(again._prefix_heads), _bits(engine._prefix_heads)))
+        assert again.prefix_heads_bytes() == engine.prefix_heads_bytes()
+    finally:
+        again.stop()
+    long = _engine(_prefix(80), "generate:long",
+                   dataclasses.replace(SIZES, slots=1))
+    try:
+        made, _ = _heads_of_the_cache(long)
+        assert all((a == b).all() and a.any() for a, b in zip(
+            _bits(long._prefix_heads), _bits(made)))
+        assert long._prefix_heads[0][0].shape == (4, 80, 16)
+        prompt = _prompt(77, 20)
+        out = _generate(long, prompt)
+        problems, stats = lm_compare.compare_logits(
+            out, *_ref_logits(long.prefix, prompt, out, margins=True))
+        assert not problems, (problems, stats)
+        assert stats["flipped"] == 0 and stats["max"] < 0.2
+    finally:
+        long.stop()
+
+
+def test_the_engines_row_and_a_gauge_name_the_held_heads(engine):
+    """``/engines`` and a gauge give the held heads' bytes where
+    ``memory_peak_bytes`` is read, and the cached rows a chunk READ (the
+    prefix's and the continued sequence's, once a layer) are counted as
+    they were: benchmark/opsbytes/deepseek_v2.py reads them so."""
+    from evam_tpu.engine.hub import EngineHub
+    from evam_tpu.obs import metrics
+
+    def counted():
+        c = metrics.get_counter
+        return {"read": c("evam_generate_latent_rows_read",
+                          {"kind": "prefill"}),
+                "tokens": c("evam_generate_tokens", {"kind": "decode"})}
+
+    _idle(engine)
+    before = counted()
+    _generate(engine, _prompt(41, 40))   # chunks of 32 and 8 tokens
+    deadline = time.time() + 10
+    while (counted()["tokens"] - before["tokens"] < NEW - 1
+           and time.time() < deadline):
+        time.sleep(0.05)
+    # the first chunk beside the prefix's 16 rows; the second beside them
+    # and the 32 continued
+    assert counted()["read"] - before["read"] == 16 + (16 + 32)
+    cfg = engine.cfg
+    want = cfg.layers * cfg.heads * 16 * (cfg.nope + cfg.v_dim) * 2
+    row = EngineHub._stat_row(engine, None, None, engine.name)
+    assert row["prefix_heads_bytes"] == engine.prefix_heads_bytes() == want
+    assert "evam_generate_prefix_heads_bytes" in metrics.render()
+    assert metrics.get_gauge("evam_generate_prefix_heads_bytes") == want
+    # at the deployment's sizes, from the shapes alone
+    for preset, n_bytes in (("deepseek_v2_ep8", 805_306_368),
+                            ("kimi_linear_ep4", 67_108_864)):
+        fam = family(PRESETS[preset]["model_type"])
+        shapes = fam.prefix_heads_shapes(
+            fam.Config.from_dict(PRESETS[preset]), 2048)
+        assert sum(a.size * a.dtype.itemsize for pair in shapes
+                   for a in pair) == n_bytes
+    for preset in ("jamba2_3b", "lfm2_moe_ep2", "laguna_xs2_pp8"):
+        assert not hasattr(family(PRESETS[preset]["model_type"]),
+                           "prefix_heads_shapes")
+
+
+def _absorbed_prefill(cfg, lp, q_nope, q_rope, lat, seg, prefix, n_prefix,
+                      cont, n_cont, prefix_heads=None):
+    """``mla.mla_prefill`` in the ABSORBED form it had until PR 45, through
+    XLA: every (token, head) one query folded through ``W_uk`` over ONE
+    list of stored rows, a row's value its ``c_kv``, the output through
+    ``W_uv``. Held heads are of no use to it."""
+    t = lat.shape[0]
+    w_uk, w_uv = mla.kv_b(cfg, lp)
+    q = jnp.concatenate(mla.absorb_q(cfg, w_uk, q_nope, q_rope), axis=-1)
+    keys = jnp.concatenate(
+        [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
+    bounds, b0 = common.chunk_bounds(
+        seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
+        0 if cont is None else cont.shape[0])
+    seen = pallas_attention._visible(
+        jnp.arange(keys.shape[0])[None, :], bounds, b0)[:, None, :]
+    o_lat = common.merge_softmax_sums(common.softmax_sums(
+        cfg.softmax_scale, "thc,sc->ths", "ths,sc->thc", q, keys,
+        keys[:, :cfg.kv_rank], seen), None).astype(jnp.bfloat16)
+    o = common.es("thc,hcv->thv", o_lat, w_uv).astype(jnp.bfloat16)
+    return common.mm(o.reshape(t, -1), lp["o"])
+
+
+def test_generations_are_token_for_token_what_the_absorbed_form_gave(
+        engine, monkeypatch):
+    """An engine whose chunks attend in the absorbed form (the program as
+    it stood, decode untouched) and the engine over materialised heads
+    generate the same ids, one chunk or two (a prompt of 40 continues in a
+    second chunk), with top logits that agree to bfloat16's roundings. The
+    tiny model's logits lie close: where the absorbed form's two best stood
+    within those roundings of each other the other may be sampled, and the
+    generations part there."""
+    monkeypatch.setattr(lm, "mla_prefill", _absorbed_prefill)
+    old = _engine(_prefix(), "generate:absorbed",
+                  dataclasses.replace(SIZES, slots=2))
+    monkeypatch.undo()
+    same = 0
+    try:
+        for seed, n in ((51, 33), (52, 3), (53, 20), (58, 40)):
+            prompt = _prompt(seed, n)
+            was, now = _generate(old, prompt), _generate(engine, prompt)
+            assert len(now["ids"]) == len(was["ids"]) == NEW
+            for i in range(NEW):
+                a, b = (np.asarray(r["top_logits"][i]) for r in (was, now))
+                if now["ids"][i] != was["ids"][i]:
+                    assert a[0] - a[1] < 0.1 and i > 0
+                    break
+                np.testing.assert_allclose(b, a, atol=0.1, rtol=0)
+                same += 1
+    finally:
+        old.stop()
+    assert same >= 3 * NEW
+
+
+@pytest.mark.parametrize("case", ["plain", "continued", "no_prefix",
+                                  "warm_up"])
+@pytest.mark.parametrize("preset", ["deepseek_v2_tiny", "kimi_linear_tiny",
+                                    "deepseek_v2_ep8", "kimi_linear_ep4"])
+def test_prefill_over_materialised_heads_is_the_absorbed_arithmetic(
+        preset, case):
+    """``mla.mla_prefill`` (heads materialised: the prefix's handed as the
+    engine holds them, the continued and own rows' expanded in the call,
+    the rope part one list for all heads) against the ABSORBED arithmetic
+    it replaced: in bfloat16 as it ran (``_absorbed_prefill``) and written
+    out in float64, per token and head a query folded through ``W_uk`` over
+    latent rows, the output through ``W_uv``. At both families' tiny
+    presets and at their published attention widths (128 and 32 heads,
+    rank 512, 128 + 64 | 128) over a hidden size cut to 64; a packed chunk
+    of three segments and dead rows, the prefix part-visible; ``case``:
+    with a sequence that continues; with no prefix at all; and warm-up's
+    own, the held heads and the prefix's rows valid only below ``n_prefix``
+    (what lies behind is whatever the pages held: it must not be read)."""
+    fam = family(PRESETS[preset]["model_type"])
+    cfg = dataclasses.replace(fam.Config.from_dict(PRESETS[preset]),
+                              hidden=64)
+    rng = np.random.default_rng(cfg.heads + len(case))
+    width = common.row_width(cfg.latent)
+    h, c = cfg.heads, cfg.kv_rank
+
+    def bf16(a):
+        return jnp.asarray(a, jnp.float32).astype(jnp.bfloat16)
+
+    def f64(a):
+        return np.asarray(a.astype(jnp.float32), np.float64)
+
+    def rows(n):
+        return bf16(np.pad(rng.normal(size=(n, cfg.latent)),
+                           ((0, 0), (0, width - cfg.latent))))
+
+    lp = {"kv_b": bf16(rng.normal(size=(c, h * (cfg.nope + cfg.v_dim)))
+                       * c ** -0.5),
+          "o": bf16(rng.normal(size=(h * cfg.v_dim, cfg.hidden))
+                    * (h * cfg.v_dim) ** -0.5)}
+    seg = np.array([0] * 5 + [1] * 3 + [-1] * 2 + [2] * 4 + [-1] * 2)
+    continued = case == "continued"
+    t, n_cont = len(seg), 5 if continued else 0
+    n_rows, n_prefix = (0, 0) if case == "no_prefix" else (16, 11)
+    prefix = rows(n_rows) if n_rows else None
+    cont, lat = rows(8) if continued else None, rows(t)
+    q_nope = bf16(rng.normal(size=(t, h, cfg.nope)))
+    q_rope = bf16(rng.normal(size=(t, h, cfg.rope)))
+    held = None
+    if n_rows:
+        held = mla.expand(cfg, lp, prefix)
+        assert [a.shape for a in held] == [
+            a.shape for a in mla.prefix_heads_shapes(cfg, 16, 1)[0]]
+    args = (cfg, lp, q_nope, q_rope, lat, jnp.asarray(seg))
+    got = f64(mla.mla_prefill(*args, prefix, n_prefix, cont, n_cont, held))
+    if n_rows:
+        # a caller that holds nothing gets the same: the prefix expanded
+        # in the call
+        unheld = f64(mla.mla_prefill(*args, prefix, n_prefix, cont, n_cont))
+        assert (unheld == got).all()
+    if case == "warm_up":
+        # chunk ``lo`` of the prefix's own prefill: rows and heads at and
+        # behind ``n_prefix`` are not the prefix's yet
+        stale = jnp.arange(n_rows)[:, None] >= n_prefix
+        early = f64(mla.mla_prefill(
+            *args, jnp.where(stale, 7.0, prefix).astype(jnp.bfloat16),
+            n_prefix, cont, n_cont,
+            tuple(jnp.where(stale[None], -5.0, a).astype(jnp.bfloat16)
+                  for a in held)))
+        assert (early == got).all()
+    was = f64(_absorbed_prefill(*args, prefix, n_prefix, cont, n_cont))
+    np.testing.assert_allclose(got, was, atol=0.03, rtol=0)
+
+    w = f64(lp["kv_b"]).reshape(c, h, cfg.nope + cfg.v_dim)
+    w_uk, w_uv = w[..., :cfg.nope], w[..., cfg.nope:]
+    keys = np.concatenate([f64(r)[:, :cfg.latent] for r in (
+        prefix, cont, lat) if r is not None])
+    base = n_rows + (8 if continued else 0)
+    want = np.zeros((t, cfg.hidden))
+    for i in range(t):
+        if seg[i] < 0:
+            continue
+        start = int(np.argmax(seg == seg[i]))
+        seen = list(range(n_prefix)) + list(range(base + start, base + i + 1))
+        if seg[i] == 0:
+            seen += list(range(n_rows, n_rows + n_cont))
+        q = np.concatenate([np.einsum("hd,chd->hc", f64(q_nope)[i], w_uk),
+                            f64(q_rope)[i]], axis=1)
+        sc = q @ keys[seen].T * cfg.softmax_scale
+        pr = np.exp(sc - sc.max(axis=1, keepdims=True))
+        pr /= pr.sum(axis=1, keepdims=True)
+        o = np.einsum("hc,chv->hv", pr @ keys[seen][:, :c], w_uv)
+        want[i] = o.reshape(-1) @ f64(lp["o"])
+    assert np.abs(want).max() > 0.5
+    # bfloat16 roundings of the expanded keys and values, the weights of
+    # the softmax, the heads' outputs and the result
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0)
+    assert not got[seg < 0].any()
+    if continued:
+        # the continued rows are in segment 0's softmax and in no other's
+        alone = f64(mla.mla_prefill(*args, prefix, n_prefix, cont, 0, held))
+        assert np.abs(alone - got)[seg == 0].max() > 0.05
+        assert (alone == got)[seg != 0].all()
+
+
 # ------------------------------------------------------ the Pallas kernel
 
 
-@pytest.mark.parametrize("zeros", [0, 64])
-@pytest.mark.parametrize("blocks", [(32, 128), (96, 256)])
-def test_latent_attention_kernel_matches_its_xla_twin(blocks, zeros):
-    """ops/pallas_mla.py in the interpreter against the same arithmetic
-    through XLA: three visible intervals per row, rows that see nothing,
-    rows and keys that do not fill whole blocks. ``zeros``: the kernel is
-    handed the rope parts as they lie in a stored row, that many zero
-    columns behind them (128 wide), the twin the unpadded ones."""
-    from evam_tpu.ops.pallas_mla import (
-        latent_attention,
-        latent_attention_xla,
-    )
-
-    rng = np.random.default_rng(0)
-    r, c, p, s = 96, 128, 64, 200
-    args = [jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
-            for sh in ((r, c), (r, p), (s, c), (s, p))]
+def _three_intervals(rng, r):
+    """Bounds of ``r`` rows over 100 prefix rows, 50 continued and 50 own:
+    three visible intervals a row, a row that sees nothing, one whose
+    intervals are all empty, one that sees the whole prefix and one own
+    row."""
     b = np.zeros((r, 4), np.int32)
     b[:, 0] = rng.integers(0, 100, r)
     b[:, 1] = 100 + rng.integers(0, 40, r)
@@ -1083,12 +1381,228 @@ def test_latent_attention_kernel_matches_its_xla_twin(blocks, zeros):
     b[5] = 0                       # a padded token: sees nothing
     b[6] = [0, 100, 150, 150]      # three empty intervals
     b[7] = [100, 100, 199, 200]    # the whole prefix and one own row
-    want = latent_attention_xla(*args, jnp.asarray(b), scale=0.1, b0=100)
-    as_stored = [jnp.pad(a, ((0, 0), (0, zeros))) if a.shape[1] == p else a
-                 for a in args]
-    got = latent_attention(*as_stored, jnp.asarray(b), scale=0.1, b0=100,
-                           block_q=blocks[0], block_k=blocks[1],
-                           interpret=True)
+    return b
+
+
+@pytest.mark.parametrize("lists", [1, 2])
+@pytest.mark.parametrize("zeros", [0, 64])
+@pytest.mark.parametrize("blocks", [(32, 128), (96, 256)])
+def test_chunk_kernel_with_a_shared_score_term_matches_its_xla_twin(
+        blocks, zeros, lists):
+    """ops/pallas_attention.py as the latent families call it, in the
+    interpreter against the same arithmetic through XLA: every head a
+    key-value head of group 1, a second score term over a part all heads
+    share; three visible intervals per row, rows that see nothing, rows
+    and keys that do not fill whole blocks. ``zeros``: the kernel is handed
+    the rope parts as they lie in a stored row, that many zero columns
+    behind them (128 wide), the twin the unpadded ones. ``lists``: the keys
+    as one list, or as the prefix's 100 rows and the rest's 100 that the
+    key axis walks one after the other (neither a whole block: the prefix's
+    padded tail must stay hidden from the intervals behind it)."""
+    from evam_tpu.ops.pallas_attention import (
+        chunk_attention,
+        chunk_attention_xla,
+    )
+
+    rng = np.random.default_rng(0)
+    g, r, d, p, s = 3, 96, 32, 64, 200
+    q, q_r, k, v, k_r = (
+        jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
+        for sh in ((g, r, d), (g, r, p), (g, s, d), (g, s, d), (s, p)))
+    b = jnp.asarray(_three_intervals(rng, r))
+    want = chunk_attention_xla(q, k, v, b, q_r, k_r, scale=0.1, b0=100)
+
+    def stored(a):
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, zeros)])
+
+    def split(a, axis):
+        return a if lists == 1 else tuple(jnp.split(a, [100], axis=axis))
+
+    got = chunk_attention(
+        q, split(k, 1), split(v, 1), b, stored(q_r), split(stored(k_r), 0),
+        scale=0.1, b0=100, block_q=blocks[0], block_k=blocks[1],
+        interpret=True)
+    if lists == 2:
+        # the twin takes the two lists too, one behind the other
+        again = chunk_attention_xla(
+            q, split(k, 1), split(v, 1), b, q_r, split(k_r, 0), scale=0.1,
+            b0=100)
+        assert (np.asarray(again) == np.asarray(want)).all()
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.abs(got - want).max() < 0.02
-    assert not got[5].any() and not got[6].any() and got[7].any()
+    assert not got[:, 5].any() and not got[:, 6].any() and got[:, 7].any()
+    # the shared term is in the scores: without it the answer is another
+    bare = np.asarray(chunk_attention_xla(q, k, v, b, scale=0.1, b0=100),
+                      np.float32)
+    assert np.abs(bare - want).max() > 0.1
+
+
+@pytest.mark.parametrize("columns", [4, 6])
+@pytest.mark.parametrize("lists", [1, 2])
+def test_chunk_kernel_with_a_value_width_of_its_own_matches_its_xla_twin(
+        lists, columns):
+    """Keys of 64 and values of 32: the output, and the running sums in
+    VMEM, are as wide as the VALUES. In the interpreter against the twin,
+    under bounds of 4 columns and of 6 (a first visible row of the two
+    leading intervals), the keys as one list and as two."""
+    from evam_tpu.ops.pallas_attention import (
+        chunk_attention,
+        chunk_attention_xla,
+    )
+
+    rng = np.random.default_rng(columns)
+    g, r, d, dv, s = 2, 96, 64, 32, 200
+    q, k, v = (jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
+               for sh in ((g, r, d), (g, s, d), (g, s, dv)))
+    b = _three_intervals(rng, r)
+    if columns == 6:
+        b = np.concatenate([b, np.maximum(b[:, :1] - 30, 0),
+                            np.maximum(b[:, 1:2] - 10, 100)], axis=1)
+    b = jnp.asarray(b)
+    want = chunk_attention_xla(q, k, v, b, scale=0.1, b0=100)
+
+    def split(a):
+        return a if lists == 1 else tuple(jnp.split(a, [100], axis=1))
+
+    got = chunk_attention(q, split(k), split(v), b, scale=0.1, b0=100,
+                          block_q=32, block_k=128, interpret=True)
+    assert got.shape == want.shape == (g, r, dv)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.abs(got - want).max() < 0.02
+    assert not got[:, 5].any() and not got[:, 6].any() and got[:, 7].any()
+    if columns == 6:
+        # the lower bounds hide rows: without them the answer is another
+        bare = np.asarray(chunk_attention_xla(q, k, v, b[:, :4], scale=0.1,
+                                              b0=100), np.float32)
+        assert np.abs(bare - want).max() > 0.05
+
+
+def _chunk_attention_of_pr_42(q, k, v, bounds, *, scale, b0, block_q,
+                              block_k):
+    """The chunk kernel as it stood before it took a shared score term and
+    two key lists (PR 40's, with PR 42's bounds), in the interpreter: what
+    LFM2's and Laguna's chunks ran, kept here to be compared with."""
+    import functools
+
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32, neg = jnp.float32, -1e30
+
+    def visible(col, bounds):
+        a, b1, c0, c1 = (bounds[:, i:i + 1] for i in range(4))
+        if bounds.shape[1] == 4:
+            return ((col < a) | ((col >= b0) & (col < b1))
+                    | ((col >= c0) & (col < c1)))
+        a_lo, b_lo = bounds[:, 4:5], bounds[:, 5:6]
+        return (((col >= a_lo) & (col < a)) | ((col >= b_lo) & (col < b1))
+                | ((col >= c0) & (col < c1)))
+
+    def kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+               acc_ref):
+        kv = pl.program_id(2)
+
+        @pl.when(kv == 0)
+        def _():
+            m_ref[...] = jnp.full(m_ref.shape, neg, f32)
+            l_ref[...] = jnp.zeros(l_ref.shape, f32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+        v = v_ref[...]
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32) * scale
+        col = kv * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = visible(col, bounds_ref[...])
+        s = jnp.where(ok, s, neg)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m_ref[...] = m_new
+
+        @pl.when(kv == pl.num_programs(2) - 1)
+        def _():
+            l = l_ref[...]
+            o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+                o_ref.dtype)
+
+    g, r, d = q.shape
+    s = k.shape[1]
+    block_q = min(block_q, -(-r // 16) * 16)
+    block_k = min(block_k, -(-s // 128) * 128)
+    rp, sp = -(-r // block_q) * block_q, -(-s // block_k) * block_k
+
+    def pad(x, rows):
+        return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, 0)))
+
+    out = pl.pallas_call(
+        kernel, grid=(g, rp // block_q, sp // block_k),
+        in_specs=[
+            pl.BlockSpec((block_q, bounds.shape[1]), lambda h, i, j: (i, 0)),
+            pl.BlockSpec((None, block_q, d), lambda h, i, j: (h, i, 0)),
+            pl.BlockSpec((None, block_k, d), lambda h, i, j: (h, j, 0)),
+            pl.BlockSpec((None, block_k, d), lambda h, i, j: (h, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, block_q, d), lambda h, i, j: (h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((g, rp, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, 1), f32),
+                        pltpu.VMEM((block_q, 1), f32),
+                        pltpu.VMEM((block_q, d), f32)],
+        interpret=True,
+    )(jnp.pad(bounds, ((0, rp - r), (0, 0))), pad(q, rp), pad(k, sp),
+      pad(v, sp))
+    return out[:, :r]
+
+
+@pytest.mark.parametrize("family_shape,group,dim,window", [
+    ("lfm2", 4, 64, None),     # 8 key-value heads of 64 under groups of 4
+    ("laguna", 8, 128, 40),    # a window layer: bounds of six columns
+])
+def test_chunk_kernel_without_a_shared_term_is_bit_for_bit_what_it_was(
+        family_shape, group, dim, window):
+    """A family without latent layers hands no shared part and one list of
+    keys: the kernel then computes, bit for bit, what it computed before
+    it learned either (the kernel of PR 42 kept above), and traces to the
+    same program (no operand, no branch and no operation more)."""
+    import jax
+
+    from evam_tpu.ops.pallas_attention import chunk_attention
+
+    rng = np.random.default_rng(3)
+    g, t, s = 2, 24, 200
+    q, k, v = (jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
+               for sh in ((g, t * group, dim), (g, s, dim), (g, s, dim)))
+    b = _three_intervals(rng, t)
+    if window is not None:
+        lo = np.maximum(b[:, 3] - window, 0)
+        b = np.concatenate([b, np.maximum(b[:, :1] - 30, 0),
+                            np.maximum(lo[:, None], 100)], axis=1)
+        b[:, 2] = np.maximum(b[:, 2], lo)
+    b = jnp.asarray(np.repeat(b, group, axis=0))
+    kw = dict(scale=dim ** -0.5, b0=100, block_q=32, block_k=128)
+    got = chunk_attention(q, k, v, b, interpret=True, **kw)
+    was = _chunk_attention_of_pr_42(q, k, v, b, **kw)
+    assert got.dtype == was.dtype and (np.asarray(got) == np.asarray(
+        was)).all()
+    assert np.asarray(got, np.float32).any()
+
+    def kernel_of(fn):
+        """The kernel's own program, as traced."""
+        def find(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    return str(eqn.params["jaxpr"])
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    if (found := find(sub)) is not None:
+                        return found
+        return find(jax.make_jaxpr(fn)(q, k, v, b).jaxpr)
+
+    now = kernel_of(lambda *a: chunk_attention(*a, interpret=True, **kw))
+    assert now is not None and now == kernel_of(
+        lambda *a: _chunk_attention_of_pr_42(*a, **kw))

@@ -277,9 +277,9 @@ def test_eight_segments_in_one_chunk_do_not_see_each_other(engine):
     _idle(engine)
     chunks, inner = [], engine._prefill
 
-    def spy(params, state, last_ids, mat, aux):
+    def spy(params, state, last_ids, heads, mat, aux):
         chunks.append(np.array(mat[1]))
-        return inner(params, state, last_ids, mat, aux)
+        return inner(params, state, last_ids, heads, mat, aux)
 
     engine._prefill = spy
     engine._admit = lambda: None  # hold admission until all eight wait
@@ -382,6 +382,8 @@ def test_state_counters_and_the_engines_row(engine):
     grew = {k: v - before[k] for k, v in counted().items()}
     assert grew == {"decode": NEW - 1, "prefill": 2, "restores": 1,
                     "tokens": NEW - 1}
+    # no latent layer: no prefix's heads are held
+    assert engine._prefix_heads == () and engine.prefix_heads_bytes() == 0
     cfg = engine.cfg
     per_row = cfg.mamba_layers * (4 * cfg.d_state * cfg.d_inner
                                   + 2 * 3 * cfg.d_inner)
@@ -390,6 +392,8 @@ def test_state_counters_and_the_engines_row(engine):
     assert (row["state_slots"], row["state_slots_in_use"],
             row["state_bytes"]) == (8, 0, 10 * per_row)
     assert row["pages"] == 2 + 8 * 6
+    assert row["prefix_heads_bytes"] == 0
+    assert metrics.get_gauge("evam_generate_prefix_heads_bytes") == 0
     text = metrics.render()
     for series in ('evam_generate_state_rows_total{kind="decode"}',
                    "evam_generate_prefix_restores_total",

@@ -25,7 +25,7 @@ from benchmark.reference import kimi_linear_plain as ref
 from benchmark.reference.compare import check_schema
 from evam_tpu.config.settings import LMSettings, Settings
 from evam_tpu.engine.generate import GenerateEngine, GenerateSizes
-from evam_tpu.models.lm import common, experts, family
+from evam_tpu.models.lm import common, experts, family, mla
 from evam_tpu.models.lm import kimi_linear as lm
 from evam_tpu.models.lm.presets import KIMI_LINEAR_PUBLISHED, PRESETS
 from evam_tpu.ops import pallas_kda as pk
@@ -471,9 +471,9 @@ def test_eight_segments_in_one_chunk_do_not_see_each_other(engine):
     _idle(engine)
     chunks, inner = [], engine._prefill
 
-    def spy(params, state, last_ids, mat, aux):
+    def spy(params, state, last_ids, heads, mat, aux):
         chunks.append(np.array(mat[1]))
-        return inner(params, state, last_ids, mat, aux)
+        return inner(params, state, last_ids, heads, mat, aux)
 
     engine._prefill = spy
     engine._admit = lambda: None  # hold admission until all eight wait
@@ -584,7 +584,9 @@ def test_every_series_is_live_and_the_engines_row(engine):
                              {"kind": "prefill"}),
             "reads_decode": c("evam_moe_expert_reads", {"kind": "decode"}),
             "reads_prefill": c("evam_moe_expert_reads",
-                               {"kind": "prefill"})}
+                               {"kind": "prefill"}),
+            "read_prefill": c("evam_generate_latent_rows_read",
+                              {"kind": "prefill"})}
 
     _idle(engine)
     before = counted()
@@ -616,6 +618,18 @@ def test_every_series_is_live_and_the_engines_row(engine):
     assert (row["state_slots"], row["state_slots_in_use"],
             row["state_bytes"]) == (8, 0, 10 * per_row)
     assert (row["pages"], row["pages_in_use"]) == (2 + 8 * 20, 2)
+    # the prefix's heads of the ONE MLA layer, held beside the weights:
+    # W_kvb of its cached rows, [heads, 16, 16] keys and as many values
+    (k, v), = engine._prefix_heads
+    made = mla.expand(cfg, engine._params["mla"][0], common.layer_page_rows(
+        engine._state["pages"], 0, np.asarray(engine._shared, np.int32)))
+    assert k.shape == v.shape == (cfg.heads, 16, 16)
+    assert (np.asarray(k) == np.asarray(made[0])).all() and np.asarray(k).any()
+    assert (np.asarray(v) == np.asarray(made[1])).all()
+    assert row["prefix_heads_bytes"] == 2 * cfg.heads * 16 * 16 * 2
+    # two chunks of one latent layer read the prefix's 16 rows, then the
+    # prefix's and the 128 continued: the counter means what it meant
+    assert grew["read_prefill"] == 16 + (16 + 128)
     text = metrics.render()
     for series in ('evam_moe_held_experts_hit_total{kind="decode"}',
                    'evam_moe_expert_reads_total{kind="decode"}',

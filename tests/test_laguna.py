@@ -4,7 +4,7 @@ it shares with Jamba and LFM2 taking its data per LAYER KIND
 (models/lm/attention.py: two head counts, a window, two rotations of which
 one partial and rescaled, a gate on every head's output), the one
 visibility rule with a first visible row (models/lm/common.py
-``chunk_bounds``, ops/pallas_mla.py ``_visible``, the chunk kernel of
+``chunk_bounds``, ops/pallas_attention.py ``_visible``, the chunk kernel of
 ops/pallas_attention.py), the expert layer with ALL its experts held and a
 shared one (models/lm/experts.py), the fifth describe pipeline, and the
 comparison that decides the Laguna cell's ``correct``
@@ -34,7 +34,7 @@ from evam_tpu.engine.generate import GenerateEngine, GenerateSizes
 from evam_tpu.models.lm import attention, common, experts, family
 from evam_tpu.models.lm import laguna as lm
 from evam_tpu.models.lm.presets import LAGUNA_XS2_PUBLISHED, PRESETS
-from evam_tpu.ops import pallas_mla
+from evam_tpu.ops import pallas_attention
 
 REPO = Path(__file__).resolve().parent.parent
 TINY = PRESETS["laguna_tiny"]
@@ -317,12 +317,12 @@ def test_chunk_bounds_under_a_window_have_a_first_visible_row():
     assert got[7].tolist() == [6, 6, 18, 22, 7, 7]
     assert got[8, :4].tolist() == [0, 6, 0, 0]   # three empty intervals
     col = jnp.arange(6 + 8 + 9)[None, :]
-    seen = np.asarray(pallas_mla._visible(col, bounds, b0))
+    seen = np.asarray(pallas_attention._visible(col, bounds, b0))
     assert seen.sum(1).tolist() == [4, 4, 4, 4, 4, 4, 4, 4, 0]
     assert np.flatnonzero(seen[3]).tolist() == [3, 4, 5, 17]
     assert np.flatnonzero(seen[0]).tolist() == [8, 9, 10, 14]
     # without a window the same call is the three intervals of old
-    old = np.asarray(pallas_mla._visible(col, plain, b0))
+    old = np.asarray(pallas_attention._visible(col, plain, b0))
     assert old.sum(1).tolist() == [12, 13, 14, 7, 8, 9, 10, 11, 0]
 
 
@@ -344,8 +344,8 @@ def test_chunk_kernel_with_a_lower_bound_matches_its_xla_twin(
                                      prefix, cont, window=window)
     assert bounds.shape == (tokens, 6)
     keys = prefix + cont + tokens
-    seen = np.asarray(pallas_mla._visible(jnp.arange(keys)[None, :], bounds,
-                                          b0))
+    seen = np.asarray(pallas_attention._visible(
+        jnp.arange(keys)[None, :], bounds, b0))
     place = np.arange(tokens) - np.repeat(
         np.arange(4) * (tokens // 4), tokens // 4)
     before = n_prefix + np.where(seg == 0, n_cont, 0) + place + 1
@@ -618,9 +618,9 @@ def test_eight_segments_in_one_chunk_do_not_see_each_other(engine):
     _idle(engine)
     chunks, inner = [], engine._prefill
 
-    def spy(params, state, last_ids, mat, aux):
+    def spy(params, state, last_ids, heads, mat, aux):
         chunks.append(np.array(mat[1]))
-        return inner(params, state, last_ids, mat, aux)
+        return inner(params, state, last_ids, heads, mat, aux)
 
     engine._prefill = spy
     engine._admit = lambda: None  # hold admission until all eight wait

@@ -562,9 +562,9 @@ def test_eight_segments_in_one_chunk_do_not_see_each_other(engine):
     _idle(engine)
     chunks, inner = [], engine._prefill
 
-    def spy(params, state, last_ids, mat, aux):
+    def spy(params, state, last_ids, heads, mat, aux):
         chunks.append(np.array(mat[1]))
-        return inner(params, state, last_ids, mat, aux)
+        return inner(params, state, last_ids, heads, mat, aux)
 
     engine._prefill = spy
     engine._admit = lambda: None  # hold admission until all eight wait

@@ -21,25 +21,6 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows,keys", [
-    (512 * 128, 2048 + 384 + 512),   # a prefill chunk, every head
-    (64 * 128, 512 + 64),            # a short chunk, ragged key count
-])
-def test_latent_attention_kernel_compiles_at_published_widths(
-        one_chip, rows, keys):
-    from evam_tpu.ops.pallas_mla import latent_attention
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    compiled = jax.jit(
-        lambda *a: latent_attention(*a, scale=0.1147, b0=2048)).lower(
-        s((rows, 512), jnp.bfloat16), s((rows, 128), jnp.bfloat16),
-        s((keys, 512), jnp.bfloat16), s((keys, 128), jnp.bfloat16),
-        s((rows, 4), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("tokens,segments", [
     (512, 8),    # a prefill chunk of the deployment
     (64, 4),     # a short chunk
@@ -201,7 +182,9 @@ def _compile_step(lm, cfg, params, one_chip, program, traced_prefix=False):
     512 tokens at the deployment's sizes (401 pages of 128, 128 slots, a
     prefix of 16 pages), the state donated, for the described chip:
     ``(compiled, the state's shapes)``. ``traced_prefix``: the chunk takes
-    the prefix's length as an argument, as the engine's program does."""
+    the prefix's length as an argument, as the engine's program does. A
+    latent family's chunk takes the prefix's held heads beside the weights,
+    as the engine's does."""
     import numpy as np
 
     def s(shape, dtype=jnp.bfloat16):
@@ -225,13 +208,18 @@ def _compile_step(lm, cfg, params, one_chip, program, traced_prefix=False):
     else:
         t, n_seg = 512, 8
 
-        def step(params, state, tokens, seg, pos, page, off, cont, n_cont,
-                 last_idx, seg_from, seg_to, n_prefix=2048):
-            return lm.prefill_chunk(cfg, params, state, tokens, seg, pos,
-                                    page, off, shared, n_prefix, cont, n_cont,
-                                    last_idx, seg_from, seg_to)
+        heads = (jax.tree.map(lambda a: s(a.shape, a.dtype),
+                              lm.prefix_heads_shapes(cfg, 2048))
+                 if hasattr(lm, "prefix_heads_shapes") else ())
 
-        args = (*[s((t,), i32)] * 5, s((3,), i32), s((), i32),
+        def step(params, state, heads, tokens, seg, pos, page, off, cont,
+                 n_cont, last_idx, seg_from, seg_to, n_prefix=2048):
+            return lm.prefill_chunk(
+                cfg, params, state, tokens, seg, pos, page, off, shared,
+                n_prefix, cont, n_cont, last_idx, seg_from, seg_to,
+                **({"prefix_heads": heads} if heads else {}))
+
+        args = (heads, *[s((t,), i32)] * 5, s((3,), i32), s((), i32),
                 *[s((n_seg,), i32)] * 3,
                 *([s((), i32)] if traced_prefix else []))
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
@@ -345,7 +333,12 @@ def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
     and leaves rows-minor and no copy turns it tokens-minor (at 576 the
     compiler kept it tokens-minor and each program copied all of it to
     rows-minor and back, every step: 355 MB each way in DeepSeek's), and
-    every layer gathers its pages out of the whole array.
+    every layer gathers its pages out of the whole array. The prefix's
+    heads, which a chunk attends to and the engine holds (``[heads, 2048,
+    128]`` keys and as many values a latent layer: 134 MB in DeepSeek's),
+    are parameters of the PREFILL program that nothing copies and nothing
+    is concatenated behind (the chunk kernel walks them and the chunk's new
+    rows' as two lists), and the decode program does not take them.
     DeepSeek's programs (six layers unrolled) hold no copy of the whole
     cache at all; Kimi's two MLA layers sit in branches of a
     ``lax.switch`` inside a ``lax.scan``, in whose branches the compiler
@@ -377,9 +370,27 @@ def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
     # (``common.layer_page_rows``): six of those are the whole cache too
     layer = re.escape("bf16[%s]" % ",".join(map(str, pages[1:])))
     assert not re.search(rf"= {layer}", text)
+    held = re.escape(f"bf16[{cfg.heads},2048,128]")
+    handed = re.findall(rf"= {held}\S* parameter\(", text)
+    # read-only data: neither program returns them (nothing to donate)
+    assert (cfg.heads, 2048, 128) not in [
+        tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)]
+    if program == "prefill":
+        n_latent = len(lm.prefix_heads_shapes(cfg, 2048))
+        assert n_latent == {"deepseek_v2_ep8": 6, "kimi_linear_ep4": 2}[preset]
+        assert len(handed) >= 2 * n_latent   # at the entry; in branches too
+        assert not re.search(rf"= {held}\S* (copy|concatenate)\(", text)
+        # nor the prefix's and the new rows' heads as one list
+        assert f"bf16[{cfg.heads},{2048 + 384 + 512},128]" not in text
+        assert len(re.findall(r"%attn_chunk_attention[.\d]* = ", text)) == (
+            n_latent)
+        assert "mla_latent_attention" not in text
+    else:
+        assert not handed, handed[:2]
     if preset == "deepseek_v2_ep8":
         assert not copies, copies
-        # 74 and 228 MiB; with the 576-value row's two copies 434 and 626
+        # 74 and 228 MiB before the chunk ran over materialised heads;
+        # with the 576-value row's two copies 434 and 626
         assert compiled.memory_analysis().temp_size_in_bytes < {
             "decode": 256, "prefill": 400}[program] << 20
     else:
@@ -408,3 +419,27 @@ def test_chunk_attention_kernel_compiles_at_published_widths(
         s((rows, bounds), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "attn_chunk_attention" in text
+
+
+@pytest.mark.parametrize("heads", [128, 32])   # DeepSeek-V2's, Kimi-Linear's
+def test_chunk_attention_kernel_compiles_for_latent_heads(one_chip, heads):
+    """A latent family's chunk: every head a key-value head of group 1,
+    the prefix's held heads and the 384 + 512 new rows' as two lists, the
+    rope part one list all heads share, 128 wide as it lies in a row."""
+    from evam_tpu.ops.pallas_attention import chunk_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def lists(*lead):
+        return tuple(s((*lead, n, 128)) for n in (2048, 896))
+
+    compiled = jax.jit(
+        lambda *a: chunk_attention(*a, scale=0.1147, b0=2048)).lower(
+        s((heads, 512, 128)), lists(heads), lists(heads),
+        s((512, 4), jnp.int32), s((heads, 512, 128)), lists()).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "attn_chunk_attention" in text
+    # neither list copied behind the other: the new rows' 896 padded to
+    # whole blocks (2 x 34 MB at 128 heads) and no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
