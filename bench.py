@@ -462,8 +462,8 @@ def main() -> int:
                    "path (weights stay float; ops/qlinear.py)")
     p.add_argument("--sweep", action="store_true",
                    help="measure several (batch, depth) operating points "
-                   "and report the best meeting --p99-target (tuning "
-                   "mode; the JSON line reports the winner)")
+                   "and report the best meeting --p99-target (the "
+                   "JSON line reports the winner)")
     p.add_argument("--p99-target-ms", type=float, default=100.0,
                    help="latency bound the sweep optimizes under")
     args = p.parse_args()

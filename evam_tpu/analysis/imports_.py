@@ -1,10 +1,17 @@
-"""Package-level import-cycle pass.
+"""Package-level import pass: cycles and layers.
 
 PR 7 tied a hub⇄fleet knot that only surfaced at import time; the fix
-was a deliberate function-level deferred import.  This pass builds the
-module graph from *top-level* imports only (deferred imports inside
-function bodies are exactly the sanctioned cycle breakers and are
-ignored) and reports every strongly-connected component of size > 1.
+was a deliberate function-level deferred import.  The cycle check
+builds the module graph from *top-level* imports only (deferred
+imports inside function bodies are exactly the sanctioned cycle
+breakers and are ignored) and reports every strongly-connected
+component of size > 1.
+
+The layer check reads EVERY import, deferred ones too: a deferred
+import breaks a cycle at import time, it does not change which layer
+knows of which.  ``LAYERS`` orders the packages bottom to top; an
+import from a package to one above it is ``layer-up:<from>-><to>``,
+one finding a package pair.
 """
 
 from __future__ import annotations
@@ -13,6 +20,15 @@ import ast
 from pathlib import Path
 
 from .core import Finding, SourceFile
+
+#: the packages under evam_tpu/, bottom to top: ONE total order. A
+#: package imports only from those before it; what does not yet is an
+#: allowlist entry that names its debt.
+LAYERS = (
+    "config", "obs", "analysis", "native", "ops", "modelproc", "models",
+    "parallel", "graph", "media", "sched", "state", "aot", "engine",
+    "fleet", "stages", "publish", "extensions", "server", "eii", "cli",
+)
 
 
 def _top_level_imports(tree: ast.Module) -> list[ast.stmt]:
@@ -33,9 +49,10 @@ def _top_level_imports(tree: ast.Module) -> list[ast.stmt]:
     return out
 
 
-def _edges(sf: SourceFile, known: set[str]) -> set[str]:
-    """Outgoing intra-package edges as repo-relative paths."""
-    assert isinstance(sf.tree, ast.Module)
+def _edges(sf: SourceFile, known: set[str],
+           nodes: list[ast.stmt]) -> set[str]:
+    """The intra-package files that the import statements ``nodes`` of
+    ``sf`` reach, as repo-relative paths."""
     self_pkg = sf.rel.split("/")[:-1]
     targets: set[str] = set()
 
@@ -65,7 +82,7 @@ def _edges(sf: SourceFile, known: set[str]) -> set[str]:
                     if cand in known:
                         targets.add(cand)
 
-    for node in _top_level_imports(sf.tree):
+    for node in nodes:
         if isinstance(node, ast.Import):
             for alias in node.names:
                 if alias.name == "evam_tpu" or alias.name.startswith("evam_tpu."):
@@ -136,11 +153,40 @@ def _tarjan_sccs(graph: dict[str, set[str]]) -> list[list[str]]:
     return sccs
 
 
+def _package(rel: str) -> str:
+    """``evam_tpu/engine/hub.py`` -> ``engine``; ``evam_tpu/native.py``
+    -> ``native``."""
+    return rel.split("/")[1].removesuffix(".py")
+
+
+def _layer_findings(files: list[SourceFile],
+                    known: set[str]) -> list[Finding]:
+    rank = {pkg: i for i, pkg in enumerate(LAYERS)}
+    findings: dict[str, Finding] = {}
+    for sf in files:
+        src = _package(sf.rel)
+        if src not in rank or not isinstance(sf.tree, ast.Module):
+            continue
+        for node in ast.walk(sf.tree):
+            if not isinstance(node, (ast.Import, ast.ImportFrom)):
+                continue
+            for target in sorted(_edges(sf, known, [node])):
+                dst = _package(target)
+                if rank.get(dst, -1) > rank[src]:
+                    ident = f"layer-up:{src}->{dst}"
+                    findings.setdefault(ident, Finding(
+                        "imports", sf.rel, node.lineno, ident,
+                        f"{src} imports {dst}, a layer above it "
+                        f"(imports_.LAYERS): hand it in from above or "
+                        f"move what is shared down"))
+    return list(findings.values())
+
+
 def run(root: Path, files: list[SourceFile]) -> list[Finding]:
     known = {sf.rel for sf in files}
-    graph = {sf.rel: _edges(sf, known) for sf in files
-             if isinstance(sf.tree, ast.Module)}
-    findings: list[Finding] = []
+    graph = {sf.rel: _edges(sf, known, _top_level_imports(sf.tree))
+             for sf in files if isinstance(sf.tree, ast.Module)}
+    findings = _layer_findings(files, known)
     for scc in _tarjan_sccs(graph):
         if len(scc) < 2:
             continue

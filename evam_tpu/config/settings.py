@@ -65,9 +65,7 @@ class TPUSettings(BaseModel):
     first_batch_grace: float = 10.0
     #: upload-queue depth (engine/batcher.py): how many staged batches
     #: may sit between the dispatcher's h2d_issue and the launcher.
-    #: 2 = one batch uploading while one launches; the control plane
-    #: (EVAM_TUNE=on) retunes it live from the h2d_wait/launch ratio.
-    #: Setting it explicitly pins it against the controller.
+    #: 2 = one batch uploading while one launches.
     transfer_depth: int = 2
     #: ragged batching (engine/ragged.py): "packed" packs classify
     #: region sets into one fixed masked-compute device shape (row
@@ -96,12 +94,11 @@ class TPUSettings(BaseModel):
     #: count) — a chip serving 1/N of the streams doesn't need the
     #: fleet-wide max_batch worth of compile bill and staging memory
     fleet_shard_max_batch: int = 0
-    #: fleet autoscaling ceiling (the eighth control law): the fleet
-    #: may grow up to this many shards (bounded by the mesh) when
-    #: utilization stays over EVAM_TUNE_SCALE_UP_UTIL, and drains back
-    #: when it stays under EVAM_TUNE_SCALE_DOWN_UTIL. 0 (default)
-    #: keeps the law inert — the fleet stays at EVAM_FLEET_SHARDS.
-    #: Note EVAM_FLEET_SHARDS names the BOOT size, not a pin.
+    #: fleet growth ceiling: FleetEngine.scale_up may grow the fleet
+    #: up to this many shards (bounded by the mesh), and the fleet
+    #: then boots at EVAM_FLEET_SHARDS. 0 (default): no growth, the
+    #: fleet stays at EVAM_FLEET_SHARDS. Nothing in the server calls
+    #: scale_up yet (ROADMAP.md Queue 3).
     fleet_max_shards: int = 0
 
 
@@ -191,50 +188,6 @@ class CkptSettings(BaseModel):
     restore_timeout_s: float = 2.0
 
 
-class TuneSettings(BaseModel):
-    """Self-tuning control plane knobs (evam_tpu/control/): a feedback
-    controller on the watchdog cadence that retunes the registered
-    serving knobs — batch-formation deadlines, batch cap, transfer
-    upload-queue depth, gate thresholds, admission utilization /
-    capacity, staleness budgets — from the live stage clock and queue
-    gauges. ``EVAM_TUNE=off`` (default until a TPU window proves it)
-    disables the whole layer — byte-identical A/B
-    (tools/bench_tune.py), same discipline as EVAM_GATE /
-    EVAM_TRACE. Every knob the controller manages stays
-    pinnable via its existing env var: an explicitly-set key is
-    clamped out of the control loop."""
-
-    enabled: bool = False
-    #: controller tick period in seconds (the hub watchdog cadence is
-    #: stall_timeout_s/4; the controller runs its own clock so tests
-    #: and benches can spin it fast)
-    interval_s: float = 2.0
-    #: bounded log of the last N control actions, served on /scheduler
-    actions: int = 32
-    #: anti-flap damping: a rule must agree for this many CONSECUTIVE
-    #: ticks before its action is applied
-    damping: int = 3
-    #: per-knob cooldown in ticks after an applied action (hysteresis:
-    #: a knob that just moved must re-earn its next move)
-    cooldown: int = 2
-    #: utilization above which the controller tightens (gate
-    #: thresholds up, staleness budgets down, admission ceiling down)
-    util_hi: float = 0.80
-    #: utilization below which it relaxes back toward the static
-    #: operating point (dead band between util_lo and util_hi)
-    util_lo: float = 0.50
-    #: eighth law (autoscaling, needs EVAM_FLEET_MAX_SHARDS > 0):
-    #: fleet utilization sustained ABOVE this for `damping` ticks
-    #: spawns one shard from the AOT cache — deliberately above
-    #: util_hi so the in-shard laws (deadlines, gate, admission) get
-    #: to absorb pressure before the fleet pays for a new chip
-    scale_up_util: float = 0.90
-    #: sustained utilization BELOW this drains one shard through
-    #: scale_down() + checkpointed stream migration; deliberately
-    #: below util_lo so grow/shrink never oscillate across one band
-    scale_down_util: float = 0.30
-
-
 class AotSettings(BaseModel):
     """Persistent AOT executable cache (evam_tpu/aot/): serialized
     compiled executables in a content-addressed, CRC-guarded,
@@ -320,7 +273,6 @@ class Settings(BaseModel):
     tpu: TPUSettings = Field(default_factory=TPUSettings)
     sched: SchedSettings = Field(default_factory=SchedSettings)
     trace: TraceSettings = Field(default_factory=TraceSettings)
-    tune: TuneSettings = Field(default_factory=TuneSettings)
     ckpt: CkptSettings = Field(default_factory=CkptSettings)
     aot: AotSettings = Field(default_factory=AotSettings)
     lm: LMSettings = Field(default_factory=LMSettings)
@@ -428,23 +380,6 @@ class Settings(BaseModel):
             for var, (key, conv) in ckpt_mapping.items():
                 if var in env:
                     ckpt[key] = conv(env[var])
-
-        tune = data.setdefault("tune", {})
-        tune_mapping = {
-            "EVAM_TUNE": ("enabled", _parse_bool),
-            "EVAM_TUNE_INTERVAL_S": ("interval_s", float),
-            "EVAM_TUNE_ACTIONS": ("actions", int),
-            "EVAM_TUNE_DAMPING": ("damping", int),
-            "EVAM_TUNE_COOLDOWN": ("cooldown", int),
-            "EVAM_TUNE_UTIL_HI": ("util_hi", float),
-            "EVAM_TUNE_UTIL_LO": ("util_lo", float),
-            "EVAM_TUNE_SCALE_UP_UTIL": ("scale_up_util", float),
-            "EVAM_TUNE_SCALE_DOWN_UTIL": ("scale_down_util", float),
-        }
-        if isinstance(tune, dict):
-            for var, (key, conv) in tune_mapping.items():
-                if var in env:
-                    tune[key] = conv(env[var])
 
         aot = data.setdefault("aot", {})
         aot_mapping = {

@@ -79,7 +79,6 @@ import numpy as np
 
 from evam_tpu.aot import active as aot_active
 from evam_tpu.aot import cache_key as aot_cache_key
-from evam_tpu.control.state import current_op
 from evam_tpu.engine.ragged import (
     RaggedSpec,
     consolidate_buckets,
@@ -123,18 +122,6 @@ class _WorkItem:
     #: item's frame span tree to the batch it rides in; None when
     #: tracing is off or the caller has no frame context
     trace: object | None = None
-
-
-class _TunableQueue(queue.Queue):
-    """``queue.Queue`` whose bound is retunable live (the control
-    plane's upload-queue depth knob). Growing the bound wakes blocked
-    putters immediately; shrinking applies lazily as the consumer
-    drains below the new bound — no staged batch is ever dropped."""
-
-    def set_depth(self, n: int) -> None:
-        with self.mutex:
-            self.maxsize = max(1, int(n))
-            self.not_full.notify_all()
 
 
 def _safe_set_result(fut: Future, value) -> None:
@@ -478,16 +465,9 @@ class BatchEngine:
         #: sealed batches whose H2D copy has been issued, awaiting
         #: launch. Default depth 2 — device-side
         #: double buffering (one batch uploading while one launches);
-        #: EVAM_TRANSFER_DEPTH pins it, and the control plane
-        #: (EVAM_TUNE=on) retunes it live from the h2d_wait/launch
-        #: ratio via retune(). Construction reads the live operating
-        #: point first so a supervisor rebuild resumes at the
-        #: controller's current depth, not the boot value.
-        op = current_op()
-        live_depth = op.transfer_depth if op is not None else 0
-        self.transfer_depth = max(1, int(live_depth
-                                         or (transfer_depth or 2)))
-        self._upload_q: _TunableQueue = _TunableQueue(
+        #: EVAM_TRANSFER_DEPTH sets it.
+        self.transfer_depth = max(1, int(transfer_depth or 2))
+        self._upload_q: queue.Queue = queue.Queue(
             maxsize=self.transfer_depth)
         self._warm_lock = threading.Lock()
         self._warming = False
@@ -635,17 +615,6 @@ class BatchEngine:
         """Per-class shed totals (zeros without a scheduler config:
         no class has a staleness budget)."""
         return dict(self._shedder.counts)
-
-    def retune(self, op) -> None:
-        """Apply the controller's operating point to this engine's
-        structural knobs (control-plane push path — evam_tpu/control/).
-        Scalar setpoints (deadline scale, batch cap) are pulled per
-        dispatch instead, so rebuilds inherit them for free; only the
-        upload-queue depth needs an explicit resize."""
-        depth = int(op.transfer_depth or 0)
-        if depth and depth != self.transfer_depth:
-            self.transfer_depth = max(1, depth)
-            self._upload_q.set_depth(self.transfer_depth)
 
     def warmup(self) -> None:
         """Compile every bucket size ahead of traffic.
@@ -1234,17 +1203,8 @@ class BatchEngine:
             cls = cq.pick(timeout=0.05)
             if cls is None:
                 continue
-            # live setpoints (control plane): one None-check with
-            # EVAM_TUNE=off — deadlines scale, formation caps at the
-            # demanded bucket rung
-            op = current_op()
-            cap = self.max_batch
-            deadline = self._class_deadline_s[cls]
-            if op is not None:
-                if op.batch_cap:
-                    cap = min(cap, op.batch_cap)
-                deadline *= op.deadline_scale
-            items = cq.collect(cls, cap, deadline)
+            items = cq.collect(cls, self.max_batch,
+                               self._class_deadline_s[cls])
             # the batch-formation wait itself can age items past
             # budget (and a realtime burst can delay a picked batch
             # class) — filter the formed batch too

@@ -605,9 +605,6 @@ class GenerateEngine:
     def thread_states(self) -> dict[str, tuple]:
         return {"generate": self._spans.where()}
 
-    def retune(self, op) -> None:
-        """No structural knob of the control plane applies here."""
-
     def refresh_queue_gauges(self) -> None:
         """The supervisor's 0.1 s poll: the backlog gauges, and the
         stall check (a step that has not come back in

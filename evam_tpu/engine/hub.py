@@ -97,12 +97,8 @@ class EngineHub:
         #: because the factory closure carries it. None = every
         #: engine's class queues run as one FIFO (EVAM_SCHED=off).
         self.sched = sched if (sched is not None and sched.enabled) else None
-        #: upload-queue bound (EVAM_TRANSFER_DEPTH): the
-        #: static boot value; the control plane (evam_tpu/control/)
-        #: retunes the live bound through ``retune``. Part of the
-        #: rebuild recipe — but BatchEngine construction consults the
-        #: live operating point first, so a supervisor rebuild resumes
-        #: at the controller's current depth, not this boot value.
+        #: upload-queue bound (EVAM_TRANSFER_DEPTH). Part of the
+        #: rebuild recipe.
         self.transfer_depth = transfer_depth
         #: ragged batching (engine/ragged.py, EVAM_RAGGED): "packed"
         #: gives classify-family engines masked region packing (the
@@ -140,11 +136,9 @@ class EngineHub:
         self.fleet_shard_max_batch = fleet_shard_max_batch or (
             max(1, max_batch // plan.data_size) if self.fleet_active
             else max_batch)
-        #: autoscaling ceiling (EVAM_FLEET_MAX_SHARDS): how many
-        #: shards the eighth control law may grow the fleet to,
-        #: bounded by the mesh. 0 (default) keeps the law inert —
-        #: fleet_summary reports max_shards 0 and the controller
-        #: never proposes a move.
+        #: growth ceiling (EVAM_FLEET_MAX_SHARDS): how many shards
+        #: the fleet may be grown to, bounded by the mesh. 0
+        #: (default): fleet_summary reports max_shards 0.
         self.fleet_max_shards = fleet_max_shards
         #: boot fleet size (EVAM_FLEET_SHARDS when autoscaling):
         #: FleetEngines start with this many shards and grow/shrink
@@ -665,8 +659,7 @@ class EngineHub:
                 out["streams"][label] = out["streams"].get(label, 0) + n
         # autoscaling policy ceiling: the structural bound above is
         # the mesh (len(plans)); the operator's EVAM_FLEET_MAX_SHARDS
-        # clamps it, and 0 — the default — disables the eighth law
-        # (the controller treats max_shards 0 as "never scale")
+        # clamps it, and 0 — the default — reads "never grow"
         if self.fleet_active and self.fleet_max_shards > 0:
             cap = self.fleet_max_shards
             if out["max_shards"]:
@@ -675,21 +668,6 @@ class EngineHub:
         else:
             out["max_shards"] = 0
         return out
-
-    def retune(self, op) -> None:
-        """Push the controller's operating point to every cached engine
-        (evam_tpu/control/). Only structural knobs travel this path —
-        scalar setpoints are pulled per dispatch via
-        ``control.state.current_op``. SupervisedEngine delegates to its
-        live BatchEngine; FleetEngine broadcasts to shards + mesh."""
-        with self._lock:
-            engines = list(self._engines.values())
-        for e in engines:
-            try:
-                e.retune(op)
-            except Exception:  # noqa: BLE001 — engine mid-teardown
-                log.debug("retune skipped for a stopping engine",
-                          exc_info=True)
 
     def stop(self) -> None:
         with self._lock:

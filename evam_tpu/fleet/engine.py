@@ -150,8 +150,8 @@ class FleetEngine:
         self.scale_ups = 0
         self.scale_downs = 0
         #: one spin-up at a time (warm-before-join can take seconds;
-        #: a second concurrent grow must queue behind the controller's
-        #: next tick, not race the first)
+        #: a second concurrent grow returns None, it does not race
+        #: the first)
         self._scaling = False
         #: last scale_up's build+warm wall seconds (soak/bench probe)
         self._last_spinup_s = 0.0
@@ -302,8 +302,9 @@ class FleetEngine:
         return label
 
     def scale_up(self, warm_timeout_s: float = 120.0) -> str | None:
-        """Grow the fleet by one shard (the eighth control law's up
-        action, and the counterpart to :meth:`scale_down`).
+        """Grow the fleet by one shard (the counterpart to
+        :meth:`scale_down`; nothing in the server calls it yet,
+        ROADMAP.md Queue 3).
 
         The shard is built from the factory — whose warmup path goes
         through the persistent AOT cache (evam_tpu/aot/), so a
@@ -521,43 +522,6 @@ class FleetEngine:
         for e in shards:
             e.warm_async(**example)
 
-    def retune(self, op) -> None:
-        """Broadcast the controller's operating point to every shard
-        plus the mesh twin (evam_tpu/control/): the fleet must run one
-        operating point, not whichever shard __getattr__ answers from.
-
-        The eighth law actuates here too: ``op.fleet_shards`` > 0 is
-        the controller's (damped, cooled-down) target fleet size, and
-        each retune moves ONE step toward it — grow on a background
-        thread (warm-before-join takes real seconds and the
-        controller tick must not block), shrink inline through
-        :meth:`scale_down` + checkpointed migration. 0 (the knob's
-        rest state) actuates nothing."""
-        for e in self._members():
-            try:
-                e.retune(op)
-            except Exception:  # noqa: BLE001 — shard mid-teardown
-                pass
-        target = int(getattr(op, "fleet_shards", 0) or 0)
-        if target <= 0:
-            return
-        with self._lock:
-            live = len(self.shards)
-            scaling = self._scaling
-        if target > live and not scaling:
-            threading.Thread(
-                target=self._scale_up_guarded,
-                name=f"fleet-{self.name}-scale-up", daemon=True,
-            ).start()
-        elif target < live and live > 1:
-            self.scale_down()
-
-    def _scale_up_guarded(self) -> None:
-        try:
-            self.scale_up()
-        except Exception:  # noqa: BLE001 — a failed grow must not kill the thread owner
-            log.exception("fleet %s: scale_up failed", self.name)
-
     def abandon(self) -> None:
         for e in self._members():
             try:
@@ -609,10 +573,9 @@ class FleetEngine:
                 "degraded_shards": len(self._degraded),
                 "streams": self.placement_counts(),
                 "rebalances": self.rebalances,
-                # autoscaling surface (eighth law): the structural
-                # ceiling (mesh size minus dead chips — the hub clamps
-                # it to EVAM_FLEET_MAX_SHARDS) and the grow/shrink
-                # totals /scheduler explains
+                # the structural ceiling (mesh size minus dead chips;
+                # the hub clamps it to EVAM_FLEET_MAX_SHARDS) and the
+                # grow/shrink totals /scheduler reports
                 "max_shards": len(self._plans) - len(self._degraded),
                 "scale_ups": self.scale_ups,
                 "scale_downs": self.scale_downs,

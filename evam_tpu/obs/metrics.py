@@ -145,12 +145,6 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # of the dispatcher's and completer's work stretches
     "evam_engine_thread_seconds": ("counter", ("engine", "thread", "state")),
     "evam_engine_thread_cpu_seconds": ("counter", ("engine", "thread")),
-    # self-tuning control plane (evam_tpu/control/): controller ticks,
-    # applied retune actions per knob, and the current operating-point
-    # setpoint per knob (the same values /scheduler reports)
-    "evam_tune_ticks": ("counter", ()),
-    "evam_tune_actions": ("counter", ("knob",)),
-    "evam_tune_setpoint": ("gauge", ("knob",)),
 }
 
 

@@ -35,7 +35,6 @@ import math
 import threading
 import uuid
 
-from evam_tpu.control.state import current_op
 from evam_tpu.obs import get_logger, metrics
 from evam_tpu.sched.classes import PRIORITIES, SchedConfig
 
@@ -131,7 +130,7 @@ class AdmissionController:
             cap = self.capacity_fps()
             if cap > 0:
                 util = (self.effective_demand_fps() + fps) / cap
-                ceiling = self.admit_util() * CLASS_HEADROOM.get(
+                ceiling = self.cfg.admit_util * CLASS_HEADROOM.get(
                     priority, 1.0)
                 if util > ceiling:
                     retry_after = self._retry_after_s(util, ceiling)
@@ -164,16 +163,6 @@ class AdmissionController:
         with self._lock:
             return sum(fps for _, fps in self._streams.values())
 
-    def admit_util(self) -> float:
-        """The live utilization ceiling: the controller's override when
-        it has stepped off the static EVAM_SCHED_ADMIT_UTIL (shedding
-        observed → tightened; headroom → relaxed back toward static),
-        else the configured value. One None-check with EVAM_TUNE=off."""
-        op = current_op()
-        if op is not None and op.admit_util > 0:
-            return op.admit_util
-        return self.cfg.admit_util
-
     def effective_demand_fps(self) -> float:
         """Declared demand minus the motion gate's recent
         skipped-frames/s (stages/gate.py registry): frames the gate is
@@ -186,13 +175,9 @@ class AdmissionController:
 
         return max(0.0, self.demand_fps() - gate_registry.skipped_fps())
 
-    def capacity_fps(self, live: bool = False) -> float:
-        """Declared capacity, the controller's published EWMA, or the
-        bottleneck projection from live stats; 0 = unknown (cold hub —
-        admit). ``live=True`` skips the controller's published setpoint
-        and reports the raw projection — the controller itself reads
-        this form, so its capacity EWMA feeds on measurements rather
-        than on its own output.
+    def capacity_fps(self) -> float:
+        """Declared capacity, or the bottleneck projection from live
+        stats; 0 = unknown (cold hub — admit).
 
         Fleet-aware aggregation (evam_tpu/fleet/): each stats row
         derives ITS OWN capacity from its own EngineStats (per-chip
@@ -208,10 +193,6 @@ class AdmissionController:
         exactly."""
         if self.cfg.capacity_fps > 0:
             return self.cfg.capacity_fps
-        if not live:
-            op = current_op()
-            if op is not None and op.capacity_fps > 0:
-                return op.capacity_fps
         measured: dict[str, list[float]] = {}
         unmeasured: dict[str, int] = {}
         for key, stats in self.hub.stats().items():
@@ -295,9 +276,7 @@ class AdmissionController:
         counts = self.counts()
         return {
             "enabled": bool(self.cfg.enabled),
-            # the live ceiling (== the static EVAM_SCHED_ADMIT_UTIL
-            # unless the controller has stepped it)
-            "admit_util": self.admit_util(),
+            "admit_util": self.cfg.admit_util,
             "capacity_fps": round(self.capacity_fps(), 1),
             "demand_fps": round(self.demand_fps(), 1),
             # post-gate view (stages/gate.py): what the engines

@@ -22,7 +22,6 @@ from __future__ import annotations
 import threading
 import time
 
-from evam_tpu.control.state import current_op
 from evam_tpu.obs import get_logger, metrics
 from evam_tpu.sched.classes import PRIORITIES
 
@@ -74,27 +73,14 @@ class Shedder:
         number shed."""
         now = time.perf_counter() if now is None else now
         total = 0
-        scale = self._staleness_scale()
         for cls, budget in self.staleness_s.items():
             if budget <= 0:
                 continue
-            budget *= scale
             expired = queues.pop_expired(cls, now - budget)
             if expired:
                 self._fail(cls, expired, now, budget)
                 total += len(expired)
         return total
-
-    @staticmethod
-    def _staleness_scale() -> float:
-        """The control plane's staleness multiplier (<1 sheds earlier
-        under sustained overload) — applied at use time so the
-        controller's current value always wins and EVAM_TUNE=off costs
-        one None-check. Per-class budgets pinned via their env vars
-        never reach here scaled: the controller clamps the knob to 1.0
-        when any EVAM_SCHED_STALENESS_MS_* is set."""
-        op = current_op()
-        return op.staleness_scale if op is not None else 1.0
 
     def shed(self, priority: str, items: list,
              now: float | None = None) -> list:
@@ -103,7 +89,6 @@ class Shedder:
         budget = self.staleness_s.get(priority, 0.0)
         if budget <= 0 or not items:
             return items
-        budget *= self._staleness_scale()
         now = time.perf_counter() if now is None else now
         cutoff = now - budget
         survivors = [it for it in items if it.t_submit >= cutoff]

@@ -17,7 +17,6 @@ from pathlib import Path
 from typing import Any
 
 from evam_tpu.config import Settings
-from evam_tpu.control import state as control_state
 from evam_tpu.engine.hub import EngineHub
 from evam_tpu.graph import PipelineLoader, resolve_parameters
 from evam_tpu.models.registry import ModelRegistry
@@ -106,21 +105,6 @@ class PipelineRegistry:
         self.sched_cfg = (getattr(hub, "sched", None)
                           or SchedConfig.disabled())
         self.admission = AdmissionController(hub, self.sched_cfg)
-        #: self-tuning control plane (evam_tpu/control/, EVAM_TUNE):
-        #: a feedback loop on the live signals (stage clock, queue
-        #: gauges, gate skip rate, admission utilization, shed counts)
-        #: continuously retuning deadlines, bucket caps, transfer
-        #: depth, gate thresholds and admission headroom. Off (the
-        #: default) this is one memoized None-check and the server is
-        #: byte-identical to the static configuration.
-        self.tuner = None
-        tune_state = control_state.active()
-        if tune_state is not None:
-            from evam_tpu.control import TuneController
-
-            self.tuner = TuneController(
-                hub, tune_state, admission=self.admission)
-            self.tuner.start()
         #: shared decode pool (opt-in, EVAM_DECODE_POOL_WORKERS>0):
         #: bounds total decode threads across all instances
         self.decode_pool = None
@@ -457,13 +441,6 @@ class PipelineRegistry:
             "mode": "off", "shards": 0, "degraded_shards": 0,
             "rebalances": 0, "streams": {},
             "max_shards": 0, "scale_ups": 0, "scale_downs": 0})
-        # self-tuning operating point (evam_tpu/control/): the current
-        # setpoints, the signals that produced them, and the last N
-        # control actions with reasons — the same fixed shape (with
-        # zeros and an empty action log) when EVAM_TUNE=off
-        st = control_state.active()
-        out["tuning"] = (st.snapshot() if st is not None
-                         else control_state.disabled_snapshot())
         return out
 
     def stop_all(self) -> int:
@@ -539,8 +516,6 @@ class PipelineRegistry:
             if not i.deleted
             and i.state not in (InstanceState.COMPLETED, InstanceState.ERROR)
         ])
-        if self.tuner is not None:
-            self.tuner.stop()
         self.hub.stop()
         return leaked
 

@@ -49,7 +49,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from evam_tpu.control.state import current_op
 from evam_tpu.obs import get_logger, metrics
 
 log = get_logger("stages.gate")
@@ -96,10 +95,6 @@ class GateConfig:
     #: forced-refresh period: run at least every N frames regardless of
     #: motion state (0 = rely on max_skip alone)
     refresh: int = 30
-    #: operator pinned the thresholds (explicit property or env var):
-    #: the control plane's gate_scale must leave this gate alone —
-    #: clamp-to-pinned-knob, per gate
-    pinned: bool = False
 
     @classmethod
     def from_properties(cls, properties: dict) -> "GateConfig":
@@ -119,12 +114,6 @@ class GateConfig:
             "gate-threshold", _env_float("EVAM_GATE_THRESHOLD", 2.0)))
         lo_default = _env_float("EVAM_GATE_THRESHOLD_LO", thr / 2.0)
         lo = float(properties.get("gate-threshold-lo", lo_default))
-        # any explicit threshold — per-pipeline property or global env
-        # override — pins this gate against the controller's gate_scale
-        pinned = ("gate-threshold" in properties
-                  or "gate-threshold-lo" in properties
-                  or "EVAM_GATE_THRESHOLD" in os.environ
-                  or "EVAM_GATE_THRESHOLD_LO" in os.environ)
         return cls(
             enabled=enabled,
             threshold=thr,
@@ -133,7 +122,6 @@ class GateConfig:
                 "gate-max-skip", _env_int("EVAM_GATE_MAX_SKIP", 8)))),
             refresh=max(0, int(properties.get(
                 "gate-refresh", _env_int("EVAM_GATE_REFRESH", 30)))),
-            pinned=pinned,
         )
 
 
@@ -203,19 +191,8 @@ class MotionGate:
             run = True  # staleness bound
         else:
             # hysteresis: enter "moving" at threshold, leave at
-            # threshold_lo — a score between the two keeps the state.
-            # The control plane's gate_scale stretches both bounds
-            # (gate harder as utilization climbs) unless this gate's
-            # thresholds were explicitly pinned; max_skip and refresh
-            # stay untouched — the staleness/drift bounds hold at any
-            # operating point.
-            thr = self.cfg.threshold
-            lo = self.cfg.threshold_lo
-            if not self.cfg.pinned:
-                op = current_op()
-                if op is not None and op.gate_scale != 1.0:
-                    thr *= op.gate_scale
-                    lo *= op.gate_scale
+            # threshold_lo — a score between the two keeps the state
+            thr, lo = self.cfg.threshold, self.cfg.threshold_lo
             if s >= thr:
                 self._moving = True
             elif s <= lo:

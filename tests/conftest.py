@@ -35,7 +35,7 @@ SLOW_MODULES = {
     "test_accuracy", "test_bench_contract", "test_eii", "test_ir",
     "test_ir_fuzz", "test_load", "test_media", "test_models",
     "test_multihost", "test_ops", "test_parallel", "test_quant",
-    "test_rtc", "test_soak", "test_stages", "test_reference_compat",
+    "test_rtc", "test_soak", "test_reference_compat",
 }
 
 
@@ -92,17 +92,12 @@ def _reset_fault_memo():
     injector never leaks into the next test's engines."""
     yield
     from evam_tpu import aot
-    from evam_tpu.control import state as control_state
     from evam_tpu.obs import faults, trace
 
     faults.reset_cache()
     # the trace ring is memoized the same way (obs/trace.py active());
     # tests that monkeypatch EVAM_TRACE* must not leak a stale ring
     trace.reset_cache()
-    # ... and so is the control plane's TuneState (control/state.py):
-    # a leaked live operating point would silently retune every
-    # engine built by the next test
-    control_state.reset_cache()
     # ... and the AOT executable cache (evam_tpu/aot/): a leaked live
     # cache would serve stale executables to the next test's engines
     aot.reset_cache()

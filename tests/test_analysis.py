@@ -491,6 +491,93 @@ def test_imports_submodule_import_not_a_package_edge(tmp_path):
     assert imports_.run(tmp_path, files) == []
 
 
+def test_layers_upward_deferred_import_found(tmp_path):
+    # a deferred import breaks a cycle, not the layering: engine/
+    # learning of server/ inside a function body is still a finding
+    files = make_tree(tmp_path, {
+        "evam_tpu/__init__.py": "",
+        "evam_tpu/server/__init__.py": "",
+        "evam_tpu/server/app.py": "",
+        "evam_tpu/engine/__init__.py": "",
+        "evam_tpu/engine/hub.py": """
+            def late():
+                from evam_tpu.server import app
+                return app
+        """,
+    })
+    found = imports_.run(tmp_path, files)
+    assert [(f.ident, f.file, f.line) for f in found] == [
+        ("layer-up:engine->server", "evam_tpu/engine/hub.py", 3)]
+
+
+def test_layers_downward_deferred_import_passes(tmp_path):
+    files = make_tree(tmp_path, {
+        "evam_tpu/__init__.py": "",
+        "evam_tpu/engine/__init__.py": "",
+        "evam_tpu/engine/hub.py": "",
+        "evam_tpu/server/__init__.py": "",
+        "evam_tpu/server/app.py": """
+            from evam_tpu.obs import log
+
+            def late():
+                from evam_tpu.engine import hub
+                return hub
+        """,
+        "evam_tpu/obs/__init__.py": "",
+        "evam_tpu/obs/log.py": "",
+    })
+    assert imports_.run(tmp_path, files) == []
+
+
+@pytest.fixture(scope="module")
+def repo_layer_findings():
+    """The repo's raw upward imports (no allowlist applied), by ident."""
+    return {f.ident: f for f in run_passes(REPO, ("imports",))
+            if f.ident.startswith("layer-up:")}
+
+
+@pytest.fixture(scope="module")
+def layer_allowlist():
+    return {e["ident"]: e for e in Allowlist.load(cli.ALLOWLIST).entries
+            if e["ident"].startswith("layer-up:")}
+
+
+def test_layers_name_every_package():
+    """ONE total order over what is on disk: a new package takes its
+    place in the table, or the check does not see it."""
+    on_disk = {imports_._package(sf.rel) for sf in iter_package_files(REPO)}
+    on_disk.discard("__init__")
+    assert sorted(imports_.LAYERS) == sorted(on_disk)
+    assert len(set(imports_.LAYERS)) == len(imports_.LAYERS)
+
+
+@pytest.mark.parametrize("pkg", imports_.LAYERS)
+def test_layer_imports_nothing_above_it(pkg, repo_layer_findings,
+                                        layer_allowlist):
+    """``pkg`` imports, top-level or deferred, nothing above it but
+    what the allowlist names."""
+    up = {i for i in repo_layer_findings if i.startswith(f"layer-up:{pkg}->")}
+    assert up <= set(layer_allowlist), sorted(up - set(layer_allowlist))
+
+
+@pytest.mark.parametrize("ident, file", [
+    ("layer-up:engine->fleet", "evam_tpu/engine/hub.py"),
+    ("layer-up:sched->stages", "evam_tpu/sched/admission.py"),
+    ("layer-up:models->parallel", "evam_tpu/models/zoo/action.py"),
+])
+def test_layer_debt_is_still_owed(ident, file, repo_layer_findings,
+                                  layer_allowlist):
+    """Each allowlisted upward import is really there, in the file its
+    justification names: once it is gone this fails, and the entry
+    goes with it."""
+    assert set(layer_allowlist) == {
+        "layer-up:engine->fleet", "layer-up:sched->stages",
+        "layer-up:models->parallel"}
+    assert repo_layer_findings[ident].file == file
+    assert file.removeprefix("evam_tpu/") in (
+        layer_allowlist[ident]["justification"])
+
+
 # -------------------------------------------------------------- allowlist
 
 def test_allowlist_requires_justification(tmp_path):
@@ -535,10 +622,12 @@ def test_lock_allowlist_is_empty():
     assert [e for e in allow.entries if e["pass"] == "locks"] == []
 
 
-def test_repo_locks_and_imports_clean_without_allowlist():
-    """The two fix-don't-suppress passes hold with NO allowlist at
-    all — the suppressions only cover knobs/hotloop."""
-    assert run_passes(REPO, ("locks", "imports")) == []
+def test_repo_locks_and_cycles_clean_without_allowlist():
+    """The lock pass and the cycle check hold with NO allowlist at
+    all — of these two passes the suppressions only cover the named
+    upward imports."""
+    assert [f for f in run_passes(REPO, ("locks", "imports"))
+            if not f.ident.startswith("layer-up:")] == []
 
 
 def test_knob_inventory_covers_fault_keys():

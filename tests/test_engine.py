@@ -145,6 +145,28 @@ def test_engine_stop_rejects_new_work():
         eng.submit(x=np.ones((2, 2, 3), np.uint8))
 
 
+@pytest.mark.parametrize("env, bound", [({}, 2),
+                                        ({"EVAM_TRANSFER_DEPTH": "4"}, 4)])
+def test_upload_queue_bound_is_the_setting(monkeypatch, env, bound):
+    """The upload queue holds EVAM_TRANSFER_DEPTH staged batches, 2
+    where it is not set: the setting reaches the engine through the
+    hub, and nothing else decides it."""
+    from evam_tpu.config.settings import Settings
+
+    monkeypatch.delenv("EVAM_TRANSFER_DEPTH", raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    depth = Settings.from_env().tpu.transfer_depth
+    h = EngineHub(registry=None, plan=None, max_batch=4, supervise=False,
+                  stall_timeout_s=0, transfer_depth=depth)
+    eng = h._build("depth", lambda params, x: x + 1.0, None, ("x",))
+    try:
+        assert eng.transfer_depth == bound
+        assert eng._upload_q.maxsize == bound
+    finally:
+        eng.stop()
+
+
 def test_hub_stats(hub):
     stats = hub.stats()
     det = stats["detect:object_detection/person_vehicle_bike"]

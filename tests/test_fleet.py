@@ -327,26 +327,6 @@ class TestScaleUp:
             eng._scaling = False
         assert eng.scale_up() == "s2"
 
-    def test_retune_moves_one_step_toward_the_target(self):
-        from evam_tpu.control.state import OperatingPoint
-
-        eng, shards = _fake_fleet(n=4, initial=2)
-        eng.set_example(frames=np.zeros(1))
-        # grow runs on a background thread (warm-before-join must not
-        # block the controller tick) — one step per push
-        eng.retune(OperatingPoint(fleet_shards=4))
-        assert _wait(lambda: len(eng.shards) == 3)
-        assert _wait(lambda: not eng._scaling)
-        eng.retune(OperatingPoint(fleet_shards=4))
-        assert _wait(lambda: len(eng.shards) == 4)
-        # shrink is inline, also one step
-        eng.retune(OperatingPoint(fleet_shards=1))
-        assert len(eng.shards) == 3
-        # the knob's rest state actuates nothing
-        eng.retune(OperatingPoint(fleet_shards=0))
-        assert _wait(lambda: not eng._scaling)
-        assert len(eng.shards) == 3
-
     def test_scale_up_checkpoints_moving_streams(self, monkeypatch):
         """The warm shard's first frame must see each migrated
         stream's gate/coaster/tracker state: the pre_rebalance barrier

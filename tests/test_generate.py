@@ -1006,7 +1006,7 @@ def test_admission_reads_an_engines_own_capacity():
     ctrl = AdmissionController(Hub(), SchedConfig())
     # the detector alone would read 800 fps; the pipeline's slowest
     # engine serves tens of frames a second
-    assert ctrl.capacity_fps(live=True) == 40.0
+    assert ctrl.capacity_fps() == 40.0
 
 
 def _modelled_capacity(prefill_s, decode_s, prompt=272, new=48):

@@ -205,6 +205,34 @@ class TestShedder:
             "evam_sched_shed", labels={"class": "batch"}) == before + 1
 
 
+    @pytest.mark.parametrize("cls", [
+        c for c, ms in SchedConfig().staleness_ms.items() if ms > 0])
+    def test_a_class_sheds_at_its_own_setting(self, cls):
+        """A class's staleness budget is its EVAM_SCHED_STALENESS_MS_*
+        setting and nothing scales it: what is a tenth younger than it
+        rides, what is a tenth older is shed and says so, in a formed
+        batch and in the waiting queue alike."""
+        cfg = SchedConfig()
+        budget = cfg.staleness_ms[cls] / 1e3
+        eng = _toy_engine(f"sched-budget-{cls}", sched=cfg)
+        try:
+            assert eng._shedder.staleness_s[cls] == budget
+        finally:
+            eng.stop()
+        sh = Shedder("eng", cfg.staleness_s())
+        now = time.perf_counter()
+        old, young = _Item(t=now - 1.1 * budget), _Item(t=now - 0.9 * budget)
+        assert sh.shed(cls, [old, young], now=now) == [young]
+        with pytest.raises(ShedError) as ei:
+            old.future.result(timeout=0)
+        assert ei.value.budget_s == budget
+        q = ClassQueues()
+        q.put(cls, _Item(t=now - 1.1 * budget))
+        q.put(cls, _Item(t=now - 0.9 * budget))
+        assert sh.sweep(q, now=now) == 1
+        assert q.depth_by_class()[cls] == 1
+
+
 # ------------------------------------------------------------- admission
 
 
@@ -302,6 +330,39 @@ class TestAdmission:
         row.update(stage_batches=1, stage_ms={
             "h2d_issue": 1.0, "launch": 3.0, "readback": 1.0})
         assert ctrl.capacity_fps() == pytest.approx(1600.0)
+
+    def test_ceiling_is_the_configured_one(self):
+        """The ceiling a start is held to is EVAM_SCHED_ADMIT_UTIL
+        times its class's headroom, before and after a rejection, and
+        /scheduler reports the setting."""
+        cfg = SchedConfig(capacity_fps=100.0, admit_util=0.7)
+        ctrl = AdmissionController(_FakeHub(), cfg)
+        for _ in range(2):
+            with pytest.raises(AdmissionError) as ei:
+                ctrl.admit("standard", 90.0)
+            assert ei.value.ceiling == pytest.approx(0.7 * 0.85)
+        assert ctrl.snapshot()["admit_util"] == 0.7
+        ctrl.admit("standard", 59.0)  # 0.59 <= 0.595
+
+    def test_capacity_is_the_engines_own_projection(self):
+        """With no EVAM_SCHED_CAPACITY_FPS, capacity is read from the
+        engines' stats at each call, unsmoothed: a row that models its
+        own (engine/generate.py) is taken at its word, the slowest
+        engine kind bounds the pipeline, and a new reading is the
+        answer at once. A declared capacity beats every reading."""
+        stats = {
+            "detect:m": {"batches": 10, "items": 40, "stage_batches": 10,
+                         "stage_ms": {"launch": 10.0}},  # 400 fps
+            "generate:m": {"capacity_fps": 40.0},
+        }
+        hub = _FakeHub(stats)
+        ctrl = AdmissionController(hub, SchedConfig())
+        assert ctrl.capacity_fps() == pytest.approx(40.0)
+        assert ctrl.snapshot()["capacity_fps"] == 40.0
+        stats["generate:m"]["capacity_fps"] = 1000.0
+        assert ctrl.capacity_fps() == pytest.approx(400.0)
+        declared = AdmissionController(hub, SchedConfig(capacity_fps=55.0))
+        assert declared.capacity_fps() == 55.0
 
     def test_snapshot_shape(self):
         ctrl = AdmissionController(_FakeHub(), SchedConfig())
@@ -461,6 +522,29 @@ class TestEngineSched:
             f2.result(timeout=60)
             assert time.perf_counter() - t0 >= 0.3
             assert eng.stats.batches == 1 and eng.stats.items == 2
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("cls", ["realtime", "standard", "batch"])
+    def test_a_batch_forms_under_max_batch_and_its_class_deadline(
+            self, cls):
+        """The dispatcher collects a class's batch under the engine's
+        ``max_batch`` and that class's EVAM_SCHED_DEADLINE_MS_*, and
+        under nothing else."""
+        cfg = SchedConfig(deadline_ms={
+            "realtime": 3.0, "standard": 7.0, "batch": 11.0})
+        eng = _toy_engine(f"sched-collect-{cls}", sched=cfg, max_batch=4)
+        calls: list[tuple] = []
+        orig = eng._classq.collect
+
+        def spy(priority, max_n, deadline_s):
+            calls.append((priority, max_n, deadline_s))
+            return orig(priority, max_n, deadline_s)
+
+        eng._classq.collect = spy
+        try:
+            eng.submit(priority=cls, x=_x(1.0)).result(timeout=60)
+            assert calls == [(cls, 4, cfg.deadline_ms[cls] / 1e3)]
         finally:
             eng.stop()
 

@@ -14,9 +14,6 @@ import time
 import numpy as np
 import pytest
 
-from evam_tpu.config.settings import reset_settings
-from evam_tpu.control import state as control_state
-from evam_tpu.control.state import ZERO_SIGNALS, OperatingPoint
 from evam_tpu.engine import batcher
 from evam_tpu.engine.batcher import BatchEngine
 from evam_tpu.engine.ringbuf import STAGES
@@ -238,20 +235,15 @@ class TestBatchFailures:
 
 
 class TestSupervisorInheritsTransferDepth:
-    def test_rebuild_resumes_at_the_live_transfer_depth(
+    def test_rebuild_resumes_at_the_factory_transfer_depth(
             self, monkeypatch):
-        """The factory closure carries the boot depth, but a rebuild
-        reads the controller's live operating point first: an engine
-        rebuilt after a wedge comes back at the depth the controller
-        holds now, with a live launcher thread."""
+        """The factory closure carries the depth: an engine rebuilt
+        after a wedge comes back at it, with a live launcher
+        thread."""
         from evam_tpu.engine.supervisor import SupervisedEngine
 
-        monkeypatch.setenv("EVAM_TUNE", "on")
-        reset_settings()
-        control_state.reset_cache()
-
         def factory() -> BatchEngine:
-            return _engine("xfer-sup", transfer_depth=2,
+            return _engine("xfer-sup", transfer_depth=3,
                            max_batch=4, deadline_ms=1.0,
                            stall_timeout_s=0.5)
 
@@ -260,10 +252,8 @@ class TestSupervisorInheritsTransferDepth:
             max_restarts=3, restart_window_s=60.0, backoff_s=0.05)
         try:
             first = sup._engine
-            assert first.transfer_depth == 2
+            assert first.transfer_depth == 3
             sup.submit(x=np.zeros((4,), np.uint8)).result(timeout=30)
-            control_state.active().install(
-                OperatingPoint(transfer_depth=5), dict(ZERO_SIGNALS))
             monkeypatch.setenv("EVAM_FAULT_INJECT",
                                "wedge=1,wedge_n=1,wedge_s=4")
             faults.reset_cache()
@@ -277,8 +267,8 @@ class TestSupervisorInheritsTransferDepth:
                 time.sleep(0.05)
             assert sup.state == "running" and sup.restarts == 1
             assert sup._engine is not first
-            assert sup._engine.transfer_depth == 5
-            assert sup._engine._upload_q.maxsize == 5
+            assert sup._engine.transfer_depth == 3
+            assert sup._engine._upload_q.maxsize == 3
             assert sup._engine._launcher.is_alive()
             monkeypatch.setenv("EVAM_FAULT_INJECT", "")
             faults.reset_cache()
@@ -287,8 +277,6 @@ class TestSupervisorInheritsTransferDepth:
             np.testing.assert_array_equal(out, np.full((4,), 16))
         finally:
             sup.stop()
-            monkeypatch.delenv("EVAM_TUNE")
-            reset_settings()
 
     def test_hub_factory_carries_transfer_depth(self):
         from evam_tpu.engine.hub import EngineHub

@@ -18,9 +18,8 @@ under that loss:
   chip loss degrades fleet capacity, never a stream's liveness.
 
 ``--ramp`` mode — elastic scaling (ISSUE 18): an elastic fleet grows
-2→8→2 one shard at a time under live realtime tracking streams,
-actuated the way the eighth control law does it — one
-``hub.retune(OperatingPoint(fleet_shards=n))`` push per step. A seed
+2→8→2 one shard at a time under live realtime tracking streams: one
+``scale_up()`` or ``scale_down()`` of every fleet per step. A seed
 phase first warms a full-peak fleet against a fresh EVAM_AOT_DIR, so
 every grow during the ramp is a CACHE-HIT spin-up (deserialize, not
 compile). The contract under the ramp:
@@ -165,7 +164,6 @@ def ramp(args) -> int:
     from evam_tpu import aot
     from evam_tpu import state as stream_state
     from evam_tpu.config.settings import reset_settings
-    from evam_tpu.control.state import OperatingPoint
     from evam_tpu.state import decode
 
     reset_settings()
@@ -205,10 +203,14 @@ def ramp(args) -> int:
                    + list(range(peak - 1, base - 1, -1)))
         prev = base
         for n in targets:
-            # one eighth-law push per step: FleetEngine.retune moves
-            # ONE shard toward op.fleet_shards (grow on a background
-            # thread, shrink inline) — poll until it lands
-            hub.retune(OperatingPoint(fleet_shards=n))
+            # one shard per step: scale_up returns once the new shard
+            # is warm and in the ring, scale_down once its streams have
+            # moved — poll until the summary shows it
+            for f in fleets:
+                if n > prev:
+                    f.scale_up()
+                else:
+                    f.scale_down()
             deadline = time.monotonic() + 120.0
             while hub.fleet_summary()["shards"] != n:
                 if time.monotonic() >= deadline:
