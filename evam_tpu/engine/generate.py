@@ -267,6 +267,9 @@ class _Step:
     #: snapshot; both 0 for a family that keeps no slot state
     state_rows: int
     restores: int
+    #: a decode step: of the pages its sequences' tables name, those that
+    #: hold a row of the sequence, and those wholly behind its rows
+    own_pages: tuple[int, int]
     #: (row of the outputs, sequence) for every token this step sampled
     takers: list
     top: jax.Array
@@ -536,9 +539,13 @@ class GenerateEngine:
         chunk = (np.arange(n) % self.cfg.vocab, np.arange(n) // per,
                  len(self.prefix) + np.arange(n) % per,
                  np.arange(n) % sz.page_tokens)
+        # a decode row at its longest: the kernel that walks a row's own
+        # pages copies and computes those that hold its rows, so a row of
+        # one token would be timed at a third of a row in service
+        last = sz.private_tokens - 1
         steps = [(f"decode:{b}", lambda b=b: self._dispatch_decode_raw(
-            [(slot, 0, [0]) for slot in range(b)], b, []))
-            for b in self.buckets]
+            [(slot, last, [0] * self._private_pages) for slot in range(b)],
+            b, [])) for b in self.buckets]
         steps.append(("prefill", lambda: self._dispatch_prefill_raw(
             *chunk, len(self.prefix), None, 0, [], [])))
         for run in range(3):
@@ -898,7 +905,7 @@ class GenerateEngine:
         mat[0] = sz.slots
         mat[2] = 1
         table = np.zeros((bucket, self._private_pages), np.int32)
-        rows_read = window_read = 0
+        rows_read = window_read = pages_read = 0
         for b, (slot, k, pages) in enumerate(rows):
             row = self._where(pages, k)
             mat[:, b] = (slot, len(self.prefix) + k, k + 1,
@@ -909,13 +916,18 @@ class GenerateEngine:
             rows_read += ctx
             # of which a window layer sees the last ``window``
             window_read += min(ctx, self._window or ctx)
+            # the table's pages that hold the k + 1 own rows
+            pages_read += k // sz.page_tokens + 1
         return self._run("decode", f"decode:{bucket}", self._decode,
                          (mat, table), tokens=len(rows), rows_read=rows_read,
                          takers=takers, state_rows=len(rows),
-                         window_read=window_read)
+                         window_read=window_read, own_pages=(
+                             pages_read,
+                             len(rows) * self._private_pages - pages_read))
 
     def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
-             takers, state_rows, restores=0, window_read=None) -> _Step:
+             takers, state_rows, restores=0, window_read=None,
+             own_pages=(0, 0)) -> _Step:
         t0 = time.perf_counter()
         self._step_started = t0
         cold = key not in self._seen
@@ -932,7 +944,7 @@ class GenerateEngine:
             state_rows = restores = 0
         return _Step(kind, key, t0, tokens, rows_read,
                      rows_read if window_read is None else window_read,
-                     state_rows, restores, takers, top, ids, held)
+                     state_rows, restores, own_pages, takers, top, ids, held)
 
     # ------------------------------------------------------------- harvest
 
@@ -962,6 +974,11 @@ class GenerateEngine:
                         float(step.window_read), labels)
             metrics.inc("evam_generate_window_rows_skipped",
                         float(step.rows_read - step.window_read), labels)
+        if step.kind == "decode":
+            metrics.inc("evam_generate_own_pages_read",
+                        float(step.own_pages[0]), labels)
+            metrics.inc("evam_generate_own_pages_skipped",
+                        float(step.own_pages[1]), labels)
         metrics.inc("evam_generate_state_rows", float(step.state_rows),
                     labels)
         metrics.inc("evam_generate_prefix_restores", float(step.restores))

@@ -25,13 +25,20 @@ cached rows, and ALL rows' queries against the shared prefix's rows in one
 product per key-value head, read once a step. Under a window the prefix's
 rows are only those a token behind the prefix can still see
 (``window_pages``: the last pages of it), each row masked from its own
-lower bound. Both go through XLA, but a chunk of a kind that says so
-(``chunk_kernel``) runs in a Pallas kernel that keeps the scores on the
-chip (ops/pallas_attention.py): measured on a v5e (PERF.md section 6, PR
-40) XLA's materialised scores are 1.67 ms a layer of LFM2's chunk (8
-key-value heads of 64 under groups of 4, six layers: 30 % of the chunk)
-for the kernel's 0.36, and 0.16 ms a layer of Jamba's (one key-value head
-of 128, two layers of 28) for the kernel's 0.22.
+lower bound. The prefix's part goes through XLA; two parts are Pallas
+kernels (ops/pallas_attention.py). A chunk of a kind that says so
+(``chunk_kernel``) runs in one that keeps the scores on the chip:
+measured on a v5e (PERF.md section 6, PR 40) XLA's materialised scores
+are 1.67 ms a layer of LFM2's chunk (8 key-value heads of 64 under groups
+of 4, six layers: 30 % of the chunk) for the kernel's 0.36, and 0.16 ms a
+layer of Jamba's (one key-value head of 128, two layers of 28) for the
+kernel's 0.22. A decode step's OWN part runs in one that walks each row's
+page table over the whole cache (``decode_pages``): the pages are read
+once, where they lie, where XLA gathered them (100 MB a layer of Laguna's
+64-row step), split keys from values and turned both; which rows take it
+is read off their size (``_own_pages_kernel``: 0.14 ms a layer of Laguna's
+step for XLA's 0.48, 0.075 of LFM2's for 0.13, and Jamba's 196 KB a row
+left to XLA, 0.03 for the kernel's 0.05; PERF.md section 6, PR 49).
 
 What differs is data of the LAYER KIND, whatever object carries it (a
 family with one kind of attention layer hands its config, one with several
@@ -255,30 +262,70 @@ def attn_prefill(kind, lp: dict, q, kv, seg, prefix, n_prefix, cont, n_cont,
     return _out(lp, o, gates)
 
 
-def attn_decode(kind, lp: dict, q, ctx, ctx_len, prefix, n_prefix,
-                gates=None, prefix_first=0):
-    """One new token per row, its softmax in two parts (as
-    mla.mla_decode): each row against its OWN cached rows ``ctx`` [B, T,
-    kv_width], visible below ``ctx_len`` (the new token's own row the last
-    of them); all rows' queries against the shared prefix rows ``prefix``
-    (the first of them at position ``prefix_first``) in one product per
-    key-value head. Under the kind's ``window`` a row whose token stands at
-    position ``n_prefix + ctx_len - 1`` sees the ``window`` positions that
-    end there: of its own rows those from ``ctx_len - window`` on, of the
-    prefix those from ``n_prefix + ctx_len - window`` on."""
-    q = _grouped(kind, q)
+#: bytes of a row's own pages from which the kernel is the faster: under
+#: them a grid step a row costs more than the copies it saves (measured on
+#: a v5e, PERF.md section 6, PR 49: Jamba's 196 KB a row, 64 rows a layer,
+#: 50 us through the kernel for XLA's 31; LFM2's 786 KB 99 for 133;
+#: Laguna's 1.5 MB 143 for 482)
+OWN_PAGES_KERNEL_FROM = 512 << 10
+
+
+def _own_pages_kernel(kind, cache, page_table) -> bool:
+    """Whether a decode step's own part runs in the kernel: on the chip,
+    for rows the kernel takes (a rehearsal's tiny heads go through XLA)
+    and that are worth a grid step each."""
+    page_tokens, width = cache.shape[-2:]
+    n_pages = page_table.shape[1]
+    return (common.on_tpu() and pallas_attention.decode_pages_fits(
+        kind.head_dim, width, page_tokens, n_pages)
+        and n_pages * page_tokens * width * cache.dtype.itemsize
+        >= OWN_PAGES_KERNEL_FROM)
+
+
+def _own_sums(kind, q, cache, layer, page_table, ctx_len):
+    """``common.softmax_sums`` of each row's queries ``q`` [B, heads,
+    head_dim] over its OWN rows: the pages ``page_table`` [B, P] names of
+    ``layer`` of the whole ``cache``, visible below ``ctx_len`` and, under
+    the kind's ``window``, from ``ctx_len - window`` on."""
+    if _own_pages_kernel(kind, cache, page_table):
+        return pallas_attention.decode_pages(
+            q, cache, layer, page_table, ctx_len, kv_heads=kind.kv_heads,
+            scale=kind.head_dim ** -0.5, window=kind.window)
+    ctx = common.layer_page_rows(cache, layer, page_table)
     at = jnp.arange(ctx.shape[1])[None, None, None, :]
     n_own = ctx_len[:, None, None, None]
     own = at < n_own
     if kind.window is not None:
         own &= at >= n_own - kind.window
-    sums = _sums(kind, "bkgd,btkd->bkgt", "bkgt,btkd->bkgd", q, ctx, own)
+    return _sums(kind, "bkgd,btkd->bkgt", "bkgt,btkd->bkgd",
+                 _grouped(kind, q), ctx, own)
+
+
+def attn_decode(kind, lp: dict, q, cache, layer, page_table, ctx_len, prefix,
+                n_prefix, gates=None, prefix_first=0):
+    """One new token per row, its softmax in two parts (as
+    mla.mla_decode): each row against its OWN cached rows, the pages
+    ``page_table`` [B, P] names of ``layer`` of the whole ``cache``
+    [layers, pages, page_tokens, kv_width], visible below ``ctx_len`` (the
+    new token's own row the last of them); all rows' queries against the
+    shared prefix rows ``prefix`` (the first of them at position
+    ``prefix_first``) in one product per key-value head. Under the kind's
+    ``window`` a row whose token stands at position ``n_prefix + ctx_len -
+    1`` sees the ``window`` positions that end there: of its own rows
+    those from ``ctx_len - window`` on, of the prefix those from
+    ``n_prefix + ctx_len - window`` on. On the chip the own part is
+    ops/pallas_attention.py ``decode_pages``, which reads each row's pages
+    where they lie (rows worth a grid step each: ``_own_pages_kernel``);
+    elsewhere the pages are gathered and the part goes through XLA, which
+    is what the kernel is checked against."""
+    sums = _own_sums(kind, q, cache, layer, page_table, ctx_len)
     shared = None
     if prefix is not None:
         at = prefix_first + jnp.arange(prefix.shape[0])
         seen = at < n_prefix
         if kind.window is not None:
-            seen = seen & (at >= n_prefix + n_own - kind.window)
-        shared = _sums(kind, "bkgd,skd->bkgs", "bkgs,skd->bkgd", q, prefix,
-                       seen)
+            seen = seen & (at >= n_prefix + ctx_len[:, None, None, None]
+                           - kind.window)
+        shared = _sums(kind, "bkgd,skd->bkgs", "bkgs,skd->bkgd",
+                       _grouped(kind, q), prefix, seen)
     return _out(lp, common.merge_softmax_sums(sums, shared), gates)

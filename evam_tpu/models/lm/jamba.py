@@ -434,10 +434,9 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
         with jax.named_scope("attn"):
             q, kv = _qkv(cfg, lp, x)
             pages = pages.at[j, dest_page, dest_off].set(kv)
-            ctx = pages[j][page_table].reshape(b, -1, cfg.kv_width)
             x = x + attention.attn_decode(
-                cfg, lp, q, ctx, ctx_len,
-                common.page_rows(pages[j], prefix_pages), n_prefix)
+                cfg, lp, q, pages, j, page_table, ctx_len,
+                common.layer_page_rows(pages, j, prefix_pages), n_prefix)
         return _feed_forward(cfg, lp, x), pages
 
     with jax.named_scope("embed"):

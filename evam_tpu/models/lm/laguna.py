@@ -412,8 +412,8 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
         seen, first = attention.window_pages(kind.window, prefix_pages,
                                              n_prefix, page_tokens)
         y = attention.attn_decode(
-            kind, lp, q, common.layer_page_rows(pages, i, page_table),
-            ctx_len, common.layer_page_rows(pages, i, seen), n_prefix,
+            kind, lp, q, pages, i, page_table, ctx_len,
+            common.layer_page_rows(pages, i, seen), n_prefix,
             attention.head_gates(lp, h), first)
         return y, pages
 

@@ -471,8 +471,7 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
             q, kv = attention.qkv(cfg, lp, h, pos)
             pages = pages.at[j, dest_page, dest_off].set(kv)
             y = attention.attn_decode(
-                cfg, lp, q,
-                common.layer_page_rows(pages, j, page_table), ctx_len,
+                cfg, lp, q, pages, j, page_table, ctx_len,
                 common.layer_page_rows(pages, j, prefix_pages), n_prefix)
         return y, pages
 

@@ -66,6 +66,16 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # counter above
     "evam_generate_window_rows_read": ("counter", ("kind",)),
     "evam_generate_window_rows_skipped": ("counter", ("kind",)),
+    # a decode step (``kind="decode"`` alone), per row that carries a
+    # sequence: of the pages its table names, those that hold a row of
+    # the sequence and those wholly behind its rows; the two add up to
+    # rows x the table's width. The second is what the decode kernel of
+    # the plain key-value families neither fetches nor computes
+    # (ops/pallas_attention.py ``decode_pages``; a window layer also
+    # leaves out the pages wholly before its window, not counted here);
+    # a family whose own rows are gathered through XLA reads them all
+    "evam_generate_own_pages_read": ("counter", ("kind",)),
+    "evam_generate_own_pages_skipped": ("counter", ("kind",)),
     # per-slot recurrent state (a family that keeps none counts 0): slot
     # states a step read and wrote (decode rows; a chunk's segments),
     # sequences started from the prefix snapshot, and the state's bytes

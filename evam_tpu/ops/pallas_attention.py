@@ -1,8 +1,14 @@
-"""Pallas TPU kernel: the attention of a packed prefill chunk's queries over
-cache rows a key-value head, never materialising the scores. ONE kernel for
-every family whose chunks run one (models/lm/attention.py: LFM2-MoE's and
-Laguna's grouped queries; models/lm/mla.py: DeepSeek-V2's and Kimi-Linear's
-latent attention over materialised heads).
+"""Pallas TPU kernels of the language models' attention. ``chunk_attention``:
+a packed prefill chunk's queries over cache rows a key-value head, never
+materialising the scores; ONE kernel for every family whose chunks run one
+(models/lm/attention.py: LFM2-MoE's and Laguna's grouped queries;
+models/lm/mla.py: DeepSeek-V2's and Kimi-Linear's latent attention over
+materialised heads). ``decode_pages`` (at the end of the file): a decode
+step's rows, each over its OWN pages of a ``[k ; v]`` cache, read where
+they lie by the row's page table (models/lm/attention.py ``attn_decode``:
+Laguna's and LFM2-MoE's; the prefix's part of that softmax stays with XLA).
+
+The chunk kernel.
 
 A prefill chunk asks, for every token and query head, a softmax over up to
 ~3 k cached rows: the shared instruction prefix, the sequence's own earlier
@@ -271,3 +277,237 @@ def chunk_attention_xla(q, k, v, bounds, q_shared=None, k_shared=None, *,
     if not hi:
         p = p.astype(F32)
     return jnp.einsum("grs,gsd->grd", p, v, **hi).astype(jnp.bfloat16)
+
+
+# ------------------------------------------------- decode: a row's own pages
+
+
+def _pages_kernel(layer_ref, table_ref, len_ref, q_ref, cache_ref, acc_out,
+                  stat_out, buf_ref, sem_ref, qbd_ref, m_ref, l_ref, acc_ref,
+                  *, scale, window, d, head_rows, page_tokens, n_pages,
+                  group):
+    """One row a grid step: its pages copied out of the cache where they
+    lie, the next row's while this one's are computed. ``q_ref`` [rows,
+    lane]: the row's query heads, ``head_rows`` a key-value head, a head's
+    ``d`` values first in its whole lane tiles (``decode_pages``; so is a
+    row of ``acc_out``); ``cache_ref`` the whole cache in HBM; ``buf_ref``
+    [2, pages x page_tokens, kv_width]: two rows' pages, ``[k ; v]`` a
+    token. ``qbd_ref`` [rows, kv_width / 2]: the queries block-diagonal
+    over the keys' lanes, so that ONE product scores every head against
+    its own key-value head's keys; ``acc_ref`` as wide: every head's
+    weights over every head's values, of which a row's own head's lanes
+    are its output. The pages are walked ``group`` at a time (one product
+    over their tokens, the online softmax between groups)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    tokens = group * page_tokens
+    rows, half = qbd_ref.shape
+    lane = q_ref.shape[1]
+    per = lane // d    # heads under 128 values share a lane tile
+
+    def pages_of(i):
+        """The pages row ``i`` needs, ``[first, last)``: none wholly behind
+        its rows, none wholly before its window."""
+        n = len_ref[i]
+        last = jax.lax.div(n + page_tokens - 1, page_tokens)
+        if window is None:
+            return 0, last
+        return jax.lax.div(jnp.maximum(n - window, 0), page_tokens), last
+
+    def copy(i, slot, p):
+        return pltpu.make_async_copy(
+            cache_ref.at[layer_ref[0], table_ref[i * n_pages + p]],
+            buf_ref.at[slot, pl.ds(p * page_tokens, page_tokens)],
+            sem_ref.at[slot, p])
+
+    def fetch(i, slot):
+        def start(p, carry):
+            copy(i, slot, p).start()
+            return carry
+
+        jax.lax.fori_loop(*pages_of(i), start, 0)
+
+    slot = jax.lax.rem(b, 2)
+
+    @pl.when(b == 0)
+    def _():
+        # what no copy has written is masked, and must be finite
+        buf_ref[...] = jnp.zeros(buf_ref.shape, buf_ref.dtype)
+        fetch(0, 0)
+
+    pl.when(b + 1 < pl.num_programs(0))(lambda: fetch(b + 1, 1 - slot))
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+
+    def rows_of(h):
+        """The query rows of key-value head ``h``, whose keys (and values)
+        start ``h % per * d`` lanes into lane tile ``h // per`` of a row."""
+        return (row >= h * head_rows) & (row < (h + 1) * head_rows)
+
+    n = len_ref[b]
+    first, last = pages_of(b)
+    m_ref[...] = jnp.full(m_ref.shape, NEG, F32)
+    l_ref[...] = jnp.zeros(l_ref.shape, F32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+    q = q_ref[...].astype(F32)
+    for t in range(half // lane):
+        tile = jnp.zeros((rows, lane), F32)
+        for j in range(per):
+            tile = jnp.where(rows_of(t * per + j),
+                             pltpu.roll(q, j * d, 1) if j else q, tile)
+        qbd_ref[:, t * lane:(t + 1) * lane] = tile.astype(qbd_ref.dtype)
+
+    def visit(gi, carry):
+        for k in range(group):
+            p = gi * group + k
+            pl.when((p >= first) & (p < last))(
+                lambda p=p: copy(b, slot, p).wait())
+        at = pl.multiple_of(gi * tokens, tokens)
+        s = jax.lax.dot_general(
+            qbd_ref[...], buf_ref[slot, pl.ds(at, tokens), :half],
+            (((1,), (1,)), ((), ())), preferred_element_type=F32) * scale
+        col = at + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = col < n
+        if window is not None:
+            ok &= col >= n - window
+        s = jnp.where(ok, s, NEG)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        w = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + w.sum(axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            w.astype(buf_ref.dtype), buf_ref[slot, pl.ds(at, tokens), half:],
+            (((1,), (0,)), ((), ())), preferred_element_type=F32)
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(jax.lax.div(first, group),
+                      jax.lax.div(last + group - 1, group), visit, 0)
+    out = jnp.zeros(acc_out.shape, F32)
+    for t in range(half // lane):
+        tile = acc_ref[:, t * lane:(t + 1) * lane]
+        for j in range(per):
+            out = jnp.where(
+                rows_of(t * per + j),
+                pltpu.roll(tile, lane - j * d, 1) if j else tile, out)
+    acc_out[...] = out
+    lead = jax.lax.broadcasted_iota(
+        jnp.int32, stat_out.shape, 1) < stat_out.shape[1] // 2
+    stat_out[...] = jnp.where(lead, m_ref[...], l_ref[...])
+
+
+#: what two rows' pages may take on the chip (the table's width is the
+#: program's: a longer one goes through XLA)
+PAGES_VMEM = 8 << 20
+#: pages a row walks at once, one product over their tokens: a visit a
+#: page is a chain of two products and a softmax that waits 0.5 us for
+#: itself (PERF.md section 6, PR 49: LFM2's layer 138 us a page at a
+#: time for 99 three at once, the copies alone 98)
+PAGE_GROUP = 4
+
+
+def decode_pages_fits(head_dim: int, kv_width: int, page_tokens: int,
+                      n_pages: int) -> bool:
+    """Whether ``decode_pages`` takes a cache of such rows under such a
+    table: heads that fill or evenly share the lanes of whole tiles, keys
+    of whole tiles, pages of whole bfloat16 row tiles, and two rows' pages
+    within ``PAGES_VMEM``. (A rehearsal's tiny widths do not fit, and go
+    through XLA wherever they run.)"""
+    return ((kv_width // 2) % 128 == 0 and page_tokens % 16 == 0
+            and (128 % head_dim == 0 or head_dim % 128 == 0)
+            and 2 * n_pages * page_tokens * kv_width * 2 <= PAGES_VMEM)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kv_heads", "scale", "window", "interpret"))
+def decode_pages(q, cache, layer, page_table, ctx_len, *, kv_heads, scale,
+                 window=None, interpret=False):
+    """The OWN-rows part of a decode step's softmax, each row's pages read
+    where they lie. ``q`` [B, heads, d]; ``cache`` [layers, pages,
+    page_tokens, kv_width], the WHOLE cache, rows ``[k ; v]`` of
+    ``kv_heads`` heads of ``d``; ``layer`` an int32 scalar (traced: the
+    layers run in one scan); ``page_table`` [B, P]; ``ctx_len`` [B]: row
+    ``b`` sees the first ``ctx_len[b]`` tokens of its pages, under a
+    ``window`` the last ``window`` of those. Returns what
+    ``models/lm/common.py`` ``softmax_sums`` returns for those rows:
+    ``(m, l, acc)`` float32, [B, kv_heads, group, 1 | 1 | d], not divided;
+    a row that sees nothing ``(NEG, 0, 0)``.
+
+    Grid (B,): step ``b`` copies row ``b + 1``'s pages ``cache[layer,
+    page_table[b + 1, p]]`` into one half of a buffer while it walks row
+    ``b``'s in the other, so nothing is gathered and a page is read once.
+    A page the row does not need (wholly behind ``ctx_len``, or wholly
+    before the window) is not copied; the pages are computed
+    ``PAGE_GROUP`` at a time, what is not visible masked, and a group
+    none of whose pages the row needs is not computed. The group's query
+    rows are padded to 8, a head's values to whole lane tiles."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, d = q.shape
+    page_tokens, width = cache.shape[2:]
+    n_pages = page_table.shape[1]
+    half, g = width // 2, heads // kv_heads
+    if not decode_pages_fits(d, width, page_tokens, n_pages):
+        raise ValueError(f"heads of {d} in rows of {width}, {n_pages} pages "
+                         f"of {page_tokens}: not whole tiles, or too many")
+    gpad = -(-g // 8) * 8
+    rows = -(-kv_heads * gpad // 16) * 16
+    lane = -(-d // 128) * 128
+    qg = jnp.pad(q.reshape(b, kv_heads, g, d),
+                 ((0, 0), (0, 0), (0, gpad - g), (0, lane - d)))
+    qg = jnp.pad(qg.reshape(b, kv_heads * gpad, lane),
+                 ((0, 0), (0, rows - kv_heads * gpad), (0, 0)))
+
+    def mine(i, *_):
+        return i, 0, 0
+
+    group = min(n_pages, PAGE_GROUP)
+    held = -(-n_pages // group) * group * page_tokens
+    acc, stat = pl.pallas_call(
+        functools.partial(_pages_kernel, scale=scale, window=window, d=d,
+                          head_rows=gpad, page_tokens=page_tokens,
+                          n_pages=n_pages, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((None, rows, lane), mine),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, rows, lane), mine),
+                       pl.BlockSpec((None, rows, 128), mine)],
+            scratch_shapes=[
+                pltpu.VMEM((2, held, width), cache.dtype),
+                pltpu.SemaphoreType.DMA((2, n_pages)),
+                pltpu.VMEM((rows, half), q.dtype),
+                pltpu.VMEM((rows, 1), F32),
+                pltpu.VMEM((rows, 1), F32),
+                pltpu.VMEM((rows, half), F32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, rows, lane), F32),
+                   jax.ShapeDtypeStruct((b, rows, 128), F32)],
+        compiler_params=pltpu.CompilerParams(
+            # a step starts the next row's copies: the rows run in order
+            dimension_semantics=("arbitrary",),
+            # half the chip's 128 MiB, far more than the buffers above:
+            # what the call may take XLA cannot hold on chip ACROSS it.
+            # At 32 MiB XLA used the room the gathered rows had left to
+            # fetch LFM2's dense weights (59 MB) behind the mixers of
+            # EVERY layer, for the two that read them: 0.9 ms a step
+            # (PERF.md section 6, PR 49)
+            vmem_limit_bytes=64 * 1024 * 1024),
+        # the pages it reads, not the cache it is handed
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * rows * half * n_pages * page_tokens,
+            transcendentals=b * rows * n_pages * page_tokens,
+            bytes_accessed=b * n_pages * page_tokens * width
+            * cache.dtype.itemsize),
+        name="attn_decode_pages",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32).reshape(-1), ctx_len.astype(jnp.int32),
+      qg, cache)
+    acc = acc[:, :kv_heads * gpad, :d].reshape(b, kv_heads, gpad, d)[:, :, :g]
+    stat = stat[:, :kv_heads * gpad].reshape(b, kv_heads, gpad, 128)[:, :, :g]
+    return stat[..., :1], stat[..., 64:65], acc

@@ -431,10 +431,13 @@ def test_a_decode_row_sees_the_7th_row_before_it_and_not_the_8th():
     prefix = jnp.asarray(r.standard_normal((8, width)), lm.BF16)
     half = width // 2
 
+    # each row's own rows are one page of 12 of a one-layer cache
+    table = jnp.arange(3)[:, None]
+
     def run(ctx, prefix):
         # the prefix list holds positions 8..15 of a prefix of 16
         return np.asarray(attention.attn_decode(
-            kind, lp, q, ctx, ctx_len, prefix, 16,
+            kind, lp, q, ctx[None], 0, table, ctx_len, prefix, 16,
             attention.head_gates(lp, h), 8), np.float32)
 
     base = run(ctx, prefix)
@@ -459,7 +462,8 @@ def test_a_decode_row_sees_the_7th_row_before_it_and_not_the_8th():
 
     def full(ctx):
         return np.asarray(attention.attn_decode(
-            cfg.full, full_lp, fq, ctx, ctx_len, prefix, 8), np.float32)
+            cfg.full, full_lp, fq, ctx[None], 0, table, ctx_len, prefix, 8),
+            np.float32)
 
     assert np.abs(full(ctx.at[2, 0, half:].add(1.0))[2]
                   - full(ctx)[2]).max() > 1e-3
@@ -730,6 +734,56 @@ def test_the_window_counters_count_rows_read_and_rows_skipped(engine):
     assert engine._window == 8
     assert metrics.get_counter("evam_generate_window_rows_read",
                                {"kind": "decode"}) > 0
+
+
+def test_the_own_pages_counters_add_up_to_rows_times_the_table(engine):
+    """Per decode row the pages of its table that hold its own rows, and
+    those wholly behind them, which the decode kernel neither fetches nor
+    computes (ops/pallas_attention.py ``decode_pages``)."""
+    from evam_tpu.obs import metrics
+
+    def counted():
+        return {name: metrics.get_counter(f"evam_generate_{series}",
+                                          {"kind": "decode"})
+                for name, series in (("read", "own_pages_read"),
+                                     ("skipped", "own_pages_skipped"),
+                                     ("steps", "steps"),
+                                     ("tokens", "tokens"))}
+
+    _idle(engine)
+    before = counted()
+    _generate(engine, _prompt(6, 100))
+    deadline = time.time() + 10
+    while (counted()["tokens"] - before["tokens"] < NEW - 1
+           and time.time() < deadline):
+        time.sleep(0.05)
+    grew = {k: v - before[k] for k, v in counted().items()}
+    assert grew["steps"] == grew["tokens"] == NEW - 1   # one row a step
+    table = -(-SIZES.private_tokens // SIZES.page_tokens)
+    assert grew["read"] + grew["skipped"] == (NEW - 1) * table == 150
+    # step i feeds back own token 100 + i: 101 + i own rows, pages of 4
+    assert grew["read"] == sum(-(-(101 + i) // 4) for i in range(NEW - 1))
+    # a prefill chunk counts none
+    assert not metrics.get_counter("evam_generate_own_pages_read",
+                                   {"kind": "prefill"})
+
+
+@pytest.mark.parametrize("own_rows,read", [
+    (1, 1), (128, 1), (129, 2), (130, 2), (257, 3), (384, 3)])
+def test_a_decode_row_reads_the_pages_that_hold_its_rows(own_rows, read):
+    """At the deployment's sizes (pages of 128, a table of 3): a row of
+    130 own rows reads 2 pages of 3. The dispatch alone, on an engine that
+    was never built."""
+    eng = object.__new__(GenerateEngine)
+    eng.sizes = GenerateSizes()
+    eng.prefix = np.zeros(2048, np.int32)
+    eng._private_pages, eng._window, eng._decode = 3, None, None
+    eng._run = lambda *a, **kw: kw
+    step = eng._dispatch_decode_raw(
+        [(0, own_rows - 1, [17, 18, 19]), (1, 383, [20, 21, 22])], 16, [])
+    assert step["own_pages"] == (read + 3, 6 - (read + 3))
+    assert step["tokens"] == 2 and step["rows_read"] == (
+        2048 + own_rows + 2048 + 384)
 
 
 # ------------------------------------------------------ the comparator

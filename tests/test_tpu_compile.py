@@ -177,6 +177,17 @@ def test_conv_decode_kernel_compiles_at_published_widths(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
+def _own_pages_stay(text, cache, gathered, halves):
+    """A decode program's text holds no gather of the rows' own pages
+    (``gathered``: rows x table x page), neither half of them split off or
+    turned (``halves``), and no copy of the whole ``cache``."""
+    import re
+
+    for shape in (gathered, halves):
+        assert f"bf16[{shape}]" not in text, shape
+    assert not re.search(rf"= bf16\[{cache}\]\S* copy\(", text)
+
+
 def _compile_step(lm, cfg, params, one_chip, program, traced_prefix=False):
     """A family's ``decode`` step at 64 rows or its ``prefill`` chunk of
     512 tokens at the deployment's sizes (401 pages of 128, 128 slots, a
@@ -264,9 +275,10 @@ def test_lfm2_step_programs_compile_at_published_widths(one_chip,
     calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
     for name, n in (("expert_gate_up", 1), ("expert_down", 1),
                     ("conv_decode_rows", int(program == "decode")),
+                    ("attn_decode_pages", int(program == "decode")),
                     ("attn_chunk_attention", int(program == "prefill"))):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
-    assert len(calls) == 3
+    assert len(calls) == 3 + int(program == "decode")
     assert "ragged" not in text
     slot_state = "bf16[18,130,16,256]"
     moved = [line for line in text.splitlines()
@@ -275,8 +287,17 @@ def test_lfm2_step_programs_compile_at_published_widths(one_chip,
     if program == "decode":
         assert not moved, moved[:2]
         assert not re.search(r"= bf16\[(1,)?130,16,256\]\S* scatter\(", text)
+        # a row's own pages are read where they lie: none gathered, split
+        # into keys and values or turned, and the cache nowhere copied
+        _own_pages_stay(text, "6,401,128,1024", "64,3,128,1024",
+                        "64,384,512")
+        # nor does XLA fetch the dense layers' weights (59 MB) on chip
+        # behind every layer's mixer, as it did in the room the gathered
+        # rows left until the kernel asked for that room itself
+        assert "slice-start" not in text
     # no layer's pages sliced out (105 MB), no scores materialised
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert compiled.memory_analysis().temp_size_in_bytes < {
+        "decode": 16, "prefill": 64}[program] << 20
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -310,6 +331,7 @@ def test_laguna_step_programs_compile_at_published_widths(one_chip,
                                     traced_prefix=True)
     text = compiled.as_text()
     for name, n in (("expert_gate_up", 1), ("expert_down", 1),
+                    ("attn_decode_pages", 2 * int(program == "decode")),
                     ("attn_chunk_attention", 2 * int(program == "prefill"))):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
     assert "ragged" not in text
@@ -320,7 +342,13 @@ def test_laguna_step_programs_compile_at_published_widths(one_chip,
     # nor a layer taken out of the cache before its pages are gathered
     assert state["pages"].shape == (5, 401, 128, 2048)
     assert not re.search(r"= bf16\[401,128,2048\]", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
+    if program == "decode":
+        # once a kind of layer the kernel that reads a row's own pages
+        # where they lie (101 MiB of temporaries while they were gathered)
+        _own_pages_stay(text, "5,401,128,2048", "64,3,128,2048",
+                        "64,384,1024")
+    assert compiled.memory_analysis().temp_size_in_bytes < {
+        "decode": 16, "prefill": 400}[program] << 20
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -443,3 +471,29 @@ def test_chunk_attention_kernel_compiles_for_latent_heads(one_chip, heads):
     # neither list copied behind the other: the new rows' 896 padded to
     # whole blocks (2 x 34 MB at 128 heads) and no more
     assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
+
+
+@pytest.mark.parametrize("rows", [16, 64, 128])
+@pytest.mark.parametrize("heads,kv_heads,dim,window,layers", [
+    (48, 8, 128, None, 5),   # Laguna: a full layer
+    (64, 8, 128, 512, 5),    # a window layer
+    (32, 8, 64, None, 6),    # LFM2: two heads a lane tile
+    (20, 1, 128, None, 2),   # Jamba (served through XLA: rows too small)
+])
+def test_decode_pages_kernel_compiles_at_published_widths(
+        one_chip, rows, heads, kv_heads, dim, window, layers):
+    """The whole cache goes in as it lies and nothing of its size comes
+    out or is kept beside it."""
+    from evam_tpu.ops.pallas_attention import decode_pages
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *a: decode_pages(
+        *a, kv_heads=kv_heads, scale=dim ** -0.5, window=window)).lower(
+        s((rows, heads, dim)), s((layers, 401, 128, 2 * kv_heads * dim)),
+        s((), jnp.int32), s((rows, 3), jnp.int32),
+        s((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "attn_decode_pages" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
