@@ -84,6 +84,7 @@ largest logits with their ids.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -98,6 +99,7 @@ from evam_tpu.engine.pages import PagePool
 from evam_tpu.models.lm import family
 from evam_tpu.obs import get_logger, metrics
 from evam_tpu.obs import trace
+from evam_tpu.ops.pallas_attention import CLASSES, count_classes
 from evam_tpu.sched.classes import DEFAULT_PRIORITY, PRIORITIES, SchedConfig
 from evam_tpu.sched.shedder import Shedder
 
@@ -270,11 +272,31 @@ class _Step:
     #: a decode step: of the pages its sequences' tables name, those that
     #: hold a row of the sequence, and those wholly behind its rows
     own_pages: tuple[int, int]
+    #: a chunk of a family whose chunks run the chunk kernel: per kind of
+    #: such layer ``(its name, (mixed, whole, not visited))``, the (query
+    #: block, key block) pairs of one key-value head's grid over the
+    #: kind's layers, by class (ops/pallas_attention.py ``block_classes``)
+    key_blocks: list
     #: (row of the outputs, sequence) for every token this step sampled
     takers: list
     top: jax.Array
     ids: jax.Array
     held: jax.Array
+
+
+@functools.lru_cache(maxsize=1024)
+def _key_blocks(lm, cfg, seg: bytes, n_prefix: int, n_cont: int,
+                prefix_pages: int, cont_pages: int, page_tokens: int) -> list:
+    """``_Step.key_blocks`` of a chunk whose segments are ``seg`` (int32):
+    the family's ``chunk_key_blocks`` counted by class over each kind's
+    layers; none for a family whose chunks do not run the kernel. Kept a
+    layout: prompts of a few lengths pack into a few hundred layouts, and
+    a new one costs the engine's thread 0.3-0.9 ms of numpy."""
+    classes = getattr(lm, "chunk_key_blocks", None)
+    return [(name, tuple(layers * k for k in count_classes(pairs)))
+            for name, layers, pairs in classes(
+                cfg, np.frombuffer(seg, np.int32), n_prefix, n_cont,
+                prefix_pages, cont_pages, page_tokens)] if classes else []
 
 
 class GenerateEngine:
@@ -871,11 +893,17 @@ class GenerateEngine:
         # window layer the last of them that the chunk's first token sees
         cached = (n_prefix + n_cont) if n else 0
         seen = min(cached, self._window - 1) if self._window else cached
+        # the chunk's key blocks by class, as the kernel's own blocks cut
+        # what the program is handed (the whole table of continued pages)
+        key_blocks = _key_blocks(
+            self._lm, self.cfg, mat[1].tobytes(), int(n_prefix), int(n_cont),
+            self._prefix_pages, self._private_pages, sz.page_tokens)
         return self._run(
             "prefill", "prefill", self._prefill,
             (self._prefix_heads, mat, aux), tokens=live, rows_read=cached,
             takers=takers, window_read=seen, state_rows=len(segs),
-            restores=sum(row[2] == sz.slots + 1 for row in segs))
+            restores=sum(row[2] == sz.slots + 1 for row in segs),
+            key_blocks=key_blocks)
 
     def _dispatch_decode(self) -> _Step:
         seqs = self._decoding
@@ -927,7 +955,7 @@ class GenerateEngine:
 
     def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
              takers, state_rows, restores=0, window_read=None,
-             own_pages=(0, 0)) -> _Step:
+             own_pages=(0, 0), key_blocks=()) -> _Step:
         t0 = time.perf_counter()
         self._step_started = t0
         cold = key not in self._seen
@@ -944,7 +972,8 @@ class GenerateEngine:
             state_rows = restores = 0
         return _Step(kind, key, t0, tokens, rows_read,
                      rows_read if window_read is None else window_read,
-                     state_rows, restores, own_pages, takers, top, ids, held)
+                     state_rows, restores, own_pages, key_blocks, takers,
+                     top, ids, held)
 
     # ------------------------------------------------------------- harvest
 
@@ -979,6 +1008,10 @@ class GenerateEngine:
                         float(step.own_pages[0]), labels)
             metrics.inc("evam_generate_own_pages_skipped",
                         float(step.own_pages[1]), labels)
+        for name, counts in step.key_blocks:
+            for cls, n in zip(CLASSES, counts):
+                metrics.inc("evam_generate_chunk_key_blocks", float(n),
+                            {"layers": name, "class": cls})
         metrics.inc("evam_generate_state_rows", float(step.state_rows),
                     labels)
         metrics.inc("evam_generate_prefix_restores", float(step.restores))

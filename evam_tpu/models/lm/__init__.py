@@ -14,7 +14,11 @@ keeps per slot), optionally ``prefix_heads_shapes`` and ``prefix_heads``
 weights: a latent family's materialised heads of the shared prefix's rows,
 made from ``params``, the state and the pinned pages once warm-up has
 prefilled them, handed to ``prefill_chunk`` as ``prefix_heads``; a family
-without them has no such name), ``prefill_chunk`` and ``decode_tokens``
+without them has no such name), optionally ``chunk_key_blocks`` (a family
+whose chunks run the chunk attention kernel, ops/pallas_attention.py: on the
+host, per kind of such layer, the classes of the kernel call's key blocks
+for a chunk of given segments, which the engine counts by), ``prefill_chunk``
+and ``decode_tokens``
 (each returns the state, the top logits with their ids, and int32 ``[held assignments, held
 experts hit, expert matrices read a product]``, zeros for a family
 without experts) and ``SEGMENT_ALIGN``

@@ -60,6 +60,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from evam_tpu.models.lm import common
 from evam_tpu.models.lm.common import BF16, F32, mm, rms_norm
@@ -181,22 +182,34 @@ def head_gates(lp: dict, h):
                                       preferred_element_type=F32))
 
 
+def window_span(window, n_pages: int, n_prefix, page_tokens: int, xp=jnp):
+    """Of a shared prefix of ``n_pages`` pages whose length ``n_prefix`` is
+    an argument of the program (a chunk, the prefix's own among them): how
+    many pages a token BEHIND the prefix can see into under ``window``
+    (positions ``n_prefix - window + 1`` and later), a static length, one
+    page more where ``n_prefix`` need not end a page, and the first of
+    them. No window: all of them, from 0. ``xp``: ``numpy`` on the
+    host."""
+    if window is None:
+        return n_pages, 0
+    keep = min(n_pages, -(-(window - 1) // page_tokens) + 1)
+    return keep, xp.clip(-(-n_prefix // page_tokens) - keep, 0,
+                         n_pages - keep)
+
+
 def window_pages(window, pages, n_prefix, page_tokens: int):
     """The shared prefix's pages that hold a row some token BEHIND the
-    prefix can see under ``window`` (positions ``n_prefix - window + 1``
-    and later), and the position of their first row. No window: all of
-    them, from 0. With ``n_prefix`` a Python int (a decode step: the whole
-    prefix is there) the slice is static, exactly those pages; traced (a
-    chunk, the prefix's own among them) it is a dynamic slice of a static
-    length, one page more where ``n_prefix`` need not end a page."""
+    prefix can see under ``window``, and the position of their first row.
+    No window: all of them, from 0. With ``n_prefix`` a Python int (a
+    decode step: the whole prefix is there) the slice is static, exactly
+    those pages; traced it is a dynamic slice of ``window_span``'s static
+    length."""
     if pages is None or window is None:
         return pages, 0
-    n = len(pages)
     if isinstance(n_prefix, int):
         first = max(n_prefix - window + 1, 0) // page_tokens
         return pages[first:-(-n_prefix // page_tokens)], first * page_tokens
-    keep = min(n, -(-(window - 1) // page_tokens) + 1)
-    first = jnp.clip(-(-n_prefix // page_tokens) - keep, 0, n - keep)
+    keep, first = window_span(window, len(pages), n_prefix, page_tokens)
     return (jax.lax.dynamic_slice(jnp.asarray(pages), (first,), (keep,)),
             first * page_tokens)
 
@@ -260,6 +273,25 @@ def attn_prefill(kind, lp: dict, q, kv, seg, prefix, n_prefix, cont, n_cont,
             _sums(kind, "tkgd,skd->tkgs", "tkgs,skd->tkgd", _grouped(kind, q),
                   rows, seen[:, None, None, :]), None)
     return _out(lp, o, gates)
+
+
+def chunk_key_blocks(kind, seg, n_prefix: int, n_cont: int, prefix_pages: int,
+                     cont_rows: int, page_tokens: int):
+    """On the host: the classes of the (query block, key block) pairs
+    (ops/pallas_attention.py ``block_classes``) of the kernel call that
+    ``attn_prefill`` makes for such a chunk of a ``kind`` with
+    ``chunk_kernel``, handed the prefix's pages as a chunk's program hands
+    them (``window_span`` of ``prefix_pages``) and ``cont_rows`` continued
+    rows; ``seg`` a numpy array."""
+    keep, first = window_span(kind.window, prefix_pages, n_prefix,
+                              page_tokens, xp=np)
+    prefix_rows = keep * page_tokens
+    bounds, b0 = common.chunk_bounds(
+        seg, n_prefix, n_cont, prefix_rows, cont_rows, kind.window,
+        first * page_tokens, xp=np)
+    return pallas_attention.block_classes(
+        bounds, b0, (prefix_rows + cont_rows + len(seg),),
+        group=kind.heads // kind.kv_heads, xp=np)
 
 
 #: bytes of a row's own pages from which the kernel is the faster: under

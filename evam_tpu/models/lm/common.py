@@ -150,7 +150,7 @@ def merge_softmax_sums(own, shared):
 
 
 def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int,
-                 window: int | None = None, prefix_first=0):
+                 window: int | None = None, prefix_first=0, xp=jnp):
     """Which rows of ``[prefix rows | continued rows | the chunk's own
     rows]`` each token of a packed chunk may see, as ops/pallas_attention.py's
     three half-open intervals ``[0, a) | [b0, b1) | [c0, c1)`` per token
@@ -167,27 +167,28 @@ def chunk_bounds(seg, n_prefix, n_cont, prefix_rows: int, cont_rows: int,
     The position of a prefix row is ``prefix_first`` + its index (the list
     may start behind the prefix's first row: what no token here can see
     need not be handed); of a continued row ``n_prefix`` + its index; of
-    an own row ``n_prefix`` + its place in its sequence."""
+    an own row ``n_prefix`` + its place in its sequence. ``xp``: ``numpy``
+    on the host, where the engine counts a chunk's key blocks by class."""
     t = seg.shape[0]
     b0 = prefix_rows
     c_base = b0 + cont_rows
     live = seg >= 0
-    idx = jnp.arange(t)
-    start = jnp.argmax(seg[:, None] == seg[None, :], axis=1)
-    before = jnp.where(live & (seg == 0), n_cont if cont_rows else 0, 0)
-    cols = [jnp.where(live, (n_prefix - prefix_first) if prefix_rows else 0,
+    idx = xp.arange(t)
+    start = xp.argmax(seg[:, None] == seg[None, :], axis=1)
+    before = xp.where(live & (seg == 0), n_cont if cont_rows else 0, 0)
+    cols = [xp.where(live, (n_prefix - prefix_first) if prefix_rows else 0,
                       0),
             b0 + before,
-            jnp.where(live, c_base + start, 0),
-            jnp.where(live, c_base + idx + 1, 0)]
+            xp.where(live, c_base + start, 0),
+            xp.where(live, c_base + idx + 1, 0)]
     if window is not None:
         # the first position the token sees, counted from the prefix's end
         lo = before + idx - start - window + 1
-        cols[2] = jnp.where(
-            live, c_base + jnp.maximum(start, idx - window + 1), 0)
-        cols += [jnp.maximum(lo + n_prefix - prefix_first, 0),
-                 b0 + jnp.maximum(lo, 0)]
-    return jnp.stack(cols, axis=1).astype(jnp.int32), b0
+        cols[2] = xp.where(
+            live, c_base + xp.maximum(start, idx - window + 1), 0)
+        cols += [xp.maximum(lo + n_prefix - prefix_first, 0),
+                 b0 + xp.maximum(lo, 0)]
+    return xp.stack(cols, axis=1).astype(xp.int32), b0
 
 
 def packed_conv_inputs(u_pre, seg, conv0, k1: int):

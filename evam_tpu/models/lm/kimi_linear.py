@@ -485,6 +485,18 @@ def prefix_heads(cfg: Config, params: dict, state, prefix_pages) -> list:
     return mla.prefix_heads(cfg, params["mla"], state["pages"], prefix_pages)
 
 
+def chunk_key_blocks(cfg: Config, seg, n_prefix: int, n_cont: int,
+                     prefix_pages: int, cont_pages: int,
+                     page_tokens: int) -> list:
+    """What the engine counts a chunk's key blocks by: per kind of layer
+    whose chunks run the chunk kernel ``(its name, its layers, the classes
+    of one layer's call)`` for a chunk of segments ``seg`` (numpy) over a
+    prefix of ``prefix_pages`` pages and ``cont_pages`` continued ones."""
+    return [("mla", len(cfg.mla_ids), mla.chunk_key_blocks(
+        seg, n_prefix, n_cont, prefix_pages * page_tokens,
+        cont_pages * page_tokens))]
+
+
 def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
                   dest_page, dest_off, prefix_pages, n_prefix, cont_pages,
                   n_cont, last_idx, seg_from, seg_to, prefix_heads=None):

@@ -364,6 +364,21 @@ def _layers(cfg: Config, params: dict, x, pages, live, attn_layer):
     return x, pages, held
 
 
+def chunk_key_blocks(cfg: Config, seg, n_prefix: int, n_cont: int,
+                     prefix_pages: int, cont_pages: int,
+                     page_tokens: int) -> list:
+    """What the engine counts a chunk's key blocks by: per kind of layer
+    whose chunks run the chunk kernel ``(its name, its layers, the classes
+    of one layer's call)`` for a chunk of segments ``seg`` (numpy) over a
+    prefix of ``prefix_pages`` pages and ``cont_pages`` continued ones."""
+    return [(name, len(ids), attention.chunk_key_blocks(
+        kind, seg, n_prefix, n_cont, prefix_pages, cont_pages * page_tokens,
+        page_tokens))
+        for name, ids, kind in (("attn_full", cfg.full_ids, cfg.full),
+                                ("attn_window", cfg.window_ids,
+                                 cfg.windowed))]
+
+
 def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
                   dest_page, dest_off, prefix_pages, n_prefix, cont_pages,
                   n_cont, last_idx, seg_from, seg_to):

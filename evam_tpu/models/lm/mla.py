@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from evam_tpu.models.lm import common
 from evam_tpu.models.lm.common import BF16, F32, es, mm, rms_norm
@@ -204,3 +205,16 @@ def mla_prefill(cfg, lp: dict, q_nope, q_rope, lat, seg, prefix,
                _tail(cfg, q_rope).transpose(1, 0, 2), k_r,
                scale=cfg.softmax_scale, b0=b0)
     return mm(o.transpose(1, 0, 2).reshape(t, -1), lp["o"])
+
+
+def chunk_key_blocks(seg, n_prefix: int, n_cont: int, prefix_rows: int,
+                     cont_rows: int):
+    """On the host: the classes of the (query block, key block) pairs
+    (ops/pallas_attention.py ``block_classes``) of the kernel call that
+    ``mla_prefill`` makes for such a chunk over ``prefix_rows`` prefix rows
+    and ``cont_rows`` continued ones; ``seg`` a numpy array."""
+    bounds, b0 = common.chunk_bounds(seg, n_prefix, n_cont, prefix_rows,
+                                     cont_rows, xp=np)
+    new = cont_rows + len(seg)
+    return pallas_attention.block_classes(
+        bounds, b0, (prefix_rows, new) if prefix_rows else (new,), xp=np)

@@ -76,6 +76,15 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # a family whose own rows are gathered through XLA reads them all
     "evam_generate_own_pages_read": ("counter", ("kind",)),
     "evam_generate_own_pages_skipped": ("counter", ("kind",)),
+    # a chunk of a family whose chunks run the chunk kernel
+    # (ops/pallas_attention.py): per kind of such layer (``layers``: mla |
+    # attn | attn_full | attn_window) the (query block, key block) pairs
+    # of one key-value head's grid, over the kind's layers, by ``class``:
+    # whole (every live query row sees every key: no mask is computed),
+    # none (no row sees any: not visited), mixed (the rule is
+    # asked a score); counted on the host from the chunk's segments with
+    # the kernel's own block sizes
+    "evam_generate_chunk_key_blocks": ("counter", ("layers", "class")),
     # per-slot recurrent state (a family that keeps none counts 0): slot
     # states a step read and wrote (decode rows; a chunk's segments),
     # sequences started from the prefix snapshot, and the state's bytes

@@ -23,16 +23,20 @@ attention), so the scores never leave the chip.
 The query heads that read one key-value head are one long list of query
 rows (``q`` [kv_heads, R, d], R = tokens x group); keys and values are
 ``k``, ``v`` [kv_heads, S, d]; the grid's first axis walks the key-value
-heads. Which keys a query row may see is three half-open intervals of key
-positions per row, ``[0, a) | [b0, b1) | [c0, c1)`` (``bounds`` [R, 4] = a,
-b1, c0, c1; ``b0`` static; [R, 6] with a first visible row of the two
-leading intervals, for a layer under a window: models/lm/laguna.py): the
+heads, several a step (``HEAD_BODIES``). Which keys a query row may see
+is three half-open intervals of key positions per row, ``[0, a) | [b0, b1)
+| [c0, c1)`` (``bounds`` [R, 4] = a, b1, c0, c1; ``b0`` static; [R, 6]
+with a first visible row of the two leading intervals, for a layer under a window: models/lm/laguna.py): the
 prefix rows, the sequence's earlier rows, and the chunk's own rows up to
 the token itself; the same for every key-value head. A row whose intervals
-are empty (a padded token) comes out 0.
+are empty (a padded token) comes out 0. The rule is asked a score only
+where a key block's answer differs from row to row: every (query block, key
+block) pair has a class, read off the bounds before the kernel runs
+(``block_classes``), and a block every live row sees whole runs without a
+mask, one no row sees is not visited.
 
 Two things a latent family adds, both absent for the others, whose calls
-trace to what they did without them:
+hold no operand for them:
 
 * a SECOND SCORE TERM over a part that all heads share: ``q_shared`` [kv_heads,
   R, P] against ``k_shared`` [S, P] (the rope part of a latent row, one list
@@ -51,6 +55,7 @@ check the kernel against it in the interpreter.
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -59,101 +64,36 @@ F32 = jnp.float32
 NEG = -1e30
 
 
-def _visible(col, bounds, b0, part=None):
-    """The one visibility rule: ``bounds`` [rows, 4] = a, b1, c0, c1 for
-    ``[0, a) | [b0, b1) | [c0, c1)``, or [rows, 6] with a first visible
-    row of the two leading intervals behind them, a_lo and b_lo (a window:
-    models/lm/common.py ``chunk_bounds``). ``part``: where ``col`` is known
+def _intervals(bounds, b0, part=None):
+    """The visible intervals of key positions per row, ``[(first, end)]``
+    (``first`` None: 0): ``bounds`` [..., 4] = a, b1, c0, c1 for ``[0, a) |
+    [b0, b1) | [c0, c1)``, or [..., 6] with a first visible row of the two
+    leading intervals behind them, a_lo and b_lo (a window:
+    models/lm/common.py ``chunk_bounds``). ``part``: where a key is known
     to lie: "prefix" below ``b0``, where only the first interval can hold
     (``a`` never passes ``b0``), "rest" at or behind it, where only the
     other two can; None: anywhere."""
-    a, b1, c0, c1 = (bounds[:, i:i + 1] for i in range(4))
-    windowed = bounds.shape[1] == 6
-    if windowed:
-        a_lo, b_lo = bounds[:, 4:5], bounds[:, 5:6]
-
-    def lead():
-        return ((col >= a_lo) & (col < a)) if windowed else (col < a)
-
-    def cont():
-        return (col >= (b_lo if windowed else b0)) & (col < b1)
-
-    def own():
-        return (col >= c0) & (col < c1)
-
-    if part == "prefix":
-        return lead()
-    if part == "rest":
-        return cont() | own()
-    return lead() | cont() | own()
+    a, b1, c0, c1 = (bounds[..., i:i + 1] for i in range(4))
+    a_lo, b_lo = ((bounds[..., 4:5], bounds[..., 5:6])
+                  if bounds.shape[-1] == 6 else (None, b0))
+    lead, rest = [(a_lo, a)], [(b_lo, b1), (c0, c1)]
+    return {"prefix": lead, "rest": rest, None: lead + rest}[part]
 
 
-def _kernel(bounds_ref, q_ref, *refs, scale, b0, shared, prefix_blocks):
-    """``refs``: ``q_shared`` where there is a ``shared`` term; per key list
-    its keys, its values and, with ``shared``, its shared part; the output;
-    the running maximum, sum and output. ``prefix_blocks``: the key blocks
-    of the first of two lists (None: one list). A list's key block is what
-    its refs hold."""
-    from jax.experimental import pallas as pl
-
-    if shared:
-        qs_ref, *refs = refs
-    *lists, o_ref, m_ref, l_ref, acc_ref = refs
-    per = 3 if shared else 2
-    lists = [lists[i:i + per] for i in range(0, len(lists), per)]
-    kv = pl.program_id(2)
-    contract_last = (((1,), (1,)), ((), ()))
-
-    @pl.when(kv == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG, F32)
-        l_ref[...] = jnp.zeros(l_ref.shape, F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
-
-    def visit(k_ref, v_ref, ks_ref=None, skipped=0, col0=0, part=None):
-        """One key block of a list whose first row stands at position
-        ``col0``, ``skipped`` key blocks into the key axis."""
-        rows = k_ref.shape[0]
-        v = v_ref[...]
-        s = jax.lax.dot_general(q_ref[...], k_ref[...], contract_last,
-                                preferred_element_type=F32)
-        if shared:
-            s = s + jax.lax.dot_general(qs_ref[...], ks_ref[...],
-                                        contract_last,
-                                        preferred_element_type=F32)
-        s = s * scale
-        first = (kv - skipped) * rows + col0 if skipped else kv * rows
-        col = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = _visible(col, bounds_ref[...], b0, part)
-        s = jnp.where(ok, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=F32)
-        m_ref[...] = m_new
-
-    if prefix_blocks is None:
-        visit(*lists[0])
-    else:
-        pl.when(kv < prefix_blocks)(
-            lambda: visit(*lists[0], part="prefix"))
-        pl.when(kv >= prefix_blocks)(
-            lambda: visit(*lists[1], skipped=prefix_blocks, col0=b0,
-                          part="rest"))
-
-    @pl.when(kv == pl.num_programs(2) - 1)
-    def _():
-        l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
-            o_ref.dtype)
+def _visible(col, bounds, b0, part=None):
+    """The one visibility rule: whether the key at position ``col`` lies
+    in one of the row's ``_intervals``."""
+    return functools.reduce(operator.or_, (
+        col < end if first is None else (col >= first) & (col < end)
+        for first, end in _intervals(bounds, b0, part)))
 
 
-def _lists(x):
-    return x if isinstance(x, (tuple, list)) else (x,)
+def _live(bounds, b0):
+    """Whether a row sees anything at all (a padded token's intervals are
+    all empty)."""
+    return functools.reduce(operator.or_, (
+        end > (0 if first is None else first)
+        for first, end in _intervals(bounds, b0)))
 
 
 #: the scores a grid step holds: 512 keys under 1024 query rows (LFM2's and
@@ -163,6 +103,198 @@ def _lists(x):
 #: read 1.88 once and 1.22 once, for twice the scores in VMEM: PERF.md
 #: section 6, PR 44)
 SCORE_TILE = 1024 * 512
+
+
+#: the class of a (query block, key block) pair: ``MIXED``, the rule is
+#: asked a score; ``WHOLE``, every live row of the query block sees every
+#: key of the block, nothing is asked; ``NONE``, no row sees any: the pair
+#: is not visited
+MIXED, WHOLE, NONE = 0, 1, 2
+CLASSES = ("mixed", "whole", "none")
+#: a grid step's heads times the call's key lists: a head's chain (scores,
+#: softmax, values) leaves the MXU idle while the vector unit works and
+#: the other way round, and a grid step costs ~0.9 us of its own under ten
+#: operands; several heads a step, written stage by stage, overlap the one
+#: and share the other. The kernel's code holds every head of a step once
+#: a list and class (3.3 MB at four heads of two lists), and past this
+#: many a call inside a step program slows again (a v5e, PERF.md section
+#: 6, PR 51: DeepSeek's layer of two lists 1.19 ms a head a step, 0.96
+#: two, 1.02 four, though four read 0.90 called alone; LFM2's of one list
+#: 0.356, 0.286, 0.251)
+HEAD_BODIES = 4
+
+
+def chunk_blocks(rows: int, list_rows, block_q: int = 1024, block_k=None):
+    """How ``chunk_attention`` cuts ``rows`` query rows and key lists of
+    ``list_rows`` rows: the query block and, per list, ``(key block, how
+    many)``. The kernel's grid and the engine's count of a chunk's key
+    blocks by class (engine/generate.py) both come from here. ``block_k``
+    None: what ``SCORE_TILE`` leaves the query block, at most 1024; a list
+    shorter than a key block is one block of its own rows in whole lane
+    tiles."""
+    block_q = min(block_q, -(-rows // 16) * 16)
+    if block_k is None:
+        block_k = min(1024, SCORE_TILE // block_q // 128 * 128)
+    blocks = [min(block_k, -(-n // 128) * 128) for n in list_rows]
+    return block_q, [(b, -(-n // b)) for n, b in zip(list_rows, blocks)]
+
+
+def block_classes(bounds, b0, list_rows, block_q: int = 1024, block_k=None,
+                  group: int = 1, xp=jnp):
+    """The class of every (query block, key block) pair of the call
+    ``chunk_attention`` makes under ``bounds`` [R, 4 | 6] over key lists
+    of ``list_rows`` rows: int32 [query blocks, key blocks], ``MIXED``,
+    ``WHOLE`` or ``NONE``. The rule stays ``_intervals``, read at
+    a block's first and last key: a block is WHOLE where one interval of
+    every live row holds both (a dead row, every interval empty, does not
+    stop it: the kernel zeroes those rows itself; a list's padded keys
+    behind its last row lie in no interval, so their block is MIXED), and
+    not visited where no interval of any row reaches into it. On the
+    host, for the engine's counter: ``xp`` ``numpy``, and each row of
+    ``bounds`` may stand for ``group`` query rows (a token's heads)."""
+    block_q, lists = chunk_blocks(bounds.shape[0] * group, list_rows,
+                                  block_q, block_k)
+    nq = -(-bounds.shape[0] * group // block_q)
+    live = _live(bounds, b0)
+    inside, reach = [], []
+    for at, (block, n) in enumerate(lists):
+        # a list's blocks side by side: [rows, its blocks]
+        first = (b0 if at else 0) + block * xp.arange(n)[None, :]
+        end = first + block
+        sees_all = sees_any = False
+        for lo, hi in _intervals(
+                bounds, b0,
+                ("prefix", "rest")[at] if len(lists) == 2 else None):
+            lo = 0 if lo is None else lo
+            sees_all = sees_all | ((lo <= first) & (end <= hi))
+            sees_any = sees_any | ((hi > first) & (lo < end) & (hi > lo))
+        inside.append(sees_all & live)
+        reach.append(sees_any)
+    # how many rows of each query block are live, see all of a key block
+    # and see any of it: one product with the rows' membership
+    row = xp.arange(bounds.shape[0])[None, :] * group
+    block = xp.arange(nq)[:, None] * block_q
+    member = (row < block + block_q) & (row + group > block)
+    steps = sum(n for _, n in lists)
+    n_live, n_inside, n_reach = xp.split(
+        member.astype(xp.float32) @ xp.concatenate(
+            [live, *inside, *reach], axis=1).astype(xp.float32),
+        [1, 1 + steps], axis=1)
+    return xp.where(
+        n_reach > 0,
+        xp.where((n_inside == n_live) & (n_live > 0), WHOLE, MIXED),
+        NONE).astype(xp.int32)
+
+
+def count_classes(classes) -> tuple[int, int, int]:
+    """How many pairs of ``block_classes``'s array (on the host) are
+    ``CLASSES``: mixed, whole, not visited."""
+    return tuple(int((classes == c).sum()) for c in (MIXED, WHOLE, NONE))
+
+
+def _kernel(cls_ref, bounds_ref, q_ref, *refs, scale, b0, shared,
+            prefix_blocks):
+    """``cls_ref``: ``block_classes``, flat, a prefetched scalar operand.
+    ``q_ref`` [heads a step, query block, d]; ``refs``: ``q_shared`` where
+    there is a ``shared`` term; per key list its keys, its values (the
+    step's heads') and, with ``shared``, its shared part (one for all
+    heads); the output; the running maximum, sum and output. ``prefix_
+    blocks``: the key blocks of the first of two lists (None: one list). A
+    list's key block is what its refs hold."""
+    from jax.experimental import pallas as pl
+
+    if shared:
+        qs_ref, *refs = refs
+    *lists, o_ref, m_ref, l_ref, acc_ref = refs
+    per = 3 if shared else 2
+    lists = [lists[i:i + per] for i in range(0, len(lists), per)]
+    kv = pl.program_id(2)
+    cls = cls_ref[pl.program_id(1) * pl.num_programs(2) + kv]
+    contract_last = (((1,), (1,)), ((), ()))
+    heads = range(q_ref.shape[0])
+
+    @pl.when(kv == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    def visit(k_ref, v_ref, ks_ref=None, skipped=0, col0=0, part=None,
+              masked=True):
+        """One key block of a list whose first row stands at position
+        ``col0``, ``skipped`` key blocks into the key axis, for each of the
+        step's heads. Written STAGE BY STAGE over the heads (every head's
+        scores, then every head's softmax, then every head's values): a
+        head's chain is products, then vector work that needs them whole,
+        then products that need that, and within one head nothing
+        overlaps; the heads are independent, so one's products run on the
+        MXU beside another's softmax on the vector unit (PERF.md section
+        6, PR 51). Not ``masked`` (a WHOLE block): the same arithmetic with
+        the rule's answer known to be yes, so a live row's sums are bit
+        for bit the masked visit's. With a ``shared`` term the two
+        products are ONE over ``[q ; q_shared]`` and ``[k ; k_shared]``:
+        the MXU adds its 128-deep passes in float32, which is the sum the
+        two products' results made (bit for bit on a v5e, measured)."""
+        rows = k_ref.shape[1]
+        if masked:
+            first = (kv - skipped) * rows + col0 if skipped else kv * rows
+            col = first + jax.lax.broadcasted_iota(
+                jnp.int32, (q_ref.shape[1], rows), 1)
+            ok = _visible(col, bounds_ref[...], b0, part)
+        scores = []
+        for h in heads:
+            if shared:
+                s = jax.lax.dot_general(
+                    jnp.concatenate([q_ref[h], qs_ref[h]], axis=1),
+                    jnp.concatenate([k_ref[h], ks_ref[...]], axis=1),
+                    contract_last, preferred_element_type=F32)
+            else:
+                s = jax.lax.dot_general(q_ref[h], k_ref[h], contract_last,
+                                        preferred_element_type=F32)
+            s = s * scale
+            scores.append(jnp.where(ok, s, NEG) if masked else s)
+        weights = []
+        for h, s in zip(heads, scores):
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                p = jnp.where(ok, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=1, keepdims=True)
+            m_ref[h] = m_new
+            weights.append((alpha, p))
+        for h, (alpha, p) in zip(heads, weights):
+            v = v_ref[h]
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=F32)
+
+    def walk(here, *refs, **where):
+        """The key steps ``here`` of one list, each by its class."""
+        pl.when(here & (cls == MIXED))(lambda: visit(*refs, **where))
+        pl.when(here & (cls == WHOLE))(
+            lambda: visit(*refs, masked=False, **where))
+
+    if prefix_blocks is None:
+        walk(True, *lists[0])
+    else:
+        walk(kv < prefix_blocks, *lists[0], part="prefix")
+        walk(kv >= prefix_blocks, *lists[1], skipped=prefix_blocks, col0=b0,
+             part="rest")
+
+    @pl.when(kv == pl.num_programs(2) - 1)
+    def _():
+        # a dead row may have run through WHOLE blocks: zeroed by its
+        # bounds, not by its sum
+        l = l_ref[...]
+        o_ref[...] = jnp.where(
+            _live(bounds_ref[...], b0)[None],
+            acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0).astype(o_ref.dtype)
+
+
+def _lists(x):
+    return x if isinstance(x, (tuple, list)) else (x,)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -177,10 +309,10 @@ def chunk_attention(q, k, v, bounds, q_shared=None, k_shared=None, *, scale,
     each be a pair of lists, the prefix's ``b0`` rows and the rest's: the
     key axis walks the first, then the second, and neither is copied
     behind the other. R and every list's rows are padded here to whole
-    blocks; a list shorter than a key block is one block of its own rows
-    in whole lane tiles (a chunk's 896 new rows are not padded to 1024).
-    ``block_k`` None: what ``SCORE_TILE`` leaves the query block, at most
-    1024."""
+    blocks (``chunk_blocks``; a chunk's 896 new rows are not padded to
+    1024). Every (query block, key block) pair has a class, read off
+    ``bounds`` here (``block_classes``): the rule is asked a score only
+    where a block's answer differs from row to row."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -191,12 +323,13 @@ def chunk_attention(q, k, v, bounds, q_shared=None, k_shared=None, *, scale,
     kss = _lists(k_shared) if shared else [None] * len(ks)
     if len(ks) == 2 and ks[0].shape[1] != b0:
         raise ValueError("the first of two key lists holds the prefix's rows")
-    block_q = min(block_q, -(-r // 16) * 16)
-    if block_k is None:
-        block_k = min(1024, SCORE_TILE // block_q // 128 * 128)
-    rp = -(-r // block_q) * block_q
-    block_ks = [min(block_k, -(-x.shape[1] // 128) * 128) for x in ks]
-    blocks = [-(-x.shape[1] // b) for x, b in zip(ks, block_ks)]
+    list_rows = [x.shape[1] for x in ks]
+    hb = next(n for n in (4, 2, 1)
+              if n * len(ks) <= HEAD_BODIES and g % n == 0)
+    classes = block_classes(bounds, b0, list_rows, block_q, block_k)
+    block_q, blocks = chunk_blocks(r, list_rows, block_q, block_k)
+    rp = classes.shape[0] * block_q
+    steps = classes.shape[1]
 
     def pad(x, rows):
         """``x`` [..., rows, width] with zero rows behind its own."""
@@ -211,45 +344,49 @@ def chunk_attention(q, k, v, bounds, q_shared=None, k_shared=None, *, scale,
             return lambda j: j
         return lambda j: jnp.clip(j - skipped, 0, n - 1)
 
+    def mine(h, i, j, cls):
+        return h, i, 0
+
     in_specs = [pl.BlockSpec((block_q, bounds.shape[1]),
-                             lambda h, i, j: (i, 0)),
-                pl.BlockSpec((None, block_q, d), lambda h, i, j: (h, i, 0))]
+                             lambda h, i, j, cls: (i, 0)),
+                pl.BlockSpec((hb, block_q, d), mine)]
     args = [pad(bounds, rp), pad(q, rp)]
     if shared:
-        in_specs.append(pl.BlockSpec((None, block_q, q_shared.shape[2]),
-                                     lambda h, i, j: (h, i, 0)))
+        in_specs.append(pl.BlockSpec((hb, block_q, q_shared.shape[2]),
+                                     mine))
         args.append(pad(q_shared, rp))
     skipped = 0
-    for one_k, one_v, one_ks, n, rows in zip(ks, vs, kss, blocks, block_ks):
+    for one_k, one_v, one_ks, (rows, n) in zip(ks, vs, kss, blocks):
         blk = at(skipped, n)
-        in_specs += [pl.BlockSpec((None, rows, width), lambda h, i, j,
+        in_specs += [pl.BlockSpec((hb, rows, width), lambda h, i, j, cls,
                                   blk=blk: (h, blk(j), 0))
                      for width in (d, dv)]
         args += [pad(one_k, n * rows), pad(one_v, n * rows)]
         if shared:
             in_specs.append(pl.BlockSpec(
-                (rows, one_ks.shape[1]), lambda h, i, j, blk=blk:
+                (rows, one_ks.shape[1]), lambda h, i, j, cls, blk=blk:
                 (blk(j), 0)))
             args.append(pad(one_ks, n * rows))
         skipped += n
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=scale, b0=b0, shared=shared,
-            prefix_blocks=blocks[0] if len(ks) == 2 else None),
-        grid=(g, rp // block_q, sum(blocks)),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, block_q, dv),
-                               lambda h, i, j: (h, i, 0)),
+            prefix_blocks=blocks[0][1] if len(ks) == 2 else None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(g // hb, rp // block_q, steps),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((hb, block_q, dv), mine),
+            scratch_shapes=[pltpu.VMEM((hb, block_q, 1), F32),
+                            pltpu.VMEM((hb, block_q, 1), F32),
+                            pltpu.VMEM((hb, block_q, dv), F32)]),
         out_shape=jax.ShapeDtypeStruct((g, rp, dv), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), F32),
-                        pltpu.VMEM((block_q, 1), F32),
-                        pltpu.VMEM((block_q, dv), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=32 * 1024 * 1024),
         name="attn_chunk_attention",
         interpret=interpret,
-    )(*args)
+    )(classes.reshape(-1), *args)
     return out[:, :r]
 
 

@@ -1568,8 +1568,8 @@ def test_chunk_kernel_without_a_shared_term_is_bit_for_bit_what_it_was(
         family_shape, group, dim, window):
     """A family without latent layers hands no shared part and one list of
     keys: the kernel then computes, bit for bit, what it computed before
-    it learned either (the kernel of PR 42 kept above), and traces to the
-    same program (no operand, no branch and no operation more)."""
+    it learned either or gave its key blocks a class (the kernel of PR 42
+    kept above), and its call has no shared operand and no second list."""
     import jax
 
     from evam_tpu.ops.pallas_attention import chunk_attention
@@ -1592,17 +1592,244 @@ def test_chunk_kernel_without_a_shared_term_is_bit_for_bit_what_it_was(
         was)).all()
     assert np.asarray(got, np.float32).any()
 
-    def kernel_of(fn):
-        """The kernel's own program, as traced."""
+    def kernel_call(fn):
+        """The kernel's call, as traced."""
         def find(jaxpr):
             for eqn in jaxpr.eqns:
                 if eqn.primitive.name == "pallas_call":
-                    return str(eqn.params["jaxpr"])
+                    return eqn
                 for sub in jax.core.jaxprs_in_params(eqn.params):
                     if (found := find(sub)) is not None:
                         return found
         return find(jax.make_jaxpr(fn)(q, k, v, b).jaxpr)
 
-    now = kernel_of(lambda *a: chunk_attention(*a, interpret=True, **kw))
-    assert now is not None and now == kernel_of(
-        lambda *a: _chunk_attention_of_pr_42(*a, **kw))
+    # no shared operand and no second list: the classes, the bounds, the
+    # queries, ONE list of keys and one of values, and nothing else
+    call = kernel_call(lambda *a: chunk_attention(*a, interpret=True, **kw))
+    rp = -(-t * group // 32) * 32
+    assert [tuple(x.aval.shape) for x in call.invars] == [
+        (rp // 32 * 2,), (rp, b.shape[1]), (g, rp, dim), (g, 256, dim),
+        (g, 256, dim)]
+    assert [tuple(x.aval.shape) for x in call.outvars] == [(g, rp, dim)]
+
+
+def _chunk_of(case):
+    """A small chunk's operands and bounds for the cases of the classes:
+    ``(q, k, v, bounds, q_shared, k_shared, b0, blocks, group)``, keys as
+    lists where the case has two."""
+    from evam_tpu.models.lm import common
+
+    rng = np.random.default_rng(11)
+    seg, n_prefix, n_cont, prefix, cont, window, group, two = {
+        # a latent family's full chunk: the held prefix, then the rest
+        "full": ([0] * 40 + [1] * 24, 256, 20, 256, 32, None, 1, True),
+        # the same chunk part-full: dead rows between and behind
+        "part": ([0] * 30 + [-1] * 2 + [1] * 20 + [-1] * 12, 256, 20, 256,
+                 32, None, 1, True),
+        # a window layer, two heads a group: no row's window reaches the
+        # prefix's first block, every row's cuts its second
+        "window": ([0] * 64, 256, 0, 256, 0, 40, 2, False),
+        # no segment continues: the continued rows' block, in the middle
+        # of the key axis, is seen by no row
+        "middle": ([0] * 40 + [1] * 24, 128, 0, 128, 128, None, 1, False),
+    }[case]
+    seg = np.asarray(seg, np.int32)
+    bounds, b0 = common.chunk_bounds(jnp.asarray(seg), n_prefix, n_cont,
+                                     prefix, cont, window)
+    bounds = jnp.repeat(bounds, group, axis=0)
+    g, d, p, s = 2, 32, 64, prefix + cont + len(seg)
+    q, qs, k, v, ks = (
+        jnp.asarray(rng.standard_normal(sh), jnp.bfloat16)
+        for sh in ((g, len(seg) * group, d), (g, len(seg) * group, p),
+                   (g, s, d), (g, s, d), (s, p)))
+    if two:
+        k, v = (tuple(jnp.split(a, [prefix], axis=1)) for a in (k, v))
+        ks = tuple(jnp.split(ks, [prefix], axis=0))
+    else:
+        qs = ks = None
+    return q, k, v, bounds, qs, ks, b0, seg, group
+
+
+@pytest.mark.parametrize("case,classes", [
+    ("full", [[1, 1, 0]]),
+    ("part", [[1, 1, 0]]),
+    ("window", [[2, 0, 0], [2, 0, 0]]),
+    ("middle", [[1, 2, 0]]),
+])
+def test_chunk_kernel_asks_its_rule_only_where_a_blocks_answer_differs(
+        monkeypatch, case, classes):
+    """Every (query block, key block) pair has a class (``block_classes``:
+    1 WHOLE, every live row sees every key and no mask is computed; 0
+    MIXED, the masked visit; 2 NONE, no row sees any, not visited). In the
+    interpreter, two heads a grid step:
+    the classes are what the chunk's segments say; the outputs are BIT FOR
+    BIT those of the same kernel with every pair MIXED (the masked visit
+    everywhere, what the kernel did before it had classes) for every live
+    row, a dead row comes out 0 though it ran through WHOLE blocks, and
+    both agree with the twin through XLA. Query blocks of 64 rows, key
+    blocks of 128."""
+    from evam_tpu.ops import pallas_attention as pa
+
+    q, k, v, bounds, qs, ks, b0, seg, group = _chunk_of(case)
+    list_rows = [x.shape[1] for x in pa._lists(k)]
+    kw = dict(scale=0.1, b0=b0, block_q=64, block_k=128)
+    handed = pa.block_classes(bounds, b0, list_rows, 64, 128)
+    assert np.asarray(handed).tolist() == classes
+    got = np.asarray(pa.chunk_attention(q, k, v, bounds, qs, ks,
+                                        interpret=True, **kw))
+    monkeypatch.setattr(pa, "block_classes",
+                        lambda *a, **kw: jnp.zeros_like(handed))
+    masked = np.asarray(pa.chunk_attention.__wrapped__(
+        q, k, v, bounds, qs, ks, interpret=True, **kw))
+    live = np.repeat(seg >= 0, group)
+    assert got.dtype == masked.dtype and (got == masked)[:, live].all()
+    assert not got[:, ~live].any() and got[:, live].any(axis=-1).all()
+    want = np.asarray(pa.chunk_attention_xla(q, k, v, bounds, qs, ks,
+                                             scale=0.1, b0=b0), np.float32)
+    assert np.abs(got.astype(np.float32) - want).max() < 0.02
+    if case in ("window", "middle"):
+        # one list and no shared term: PR 42's kernel, kept above
+        was = _chunk_attention_of_pr_42(q, k, v, bounds, **kw)
+        assert (got == np.asarray(was)).all()
+
+
+@pytest.mark.parametrize("preset,kind_name,seg,n_prefix,n_cont", [
+    ("deepseek_v2_tiny", "mla", [0] * 272 + [1] * 240, 2048, 0),
+    ("deepseek_v2_tiny", "mla", [0] * 100 + [-1] * 412, 2000, 300),
+    ("lfm2_moe_tiny", "attn", [0] * 32 + [1] * 272 + [2] * 208, 2048, 240),
+    ("lfm2_moe_tiny", "attn", [0] * 272 + [-1] * 240, 2048, 0),
+    ("laguna_tiny", "attn_full", [0] * 272 + [1] * 240, 2048, 0),
+    ("laguna_tiny", "attn_window", [0] * 272 + [1] * 240, 2048, 0),
+    ("laguna_tiny", "attn_window", [0] * 512, 1990, 300),
+])
+def test_the_engines_count_of_key_blocks_is_of_the_array_the_kernel_is_handed(
+        monkeypatch, preset, kind_name, seg, n_prefix, n_cont):
+    """``evam_generate_chunk_key_blocks``: the host counts a chunk's key
+    blocks by class from its segments (the family's ``chunk_key_blocks``,
+    numpy, a token's bounds once); the program computes the classes from
+    the bounds it hands the kernel (``block_classes`` in
+    ``chunk_attention``, every query row's). For the same chunk the two
+    are the same array: recorded here from the call that ``mla_prefill``
+    or ``attn_prefill`` makes as the family's chunk makes it, at the
+    deployment's rows (a prefix of 16 pages of 128, 3 continued pages, a
+    chunk of 512) and a tiny model's widths, with the kernel's path taken
+    (its twin stands in for the kernel, which the CPU cannot run) and the
+    prefix's length an argument, as a chunk's program has it."""
+    from evam_tpu.models.lm import attention
+
+    fam = family(PRESETS[preset]["model_type"])
+    cfg = fam.Config.from_dict(PRESETS[preset])
+    page_tokens, prefix_pages, cont_pages = 128, 16, 3
+    seg = np.asarray(seg, np.int32)
+    t = len(seg)
+    handed = []
+
+    def kernel(q, k, v, bounds, q_shared=None, k_shared=None, *, scale, b0):
+        handed.append(np.asarray(pallas_attention.block_classes(
+            bounds, b0, [x.shape[1] for x in pallas_attention._lists(k)])))
+        return jnp.zeros(
+            (*q.shape[:2], pallas_attention._lists(v)[0].shape[2]), q.dtype)
+
+    monkeypatch.setattr(pallas_attention, "chunk_attention", kernel)
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+
+    def zeros(*shape):
+        return jnp.zeros(shape, jnp.bfloat16)
+
+    traced = (jnp.asarray(n_prefix, jnp.int32), jnp.asarray(n_cont, jnp.int32))
+    if kind_name == "mla":
+        width = common.row_width(cfg.latent)
+        lp = {"kv_b": zeros(cfg.kv_rank, cfg.heads * (cfg.nope + cfg.v_dim)),
+              "o": zeros(cfg.heads * cfg.v_dim, cfg.hidden)}
+        mla.mla_prefill(
+            cfg, lp, zeros(t, cfg.heads, cfg.nope),
+            zeros(t, cfg.heads, cfg.rope), zeros(t, width), jnp.asarray(seg),
+            zeros(prefix_pages * page_tokens, width), traced[0],
+            zeros(cont_pages * page_tokens, width), traced[1])
+    else:
+        kind = {"attn": cfg, "attn_full": getattr(cfg, "full", None),
+                "attn_window": getattr(cfg, "windowed", None)}[kind_name]
+        seen, first = attention.window_pages(
+            kind.window, np.arange(1, 1 + prefix_pages, dtype=np.int32),
+            traced[0], page_tokens)
+        width = attention.kv_width(kind)
+        attention.attn_prefill(
+            kind, {"o": zeros(kind.heads * kind.head_dim, kind.hidden)},
+            zeros(t, kind.heads, kind.head_dim), zeros(t, width),
+            jnp.asarray(seg), zeros(len(seen) * page_tokens, width),
+            traced[0], zeros(cont_pages * page_tokens, width), traced[1],
+            None, first)
+    counted = {name: (layers, classes) for name, layers, classes
+               in fam.chunk_key_blocks(cfg, seg, n_prefix, n_cont,
+                                       prefix_pages, cont_pages, page_tokens)}
+    (program,), (layers, host) = handed, counted[kind_name]
+    assert host.dtype == program.dtype and (host == program).all()
+    assert layers > 0 and sum(pallas_attention.count_classes(host)) == (
+        host.size)
+    # the kernel's own blocks cut these rows into several, not all MIXED
+    assert program.shape[1] > 2 and (program != 0).any()
+
+
+@pytest.mark.parametrize("preset,seg,n_cont,want", [
+    # describe_replay's full chunk: the prefix's two blocks of 1024 WHOLE,
+    # the 896 new rows' one MIXED, a layer
+    ("deepseek_v2_ep8", [0] * 272 + [1] * 240, 0, [("mla", (6, 12, 0))]),
+    # part-full: the dead rows behind do not demote the prefix's blocks
+    ("deepseek_v2_ep8", [0] * 272 + [-1] * 240, 0, [("mla", (6, 12, 0))]),
+    ("kimi_linear_ep4", [0] * 32 + [1] * 272 + [2] * 208, 240,
+     [("mla", (2, 4, 0))]),
+    # 2 query blocks of 1024 rows over 4 + 2 key blocks of 512
+    ("lfm2_moe_ep2", [0] * 272 + [1] * 240, 0, [("attn", (24, 48, 0))]),
+    # a full layer's last query block does not reach the continued rows'
+    # block; a window layer's every block is asked
+    ("laguna_xs2_pp8", [0] * 272 + [1] * 240, 0,
+     [("attn_full", (10, 24, 2)), ("attn_window", (36, 0, 0))]),
+    # Jamba's chunks do not run the kernel: nothing to count
+    ("jamba2_3b", [0] * 272 + [1] * 240, 0, []),
+])
+def test_a_chunk_counts_its_key_blocks_by_class(preset, seg, n_cont, want):
+    """``evam_generate_chunk_key_blocks{layers, class}``: per kind of layer
+    whose chunks run the chunk kernel ``(mixed, whole, not visited)``, the
+    (query block, key block) pairs of one key-value head's grid over the
+    kind's layers, at the deployment's sizes (a prefix of 2 048, a table
+    of 3 pages of 128, a chunk of 512). The dispatch alone, on an engine
+    that was never built."""
+    eng = object.__new__(GenerateEngine)
+    eng.sizes = sz = GenerateSizes()
+    eng._lm = family(PRESETS[preset]["model_type"])
+    eng.cfg = eng._lm.Config.from_dict(PRESETS[preset])
+    eng.prefix = np.zeros(2048, np.int32)
+    eng._prefix_pages, eng._private_pages = 16, 3
+    eng._window = getattr(eng.cfg, "window", None)
+    eng._prefill = eng._prefix_heads = None
+    eng._run = lambda *a, **kw: kw
+    assert len(seg) == sz.chunk_tokens and sz.page_tokens == 128
+    step = eng._dispatch_prefill_raw(
+        [1] * len(seg), seg, [0] * len(seg), [0] * len(seg), 2048,
+        [17, 18, 19] if n_cont else None, n_cont, [], [])
+    assert step["key_blocks"] == want
+
+
+def test_the_key_block_classes_are_on_metrics(engine):
+    """A generation's chunks count their key blocks by class, and the
+    series is rendered for ``/metrics``."""
+    from evam_tpu.obs import metrics
+
+    def counted():
+        return [metrics.get_counter("evam_generate_chunk_key_blocks",
+                                    {"layers": "mla", "class": cls})
+                for cls in pallas_attention.CLASSES]
+
+    _idle(engine)
+    before, chunks = counted(), metrics.get_counter(
+        "evam_generate_steps", {"kind": "prefill"})
+    _generate(engine, _prompt(61, 40))   # continues in a second chunk
+    _idle(engine)
+    chunks = metrics.get_counter("evam_generate_steps",
+                                 {"kind": "prefill"}) - chunks
+    grew = [b - a for a, b in zip(before, counted())]
+    # the tiny shapes are one query block over the prefix's one key block
+    # and the rest's one, in each of the three latent layers
+    assert chunks == 2 and sum(grew) == chunks * 2 * engine.cfg.layers
+    assert 'evam_generate_chunk_key_blocks_total{class="mixed",' in (
+        metrics.render())

@@ -188,6 +188,20 @@ def _own_pages_stay(text, cache, gathered, halves):
     assert not re.search(rf"= bf16\[{cache}\]\S* copy\(", text)
 
 
+def _chunk_kernel_operands(text):
+    """Per call of the chunk kernel in a program's text, its operands'
+    shapes in order: the classes of its key blocks (ops/pallas_attention.py
+    ``block_classes``, the one operand the classes brought), the bounds,
+    the queries, and per key list its keys and values (a latent family: a
+    shared part behind the queries and behind each list)."""
+    import re
+
+    return [re.findall(r"(\w+\[[\d,]*\])\{", re.search(
+        r"operand_layout_constraints=\{(.*?\})\}", line).group(1))
+        for line in text.splitlines()
+        if re.search(r"%attn_chunk_attention[.\d]* = ", line)]
+
+
 def _compile_step(lm, cfg, params, one_chip, program, traced_prefix=False):
     """A family's ``decode`` step at 64 rows or its ``prefill`` chunk of
     512 tokens at the deployment's sizes (401 pages of 128, 128 slots, a
@@ -280,6 +294,12 @@ def test_lfm2_step_programs_compile_at_published_widths(one_chip,
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
     assert len(calls) == 3 + int(program == "decode")
     assert "ragged" not in text
+    if program == "prefill":
+        # 2 query blocks of 1024 rows over 6 key blocks of 512: ONE list,
+        # padded to whole blocks, and no operand beyond the classes
+        assert _chunk_kernel_operands(text) == [[
+            "s32[12]", "s32[2048,4]", "bf16[8,2048,64]", "bf16[8,3072,64]",
+            "bf16[8,3072,64]"]]
     slot_state = "bf16[18,130,16,256]"
     moved = [line for line in text.splitlines()
              if re.search(rf"= {re.escape(slot_state)}\S* (scatter|copy)\(",
@@ -412,6 +432,14 @@ def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
         assert f"bf16[{cfg.heads},{2048 + 384 + 512},128]" not in text
         assert len(re.findall(r"%attn_chunk_attention[.\d]* = ", text)) == (
             n_latent)
+        # the held heads and the chunk's rows' as two lists, the rope part
+        # once a list, and no operand beyond the classes of 3 key blocks
+        h = cfg.heads
+        assert _chunk_kernel_operands(text) == [[
+            "s32[3]", "s32[512,4]", f"bf16[{h},512,128]", f"bf16[{h},512,128]",
+            f"bf16[{h},2048,128]", f"bf16[{h},2048,128]", "bf16[2048,128]",
+            f"bf16[{h},896,128]", f"bf16[{h},896,128]", "bf16[896,128]"]
+        ] * n_latent
         assert "mla_latent_attention" not in text
     else:
         assert not handed, handed[:2]
