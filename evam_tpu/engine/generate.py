@@ -317,6 +317,11 @@ class GenerateEngine:
         #: the positions a window layer sees (None: every layer of the
         #: family sees everything earlier)
         self._window = getattr(self.cfg, "window", None)
+        #: assignments a live token is ROUTED, summed over the expert
+        #: layers (held or not: ``top_k`` a layer; 0 for a family without
+        #: experts)
+        self._routed_per_token = (len(getattr(self.cfg, "moe_ids", ()))
+                                  * getattr(self.cfg, "top_k", 0))
         if sz.chunk_tokens % (self._align * sz.max_segments):
             raise ValueError(
                 f"a chunk of {sz.chunk_tokens} tokens is not "
@@ -383,6 +388,7 @@ class GenerateEngine:
         self._done = 0
         self._outstanding: dict[int, _Seq] = {}
         self._spans = trace.thread_spans(name, "generate")
+        trace.watch_engine(self)
         self._params = None
         self._state = None
         self._last_ids = None
@@ -1016,6 +1022,10 @@ class GenerateEngine:
                     labels)
         metrics.inc("evam_generate_prefix_restores", float(step.restores))
         metrics.inc("evam_moe_held_assignments", float(held))
+        # of which the sort's rows are: held over routed is the share of
+        # them that carry work on this chip
+        metrics.inc("evam_moe_routed_assignments",
+                    float(step.tokens * self._routed_per_token))
         # per expert layer, the held experts with at least one assignment
         metrics.inc("evam_moe_held_experts_hit", float(hit), labels)
         # and the (row tile, expert) pairs one grouped product visited

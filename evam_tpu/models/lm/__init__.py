@@ -37,12 +37,19 @@ under grouped queries, what differs as data of the layer KIND: ``jamba``
 one key-value head and no positions, ``lfm2_moe`` eight with head norms
 and rotary positions, ``laguna`` two kinds in one model, full layers of 48
 query heads under a rescaled partial rotation and window layers of 64
-under a plain one, both with a gate on every head's output) and
+under a plain one, both with a gate on every head's output, ``nemotron_h``
+32 query heads over 2 key-value heads and no positions) and
 ``experts.py`` (the expert layer told which experts it holds:
 ``deepseek_v2`` softmax scores and group-limited routing, ``kimi_linear``
 sigmoid scores with a selection bias, ``lfm2_moe`` the same with no shared
 expert and its expert layers' tensors in one stack, ``laguna`` as
-Kimi-Linear's in one stack with ALL 256 of a layer's experts held).
+Kimi-Linear's in one stack with ALL 256 of a layer's experts held,
+``nemotron_h`` 22 chosen of 512 with an eighth held, experts of two
+matrices under ``relu^2`` that work in a projected latent). A family whose
+blocks are each ONE sublayer (``nemotron_h``: a Mamba-2 mixer, an expert
+layer or attention by a pattern string) is a family like the others: what a
+block is made of is the family's own, the engine sees ``state_shapes`` and
+the two step programs.
 """
 
 from __future__ import annotations
@@ -51,7 +58,7 @@ import importlib
 
 FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba",
             "kimi_linear": "kimi_linear", "lfm2_moe": "lfm2_moe",
-            "laguna": "laguna"}
+            "laguna": "laguna", "nemotron_h": "nemotron_h"}
 
 
 def family(model_type: str):

@@ -95,6 +95,17 @@ def step_bias(key, shape, lo: float, hi: float):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def conv_silu(w, bias, taps):
+    """``silu(bias + sum_k w[k] * tap_k)`` of a short causal depthwise
+    convolution whose taps are given oldest first (``packed_conv_inputs``,
+    or a decode row's carried inputs): float32 sums, bfloat16 out."""
+    w = w.astype(F32)
+    acc = bias.astype(F32)
+    for k, tap in enumerate(taps):
+        acc = acc + w[k] * tap.astype(F32)
+    return jax.nn.silu(acc).astype(BF16)
+
+
 # ---------------------------------------------------------------- layers
 
 

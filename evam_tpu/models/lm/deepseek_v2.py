@@ -134,6 +134,8 @@ class Config:
 
     #: what models/lm/experts.py reads beside the fields
     score_func = "softmax"
+    expert_act = "swiglu"
+    moe_latent = None
     topk_eps = 1e-20
 
     @property
@@ -161,6 +163,11 @@ class Config:
     @property
     def moe_layers(self) -> int:
         return self.layers - self.first_dense
+
+    @property
+    def moe_ids(self) -> tuple:
+        """The model layers that have the expert layer, in order."""
+        return tuple(range(self.first_dense, self.layers))
 
 
 # ------------------------------------------------------------------ YaRN

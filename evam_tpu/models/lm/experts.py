@@ -8,7 +8,11 @@ models/lm/laguna.py: as Kimi-Linear's in one stack too, with ALL 256 small
 experts of a layer held, 8 a token: the held range is the whole range, a
 64-row step's 512 assignments a layer reach most of 256 groups with two or
 three rows each, and a 512-token chunk gives every expert some 16 rows, an
-eighth of a row tile).
+eighth of a row tile; models/lm/nemotron_h.py: sigmoid scores with a
+selection bias, 22 a token of 512 of which an eighth is held, experts of
+TWO matrices under ``relu^2`` that work in a LATENT of a quarter of the
+hidden width, and a shared expert of the same two-matrix form on the hidden
+itself).
 
 A router over ALL ``n_experts`` (scores in float32), the routed experts
 this chip HOLDS (ids ``[held_lo, held_lo + n_held)``, ``n_held`` the
@@ -25,7 +29,15 @@ What differs between the families is data of the config: ``score_func``
 limiting), ``top_k``, ``norm_topk`` (the chosen weights divided by their
 sum plus ``topk_eps``), ``scale_routed`` (times ``routed_scale``),
 ``held_lo``, ``n_shared`` (0: the layer has no ``shared_*`` tensors and
-no shared term); and of the layer: ``router_bias`` (added to the scores
+no shared term), ``expert_act`` (``swiglu``: an expert is ``W_down
+(silu(W_gate x) * W_up x)``, three matrices; ``relu2``: ``W_down max(W_up x,
+0)^2``, two, and the shared expert likewise) and ``moe_latent`` (None: the
+experts read and write the hidden; else a width: every token is projected
+ONCE to it before the sort (``latent_down``, shared by all experts), the
+experts work there, and the weighted sum of THIS chip's experts is projected
+back once (``latent_up``): the projection is linear, so the chips' shares add
+up behind it as they would before it; the router and the shared expert read
+the hidden); and of the layer: ``router_bias`` (added to the scores
 for the SELECTION only; the weights are the scores without it). A family
 whose expert layers run in one loop body hands ``moe`` every layer's
 tensors stacked on a leading axis and the layer as a traced index
@@ -38,21 +50,34 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from evam_tpu.models.lm.common import BF16, F32, on_tpu, swiglu
+from evam_tpu.models.lm.common import BF16, F32, mm, on_tpu, swiglu
 from evam_tpu.ops import pallas_grouped
 
 
 def tensor_shapes(cfg, bias: bool) -> dict[str, tuple]:
     """The layer's tensors; each ``expert_*`` is one expert's."""
     h, s = cfg.hidden, cfg.n_shared * cfg.moe_inter
+    gated = cfg.expert_act == "swiglu"
     out = {"router": (h, cfg.n_experts)}
     if bias:
         out["router_bias"] = (cfg.n_experts,)
+    w = h  # the width the experts work in
+    if cfg.moe_latent:
+        w = cfg.moe_latent
+        out.update(latent_down=(h, w), latent_up=(w, h))
+    if s and gated:
+        out["shared_gate"] = (h, s)
     if s:
-        out.update(shared_gate=(h, s), shared_up=(h, s), shared_down=(s, h))
-    out.update(expert_gate=(h, cfg.moe_inter), expert_up=(h, cfg.moe_inter),
-               expert_down=(cfg.moe_inter, h))
+        out.update(shared_up=(h, s), shared_down=(s, h))
+    if gated:
+        out["expert_gate"] = (w, cfg.moe_inter)
+    out.update(expert_up=(w, cfg.moe_inter), expert_down=(cfg.moe_inter, w))
     return out
+
+
+def relu2(x, up, down):
+    """``W_down max(W_up x, 0)^2``: a feed-forward of two matrices."""
+    return mm(jnp.square(jax.nn.relu(mm(x, up))), down)
 
 
 def route(cfg, x, router, bias=None):
@@ -92,7 +117,8 @@ def held_experts(cfg, lp: dict, x, w, ids, live, layer=None):
     held expert (those of other chips' experts, and of dead rows, last;
     padded to whole row tiles), and the sorted rows go through ONE
     grouped product a call (ops/pallas_grouped.py: gate and up with their
-    epilogue, then down), over all rows whatever the routing: a call
+    epilogue, or up alone with ``relu^2``, then down), over all rows whatever
+    the routing: a call
     costs the row tiles that hold assignments and reads an expert's
     matrix once a tile that holds some of its rows, so an expert no
     assignment reaches is never read and the rows past the last
@@ -106,7 +132,7 @@ def held_experts(cfg, lp: dict, x, w, ids, live, layer=None):
     expert's matrix is read). With ``layer`` the ``expert_*`` tensors
     are stacks [layers, held, ...] of which that layer's are read."""
     t, k = ids.shape
-    n_held = lp["expert_gate"].shape[-3]
+    n_held = lp["expert_down"].shape[-3]
     local = ids - cfg.held_lo
     mine = (local >= 0) & (local < n_held) & live[:, None]
     m = pallas_grouped.padded(t * k)
@@ -117,11 +143,15 @@ def held_experts(cfg, lp: dict, x, w, ids, live, layer=None):
         jnp.int32)
     n_mine = sizes.sum()
     rows = x[jnp.minimum(order // k, t - 1)]
-    swiglu_rows, product_rows = (
-        (pallas_grouped.swiglu, pallas_grouped.product) if on_tpu()
-        else (pallas_grouped.swiglu_xla, pallas_grouped.product_xla))
-    hmid = swiglu_rows(rows, lp["expert_gate"], lp["expert_up"], sizes,
-                       layer)
+    ops = pallas_grouped
+    swiglu_rows, relu2_rows, product_rows = (
+        (ops.swiglu, ops.relu2, ops.product) if on_tpu()
+        else (ops.swiglu_xla, ops.relu2_xla, ops.product_xla))
+    if cfg.expert_act == "swiglu":
+        hmid = swiglu_rows(rows, lp["expert_gate"], lp["expert_up"], sizes,
+                           layer)
+    else:
+        hmid = relu2_rows(rows, lp["expert_up"], sizes, layer)
     y = product_rows(hmid, lp["expert_down"], sizes, layer)
     # rows past the last group hold whatever the kernel left there
     y = jnp.where((jnp.arange(m) < n_mine)[:, None], y, 0)
@@ -140,14 +170,24 @@ def moe(cfg, lp: dict, x, live, layer=None):
     router, bias = lp["router"], lp.get("router_bias")
     if layer is not None:
         router, bias = router[layer], None if bias is None else bias[layer]
+    def of_layer(name):
+        return lp[name] if layer is None else lp[name][layer]
+
     with jax.named_scope("router"):
         w, ids = route(cfg, x, router, bias)
+    rows = x
+    if cfg.moe_latent:
+        with jax.named_scope("latent_down"):
+            rows = mm(x, of_layer("latent_down"))
     with jax.named_scope("experts"):
-        y, *counts = held_experts(cfg, lp, x, w, ids, live, layer)
+        y, *counts = held_experts(cfg, lp, rows, w, ids, live, layer)
+    if cfg.moe_latent:
+        with jax.named_scope("latent_up"):
+            y = mm(y, of_layer("latent_up"))
     if cfg.n_shared:
-        shared = (lp[f"shared_{name}"] for name in ("gate", "up", "down"))
-        if layer is not None:
-            shared = (w[layer] for w in shared)
+        gated = cfg.expert_act == "swiglu"
+        shared = (of_layer(f"shared_{name}") for name in (
+            ("gate", "up", "down") if gated else ("up", "down")))
         with jax.named_scope("shared"):
-            y = y + swiglu(x, *shared)
+            y = y + (swiglu if gated else relu2)(x, *shared)
     return y, jnp.stack(counts)

@@ -260,15 +260,6 @@ def _feed_forward(cfg: Config, lp: dict, x):
         return x + common.swiglu(h, lp["ff_gate"], lp["ff_up"], lp["ff_down"])
 
 
-def _conv(cfg: Config, lp: dict, taps):
-    """silu(bias + sum_k w[k] * tap_k), float32 sums, bfloat16 out."""
-    w = lp["conv_w"].astype(F32)
-    acc = lp["conv_b"].astype(F32)
-    for k, tap in enumerate(taps):
-        acc = acc + w[k] * tap.astype(F32)
-    return jax.nn.silu(acc).astype(BF16)
-
-
 def _dt_b_c(cfg: Config, lp: dict, u):
     """From the convolved input: the float32 step size [T, d_inner] and
     the normed ``B``, ``C`` [T, d_state]."""
@@ -292,7 +283,7 @@ def mamba_prefill(cfg: Config, lp: dict, x, seg, conv0, h0):
     uz = _mm(rms_norm(x, lp["in_norm"], cfg.eps), lp["in_proj"])
     u_pre, z = uz[:, :c], uz[:, c:]
     taps, conv_end = common.packed_conv_inputs(u_pre, seg, conv0, k1)
-    u = _conv(cfg, lp, taps + [u_pre])
+    u = common.conv_silu(lp["conv_w"], lp["conv_b"], taps + [u_pre])
     dt, b, cc = _dt_b_c(cfg, lp, u)
     a = -jnp.exp(lp["A_log"].astype(F32))
     scan = (pallas_selective_scan.selective_scan if common.on_tpu()
@@ -322,7 +313,7 @@ def mamba_decode(cfg: Config, lp: dict, l, x, slot, live, conv_all, ssm):
     conv_old = conv_all[l, slot].reshape(x.shape[0], -1)
     taps = [conv_old[:, k * c:(k + 1) * c] for k in range(cfg.d_conv - 1)]
     conv_new = jnp.concatenate([conv_old[:, c:], u_pre], axis=1)
-    u = _conv(cfg, lp, taps + [u_pre])
+    u = common.conv_silu(lp["conv_w"], lp["conv_b"], taps + [u_pre])
     dt, b, cc = _dt_b_c(cfg, lp, u)
     a = -jnp.exp(lp["A_log"].astype(F32))
     rows = (pallas_selective_scan.decode_rows if common.on_tpu()

@@ -109,6 +109,8 @@ class Config:
     #: what models/lm/mla.py and models/lm/experts.py read beside the fields
     q_rank = 0
     score_func = "sigmoid"
+    expert_act = "swiglu"
+    moe_latent = None
     scale_routed = True
     topk_eps = 1e-20
     n_group = 1

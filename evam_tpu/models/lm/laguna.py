@@ -109,6 +109,8 @@ class Config:
 
     #: what models/lm/experts.py reads beside the fields
     score_func = "sigmoid"
+    expert_act = "swiglu"
+    moe_latent = None
     norm_topk = True
     scale_routed = True
     n_group = 1

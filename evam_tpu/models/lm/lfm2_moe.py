@@ -95,6 +95,8 @@ class Config:
     window = None
     #: what models/lm/experts.py reads beside the fields
     score_func = "sigmoid"
+    expert_act = "swiglu"
+    moe_latent = None
     scale_routed = True
     n_group = 1
     topk_group = 1

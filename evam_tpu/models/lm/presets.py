@@ -58,12 +58,32 @@ window of 8; half of a head rotated under a YaRN table whose ramp lies
 inside it on the full layers; 8 experts, all held, 2 a token, one shared)
 at a size a CPU test runs.
 
+``nemotron3_super_ep8`` is NVIDIA-Nemotron-3-Super-120B-A12B's published
+config
+(https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16/blob/main/config.json)
+with every width as published, cut to what ONE chip of the eight that share
+each block holds of the FIRST of eight pipeline stages: blocks 0-10
+(``MEMEMEM*EME``: five Mamba-2 mixers, five latent expert layers, one
+attention block, the published 5 : 5 : 1), experts ``[held_lo, held_lo +
+experts_held)`` (64 of 512) of every expert layer, an eighth of the
+vocabulary, and the final norm and head so that the stage yields logits; the
+router keeps its 512 outputs and 22 a token. The multi-token-prediction head
+(``num_nextn_predict_layers``) is not held. ``attn_qk_init_scale`` widens
+the seeded ``q`` and ``k`` of the one attention block (as
+``mla_qk_init_scale``); ``mamba_a_init_max`` and ``mamba_dt_init_max`` are
+the upper ends of the seeded decays' and steps' draws (absent: Mamba-2's
+own, 16 and ``time_step_max``). ``nemotron_h_tiny`` has the same structure
+(``MEM*EME``; 4 Mamba-2 heads of 8 over a state of 16 in 2 groups; 4 query
+heads over 2 key-value heads; 16 experts, 2 held, 6 a token, a latent of 32)
+at a size a CPU test runs.
+
 Which module serves a preset is its ``model_type`` (models/lm
 ``FAMILIES``); the latent attention (models/lm/mla.py: ``deepseek_v2``,
 ``kimi_linear``), the plain attention (models/lm/attention.py: ``jamba``,
-``lfm2_moe``, ``laguna``) and the expert layer (models/lm/experts.py:
-``deepseek_v2``, ``kimi_linear``, ``lfm2_moe``, ``laguna``) are each shared
-and read what differs from these keys.
+``lfm2_moe``, ``laguna``, ``nemotron_h``) and the expert layer
+(models/lm/experts.py: ``deepseek_v2``, ``kimi_linear``, ``lfm2_moe``,
+``laguna``, ``nemotron_h``) are each shared and read what differs from
+these keys.
 """
 
 from __future__ import annotations
@@ -172,7 +192,76 @@ LAGUNA_XS2_PUBLISHED = {
     "tie_word_embeddings": False, "vocab_size": 100352,
 }
 
+NEMOTRON3_SUPER_PUBLISHED = {
+    "attention_bias": False, "chunk_size": 128, "conv_kernel": 4,
+    "expand": 2, "head_dim": 128, "hidden_size": 4096,
+    "hybrid_override_pattern": (
+        "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+        "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+    "intermediate_size": 2688, "layer_norm_epsilon": 1e-05,
+    "mamba_head_dim": 64, "mamba_hidden_act": "silu",
+    "mamba_num_heads": 128, "mamba_proj_bias": False,
+    "max_position_embeddings": 262144, "mlp_bias": False,
+    "mlp_hidden_act": "relu2", "model_type": "nemotron_h",
+    "moe_intermediate_size": 2688, "moe_latent_size": 1024,
+    "moe_shared_expert_intermediate_size": 5376,
+    "moe_shared_expert_overlap": False,
+    "mtp_hybrid_override_pattern": "*E", "n_group": 1, "n_groups": 8,
+    "n_routed_experts": 512, "n_shared_experts": 1, "norm_eps": 1e-05,
+    "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_experts_per_tok": 22, "num_hidden_layers": 88,
+    "num_key_value_heads": 2, "num_logits_to_keep": 1,
+    "num_nextn_predict_layers": 1, "partial_rotary_factor": 1,
+    "rescale_prenorm_residual": True, "residual_in_fp32": False,
+    "rope_theta": 10000, "routed_scaling_factor": 5,
+    "sliding_window": None, "ssm_state_size": 128,
+    "tie_word_embeddings": False, "time_step_floor": 0.0001,
+    "time_step_max": 0.1, "time_step_min": 0.001, "topk_group": 1,
+    "use_bias": False, "use_conv_bias": True, "use_mamba_kernels": True,
+    "vocab_size": 131072,
+}
+
 PRESETS = {
+    "nemotron3_super_ep8": {
+        **NEMOTRON3_SUPER_PUBLISHED,
+        # the cut: the first stage's blocks, the chip's share of the
+        # experts and vocabulary
+        "num_hidden_layers": 11,
+        "experts_held": 64,
+        "held_lo": 0,
+        "vocab_held": 16384,
+        "weights_seed": 20260311,
+        "initializer_range": 0.02,
+        # assumed: the attention block's q and k drawn this much wider, for
+        # a softmax as peaked as a trained one
+        "attn_qk_init_scale": 1.5,
+        # assumed: the heads' decays and steps drawn from the LOW end of
+        # Mamba-2's initialisation (A in [1, 16], steps in [time_step_min,
+        # time_step_max]), for a state that remembers across a prompt as a
+        # trained one's: at the whole ranges it forgets in ~25 tokens and a
+        # state kept in bfloat16 reads as the float32 one
+        "mamba_a_init_max": 1.5,
+        "mamba_dt_init_max": 0.002,
+    },
+    "nemotron_h_tiny": {
+        **NEMOTRON3_SUPER_PUBLISHED,
+        "hidden_size": 64, "hybrid_override_pattern": "MEM*EME",
+        "num_hidden_layers": 7,
+        "mamba_num_heads": 4, "mamba_head_dim": 8, "ssm_state_size": 16,
+        "n_groups": 2, "head_dim": 16, "num_attention_heads": 4,
+        "num_key_value_heads": 2,
+        "moe_intermediate_size": 48, "intermediate_size": 48,
+        "moe_latent_size": 32, "moe_shared_expert_intermediate_size": 96,
+        "n_routed_experts": 16, "num_experts_per_tok": 6,
+        "vocab_size": 512,
+        "experts_held": 2,
+        "held_lo": 0,
+        "vocab_held": 128,
+        "weights_seed": 23,
+        # as deepseek_v2_tiny: 0.02 at width 64 leaves every score flat
+        "initializer_range": 0.15,
+        "attn_qk_init_scale": 1.0,
+    },
     "laguna_xs2_pp8": {
         **LAGUNA_XS2_PUBLISHED,
         # the cut: the first pipeline stage's five layers, each whole

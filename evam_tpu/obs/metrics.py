@@ -102,6 +102,12 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_generate_slots_active": ("gauge", ()),
     "evam_generate_pages_in_use": ("gauge", ()),
     "evam_moe_held_assignments": ("counter", ()),
+    # tokens x top_k a step and expert layer, counted on the host: every
+    # assignment the router made, held here or not. Held over routed is
+    # the share of the expert layer's sorted rows that carry work on this
+    # chip (an eighth where an eighth of the experts is held and the
+    # routing is even, all of them where every expert is)
+    "evam_moe_routed_assignments": ("counter", ()),
     # per step and expert layer, the held experts that received at least
     # one assignment (the grouped products read only those experts'
     # weights): what a decode step's bytes follow

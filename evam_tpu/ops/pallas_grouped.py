@@ -25,11 +25,14 @@ whole lane tiles so that one block of a matrix is at most ``BLOCK_BYTES``,
 and is the grid's OUTER axis (a tile's output block may be revisited only
 by consecutive grid steps).
 
-Two entry points, one kernel body: ``swiglu`` (two matrices a group, the
+Three entry points, one kernel body: ``swiglu`` (two matrices a group, the
 epilogue ``silu(g) * u`` on the two products rounded to bfloat16, written
-once) and ``product`` (one matrix, rounded to bfloat16). Rows of no group
+once), ``relu2`` (one matrix, the epilogue ``max(t, 0)^2`` on the product
+rounded to bfloat16: an expert of two matrices and no gate) and ``product``
+(one matrix, rounded to bfloat16). Rows of no group
 come back as whatever the memory held (a tile no visit names is never
-written): the caller masks them. ``swiglu_xla`` and ``product_xla`` are the
+written): the caller masks them. ``swiglu_xla``, ``relu2_xla`` and
+``product_xla`` are the
 same mathematics through ``jax.lax.ragged_dot`` (rows of no group zero);
 the CPU tests run them, and check the kernel against them in the
 interpreter.
@@ -109,7 +112,7 @@ def n_visits(sizes, m: int):
     return visits(sizes, m)[4]
 
 
-def _kernel(tile: int, n_w: int):
+def _kernel(tile: int, n_w: int, squared: bool = False):
     from jax.experimental import pallas as pl
 
     def kernel(tile_of, group_of, starts, ends, layer, rows_ref, *refs):
@@ -126,6 +129,9 @@ def _kernel(tile: int, n_w: int):
             gate = y.astype(F32)
             act = (gate * jax.nn.sigmoid(gate)).astype(BF16).astype(F32)
             y = (act * acc[1].astype(F32)).astype(BF16)
+        elif squared:
+            up = jnp.maximum(y.astype(F32), 0.0)
+            y = (up * up).astype(BF16)
         row = tile_of[v] * tile + jax.lax.broadcasted_iota(
             jnp.int32, y.shape, 0)
         mine = (row >= starts[g]) & (row < ends[g])
@@ -134,7 +140,8 @@ def _kernel(tile: int, n_w: int):
     return kernel
 
 
-def _call(name: str, rows, ws, sizes, layer, interpret: bool):
+def _call(name: str, rows, ws, sizes, layer, interpret: bool,
+          squared: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -163,7 +170,7 @@ def _call(name: str, rows, ws, sizes, layer, interpret: bool):
         w_spec = pl.BlockSpec((None, k, cols),
                               lambda j, v, t, g, *_: (g[v], 0, j))
     return pl.pallas_call(
-        _kernel(tile, len(ws)),
+        _kernel(tile, len(ws), squared),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n // cols, count),
@@ -194,6 +201,16 @@ def swiglu(rows, gate, up, sizes, layer=None, *, interpret=False):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+def relu2(rows, up, sizes, layer=None, *, interpret=False):
+    """``max(rows @ up[g], 0)^2`` for the rows of each group ``g``, the
+    product rounded to bfloat16 before the epilogue: ``rows`` [m, k], ``up``
+    [groups, k, n] (or [layers, groups, k, n] with ``layer``) -> [m, n]
+    bfloat16."""
+    return _call("expert_up", rows, (up,), sizes, layer, interpret,
+                 squared=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def product(rows, w, sizes, layer=None, *, interpret=False):
     """``rows @ w[g]`` for the rows of each group ``g``: ``rows`` [m, k],
     ``w`` [groups, k, n] (or [layers, groups, k, n] with ``layer``) -> [m,
@@ -209,6 +226,10 @@ def product_xla(rows, w, sizes, layer=None):
         w = w[layer]
     return jax.lax.ragged_dot(rows.astype(BF16), w, sizes.astype(jnp.int32),
                               preferred_element_type=F32).astype(BF16)
+
+
+def relu2_xla(rows, up, sizes, layer=None):
+    return jnp.square(jax.nn.relu(product_xla(rows, up, sizes, layer)))
 
 
 def swiglu_xla(rows, gate, up, sizes, layer=None):

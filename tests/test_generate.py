@@ -544,6 +544,18 @@ def test_compiled_programs_constant_after_warmup(engine):
     assert set(engine.stats.bucket_batches) <= set(SIZES.slot_buckets)
 
 
+def test_the_freeze_recorder_watches_the_generate_engine(engine):
+    """A stand-still of the engine's thread (its queue a second old while
+    the heartbeat wakes on time) leaves a ``stall`` dump with every
+    thread's stack, as a batch engine's does: the recorder reads the
+    engines that ``trace.watch_engine`` was handed."""
+    from evam_tpu.obs import trace
+
+    assert engine in trace._watched
+    assert engine.queue_age_s() == 0.0
+    assert set(engine.thread_states()) == {"generate"}
+
+
 def test_joining_and_leaving_leave_the_others_logits_unchanged(engine):
     prompt = _prompt(7, 12)
     alone = _generate(engine, prompt, n=10)
@@ -1833,3 +1845,25 @@ def test_the_key_block_classes_are_on_metrics(engine):
     assert chunks == 2 and sum(grew) == chunks * 2 * engine.cfg.layers
     assert 'evam_generate_chunk_key_blocks_total{class="mixed",' in (
         metrics.render())
+
+
+# ------------------------------------ what the shared modules compute
+
+
+@pytest.mark.parametrize("program,on_chip,want", [
+    ("decode", True, "29fba60f84d7b130"),
+    ("decode", False, "f176501333a5cc85"),
+    ("prefill", True, "e1547fc367187d35"),
+    ("prefill", False, "2c5a809c8c26e617")])
+def test_the_step_programs_compute_what_they_did(monkeypatch, program,
+                                                 on_chip, want):
+    """The guard of the modules this family shares with the others
+    (tests/_step_trace.py): its two step programs at the deployment's
+    sizes, traced for the chip (the Pallas kernels' bodies among the
+    operations) and for the host (their twins), digest to what they did
+    before the newest family came beside it. A PR that changes an
+    operation of THIS family's served path moves the digest, and says
+    so."""
+    from _step_trace import check
+
+    check("deepseek_v2_ep8", program, on_chip, monkeypatch, want)

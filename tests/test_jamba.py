@@ -631,3 +631,25 @@ def test_second_describe_pipeline_end_to_end_through_rest(eight_devices,
     assert row["items"] == 6 and row["compiled_programs"] == 5
     assert (row["state_slots"], row["state_slots_in_use"]) == (4, 0)
     assert row["pages_in_use"] == 2 and row["capacity_fps"] > 0
+
+
+# ------------------------------------ what the shared modules compute
+
+
+@pytest.mark.parametrize("program,on_chip,want", [
+    ("decode", True, "baeb5ec35611c3d0"),
+    ("decode", False, "205a22891147f132"),
+    ("prefill", True, "0fcdc45a698985e9"),
+    ("prefill", False, "cea5795356c8d049")])
+def test_the_step_programs_compute_what_they_did(monkeypatch, program,
+                                                 on_chip, want):
+    """The guard of the modules this family shares with the others
+    (tests/_step_trace.py): its two step programs at the deployment's
+    sizes, traced for the chip (the Pallas kernels' bodies among the
+    operations) and for the host (their twins), digest to what they did
+    before the newest family came beside it. A PR that changes an
+    operation of THIS family's served path moves the digest, and says
+    so."""
+    from _step_trace import check
+
+    check("jamba2_3b", program, on_chip, monkeypatch, want)

@@ -949,3 +949,25 @@ def test_third_describe_pipeline_end_to_end_through_rest(eight_devices,
     assert (row["state_slots"], row["state_slots_in_use"]) == (4, 0)
     assert row["pages_in_use"] == 2 and row["capacity_fps"] > 0
     assert row["state_bytes"] > 0
+
+
+# ------------------------------------ what the shared modules compute
+
+
+@pytest.mark.parametrize("program,on_chip,want", [
+    ("decode", True, "b188afb903b0abb9"),
+    ("decode", False, "a71ddef74500e5e9"),
+    ("prefill", True, "c443cafafef2d501"),
+    ("prefill", False, "046ee8314a8f1a57")])
+def test_the_step_programs_compute_what_they_did(monkeypatch, program,
+                                                 on_chip, want):
+    """The guard of the modules this family shares with the others
+    (tests/_step_trace.py): its two step programs at the deployment's
+    sizes, traced for the chip (the Pallas kernels' bodies among the
+    operations) and for the host (their twins), digest to what they did
+    before the newest family came beside it. A PR that changes an
+    operation of THIS family's served path moves the digest, and says
+    so."""
+    from _step_trace import check
+
+    check("kimi_linear_ep4", program, on_chip, monkeypatch, want)

@@ -891,15 +891,15 @@ def test_benchmark_config_holds_the_published_widths_and_the_preset():
     assert set(cfg["server_env"]) == {"EVAM_PRELOAD", "EVAM_MAX_BATCH",
                                       "EVAM_NATIVE"}
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["chips"], cell["traffic"]) == (
-        "describe_laguna_replay", "laguna_xs2_pp8", 1, "replay_1080p_x32")
+    cell = next(w for w in bench["workloads"]
+                if w["name"] == "describe_laguna_replay")
+    assert (cell["config"], cell["chips"], cell["traffic"]) == (
+        "laguna_xs2_pp8", 1, "replay_1080p_x32")
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
-    assert entry["name"] == cell["config"]
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == cfg["reduced"] and len(entry["why"]) <= 200
     rate = next(m for m in bench["end_to_end"] if m["name"] == "frames_per_s")
-    assert rate["workloads"][-1] == "describe_laguna_replay"
+    assert "describe_laguna_replay" in rate["workloads"]
     mine = [m for m in bench["per_layer"]
             if "describe_laguna_replay" in m.get("workloads", [])]
     # the harness admits 128 per-layer metrics: 15 were left
@@ -1145,3 +1145,25 @@ def test_fifth_describe_pipeline_end_to_end_through_rest(eight_devices,
     assert row["items"] == 6 and row["compiled_programs"] == 5
     assert (row["state_slots_in_use"], row["state_bytes"]) == (0, 0)
     assert row["pages_in_use"] == 4 and row["capacity_fps"] > 0
+
+
+# ------------------------------------ what the shared modules compute
+
+
+@pytest.mark.parametrize("program,on_chip,want", [
+    ("decode", True, "6f333eba2ff6cb62"),
+    ("decode", False, "fbd2049b6b42458b"),
+    ("prefill", True, "a7ca02a97ebe7fda"),
+    ("prefill", False, "c979b374cca33766")])
+def test_the_step_programs_compute_what_they_did(monkeypatch, program,
+                                                 on_chip, want):
+    """The guard of the modules this family shares with the others
+    (tests/_step_trace.py): its two step programs at the deployment's
+    sizes, traced for the chip (the Pallas kernels' bodies among the
+    operations) and for the host (their twins), digest to what they did
+    before the newest family came beside it. A PR that changes an
+    operation of THIS family's served path moves the digest, and says
+    so."""
+    from _step_trace import check
+
+    check("laguna_xs2_pp8", program, on_chip, monkeypatch, want)

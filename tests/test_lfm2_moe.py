@@ -970,3 +970,25 @@ def test_fourth_describe_pipeline_end_to_end_through_rest(eight_devices,
     assert (row["state_slots"], row["state_slots_in_use"]) == (4, 0)
     assert row["pages_in_use"] == 2 and row["capacity_fps"] > 0
     assert row["state_bytes"] > 0
+
+
+# ------------------------------------ what the shared modules compute
+
+
+@pytest.mark.parametrize("program,on_chip,want", [
+    ("decode", True, "f3610f2f6088ee1d"),
+    ("decode", False, "d02a0d3da5bdbfaf"),
+    ("prefill", True, "a42afe6a7709bb15"),
+    ("prefill", False, "c274ed507b4adcaa")])
+def test_the_step_programs_compute_what_they_did(monkeypatch, program,
+                                                 on_chip, want):
+    """The guard of the modules this family shares with the others
+    (tests/_step_trace.py): its two step programs at the deployment's
+    sizes, traced for the chip (the Pallas kernels' bodies among the
+    operations) and for the host (their twins), digest to what they did
+    before the newest family came beside it. A PR that changes an
+    operation of THIS family's served path moves the digest, and says
+    so."""
+    from _step_trace import check
+
+    check("lfm2_moe_ep2", program, on_chip, monkeypatch, want)

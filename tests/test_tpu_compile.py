@@ -525,3 +525,125 @@ def test_decode_pages_kernel_compiles_at_published_widths(
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "attn_decode_pages" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+# ------------------------------------------------------- Nemotron-H's
+
+
+@pytest.mark.parametrize("tokens,segments", [
+    (512, 8),     # a chunk of the deployment
+    (128, 1),     # one block, one segment
+])
+def test_ssd_chunk_scan_kernel_compiles_at_published_widths(
+        one_chip, tokens, segments):
+    """Nemotron-3-Super's Mamba-2 recurrence in its chunkwise form: 128
+    heads of 64 over a state of 128 in 8 groups, a group's 8 pairs of heads
+    a grid step, the visits of a packed chunk its prefetched scalars; the
+    states of 8 segments (32 MB) go in and out and nothing of that size is
+    made beside them."""
+    from evam_tpu.ops import pallas_ssd
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    compiled = jax.jit(pallas_ssd.chunk_scan).lower(
+        s((tokens, 8192)), s((tokens, 128), f32), s((128,), f32),
+        s((tokens, 1024)), s((tokens, 1024)), s((tokens,), jnp.int32),
+        s((segments, 64, 128, 128), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssd_chunk_scan" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+
+
+@pytest.mark.parametrize("rows", [64, 128])   # the cell's step, the widest
+def test_ssd_decode_kernel_compiles_at_published_widths(one_chip, rows):
+    """A decode step's one token a row over the WHOLE slot state of the
+    deployment (5 layers x 130 rows x 4 MB = 2.7 GB), donated: the state
+    is aliased through the kernel and no copy of it, whole or a layer of
+    it, is made around it."""
+    import re
+
+    from evam_tpu.ops import pallas_ssd, slot_rows
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    tile = slot_rows.tiled(3 * 10240)
+    compiled = jax.jit(pallas_ssd.decode_rows, donate_argnums=(9, 10)).lower(
+        s((), jnp.int32), s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        s((rows, 128), f32), s((128,), f32), s((rows, 8192)),
+        s((rows, 1024)), s((rows, 1024)), s((rows, *tile)),
+        s((5, 130, 64, 128, 128), f32), s((5, 130, *tile))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssd_decode_rows" in text
+    assert not re.search(r"= f32\[(5,)?130,64,128,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("m", [11264, 1408])   # a chunk's 512 x 22, a step's
+def test_grouped_relu2_kernel_compiles_at_published_widths(one_chip, m):
+    """An expert of TWO matrices in the latent: up [64, 1024, 2688] under
+    ``relu^2`` in one call, down [64, 2688, 1024] in the next, out of the
+    five layers' stacks (1.76 GB each) by a prefetched layer id, over all
+    ``T x 22`` sorted assignments of which an eighth is held."""
+    from evam_tpu.ops import pallas_grouped as pg
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(rows, up, down, sizes, l):
+        return pg.product(pg.relu2(rows, up, sizes, l), down, sizes, l)
+
+    compiled = jax.jit(layer).lower(
+        s((m, 1024)), s((5, 64, 1024, 2688)), s((5, 64, 2688, 1024)),
+        s((64,), jnp.int32), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "expert_up" in text and "expert_down" in text
+    assert "expert_gate_up" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_nemotron_step_programs_compile_at_published_widths(one_chip,
+                                                            monkeypatch,
+                                                            program):
+    """Both step programs of the Nemotron-H family at the deployment's
+    sizes: the eleven blocks in one loop body of five trips (a Mamba-2
+    mixer, the attention block under a ``cond`` in one of them, an expert
+    layer), so each kernel is in its program ONCE; the 2.7 GB of slot state
+    pass the loop in place (no copy of the state, whole or a layer of it,
+    around ``ssd_decode_rows`` or the chunk's row writes), the 3.5 GB of
+    stacked expert tensors are read where they lie, and a decode row's own
+    pages (393 KB) go through XLA, as Jamba's."""
+    import re
+
+    from evam_tpu.models.lm import common, nemotron_h as lm
+    from evam_tpu.models.lm.presets import PRESETS
+
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+    cfg = lm.Config.from_dict(PRESETS["nemotron3_super_ep8"])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(lambda: lm.make_params(cfg)))
+    assert params["moe"]["expert_up"].shape == (5, 64, 1024, 2688)
+    compiled, state = _compile_step(lm, cfg, params, one_chip, program,
+                                    traced_prefix=True)
+    text = compiled.as_text()
+    for name, n in (("expert_up", 1), ("expert_down", 1),
+                    ("ssd_decode_rows", int(program == "decode")),
+                    ("ssd_chunk_scan", int(program == "prefill")),
+                    ("attn_chunk_attention", int(program == "prefill")),
+                    ("attn_decode_pages", 0), ("expert_gate_up", 0)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
+    assert "ragged" not in text
+    assert state["ssm"].shape == (5, 130, 64, 128, 128)
+    for shape in ("5,130,64,128,128", "130,64,128,128", "5,64,1024,2688",
+                  "64,1024,2688", "5,64,2688,1024", "64,2688,1024"):
+        assert not re.search(rf"= \w+\[{shape}\]\S* copy\(", text), shape
+    assert compiled.memory_analysis().temp_size_in_bytes < {
+        "decode": 32, "prefill": 256}[program] << 20
