@@ -35,7 +35,9 @@ Per layer, pre-norm residual, RMSNorm:
 
 Weights: every tensor is ``normal(key) * initializer_range`` in float32,
 ``key = fold_in(fold_in(fold_in(PRNGKey(seed), layer), crc32(name)),
-expert)``, stored bfloat16 (norm gains: 1 + that). Made on the device.
+expert)``, stored bfloat16 (norm gains: 1 + that). Made on the device;
+the latent mixer's up-projections are then laid as its products read
+them (``mla.store``, once a layer).
 """
 
 from __future__ import annotations
@@ -232,8 +234,9 @@ _make_experts = jax.jit(
 
 
 def make_layer(cfg: Config, layer: int, experts=None) -> dict:
-    """One layer's tensors; ``experts`` are the routed experts held
-    (default: the config's held group)."""
+    """One layer's tensors, the latent mixer's as ``mla.store`` lays them;
+    ``experts`` are the routed experts held (default: the config's held
+    group)."""
     if experts is None:
         experts = range(cfg.held_lo, cfg.held_lo + cfg.per_group)
     ids = jnp.asarray(list(experts), jnp.uint32)
@@ -245,7 +248,7 @@ def make_layer(cfg: Config, layer: int, experts=None) -> dict:
         else:
             out[name] = _make_one(key, shape, cfg.init_range,
                                   name.endswith("norm"))
-    return out
+    return mla.store(cfg, out)
 
 
 def make_params(cfg: Config) -> dict:

@@ -263,7 +263,8 @@ def ffn_shapes(cfg: Config, layer: int) -> dict[str, tuple]:
 
 def make_params(cfg: Config, held=None) -> dict:
     """``kda``: every KDA mixer's tensors with its two norms, stacked;
-    ``mla``: a list, one dict an MLA layer; ``ffn``: a list, one dict a
+    ``mla``: a list, one dict an MLA layer, its up-projections as
+    ``mla.store`` lays them; ``ffn``: a list, one dict a
     model layer (dense or experts). ``held``: the routed experts held
     (default: the config's range)."""
     if held is None:
@@ -274,8 +275,8 @@ def make_params(cfg: Config, held=None) -> dict:
     kda = [make_layer(cfg, i, kda_shapes(cfg)) for i in cfg.kda_ids]
     params["kda"] = {name: jnp.stack([lp[name] for lp in kda])
                      for name in kda[0]}
-    params["mla"] = [make_layer(cfg, i, mla_shapes(cfg), wider=("q", "kv_a"))
-                     for i in cfg.mla_ids]
+    params["mla"] = [mla.store(cfg, make_layer(
+        cfg, i, mla_shapes(cfg), wider=("q", "kv_a"))) for i in cfg.mla_ids]
     params["ffn"] = [make_layer(cfg, i, ffn_shapes(cfg, i), held)
                      for i in range(cfg.layers)]
     return params

@@ -104,6 +104,17 @@ def _ref_logits(prefix, prompt, result, **kw):
 # ------------------------------------------------------------ the model
 
 
+def _laid(cfg, **made):
+    """A latent mixer's tensors as the loader lays them (``mla.store``),
+    from arrays ``made`` in their PUBLISHED names and shapes
+    (``mla.tensor_shapes``); zeros for those a test has no use for."""
+    shapes = mla.tensor_shapes(cfg)
+    full = {name: jnp.zeros(shapes[name], jnp.bfloat16)
+            for name in mla.LAID if name in shapes}
+    return mla.store(cfg, {**full, **{
+        k: jnp.asarray(v).astype(jnp.bfloat16) for k, v in made.items()}})
+
+
 def test_yarn_frequencies_against_closed_form():
     cfg = lm.Config.from_dict(DEEPSEEK_V2_PUBLISHED | {
         "vocab_held": 8, "held_group": 0, "weights_seed": 0,
@@ -267,14 +278,58 @@ def test_weights_are_the_same_tensors_in_program_and_reference():
     cfg = lm.Config.from_dict(TINY)
     lp = lm.make_layer(cfg, 2)
     w = ref.layer_weights(TINY, 2)
-    for name in ("q_b", "kv_a_norm", "router", "shared_down"):
+    for name in ("kv_a_norm", "router", "shared_down"):
         np.testing.assert_array_equal(np.asarray(lp[name], np.float32),
                                       np.asarray(w[name]))
+    # the latent mixer's up-projections: the reference's values, laid
+    laid = _laid(cfg, **{name: w[name] for name in ("q_b", "kv_b", "o")})
+    for name in ("w_qn", "w_qr", "w_uk", "w_uv", "o"):
+        np.testing.assert_array_equal(np.asarray(lp[name], np.float32),
+                                      np.asarray(laid[name], np.float32))
     e = cfg.held_lo + 3
     np.testing.assert_array_equal(
         np.asarray(lp["expert_up"][3], np.float32),
         np.asarray(ref.tensor(TINY, 2, "expert_up",
                               (cfg.hidden, cfg.moe_inter), e)))
+
+
+@pytest.mark.parametrize("preset", ["deepseek_v2_ep8", "kimi_linear_ep4",
+                                    "deepseek_v2_tiny", "kimi_linear_tiny"])
+def test_the_stored_up_projections_are_the_published_ones_turned_and_split(
+        preset):
+    """``mla.store``: what the step programs hold of ``kv_b``, of the
+    query's projection and of ``o`` is the tensor made under its published
+    name, key and shape, per head with the contraction last and split into
+    its two parts, value for value; the published arrays are not kept."""
+    cfg = family(PRESETS[preset]["model_type"]).Config.from_dict(
+        PRESETS[preset])
+    shapes = mla.tensor_shapes(cfg)
+    made = {name: common.make_one(common.tensor_key(cfg.seed, 1, name),
+                                  shapes[name], cfg.init_range, False)
+            for name in mla.LAID if name in shapes}
+    gain = jnp.ones((cfg.kv_rank,), jnp.bfloat16)
+    lp = mla.store(cfg, {**made, "kv_a_norm": gain})
+    assert set(lp) == {"w_qn", "w_qr", "w_uk", "w_uv", "o", "kv_a_norm"}
+    assert lp["kv_a_norm"] is gain
+    assert sum(a.size for a in lp.values()) - gain.size == sum(
+        a.size for a in made.values())
+    hd, nope = cfg.heads, cfg.nope
+    kv = np.asarray(made["kv_b"]).reshape(cfg.kv_rank, hd, nope + cfg.v_dim)
+    assert lp["w_uk"].shape == (hd, nope, cfg.kv_rank)
+    assert lp["w_uv"].shape == (hd, cfg.v_dim, cfg.kv_rank)
+    assert (np.asarray(lp["w_uk"]) == kv[..., :nope].transpose(1, 2, 0)).all()
+    assert (np.asarray(lp["w_uv"]) == kv[..., nope:].transpose(1, 2, 0)).all()
+    q = np.asarray(made["q_b" if cfg.q_rank else "q"])
+    q = q.reshape(q.shape[0], hd, nope + cfg.rope)
+    assert (np.asarray(lp["w_qn"]) == q[..., :nope].transpose(1, 2, 0)).all()
+    # the rope part: the smaller of its two leading axes outermost
+    turned = (2, 1, 0) if cfg.rope < hd else (1, 2, 0)
+    assert (np.asarray(lp["w_qr"]) == q[..., nope:].transpose(turned)).all()
+    assert lp["w_qr"].shape == {
+        "deepseek_v2_ep8": (64, 128, 1536), "kimi_linear_ep4": (32, 64, 2304),
+    }.get(preset, lp["w_qr"].shape)
+    assert (np.asarray(lp["o"]) == np.asarray(made["o"]).reshape(
+        hd, cfg.v_dim, cfg.hidden)).all()
 
 
 def test_parameter_count_matches_the_benchmarks_arithmetic():
@@ -419,7 +474,8 @@ def test_two_part_decode_attention_is_the_one_softmax(case):
     with materialised heads' arithmetic left absorbed."""
     prefix_pages, n_prefix, own = DECODE_CASES[case]
     cfg = lm.Config.from_dict(TINY)
-    lp = lm.make_layer(cfg, 1)
+    made = ref.layer_weights(TINY, 1)
+    lp = _laid(cfg, kv_b=made["kv_b"], o=made["o"])
     page, own_pages = 8, 3
     rng = np.random.default_rng(sorted(DECODE_CASES).index(case))
     b = len(own)
@@ -449,14 +505,16 @@ def test_two_part_decode_attention_is_the_one_softmax(case):
     # as decode_tokens hands them over
     pages = None if prefix_pages is None else np.asarray(prefix_pages)
     got = lm.mla_decode(
-        cfg, lp, q_nope, q_rope,
+        cfg, lp, q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2),
         common.layer_page_rows(cache[None], 0, jnp.asarray(table)),
         jnp.asarray(ctx_len),
         common.layer_page_rows(cache[None], 0, pages), n_prefix)
     assert got.shape == (b, cfg.hidden)
 
-    w = f32(lp["kv_b"]).reshape(cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
+    w = np.asarray(made["kv_b"], np.float64).reshape(
+        cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
     w_uk, w_uv = w[..., :cfg.nope], w[..., cfg.nope:]
+    w_o = np.asarray(made["o"], np.float64)
     want, own_only = [], []
     for i in range(b):
         q_lat = np.einsum("hd,chd->hc", f32(q_nope)[i], w_uk)
@@ -467,7 +525,7 @@ def test_two_part_decode_attention_is_the_one_softmax(case):
             pr = np.exp(s - s.max(axis=1, keepdims=True))
             pr /= pr.sum(axis=1, keepdims=True)
             o = np.einsum("hc,chv->hv", pr @ keys[:, :cfg.kv_rank], w_uv)
-            return o.reshape(-1) @ f32(lp["o"])
+            return o.reshape(-1) @ w_o
 
         mine = rows_of(table[i])[:ctx_len[i]]
         seen = (rows_of(pages)[:n_prefix] if pages is not None
@@ -1090,10 +1148,10 @@ def _heads_of_the_cache(eng):
     made = jax.jit(lambda params, state: lm.prefix_heads(
         cfg, params, state, pinned))(eng._params, eng._state)
     exact = []
-    for i, lp in enumerate(eng._params["layers"]):
+    for i in range(cfg.layers):
         rows = np.asarray(common.layer_page_rows(
             eng._state["pages"], i, pinned).astype(jnp.float32), np.float64)
-        w = np.asarray(lp["kv_b"].astype(jnp.float32), np.float64).reshape(
+        w = np.asarray(ref.layer_weights(TINY, i)["kv_b"], np.float64).reshape(
             cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
         both = np.einsum("sc,chd->hsd", rows[:, :cfg.kv_rank], w)
         exact.append((both[..., :cfg.nope], both[..., cfg.nope:]))
@@ -1225,21 +1283,20 @@ def _absorbed_prefill(cfg, lp, q_nope, q_rope, lat, seg, prefix, n_prefix,
     XLA: every (token, head) one query folded through ``W_uk`` over ONE
     list of stored rows, a row's value its ``c_kv``, the output through
     ``W_uv``. Held heads are of no use to it."""
-    t = lat.shape[0]
-    w_uk, w_uv = mla.kv_b(cfg, lp)
-    q = jnp.concatenate(mla.absorb_q(cfg, w_uk, q_nope, q_rope), axis=-1)
+    q = jnp.concatenate(mla.absorb_q(cfg, lp["w_uk"], q_nope, q_rope),
+                        axis=-1)
     keys = jnp.concatenate(
         [rows for rows in (prefix, cont, lat) if rows is not None], axis=0)
     bounds, b0 = common.chunk_bounds(
         seg, n_prefix, n_cont, 0 if prefix is None else prefix.shape[0],
         0 if cont is None else cont.shape[0])
     seen = pallas_attention._visible(
-        jnp.arange(keys.shape[0])[None, :], bounds, b0)[:, None, :]
+        jnp.arange(keys.shape[0])[None, :], bounds, b0)[None]
     o_lat = common.merge_softmax_sums(common.softmax_sums(
-        cfg.softmax_scale, "thc,sc->ths", "ths,sc->thc", q, keys,
+        cfg.softmax_scale, "htc,sc->hts", "hts,sc->htc", q, keys,
         keys[:, :cfg.kv_rank], seen), None).astype(jnp.bfloat16)
-    o = common.es("thc,hcv->thv", o_lat, w_uv).astype(jnp.bfloat16)
-    return common.mm(o.reshape(t, -1), lp["o"])
+    o = common.es("htc,hvc->htv", o_lat, lp["w_uv"]).astype(jnp.bfloat16)
+    return common.es("htv,hvo->to", o, lp["o"]).astype(jnp.bfloat16)
 
 
 def test_generations_are_token_for_token_what_the_absorbed_form_gave(
@@ -1308,10 +1365,11 @@ def test_prefill_over_materialised_heads_is_the_absorbed_arithmetic(
         return bf16(np.pad(rng.normal(size=(n, cfg.latent)),
                            ((0, 0), (0, width - cfg.latent))))
 
-    lp = {"kv_b": bf16(rng.normal(size=(c, h * (cfg.nope + cfg.v_dim)))
-                       * c ** -0.5),
-          "o": bf16(rng.normal(size=(h * cfg.v_dim, cfg.hidden))
-                    * (h * cfg.v_dim) ** -0.5)}
+    made = {"kv_b": bf16(rng.normal(size=(c, h * (cfg.nope + cfg.v_dim)))
+                         * c ** -0.5),
+            "o": bf16(rng.normal(size=(h * cfg.v_dim, cfg.hidden))
+                      * (h * cfg.v_dim) ** -0.5)}
+    lp = _laid(cfg, **made)
     seg = np.array([0] * 5 + [1] * 3 + [-1] * 2 + [2] * 4 + [-1] * 2)
     continued = case == "continued"
     t, n_cont = len(seg), 5 if continued else 0
@@ -1325,7 +1383,9 @@ def test_prefill_over_materialised_heads_is_the_absorbed_arithmetic(
         held = mla.expand(cfg, lp, prefix)
         assert [a.shape for a in held] == [
             a.shape for a in mla.prefix_heads_shapes(cfg, 16, 1)[0]]
-    args = (cfg, lp, q_nope, q_rope, lat, jnp.asarray(seg))
+    # heads-major, as ``mla.qkv`` writes the query
+    args = (cfg, lp, q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2),
+            lat, jnp.asarray(seg))
     got = f64(mla.mla_prefill(*args, prefix, n_prefix, cont, n_cont, held))
     if n_rows:
         # a caller that holds nothing gets the same: the prefix expanded
@@ -1345,7 +1405,7 @@ def test_prefill_over_materialised_heads_is_the_absorbed_arithmetic(
     was = f64(_absorbed_prefill(*args, prefix, n_prefix, cont, n_cont))
     np.testing.assert_allclose(got, was, atol=0.03, rtol=0)
 
-    w = f64(lp["kv_b"]).reshape(c, h, cfg.nope + cfg.v_dim)
+    w = f64(made["kv_b"]).reshape(c, h, cfg.nope + cfg.v_dim)
     w_uk, w_uv = w[..., :cfg.nope], w[..., cfg.nope:]
     keys = np.concatenate([f64(r)[:, :cfg.latent] for r in (
         prefix, cont, lat) if r is not None])
@@ -1364,7 +1424,7 @@ def test_prefill_over_materialised_heads_is_the_absorbed_arithmetic(
         pr = np.exp(sc - sc.max(axis=1, keepdims=True))
         pr /= pr.sum(axis=1, keepdims=True)
         o = np.einsum("hc,chv->hv", pr @ keys[seen][:, :c], w_uv)
-        want[i] = o.reshape(-1) @ f64(lp["o"])
+        want[i] = o.reshape(-1) @ f64(made["o"])
     assert np.abs(want).max() > 0.5
     # bfloat16 roundings of the expanded keys and values, the weights of
     # the softmax, the heads' outputs and the result
@@ -1751,11 +1811,9 @@ def test_the_engines_count_of_key_blocks_is_of_the_array_the_kernel_is_handed(
     traced = (jnp.asarray(n_prefix, jnp.int32), jnp.asarray(n_cont, jnp.int32))
     if kind_name == "mla":
         width = common.row_width(cfg.latent)
-        lp = {"kv_b": zeros(cfg.kv_rank, cfg.heads * (cfg.nope + cfg.v_dim)),
-              "o": zeros(cfg.heads * cfg.v_dim, cfg.hidden)}
         mla.mla_prefill(
-            cfg, lp, zeros(t, cfg.heads, cfg.nope),
-            zeros(t, cfg.heads, cfg.rope), zeros(t, width), jnp.asarray(seg),
+            cfg, _laid(cfg), zeros(cfg.heads, t, cfg.nope),
+            zeros(cfg.heads, t, cfg.rope), zeros(t, width), jnp.asarray(seg),
             zeros(prefix_pages * page_tokens, width), traced[0],
             zeros(cont_pages * page_tokens, width), traced[1])
     else:
@@ -1851,10 +1909,10 @@ def test_the_key_block_classes_are_on_metrics(engine):
 
 
 @pytest.mark.parametrize("program,on_chip,want", [
-    ("decode", True, "29fba60f84d7b130"),
-    ("decode", False, "f176501333a5cc85"),
-    ("prefill", True, "e1547fc367187d35"),
-    ("prefill", False, "2c5a809c8c26e617")])
+    ("decode", True, "68d83f321286dda0"),
+    ("decode", False, "2ef1443f3948d784"),
+    ("prefill", True, "687abf37d442df99"),
+    ("prefill", False, "5c03add241319fb1")])
 def test_the_step_programs_compute_what_they_did(monkeypatch, program,
                                                  on_chip, want):
     """The guard of the modules this family shares with the others

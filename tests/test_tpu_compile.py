@@ -371,6 +371,90 @@ def test_laguna_step_programs_compile_at_published_widths(one_chip,
         "decode": 16, "prefill": 400}[program] << 20
 
 
+#: (preset, program) -> what ``_latent_program`` compiled
+_LATENT_PROGRAMS: dict = {}
+
+
+def _latent_program(one_chip, monkeypatch, preset, program):
+    """A latent family's step program at the deployment's sizes, compiled
+    once for the tests below: ``(the family, its config, its parameters'
+    shapes, the compiled program, the state's shapes, its text)``."""
+    from evam_tpu.models.lm import common, family
+    from evam_tpu.models.lm.presets import PRESETS
+
+    if (preset, program) not in _LATENT_PROGRAMS:
+        monkeypatch.setattr(common, "TARGET_TPU", True)
+        lm = family(PRESETS[preset]["model_type"])
+        cfg = lm.Config.from_dict(PRESETS[preset])
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda: lm.make_params(cfg)))
+        compiled, state = _compile_step(lm, cfg, params, one_chip, program)
+        _LATENT_PROGRAMS[preset, program] = (lm, cfg, params, compiled,
+                                             state, compiled.as_text())
+    return _LATENT_PROGRAMS[preset, program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("preset", ["deepseek_v2_ep8", "kimi_linear_ep4"])
+def test_no_step_program_turns_a_latent_layers_weights(one_chip, monkeypatch,
+                                                       preset, program):
+    """The latent mixer's up-projections are held as their products read
+    them (``mla.store``: ``w_qn``, ``w_qr``, ``w_uk``, ``w_uv`` per head
+    with the contraction last, ``o`` [heads, v_dim, hidden]), so neither
+    step program of either family copies one: no ``copy`` whose operand or
+    ``op_name`` is such a parameter, none that writes an array of such a
+    tensor's size in any axis order (inside Kimi's loop a parameter is an
+    element of a tuple, its name gone), and none of the published shapes
+    either. While ``kv_b`` was held [kv_rank, heads * 256] DeepSeek's
+    decode program turned it twice a layer, ``bf16[512,32768]`` and
+    ``bf16[512,128,256]``, 805 MB read and written a step, and a chunk
+    once; a chunk also turned the query behind ``q_b``
+    (``bf16[512,24576]``), its two parts heads-major for the chunk kernel
+    (``bf16[512,128,64]``) and the kernel's output back to rows
+    (``bf16[512,128,128]``): the products write and read those heads-major
+    now."""
+    import re
+
+    from evam_tpu.models.lm import mla
+
+    _, cfg, params, _, _, text = _latent_program(one_chip, monkeypatch,
+                                                 preset, program)
+    layer = (params["layers"] if "layers" in params else params["mla"])[0]
+    held = {name: layer[name].shape
+            for name in ("w_qn", "w_qr", "w_uk", "w_uv", "o")}
+    # no published array kept beside them
+    assert not (set(mla.LAID) - {"o"}) & set(layer)
+    assert held["o"] == (cfg.heads, cfg.v_dim, cfg.hidden)
+    sizes = {tuple(sorted(shape)) for shape in held.values()}
+    q_out, kv_out = cfg.nope + cfg.rope, cfg.nope + cfg.v_dim
+    published = [(cfg.kv_rank, cfg.heads * kv_out),
+                 (cfg.kv_rank, cfg.heads, kv_out),
+                 (512, cfg.heads * q_out), (512, cfg.heads, cfg.rope),
+                 (512, cfg.heads, cfg.v_dim)]
+    copies = [line for line in text.splitlines()
+              if re.search(r"= \w+\[[\d,]+\]\S* copy\(", line)]
+    assert copies   # the pattern still finds this compiler's copies
+    for line in copies:
+        shape = tuple(map(int, re.search(
+            r"= \w+\[([\d,]+)\]", line).group(1).split(",")))
+        assert tuple(sorted(shape)) not in sizes, line[:200]
+        assert shape not in published, line[:200]
+        assert not re.search(
+            r"copy\(%params\w*_(w_qn|w_qr|w_uk|w_uv|o|q|q_b|kv_b)__",
+            line), line[:200]
+        name = re.search(r'op_name="([^"]*)"', line)
+        assert not (name and re.search(
+            r"\['(w_qn|w_qr|w_uk|w_uv|o|q|q_b|kv_b)\\?'\]", name.group(1))
+        ), line[:200]
+    if program == "prefill":
+        # the query's parts leave their products heads-major: nothing of
+        # the rows-major shapes is left under the mixer's scope at all
+        for shape in published[2:4]:
+            assert "bf16[%s]" % ",".join(map(str, shape)) not in text
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("preset", ["deepseek_v2_ep8", "kimi_linear_ep4"])
 def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
@@ -393,20 +477,8 @@ def test_latent_page_cache_stays_where_it_lies(one_chip, monkeypatch, preset,
     still copies the cache, same layout in and out (PERF.md section 7)."""
     import re
 
-    from evam_tpu.models.lm import common, family
-    from evam_tpu.models.lm.presets import PRESETS
-
-    monkeypatch.setattr(common, "TARGET_TPU", True)
-    lm = family(PRESETS[preset]["model_type"])
-    cfg = lm.Config.from_dict(PRESETS[preset])
-
-    def s(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
-                          jax.eval_shape(lambda: lm.make_params(cfg)))
-    compiled, state = _compile_step(lm, cfg, params, one_chip, program)
-    text = compiled.as_text()
+    lm, cfg, _, compiled, state, text = _latent_program(
+        one_chip, monkeypatch, preset, program)
     pages = state["pages"].shape
     assert pages[-1] == 640 and cfg.latent == 576
     cache = re.escape("bf16[%s]" % ",".join(map(str, pages)))
