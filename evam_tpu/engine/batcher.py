@@ -255,6 +255,8 @@ class BatchEngine:
         "_buckets_done": "_exec_lock",
         "_outstanding": "_exec_lock",
         "_next_batch_id": "_exec_lock",
+        "_on_device": "_exec_lock",
+        "_idle_since": "_exec_lock",
         "_aot_exec": "_exec_lock",
     }
 
@@ -481,7 +483,16 @@ class BatchEngine:
         #: each worker thread's own stretches (obs/trace.py); inert
         #: with EVAM_TRACE=off
         self._sp_dispatch = trace.thread_spans(name, "dispatch", cpu=True)
-        self._sp_launch = trace.thread_spans(name, "launch")
+        #: the engine's idle ledger (None with EVAM_TRACE=off): batches
+        #: launched and not yet read back, and the moment that count
+        #: reached zero, stamped by the completer and taken by the
+        #: launcher, which puts the stretch up to its launch down to
+        #: where the launched batch was meanwhile
+        self._idle = trace.idle_ledger(name)
+        self._on_device = 0
+        self._idle_since: float | None = None
+        self._sp_launch = trace.thread_spans(name, "launch",
+                                             ledger=self._idle)
         self._sp_complete = trace.thread_spans(name, "complete", cpu=True)
         trace.watch_engine(self)
         self._dispatcher = threading.Thread(
@@ -1113,6 +1124,7 @@ class BatchEngine:
         inside a launch (or blocked on a wedged backend RPC), the
         dispatcher keeps staging and uploading."""
         sp = self._sp_launch
+        idle = self._idle
         while True:
             # seconds kept, no annotation: while the launcher idles for
             # want of an upload, what gates the device is whatever the
@@ -1162,8 +1174,20 @@ class BatchEngine:
                                      status="error")
                 log.exception("engine %s step failed", self.name)
                 continue
+            since = None
+            if idle is not None:
+                with self._exec_lock:
+                    since, self._idle_since = self._idle_since, None
+                    self._on_device += 1
+                # the launch span's end, before the completer appends
+                _, t1, dur = clock.spans[-1]
             self._done.put((out, t0, bid, sealed))
             self._record_batch(sealed, unclocked)
+            if since is not None:
+                # launched onto a dry engine: the stretch up to the
+                # launch's end, by where this batch was meanwhile
+                # (spans the completer has appended since lie behind it)
+                idle.add(since, t1 + dur, items, clock)
         sp.to(None)
 
     def _drain_upload_q(self, exc: Exception) -> None:
@@ -1191,7 +1215,9 @@ class BatchEngine:
         sp = self._sp_dispatch
         while True:
             # from here until a batch is formed the dispatcher waits
-            # for items (the pick, then the class deadline's fill)
+            # for items: the class queues are empty (the pick), then it
+            # HOLDS items for the class deadline's fill; one state in
+            # the sums, two names in a capture
             sp.to("wait_items")
             if self._stop.is_set():
                 self._fail_queued(RuntimeError("engine stopped"))
@@ -1203,6 +1229,7 @@ class BatchEngine:
             cls = cq.pick(timeout=0.05)
             if cls is None:
                 continue
+            sp.to("wait_items.fill")
             items = cq.collect(cls, self.max_batch,
                                self._class_deadline_s[cls])
             # the batch-formation wait itself can age items past
@@ -1246,6 +1273,7 @@ class BatchEngine:
 
     def _completion_loop(self) -> None:
         sp = self._sp_complete
+        idle = self._idle
         while True:
             sp.to("wait_launch", annotate=False)  # as the launcher's
             entry = self._done.get()
@@ -1271,9 +1299,13 @@ class BatchEngine:
                 self._in_flight.release()
                 continue
             finally:
-                sp.to("resolve")
+                t_end = sp.to("resolve")
                 with self._exec_lock:
                     done = self._outstanding.pop(bid, None)
+                    if idle is not None:
+                        self._on_device -= 1
+                        if not self._on_device:
+                            self._idle_since = t_end
             self._in_flight.release()
             if done is not None:
                 # bucket compiled + round-tripped: plain watchdog

@@ -388,6 +388,8 @@ class GenerateEngine:
         self._done = 0
         self._outstanding: dict[int, _Seq] = {}
         self._spans = trace.thread_spans(name, "generate")
+        #: whether dispatches onto a dry device are counted (tracing on)
+        self._count_dry = trace.active() is not None
         trace.watch_engine(self)
         self._params = None
         self._state = None
@@ -699,6 +701,10 @@ class GenerateEngine:
                 return
         if self.warm_error is not None:
             return  # nothing to serve with: the supervisor sees it
+        if self._count_dry:
+            for kind in ("prefill", "decode"):  # none yet is a reading
+                metrics.inc("evam_generate_dry_dispatches", 0.0,
+                            {"kind": kind})
         try:
             while not self._stop.is_set():
                 self._take_cancels()
@@ -962,6 +968,13 @@ class GenerateEngine:
     def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
              takers, state_rows, restores=0, window_read=None,
              own_pages=(0, 0), key_blocks=()) -> _Step:
+        if (self._count_dry and self._inflight
+                and self._inflight[-1].held.is_ready()):
+            # the loop keeps one step in flight while it builds the
+            # next: that step has already ended, so the device ran dry
+            # before this dispatch (asked, not waited for)
+            metrics.inc("evam_generate_dry_dispatches", 1.0,
+                        {"kind": kind})
         t0 = time.perf_counter()
         self._step_started = t0
         cold = key not in self._seen

@@ -56,6 +56,11 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # first prefill chunk, and what the engine holds
     "evam_generate_step_seconds": ("histogram", ("kind",)),
     "evam_generate_steps": ("counter", ("kind",)),
+    # of the steps dispatched while the step before was still held in
+    # flight, those that found it already ended: the device ran dry
+    # before the dispatch (tracing on; counted at dispatch, the steps
+    # above at harvest)
+    "evam_generate_dry_dispatches": ("counter", ("kind",)),
     "evam_generate_tokens": ("counter", ("kind",)),
     # cache rows read, per layer THAT HAS a cache (every layer of
     # DeepSeek-V2, the attention layers of Jamba)
@@ -167,9 +172,19 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     "evam_runner_resumes": ("counter", ("by",)),
     # wall seconds of the dispatch/launch/complete threads by state
     # ("work", or the wait named by what it waits for) and CPU seconds
-    # of the dispatcher's and completer's work stretches
+    # of the dispatcher's and completer's work stretches; the streams'
+    # chain threads the same, summed over streams under engine
+    # "streams", thread "chain" (states "work" and "wait_result")
     "evam_engine_thread_seconds": ("counter", ("engine", "thread", "state")),
     "evam_engine_thread_cpu_seconds": ("counter", ("engine", "thread")),
+    # seconds in which a batch engine had no program on the device, by
+    # where the batch that ended the stretch was (obs/trace.py
+    # ``divide_idle``): "upstream" (not yet submitted), "queued" (in the
+    # class queue: the deadline's fill, or the dispatcher busy), "stage"
+    # (wait_staging, slot_write, seal), "upload" (h2d_issue,
+    # wait_launcher, wait_slot, h2d_wait), "launch" (bookkeep, launch);
+    # ``stage`` names the span. The five add up to the engine's idle
+    "evam_engine_idle_seconds": ("counter", ("engine", "where", "stage")),
 }
 
 

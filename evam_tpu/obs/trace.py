@@ -23,11 +23,16 @@ no-op when it is ``off``: ``active()`` memoizes to None, FrameContext
   holds the SAME clock object the dispatch path fills in, so a flight
   dump of a wedged batch shows the last stage it completed.
 * **Thread stretches**: ``ThreadSpans`` is one engine thread's state
-  machine (dispatch, launch, complete). Each stretch is a
-  ``jax.profiler.TraceAnnotation`` named ``evam.<thread>.<what>`` (a
-  wait is named by what it waits for), so a profiler capture shows the
-  host on the device's clock, and its seconds land in
+  machine (dispatch, launch, complete; a stream's chain thread, seconds
+  only). Each stretch is a ``jax.profiler.TraceAnnotation`` named
+  ``evam.<thread>.<what>`` (a wait is named by what it waits for, and
+  behind a dot by which of its cases it is), so a profiler capture
+  shows the host on the device's clock, and its seconds land in
   ``evam_engine_thread_seconds{engine,thread,state}``.
+* **The idle ledger**: ``IdleLedger`` is one batch engine's stretches
+  with no program on the device, each divided by the timeline of the
+  batch that ended it (``divide_idle``) into where that batch was:
+  ``evam_engine_idle_seconds{engine,where,stage}``.
 * **The freeze recorder**: ``FreezeRecorder`` is one daemon heartbeat.
   Every 250 ms it measures how late it woke; ``gc.callbacks`` stamp
   every collection. A wake >= 100 ms late lands in
@@ -167,20 +172,29 @@ class ThreadSpans:
     the thread was in and begins ``state``: while a profiler capture
     runs, an annotation ``evam.<thread>.<state>`` on the calling thread
     (no capture, no object); always, the ended stretch's wall seconds
-    under ``work`` or, for a state named ``wait_*``, under that name.
+    under ``work`` or, for a state named ``wait_*``, under that name up
+    to its first dot (``wait_items.fill`` is a stretch of its own in a
+    capture and ``wait_items`` in the sums).
     With ``cpu`` the thread's CPU seconds (``time.thread_time``) are
     kept too: a wait burns none, so they are the work stretches', and
     wall far above CPU inside work is time spent waiting for the GIL.
     The sums reach the registry about every ``FLUSH_S``, on the owning
-    thread: a stretch costs one stamp and one dict update, no lock."""
+    thread, and a ``ledger`` kept on that thread goes with them: a
+    stretch costs one stamp and one dict update, no lock. A thread
+    whose waits gate nothing (``annotates=False``: a consumer's, as a
+    stream's chain thread) keeps its seconds and annotates none."""
 
     __slots__ = ("_prefix", "_labels", "_ann", "_key", "_state", "_t",
-                 "_acc", "_flushed", "_cpu0")
+                 "_acc", "_flushed", "_cpu0", "_ledger", "_annotates")
 
     FLUSH_S = 0.25
 
-    def __init__(self, engine: str, thread: str, cpu: bool = False) -> None:
-        _profiler_hooks()
+    def __init__(self, engine: str, thread: str, cpu: bool = False,
+                 ledger: "IdleLedger | None" = None,
+                 annotates: bool = True) -> None:
+        if annotates:
+            _profiler_hooks()
+        self._annotates = annotates
         self._prefix = f"evam.{thread}."
         self._labels = {"engine": engine, "thread": thread}
         self._ann = None
@@ -193,6 +207,7 @@ class ThreadSpans:
         #: thread CPU seconds at the last flush (None: not kept); the
         #: first flush, on the owning thread, only sets it
         self._cpu0: float | None = -1.0 if cpu else None
+        self._ledger = ledger
 
     def to(self, state: str | None, now: float | None = None,
            annotate: bool = True) -> float:
@@ -208,11 +223,13 @@ class ThreadSpans:
         key = self._key
         if key is not None:
             self._acc[key] = self._acc.get(key, 0.0) + now - self._t
-        self._key = (state if state is None or state[:5] == "wait_"
+        self._key = (state if state is None
+                     else state.partition(".")[0] if state[:5] == "wait_"
                      else "work")
         self._state = state
         self._t = now
-        if annotate and state is not None and _profiling():
+        if (annotate and self._annotates and state is not None
+                and _profiling()):
             self._ann = _annotation(self._prefix + state)
         if now - self._flushed >= self.FLUSH_S or state is None:
             self._flush(now)
@@ -235,6 +252,8 @@ class ThreadSpans:
                 metrics.inc("evam_engine_thread_cpu_seconds",
                             cpu - self._cpu0, self._labels)
             self._cpu0 = cpu
+        if self._ledger is not None:
+            self._ledger.flush()
 
 
 class _NoSpans:
@@ -252,10 +271,91 @@ class _NoSpans:
 _NO_SPANS = _NoSpans()
 
 
-def thread_spans(engine: str, thread: str,
-                 cpu: bool = False) -> "ThreadSpans | _NoSpans":
-    return (ThreadSpans(engine, thread, cpu) if active() is not None
-            else _NO_SPANS)
+def thread_spans(engine: str, thread: str, cpu: bool = False,
+                 ledger: "IdleLedger | None" = None,
+                 annotates: bool = True) -> "ThreadSpans | _NoSpans":
+    return (ThreadSpans(engine, thread, cpu, ledger, annotates)
+            if active() is not None else _NO_SPANS)
+
+
+#: where a batch is during each span of its clock up to its launch: in
+#: the dispatcher's staging, on its way to the device, or in the launch
+_IDLE_WHERE = {"slot_write": "stage", "seal": "stage",
+               "h2d_issue": "upload", "wait_launcher": "upload",
+               "wait_slot": "upload", "h2d_wait": "upload",
+               "launch": "launch"}
+
+
+def divide_idle(since: float, until: float, items,
+                clock: StageClock) -> dict[tuple[str, str], float]:
+    """``[since, until)``, in which an engine had no program on the
+    device, by where the batch launched at ``until`` was, as ``(where,
+    stage) -> seconds``. Up to the earliest ``t_submit`` of ``items`` no
+    frame of the batch had reached the engine (``upstream``); up to the
+    moment the dispatcher took them (the head's submit plus the clock's
+    ``submit_wait``) they lay in the class queue, for the deadline's
+    fill or for the dispatcher (``queued``); then the clock's spans as
+    they lie, each clipped to the stretch: ``stage`` (the wait for a
+    free staging block before the first span, ``slot_write``, ``seal``),
+    ``upload`` (``h2d_issue`` to ``h2d_wait``), ``launch`` (the call,
+    and the launcher's own steps between the spans: ``bookkeep``). The
+    parts add up to the stretch."""
+    out: dict[tuple[str, str], float] = {}
+    at = since
+
+    def upto(t: float, where: str, stage: str) -> None:
+        nonlocal at
+        t = min(t, until)
+        if t > at:
+            out[where, stage] = out.get((where, stage), 0.0) + t - at
+            at = t
+
+    upto(min(it.t_submit for it in items), "upstream", "upstream")
+    upto(items[0].t_submit + clock["submit_wait"], "queued", "queued")
+    before = ("stage", "wait_staging")
+    for name, t0, dur in clock.spans:
+        upto(t0, *before)
+        upto(t0 + dur, _IDLE_WHERE.get(name, "launch"), name)
+        before = ("launch", "bookkeep")
+    upto(until, *before)
+    return out
+
+
+class IdleLedger:
+    """One batch engine's seconds with no program on the device by where
+    the batch that ended each stretch was (``divide_idle``), summed on
+    the launcher's thread and flushed with its ``ThreadSpans``. It is
+    the ENGINE's idle: two engines that share a chip each keep their
+    own, and the chip idles only where both do."""
+
+    __slots__ = ("_engine", "_acc")
+
+    def __init__(self, engine: str) -> None:
+        self._engine = engine
+        # each ``where`` is there from the start, at 0: an engine that
+        # never ran dry reads 0, a build without the ledger nothing
+        self._acc: dict[tuple[str, str], float] = {
+            ("upstream", "upstream"): 0.0, ("queued", "queued"): 0.0,
+            ("stage", "slot_write"): 0.0, ("upload", "h2d_issue"): 0.0,
+            ("launch", "launch"): 0.0}
+        self.flush()
+
+    def add(self, since: float, until: float, items,
+            clock: StageClock) -> None:
+        acc = self._acc
+        for key, sec in divide_idle(since, until, items, clock).items():
+            acc[key] = acc.get(key, 0.0) + sec
+
+    def flush(self) -> None:
+        for (where, stage), sec in self._acc.items():
+            metrics.inc("evam_engine_idle_seconds", sec,
+                        {"engine": self._engine, "where": where,
+                         "stage": stage})
+        self._acc.clear()
+
+
+def idle_ledger(engine: str) -> IdleLedger | None:
+    return IdleLedger(engine) if active() is not None else None
 
 
 def annotate(name: str):

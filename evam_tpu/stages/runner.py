@@ -233,8 +233,14 @@ class StreamRunner:
     def _chain(self) -> None:
         """The chain thread: resume whichever stage's head has its
         result, else take the next fed frame in, else sleep."""
+        # this thread's seconds asleep and at work, and its CPU: summed
+        # over the streams, work far above CPU is the streams' side of
+        # the GIL. No annotation: a consumer's wait gates nothing
+        sp = trace.thread_spans("streams", "chain", cpu=True,
+                                annotates=False)
         try:
             fed = False  # took a fed frame in since it last slept
+            sp.to("work")
             while True:
                 parked = self._ready_head()
                 if parked is not None:
@@ -271,15 +277,15 @@ class StreamRunner:
                     # with a frame's way back into the engine
                     with self._lock:
                         self._room.notify_all()
-                if self._in_chain and trace.active() is not None:
-                    with trace.annotate("evam.runner.wait_result"):
-                        self._wake.get()
-                else:
-                    self._wake.get()
+                sp.to("wait_result")
+                self._wake.get()
+                sp.to("work")
         except BaseException as exc:  # noqa: BLE001 — raised by feed/drain
             with self._lock:
                 self._fatal = exc
                 self._room.notify_all()
+        finally:
+            sp.to(None)
 
     def _ready_head(self) -> _Parked | None:
         for i, fifo in self._parked.items():

@@ -745,6 +745,58 @@ def test_capacity_model_and_counters(engine):
         assert series in text, series
 
 
+def test_a_dispatch_onto_a_dry_device_is_counted(engine):
+    """``evam_generate_dry_dispatches_total{kind}``: a step dispatched
+    while the loop still holds the step before in flight, which has
+    already ended. With every decode step waited for where it is issued,
+    each decode dispatch behind another finds the device dry; however the
+    steps fall, no more dispatches are dry than steps are counted."""
+    import jax
+
+    from evam_tpu.obs import metrics
+
+    def counted():
+        return {name: sum(metrics.get_counter(f"evam_generate_{name}",
+                                              {"kind": kind})
+                          for kind in ("prefill", "decode"))
+                for name in ("dry_dispatches", "steps")}
+
+    text = metrics.render()
+    for kind in ("prefill", "decode"):  # there from the loop's start, at 0
+        assert f'evam_generate_dry_dispatches_total{{kind="{kind}"}}' in text
+    inner = engine._decode
+
+    def waited_for(*args):
+        out = inner(*args)
+        jax.block_until_ready(out)
+        return out
+
+    _idle(engine)
+    before = counted()
+    engine._decode = waited_for
+    try:
+        _generate(engine, _prompt(6, 9))
+    finally:
+        engine._decode = inner
+    _idle(engine)
+    deadline = time.time() + 10
+    while (counted()["steps"] - before["steps"] < NEW
+           and time.time() < deadline):
+        time.sleep(0.05)
+    grew = {k: v - before[k] for k, v in counted().items()}
+    # one chunk and NEW - 1 decode steps; every decode step but the first
+    # was dispatched behind a decode step that had been waited for
+    assert grew["steps"] == NEW
+    assert NEW - 2 <= grew["dry_dispatches"] <= grew["steps"]
+    futs = [engine.submit(stream=f"d{i}", prompt_ids=_prompt(i, 5 + 4 * i),
+                          max_new_tokens=NEW) for i in range(6)]
+    for f in futs:
+        f.result(timeout=300)
+    _idle(engine)
+    total = counted()
+    assert total["dry_dispatches"] <= total["steps"]
+
+
 # ------------------------------------------------------ the comparator
 
 

@@ -177,6 +177,70 @@ def _two_stage(window: int = 6, **second):
     return runner, e1, e2, s1, s2, sink
 
 
+# the chain thread's own account -------------------------------------
+
+@pytest.mark.parametrize("tracing", ["on", "off"])
+def test_the_chain_threads_wait_is_counted_and_never_annotated(
+        monkeypatch, tracing):
+    """The chain thread keeps its seconds asleep (``wait_result``) and at
+    work, and its CPU, under ``thread="chain"``; even while a profiler
+    capture runs it annotates neither (a consumer's wait gates nothing).
+    With EVAM_TRACE=off it keeps nothing and reads no clock for it."""
+    from evam_tpu.config.settings import reset_settings
+    from evam_tpu.obs import trace
+
+    monkeypatch.setenv("EVAM_TRACE", tracing)
+    reset_settings()
+    trace.reset_cache()
+    names: list[str] = []
+
+    class _Ann:
+        def __init__(self, name: str) -> None:
+            names.append(name)
+
+        def __exit__(self, *exc) -> None:
+            pass
+
+    monkeypatch.setattr(trace, "_annotation", _Ann)
+    monkeypatch.setattr(trace, "_profiling", lambda: True)
+
+    def seconds() -> dict[str, float]:
+        labels = {"engine": "streams", "thread": "chain"}
+        out = {state: metrics.get_counter(
+            "evam_engine_thread_seconds", {**labels, "state": state})
+            for state in ("wait_result", "work")}
+        out["cpu"] = metrics.get_counter("evam_engine_thread_cpu_seconds",
+                                         labels)
+        return out
+
+    before = seconds()
+    if tracing == "off":
+        class _NoClock:
+            def __getattr__(self, name):
+                raise AssertionError(f"time.{name} read with EVAM_TRACE=off")
+
+        monkeypatch.setattr(trace, "time", _NoClock())
+    eng, sink = ManualEngine(), Sink()
+    runner = StreamRunner("cam", [FakeAsync("one", eng), sink])
+    t0 = time.perf_counter()
+    for seq in range(3):
+        runner.feed(_event(seq))
+        assert _wait(lambda: seq in eng.futures)
+        time.sleep(0.02)  # the frame is parked in the engine
+        eng.resolve(seq)
+    runner.drain()  # the thread ends, and flushes
+    wall = time.perf_counter() - t0
+    assert sink.seqs == [0, 1, 2]
+    grew = {k: v - before[k] for k, v in seconds().items()}
+    assert names == []
+    if tracing == "off":
+        assert grew == {"wait_result": 0.0, "work": 0.0, "cpu": 0.0}
+    else:
+        assert 0.06 <= grew["wait_result"] <= wall
+        assert 0.0 < grew["work"] < wall - grew["wait_result"] + 1e-6
+        assert 0.0 <= grew["cpu"] <= grew["work"] + 0.01
+
+
 # (a) ---------------------------------------------------------------
 
 def test_a_message_leaves_before_the_next_feed():

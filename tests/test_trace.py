@@ -607,6 +607,9 @@ def paced_run(eight_devices):
             SchedConfig.from_settings(settings.sched,
                                       standard_deadline_ms=4.0),
             capacity_fps=1000.0))
+    # every earlier engine of this process has stopped and flushed: the
+    # idle seconds from here on are this hub's engines'
+    start, t_start = metrics.render(), time.perf_counter()
     registry = PipelineRegistry(settings, hub=hub)
     n = 12
     try:
@@ -625,7 +628,10 @@ def paced_run(eight_devices):
              "destination": {"metadata": {"type": "null"}}})
         inst.wait(timeout=180)
         assert inst.state.value == "COMPLETED", inst.error
-        after = metrics.render()
+        # the launcher flushes its sums, the ledger's with them, on its
+        # next turn after FLUSH_S (it turns every 0.1 s while it waits)
+        time.sleep(trace.ThreadSpans.FLUSH_S + 0.2)
+        after, t_after = metrics.render(), time.perf_counter()
         frames, batches, _ = trace.active().snapshot()
         payload = trace.traces_payload()
     finally:
@@ -639,7 +645,8 @@ def paced_run(eight_devices):
     frames = [f for f in frames if f.stream_id == inst.id]
     assert len(frames) == n
     return {"n": n, "before": before, "after": after, "frames": frames,
-            "batches": batches, "payload": payload, "stream": inst.id}
+            "batches": batches, "payload": payload, "stream": inst.id,
+            "start": start, "wall_s": t_after - t_start}
 
 
 def _series(text: str, key: str) -> float:
@@ -711,6 +718,52 @@ def test_waits_between_layers_observe_once_per_frame(paced_run):
                    for l in after.splitlines()), state
     assert any(l.startswith("evam_engine_thread_cpu_seconds_total{")
                and 'thread="dispatch"' in l for l in after.splitlines())
+
+
+def _summed(text: str, series: str, **labels: str) -> float:
+    """Every line of ``series`` whose labels hold ``labels``, summed."""
+    return sum(float(l.split()[-1]) for l in text.splitlines()
+               if l.startswith(series + "{")
+               and all(f'{k}="{v}"' in l for k, v in labels.items()))
+
+
+@_time_limit(240)
+def test_a_served_runs_idle_is_divided_and_no_more_than_its_wall(paced_run):
+    """The engines' idle ledger over a served run: seconds under the five
+    ``where`` alone, more than none (a paced camera leaves the engine dry
+    between frames) and no more than the wall time the hub has lived."""
+    start, after = paced_run["start"], paced_run["after"]
+    series = "evam_engine_idle_seconds_total"
+    idle = _summed(after, series) - _summed(start, series)
+    assert 0.0 < idle <= paced_run["wall_s"], (idle, paced_run["wall_s"])
+    parts = {w: _summed(after, series, where=w) - _summed(start, series,
+                                                          where=w)
+             for w in ("upstream", "queued", "stage", "upload", "launch")}
+    assert sum(parts.values()) == pytest.approx(idle)
+    # one camera at 30 frames/s: the engine is dry for want of a frame
+    # most of the time, and every frame waits out the class deadline
+    assert parts["upstream"] > parts["stage"] + parts["upload"]
+    assert parts["queued"] > 0.0
+    assert not [l for l in after.splitlines() if l.startswith(series)
+                and 'stage="' not in l]
+
+
+@_time_limit(240)
+def test_the_chain_threads_account_for_their_seconds(paced_run):
+    """A stream's chain thread is a fourth ``ThreadSpans``: wall seconds
+    asleep and at work and its CPU, summed over the streams."""
+    start, after = paced_run["start"], paced_run["after"]
+    labels = {"engine": "streams", "thread": "chain"}
+    grew = {state: _summed(after, "evam_engine_thread_seconds_total",
+                           state=state, **labels)
+            - _summed(start, "evam_engine_thread_seconds_total",
+                      state=state, **labels)
+            for state in ("wait_result", "work")}
+    assert grew["wait_result"] > grew["work"] > 0.0, grew
+    assert sum(grew.values()) <= 2 * paced_run["wall_s"]  # two streams
+    cpu = (_summed(after, "evam_engine_thread_cpu_seconds_total", **labels)
+           - _summed(start, "evam_engine_thread_cpu_seconds_total", **labels))
+    assert 0.0 < cpu <= paced_run["wall_s"]
 
 
 @_time_limit(240)
