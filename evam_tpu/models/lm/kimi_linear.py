@@ -38,10 +38,21 @@ update float32. The KDA mixers are STACKED and ONE ``lax.scan`` runs over
 them, so that the kernel has one name in a device trace: a trip is a KDA
 mixer, its feed-forward and, where an MLA layer follows it, that layer with
 ITS feed-forward. The feed-forwards and the MLA layers are NOT stacked:
-each trip picks what follows its mixer by ONE ``lax.switch`` over branches
-that hold their weights as they are (a grouped product cannot read a slice of a stack of
-expert weights in place: the 0.9 GB of a layer's held experts would be
-copied every step).
+each trip picks what follows its mixer by a ``lax.switch`` over branches
+that hold their weights as they are (a grouped product can address a stack
+of expert weights by a prefetched layer id, ops/pallas_grouped.py; nobody
+has restacked this family's: ROADMAP.md Queue 3 item 13(b)). The page
+cache is an operand of those branches and never a result: a branch reads
+the pages it gathers and returns the MLA layer's new latent rows, and the
+loop body makes the cache's one write, under a two-branch ``lax.cond``
+that holds the write alone (``_layers``): returned by the ``switch``, the
+compiler copies the cache whole in the branches that hand it through (131
+MB, twice a decode step and three times a chunk); through that ``cond`` it
+passes in place, which tests/test_tpu_compile.py holds. A chunk attends to its
+new rows as values, so one ``lax.switch`` holds its trip; a decode row
+attends to its own new row through the cache, so its trip is ``switch``
+(feed-forward, ``qkv``), write, ``switch`` (attention and the MLA layer's
+feed-forward).
 
 Weights (``common.tensor_key``): ``normal * initializer_range``, gains ``1
 + that``; the convolutions uniform in +-d_conv^-1/2; ``A_log = log U(1,
@@ -59,6 +70,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -419,6 +431,13 @@ def kda_decode(cfg: Config, lp: dict, x, slot, live, conv_all, s_all, l=None):
     return (y, conv_all[0], s_all[0]) if one else (y, conv_all, s_all)
 
 
+def _mla_qkv(cfg: Config, lp: dict, x):
+    """Of an MLA layer, from the residual rows ``x``: the query's two
+    parts and the rows the cache holds (``mla.qkv``)."""
+    qn, qr, lat = mla.qkv(cfg, lp, rms_norm(x, lp["input_norm"], cfg.eps))
+    return (qn, qr), lat
+
+
 def head(cfg: Config, params: dict, x):
     return common.head(x, params["final_norm"], cfg.eps, params["head"])
 
@@ -426,14 +445,26 @@ def head(cfg: Config, params: dict, x):
 # ----------------------------------------------------------- step bodies
 
 
-def _layers(cfg: Config, params: dict, x, state, live, kda_layer, mla_layer):
+def _layers(cfg: Config, params: dict, x, state, live, dest, kda_layer,
+            attend, through_cache: bool):
     """Every layer in its order, as ONE ``lax.scan`` over the stacked KDA
     mixers: ``kda_layer(lp, l, x, kda, conv)`` with ``l`` the layer's row
-    of the slot state, then that layer's feed-forward, then, where an MLA
-    layer follows, ``mla_layer(lp, j, x, pages)`` (pages at index ``j``)
-    and its feed-forward. Returns ``x``, the state and the expert
-    layers' counts (``experts.moe``) summed over the layers."""
+    of the slot state, then that layer's feed-forward, then, where MLA
+    layer ``j`` follows, its rows' ``mla.qkv``, ``attend(lp, j, qn, qr, lat,
+    pages)`` (the mixer's output, before ``x`` is added) and its
+    feed-forward. The branches that hold the layers read the page cache
+    and never return it: they return the rows' new latents ``lat``
+    (zeros where the trip has no MLA layer), which the loop body writes
+    to ``pages[j, *dest]`` (``dest``: the rows' ``(dest_page,
+    dest_off)``) under a ``lax.cond`` of its own that holds nothing else.
+    ``through_cache``: the rows attend to their own new latents in the
+    cache, so the write stands between ``qkv`` and ``attend`` (handed
+    ``lat`` None), which a second ``lax.switch`` then holds; else
+    ``attend`` reads ``lat`` itself and one ``lax.switch`` holds the
+    whole trip. Returns ``x``, the state and the expert layers' counts
+    (``experts.moe``) summed over the layers."""
     none = jnp.zeros((3,), jnp.int32)
+    dest_page, dest_off = dest
 
     def ffn(layer: int, post_norm, x):
         """The feed-forward of model layer ``layer``, residual added."""
@@ -447,34 +478,58 @@ def _layers(cfg: Config, params: dict, x, state, live, kda_layer, mla_layer):
                                      w["mlp_down"]), none
         return x + y, n
 
+    def mixed(j: int, x, q, pages, lat=None):
+        """MLA layer ``j`` from its query on, and its feed-forward."""
+        lp = params["mla"][j]
+        with jax.named_scope("mla"):
+            x = x + attend(lp, j, *q, lat, pages)
+        return ffn(cfg.mla_ids[j], lp["post_norm"], x)
+
     def behind(l: int):
-        """What follows KDA mixer ``l`` before the next one: its
-        feed-forward and, where an MLA layer is next, that layer and ITS
-        feed-forward."""
+        """What follows KDA mixer ``l`` up to the cache's write: its
+        feed-forward and, where an MLA layer is next, that layer's
+        ``qkv``; where its rows do not attend through the cache, the
+        rest of the trip too. Returns ``x``, the counts, the new latent
+        rows and what the second conditional is handed of the query."""
         def run(post_norm, x, pages):
             x, n = ffn(cfg.kda_ids[l], post_norm, x)
             j = cfg.mla_after[l]
-            if j >= 0:
-                lp = params["mla"][j]
-                x, pages = mla_layer(lp, j, x, pages)
-                x, m = ffn(cfg.mla_ids[j], lp["post_norm"], x)
-                n = n + m
-            return x, pages, n
+            if j < 0:
+                return x, n, *jax.tree.map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), blank)
+            lp = params["mla"][j]
+            with jax.named_scope("mla"):
+                q, lat = _mla_qkv(cfg, lp, x)
+            if through_cache:
+                return x, n, lat, q
+            x, m = mixed(j, x, q, pages, lat)
+            return x, n + m, lat, ()
         return run
 
-    rest = [behind(l) for l in range(len(cfg.kda_ids))]
+    q, lat = jax.eval_shape(lambda: _mla_qkv(cfg, params["mla"][0], x))
+    blank = (lat, q if through_cache else ())
+    first = [behind(l) for l in range(len(cfg.kda_ids))]
+    second = [lambda x, q, pages: (x, none),
+              *(partial(mixed, j) for j in range(len(cfg.mla_ids)))]
 
     def body(carry, xs):
-        lp, l = xs
+        lp, l, j = xs
         x, pages, kda, conv, held = carry
         x, kda, conv = kda_layer(lp, l, x, kda, conv)
-        x, pages, n = jax.lax.switch(l, rest, lp["post_norm"], x, pages)
+        x, n, lat, q = jax.lax.switch(l, first, lp["post_norm"], x, pages)
+        pages = jax.lax.cond(
+            j >= 0, lambda pages: pages.at[j, dest_page, dest_off].set(lat),
+            lambda pages: pages, pages)
+        if through_cache:
+            x, m = jax.lax.switch(j + 1, second, x, q, pages)
+            n = n + m
         return (x, pages, kda, conv, held + n), None
 
     n = len(cfg.kda_ids)
     carry = (x, state["pages"], state["kda"], state["conv"], none)
     (x, pages, kda, conv, held), _ = jax.lax.scan(
-        body, carry, (params["kda"], jnp.arange(n, dtype=jnp.int32)))
+        body, carry, (params["kda"], jnp.arange(n, dtype=jnp.int32),
+                      jnp.asarray(cfg.mla_after, jnp.int32)))
     return x, {"pages": pages, "kda": kda, "conv": conv}, held
 
 
@@ -526,22 +581,18 @@ def prefill_chunk(cfg: Config, params: dict, state, tokens, seg, pos,
                 conv_end.reshape(-1, *conv.shape[2:]))
         return x + y, kda, conv
 
-    def mla_layer(lp, j, x, pages):
-        with jax.named_scope("mla"):
-            h = rms_norm(x, lp["input_norm"], cfg.eps)
-            qn, qr, lat = mla.qkv(cfg, lp, h)
-            x = x + mla.mla_prefill(
-                cfg, lp, qn, qr, lat, seg,
-                common.layer_page_rows(pages, j, prefix_pages), n_prefix,
-                common.layer_page_rows(pages, j, cont_pages), n_cont,
-                prefix_heads[j] if prefix_heads else None)
-            pages = pages.at[j, dest_page, dest_off].set(lat)
-        return x, pages
+    def attend(lp, j, qn, qr, lat, pages):
+        return mla.mla_prefill(
+            cfg, lp, qn, qr, lat, seg,
+            common.layer_page_rows(pages, j, prefix_pages), n_prefix,
+            common.layer_page_rows(pages, j, cont_pages), n_cont,
+            prefix_heads[j] if prefix_heads else None)
 
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-    x, state, held = _layers(cfg, params, x, state, seg >= 0, kda_layer,
-                             mla_layer)
+    x, state, held = _layers(cfg, params, x, state, seg >= 0,
+                             (dest_page, dest_off), kda_layer, attend,
+                             through_cache=False)
     _, top, ids = head(cfg, params, x[last_idx])
     return state, top, ids, held
 
@@ -562,20 +613,16 @@ def decode_tokens(cfg: Config, params: dict, state, tokens, pos, page_table,
             y, conv, kda = kda_decode(cfg, lp, x, slot, live, conv, kda, l)
         return x + y, kda, conv
 
-    def mla_layer(lp, j, x, pages):
-        with jax.named_scope("mla"):
-            h = rms_norm(x, lp["input_norm"], cfg.eps)
-            qn, qr, lat = mla.qkv(cfg, lp, h)
-            pages = pages.at[j, dest_page, dest_off].set(lat)
-            x = x + mla.mla_decode(
-                cfg, lp, qn, qr,
-                common.layer_page_rows(pages, j, page_table), ctx_len,
-                common.layer_page_rows(pages, j, prefix_pages), n_prefix)
-        return x, pages
+    def attend(lp, j, qn, qr, lat, pages):
+        return mla.mla_decode(
+            cfg, lp, qn, qr,
+            common.layer_page_rows(pages, j, page_table), ctx_len,
+            common.layer_page_rows(pages, j, prefix_pages), n_prefix)
 
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-    x, state, held = _layers(cfg, params, x, state, live, kda_layer,
-                             mla_layer)
+    x, state, held = _layers(cfg, params, x, state, live,
+                             (dest_page, dest_off), kda_layer, attend,
+                             through_cache=True)
     _, top, ids = head(cfg, params, x)
     return state, top, ids, held

@@ -36,6 +36,12 @@ FULL = PRESETS["kimi_linear_ep4"]
 SIZES = GenerateSizes(slots=8, page_tokens=8, chunk_tokens=128,
                       max_segments=8, private_tokens=160)
 NEW = 6
+#: the same at six layers: KDA, KDA, MLA, KDA, MLA, KDA, so that the walk
+#: over the KDA mixers has trips with no MLA layer, with the first and with
+#: the second (as the deployment's: MLA behind its third and sixth mixer)
+TWO_MLA = {**TINY, "num_hidden_layers": 6, "linear_attn_config": {
+    **TINY["linear_attn_config"], "kda_layers": [1, 2, 4, 6],
+    "full_attn_layers": [3, 5]}}
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -53,8 +59,8 @@ def _prompt(seed, n):
         1, TINY["vocab_held"], size=n)
 
 
-def _engine(prefix, name="generate:kimi", sizes=SIZES):
-    eng = GenerateEngine(name, TINY, prefix, sizes=sizes)
+def _engine(prefix, name="generate:kimi", sizes=SIZES, model=TINY):
+    eng = GenerateEngine(name, model, prefix, sizes=sizes)
     eng.warm_async()
     assert eng.warmed.wait(300) and eng.warm_error is None
     return eng
@@ -75,12 +81,12 @@ def _generate(eng, prompt, n=NEW, stream="s"):
                       max_new_tokens=n).result(timeout=300)
 
 
-def _ref_logits(prefix, prompt, result, **kw):
+def _ref_logits(prefix, prompt, result, model=TINY, **kw):
     """The reference's logits rows at the generated positions."""
     full = np.concatenate([prefix, prompt, result["ids"]]).astype(np.int64)
     first = len(prefix) + len(prompt) - 1
     return np.asarray(ref.forward(
-        TINY, full, rows=list(range(first, first + len(result["ids"]))),
+        model, full, rows=list(range(first, first + len(result["ids"]))),
         **kw))
 
 
@@ -418,6 +424,108 @@ def test_rows_that_carry_no_sequence_never_reach_the_state(engine):
             engine._state[key][:, SIZES.slots], np.float32), was, key)
     problems, stats = _compare(out, _ref_logits(engine.prefix, prompt, out))
     assert not problems, (problems, stats)
+
+
+# ------------------------------------- the page cache through the walk
+
+
+def _walk_case(program):
+    """One step of ``program`` at the six-layer size over a state of
+    noise: ``(cfg, pages before, pages after, the rows' dest_page,
+    dest_off, which of them carry a token)``."""
+    cfg = lm.Config.from_dict(TWO_MLA)
+    assert cfg.mla_after == (-1, 0, 1, -1)
+    params = lm.make_params(cfg)
+    slots, i32 = 4, jnp.int32
+    r = np.random.default_rng(11)
+    state = {k: jnp.asarray(r.standard_normal(v.shape) * 0.1, v.dtype)
+             for k, v in lm.state_shapes(cfg, 12, 8, slots).items()}
+    prefix_pages = jnp.asarray([1, 2], i32)
+    if program == "decode":
+        live = np.array([True, True, False])
+        page, off = np.array([5, 7, 0]), np.array([2, 3, 0])
+        out = lm.decode_tokens(
+            cfg, params, state, jnp.asarray([3, 9, 0], i32),
+            jnp.asarray([18, 27, 0], i32),
+            jnp.asarray([[5, 6], [6, 7], [0, 0]], i32),
+            jnp.asarray([3, 12, 0], i32), jnp.asarray(page, i32),
+            jnp.asarray(off, i32), jnp.asarray(live), prefix_pages, 16,
+            jnp.asarray([1, 3, slots], i32))
+    else:
+        # two segments of 32 and 16 tokens and a tail of no segment
+        seg = np.repeat([0, 1, -1], [32, 16, 16])
+        live = seg >= 0
+        at = np.where(live, np.arange(64), 0)
+        page, off = np.where(live, 5 + at // 8, 0), at % 8
+        out = lm.prefill_chunk(
+            cfg, params, state, jnp.asarray(r.integers(1, 128, 64), i32),
+            jnp.asarray(seg, i32), jnp.asarray(16 + at, i32),
+            jnp.asarray(page, i32), jnp.asarray(off, i32), prefix_pages, 16,
+            jnp.zeros((2,), i32), 0, jnp.asarray([31, 47], i32),
+            jnp.full((2,), slots + 1, i32), jnp.asarray([0, 2], i32))
+    assert np.isfinite(np.asarray(out[1])).all()
+    return (cfg, np.asarray(state["pages"], np.float32),
+            np.asarray(out[0]["pages"], np.float32), page, off, live)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_walk_writes_the_named_rows_and_the_null_page_alone(program):
+    """The loop body writes the cache, not the branches that hold the
+    layers (``_layers``): the MLA layer's new rows where the trip over
+    the KDA mixers has one, nothing where it has none (the zeros such a
+    trip's branch returns go nowhere). After a decode step and after a
+    chunk every row of both MLA layers other than those ``dest_page`` /
+    ``dest_off`` name and page 0, where rows of no sequence write, is
+    what it was, bit for bit; the named rows hold ``[c_kv | k_r | zeros]``
+    in BOTH layers, each layer its own."""
+    cfg, before, after, page, off, live = _walk_case(program)
+    assert before.shape[0] == 2
+    named = np.zeros(before.shape[:3], bool)
+    named[:, 0] = True
+    named[:, page, off] = True
+    np.testing.assert_array_equal(after[~named], before[~named])
+    rows = after[:, page[live], off[live]]
+    assert (rows != before[:, page[live], off[live]]).any(axis=-1).all()
+    assert np.abs(rows[..., :cfg.latent]).sum(axis=-1).all()
+    assert not rows[..., cfg.latent:].any()
+    assert (rows[0] != rows[1]).any(axis=-1).all()
+
+
+@pytest.fixture(scope="module")
+def two_mla_engine():
+    # one slot: one decode program to compile
+    eng = _engine(_prefix(), name="generate:kimi_two_mla", model=TWO_MLA,
+                  sizes=GenerateSizes(slots=1, page_tokens=8, chunk_tokens=64,
+                                      max_segments=4, private_tokens=96))
+    yield eng
+    eng.stop()
+
+
+@pytest.mark.parametrize("length", [5, 70])
+def test_two_latent_layers_through_the_walk_match_the_reference(
+        two_mla_engine, length):
+    """Six layers, two of them MLA, through the engine against the
+    reference's full forward pass: tokens and logits of a prompt in one
+    chunk and of one that crosses a chunk boundary (the second chunk
+    attends to the first's pages of both layers), then decode steps
+    whose rows read their own new latent rows through the cache; the
+    slot state carries what the later tokens' logits rest on."""
+    eng = two_mla_engine
+    prompt = _prompt(200 + length, length)
+    before = np.asarray(eng._state["pages"], np.float32)
+    out = _generate(eng, prompt)
+    problems, stats = _compare(
+        out, _ref_logits(eng.prefix, prompt, out, model=TWO_MLA))
+    assert not problems, (problems, stats)
+    _idle(eng)
+    # the prefix's pinned pages, which every row attends to, are as they
+    # were in both layers; the sequence's rows went to pages of its own
+    after = np.asarray(eng._state["pages"], np.float32)
+    pinned = slice(1, 1 + eng._prefix_pages)
+    np.testing.assert_array_equal(after[:, pinned], before[:, pinned])
+    moved = (after != before).any(axis=(0, 2, 3))
+    moved[0] = False
+    assert 1 <= moved.sum() <= -(-(length + NEW) // 8)
 
 
 # ---------------------------------------------------------- the engine
@@ -960,10 +1068,10 @@ def test_third_describe_pipeline_end_to_end_through_rest(eight_devices,
 
 
 @pytest.mark.parametrize("program,on_chip,want", [
-    ("decode", True, "1b901f8b56f4629c"),
-    ("decode", False, "b9ae772387cf86f6"),
-    ("prefill", True, "d804646e1368d32f"),
-    ("prefill", False, "67d0cae0daf6c68b")])
+    ("decode", True, "0e80c663e0b22c01"),
+    ("decode", False, "8c08761242c93aea"),
+    ("prefill", True, "091c3e5009ed0408"),
+    ("prefill", False, "901fb13099eedfe5")])
 def test_the_step_programs_compute_what_they_did(monkeypatch, program,
                                                  on_chip, want):
     """The guard of the modules this family shares with the others
