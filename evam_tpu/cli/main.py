@@ -144,7 +144,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="--synthesize-lm: which config of "
                         "models/lm/presets.py (deepseek_v2_ep8, jamba2_3b, "
                         "kimi_linear_ep4, lfm2_moe_ep2, laguna_xs2_pp8, "
-                        "nemotron3_super_ep8, and a "
+                        "nemotron3_super_ep8, brumby_14b_pp8, and a "
                         "tiny one of each family)")
     f.add_argument("--size", type=int, default=None,
                    help="input resolution for --synthesize-omz "

@@ -214,8 +214,10 @@ class LMSettings(BaseModel):
     EVAM_LM_SHAPES exists for rehearsals and tests at a tiny size, and no
     deployment file sets it."""
 
-    #: sequence slots: generations in flight on the device; a family
-    #: with recurrent layers keeps one row of state per slot
+    #: the CEILING of the sequence slots (generations in flight on the
+    #: device; a family with recurrent layers keeps one row of state per
+    #: slot): the engine takes as many of them as its family's state
+    #: leaves room for on the device (engine/generate.py ``fit_slots``)
     slots: int = 128
     #: tokens per page of the family's cache (latent rows, or keys and
     #: values)

@@ -10,7 +10,12 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
 
 * ``slots`` sequence slots. A submitted request waits for a slot (the
   class's staleness budget applies to THIS wait and to nothing after
-  it), then holds it until its last token.
+  it), then holds it until its last token. ``GenerateSizes.slots`` is the
+  CEILING: the engine takes what the family's state leaves room for on its
+  device, in whole rungs of the decode ladder (``fit_slots``: weights,
+  the prefix's heads, a slot's state row and pages, against the device's
+  memory limit less ``RESERVE_BYTES``). 128 for every family whose row is
+  megabytes, 32 for Brumby's 170 MB a row.
 * One thread runs device steps of FIXED shapes from a small set of
   programs, all compiled by ``warm_async``: ``decode`` over the running
   sequences, padded to a slot bucket, and ``prefill`` of one packed
@@ -36,7 +41,9 @@ like any other (engine/hub.py ``generate_engine``), and it is listed on
   (engine/pages.py): latent rows in every layer of DeepSeek-V2 (576
   values stored 640 wide: whole lane tiles, so the donated array enters
   and leaves both programs where it lies), key and value rows in Jamba's
-  two attention layers. The pages of the shared
+  two attention layers; ABSENT for a family none of whose layers keeps
+  rows (Brumby: no page is pinned, allocated, written or counted, the
+  prefix is the snapshot row alone). The pages of the shared
   instruction prefix are prefilled once in ``warm_async``, never written
   again, and a constant of both programs: a prefill chunk and a decode
   step each read them ONCE for all their rows. A decode row's page table
@@ -128,6 +135,35 @@ PART_CHUNK_PATIENCE = 12
 #: (PERF.md section 6, PR 28).
 DECODE_LADDER = 8
 
+#: what the engine leaves of its device's memory limit to everything it
+#: does not reckon itself. Two parts, read on a v5e (16.9 GB reported):
+#: the detector's engine, both step programs' temporaries and the runtime's
+#: own, which took 1.6-1.9 GB beside the six models' weights, state and
+#: pages (``memory_peak_bytes`` less ``fit_slots``' sum: PERF.md section 4);
+#: and the 2.4 GB that every configuration's fallback line (a peak of
+#: 14.5 GB) keeps free for what a run allocates late.
+RESERVE_BYTES = 4_300_000_000
+
+
+def fit_slots(ceiling: int, limit: int | None, fixed: int,
+              per_slot: int) -> int:
+    """The slots an engine takes: ``ceiling``, or where the device reports
+    a memory ``limit`` that it does not leave room for, the most that
+    ``fixed`` bytes (weights, the prefix's heads, the state's two spare
+    rows, the null and the prefix's pages) and ``per_slot`` bytes a slot
+    (its state row and its own pages) fit under ``limit - RESERVE_BYTES``,
+    in whole rungs of the decode ladder."""
+    if limit is None or not per_slot:
+        return ceiling
+    fit = (limit - RESERVE_BYTES - fixed) // per_slot
+    fit = min(ceiling, fit // DECODE_LADDER * DECODE_LADDER)
+    if fit < min(ceiling, DECODE_LADDER):
+        raise ValueError(
+            f"{fixed / 1e9:.2f} GB of weights and {per_slot / 1e6:.1f} MB a "
+            f"slot leave no room for {DECODE_LADDER} slots under "
+            f"{limit / 1e9:.2f} GB less {RESERVE_BYTES / 1e9:.1f} GB")
+    return fit
+
 
 def next_step_kind(waiting: int, decoding: bool, prefill_run: int,
                    passed_over: int, chunk_tokens: int,
@@ -200,8 +236,10 @@ class PrefillPace:
 @dataclasses.dataclass(frozen=True)
 class GenerateSizes:
     """The engine's fixed shapes (config/settings.py ``LMSettings``:
-    the defaults are the deployment's, a rehearsal sets tiny ones). The
-    decode ladder follows from the slots."""
+    the defaults are the deployment's, a rehearsal sets tiny ones).
+    ``slots`` is the CEILING an engine is handed; the sizes it serves with
+    hold the slots it took (``fit_slots``). The decode ladder follows from
+    them."""
 
     slots: int = 128
     page_tokens: int = 128
@@ -227,9 +265,9 @@ class _Seq:
     """One request from submit to its future's result."""
 
     __slots__ = ("prompt", "max_new", "stream", "priority", "trace",
-                 "future", "t_submit", "slot", "pages", "n_prefilled",
-                 "n_gen", "ids", "top_ids", "top_logits", "t_first",
-                 "t_prefilled", "cancelled")
+                 "future", "t_submit", "t_slot", "slot", "pages",
+                 "n_prefilled", "n_gen", "ids", "top_ids", "top_logits",
+                 "t_first", "t_prefilled", "cancelled")
 
     def __init__(self, prompt, max_new, stream, priority, ftrace):
         self.prompt = prompt
@@ -239,6 +277,7 @@ class _Seq:
         self.trace = ftrace
         self.future: Future = Future()
         self.t_submit = time.perf_counter()
+        self.t_slot: float | None = None
         self.slot = -1
         self.pages: list[int] = []
         #: prompt tokens / generated tokens whose step has been dispatched
@@ -284,6 +323,10 @@ class _Step:
     held: jax.Array
 
 
+def _nbytes(shapes) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+
+
 @functools.lru_cache(maxsize=1024)
 def _key_blocks(lm, cfg, seg: bytes, n_prefix: int, n_cont: int,
                 prefix_pages: int, cont_pages: int, page_tokens: int) -> list:
@@ -302,16 +345,24 @@ def _key_blocks(lm, cfg, seg: bytes, n_prefix: int, n_cont: int,
 class GenerateEngine:
     #: what the hub's rows read of any engine
     ragged = "off"
+    #: whether the family keeps cache rows in pages (every family but one
+    #: whose ``state_shapes`` has no ``pages``: set in ``__init__``)
+    _paged = True
 
     def __init__(self, name: str, model_cfg: dict, prefix_ids,
                  sizes: GenerateSizes | None = None, plan=None,
                  sched: SchedConfig | None = None,
                  stall_timeout_s: float = 120.0,
-                 first_batch_grace: float = 10.0):
+                 first_batch_grace: float = 10.0,
+                 memory_limit: int | None = None):
+        """``memory_limit``: the device's, for a test; None asks the
+        device (a CPU reports none: the ceiling)."""
         self.name = name
         self._lm = family(model_cfg["model_type"])
         self.cfg = self._lm.Config.from_dict(model_cfg)
-        self.sizes = sz = sizes or GenerateSizes()
+        sz = sizes or GenerateSizes()
+        #: what the engine was handed; ``sizes.slots`` is what it took
+        self.slots_ceiling = sz.slots
         #: a chunk's segments start at multiples of this many tokens
         self._align = self._lm.SEGMENT_ALIGN
         #: the positions a window layer sees (None: every layer of the
@@ -337,28 +388,48 @@ class GenerateEngine:
         if len(prefix) and (prefix.min() < 0 or prefix.max() >= self.cfg.vocab):
             raise ValueError("prefix ids outside the held vocabulary")
         self.prefix = prefix
-        self.buckets = list(sz.slot_buckets)
         self._device = (plan.mesh.devices.flat[0] if plan is not None
                         else jax.devices()[0])
-        self._prefix_pages = len(prefix) // sz.page_tokens
-        self._private_pages = -(-sz.private_tokens // sz.page_tokens)
-        self._pool = PagePool(
-            1 + self._prefix_pages + sz.slots * self._private_pages,
-            sz.page_tokens)
-        self._shared = self._pool.pin(self._prefix_pages)
-        self._state_shapes = self._lm.state_shapes(
-            self.cfg, self._pool.n_pages, sz.page_tokens, sz.slots)
-        #: bytes of what the family keeps per slot (0: pages alone)
-        self._state_bytes = sum(
-            a.size * a.dtype.itemsize
-            for k, a in self._state_shapes.items() if k != "pages")
         #: a latent family's: per latent layer the shapes of the prefix's
         #: heads, which the prefill program takes beside the weights
         heads = getattr(self._lm, "prefix_heads_shapes", None)
         self._heads_shapes = (heads(self.cfg, len(prefix))
                               if heads is not None and len(prefix) else ())
-        self._heads_bytes = sum(a.size * a.dtype.itemsize
-                                for a in jax.tree.leaves(self._heads_shapes))
+        self._heads_bytes = _nbytes(self._heads_shapes)
+        self._private_pages = -(-sz.private_tokens // sz.page_tokens)
+        #: a family none of whose layers keeps rows has no ``pages``: no
+        #: page is pinned, allocated, written or counted
+        self._paged = "pages" in self._lm.state_shapes(
+            self.cfg, 1, sz.page_tokens, 0)
+        self._prefix_pages = (len(prefix) // sz.page_tokens
+                              if self._paged else 0)
+
+        def n_pages(slots: int) -> int:
+            return 1 + self._prefix_pages + (
+                slots * self._private_pages if self._paged else 0)
+
+        def held(slots: int) -> int:
+            return _nbytes(self._lm.state_shapes(
+                self.cfg, n_pages(slots), sz.page_tokens, slots))
+
+        if memory_limit is None:
+            memory_limit = (self._device.memory_stats() or {}).get(
+                "bytes_limit")
+        self._memory_limit = memory_limit
+        self.sizes = sz = dataclasses.replace(sz, slots=fit_slots(
+            sz.slots, memory_limit,
+            2 * self._lm.param_count(self.cfg) + self._heads_bytes + held(0),
+            held(1) - held(0)))
+        self.buckets = list(sz.slot_buckets)
+        self._pool = PagePool(n_pages(sz.slots), sz.page_tokens)
+        self._shared = self._pool.pin(self._prefix_pages)
+        self._state_shapes = self._lm.state_shapes(
+            self.cfg, self._pool.n_pages, sz.page_tokens, sz.slots)
+        #: bytes of what the family keeps per slot (0: pages alone)
+        self._state_bytes = _nbytes({
+            k: a for k, a in self._state_shapes.items() if k != "pages"})
+        #: of which one slot's row
+        self._row_bytes = self._state_bytes // (sz.slots + 2)
         self.stats = EngineStats()
         self.warmed = threading.Event()
         self.warm_error: str | None = None
@@ -465,7 +536,9 @@ class GenerateEngine:
                 lambda a: jnp.zeros(a.shape, a.dtype), self._heads_shapes)
             jax.block_until_ready(self._params)
         log.info(
-            "engine %s: %.2f G parameters, %s on %s in %.1f s", self.name,
+            "engine %s: %d slots of a ceiling of %d under a limit of %s bytes, "
+            "%.2f G parameters, %s on %s in %.1f s", self.name, sz.slots,
+            self.slots_ceiling, self._memory_limit,
             lm.param_count(cfg) / 1e9,
             ", ".join(f"{k} {'x'.join(map(str, a.shape))}"
                       for k, a in self._state_shapes.items()),
@@ -545,7 +618,7 @@ class GenerateEngine:
                 for o in range(sz.page_tokens)]
         for lo in range(0, len(self.prefix), sz.chunk_tokens):
             part = self.prefix[lo:lo + sz.chunk_tokens]
-            dest = flat[lo:lo + len(part)]
+            dest = flat[lo:lo + len(part)] or [0] * len(part)
             self._harvest(self._dispatch_prefill_raw(
                 part, np.zeros(len(part), np.int32),
                 np.arange(lo, lo + len(part)), dest, n_prefix=lo,
@@ -687,6 +760,10 @@ class GenerateEngine:
         sz = self.sizes
         return sz.slots - len(self._free_slots), sz.slots, self._state_bytes
 
+    def slots(self) -> tuple[int, int]:
+        """(the slots the engine took, the ceiling it was handed)."""
+        return self.sizes.slots, self.slots_ceiling
+
     def prefix_heads_bytes(self) -> int:
         """Bytes of the shared prefix's heads held beside the weights (0
         for a family without latent attention, or without a prefix)."""
@@ -788,12 +865,16 @@ class GenerateEngine:
                 while q and self._free_slots:
                     seq = q[0]
                     pages = self._pool.alloc(self._pool.pages_for(
-                        len(seq.prompt) + seq.max_new - 1))
+                        len(seq.prompt) + seq.max_new - 1)
+                        if self._paged else 0)
                     if pages is None:
                         return
                     q.popleft()
                     seq.slot = self._free_slots.pop()
                     seq.pages = pages
+                    seq.t_slot = time.perf_counter()
+                    metrics.observe("evam_generate_slot_wait_seconds",
+                                    seq.t_slot - seq.t_submit)
                     self._prefilling.append(seq)
         self._set_gauges()
 
@@ -809,6 +890,8 @@ class GenerateEngine:
         metrics.set("evam_generate_slots_active",
                     self.sizes.slots - len(self._free_slots))
         metrics.set("evam_generate_pages_in_use", self._pool.in_use)
+        metrics.set("evam_generate_slots", self.sizes.slots,
+                    {"engine": self.name})
         metrics.set("evam_generate_state_bytes", self._state_bytes)
         metrics.set("evam_generate_prefix_heads_bytes", self._heads_bytes)
 
@@ -816,9 +899,10 @@ class GenerateEngine:
 
     def _where(self, pages: list[int], k: int) -> int:
         """Flat cache row (page * page_tokens + offset) of the ``k``-th
-        own token of the sequence that holds ``pages``."""
+        own token of the sequence that holds ``pages`` (the null page's
+        first for a family without pages)."""
         pt = self.sizes.page_tokens
-        return pages[k // pt] * pt + k % pt
+        return pages[k // pt] * pt + k % pt if self._paged else 0
 
     def _dispatch_prefill(self) -> _Step:
         """Pack prompt tokens of the waiting sequences, in order, into
@@ -903,7 +987,7 @@ class GenerateEngine:
         # rows of the cache the chunk reads, per layer: the prefix once
         # (all its tokens share it) and one sequence's earlier rows; a
         # window layer the last of them that the chunk's first token sees
-        cached = (n_prefix + n_cont) if n else 0
+        cached = (n_prefix + n_cont) if n and self._paged else 0
         seen = min(cached, self._window - 1) if self._window else cached
         # the chunk's key blocks by class, as the kernel's own blocks cut
         # what the program is handed (the whole table of continued pages)
@@ -951,6 +1035,8 @@ class GenerateEngine:
             mat[:, b] = (slot, len(self.prefix) + k, k + 1,
                          row // sz.page_tokens, row % sz.page_tokens, 1)
             table[b, :len(pages)] = pages
+            if not self._paged:
+                continue
             # a row's whole context, the prefix's rows among them
             ctx = len(self.prefix) + k + 1
             rows_read += ctx
@@ -963,7 +1049,8 @@ class GenerateEngine:
                          takers=takers, state_rows=len(rows),
                          window_read=window_read, own_pages=(
                              pages_read,
-                             len(rows) * self._private_pages - pages_read))
+                             len(rows) * self._private_pages * self._paged
+                             - pages_read))
 
     def _run(self, kind: str, key: str, fn, inputs, *, tokens, rows_read,
              takers, state_rows, restores=0, window_read=None,
@@ -1033,6 +1120,10 @@ class GenerateEngine:
                             {"layers": name, "class": cls})
         metrics.inc("evam_generate_state_rows", float(step.state_rows),
                     labels)
+        # each of which the step read and wrote, a slot's row of every
+        # layer: counted here, the step programs return nothing for it
+        metrics.inc("evam_generate_state_bytes",
+                    float(2 * step.state_rows * self._row_bytes), labels)
         metrics.inc("evam_generate_prefix_restores", float(step.restores))
         metrics.inc("evam_moe_held_assignments", float(held))
         # of which the sort's rows are: held over routed is the share of
@@ -1049,7 +1140,7 @@ class GenerateEngine:
         if step.kind == "decode":
             # row-key pairs that the one pass over the prefix served
             metrics.inc("evam_generate_decode_shared_rows",
-                        float(len(self.prefix) * step.tokens))
+                        float(len(self.prefix) * step.tokens * self._paged))
             bucket = int(step.key.split(":")[1])
             st.bucket_batches[bucket] = st.bucket_batches.get(bucket, 0) + 1
             st.occupancy_sum += step.tokens / bucket
@@ -1075,6 +1166,8 @@ class GenerateEngine:
         self._mean_new += a * (seq.max_new - self._mean_new)
         ft = seq.trace
         if ft is not None:
+            ft.add_span("generate.slot_wait", seq.t_submit,
+                        seq.t_slot - seq.t_submit)
             ft.add_span("generate.queue_wait", seq.t_submit,
                         seq.t_first - seq.t_submit)
             ft.add_span("generate.prefill", seq.t_first,

@@ -456,6 +456,8 @@ class EngineHub:
             row["pages_in_use"], row["pages"] = e.pages_in_use()
             (row["state_slots_in_use"], row["state_slots"],
              row["state_bytes"]) = e.state_slots()
+            # the slots the engine took, and the ceiling it was handed
+            row["slots"], row["slots_ceiling"] = e.slots()
             row["prefix_heads_bytes"] = e.prefix_heads_bytes()
         return row
 

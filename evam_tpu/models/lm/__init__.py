@@ -8,8 +8,10 @@ serves no ``describe`` stage.
 
 A FAMILY is a module with ``Config.from_dict``, ``make_params``,
 ``param_count``, ``state_shapes`` (the device state of its sequences for
-``(pages, page_tokens, slots)``: cache rows in pages, and whatever it
-keeps per slot), optionally ``prefix_heads_shapes`` and ``prefix_heads``
+``(pages, page_tokens, slots)``: ``pages``, the cache rows of the layers
+that attend, ABSENT for a family none of whose layers keeps rows
+(``brumby``: the engine then pins, allocates and counts no page), and
+whatever it keeps per slot), optionally ``prefix_heads_shapes`` and ``prefix_heads``
 (read-only data of the prefill program that the engine holds beside the
 weights: a latent family's materialised heads of the shared prefix's rows,
 made from ``params``, the state and the pinned pages once warm-up has
@@ -49,7 +51,10 @@ matrices under ``relu^2`` that work in a projected latent). A family whose
 blocks are each ONE sublayer (``nemotron_h``: a Mamba-2 mixer, an expert
 layer or attention by a pattern string) is a family like the others: what a
 block is made of is the family's own, the engine sees ``state_shapes`` and
-the two step programs.
+the two step programs. ``brumby`` is the family whose EVERY layer is
+recurrent (power retention of degree 2, ops/pallas_power.py): its
+projections, head norms and rotation are ``attention.py``'s ``qkv``, its
+state 34 MB a row and layer, its layers one stack under one ``lax.scan``.
 """
 
 from __future__ import annotations
@@ -58,7 +63,8 @@ import importlib
 
 FAMILIES = {"deepseek_v2": "deepseek_v2", "jamba": "jamba",
             "kimi_linear": "kimi_linear", "lfm2_moe": "lfm2_moe",
-            "laguna": "laguna", "nemotron_h": "nemotron_h"}
+            "laguna": "laguna", "nemotron_h": "nemotron_h",
+            "brumby": "brumby"}
 
 
 def family(model_type: str):

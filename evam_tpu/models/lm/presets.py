@@ -77,6 +77,20 @@ own, 16 and ``time_step_max``). ``nemotron_h_tiny`` has the same structure
 heads over 2 key-value heads; 16 experts, 2 held, 6 a token, a latent of 32)
 at a size a CPU test runs.
 
+``brumby_14b_pp8`` is Brumby-14B-Base's published config
+(https://huggingface.co/manifestai/Brumby-14B-Base/blob/main/config.json)
+with every width as published, cut to what the FIRST of eight pipeline
+stages holds, every layer of it whole: layers 0-4 (all power retention: the
+model has one kind of layer), the embedding, and the final norm and head so
+that the stage yields logits; the whole vocabulary is here. The config
+carries neither the degree (2), the gate nor the normaliser: they are the
+family's (models/lm/brumby.py). ``gate_memory_min`` / ``gate_memory_max``
+are the ends of the seeded decays' draw, in tokens remembered (a state that
+forgets in tens of tokens tells a bfloat16 state from a float32 one no
+more: Nemotron's lesson). ``brumby_tiny`` has the same structure (3 layers,
+4 query heads over 2 key-value heads of 16: a state of 136 x 16 a head) at
+a size a CPU test runs.
+
 Which module serves a preset is its ``model_type`` (models/lm
 ``FAMILIES``); the latent attention (models/lm/mla.py: ``deepseek_v2``,
 ``kimi_linear``), the plain attention (models/lm/attention.py: ``jamba``,
@@ -221,7 +235,46 @@ NEMOTRON3_SUPER_PUBLISHED = {
     "vocab_size": 131072,
 }
 
+BRUMBY_14B_PUBLISHED = {
+    "attention_bias": False, "head_dim": 128, "hidden_act": "silu",
+    "hidden_size": 5120, "intermediate_size": 17408,
+    "max_position_embeddings": 32768, "max_window_layers": 40,
+    "model_type": "brumby", "num_attention_heads": 40,
+    "num_hidden_layers": 40, "num_key_value_heads": 8,
+    "rms_norm_eps": 1e-06, "rope_scaling": None, "rope_theta": 1000000,
+    "sliding_window": None, "tie_word_embeddings": False,
+    "use_sliding_window": False, "vocab_size": 151936,
+}
+
 PRESETS = {
+    "brumby_14b_pp8": {
+        **BRUMBY_14B_PUBLISHED,
+        # the cut: the first pipeline stage's five layers, each whole
+        "num_hidden_layers": 5,
+        "vocab_held": 151936,
+        "weights_seed": 20261005,
+        "initializer_range": 0.02,
+        # assumed: the gates' biases drawn so that a head's decay remembers
+        # 600-1800 tokens, a quarter to three quarters of the product's
+        # context, as a model trained for long contexts does: at 150-450
+        # a state kept in bfloat16 reads only twice what bfloat16
+        # activations do (PERF.md section 6, PR 57)
+        "gate_memory_min": 600,
+        "gate_memory_max": 1800,
+    },
+    "brumby_tiny": {
+        **BRUMBY_14B_PUBLISHED,
+        "hidden_size": 64, "intermediate_size": 96, "head_dim": 16,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "num_hidden_layers": 3, "max_window_layers": 3, "vocab_size": 512,
+        "rope_theta": 100,
+        "vocab_held": 512,
+        "weights_seed": 29,
+        # as deepseek_v2_tiny: 0.02 at width 64 leaves every score flat
+        "initializer_range": 0.15,
+        "gate_memory_min": 8,
+        "gate_memory_max": 40,
+    },
     "nemotron3_super_ep8": {
         **NEMOTRON3_SUPER_PUBLISHED,
         # the cut: the first stage's blocks, the chip's share of the

@@ -95,7 +95,14 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # sequences started from the prefix snapshot, and the state's bytes
     "evam_generate_state_rows": ("counter", ("kind",)),
     "evam_generate_prefix_restores": ("counter", ()),
-    "evam_generate_state_bytes": ("gauge", ()),
+    # ONE name, two series (the registry keeps counters and gauges
+    # apart): the gauge ``evam_generate_state_bytes``, what the state
+    # holds, and the counter ``evam_generate_state_bytes_total{kind}``,
+    # the slot state the steps MOVED (rows read and written x a row's
+    # bytes over every layer, counted on the host); over
+    # ``evam_generate_tokens_total{kind="decode"}`` the state a decoded
+    # token costs
+    "evam_generate_state_bytes": ("gauge", ("kind",)),
     # a family with latent attention (DeepSeek-V2, Kimi-Linear): the bytes
     # of the shared prefix's materialised heads, held beside the weights
     # for the prefill program (0 for every other family)
@@ -104,6 +111,11 @@ METRIC_SPECS: dict[str, tuple[str, tuple[str, ...]]] = {
     # once a step for all its live rows (prefix rows x live rows)
     "evam_generate_decode_shared_rows": ("counter", ()),
     "evam_generate_queue_wait_seconds": ("histogram", ()),
+    # submit -> a slot (the queue wait above runs on to the first chunk)
+    "evam_generate_slot_wait_seconds": ("histogram", ()),
+    # the slots an engine took of its ceiling (engine/generate.py
+    # ``fit_slots``)
+    "evam_generate_slots": ("gauge", ("engine",)),
     "evam_generate_slots_active": ("gauge", ()),
     "evam_generate_pages_in_use": ("gauge", ()),
     "evam_moe_held_assignments": ("counter", ()),

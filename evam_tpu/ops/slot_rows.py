@@ -12,12 +12,16 @@ the layer ``l``, ``slot`` and ``live`` are PREFETCHED SCALARS of a kernel
 whose grid is the step's rows, one grid step a row: the state's block at
 grid step ``b`` is ``state[l, slot[b]]``, the array is aliased in and out,
 and so a row is read from its slot, moved on and written back where it
-was. No other row is touched. Three kernel bodies use it, one a kind of
+was. No other row is touched. Five kernel bodies use it, one a kind of
 recurrent layer: ``ssm_decode_rows`` (ops/pallas_selective_scan.py: a
 float32 state and the convolution's taps), ``kda_decode_rows``
 (ops/pallas_kda.py: a matrix state a head and three convolutions' taps)
-and ``conv_decode_rows`` (ops/pallas_short_conv.py: taps alone, 8 KB a
-row, the kernel's cost its grid steps and not its bytes).
+``conv_decode_rows`` (ops/pallas_short_conv.py: taps alone, 8 KB a
+row, the kernel's cost its grid steps and not its bytes),
+``ssd_decode_rows`` (ops/pallas_ssd.py: 4 MB of float32 state a row) and
+``pow_decode_rows`` (ops/pallas_power.py: 34 MB a row, more than a block
+may hold, so its grid is (step row, key-value head) and a grid step moves
+one head's 4.2 MB: ``call``'s ``parts``).
 
 What the kernels rely on, and hold:
 
@@ -76,6 +80,29 @@ def grouped(rows: int, width: int):
     return pl.BlockSpec((g, width), lambda b, *_: (b // g, 0))
 
 
+def at_slot_part(*tail: int):
+    """The block ``state[l, slot[b], p]`` of a state array [layers, rows,
+    parts, *tail] under a grid of (step row, part) (``call``'s ``parts``).
+    A row that is not live names part 0 in every one of its grid steps: its
+    block is fetched once, goes back as it came (``keep``) and is written
+    once, however many parts and dead rows follow one another."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (None, None, None) + tail,
+        lambda b, p, l, slot, live: (
+            l[0], slot[b], jnp.where(live[b] > 0, p, 0)) + _zeros(len(tail)))
+
+
+def per_part(*tail: int):
+    """Step row ``b``'s block of part ``p`` of an operand [B, parts,
+    *tail] under a grid of (step row, part)."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((None, None) + tail,
+                        lambda b, p, *_: (b, p) + _zeros(len(tail)))
+
+
 def shared(*shape: int):
     """An operand every row reads whole (fetched once)."""
     from jax.experimental import pallas as pl
@@ -99,27 +126,34 @@ def keep(*pairs) -> None:
 
 
 def call(kernel, name: str, l, slot, live, operands, specs, outs, out_specs,
-         states, state_specs, *, interpret: bool = False):
+         states, state_specs, *, parts: int | None = None, scratch=(),
+         interpret: bool = False):
     """``kernel(l_ref, slot_ref, live_ref, *operands, *states, *outs,
-    *states_out)`` over a grid of the step's rows. ``states`` are aliased
-    to the last outputs. Returns ``(*outs, *states)``."""
+    *states_out, *scratch)`` over a grid of the step's rows, or with
+    ``parts`` of (step row, part of its state) under the ``*_part`` blocks
+    (a row's state too large for one block). ``states`` are aliased to the
+    last outputs; ``scratch``: shapes of float32 VMEM the body keeps.
+    Returns ``(*outs, *states)``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     first = 3 + len(operands)
+    grid = (slot.shape[0],) + (() if parts is None else (parts,))
+    more = ({"scratch_shapes": [pltpu.VMEM(s, jnp.float32) for s in scratch]}
+            if scratch else {})
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(slot.shape[0],),
+            grid=grid,
             in_specs=[*specs, *state_specs],
-            out_specs=[*out_specs, *state_specs]),
+            out_specs=[*out_specs, *state_specs], **more),
         out_shape=[*outs, *(jax.ShapeDtypeStruct(s.shape, s.dtype)
                             for s in states)],
         input_output_aliases={first + i: len(outs) + i
                               for i in range(len(states))},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=64 * 1024 * 1024),
         name=name,
         interpret=interpret,

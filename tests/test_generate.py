@@ -1977,3 +1977,115 @@ def test_the_step_programs_compute_what_they_did(monkeypatch, program,
     from _step_trace import check
 
     check("deepseek_v2_ep8", program, on_chip, monkeypatch, want)
+
+
+# ------------------------------- the slots follow from the family's state
+
+#: what a v5e's ``memory_stats()["bytes_limit"]`` reads (my chip run, PR 57)
+V5E_LIMIT = 16_909_336_064
+BRUMBY_TINY = PRESETS["brumby_tiny"]
+BRUMBY_SIZES = GenerateSizes(slots=16, page_tokens=8, chunk_tokens=128,
+                             max_segments=2, private_tokens=160)
+
+
+def _brumby(limit=None, sizes=BRUMBY_SIZES, name="generate:rows"):
+    return GenerateEngine(name, BRUMBY_TINY, _prefix(), sizes=sizes,
+                          memory_limit=limit)
+
+
+def test_a_family_of_large_rows_gets_the_slots_its_state_leaves_room_for():
+    """With a limit handed in: a multiple of ``DECODE_LADDER`` under the
+    ceiling, and the decode ladder over THOSE slots; with none (a CPU
+    reports none), the ceiling."""
+    from evam_tpu.engine.generate import (DECODE_LADDER, RESERVE_BYTES,
+                                          fit_slots)
+    from evam_tpu.models.lm import brumby
+
+    cfg = brumby.Config.from_dict(BRUMBY_TINY)
+    row = 3 * 2 * (136 + 9) * 16 * 4          # a slot's row, three layers
+    fixed = 2 * brumby.param_count(cfg) + 2 * row
+    for room, want in ((19.5, 16), (15.9, 8), (8.0, 8), (200.0, 16)):
+        eng = _brumby(int(RESERVE_BYTES + fixed + room * row))
+        try:
+            assert eng.slots() == (want, 16), room
+            assert eng.sizes.slots == want and want % DECODE_LADDER == 0
+            assert eng.buckets == list(range(want // 8, want + 1, want // 8))
+            assert len(eng._free_slots) == want
+            shapes = eng._state_shapes
+            assert "pages" not in shapes
+            assert shapes["pow"].shape[:2] == (3, want + 2)
+        finally:
+            eng.stop()
+    eng = _brumby(None)
+    try:
+        assert eng.slots() == (16, 16)
+    finally:
+        eng.stop()
+    with pytest.raises(ValueError, match="leave no room"):
+        _brumby(int(RESERVE_BYTES + fixed + 7.9 * row))
+    assert fit_slots(128, None, 1, 1) == 128
+    assert fit_slots(128, 10 ** 12, 10 ** 9, 0) == 128
+    # the reserve stands in ONE place, with its reason
+    assert RESERVE_BYTES == 4_300_000_000
+
+
+@pytest.mark.parametrize("preset,want", [
+    ("deepseek_v2_ep8", 128), ("jamba2_3b", 128), ("kimi_linear_ep4", 128),
+    ("lfm2_moe_ep2", 128), ("laguna_xs2_pp8", 128),
+    ("nemotron3_super_ep8", 128), ("brumby_14b_pp8", 32)])
+def test_every_configuration_derives_its_slots_under_the_chips_limit(
+        preset, want):
+    """The six that were there come out at the ceiling, with the programs
+    they had (their ladder); Brumby at what 170 MB a row leave."""
+    model = PRESETS[preset]
+    eng = GenerateEngine(f"generate:{preset}", model,
+                         np.arange(2048) % model["vocab_held"],
+                         sizes=GenerateSizes(), memory_limit=V5E_LIMIT)
+    try:
+        assert eng.slots() == (want, 128)
+        assert eng.buckets == list(range(want // 8, want + 1, want // 8))
+        assert len(eng._free_slots) == want
+        assert eng._pool.n_pages == (
+            1 + 16 + want * 3 if "pages" in eng._state_shapes else 1)
+    finally:
+        eng.stop()
+
+
+def test_an_engine_without_pages_serves_with_generations_waiting_for_slots():
+    """A family with NO cache rows through the engine's whole life: more
+    generations than slots are submitted, so every one that ends hands its
+    slot to one that waits (``_pending``); a stream is cancelled while some
+    of its generations hold slots and some wait; no page is ever pinned,
+    allocated or counted."""
+    from evam_tpu.obs import metrics
+
+    eng = _brumby(sizes=dataclasses.replace(BRUMBY_SIZES, slots=2),
+                  name="generate:waits")
+    eng.warm_async()
+    assert eng.warmed.wait(300) and eng.warm_error is None
+    try:
+        waited = metrics.render().count("evam_generate_slot_wait_seconds")
+        assert waited
+        rows = metrics.get_counter("evam_generate_latent_rows_read",
+                                   {"kind": "decode"})
+        futs = [eng.submit(stream=f"s{i % 3}", prompt_ids=_prompt(i, 5 + 9 * i),
+                           max_new_tokens=5) for i in range(7)]
+        doomed = [eng.submit(stream="doomed", prompt_ids=_prompt(i, 8),
+                             max_new_tokens=40) for i in range(5)]
+        assert eng.queue_depth() > 0          # they wait for slots
+        eng.cancel_stream("doomed")
+        assert all(f.result(timeout=120) is None for f in doomed)
+        for f in futs:
+            out = f.result(timeout=300)
+            assert len(out["ids"]) == 5 and np.isfinite(
+                out["top_logits"]).all()
+        deadline = time.time() + 10
+        while len(eng._free_slots) != 2 and time.time() < deadline:
+            time.sleep(0.05)
+        assert len(eng._free_slots) == 2 and eng.queue_depth() == 0
+        assert eng.pages_in_use() == (0, 0) and not eng._pool.pinned
+        assert eng.state_slots()[:2] == (0, 2)
+        assert metrics.get_counter("evam_generate_latent_rows_read",
+                                   {"kind": "decode"}) == rows
+    finally:
+        eng.stop()

@@ -724,3 +724,101 @@ def test_nemotron_step_programs_compile_at_published_widths(one_chip,
         assert not re.search(rf"= \w+\[{shape}\]\S* copy\(", text), shape
     assert compiled.memory_analysis().temp_size_in_bytes < {
         "decode": 32, "prefill": 256}[program] << 20
+
+
+def _power_state(s, rows, layers=5, heads=8, d=128):
+    from evam_tpu.ops.pallas_power import expanded
+
+    return (s((layers, rows, heads, expanded(d), d), jnp.float32),
+            s((layers, rows, heads, d // 2 + 1, d), jnp.float32))
+
+
+@pytest.mark.parametrize("tokens,segments", [
+    (512, 8),    # a prefill chunk of the deployment
+    (128, 2),    # a short chunk
+])
+def test_pow_chunk_scan_kernel_compiles_at_published_widths(
+        one_chip, tokens, segments):
+    """Brumby's chunk kernel at the published widths: 8 key-value heads of
+    5 query heads of 128, a state of 8256 x 128 a head read from and
+    written to its slot row by prefetched scalars, the whole 5.8 GB of slot
+    state aliased in and out."""
+    from evam_tpu.ops.pallas_power import chunk_scan
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    heads, group, d = 8, 5, 128
+    compiled = jax.jit(chunk_scan, donate_argnums=(8, 9)).lower(
+        s((), jnp.int32), s((tokens, heads, group, d)),
+        s((tokens, heads, d)), s((tokens, heads, d)),
+        s((tokens, heads), jnp.float32), s((tokens,), jnp.int32),
+        s((segments,), jnp.int32), s((segments,), jnp.int32),
+        *_power_state(s, 34)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "pow_chunk_scan" in text
+    # no second copy of the state beside the donated one
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+
+
+@pytest.mark.parametrize("rows", [4, 32])   # the least bucket, the slots
+def test_pow_decode_kernel_compiles_at_published_widths(one_chip, rows):
+    """The fifth body on ops/slot_rows.py's addressing, a grid of (step
+    row, key-value head): the whole slot state goes in and comes out in
+    place."""
+    from evam_tpu.ops.pallas_power import decode_rows
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    heads, group, d = 8, 5, 128
+    compiled = jax.jit(decode_rows, donate_argnums=(7, 8)).lower(
+        s((), jnp.int32), s((rows,), jnp.int32), s((rows,), jnp.bool_),
+        s((rows, heads, group, d)), s((rows, heads, d)),
+        s((rows, heads, d)), s((rows, heads), jnp.float32),
+        *_power_state(s, 34)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "pow_decode_rows" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_brumby_step_programs_compile_at_published_widths(one_chip,
+                                                          monkeypatch,
+                                                          program):
+    """Both step programs of the Brumby family at the published widths
+    (here over 128 slots, 22 GB of state that no chip holds: the compiler
+    lays out what it is described): the five layers in ONE loop, so each
+    kernel is in its program once; the slot state passes the loop in place
+    (no copy of it, whole or a layer of it), and no page cache exists."""
+    import re
+
+    from evam_tpu.models.lm import brumby as lm, common
+    from evam_tpu.models.lm.presets import PRESETS
+
+    monkeypatch.setattr(common, "TARGET_TPU", True)
+    cfg = lm.Config.from_dict(PRESETS["brumby_14b_pp8"])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(lambda: lm.make_params(cfg)))
+    assert params["layers"]["mlp_gate"].shape == (5, 5120, 17408)
+    # the engine's rows at the slots it derives: 32 + the null + the snapshot
+    monkeypatch.setattr(
+        lm, "state_shapes", lambda cfg, n, p, slots, real=lm.state_shapes:
+        real(cfg, n, p, 32))
+    compiled, state = _compile_step(lm, cfg, params, one_chip, program,
+                                    traced_prefix=True)
+    text = compiled.as_text()
+    for name, n in (("pow_decode_rows", int(program == "decode")),
+                    ("pow_chunk_scan", int(program == "prefill"))):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == n, name
+    assert set(state) == {"pow", "pow_z"}
+    assert state["pow"].shape == (5, 34, 8, 8256, 128)
+    for shape in ("5,34,8,8256,128", "34,8,8256,128", "5,5120,17408",
+                  "5,17408,5120"):
+        assert not re.search(rf"= \w+\[{shape}\]\S* copy\(", text), shape
+    assert compiled.memory_analysis().temp_size_in_bytes < {
+        "decode": 64, "prefill": 512}[program] << 20
