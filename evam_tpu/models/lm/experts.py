@@ -22,7 +22,16 @@ token none of whose experts is held) and the shared experts; that partial
 sum goes on to the next layer. Nothing stands in for absent chips. The
 held experts' work follows the tokens routed to them: assignments are
 sorted by expert and run through the grouped products of
-ops/pallas_grouped.py (off the chip: ``jax.lax.ragged_dot``).
+ops/pallas_grouped.py (off the chip: ``jax.lax.ragged_dot``). Around
+those products no operation walks the assignments one at a time and no
+pass touches sorted rows that the un-sort does not read (PR 58: on a TPU a
+scatter and a gather of single scalars are loops of one trip an element,
+~10 ns each): the group sizes, the kept groups and the chosen experts'
+scores are dense comparisons that fuse into one reduction each, the
+assignments lie CHOICE-major (``[top_k, T]``: a token's ``top_k`` rows
+come back ``T`` apart, so the weighted sum adds ``top_k`` slabs ``[T, w]``
+and ``top_k`` is never a tile's padded minor axis), and rows that hold no
+held assignment are dropped by the select that weights the gathered rows.
 
 What differs between the families is data of the config: ``score_func``
 (``softmax`` | ``sigmoid``), ``n_group`` / ``topk_group`` (1: no group
@@ -86,7 +95,10 @@ def route(cfg, x, router, bias=None):
     expert's; the best ``topk_group`` groups are kept (ties: the lower
     index, as ``lax.top_k``), the rest set to 0; then the best ``top_k``
     of what is left. With a ``bias`` [experts] the experts are chosen by
-    ``score + bias`` and weighted by the score alone."""
+    ``score + bias`` and weighted by the score alone. The kept groups and
+    the chosen scores are one-hot comparisons with the ids, exact: the
+    ``.at[].set`` and the ``take_along_axis`` they stand for are a loop
+    of one trip an element on a TPU."""
     logits = jnp.dot(x.astype(F32), router.astype(F32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = (jax.nn.sigmoid(logits) if cfg.score_func == "sigmoid"
@@ -97,13 +109,15 @@ def route(cfg, x, router, bias=None):
         per_group = cfg.n_experts // cfg.n_group
         group = chosen_by.reshape(t, cfg.n_group, per_group).max(-1)
         _, keep = jax.lax.top_k(group, cfg.topk_group)
-        kept = jnp.zeros((t, cfg.n_group), bool).at[
-            jnp.arange(t)[:, None], keep].set(True)
+        kept = (keep[..., None] == jnp.arange(cfg.n_group)).any(1)
         chosen_by = jnp.where(jnp.repeat(kept, per_group, axis=1), chosen_by,
                               0.0)
     w, ids = jax.lax.top_k(chosen_by, cfg.top_k)
     if bias is not None:
-        w = jnp.take_along_axis(scores, ids, axis=1)
+        # a maximum over the one chosen value: a SUM here is merged by the
+        # compiler with ``w.sum`` below into one reduction in another order
+        w = jnp.where(ids[..., None] == jnp.arange(cfg.n_experts),
+                      scores[:, None, :], -jnp.inf).max(-1)
     if cfg.norm_topk and cfg.top_k > 1:
         w = w / (w.sum(-1, keepdims=True) + cfg.topk_eps)
     if cfg.scale_routed:
@@ -126,23 +140,29 @@ def held_experts(cfg, lp: dict, x, w, ids, live, layer=None):
     computed, however uneven the routing. One path for every family:
     measured on a v5e (PR 39) the kernel beats ``ragged_dot`` at 64
     experts of 4.7 MB a matrix and at 20 of 15.7 MB, in chunks and in
-    decode steps. Returns the sum [T, hidden], the number of held
+    decode steps. The assignments are numbered choice-major (``j * T + i``
+    for token ``i``'s ``j``-th expert), the group sizes are a comparison
+    of the sort key with the held ids summed over the rows (``bincount``
+    is a scatter), and nothing zeroes the sorted rows past the last held
+    assignment, which hold whatever the kernel left there: only an
+    assignment that is not this chip's gathers one, and the select that
+    weights the gathered rows ``[top_k, T, w]`` drops it, NaN or not.
+    Returns the sum [T, hidden], the number of held
     assignments, the number of held experts that received at least one
     and the (row tile, expert) pairs one product visits (the times an
     expert's matrix is read). With ``layer`` the ``expert_*`` tensors
     are stacks [layers, held, ...] of which that layer's are read."""
     t, k = ids.shape
     n_held = lp["expert_down"].shape[-3]
-    local = ids - cfg.held_lo
-    mine = (local >= 0) & (local < n_held) & live[:, None]
+    local = ids.T - cfg.held_lo
+    mine = (local >= 0) & (local < n_held) & live
     m = pallas_grouped.padded(t * k)
     sort_key = jnp.pad(jnp.where(mine, local, n_held).reshape(-1),
                        (0, m - t * k), constant_values=n_held)
     order = jnp.argsort(sort_key, stable=True)
-    sizes = jnp.bincount(sort_key, length=n_held + 1)[:n_held].astype(
-        jnp.int32)
+    sizes = (sort_key[:, None] == jnp.arange(n_held)).sum(0, dtype=jnp.int32)
     n_mine = sizes.sum()
-    rows = x[jnp.minimum(order // k, t - 1)]
+    rows = x[order % t]
     ops = pallas_grouped
     swiglu_rows, relu2_rows, product_rows = (
         (ops.swiglu, ops.relu2, ops.product) if on_tpu()
@@ -153,11 +173,10 @@ def held_experts(cfg, lp: dict, x, w, ids, live, layer=None):
     else:
         hmid = relu2_rows(rows, lp["expert_up"], sizes, layer)
     y = product_rows(hmid, lp["expert_down"], sizes, layer)
-    # rows past the last group hold whatever the kernel left there
-    y = jnp.where((jnp.arange(m) < n_mine)[:, None], y, 0)
     back = jnp.argsort(order)[:t * k]
-    y = y[back].reshape(t, k, -1).astype(F32)
-    out = (y * jnp.where(mine, w, 0.0)[..., None]).sum(1)
+    y = y[back].reshape(k, t, -1).astype(F32)
+    # a select, not a product with 0: a row past the last group may be NaN
+    out = jnp.where(mine[..., None], y * w.T[..., None], 0.0).sum(0)
     return (out.astype(BF16), n_mine, (sizes > 0).sum().astype(jnp.int32),
             pallas_grouped.n_visits(sizes, m))
 

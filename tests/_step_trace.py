@@ -26,7 +26,8 @@ from evam_tpu.models.lm.presets import PRESETS
 RECORD = Path(__file__).resolve().parent / "golden" / "step_traces.json"
 #: the deployments whose programs the record holds, one a family
 FAMILY_PRESETS = ("deepseek_v2_ep8", "jamba2_3b", "kimi_linear_ep4",
-                  "lfm2_moe_ep2", "laguna_xs2_pp8", "brumby_14b_pp8")
+                  "lfm2_moe_ep2", "laguna_xs2_pp8", "brumby_14b_pp8",
+                  "nemotron3_super_ep8")
 
 
 def _step(lm, cfg, program: str):

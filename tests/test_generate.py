@@ -1961,10 +1961,10 @@ def test_the_key_block_classes_are_on_metrics(engine):
 
 
 @pytest.mark.parametrize("program,on_chip,want", [
-    ("decode", True, "68d83f321286dda0"),
-    ("decode", False, "2ef1443f3948d784"),
-    ("prefill", True, "687abf37d442df99"),
-    ("prefill", False, "5c03add241319fb1")])
+    ("decode", True, "69e183c79370fdc9"),
+    ("decode", False, "4af32f910955f26a"),
+    ("prefill", True, "48dadf58afc1c338"),
+    ("prefill", False, "a9fc55dfbebef9d0")])
 def test_the_step_programs_compute_what_they_did(monkeypatch, program,
                                                  on_chip, want):
     """The guard of the modules this family shares with the others

@@ -1068,10 +1068,10 @@ def test_third_describe_pipeline_end_to_end_through_rest(eight_devices,
 
 
 @pytest.mark.parametrize("program,on_chip,want", [
-    ("decode", True, "0e80c663e0b22c01"),
-    ("decode", False, "8c08761242c93aea"),
-    ("prefill", True, "091c3e5009ed0408"),
-    ("prefill", False, "901fb13099eedfe5")])
+    ("decode", True, "c1a749fbd429b090"),
+    ("decode", False, "2600070ff9f97575"),
+    ("prefill", True, "a0c17e83f8d98ff4"),
+    ("prefill", False, "d27caf8b8626abd6")])
 def test_the_step_programs_compute_what_they_did(monkeypatch, program,
                                                  on_chip, want):
     """The guard of the modules this family shares with the others

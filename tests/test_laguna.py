@@ -1151,10 +1151,10 @@ def test_fifth_describe_pipeline_end_to_end_through_rest(eight_devices,
 
 
 @pytest.mark.parametrize("program,on_chip,want", [
-    ("decode", True, "6f333eba2ff6cb62"),
-    ("decode", False, "fbd2049b6b42458b"),
-    ("prefill", True, "a7ca02a97ebe7fda"),
-    ("prefill", False, "c979b374cca33766")])
+    ("decode", True, "f2b6f31423d180f4"),
+    ("decode", False, "9cfca97248f94a26"),
+    ("prefill", True, "80ba93595b73b252"),
+    ("prefill", False, "009c2d7306a62be0")])
 def test_the_step_programs_compute_what_they_did(monkeypatch, program,
                                                  on_chip, want):
     """The guard of the modules this family shares with the others

@@ -976,10 +976,10 @@ def test_fourth_describe_pipeline_end_to_end_through_rest(eight_devices,
 
 
 @pytest.mark.parametrize("program,on_chip,want", [
-    ("decode", True, "f3610f2f6088ee1d"),
-    ("decode", False, "d02a0d3da5bdbfaf"),
-    ("prefill", True, "a42afe6a7709bb15"),
-    ("prefill", False, "c274ed507b4adcaa")])
+    ("decode", True, "7843f475c18b948f"),
+    ("decode", False, "b2ca0004bdab42f1"),
+    ("prefill", True, "912983d448a06902"),
+    ("prefill", False, "e086034d64e2e7de")])
 def test_the_step_programs_compute_what_they_did(monkeypatch, program,
                                                  on_chip, want):
     """The guard of the modules this family shares with the others

@@ -903,3 +903,24 @@ def test_sixth_describe_pipeline_end_to_end_through_rest(eight_devices,
     assert row["items"] == 4 and row["compiled_programs"] == 5
     assert row["state_slots_in_use"] == 0 and row["state_bytes"] > 0
     assert row["pages_in_use"] == 4 and row["capacity_fps"] > 0
+
+
+# ------------------------------------ what the shared modules compute
+
+
+@pytest.mark.parametrize("program,on_chip,want", [
+    ("decode", True, "ca49b03d143dc641"),
+    ("decode", False, "d409d8a62c4191a3"),
+    ("prefill", True, "96e4226a98936d65"),
+    ("prefill", False, "163cb3dfc25e1c25")])
+def test_the_step_programs_compute_what_they_did(monkeypatch, program,
+                                                 on_chip, want):
+    """The guard of the modules this family shares with the others
+    (tests/_step_trace.py): its two step programs at the deployment's
+    sizes, traced for the chip (the Pallas kernels' bodies among the
+    operations) and for the host (their twins), digest to what they did
+    when PR 58 entered them in the record. A PR that changes an operation
+    of THIS family's served path moves the digest, and says so."""
+    from _step_trace import check
+
+    check("nemotron3_super_ep8", program, on_chip, monkeypatch, want)

@@ -6,6 +6,7 @@ against ``held_experts`` through its twin."""
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -137,3 +138,190 @@ def test_held_experts_through_the_kernel_are_those_through_its_twin(
     assert np.isfinite(a).all()
     assert np.abs(a - b).max() <= 0.02 * np.abs(b).max()
     assert not a[~np.asarray(live)].any()
+
+
+#: (preset, whether its family hands ``moe`` every layer's tensors stacked)
+EXPERT_PRESETS = [("deepseek_v2_tiny", False), ("kimi_linear_tiny", False),
+                  ("lfm2_moe_tiny", True), ("laguna_tiny", True),
+                  ("nemotron_h_tiny", True)]
+
+
+def _route_before(cfg, x, router, bias=None):
+    """``experts.route`` as it was before PR 58: the group mask by a
+    scatter, the chosen scores by a gather of single scalars."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.score_func == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    t = scores.shape[0]
+    chosen_by = scores if bias is None else scores + bias.astype(jnp.float32)
+    if cfg.n_group > 1:
+        per_group = cfg.n_experts // cfg.n_group
+        group = chosen_by.reshape(t, cfg.n_group, per_group).max(-1)
+        _, keep = jax.lax.top_k(group, cfg.topk_group)
+        kept = jnp.zeros((t, cfg.n_group), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        chosen_by = jnp.where(jnp.repeat(kept, per_group, axis=1), chosen_by,
+                              0.0)
+    w, ids = jax.lax.top_k(chosen_by, cfg.top_k)
+    if bias is not None:
+        w = jnp.take_along_axis(scores, ids, axis=1)
+    if cfg.norm_topk and cfg.top_k > 1:
+        w = w / (w.sum(-1, keepdims=True) + cfg.topk_eps)
+    if cfg.scale_routed:
+        w = w * cfg.routed_scale
+    return w, ids
+
+
+def _held_experts_before(cfg, lp, x, w, ids, live, layer=None):
+    """``experts.held_experts`` as it was before PR 58: the sizes by
+    ``bincount``, a token's assignments side by side, every sorted row past
+    the last assignment zeroed before the un-sort."""
+    t, k = ids.shape
+    n_held = lp["expert_down"].shape[-3]
+    local = ids - cfg.held_lo
+    mine = (local >= 0) & (local < n_held) & live[:, None]
+    m = pg.padded(t * k)
+    sort_key = jnp.pad(jnp.where(mine, local, n_held).reshape(-1),
+                       (0, m - t * k), constant_values=n_held)
+    order = jnp.argsort(sort_key, stable=True)
+    sizes = jnp.bincount(sort_key, length=n_held + 1)[:n_held].astype(
+        jnp.int32)
+    n_mine = sizes.sum()
+    rows = x[jnp.minimum(order // k, t - 1)]
+    if cfg.expert_act == "swiglu":
+        hmid = pg.swiglu_xla(rows, lp["expert_gate"], lp["expert_up"], sizes,
+                             layer)
+    else:
+        hmid = pg.relu2_xla(rows, lp["expert_up"], sizes, layer)
+    y = pg.product_xla(hmid, lp["expert_down"], sizes, layer)
+    y = jnp.where((jnp.arange(m) < n_mine)[:, None], y, 0)
+    back = jnp.argsort(order)[:t * k]
+    y = y[back].reshape(t, k, -1).astype(jnp.float32)
+    out = (y * jnp.where(mine, w, 0.0)[..., None]).sum(1)
+    return (out.astype(pg.BF16), n_mine,
+            (sizes > 0).sum().astype(jnp.int32), pg.n_visits(sizes, m))
+
+
+def _expert_layer(preset, stacked, tokens=48):
+    """A family's tiny config, seeded tensors of its expert layer (stacked:
+    two layers' of which ``layer`` 1 is run), tokens and their ``live``.
+    Experts 1 and 2 are one column of the router under one bias, and with
+    groups so are the first experts of groups 0 and 1, so scores TIE;
+    tokens 0 and 1 are one row; a fifth of the rows are dead."""
+    from evam_tpu.models.lm import family
+
+    model = PRESETS[preset]
+    cfg = family(model["model_type"]).Config.from_dict(model)
+    n_held = getattr(cfg, "n_held", None) or cfg.per_group
+    r = np.random.default_rng(58)
+    lp = {}
+    for name, shape in experts.tensor_shapes(
+            cfg, bias=model["model_type"] != "deepseek_v2").items():
+        lead = ((2,) if stacked else ()) + (
+            (n_held,) if name.startswith("expert_") else ())
+        a = r.standard_normal(lead + shape) * (
+            0.5 if name.startswith("router") else 0.1)
+        if name.startswith("router"):
+            a[..., 2] = a[..., 1]
+            if cfg.n_group > 1:
+                a[..., cfg.n_experts // cfg.n_group] = a[..., 0]
+        lp[name] = jnp.asarray(a, jnp.float32 if name.startswith("router")
+                               else pg.BF16)
+    x = r.standard_normal((tokens, cfg.hidden))
+    x[1] = x[0]
+    live = r.random(tokens) < 0.8
+    live[:2] = True
+    return (cfg, lp, jnp.asarray(x, pg.BF16), jnp.asarray(live),
+            1 if stacked else None)
+
+
+def _routed(lp, layer):
+    """The router and its selection bias, of ``layer`` where stacked."""
+    router, bias = lp["router"], lp.get("router_bias")
+    if layer is not None:
+        router, bias = router[layer], None if bias is None else bias[layer]
+    return router, bias
+
+
+@pytest.mark.parametrize("preset,stacked", EXPERT_PRESETS)
+def test_route_is_the_route_it_replaces_bit_for_bit(preset, stacked):
+    """The group mask by comparison and the chosen scores by a one-hot
+    maximum are the scatter's and the gather's, tied scores, groups and a
+    selection bias among the inputs."""
+    cfg, lp, x, _, layer = _expert_layer(preset, stacked)
+    router, bias = _routed(lp, layer)
+    (w, ids), (w0, ids0) = (f(cfg, x, router, bias)
+                            for f in (experts.route, _route_before))
+    ids0 = np.asarray(ids0)
+    assert (bias is None) == (preset == "deepseek_v2_tiny")
+    assert np.array_equal(np.asarray(ids), ids0)
+    assert np.array_equal(np.asarray(w), np.asarray(w0))
+    # the tie is among the chosen: somewhere 1 and 2 stand side by side
+    both = (ids0[:, :-1] == 1) & (ids0[:, 1:] == 2)
+    assert both.any() or cfg.n_group > 1
+
+
+@pytest.mark.parametrize("preset,stacked", EXPERT_PRESETS)
+def test_held_experts_are_the_held_experts_they_replace_bit_for_bit(
+        monkeypatch, preset, stacked):
+    """Sizes by comparison, assignments choice-major and the mask where the
+    rows are gathered give the sum and the three counts of ``bincount``, a
+    token's assignments side by side and the pass over every sorted row:
+    dead rows, tokens with no held expert and (Nemotron-H) a latent among
+    the inputs; and so does the whole layer."""
+    cfg, lp, x, live, layer = _expert_layer(preset, stacked)
+    w, ids = experts.route(cfg, x, *_routed(lp, layer))
+    rows = x
+    if cfg.moe_latent:
+        rows = experts.mm(x, lp["latent_down"][layer])
+    got = experts.held_experts(cfg, lp, rows, w, ids, live, layer)
+    want = _held_experts_before(cfg, lp, rows, w, ids, live, layer)
+    assert [int(v) for v in got[1:]] == [int(v) for v in want[1:]]
+    assert np.array_equal(np.asarray(got[0], np.float32),
+                          np.asarray(want[0], np.float32))
+    n_held = lp["expert_down"].shape[-3]
+    local = np.asarray(ids) - cfg.held_lo
+    mine = (local >= 0) & (local < n_held) & np.asarray(live)[:, None]
+    assert int(got[1]) == mine.sum() > 0
+    assert not np.asarray(live).all()
+    if n_held < cfg.n_experts:   # Laguna holds them all
+        assert (~mine.any(1) & np.asarray(live)).any()
+        assert not np.asarray(got[0], np.float32)[~mine.any(1)].any()
+
+    whole = experts.moe(cfg, lp, x, live, layer)
+    monkeypatch.setattr(experts, "route", _route_before)
+    monkeypatch.setattr(experts, "held_experts", _held_experts_before)
+    whole0 = experts.moe(cfg, lp, x, live, layer)
+    assert np.array_equal(np.asarray(whole[1]), np.asarray(whole0[1]))
+    assert np.array_equal(np.asarray(whole[0], np.float32),
+                          np.asarray(whole0[0], np.float32))
+
+
+@pytest.mark.parametrize("preset,stacked", [EXPERT_PRESETS[1],
+                                            EXPERT_PRESETS[4]])
+def test_rows_past_the_last_assignment_never_reach_the_sum(
+        monkeypatch, preset, stacked):
+    """No pass zeroes the sorted rows past the last held assignment any
+    more: a product that leaves NaN there (the kernel leaves whatever was
+    there) gives the same finite sum, because only an assignment that is
+    not this chip's gathers such a row and the select drops it."""
+    cfg, lp, x, live, layer = _expert_layer(preset, stacked)
+    w, ids = experts.route(cfg, x, *_routed(lp, layer))
+    if cfg.moe_latent:
+        x = experts.mm(x, lp["latent_down"][layer])
+    want = experts.held_experts(cfg, lp, x, w, ids, live, layer)
+    product_xla, seen = pg.product_xla, []
+
+    def product_with_nan_rows(hmid, down, sizes, layer=None):
+        y = product_xla(hmid, down, sizes, layer)
+        seen.append(y.shape[0])
+        return jnp.where((jnp.arange(y.shape[0]) < sizes.sum())[:, None], y,
+                         jnp.nan)
+
+    monkeypatch.setattr(pg, "product_xla", product_with_nan_rows)
+    got = experts.held_experts(cfg, lp, x, w, ids, live, layer)
+    assert seen and int(want[1]) < seen[0]   # there were such rows
+    a = np.asarray(got[0], np.float32)
+    assert np.isfinite(a).all()
+    assert np.array_equal(a, np.asarray(want[0], np.float32))

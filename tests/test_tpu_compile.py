@@ -159,6 +159,61 @@ def test_expert_layer_runs_the_grouped_kernel_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("rows", [64, 512])   # a decode step, a chunk
+@pytest.mark.parametrize("preset,stacked", [
+    ("deepseek_v2_ep8", False), ("kimi_linear_ep4", False),
+    ("lfm2_moe_ep2", True), ("laguna_xs2_pp8", True),
+    ("nemotron3_super_ep8", True)])
+def test_no_operation_of_the_expert_layer_walks_its_assignments(
+        one_chip, monkeypatch, preset, stacked, rows):
+    """Around the grouped kernels, in all five expert families at the
+    published widths: no scatter (the sizes and the group mask are dense
+    comparisons), no gather of single scalars under ``router`` (the chosen
+    scores are a one-hot maximum), no stand-alone select over the sorted
+    rows ``[m, w]`` (the mask is where the rows are gathered), and the
+    assignments lie choice-major: no ``[rows, top_k, w]`` with ``top_k``
+    padded to a sublane tile. Temporaries under the bound of the test
+    above."""
+    import re
+
+    from evam_tpu.models.lm import experts, family
+    from evam_tpu.models.lm.presets import PRESETS
+    from evam_tpu.ops.pallas_grouped import padded
+
+    model = PRESETS[preset]
+    cfg = family(model["model_type"]).Config.from_dict(model)
+    monkeypatch.setattr(experts, "on_tpu", lambda: True)
+    n_held = getattr(cfg, "n_held", None) or cfg.per_group
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lp = {name: s(((4,) if stacked else ())
+                  + ((n_held,) if name.startswith("expert_") else ())
+                  + shape,
+                  jnp.float32 if name.startswith("router") else jnp.bfloat16)
+          for name, shape in experts.tensor_shapes(
+              cfg, bias=preset != "deepseek_v2_ep8").items()}
+    layer = (s((), jnp.int32),) if stacked else ()
+    compiled = jax.jit(
+        lambda lp, x, live, *layer: experts.moe(cfg, lp, x, live, *layer)
+    ).lower(lp, s((rows, cfg.hidden)), s((rows,), jnp.bool_),
+            *layer).compile()
+    text = compiled.as_text()
+    assert "expert_down" in text and "ragged" not in text
+    assert "scatter" not in text
+    m, w = padded(rows * cfg.top_k), cfg.moe_latent or cfg.hidden
+    for line in text.splitlines():
+        sizes = re.search(r" gather\(.*slice_sizes=\{([\d,]*)\}", line)
+        if sizes and "/router/" in line:
+            assert set(sizes.group(1).split(",")) != {"1"}, line
+        assert not re.search(
+            rf'= bf16\[{m},{w}\]\S* fusion\(.*op_name="[^"]*select_n"',
+            line), line
+    assert f"[{rows},{cfg.top_k},{w}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("rows", [64, 128])
 def test_conv_decode_kernel_compiles_at_published_widths(one_chip, rows):
     """A layer's taps [130, 16, 256] go in and come out in place, a slot's
